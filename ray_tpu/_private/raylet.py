@@ -15,15 +15,17 @@ feeding actor failover.
 from __future__ import annotations
 
 import asyncio
+import atexit
 import logging
 import os
+import signal
 import subprocess
 import sys
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
-from ray_tpu._private import rpc
+from ray_tpu._private import compile_cache, rpc
 from ray_tpu._private.common import NodeInfo, TaskSpec
 from ray_tpu._private.config import Config
 from ray_tpu._private.ids import NodeID, ObjectID, PlacementGroupID, WorkerID
@@ -85,6 +87,7 @@ class _SharedForkServer:
             return
         finally:
             self._starting = False
+        atexit.register(self._stop_at_exit)
         if self._pending_spawns:
             pending, self._pending_spawns = self._pending_spawns, []
             if not self._write_batch([(e, lp) for e, lp, _r in pending]):
@@ -94,6 +97,28 @@ class _SharedForkServer:
                 self._fail_pending()
                 return
         asyncio.ensure_future(self._reader())
+
+    def _stop_at_exit(self):
+        """Interpreter exit: the zygote and the workers it forked are this
+        process's to stop, and none may outlive it — not even for the
+        moment the zygote would need to notice its stdin closing. SIGTERM
+        makes the zygote end and reap its children first."""
+        if self.dead or self.proc.returncode is not None:
+            return
+        pid = self.proc.pid
+        try:
+            os.kill(pid, signal.SIGTERM)
+        except ProcessLookupError:
+            return
+        deadline = time.monotonic() + 10.0
+        while time.monotonic() < deadline:
+            try:
+                if os.waitpid(pid, os.WNOHANG)[0]:
+                    return
+            except ChildProcessError:   # reaped by asyncio's child watcher
+                return
+            time.sleep(0.02)
+        os.kill(pid, signal.SIGKILL)
 
     def _write_batch(self, jobs: List[tuple]) -> bool:
         """One spawn_batch line for N workers; False if the pipe is gone."""
@@ -435,6 +460,11 @@ class WorkerHandle:
     dag_pins: set = field(default_factory=set)
 
 
+# The resource that stands for a physical device a process holds from the
+# moment it opens it until it exits (one process per chip).
+CHIP_RESOURCE = "TPU"
+
+
 class ResourcePool:
     """Vector resource accounting: node pool + per-bundle sub-pools."""
 
@@ -444,6 +474,8 @@ class ResourcePool:
         # (pg_id_bytes, bundle_index) -> {resource: amount}
         self.bundles: Dict[tuple, Dict[str, float]] = {}
         self.bundle_available: Dict[tuple, Dict[str, float]] = {}
+        # returned bundle -> chips its still-live workers lease
+        self._held_chips: Dict[tuple, float] = {}
 
     def fits(self, request: Dict[str, float], pg_key: Optional[tuple] = None) -> bool:
         pool = self.bundle_available.get(pg_key) if pg_key else self.available
@@ -467,6 +499,15 @@ class ResourcePool:
         if pg_key is not None:
             pool = self.bundle_available.get(pg_key)
             if pool is None:
+                # Bundle already returned: only the chips return_bundle
+                # held back for this lease are still owed to the node.
+                held = self._held_chips.pop(pg_key, 0.0)
+                back = min(held, request.get(CHIP_RESOURCE, 0.0))
+                if back > 0:
+                    self.available[CHIP_RESOURCE] = \
+                        self.available.get(CHIP_RESOURCE, 0.0) + back
+                if held > back:
+                    self._held_chips[pg_key] = held - back
                 return
         else:
             pool = self.available
@@ -487,10 +528,19 @@ class ResourcePool:
         return True
 
     def return_bundle(self, key: tuple):
+        """Give a removed bundle's resources back to the node — except the
+        chips a live worker still leases from it: a process keeps an opened
+        chip until it exits, so those come back through release() when the
+        worker has gone, never while the chip is still held."""
         resources = self.bundles.pop(key, None)
-        self.bundle_available.pop(key, None)
+        unleased = self.bundle_available.pop(key, None) or {}
         if resources:
             for k, v in resources.items():
+                if k == CHIP_RESOURCE:
+                    held = v - unleased.get(k, 0.0)
+                    if held > 0:
+                        self._held_chips[key] = held
+                    v -= held
                 if v > 0:
                     self.available[k] = self.available.get(k, 0.0) + v
 
@@ -583,6 +633,7 @@ class Raylet:
         self.cluster_view: Dict[NodeID, dict] = {}
         self.address = ""
         self._tasks: List[asyncio.Task] = []
+        compile_cache.export_compile_cache_dir()
         self._worker_env = dict(os.environ)
         self._stopped = False
         self._resources_dirty = False
@@ -1955,6 +2006,9 @@ class Raylet:
                 continue
             if handle.is_actor_worker:
                 continue
+            if handle.lease_resources.get(CHIP_RESOURCE, 0) > 0:
+                self._retire_chip_worker(handle)
+                continue
             handle.leased = False
             handle.lease_conn = None
             self.pool.release(handle.lease_resources, handle.lease_pg)
@@ -2068,6 +2122,9 @@ class Raylet:
         handle = self.workers.get(worker_id)
         if handle is None or not handle.leased:
             return False
+        if handle.lease_resources.get(CHIP_RESOURCE, 0) > 0:
+            self._retire_chip_worker(handle)
+            return True
         handle.leased = False
         handle.lease_conn = None
         self.pool.release(handle.lease_resources, handle.lease_pg)
@@ -2085,6 +2142,18 @@ class Raylet:
             self._offer_idle_worker(handle)
         self._try_dispatch()
         return True
+
+    def _retire_chip_worker(self, handle: WorkerHandle):
+        """End a lease that held a chip. A process that opened a TPU chip
+        keeps it until it exits, so this worker is never pooled: it is told
+        to exit with its lease left in place, and _on_worker_disconnect
+        releases the lease when the process has gone — the TPU unit is
+        not granted again while the chip may still be held."""
+        handle.lease_conn = None
+        handle.is_actor_worker = False
+        handle.actor_id = None
+        if handle.conn is not None and not handle.conn.closed:
+            handle.conn.push_nowait("shutdown", {})
 
     def _pick_best_node(self, resources: Dict[str, float]) -> Optional[NodeID]:
         """Hybrid pack/spread over local + synced cluster view."""
@@ -2332,6 +2401,9 @@ class Raylet:
                 raise
         self._record_span(trace, "actor:ctor", t_ctor, time.time())
         if isinstance(reply, dict) and reply.get("app_error"):
+            if spec.resources.get(CHIP_RESOURCE, 0) > 0:
+                self._retire_chip_worker(worker)
+                return {"app_error": reply["app_error"]}
             # Constructor raised: the worker is still healthy — return it
             # to the idle pool (without this it would leak, unleasable,
             # one process per attempt) and surface the error to the GCS

@@ -21,6 +21,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import flash_attention, mha_reference, ring_attention
 
@@ -140,6 +142,23 @@ def _rope(x, theta: float, positions):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
+def _flash_on_mesh(q, k, v, cfg: GPTConfig, mesh):
+    """The flash kernel on [B, H, S, D]. On a TPU it is a Mosaic custom
+    call, which GSPMD cannot partition, so under a mesh of more than one
+    device it runs per shard: batch over 'data' x 'fsdp', heads over
+    'tensor', each device attending its own (batch, head) slice with no
+    collective."""
+    flash = partial(flash_attention, causal=True, block_q=cfg.flash_block_q,
+                    block_k=cfg.flash_block_k)
+    if mesh is None or mesh.size == 1:
+        return flash(q, k, v)
+    spec = P(("data", "fsdp"), "tensor", None, None)
+    # check_vma off: pallas_call declares no varying axes for its outputs,
+    # and the Pallas interpreter the CPU tests use fails the check inside.
+    return shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec, check_vma=False)(q, k, v)
+
+
 def _attention_block(layer, x, cfg: GPTConfig, positions, mesh):
     b, s, d = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
@@ -158,9 +177,7 @@ def _attention_block(layer, x, cfg: GPTConfig, positions, mesh):
     elif cfg.attention == "reference":
         o = mha_reference(q, k, v, causal=True)
     else:
-        o = flash_attention(q, k, v, causal=True,
-                            block_q=cfg.flash_block_q,
-                            block_k=cfg.flash_block_k)
+        o = _flash_on_mesh(q, k, v, cfg, mesh)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
     return jnp.einsum("bsd,de->bse", o, layer["attn"]["wo"].astype(dt))
 

@@ -26,6 +26,8 @@ import subprocess
 import sys
 from typing import List, Optional
 
+from ray_tpu._private.compile_cache import export_compile_cache_dir
+
 # Fixed workload: deterministic config + data seed shared by every mode.
 _VOCAB, _SEQ, _BATCH, _STEPS = 512, 64, 8, 2
 _DATA_SEED = 7
@@ -57,8 +59,8 @@ def step_loss(data_axis: int, fsdp_axis: int) -> float:
     strategy = strategy_from_name("fsdp")
     state = init_train_state(lambda: gpt_init(jax.random.PRNGKey(0), cfg),
                              opt, mesh, strategy)
-    step = make_train_step(lambda p, b: gpt_loss(p, b, cfg), opt, mesh,
-                           strategy, sample_params=state.params)
+    step = make_train_step(lambda p, b: gpt_loss(p, b, cfg, mesh=mesh),
+                           opt, mesh, strategy, sample_params=state.params)
     tokens_np = np.random.RandomState(_DATA_SEED).randint(
         0, cfg.vocab_size, (_BATCH, _SEQ + 1))
     # device_put against the GLOBAL sharding: each process materializes
@@ -108,10 +110,9 @@ def run_gang_subprocesses(n_processes: int, local_devices: int,
     """Spawn n worker processes, each `local_devices` CPU devices, run the
     fixed workload over the global mesh; return every process's loss."""
     port = free_port()
+    export_compile_cache_dir()
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)  # each worker sets its own device count
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/ray_tpu_jax_cache_cpu")
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")

@@ -26,29 +26,8 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the replication-check kwarg was
-    renamed check_rep -> check_vma; disable whichever this jax has (the
-    body mixes collectives manually — 0.4.x's rep inference rejects the
-    per-rank lax.cond branches)."""
-    import inspect
-    params = inspect.signature(_shard_map).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = False
-    elif "check_rep" in params:
-        kw["check_rep"] = False
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
 
 from ray_tpu.models.gpt import GPTConfig, _rmsnorm, _rope
 from ray_tpu.ops.attention import flash_attention, mha_reference
@@ -231,10 +210,12 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
              jax.tree_util.tree_flatten_with_path(stacked)[0]])
         lm_head = pp_params.get("lm_head", pp_params["embed"]["table"])
         tokens = batch["tokens"]
-        fn = _shard_map_compat(
+        # check_vma off: the body mixes collectives manually, with
+        # per-rank lax.cond branches the replication check rejects.
+        fn = shard_map(
             body, mesh=mesh,
             in_specs=(stacked_specs, P(), P(), P(), P("data"), P("data")),
-            out_specs=P())
+            out_specs=P(), check_vma=False)
         return fn(stacked, pp_params["embed"]["table"],
                   pp_params["final_norm"]["scale"], lm_head,
                   tokens[:, :-1], tokens[:, 1:])

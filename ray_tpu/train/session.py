@@ -14,9 +14,12 @@ bookkeeping, replacing the reference's queue+next_results pairing
 from __future__ import annotations
 
 import queue
+import sys
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
+
+import numpy as np
 
 from ray_tpu.train.checkpoint import Checkpoint
 
@@ -55,6 +58,22 @@ class TrainContext:
 
     def get_storage_path(self) -> str:
         return self.storage_path
+
+
+def _host_value(value: Any) -> Any:
+    """Metrics leave the worker as host values. A jax.Array pickled to the
+    driver is rebuilt there as a device array, which initializes a JAX
+    backend in the driver — and on a TPU host the chip belongs to this
+    worker, so the driver would fail or hang opening it."""
+    jax = sys.modules.get("jax")
+    if jax is not None and isinstance(value, jax.Array):
+        host = np.asarray(value)
+        return host.item() if host.ndim == 0 else host
+    if isinstance(value, dict):
+        return {k: _host_value(v) for k, v in value.items()}
+    if type(value) in (list, tuple):
+        return type(value)(_host_value(v) for v in value)
+    return value
 
 
 class _Session:
@@ -99,7 +118,7 @@ class _Session:
             raise _StopTraining()
         if checkpoint is not None:
             self._save_requested.clear()
-        self._results.put({"type": "report", "metrics": dict(metrics),
+        self._results.put({"type": "report", "metrics": _host_value(metrics),
                            "checkpoint": checkpoint,
                            "rank": self.context.world_rank})
         # Block until consumed: put the *next* item only after the driver

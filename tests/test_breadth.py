@@ -158,16 +158,25 @@ def test_cpu_sampler_catches_hot_function():
 
 
 def test_memory_snapshot():
+    import tracemalloc
     from ray_tpu.util.profiling import snapshot_memory
-    first = snapshot_memory()
-    if first.get("started"):
-        big = [bytearray(100_000) for _ in range(20)]  # noqa: F841
-        snap = snapshot_memory()
-    else:
-        big = [bytearray(100_000) for _ in range(20)]  # noqa: F841
-        snap = snapshot_memory()
-    assert snap["traced_current_bytes"] > 0
-    assert snap["top"]
+    was_tracing = tracemalloc.is_tracing()
+    try:
+        first = snapshot_memory()
+        if first.get("started"):
+            big = [bytearray(100_000) for _ in range(20)]  # noqa: F841
+            snap = snapshot_memory()
+        else:
+            big = [bytearray(100_000) for _ in range(20)]  # noqa: F841
+            snap = snapshot_memory()
+        assert snap["traced_current_bytes"] > 0
+        assert snap["top"]
+    finally:
+        # snapshot_memory starts tracing and leaves it on; left on in the
+        # pytest process it traces every allocation of every later test
+        # (pure Python measured ~70x slower for the rest of the suite).
+        if not was_tracing:
+            tracemalloc.stop()
 
 
 def test_stack_dump():

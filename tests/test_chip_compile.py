@@ -1,0 +1,104 @@
+"""Compile the main path's kernels for a TPU v5e that is described, not
+attached (the chip's own compiler is installed with libtpu). Nothing runs;
+what the compiler would refuse on the chip — a tile it cannot lay out, a
+Mosaic call it cannot partition — it refuses here, at no chip time.
+
+All compiles stay in this one process: two processes that ask for the TPU
+topology at once collide on /tmp/libtpu_lockfile.
+"""
+
+import os
+
+import pytest
+
+# GPT-2 small attention shapes: [batch, heads, seq, head_dim].
+SHAPE = (8, 12, 1024, 64)
+
+
+@pytest.fixture(scope="module")
+def v5e(jax_cpu):
+    """The four devices of a described v5e 2x2 host. The persistent compile
+    cache is off around these compiles: a TPU entry written without a chip
+    cannot be read back and only warns on the next run."""
+    jax = jax_cpu
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this box
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "forward_backward"])
+def test_flash_kernel_compiles_for_v5e(v5e, backward):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=128,
+                               block_k=128, interpret=False)
+
+    fn = fwd
+    if backward:
+        fn = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                      argnums=(0, 1, 2))
+    x = jax.ShapeDtypeStruct(SHAPE, jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    # forward: one Mosaic call; backward adds the dQ and the dK/dV kernels
+    assert text.count("tpu_custom_call") >= (3 if backward else 1)
+
+
+def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch):
+    """GPT-2 small's attention block, forward and backward, under
+    tp_fsdp on fsdp=2 x tensor=2. Without the shard_map around the flash
+    call the chip's compiler refuses it ("Mosaic kernels cannot be
+    automatically partitioned"); the CPU tests cannot see that, because
+    the interpreted kernel is plain XLA ops that GSPMD partitions."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+
+    # jax.default_backend() is the CPU here; take the kernel's TPU branch.
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    cfg = gpt.GPTConfig.gpt2_small()
+    mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2), devices=v5e)
+    strategy = strategy_from_name("tp_fsdp")
+    batch, seq = SHAPE[0], SHAPE[2]
+
+    layer = jax.eval_shape(
+        lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"][0]
+    layer_sh = strategy.param_shardings(mesh, {"layers": [layer]})["layers"][0]
+    layer = jax.tree_util.tree_map(
+        lambda leaf, sh: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=sh), layer, layer_sh)
+    x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype,
+                             sharding=strategy.activation_sharding(mesh))
+
+    def loss(layer, x):
+        positions = jnp.broadcast_to(jnp.arange(seq)[None, :], (batch, seq))
+        out = gpt._attention_block(layer, x, cfg, positions, mesh)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layer, x).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # the tensor-parallel out projection and the fsdp weights need them
+    assert "all-reduce" in text or "reduce-scatter" in text
+    assert "all-gather" in text

@@ -5,8 +5,6 @@ fake-accelerator pattern)."""
 import numpy as np
 import pytest
 
-from tests.helpers.jax_compat import jax04x_shard_map_grad_skip
-
 
 @pytest.fixture(scope="module")
 def jx(jax_cpu):
@@ -123,7 +121,6 @@ class TestAttention:
         for name, a, b in zip("qkv", g_ref, g_fl):
             assert float(jnp.abs(a - b).max()) < 2e-4, name
 
-    @jax04x_shard_map_grad_skip
     def test_ring_attention_matches(self, jx):
         import jax
         import jax.numpy as jnp
@@ -197,7 +194,6 @@ class TestGraftEntry:
     # unsharded-equivalence program): needs headroom beyond the 180 s
     # default when the XLA cache is cold or the box is loaded.
     @pytest.mark.timeout(600)
-    @jax04x_shard_map_grad_skip
     def test_entry_and_dryrun(self, jx):
         import sys
         sys.path.insert(0, "/root/repo")

@@ -7,8 +7,6 @@ matched capability-wise: python/ray/dag/compiled_dag_node.py:141.
 import numpy as np
 import pytest
 
-from tests.helpers.jax_compat import jax04x_shard_map_grad_skip
-
 
 @pytest.fixture(scope="module")
 def env(jax_cpu):
@@ -52,7 +50,6 @@ def test_pp_tp_loss_matches_dense(env):
     assert abs(got - env["dense_loss"]) < 5e-2, (got, env["dense_loss"])
 
 
-@jax04x_shard_map_grad_skip
 def test_pp_grads_match_dense(env):
     import jax
 
@@ -85,7 +82,6 @@ def test_pp_round_trip_params(env):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@jax04x_shard_map_grad_skip
 def test_pp_training_step_decreases_loss(env):
     import jax
     import optax
@@ -107,7 +103,6 @@ def test_pp_training_step_decreases_loss(env):
     assert float(m["loss"]) < float(m0["loss"])
 
 
-@jax04x_shard_map_grad_skip
 def test_pp_tp_training_step(env):
     import optax
 

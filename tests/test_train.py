@@ -180,6 +180,9 @@ def test_train_step_sharded_mlp(jax_cpu):
         state, m = step(state, batch)
         losses.append(float(m["loss"]))
     assert losses[-1] < losses[0]
+    # the state init_train_state builds is typed like the state the step
+    # returns: one trace, one compile
+    assert step._cache_size() == 1
 
 
 # Budget audit (PR 15, --durations): 62s — the multiprocess SPMD
