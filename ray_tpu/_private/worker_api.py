@@ -10,6 +10,7 @@ import asyncio
 import atexit
 import logging
 import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
 from ray_tpu import exceptions as exc
@@ -113,6 +114,7 @@ def init(address: Optional[str] = None, *,
         if ignore_reinit_error:
             return _state
         raise RuntimeError("ray_tpu already initialized")
+    started = time.time()
     if isinstance(address, str) and (address.startswith("ray_tpu://")
                                      or address.startswith("ray://")):
         # Client mode (reference: ray.init("ray://...")): the process
@@ -174,7 +176,27 @@ def init(address: Optional[str] = None, *,
     _state.run(_boot(), timeout=60)
     _state.initialized = True
     atexit.register(shutdown)
+    _record_init(started, time.time())
     return _state
+
+
+def _record_init(start: float, end: float) -> None:
+    """init() as the caller saw it: ray_tpu_init_seconds in this process's
+    registry and, where tracing is enabled (a driver with tracing off
+    records no span at all), the `runtime:init` flight-recorder span."""
+    try:
+        from ray_tpu._private import flightrec
+        from ray_tpu.util import metrics, tracing
+        metrics.Gauge(
+            "ray_tpu_init_seconds",
+            "wall time of this process's last ray_tpu.init(): head or "
+            "connection up and the driver's core worker started"
+        ).set(end - start)
+        if tracing.is_enabled():
+            tracing.export_span(flightrec.span_event(
+                "runtime:init", "runtime", start, end))
+    except Exception:  # noqa: BLE001 — observability never blocks init
+        logger.debug("init span/metric not recorded", exc_info=True)
 
 
 def client_mode():

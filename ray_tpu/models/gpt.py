@@ -124,9 +124,10 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
 
 
 def _rmsnorm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
+    with jax.named_scope("norm"):
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
 def _rope(x, theta: float, positions):
@@ -165,21 +166,25 @@ def _attention_block(layer, x, cfg: GPTConfig, positions, mesh):
     dt = cfg.dtype
 
     def proj(w):
-        return jnp.einsum("bsd,de->bse", x, w.astype(dt))
+        return jnp.einsum("bsd,de->bse", x, w.astype(dt)).reshape(
+            b, s, h, hd).transpose(0, 2, 1, 3)
 
-    q = proj(layer["attn"]["wq"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    k = proj(layer["attn"]["wk"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    v = proj(layer["attn"]["wv"]).reshape(b, s, h, hd).transpose(0, 2, 1, 3)
-    q = _rope(q, cfg.rope_theta, positions)
-    k = _rope(k, cfg.rope_theta, positions)
-    if cfg.attention == "ring":
-        o = ring_attention(q, k, v, mesh=mesh, causal=True)
-    elif cfg.attention == "reference":
-        o = mha_reference(q, k, v, causal=True)
-    else:
-        o = _flash_on_mesh(q, k, v, cfg, mesh)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
-    return jnp.einsum("bsd,de->bse", o, layer["attn"]["wo"].astype(dt))
+    with jax.named_scope("attn_proj"):
+        q = proj(layer["attn"]["wq"])
+        k = proj(layer["attn"]["wk"])
+        v = proj(layer["attn"]["wv"])
+        q = _rope(q, cfg.rope_theta, positions)
+        k = _rope(k, cfg.rope_theta, positions)
+    with jax.named_scope("attn_core"):
+        if cfg.attention == "ring":
+            o = ring_attention(q, k, v, mesh=mesh, causal=True)
+        elif cfg.attention == "reference":
+            o = mha_reference(q, k, v, causal=True)
+        else:
+            o = _flash_on_mesh(q, k, v, cfg, mesh)
+    with jax.named_scope("attn_out"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
+        return jnp.einsum("bsd,de->bse", o, layer["attn"]["wo"].astype(dt))
 
 
 def _mlp_block(layer, x, cfg: GPTConfig):
@@ -222,11 +227,13 @@ def gpt_forward(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     """tokens: [B, S] int32 -> logits [B, S, vocab] (cfg.dtype)."""
     dt = cfg.dtype
     x, aux_total = gpt_backbone(params, tokens, cfg, mesh, act_sharding)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"]["table"].astype(dt))
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(dt))
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["embed"]["table"].astype(dt))
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x,
+                                params["lm_head"].astype(dt))
     return logits, aux_total
 
 
@@ -247,7 +254,8 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
             return x
         return jax.lax.with_sharding_constraint(x, act_sharding)
 
-    x = _c(params["embed"]["table"].astype(dt)[tokens])
+    with jax.named_scope("embed"):
+        x = _c(params["embed"]["table"].astype(dt)[tokens])
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     aux_total = 0.0
 
@@ -256,9 +264,11 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
             x, layer["ln1"]["scale"], cfg.rmsnorm_eps), cfg, positions, mesh))
         normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
         if cfg.n_experts > 0:
-            delta, aux = _moe_block(layer, normed, cfg)
+            with jax.named_scope("moe"):
+                delta, aux = _moe_block(layer, normed, cfg)
         else:
-            delta, aux = _mlp_block(layer, normed, cfg), 0.0
+            with jax.named_scope("mlp"):
+                delta, aux = _mlp_block(layer, normed, cfg), 0.0
         return _c(h + delta), aux
 
     policy = cfg.remat_policy or ("full" if cfg.remat else "none")
@@ -327,14 +337,15 @@ def gpt_loss(params, batch, cfg: GPTConfig, mesh=None, act_sharding=None):
     x, aux = gpt_backbone(params, inputs, cfg, mesh, act_sharding)
     b, s, d = x.shape
     dt = cfg.dtype
-    if cfg.tie_embeddings:
-        w_head = params["embed"]["table"].astype(dt).T
-    else:
-        w_head = params["lm_head"].astype(dt)
-    mask = (targets >= 0).astype(jnp.float32)
-    total, denom = chunked_xent(x.reshape(b * s, d), w_head,
-                                targets.reshape(b * s),
-                                mask.reshape(b * s))
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            w_head = params["embed"]["table"].astype(dt).T
+        else:
+            w_head = params["lm_head"].astype(dt)
+        mask = (targets >= 0).astype(jnp.float32)
+        total, denom = chunked_xent(x.reshape(b * s, d), w_head,
+                                    targets.reshape(b * s),
+                                    mask.reshape(b * s))
     loss = total / jnp.maximum(denom, 1.0)
     if cfg.n_experts > 0:
         loss = loss + 0.01 * aux / cfg.n_layers

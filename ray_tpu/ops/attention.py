@@ -135,6 +135,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
     return out.reshape(batch, heads, seq_q, d), lse
 
@@ -282,6 +283,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qr, kr, vr, gr, lse, delta)
 
     dkv_kernel = functools.partial(
@@ -307,6 +309,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
             jax.ShapeDtypeStruct((bh, seq_k, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lse, delta)
     return (dq.reshape(batch, heads, seq_q, d),
             dk.reshape(batch, heads, seq_k, d),

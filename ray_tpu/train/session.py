@@ -13,9 +13,11 @@ bookkeeping, replacing the reference's queue+next_results pairing
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import sys
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
@@ -76,6 +78,15 @@ def _host_value(value: Any) -> Any:
     return value
 
 
+class _StampedQueue(queue.Queue):
+    """Stamps a message `queued_at` at the instant it takes the slot, which
+    for a put() that had to wait is later than the call."""
+
+    def _put(self, item):
+        item["queued_at"] = time.time()
+        super()._put(item)
+
+
 class _Session:
     """Lives inside the train-worker actor; bridges the user's train fn
     (running on an executor thread) and the driver's polling."""
@@ -86,7 +97,11 @@ class _Session:
         self.context = context
         self.starting_checkpoint = checkpoint
         self.datasets = datasets or {}
-        self._results: "queue.Queue" = queue.Queue(maxsize=1)
+        self._results: "queue.Queue" = _StampedQueue(maxsize=1)
+        # Seconds the last report's put() waited for the queue's one slot
+        # (the driver had not taken the round before); rides the NEXT
+        # message, since it is known only once the put has returned.
+        self._blocked_s: Optional[float] = None
         self._stop = threading.Event()
         # Save-on-preempt: set by TrainWorker.request_save (driver push) or
         # implied by a drain notice for this worker's node; cleared when a
@@ -118,15 +133,26 @@ class _Session:
             raise _StopTraining()
         if checkpoint is not None:
             self._save_requested.clear()
-        self._results.put({"type": "report", "metrics": _host_value(metrics),
-                           "checkpoint": checkpoint,
-                           "rank": self.context.world_rank})
+        # Where jax is loaded the report is a span of the loop's thread in
+        # the profiler's own trace, beside the device's ops.
+        jax = sys.modules.get("jax")
+        with (jax.profiler.TraceAnnotation("train:report") if jax is not None
+              else contextlib.nullcontext()):
+            self._put({"type": "report", "metrics": _host_value(metrics),
+                       "checkpoint": checkpoint,
+                       "rank": self.context.world_rank})
         # Block until consumed: put the *next* item only after the driver
         # drains; queue(maxsize=1) already provides that.
 
     def finish(self, value: Any = None, error: Optional[str] = None):
-        self._results.put({"type": "error", "error": error}
-                          if error else {"type": "done", "value": value})
+        self._put({"type": "error", "error": error}
+                  if error else {"type": "done", "value": value})
+
+    def _put(self, message: dict) -> None:
+        message["blocked_s"] = self._blocked_s
+        t0 = time.perf_counter()
+        self._results.put(message)
+        self._blocked_s = time.perf_counter() - t0
 
     # -- called from the actor's RPC threads --
 

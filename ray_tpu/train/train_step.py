@@ -107,29 +107,33 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
         strategy = strategy_from_name(strategy)
 
     def _grads(params, batch):
-        return jax.value_and_grad(loss_fn)(params, batch)
+        with jax.named_scope("loss_and_grad"):
+            return jax.value_and_grad(loss_fn)(params, batch)
 
     def _step(state: TrainState, batch):
         if accum_steps:
             def micro(carry, mb):
                 loss_sum, gacc = carry
                 loss, g = _grads(state.params, mb)
-                gacc = jax.tree_util.tree_map(
-                    lambda a, gi: a + gi.astype(a.dtype), gacc, g)
+                with jax.named_scope("grad_accum"):
+                    gacc = jax.tree_util.tree_map(
+                        lambda a, gi: a + gi.astype(a.dtype), gacc, g)
                 return (loss_sum + loss.astype(jnp.float32), gacc), None
             gzero = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
             (loss_sum, gsum), _ = jax.lax.scan(
                 micro, (jnp.float32(0.0), gzero), batch)
             inv = 1.0 / accum_steps
-            grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
+            with jax.named_scope("grad_accum"):
+                grads = jax.tree_util.tree_map(lambda g: g * inv, gsum)
             loss = loss_sum * inv
         else:
             loss, grads = _grads(state.params, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            params = optax.apply_updates(state.params, updates)
+            gnorm = optax.global_norm(grads)
         return (TrainState(params, opt_state, state.step + 1),
                 {"loss": loss.astype(jnp.float32), "grad_norm": gnorm,
                  "step": state.step + 1})

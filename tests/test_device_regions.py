@@ -1,0 +1,261 @@
+"""The program names its device work: scopes on the model's regions and the
+train step, names on the three flash kernels (metadata only), and
+util/profiling.device_regions reduces a profiler trace plus the compiled
+step's HLO text to a table by region. All on the CPU: the v5e side is a
+recorded trace (benchmark/fixtures) and hand-made events."""
+
+import contextlib
+import json
+import os
+import re
+
+import pytest
+
+from ray_tpu.util import profiling
+from ray_tpu.util.profiling import KERNELS, REGIONS, UNATTRIBUTED
+
+FIXTURES = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "fixtures")
+DASH = "—"
+
+
+def _step_text(jax, **config):
+    """The compiled train step of a tiny GPT on one CPU device, as HLO.
+    With reference attention: the scopes are the same, and the interpreted
+    flash kernel would be most of the compile (its names: the jaxpr test)."""
+    import jax.numpy as jnp
+    import optax
+    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.train.train_step import init_train_state, make_train_step
+
+    accum = config.pop("accum_steps", 0)
+    cfg = GPTConfig(vocab_size=256, d_model=64, n_layers=2, n_heads=2,
+                    d_ff=128, max_seq=32, attention="reference",
+                    remat_policy="full", **config)
+    mesh = build_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    optimizer = optax.adamw(1e-3)
+    state = init_train_state(lambda: gpt_init(jax.random.PRNGKey(0), cfg),
+                             optimizer, mesh, "dp")
+    batch = {"tokens": jnp.zeros(((accum,) if accum else ()) + (4, 33),
+                                 jnp.int32)}
+    return make_train_step(
+        lambda p, b: gpt_loss(p, b, cfg, mesh=mesh), optimizer, mesh, "dp",
+        sample_params=state.params, accum_steps=accum
+    ).lower(state, batch).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def dense_text(jax_cpu):
+    return _step_text(jax_cpu)
+
+
+# (a) every region of the vocabulary is on some op of a compiled step
+
+@pytest.mark.parametrize("region", [r for r in REGIONS
+                                    if r not in ("moe", "grad_accum")])
+def test_dense_step_names_region(dense_text, region):
+    names = re.findall(r'op_name="([^"]*)"', dense_text)
+    assert any(profiling._last_of(n, REGIONS) == region for n in names)
+
+
+@pytest.fixture(scope="module")
+def moe_accumulating_text(jax_cpu):
+    return _step_text(jax_cpu, n_experts=2, accum_steps=2)
+
+
+@pytest.mark.parametrize("region", ["moe", "grad_accum"])
+def test_moe_accumulating_step_names_region(moe_accumulating_text, region):
+    names = re.findall(r'op_name="([^"]*)"', moe_accumulating_text)
+    assert any(profiling._last_of(n, REGIONS) == region for n in names)
+
+
+@pytest.mark.parametrize("phase", ["forward", "backward", "recompute"])
+def test_dense_step_names_phase(dense_text, phase):
+    """Direction and recomputation are readable from the same op_name:
+    jvp( forward, transpose( backward, rematted_computation under
+    remat_policy="full"."""
+    names = re.findall(r'op_name="([^"]*)"', dense_text)
+    mlp = {profiling._phase(n) for n in names
+           if profiling._last_of(n, REGIONS) == "mlp"}
+    assert phase in mlp
+
+
+@pytest.mark.parametrize("kernel", KERNELS)
+def test_flash_kernels_carry_their_names(jax_cpu, kernel):
+    import jax.numpy as jnp
+    from ray_tpu.ops.attention import flash_attention
+    q = jnp.zeros((1, 2, 32, 16), jnp.bfloat16)
+    jaxpr = str(jax_cpu.make_jaxpr(jax_cpu.grad(
+        lambda q: flash_attention(q, q, q).astype(jnp.float32).sum()))(q))
+    assert f"name={kernel}" in jaxpr
+
+
+# (b) names cost nothing: the step with the scopes is the step without
+
+def _without_metadata(text):
+    """The module's instructions without every metadata={..} and without
+    the tables of file names and stack frames that metadata indexes."""
+    text = re.sub(r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                  r"(?:.+\n)*", "", text, flags=re.M)
+    return re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+
+
+def test_scopes_leave_the_compiled_step_unchanged(jax_cpu, dense_text,
+                                                  monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax_cpu, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    # the persistent cache's key leaves metadata out: with it on, this
+    # compile would be handed the executable of the one with the scopes
+    was_on = jax_cpu.config.jax_enable_compilation_cache
+    jax_cpu.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        bare = _step_text(jax_cpu)
+    finally:
+        jax_cpu.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    assert "loss_and_grad" in dense_text and "loss_and_grad" not in bare
+    assert _without_metadata(dense_text) == _without_metadata(bare)
+
+
+# (c) the reduction, on hand-made events and on the recorded v5e trace
+
+MS = 1_000_000   # ns
+HLO = """HloModule jit__step, is_scheduled=true
+
+%body (p: f32[8]) -> f32[8] {
+  %dot.1 = f32[8]{0} dot(%p, %p), metadata={op_name="jit(_step)/loss_and_grad/jvp(head)/while/body/dot_general"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %fusion.1 = f32[8]{0} fusion(%a), kind=kLoop, metadata={op_name="jit(_step)/loss_and_grad/jvp(mlp)/mul" stack_frame_id=3}
+  %flash_fwd.2 = f32[8]{0} custom-call(%fusion.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(_step)/loss_and_grad/transpose(jvp(loss_and_grad))/jvp()/checkpoint/rematted_computation/attn_core/flash_fwd/pallas_call"}
+  %flash_bwd_dq.3 = f32[8]{0} custom-call(%fusion.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(_step)/loss_and_grad/transpose(jvp(loss_and_grad))/jvp()/checkpoint/attn_core/flash_bwd_dq/pallas_call"}
+  %while.4 = f32[8]{0} while(%a), body=%body, metadata={op_name="jit(_step)/loss_and_grad/jvp(head)/while"}
+  %all-reduce.5 = f32[8]{0} all-reduce(%a), metadata={op_name="jit(_step)/loss_and_grad/transpose(jvp(mlp))/dot_general"}
+  %copy.6 = f32[8]{0} copy(%a)
+  %copy.8 = f32[8]{0} copy(%flash_fwd.2)
+  %fusion.9 = (f32[8]{0}, f32[8]{0}) fusion(%a), kind=kLoop, calls=%body
+  ROOT %fusion.7 = f32[8]{0} fusion(%a), kind=kLoop, metadata={op_name="jit(_step)/optimizer/add"}
+}
+"""
+
+
+def _event(name, opcode, start_ms, end_ms):
+    return (f"%{name} = f32[8]{{0:T(8)}} {opcode}(%a)", start_ms * MS,
+            end_ms * MS)
+
+
+# one chip, a window of 100 ms: ops back to back from 0 to 60 (the while
+# spans its body's dot), a gap of 10 ms, two ops, a gap of 2 ms, one op, a
+# gap of 8 ms, and a kernel that runs on past the window's end
+HAND_MADE = {
+    "devices": {0: [
+        _event("fusion.1", "fusion", 0, 10),
+        _event("flash_fwd.2", "custom-call", 10, 20),
+        _event("flash_bwd_dq.3", "custom-call", 20, 40),
+        _event("while.4", "while", 40, 60), _event("dot.1", "dot", 45, 55),
+        _event("all-reduce.5", "all-reduce", 70, 75),
+        _event("copy.6", "copy", 75, 80),
+        _event("fusion.7", "fusion", 82, 87),
+        _event("flash_fwd.2", "custom-call", 95, 120)]},
+    "host": [(profiling.STRETCH_SPAN, 0, 100 * MS),
+             ("host:dispatch", 0, 58 * MS),
+             ("train:report", 58 * MS, 72 * MS),
+             ("host:wait_step", 72 * MS, 100 * MS)]}
+
+EXPECTED = {
+    "window_s": 0.1, "busy_s": 0.08, "busy_pct": 80.0,
+    # the last region on the path wins (attn_core under loss_and_grad);
+    # the while's own time is 20 - 10 of its body's dot, both `head`
+    "rows": [["attn_core", "backward", 0.02, 20.0, 1],
+             ["head", "forward", 0.02, 20.0, 2],
+             ["attn_core", "recompute", 0.015, 15.0, 2],
+             ["mlp", "forward", 0.01, 10.0, 1],
+             ["mlp", "backward", 0.005, 5.0, 1],
+             [UNATTRIBUTED, DASH, 0.005, 5.0, 1],
+             ["optimizer", DASH, 0.005, 5.0, 1]],
+    # the second flash_fwd is clipped at the window's end: 10 + 5 ms
+    "kernels": [["flash_bwd_dq", "backward", 0.02, 1, 0.02],
+                ["flash_fwd", "recompute", 0.015, 2, 0.0075]],
+    "collectives": [["mlp", 0.005]],
+    # 60-70 lies mostly under train:report; 80-82 is too short to name
+    # across the two clocks; 87-95 under host:wait_step
+    "idle_gaps": [["train:report", 0.01], ["host:wait_step", 0.008],
+                  ["short gaps", 0.002]]}
+
+
+def _assert_same(got, want):
+    if isinstance(want, list):
+        assert len(got) == len(want), (got, want)
+        for g, w in zip(got, want):
+            _assert_same(g, w)
+    elif isinstance(want, float):
+        assert got == pytest.approx(want), (got, want)
+    else:
+        assert got == want
+
+
+@pytest.mark.parametrize("part", sorted(EXPECTED))
+def test_hand_made_trace_gives_exact_table(part):
+    table = profiling.device_regions(HAND_MADE, HLO)["median"]
+    _assert_same(table[part], EXPECTED[part])
+
+
+def test_instruction_without_metadata_inherits_a_region_not_a_kernel():
+    """What the compiler left without metadata takes the op_name of the
+    computation it calls, else of its first operand that has one: its time
+    goes to that region, but it is not a call of the kernel it copies."""
+    named, inherits = profiling._instruction_op_names(HLO)
+    assert inherits == {"copy.8": named["flash_fwd.2"],
+                        "fusion.9": named["dot.1"]}
+    assert "copy.6" not in named
+    trace = dict(HAND_MADE, devices={0: [
+        _event("flash_fwd.2", "custom-call", 10, 20),
+        _event("copy.8", "copy", 20, 30), _event("fusion.9", "fusion", 30, 35)]})
+    table = profiling.device_regions(trace, HLO)["median"]
+    _assert_same(table["rows"], [["attn_core", "recompute", 0.02, 20.0, 2],
+                                 ["head", "forward", 0.005, 5.0, 1]])
+    _assert_same(table["kernels"],
+                 [["flash_fwd", "recompute", 0.01, 1, 0.01]])
+
+
+def test_median_over_chips_and_per_chip():
+    """A second chip that idles twice as long in the collective: the
+    median of two chips is their mean, and each chip keeps its table."""
+    second = [(n, a, b + 5 * MS) if "all-reduce" in n else (n, a, b)
+              for n, a, b in HAND_MADE["devices"][0]]
+    out = profiling.device_regions(
+        dict(HAND_MADE, devices={0: HAND_MADE["devices"][0], 1: second}), HLO)
+    assert sorted(out["per_chip"]) == ["0", "1"]
+    assert out["per_chip"]["1"]["collectives"] == [
+        ["mlp", pytest.approx(0.01)]]
+    assert out["median"]["collectives"] == [["mlp", pytest.approx(0.0075)]]
+    assert "1.3 ms" in out["clock_skew_note"]
+
+
+def test_v5e_fixture_without_scopes_is_all_unattributed():
+    """benchmark/fixtures/trace.xplane.pb was recorded from a program with
+    no scopes: all of its busy time (fixtures/expected.json, worked out by
+    hand) lands under `unattributed`, and its long gaps under the host
+    spans the benchmark's own reduction names."""
+    with open(os.path.join(FIXTURES, "expected.json")) as f:
+        expected = json.load(f)
+    out = profiling.device_regions(
+        os.path.join(FIXTURES, "trace.xplane.pb"),
+        "HloModule jit_chain\n\nENTRY %main () -> f32[] {\n}\n")
+    table = out["median"]
+    assert list(out["per_chip"]) == ["0"]
+    assert table["busy_s"] == pytest.approx(expected["busy_s"], rel=1e-9)
+    assert [r[:2] for r in table["rows"]] == [[UNATTRIBUTED, DASH]]
+    assert table["rows"][0][2] == pytest.approx(expected["busy_s"], rel=1e-9)
+    assert table["rows"][0][4] == 18
+    assert table["kernels"] == [] and table["collectives"] == []
+    assert table["idle_gaps"][0][0] == "host:batch_wait"
+
+
+def test_trace_without_a_device_plane_is_refused():
+    with pytest.raises(ValueError, match="no TPU plane"):
+        profiling.device_regions({"devices": {}, "host": []}, HLO)

@@ -96,7 +96,11 @@ def test_query_rpc_returns_aligned_counter_and_p99(ray_mod):
         counter_pts = max((s["points"] for s in counter), key=len,
                           default=[])
         hist_pts = max((s["points"] for s in hist), key=len, default=[])
-        if len(counter_pts) >= 2 and len(hist_pts) >= 2:
+        # The counter's first slots are its zero baseline, carried forward
+        # until the reporter's next frame lands: wait for the state the
+        # assertions below are about, not for two slots of any value.
+        if (len(counter_pts) >= 2 and len(hist_pts) >= 2
+                and counter_pts[-1][1] > 0):
             break
         time.sleep(0.3)
 
