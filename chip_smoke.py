@@ -57,15 +57,20 @@ class Size:
     model: str              # a GPTConfig preset name
     batch: int
     seq: int
-    kernel_shape: tuple     # [batch, heads, seq, head_dim] of the kernel check
+    kernel_shapes: tuple    # [batch, heads, seq, head_dim] of each kernel check
     prompt_len: int         # serve pads every prompt to this
     platform: str           # what the workers' jax.devices() must report
 
 
 # Batch 64 x seq 1024 is what the step was compiled for ahead of the chip
 # run (6.2 GB temporaries + 2.1 GB arguments of 16 GB); not laddered.
+# The kernel is checked at a small shape and at what one chip sees of the
+# benchmark's two train cells: its tiles follow from the shape, so a shape
+# it mis-tiles fails here before a benchmark run.
 REAL = Size(model="gpt2_small", batch=64, seq=1024,
-            kernel_shape=(8, 12, 1024, 64), prompt_len=128, platform="tpu")
+            kernel_shapes=((8, 12, 1024, 64), (64, 12, 1024, 64),
+                           (16, 16, 2048, 64)),
+            prompt_len=128, platform="tpu")
 
 
 class SmokeFailure(Exception):
@@ -110,37 +115,44 @@ def open_device(platform: str) -> dict:
     return facts
 
 
-def kernel_check(platform: str, shape: tuple, seed: int) -> dict:
-    """Body of the plain-task phase: the flash kernel's forward and
-    backward against mha_reference on bf16 inputs. Both are measured
-    against the same reference run in fp32 at full matmul precision, so the
-    tolerance is the plain bf16 path's own error: the kernel may be at most
-    twice as far from the truth."""
+def kernel_check(platform: str, shapes: tuple, seed: int) -> dict:
+    """Body of the plain-task phase: at each shape, the flash kernel's
+    forward and backward against mha_reference on bf16 inputs. Both are
+    measured against the same reference run in fp32 at full matmul
+    precision, so the tolerance is the plain bf16 path's own error: the
+    kernel may be at most twice as far from the truth. The kernel runs on
+    the whole shape; the references, which hold the score matrix, on slices
+    of the batch."""
     import jax
     import jax.numpy as jnp
     from ray_tpu.ops.attention import flash_attention, mha_reference
 
     facts = open_device(platform)
-    kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(seed), 4)
-    q, k, v, g = (jax.random.normal(key, shape, jnp.float32)
-                  for key in (kq, kk, kv, kg))
 
-    def outputs(attn, dtype):
+    def outputs(attn, dtype, arrays, rows):
+        @jax.jit
         def run(q, k, v, g):
             out, vjp = jax.vjp(lambda *a: attn(*a, causal=True), q, k, v)
             return (out,) + vjp(g)
-        arrays = jax.jit(run)(*(x.astype(dtype) for x in (q, k, v, g)))
-        return [a.astype(jnp.float32) for a in arrays]
+        parts = [run(*(x[i:i + rows].astype(dtype) for x in arrays))
+                 for i in range(0, arrays[0].shape[0], rows)]
+        return [jnp.concatenate(p).astype(jnp.float32) for p in zip(*parts)]
 
-    with jax.default_matmul_precision("highest"):
-        truth = outputs(mha_reference, jnp.float32)
-    plain = outputs(mha_reference, jnp.bfloat16)
-    flash = outputs(flash_attention, jnp.bfloat16)
-    facts["errors"] = {
-        name: {"flash": float(jnp.abs(f - t).max()),
-               "plain": float(jnp.abs(p - t).max())}
-        for name, t, p, f in zip(("out", "dq", "dk", "dv"), truth, plain,
-                                 flash)}
+    facts["errors"] = {}
+    for shape in shapes:
+        batch, heads, seq, _ = shape
+        arrays = [jax.random.normal(key, shape, jnp.float32)
+                  for key in jax.random.split(jax.random.PRNGKey(seed), 4)]
+        rows = max(1, (1 << 27) // (heads * seq * seq))   # 0.5 GB of scores
+        with jax.default_matmul_precision("highest"):
+            truth = outputs(mha_reference, jnp.float32, arrays, rows)
+        plain = outputs(mha_reference, jnp.bfloat16, arrays, rows)
+        flash = outputs(flash_attention, jnp.bfloat16, arrays, batch)
+        facts["errors"]["x".join(map(str, shape))] = {
+            name: {"flash": float(jnp.abs(f - t).max()),
+                   "plain": float(jnp.abs(p - t).max())}
+            for name, t, p, f in zip(("out", "dq", "dk", "dv"), truth, plain,
+                                     flash)}
     return facts
 
 
@@ -328,12 +340,13 @@ def task_phase(size: Size, seed: int) -> dict:
     import ray_tpu
     facts = ray_tpu.get(
         ray_tpu.remote(num_tpus=1)(kernel_check).remote(
-            size.platform, size.kernel_shape, seed), timeout=600)
-    log("task", kernel_shape=size.kernel_shape, **facts)
-    for name, err in facts["errors"].items():
-        require(err["flash"] <= 2 * err["plain"] + 1e-6,
-                f"flash kernel {name}: error {err['flash']} against fp32, "
-                f"plain bf16 attention has {err['plain']}")
+            size.platform, size.kernel_shapes, seed), timeout=600)
+    log("task", **facts)
+    for shape, errors in facts["errors"].items():
+        for name, err in errors.items():
+            require(err["flash"] <= 2 * err["plain"] + 1e-6,
+                    f"flash kernel {name} at {shape}: error {err['flash']} "
+                    f"against fp32, plain bf16 attention has {err['plain']}")
     return facts
 
 

@@ -49,9 +49,6 @@ class GPTConfig:
     #   "none": save everything (max HBM, min FLOPs)
     remat_policy: Optional[str] = None
     attention: str = "flash"          # flash | reference | ring
-    # Flash kernel tile sizes (perf knob; correctness-invariant).
-    flash_block_q: int = 128
-    flash_block_k: int = 128
     tie_embeddings: bool = False
 
     @property
@@ -149,8 +146,7 @@ def _flash_on_mesh(q, k, v, cfg: GPTConfig, mesh):
     device it runs per shard: batch over 'data' x 'fsdp', heads over
     'tensor', each device attending its own (batch, head) slice with no
     collective."""
-    flash = partial(flash_attention, causal=True, block_q=cfg.flash_block_q,
-                    block_k=cfg.flash_block_k)
+    flash = partial(flash_attention, causal=True)
     if mesh is None or mesh.size == 1:
         return flash(q, k, v)
     spec = P(("data", "fsdp"), "tensor", None, None)

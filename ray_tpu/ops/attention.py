@@ -12,6 +12,7 @@ Layouts: q, k, v are [batch, num_heads, seq, head_dim].
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 from typing import Optional
@@ -20,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -45,213 +47,351 @@ def mha_reference(q, k, v, *, causal: bool = True,
 # ---------------------------------------------------------------------------
 # Pallas flash attention (single device)
 # ---------------------------------------------------------------------------
+#
+# Three kernels, FlashAttention-2: flash_fwd, flash_bwd_dq, flash_bwd_dkv.
+# Each runs on a grid (batch*head, outer block, major block). The last grid
+# dimension is the reduction — k blocks for the forward and dQ, q blocks for
+# dK/dV — with the accumulators in VMEM scratch, zeroed at its first step and
+# written out at its last, so VMEM holds blocks and never a whole sequence,
+# and the next major block is fetched while this one is computed. A grid
+# step costs about as much as a 128 x 128 tile's work, so blocks are large,
+# and inside a step a statically unrolled loop walks the outer block in row
+# groups, one score tile [group, major block] each: wide tiles, because the
+# per-row statistics cost a pass per 128 columns whatever the width.
+#
+# Under a causal mask a step whose major block lies past the diagonal is
+# skipped (and its fetch elided: the index map repeats the last block
+# needed), and one wholly under the diagonal runs as straight-line code with
+# no mask. On the diagonal, square blocks (outer == major, the rule's
+# choice) make the geometry static: row group i meets only the columns up
+# to its own, and only the group x group corner the diagonal crosses is
+# masked. Blocks that are not square (a test's explicit block_q != block_k)
+# take the general path: every tile whole, masked by position, a group the
+# mask leaves nothing of skipped.
+#
+# Dots take their operands in the INPUT dtype (bf16 on the model path) and
+# accumulate in fp32: an fp32 x fp32 MXU matmul is several times slower on
+# v5e. Softmax statistics, exp, lse and delta are fp32. The forward's
+# running max and sum are kept lane-replicated ([rows, 128], all lanes of a
+# row equal); dK/dV works on the transposed tile ([keys, queries]), where
+# lse and delta are rows as they lie in memory and no matmul needs a
+# transposed left operand.
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
-                  block_k, seq_k, causal_offset):
-    """One (batch*head, q_block) program: loop K blocks w/ online softmax.
+LANES = 128
 
-    causal_offset = seq_k - seq_q: masking is bottom-right aligned, matching
-    mha_reference (query i attends keys <= i + offset). Also emits the
-    per-row logsumexp (lse) residual consumed by the backward kernels.
+# (outer, major, group) per kernel: the block of the sequence that is not
+# reduced over (q for the forward and dQ, k for dK/dV), the block of the
+# reduced sequence a grid step holds, and the rows of the outer block one
+# score tile covers.
+_FlashBlocks = collections.namedtuple("_FlashBlocks", "fwd dq dkv")
+
+
+def _divisor(n: int, cap: int) -> int:
+    """The largest multiple of 128 that divides n and is <= cap; n itself
+    below 128 (one block)."""
+    if n < LANES:
+        return n
+    return max(b for b in range(LANES, min(cap, n) + 1, LANES) if n % b == 0)
+
+
+def _block_sizes(seq_q: int, seq_k: int, head_dim: int) -> _FlashBlocks:
+    """Blocks of the three kernels, from the shape alone (PERF.md has the
+    sweep on a v5e behind the numbers)."""
+    for seq in (seq_q, seq_k):
+        if seq > LANES and seq % LANES:
+            raise ValueError(
+                f"flash_attention needs sequence lengths up to {LANES} or "
+                f"multiples of {LANES}, got {seq}; pad the sequence or call "
+                "mha_reference")
+    if seq_q != seq_k and min(seq_q, seq_k) < LANES:
+        # no square block fits both: one block each
+        return _FlashBlocks(fwd=(seq_q, seq_k, seq_q), dq=(seq_q, seq_k, seq_q),
+                            dkv=(seq_k, seq_q, seq_k))
+    # Square blocks as large as the sequence, up to 2048 (1024 for heads
+    # wider than 128: six blocks x head_dim, two buffers each, share 16 MB
+    # of VMEM with the score tiles): a whole row of the score matrix in one
+    # grid step where it fits. Row groups of 256 for the forward, whose
+    # per-row statistics want a wide tile, and of 128 for the backward
+    # kernels, which have none and two score-sized products a tile.
+    square = _divisor(math.gcd(seq_q, seq_k),
+                      2048 if head_dim <= LANES else 1024)
+    return _FlashBlocks(fwd=(square, square, _divisor(square, 256)),
+                        dq=(square, square, _divisor(square, LANES)),
+                        dkv=(square, square, _divisor(square, LANES)))
+
+
+def _lanes(x, n: int):
+    """A lane-replicated [rows, 128] statistic as [rows, n]."""
+    if n <= LANES:
+        return x[:, :n]
+    if n % LANES == 0:
+        return jnp.tile(x, (1, n // LANES))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _for_tiles(causal, upper, delta, outer, major, group, tile):
+    """Run tile(rows, cols, mask) for every score tile of this grid step
+    that the causal mask leaves something of: rows a slice of the outer
+    block (one group), cols a slice of the major block.
+
+    Row a of the outer block and column b of the major block stand in the
+    score matrix `delta` apart: with keys as columns (`upper` false: the
+    forward, dQ) the pair is kept iff b <= a + delta, with queries as
+    columns (`upper`: dK/dV) iff b >= a + delta. mask is None (keep all),
+    "corner" (the tile's last — `upper`: first — group columns are the
+    square the diagonal crosses, delta 0 there) or the tile's own delta.
     """
-    # Dots run in the INPUT dtype (bf16 on the model path) with fp32
-    # accumulation: an fp32 x fp32 MXU matmul is several times slower
-    # than bf16 x bf16 -> fp32 on v5e, and upcasting q/k/v before the
-    # dot was this kernel's original whole-step slowdown. Softmax math
-    # stays fp32.
-    q = q_ref[0]                                         # [bq, d] (in dt)
-    bq = q.shape[0]
-    d = q.shape[1]
-    q_idx = pl.program_id(1)
-    q_start = q_idx * bq
+    n = outer // group
+    groups = [slice(i * group, (i + 1) * group) for i in range(n)]
+    whole = slice(0, major)
 
-    num_k_blocks = pl.cdiv(seq_k, block_k)
+    def unmasked():
+        for rows in groups:
+            tile(rows, whole, None)
 
-    def body(i, carry):
-        acc, m_prev, l_prev = carry
-        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk] f32
-        if causal:
-            q_pos = q_start + causal_offset + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            k_pos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return acc, m_new, l_new
+    if not causal:
+        unmasked()
+    elif outer == major:
+        # delta is a multiple of the block (flash_attention checks): the
+        # step is under the diagonal, on it, or past it
+        pl.when(delta <= -outer if upper else delta >= outer)(unmasked)
 
-    init = (jnp.zeros((bq, d), jnp.float32),
-            jnp.full((bq,), NEG_INF, jnp.float32),
-            jnp.zeros((bq,), jnp.float32))
-    if causal:
-        # Skip fully-masked K blocks past the (offset) diagonal.
-        num_blocks = jnp.minimum(
-            num_k_blocks,
-            pl.cdiv((q_idx + 1) * bq + causal_offset, block_k)).astype(jnp.int32)
+        @pl.when(delta == 0)
+        def _on_the_diagonal():
+            for rows in groups:
+                cols = (slice(rows.start, major) if upper
+                        else slice(0, rows.stop))
+                tile(rows, cols, "corner")
     else:
-        num_blocks = num_k_blocks
-    acc, m, l = jax.lax.fori_loop(0, num_blocks, body, init)
-    l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l[:, None]).astype(o_ref.dtype)
-    lse_ref[0, 0] = m + jnp.log(l)
+        for rows in groups:
+            d = delta + rows.start
+            if upper:
+                full, some = d + group - 1 <= 0, d <= major - 1
+            else:
+                full, some = d >= major - 1, d + group - 1 >= 0
+            pl.when(full)(functools.partial(tile, rows, whole, None))
+            pl.when(some & jnp.logical_not(full))(
+                functools.partial(tile, rows, whole, d))
 
 
-def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _mask(s, mask, upper):
+    """(s with NEG_INF where the causal mask says so, what was kept or None
+    where no row can have lost every column)."""
+    def kept(shape, delta):
+        diff = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+        return diff >= delta if upper else diff <= delta
+
+    if mask is None:
+        return s, None
+    if isinstance(mask, str):
+        group, width = s.shape
+        corner = s[:, :group] if upper else s[:, width - group:]
+        corner = jnp.where(kept(corner.shape, 0), corner, NEG_INF)
+        if width == group:
+            return corner, None
+        return jnp.concatenate([corner, s[:, group:]] if upper
+                               else [s[:, :width - group], corner],
+                               axis=1), None
+    keep = kept(s.shape, mask)
+    return jnp.where(keep, s, NEG_INF), keep
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                      acc_ref, m_ref, l_ref, *, sm_scale, causal, group,
+                      offset):
+    """Grid (batch*head, q block, k major block): online softmax over the k
+    blocks of one q block.
+
+    offset = seq_k - seq_q: masking is bottom-right aligned, matching
+    mha_reference (query i attends keys <= i + offset). Also emits the
+    per-row logsumexp (lse) the backward kernels consume; a row with no key
+    to attend gives zeros and lse = NEG_INF.
+    """
+    bq, d = q_ref.shape[1:]
+    bk = k_ref.shape[1]
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def tile(rows, cols, mask):
+        # scaling q ([group, d]) is the scores' scaling ([group, width])
+        # done on the small side
+        q = q_ref[0, rows, :] * sm_scale
+        k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+        s, keep = _mask(_dot(q, k, (1, 1)), mask, False)  # [group, width]
+        m_prev, l_prev = m_ref[rows, :], l_ref[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+        if keep is not None and offset < 0:
+            # a row with no key at all (seq_q > seq_k) has m = NEG_INF and
+            # exp(NEG_INF - NEG_INF) = 1: its p is 0 by decree
+            p = jnp.where(keep, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[rows, :] = m_new
+        l_ref[rows, :] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[rows, :] = (acc_ref[rows, :] * _lanes(alpha, d)
+                            + _dot(p.astype(v.dtype), v, (1, 0)))
+
+    _for_tiles(causal, False, qi * bq + offset - ki * bk, bq, bk, group, tile)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finish():
+        l = l_ref[...]
+        l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[...] / _lanes(l, d)).astype(o_ref.dtype)
+        lse = m_ref[...] + jnp.log(l)                     # [bq, 128]
+        if bq % LANES == 0:
+            # all lanes of a row are equal, so a row of the transpose is
+            # the column as lse lies in memory; turning a [bq] reduction
+            # result into that row cost a fifth of the kernel
+            lse_ref[0] = lse.T[:1, :]
+        else:
+            lse_ref[0, 0] = jnp.max(lse, axis=1)
+
+
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                         dq_ref, acc_ref, *, sm_scale, causal, group,
+                         offset):
+    """Grid (batch*head, q block, k major block): dQ of one q block.
+
+    p = exp(s - lse); dS = p * (dO·Vᵀ - delta); dQ = scale · dS·K
+    (FlashAttention-2 backward).
+    """
+    bq = q_ref.shape[1]
+    bk = k_ref.shape[1]
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(rows, cols, mask):
+        q, do = q_ref[0, rows, :] * sm_scale, do_ref[0, rows, :]
+        k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+        # lse and delta lie in memory as rows; here they are columns
+        lse, delta = lse_ref[0, 0, rows][:, None], delta_ref[0, 0, rows][:, None]
+        s, keep = _mask(_dot(q, k, (1, 1)), mask, False)  # [group, width]
+        p = jnp.exp(s - lse)
+        if keep is not None and offset < 0:
+            p = jnp.where(keep, p, 0.0)     # lse = NEG_INF: see the forward
+        dp = _dot(do, v, (1, 1))                          # [group, width]
+        ds = p * (dp - delta)
+        acc_ref[rows, :] += _dot(ds.astype(k.dtype), k, (1, 0))
+
+    _for_tiles(causal, False, qi * bq + offset - ki * bk, bq, bk, group, tile)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finish():
+        dq_ref[0] = (acc_ref[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, sm_scale,
+                          causal, group, offset):
+    """Grid (batch*head, k block, q major block): dK and dV of one k block,
+    on transposed tiles [keys, queries].
+
+    dV = Pᵀ·dO; dK = scale · dSᵀ·Q.
+    """
+    bk = k_ref.shape[1]
+    bq = q_ref.shape[1]
+    ki, qi = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
+        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
+
+    def tile(rows, cols, mask):
+        k, v = k_ref[0, rows, :] * sm_scale, v_ref[0, rows, :]
+        q, do = q_ref[0, cols, :], do_ref[0, cols, :]
+        lse, delta = lse_ref[0, :, cols], delta_ref[0, :, cols]  # [1, width]
+        st, keep = _mask(_dot(k, q, (1, 1)), mask, True)  # [group, width]
+        pt = jnp.exp(st - lse)
+        if keep is not None and offset < 0:
+            pt = jnp.where(keep, pt, 0.0)   # lse = NEG_INF: see the forward
+        dv_acc_ref[rows, :] += _dot(pt.astype(do.dtype), do, (1, 0))
+        dpt = _dot(v, do, (1, 1))                         # [group, width]
+        dst = pt * (dpt - delta)
+        dk_acc_ref[rows, :] += _dot(dst.astype(q.dtype), q, (1, 0))
+
+    _for_tiles(causal, True, ki * bk - (qi * bq + offset), bk, bq, group, tile)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _finish():
+        dk_ref[0] = (dk_acc_ref[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
+
+
+_GRID_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _kv_index(causal, offset, bq, bkm, num_k):
+    """Index map of a K/V major block on a (b, q block, k block) grid. Past
+    the diagonal it repeats the last block the q block needs: Pallas does
+    not fetch a block whose index did not change."""
+    if not causal:
+        return lambda b, i, j: (b, j, 0)
+
+    def index(b, i, j):
+        last = jnp.maximum((i + 1) * bq - 1 + offset, 0) // bkm
+        return (b, jnp.minimum(j, jnp.minimum(last, num_k - 1)), 0)
+    return index
+
+
+def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret):
     batch, heads, seq_q, d = q.shape
     seq_k = k.shape[2]
     bh = batch * heads
-    qr = q.reshape(bh, seq_q, d)
-    kr = k.reshape(bh, seq_k, d)
-    vr = v.reshape(bh, seq_k, d)
-    kernel = functools.partial(_flash_kernel, sm_scale=sm_scale,
-                               causal=causal, block_k=block_k, seq_k=seq_k,
-                               causal_offset=seq_k - seq_q)
+    bq, bkm, group = blocks.fwd
+    offset = seq_k - seq_q
+    kernel = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
+                               causal=causal, group=group, offset=offset)
+    kv_spec = pl.BlockSpec((1, bkm, d),
+                           _kv_index(causal, offset, bq, bkm, seq_k // bkm))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, seq_q // block_q),
+        grid=(bh, seq_q // bq, seq_k // bkm),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq_k, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             # lse rides as [bh, 1, seq_q]: TPU Pallas needs the last two
             # block dims divisible by (8, 128) or equal to the array dims.
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+        ],
+        compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
         name="flash_fwd",
-    )(qr, kr, vr)
+    )(q.reshape(bh, seq_q, d), k.reshape(bh, seq_k, d),
+      v.reshape(bh, seq_k, d))
     return out.reshape(batch, heads, seq_q, d), lse
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, sm_scale, causal, block_k, seq_k,
-                         causal_offset):
-    """dQ for one (batch*head, q_block): loop K blocks.
-
-    p = exp(s - lse); dS = p * (dO·Vᵀ - delta); dQ = scale · dS·K
-    (standard flash-attention backward, FlashAttention-2 form).
-    """
-    # bf16 dot inputs + fp32 accumulation (see _flash_kernel dtype note).
-    q = q_ref[0]                                          # [bq, d]
-    do = do_ref[0]                                        # [bq, d]
-    lse = lse_ref[0, 0]                                   # [bq]
-    delta = delta_ref[0, 0]                               # [bq]
-    bq, d = q.shape
-    q_idx = pl.program_id(1)
-    q_start = q_idx * bq
-    num_k_blocks = pl.cdiv(seq_k, block_k)
-
-    def body(i, dq):
-        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = q_start + causal_offset + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            k_pos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        # Explicit zero for masked entries: a fully-masked row has
-        # lse = NEG_INF, and exp(NEG_INF - NEG_INF) would be 1, not 0.
-        p = jnp.where(s > NEG_INF / 2,
-                      jnp.exp(s - lse[:, None]), 0.0)     # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, bk]
-        ds = p * (dp - delta[:, None])
-        return dq + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        num_blocks = jnp.minimum(
-            num_k_blocks,
-            pl.cdiv((q_idx + 1) * bq + causal_offset, block_k)).astype(jnp.int32)
-    else:
-        num_blocks = num_k_blocks
-    dq = jax.lax.fori_loop(0, num_blocks, body,
-                           jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, sm_scale, causal, block_q,
-                          seq_q, causal_offset):
-    """dK/dV for one (batch*head, k_block): loop Q blocks.
-
-    dV = Pᵀ·dO; dK = scale · dSᵀ·Q. Causal skip: k block starting at ks
-    only sees q rows with q_pos >= k_pos, i.e. q >= ks - causal_offset.
-    """
-    # bf16 dot inputs + fp32 accumulation (see _flash_kernel dtype note).
-    k_blk = k_ref[0]                                      # [bk, d]
-    v_blk = v_ref[0]                                      # [bk, d]
-    bk, d = k_blk.shape
-    k_idx = pl.program_id(1)
-    k_start = k_idx * bk
-    num_q_blocks = pl.cdiv(seq_q, block_q)
-
-    def body(j, carry):
-        dk, dv = carry
-        q_blk = q_ref[0, pl.ds(j * block_q, block_q), :]
-        do_blk = do_ref[0, pl.ds(j * block_q, block_q), :]
-        lse_blk = lse_ref[0, 0, pl.ds(j * block_q, block_q)]
-        delta_blk = delta_ref[0, 0, pl.ds(j * block_q, block_q)]
-        s = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
-        if causal:
-            q_pos = j * block_q + causal_offset + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        # See dq kernel: masked rows have lse = NEG_INF; force p to 0.
-        p = jnp.where(s > NEG_INF / 2,
-                      jnp.exp(s - lse_blk[:, None]), 0.0)  # [bq, bk]
-        dv = dv + jax.lax.dot_general(
-            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bk, d]
-        dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bq, bk]
-        ds = p * (dp - delta_blk[:, None])
-        dk = dk + jax.lax.dot_general(
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [bk, d]
-        return dk, dv
-
-    if causal:
-        start = jnp.maximum(
-            0, (k_start - causal_offset) // block_q).astype(jnp.int32)
-    else:
-        start = 0
-    dk, dv = jax.lax.fori_loop(
-        start, num_q_blocks, body,
-        (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, d), jnp.float32)))
-    dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-
-
-def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
+def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
                     interpret):
     batch, heads, seq_q, d = q.shape
     seq_k = k.shape[2]
@@ -266,48 +406,51 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
                     axis=-1).reshape(bh, 1, seq_q)
     offset = seq_k - seq_q
 
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-        block_k=block_k, seq_k=seq_k, causal_offset=offset)
+    bq, bkm, group = blocks.dq
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, bkm, d),
+                           _kv_index(causal, offset, bq, bkm, seq_k // bkm))
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, seq_q // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),   # q
-            pl.BlockSpec((1, seq_k, d), lambda b, i: (b, 0, 0)),     # k
-            pl.BlockSpec((1, seq_k, d), lambda b, i: (b, 0, 0)),     # v
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),   # do
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),   # lse
-            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),   # delta
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+        functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
+                          causal=causal, group=group, offset=offset),
+        grid=(bh, seq_q // bq, seq_k // bkm),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
         name="flash_bwd_dq",
     )(qr, kr, vr, gr, lse, delta)
 
-    dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, seq_q=seq_q, causal_offset=offset)
+    bk, bqm, group = blocks.dkv
+    num_q = seq_q // bqm
+    if causal:
+        # q major blocks above the diagonal are skipped, and not fetched:
+        # the index repeats the first one this k block needs
+        def q_block(i, j):
+            first = jnp.maximum(i * bk - offset, 0) // bqm
+            return jnp.maximum(j, jnp.minimum(first, num_q - 1))
+    else:
+        def q_block(i, j):
+            return j
+    q_spec = pl.BlockSpec((1, bqm, d), lambda b, i, j: (b, q_block(i, j), 0))
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0))
+    row_spec = pl.BlockSpec((1, 1, bqm), lambda b, i, j: (b, 0, q_block(i, j)))
     dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(bh, seq_k // block_k),
-        in_specs=[
-            pl.BlockSpec((1, seq_q, d), lambda b, i: (b, 0, 0)),     # q
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),   # k
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),   # v
-            pl.BlockSpec((1, seq_q, d), lambda b, i: (b, 0, 0)),     # do
-            pl.BlockSpec((1, 1, seq_q), lambda b, i: (b, 0, 0)),     # lse
-            pl.BlockSpec((1, 1, seq_q), lambda b, i: (b, 0, 0)),     # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-        ],
+        functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
+                          causal=causal, group=group, offset=offset),
+        grid=(bh, seq_k // bk, num_q),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_k, d), k.dtype),
             jax.ShapeDtypeStruct((bh, seq_k, d), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lse, delta)
@@ -317,7 +460,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash_fn(causal, sm_scale, block_q, block_k, interpret):
+def _make_flash_fn(causal, sm_scale, blocks, interpret):
     """Pallas forward + Pallas backward under jax.custom_vjp.
 
     The backward is the flash-attention recompute form (dQ kernel + dK/dV
@@ -327,19 +470,18 @@ def _make_flash_fn(causal, sm_scale, block_q, block_k, interpret):
 
     @jax.custom_vjp
     def f(q, k, v):
-        out, _ = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                                interpret)
+        out, _ = _flash_forward(q, k, v, causal, sm_scale, blocks, interpret)
         return out
 
     def fwd(q, k, v):
-        out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q,
-                                  block_k, interpret)
+        out, lse = _flash_forward(q, k, v, causal, sm_scale, blocks,
+                                  interpret)
         return out, (q, k, v, out, lse)
 
     def bwd(res, g):
         q, k, v, out, lse = res
         return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                               block_q, block_k, interpret)
+                               blocks, interpret)
 
     f.defvjp(fwd, bwd)
     return f
@@ -353,27 +495,35 @@ def _default_interpret() -> bool:
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
     """Fused attention on the MXU; O(seq) memory via online softmax.
 
-    Sequence lengths must be multiples of the (clamped) block sizes: pad
-    upstream. A ragged shape raises instead of silently running another
-    kernel.
+    The kernels' blocks follow from the shape (`_block_sizes`). A sequence
+    length is below 128 (one block) or a multiple of 128: pad upstream; a
+    ragged shape raises instead of silently running another kernel.
+    block_q / block_k are for tests that want several blocks of a short
+    sequence: every kernel then tiles by exactly these.
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     seq_q, seq_k = q.shape[2], k.shape[2]
     if interpret is None:
         interpret = _default_interpret()
-    block_q = min(block_q, seq_q)
-    block_k = min(block_k, seq_k)
-    if seq_q % block_q or seq_k % block_k:
-        raise ValueError(
-            f"flash_attention needs seq_q={seq_q} and seq_k={seq_k} to be "
-            f"multiples of block_q={block_q} and block_k={block_k}; pad "
-            "the sequence or call mha_reference")
-    fn = _make_flash_fn(causal, float(sm_scale), block_q, block_k, interpret)
+    if block_q is None and block_k is None:
+        blocks = _block_sizes(seq_q, seq_k, q.shape[-1])
+    else:
+        bq = min(block_q or seq_q, seq_q)
+        bk = min(block_k or seq_k, seq_k)
+        if seq_q % bq or seq_k % bk:
+            raise ValueError(
+                f"flash_attention needs seq_q={seq_q} and seq_k={seq_k} to "
+                f"be multiples of block_q={bq} and block_k={bk}; pad the "
+                "sequence or call mha_reference")
+        blocks = _FlashBlocks(fwd=(bq, bk, bq), dq=(bq, bk, bq),
+                              dkv=(bk, bq, bk))
+    fn = _make_flash_fn(causal, float(sm_scale), blocks, interpret)
     return fn(q, k, v)
 
 

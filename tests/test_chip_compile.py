@@ -13,6 +13,9 @@ import pytest
 
 # GPT-2 small attention shapes: [batch, heads, seq, head_dim].
 SHAPE = (8, 12, 1024, 64)
+# What the kernels' tiles are derived from, at the lengths the benchmark's
+# cells and the serve mixes run: gpt2s, smollm-1.7b, a 128-token score batch.
+KERNEL_SHAPES = [SHAPE, (4, 16, 2048, 64), (8, 32, 128, 64)]
 
 
 @pytest.fixture(scope="module")
@@ -38,23 +41,24 @@ def v5e(jax_cpu):
     compilation_cache.reset_cache()
 
 
+@pytest.mark.parametrize("shape", KERNEL_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
 @pytest.mark.parametrize("backward", [False, True],
                          ids=["forward", "forward_backward"])
-def test_flash_kernel_compiles_for_v5e(v5e, backward):
+def test_flash_kernel_compiles_for_v5e(v5e, backward, shape):
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
     from ray_tpu.ops.attention import flash_attention
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, block_q=128,
-                               block_k=128, interpret=False)
+        return flash_attention(q, k, v, causal=True, interpret=False)
 
     fn = fwd
     if backward:
         fn = jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
                       argnums=(0, 1, 2))
-    x = jax.ShapeDtypeStruct(SHAPE, jnp.bfloat16,
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                              sharding=SingleDeviceSharding(v5e[0]))
     text = jax.jit(fn).lower(x, x, x).compile().as_text()
     # forward: one Mosaic call; backward adds the dQ and the dK/dV kernels

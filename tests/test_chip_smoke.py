@@ -14,7 +14,8 @@ import chip_smoke
 REPO = os.path.dirname(os.path.abspath(chip_smoke.__file__))
 
 TINY = chip_smoke.Size(model="tiny", batch=16, seq=128,
-                       kernel_shape=(2, 4, 128, 32), prompt_len=128,
+                       kernel_shapes=((2, 4, 128, 32), (1, 2, 256, 32)),
+                       prompt_len=128,
                        platform="cpu")
 
 
