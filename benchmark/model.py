@@ -1,14 +1,17 @@
-"""Configurations, the FLOPs arithmetic and the table of peaks.
+"""The table of peaks, the utilization arithmetic on it, and the lookup of
+a configuration's family.
 
 A configuration file (benchmark/configs/<name>.json) holds the model's sizes
 under the keys of the Hugging Face `config.json` convention, whatever its
-source calls them; `gpt_config` maps them onto the program's `GPTConfig`.
-The arithmetic and the peak are copies of bench.py's (`bench_model`,
-`PEAK_BF16_FLOPS`), kept here so that no later PR can move the yardstick.
+source calls them, and may name its family; benchmark/families/<family>.py
+maps the keys onto the program and holds the reference and the FLOPs
+arithmetic. The peak is a copy of bench.py's `PEAK_BF16_FLOPS`, kept here so
+that no later PR can move the yardstick.
 """
 
 from __future__ import annotations
 
+import importlib
 from typing import Any, Dict
 
 # One chip's published peaks, keyed by jax's device_kind. Source: Google
@@ -28,45 +31,14 @@ def peak(device_kind: str) -> Dict[str, float]:
     return PEAKS[device_kind]
 
 
-def gpt_config_kwargs(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The config file's sizes as GPTConfig's keyword arguments."""
-    if config["num_key_value_heads"] != config["num_attention_heads"]:
-        raise ValueError("models/gpt.py has no grouped-query attention")
-    return {
-        "vocab_size": config.get("padded_vocab_size", config["vocab_size"]),
-        "d_model": config["hidden_size"],
-        "n_layers": config["num_hidden_layers"],
-        "n_heads": config["num_attention_heads"],
-        "d_ff": config["intermediate_size"],
-        "max_seq": config["max_position_embeddings"],
-        "rope_theta": float(config["rope_theta"]),
-        "rmsnorm_eps": float(config["rms_norm_eps"]),
-        "tie_embeddings": bool(config["tie_word_embeddings"]),
-    }
-
-
-def param_count(config: Dict[str, Any]) -> int:
-    """Parameters of the program's block at these sizes: four d x d
-    attention matrices, a three-matrix SwiGLU MLP, two norms a layer, the
-    embedding, the final norm, and the head unless it is tied."""
-    k = gpt_config_kwargs(config)
-    d, ff, v = k["d_model"], k["d_ff"], k["vocab_size"]
-    layer = 4 * d * d + 3 * d * ff + 2 * d
-    head = 0 if k["tie_embeddings"] else d * v
-    return k["n_layers"] * layer + v * d + d + head
-
-
-def train_flops_per_token(config: Dict[str, Any], seq: int) -> float:
-    """6 N + 12 L d S: forward and backward of the matrices and of causal
-    attention counted as full (bench.py's form). Recomputation (remat) is
-    not counted: it is work the model does not require."""
-    k = gpt_config_kwargs(config)
-    return 6.0 * param_count(config) + 12.0 * k["n_layers"] * k["d_model"] * seq
-
-
-def forward_flops_per_token(config: Dict[str, Any], seq: int) -> float:
-    """A scoring forward is a third of the training arithmetic."""
-    return train_flops_per_token(config, seq) / 3.0
+def family(config: Dict[str, Any]):
+    """The module under benchmark/families/ that knows this configuration's
+    architecture (its "family" key; gpt_dense where the file has none): the
+    program's side, the plain reference, the FLOPs arithmetic and the shapes
+    of its kernels' calls. The one lookup through which the cells, the
+    readers and the selftest reach an architecture."""
+    return importlib.import_module(
+        "benchmark.families." + config.get("family", "gpt_dense"))
 
 
 def mfu_pct(tokens_per_s: float, flops_per_token: float, chips: int,

@@ -90,8 +90,10 @@ def per_layer_values(cell: Dict[str, Any], summary: Dict[str, Any]
     """Each per-layer metric through its own reader: benchmark/metrics/
     <metric>.json names a module under benchmark/readers/ and its
     arguments; <quantity>.<cells> (one quantity split by the end-to-end
-    metric it moves) reads <quantity>.json. A reader that finds nothing
-    returns None and the metric is left out."""
+    metric it moves) reads <quantity>.json. A reader gets the run's summary
+    (counters, series, the reduced trace, the peak, the device, the
+    configuration and the traffic mix). One that finds nothing returns None
+    and the metric is left out."""
     values = {}
     for metric in cell["per_layer"]:
         spec = read_json(HERE, "metrics",
@@ -150,13 +152,14 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool,
         memory_limit_bytes=facts["memory"]["memory_limit_bytes"],
         chips=cell["chips"])
     summary.update(trace=facts.get("trace"), peak=peak, device=device,
-                   config=cell["config"])
+                   config=cell["config"], traffic=cell["traffic"])
 
     facts["timeline"][0][1] -= started   # seconds after process start
     print(json.dumps({"info": summary["info"], "check": facts["check"],
                       "setup": facts["setup"], "setup_s": setup_s,
                       "timeline": facts["timeline"],
                       "memory": facts["memory"],
+                      "regions": (facts.get("trace") or {}).get("regions"),
                       "errors": summary["errors"]}), flush=True)
     if trace:
         values = per_layer_values(cell, summary)

@@ -89,42 +89,30 @@ def make_replica_class(serve_params: Dict[str, Any]):
 
         def _load(self) -> Dict[str, Any]:
             import jax
-            import jax.numpy as jnp
             import numpy as np
 
-            from benchmark import model, reference, worker
-            from ray_tpu.models.gpt import GPTConfig, gpt_forward, gpt_init
+            from benchmark import model, worker
 
             spec = self._spec
             timeline = worker.Timeline("load_entered_wall")
             device = worker.open_device(spec["platform"], 1)
             timeline.mark("device_open")
             watch = worker.CompileWatch()
-            cfg = GPTConfig(**model.gpt_config_kwargs(spec["model"]),
-                            attention="flash")
-
-            def init(key):
-                return jax.tree_util.tree_map(
-                    lambda x: x.astype(cfg.dtype), gpt_init(key, cfg))
-            params = jax.jit(init)(jax.random.PRNGKey(spec["seed"] % 2 ** 32))
-
-            def score(params, tokens):
-                logits, _ = gpt_forward(params, tokens, cfg)
-                logits = logits[:, :-1].astype(jnp.float32)
-                picked = jnp.take_along_axis(
-                    logits, tokens[:, 1:, None], axis=-1)[..., 0]
-                return picked - jax.nn.logsumexp(logits, axis=-1)
+            family = model.family(spec["model"])
+            served = family.program(spec["model"], serving=True)
+            params = jax.jit(served.init)(
+                jax.random.PRNGKey(spec["seed"] % 2 ** 32))
             jax.block_until_ready(params)
             timeline.mark("weights_ready")
             # the one shape every forward runs: max_batch_size x pad_to
             blank = np.zeros((spec["serve"]["max_batch_size"],
                               spec["serve"]["pad_to"]), np.int32)
-            program = jax.jit(score).lower(params, blank).compile()
+            program = jax.jit(served.score).lower(params, blank).compile()
             program(params, blank).block_until_ready()
             timeline.mark("forward_warm")
             self._m = {"device": device, "watch": watch, "params": params,
                        "score": program, "reference": jax.jit(
-                           lambda p, t: reference.logprobs(
+                           lambda p, t: family.reference_logprobs(
                                p, t, spec["model"]))}
             return {"device": device, "setup": watch.snapshot(),
                     "timeline": timeline.marks}
@@ -187,8 +175,8 @@ def make_replica_class(serve_params: Dict[str, Any]):
             if op == "trace_stop":
                 return m.pop("tracer").stop()
             if op == "reference":
-                # benchmark/reference.py on the served weights: float32,
-                # full precision, a few prompts at a call
+                # the family's plain reference on the served weights:
+                # float32, full precision, a few prompts at a call
                 pad_to, rows = self._spec["serve"]["pad_to"], msg["rows"]
                 answers = []
                 for at in range(0, len(msg["prompts"]), rows):
@@ -372,8 +360,8 @@ def trace_stretch(port: int, mix: Dict[str, Any], vocab: int, seed: int,
 def check_answers(port: int, load: Load, mix: Dict[str, Any]
                   ) -> Dict[str, Any]:
     """The window's answers to the pool's first `check_prompts` prompts
-    against benchmark/reference.py on the same weights. The tolerances and
-    their reason are in the mix's file."""
+    against the family's plain reference on the same weights. The tolerances
+    and their reason are in the mix's file."""
     indices = sorted(load.answers)
     if not indices:
         return {"ok": False, "why": "no answer to check"}

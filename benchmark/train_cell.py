@@ -17,17 +17,18 @@ def train_loop(config: Dict[str, Any]) -> None:
     """build_mesh -> init_train_state -> make_train_step on a fixed, seeded,
     device-resident batch; warm up, then step for `seconds` by the loop's own
     clock, reporting each step as a user's loop does. Everything measured
-    leaves as host numbers in the last report. (The persistent cache's key of
-    the step holds this file's line numbers down to the gpt_loss call, so a
-    line added above it is one cold compile in every checkout: PERF.md, PR 21.)"""
+    leaves as host numbers in the last report. What the model is comes from
+    the configuration's family (benchmark/families/). (The persistent cache's
+    key of the step holds this file's and the family's line numbers down to
+    the loss call, so a line added above it is one cold compile in every
+    checkout: PERF.md, PR 21.)"""
     import jax
     import numpy as np
     import optax
     from jax.sharding import NamedSharding
 
-    from benchmark import model, reference, worker
+    from benchmark import model, worker
     from ray_tpu import train
-    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
     from ray_tpu.parallel.sharding import strategy_from_name
     from ray_tpu.train.train_step import (TrainState, init_train_state,
@@ -41,8 +42,8 @@ def train_loop(config: Dict[str, Any]) -> None:
     watch = worker.CompileWatch()
     timeline.mark("device_open")
 
-    cfg = GPTConfig(**model.gpt_config_kwargs(config["model"]),
-                    attention="flash", remat_policy="full")
+    family = model.family(config["model"])
+    program = family.program(config["model"])
     mesh = build_mesh(MeshConfig(**mix["mesh"]))
     strategy = strategy_from_name(mix["strategy"])
     act_sharding = strategy.activation_sharding(mesh)
@@ -53,9 +54,9 @@ def train_loop(config: Dict[str, Any]) -> None:
     # called with a fixed key for the state's structure, shardings and
     # optimizer state; the weights come from one jitted call that takes the
     # seed's key as an argument, into the same shardings.
-    state = init_train_state(lambda: gpt_init(jax.random.PRNGKey(0), cfg),
+    state = init_train_state(lambda: program.init(jax.random.PRNGKey(0)),
                              optimizer, mesh, strategy)
-    seeded_init = jax.jit(lambda key: gpt_init(key, cfg),
+    seeded_init = jax.jit(program.init,
                           out_shardings=strategy.param_shardings(
                               mesh, state.params))
     key = jax.random.PRNGKey(seed % (2 ** 31))
@@ -69,8 +70,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     batch = {"tokens": jax.device_put(
         tokens, NamedSharding(mesh, strategy.batch_spec))}
     step = make_train_step(
-        lambda p, b: gpt_loss(p, b, cfg, mesh=mesh,
-                              act_sharding=act_sharding),
+        lambda p, b: program.loss(p, b, mesh, act_sharding),
         optimizer, mesh, strategy, sample_params=state.params
     ).lower(state, batch).compile()
     timeline.mark("step_compiled")
@@ -117,14 +117,15 @@ def train_loop(config: Dict[str, Any]) -> None:
         tracer.start()
         for _ in range(mix["trace_steps"]):
             state, _times, _loss = one_step(state)
-        facts["trace"] = tracer.stop()
+        facts["trace"] = tracer.stop(step)
 
-    # correctness, outside the window: the first step's loss against
-    # benchmark/reference.py (float32, full matmul precision) on the same
+    # correctness, outside the window: the first step's loss against the
+    # family's plain reference (float32, full matmul precision) on the same
     # weights and batch, a few rows at a call
     del state
     params = seeded_init(key)
-    ref_loss = jax.jit(lambda p, t: reference.loss(p, t, config["model"]))
+    ref_loss = jax.jit(
+        lambda p, t: family.reference_loss(p, t, config["model"]))
     rows = mix["reference_rows"]
     parts = []
     with jax.default_matmul_precision("highest"):
@@ -156,9 +157,9 @@ def run(cell: Dict[str, Any], args) -> Dict[str, Any]:
     check, tol = facts["check"], cell["traffic"]["loss_rel_tol"]
     gap = abs(check["first_loss"] - check["reference_loss"])
     check["gap"] = gap
-    check["ok"] = bool(
-        gap <= tol * max(1.0, abs(check["reference_loss"]))
-        and check["last_loss"] < check["first_loss"])
+    check["gap_limit"] = tol * max(1.0, abs(check["reference_loss"]))
+    check["ok"] = bool(gap <= check["gap_limit"]
+                       and check["last_loss"] < check["first_loss"])
     return facts
 
 

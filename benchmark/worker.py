@@ -121,10 +121,18 @@ class Tracer:
         self._window = jax.profiler.TraceAnnotation("bench:window")
         self._window.__enter__()
 
-    def stop(self) -> Dict[str, Any]:
+    def stop(self, compiled=None) -> Dict[str, Any]:
+        """`compiled`: the jax.stages.Compiled of the program that ran in
+        the stretch. With it the reduction also joins the device's ops to
+        the regions and kernels the program names (xplane.reduce_trace's
+        `regions`), and says what that cost: after the window, in a traced
+        run only."""
+        # the window closes before anything is imported: an import inside
+        # it is host time the device waits through, and reads as idle
+        self._window.__exit__(None, None, None)
         import jax
         from benchmark import xplane
-        self._window.__exit__(None, None, None)
+        from ray_tpu.util import profiling
         jax.profiler.stop_trace()
         path = xplane.newest_trace(self.log_dir)
         trace = xplane.load(path)
@@ -135,4 +143,13 @@ class Tracer:
             if not any(n == xplane.WINDOW_SPAN for n, _a, _b in trace["host"]):
                 raise RuntimeError("the trace lacks the bench:window span")
             return None
-        return xplane.reduce_trace(trace)
+        if compiled is None:
+            return xplane.reduce_trace(trace)
+        t0 = time.perf_counter()
+        hlo_text = compiled.as_text()
+        reduced = xplane.reduce_trace(trace, hlo_text=hlo_text,
+                                      regions=profiling.REGIONS,
+                                      kernels=profiling.KERNELS)
+        reduced["regions"].update(hlo_text_bytes=len(hlo_text),
+                                  reduce_s=time.perf_counter() - t0)
+        return reduced

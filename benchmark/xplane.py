@@ -1,6 +1,8 @@
 """Reduction of a profiler trace (.xplane.pb) to the numbers the benchmark
 reports: device busy and idle time, the ops that took most of it, time in
-collective ops, and each idle gap named by what the host was doing.
+collective ops, each idle gap named by what the host was doing, and, with
+the compiled step's HLO text, the device's time by the regions and kernels
+the program names.
 
 What a v5e trace holds (looked at by hand, fixtures/trace.xplane.pb): one
 plane "/device:TPU:<n>" per chip whose line "XLA Ops" has one event per
@@ -14,6 +16,15 @@ span that launched it), so a window is taken some seconds long.
 The benchmark's processes name their spans "bench:window" (the traced
 stretch) and "host:<what>" (what a host thread was doing). Only the process
 that holds the chip can trace it, and it reduces its own trace.
+
+A v5e trace carries no scope: an event is named by its instruction's HLO
+text and nothing else. The region comes from the compiled step, whose HLO
+text gives every instruction's `op_name`, the path of `jax.named_scope`s it
+was traced under (`jit(_step)/loss_and_grad/jvp(mlp)/dot_general`). The join
+below (event -> instruction -> op_name -> region, phase, kernel) is a copy of
+ray_tpu/util/profiling.py's `device_regions` (PR 24), kept here so that no
+later PR can move the yardstick; the program supplies only its names: the
+vocabulary of regions and of kernels.
 """
 
 from __future__ import annotations
@@ -21,7 +32,9 @@ from __future__ import annotations
 import glob
 import os
 import re
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
+
+from benchmark.estimators import median
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
@@ -32,6 +45,12 @@ COLLECTIVE = re.compile(
     r"(-start|-done)?(\.\d+)?$")
 OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
 MIN_GAP_NS = 20_000   # shorter gaps are the device's own op-to-op turnaround
+UNATTRIBUTED = "unattributed"   # an op whose op_name holds no region
+NO_PHASE = "\u2014"              # neither forward, backward nor recompute
+INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(")
+OP_NAME = re.compile(r'op_name="([^"]*)"')
+REFERENCE = re.compile(r"%([\w.\-]+)")
 
 Interval = Tuple[float, float]
 
@@ -125,14 +144,147 @@ def busiest_host_span(gap: Interval, host) -> str:
     return best
 
 
+def instruction_paths(hlo_text: str
+                      ) -> Tuple[Dict[str, str], Dict[str, str]]:
+    """instruction name -> op_name, for every instruction of every
+    computation of the module (names are unique across a module): those
+    that carry one, and those that inherit one. What the compiler left
+    without metadata (a fusion with a tuple at its root, a layout copy, an
+    async slice: 7 % of the one-chip step's time) takes the op_name nearest
+    the root of the computation it calls, or else of the first of its
+    operands that has one."""
+    named: Dict[str, str] = {}
+    inherits: Dict[str, str] = {}
+    refers: Dict[str, List[str]] = {}
+    last_in: Dict[str, str] = {}     # computation -> its last op_name
+    computation = ""
+    for line in hlo_text.splitlines():
+        m = INSTRUCTION.match(line)
+        if not m:
+            header = COMPUTATION.match(line)
+            if header:
+                computation = header.group(1)
+            continue
+        found = OP_NAME.search(line, m.end())
+        if found:
+            named[m.group(1)] = last_in[computation] = found.group(1)
+        else:
+            refers[m.group(1)] = REFERENCE.findall(line, m.end())
+
+    def inherited(name: str) -> Optional[str]:
+        if name in refers:
+            to = refers.pop(name)       # popped: looked at once
+            found = (next((last_in[r] for r in to if r in last_in), None)
+                     or next(filter(None, map(inherited, to)), None))
+            if found:
+                inherits[name] = found
+        return named.get(name) or inherits.get(name)
+
+    for name in list(refers):
+        inherited(name)
+    return named, inherits
+
+
+def last_of(path: str, vocabulary: Sequence[str]) -> Optional[str]:
+    """The last component of the scope path that is in the vocabulary,
+    inside whatever transforms wrap it: `transpose(jvp(mlp))` is `mlp`."""
+    for part in reversed(path.split("/")):
+        word = part.rsplit("(", 1)[-1].rstrip(")")
+        if word in vocabulary:
+            return word
+    return None
+
+
+def phase(path: str) -> str:
+    if "rematted_computation" in path:
+        return "recompute"
+    if "transpose(" in path:
+        return "backward"
+    return "forward" if "jvp(" in path else NO_PHASE
+
+
+def self_times(events) -> List[float]:
+    """Each event's duration less that of the events nested in it (a
+    `while` spans its body's ops on the same line), so that every busy
+    nanosecond is counted under exactly one op. `events` sorted by start,
+    the longer first."""
+    own = [b - a for _n, a, b in events]
+    enclosing: List[int] = []
+    for i, (_n, a, b) in enumerate(events):
+        while enclosing and events[enclosing[-1]][2] <= a:
+            enclosing.pop()
+        if enclosing:
+            own[enclosing[-1]] -= min(b, events[enclosing[-1]][2]) - a
+        enclosing.append(i)
+    return own
+
+
+Table = Dict[Tuple[str, ...], List[float]]
+
+
+def _chip_tables(events, named: Dict[str, str], inherits: Dict[str, str],
+                 regions: Sequence[str], kernels: Sequence[str]
+                 ) -> Tuple[Dict[str, float], Table, Table, Table]:
+    """One chip's op line, clipped to the window and sorted by start, the
+    longer first, in ns: `ops` region/op -> own ns; `rows` (region, phase)
+    -> [own ns, ops]; `calls` (kernel, phase) -> [ns, calls]; `waits`
+    (region,) -> [ns in collective ops]."""
+    ops: Dict[str, float] = {}
+    rows: Table = {}
+    calls: Table = {}
+    waits: Table = {}
+    for (n, a, b), own in zip(events, self_times(events)):
+        op = op_name(n)
+        path = named.get(op) or inherits.get(op, "")
+        region = last_of(path, regions) or UNATTRIBUTED
+        ops[region + "/" + op] = ops.get(region + "/" + op, 0.0) + own
+        row = rows.setdefault((region, phase(path)), [0.0, 0])
+        row[0] += own
+        row[1] += 1
+        kernel = last_of(named.get(op, ""), kernels)
+        if kernel:
+            call = calls.setdefault((kernel, phase(path)), [0.0, 0])
+            call[0] += b - a
+            call[1] += 1
+        if is_collective(n):
+            waits.setdefault((region,), [0.0])[0] += b - a
+    return ops, rows, calls, waits
+
+
+def _median_table(chips: List[Table]) -> List[Tuple[Tuple[str, ...],
+                                                    List[float]]]:
+    """Key by key the median over the chips (a key a chip lacks is 0
+    there), the largest first."""
+    widths = {k: len(v) for c in chips for k, v in c.items()}
+    table = {k: [median([c.get(k, [0.0] * n)[i] for c in chips])
+                 for i in range(n)] for k, n in widths.items()}
+    return sorted(table.items(), key=lambda kv: -kv[1][0])
+
+
 def reduce_trace(trace: Dict[str, Any],
-                 window: Optional[Interval] = None) -> Dict[str, Any]:
+                 window: Optional[Interval] = None,
+                 hlo_text: Optional[str] = None,
+                 regions: Sequence[str] = (),
+                 kernels: Sequence[str] = ()) -> Dict[str, Any]:
     """Numbers of the traced window, in seconds, averaged over the chips:
     window_s, busy_s, idle_pct, collective_s and collective_pct (median over
     chips of the time the op line spends in collective ops: the time the
     core waits on or runs them, not what is hidden under compute), and the
-    breakdown: device_ops [[op, seconds]] summed over the window on the
-    busiest chip, idle_gaps [[host span, seconds]] of the first chip."""
+    breakdown: device_ops [[region/op, seconds]], each op's own time (without
+    the ops nested in it) summed over the window on the busiest chip,
+    idle_gaps [[host span, seconds]] of the first chip.
+
+    With `hlo_text`, the text of the compiled step that ran in the window,
+    and the program's vocabularies of `regions` and `kernels`, also
+    `regions`: the median over the chips of
+      rows        [region, phase, seconds, % of window, ops] - an op's own
+                  time, so the rows sum to the busy time; phase forward |
+                  backward | recompute | NO_PHASE; an op whose op_name holds
+                  no region under UNATTRIBUTED;
+      kernels     [kernel, phase, seconds, calls, seconds a call] - their
+                  time is in rows too, in the region that calls them;
+      collectives [region, seconds] on the op line.
+    Without it every op is UNATTRIBUTED."""
     if window is None:
         spans = [(a, b) for n, a, b in trace["host"] if n == WINDOW_SPAN]
         if not spans:
@@ -141,18 +293,22 @@ def reduce_trace(trace: Dict[str, Any],
     start, end = window
     if not trace["devices"]:
         raise ValueError("the trace has no TPU plane: nothing ran on a chip")
+    named, inherits = instruction_paths(hlo_text or "")
     host = [(n, a, b) for n, a, b in clip(trace["host"], start, end)
             if n != WINDOW_SPAN]
     busy, collective, per_op, gap_names = [], [], {}, {}
+    rows, calls, waits = [], [], []
     for index in sorted(trace["devices"]):
-        events = clip(trace["devices"][index], start, end)
+        events = sorted(clip(trace["devices"][index], start, end),
+                        key=lambda e: (e[1], -e[2]))
         spans = [(a, b) for _n, a, b in events]
         busy.append(union_length(spans))
         collective.append(sum(b - a for n, a, b in events if is_collective(n)))
+        ops, *tables = _chip_tables(events, named, inherits, regions, kernels)
+        for kept, table in zip((rows, calls, waits), tables):
+            kept.append(table)
         if busy[-1] == max(busy):
-            per_op = {}
-            for n, a, b in events:
-                per_op[op_name(n)] = per_op.get(op_name(n), 0.0) + (b - a)
+            per_op = ops
         if index == min(trace["devices"]):
             for gap in gaps_in(spans, start, end):
                 if gap[1] - gap[0] >= MIN_GAP_NS:
@@ -167,10 +323,19 @@ def reduce_trace(trace: Dict[str, Any],
     def top(table):
         return [[k, v / 1e9] for k, v in
                 sorted(table.items(), key=lambda kv: -kv[1])[:10]]
-    return {"window_s": window_ns / 1e9, "busy_s": busy_ns / 1e9,
-            "idle_pct": 100.0 * (1.0 - busy_ns / window_ns),
-            "collective_s": mid / 1e9,
-            "collective_pct": 100.0 * mid / window_ns,
-            "chips": len(busy),
-            "breakdown": {"device_ops": top(per_op),
-                          "idle_gaps": top(gap_names)}}
+    reduced = {"window_s": window_ns / 1e9, "busy_s": busy_ns / 1e9,
+               "idle_pct": 100.0 * (1.0 - busy_ns / window_ns),
+               "collective_s": mid / 1e9,
+               "collective_pct": 100.0 * mid / window_ns,
+               "chips": len(busy),
+               "breakdown": {"device_ops": top(per_op),
+                             "idle_gaps": top(gap_names)}}
+    if hlo_text is not None:
+        reduced["regions"] = {
+            "rows": [list(k) + [v[0] / 1e9, 100.0 * v[0] / window_ns, v[1]]
+                     for k, v in _median_table(rows)],
+            "kernels": [list(k) + [v[0] / 1e9, v[1], v[0] / 1e9 / v[1]]
+                        for k, v in _median_table(calls)],
+            "collectives": [list(k) + [v[0] / 1e9]
+                            for k, v in _median_table(waits)]}
+    return reduced
