@@ -5,7 +5,8 @@ ray_tpu.parallel.sharding rules: `layers/<i>/attn/wq`, `mlp/w_up`,
 `embed/table`, `lm_head`, `moe/...`. Design choices for the MXU/HBM:
 bfloat16 activations + params with fp32 softmax/layernorm accumulation,
 flash-attention Pallas kernel, optional ring attention (sequence sharded),
-optional MoE (expert-parallel), per-layer jax.checkpoint (remat) for memory.
+optional sparse experts (top-k routing with real dispatch: ops/moe.py),
+per-layer jax.checkpoint (remat) for memory.
 
 Capability parity target: the models RLlib/Train wrap in the reference are
 torch modules; here the model is a (init, apply) pair compatible with pjit.
@@ -24,6 +25,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.ops import moe
 from ray_tpu.ops.attention import flash_attention, mha_reference, ring_attention
 
 
@@ -33,14 +35,21 @@ class GPTConfig:
     d_model: int = 768
     n_layers: int = 12
     n_heads: int = 12
-    d_ff: int = 3072
+    d_ff: int = 3072                  # the MLP's width; of ONE expert's, if sparse
     max_seq: int = 1024
     dtype: Any = jnp.bfloat16
     rope_theta: float = 10000.0
     rmsnorm_eps: float = 1e-5
-    # MoE: 0 = dense MLPs; >0 = that many experts with top-2 routing.
+    # RMSNorm over the whole q and k projections, before the head split.
+    qk_norm: bool = False
+    # 0 = a dense MLP a layer; >0 = that many experts in its place, each
+    # token through the expert_top_k the router gives the most probability
+    # (used as they come out of the softmax, not renormalised). The loss
+    # adds the load-balancing and the router z-loss at these weights.
     n_experts: int = 0
     expert_top_k: int = 2
+    router_aux_loss_coef: float = 0.01
+    router_z_loss_coef: float = 0.001
     remat: bool = True
     # Remat granularity: None -> "full" if remat else "none".
     #   "full": recompute the whole layer in backward (min HBM, max FLOPs)
@@ -100,11 +109,17 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
                                   scale=1.0 / math.sqrt(2 * cfg.n_layers * d)),
             },
         }
+        if cfg.qk_norm:
+            layer["attn"]["q_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
+            layer["attn"]["k_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
         if e > 0:
+            # stacked [e, fan-in, fan-out]: the scale is the fan-in's
             layer["moe"] = {
                 "router": _init_dense(k[4], (d, e), scale=0.02),
-                "w_gate": _init_dense(k[5], (e, d, ff)),
-                "w_up": _init_dense(k[6], (e, d, ff)),
+                "w_gate": _init_dense(k[5], (e, d, ff),
+                                      scale=1.0 / math.sqrt(d)),
+                "w_up": _init_dense(k[6], (e, d, ff),
+                                    scale=1.0 / math.sqrt(d)),
                 "w_down": _init_dense(k[7], (e, ff, d),
                                       scale=1.0 / math.sqrt(2 * cfg.n_layers * ff)),
             }
@@ -161,13 +176,15 @@ def _attention_block(layer, x, cfg: GPTConfig, positions, mesh):
     h, hd = cfg.n_heads, cfg.head_dim
     dt = cfg.dtype
 
-    def proj(w):
-        return jnp.einsum("bsd,de->bse", x, w.astype(dt)).reshape(
-            b, s, h, hd).transpose(0, 2, 1, 3)
+    def proj(w, norm=None):
+        y = jnp.einsum("bsd,de->bse", x, w.astype(dt))
+        if norm is not None:
+            y = _rmsnorm(y, norm["scale"], cfg.rmsnorm_eps)
+        return y.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
 
     with jax.named_scope("attn_proj"):
-        q = proj(layer["attn"]["wq"])
-        k = proj(layer["attn"]["wk"])
+        q = proj(layer["attn"]["wq"], layer["attn"].get("q_norm"))
+        k = proj(layer["attn"]["wk"], layer["attn"].get("k_norm"))
         v = proj(layer["attn"]["wv"])
         q = _rope(q, cfg.rope_theta, positions)
         k = _rope(k, cfg.rope_theta, positions)
@@ -192,37 +209,80 @@ def _mlp_block(layer, x, cfg: GPTConfig):
                       m["w_down"].astype(dt))
 
 
-def _moe_block(layer, x, cfg: GPTConfig):
-    """Top-k routed MoE with dense dispatch (einsum over one-hot combine
-    weights) — compiles to static shapes; the 'expert' mesh axis shards the
-    expert dimension of w_gate/w_up/w_down (expert parallelism, net-new vs
-    the reference per SURVEY.md §2.5)."""
+def _route(m, x, cfg: GPTConfig):
+    """The router, in float32: probabilities over the experts, each token's
+    expert_top_k largest as they come (not renormalised), and the layer's
+    routing statistics: the load-balancing loss E x sum_e f_e P_e (f_e the
+    share of tokens that chose e among ALL their k choices, P_e the mean
+    probability of e), the z-loss mean(logsumexp(logits)^2), and the
+    largest expert's load over the mean load."""
+    e, k = cfg.n_experts, cfg.expert_top_k
+    # HIGHEST: at the default precision a TPU rounds a float32 matmul's
+    # operands to bfloat16, and near-tied experts then swap
+    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                        m["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, idx = jax.lax.top_k(probs, k)
+    load = jnp.mean(jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32),
+                            axis=2), axis=(0, 1))
+    stats = {
+        "router_balance_loss": e * jnp.sum(load * jnp.mean(probs, axis=(0, 1))),
+        "router_z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
+        "expert_load_max_over_mean": jnp.max(load) * e / k,
+    }
+    return weights, idx, stats
+
+
+def _experts(x, weights, idx, w_gate, w_up, w_down):
+    """x [b, s, d] through each token's chosen experts (ops/moe.py): rows
+    ordered by expert, three grouped matmuls with SwiGLU between, weighted
+    return. The gathers either side are the layer's sparsity, not its
+    arithmetic: scope `moe_route`."""
+    b, s, d = x.shape
+    e = w_gate.shape[0]
+    with jax.named_scope("moe_route"):
+        idx = idx.reshape(b * s, -1)
+        plan = moe.plan_dispatch(
+            idx, e, moe.tile_rows(idx.size, e, x.dtype))
+        rows = moe.dispatch(x.reshape(b * s, d), plan)
+    gate = moe.grouped_matmul(rows, w_gate, plan)
+    up = moe.grouped_matmul(rows, w_up, plan)
+    out = moe.grouped_matmul(jax.nn.silu(gate) * up, w_down, plan)
+    with jax.named_scope("moe_route"):
+        y = moe.combine(out, weights.reshape(b * s, -1), plan)
+    return y.reshape(b, s, d)
+
+
+def _moe_block(layer, x, cfg: GPTConfig, mesh):
+    """Sparse experts in the MLP's place: y = sum over a token's top-k of
+    p_e x down_e(silu(gate_e x) * up_e x). No capacity and no dropped
+    token: every token-slot is computed, by its own expert only. The
+    grouped matmuls are Mosaic custom calls, which GSPMD cannot partition,
+    so under a mesh each device dispatches its own tokens (batch over
+    'data' x 'fsdp', as _flash_on_mesh) to all the experts, whose matrices
+    it is handed whole; the router and its losses stay outside, over the
+    whole batch."""
     dt = cfg.dtype
     m = layer["moe"]
-    e = cfg.n_experts
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
-                        m["router"].astype(jnp.float32))
-    weights, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
-                                 cfg.expert_top_k)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    onehot = jax.nn.one_hot(idx, e, dtype=jnp.float32)       # [b,s,k,e]
-    combine = jnp.einsum("bsk,bske->bse", weights, onehot)   # [b,s,e]
-    gate = jnp.einsum("bsd,edf->bsef", x, m["w_gate"].astype(dt))
-    up = jnp.einsum("bsd,edf->bsef", x, m["w_up"].astype(dt))
-    act = jax.nn.silu(gate) * up
-    out = jnp.einsum("bsef,efd->bsed", act, m["w_down"].astype(dt))
-    y = jnp.einsum("bsed,bse->bsd", out.astype(jnp.float32), combine)
-    # Load-balancing auxiliary loss (Switch-style).
-    density = jnp.mean(onehot[:, :, 0, :], axis=(0, 1))
-    router_prob = jnp.mean(jax.nn.softmax(logits, -1), axis=(0, 1))
-    aux = e * jnp.sum(density * router_prob)
-    return y.astype(dt), aux
+    with jax.named_scope("moe_route"):
+        weights, idx, stats = _route(m, x, cfg)
+    matrices = [m[name].astype(dt) for name in ("w_gate", "w_up", "w_down")]
+    if mesh is None or mesh.size == 1:
+        return _experts(x, weights, idx, *matrices), stats
+    tokens = P(("data", "fsdp"), None, None)
+    y = shard_map(_experts, mesh=mesh,
+                  in_specs=(tokens, tokens, tokens, P(), P(), P()),
+                  out_specs=tokens, check_vma=False)(
+                      x, weights, idx, *matrices)
+    return y, stats
 
 
 def gpt_forward(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
-    """tokens: [B, S] int32 -> logits [B, S, vocab] (cfg.dtype)."""
+    """tokens: [B, S] int32 -> (logits [B, S, vocab] (cfg.dtype), the
+    router's statistics as gpt_backbone gives them)."""
     dt = cfg.dtype
-    x, aux_total = gpt_backbone(params, tokens, cfg, mesh, act_sharding)
+    x, router = gpt_backbone(params, tokens, cfg, mesh, act_sharding)
     with jax.named_scope("head"):
         if cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", x,
@@ -230,11 +290,13 @@ def gpt_forward(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
         else:
             logits = jnp.einsum("bsd,dv->bsv", x,
                                 params["lm_head"].astype(dt))
-    return logits, aux_total
+    return logits, router
 
 
 def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
-    """tokens: [B, S] -> final hidden states [B, S, D] (pre-LM-head).
+    """tokens: [B, S] -> (final hidden states [B, S, D] (pre-LM-head), the
+    router's statistics averaged over the layers: _route's dict for a
+    sparse model, {} for a dense one).
 
     act_sharding (a NamedSharding for [B, S, D] activations, usually
     ``strategy.activation_sharding(mesh)``) pins the residual stream at
@@ -253,7 +315,6 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     with jax.named_scope("embed"):
         x = _c(params["embed"]["table"].astype(dt)[tokens])
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-    aux_total = 0.0
 
     def layer_fn(x, layer):
         h = _c(x + _attention_block(layer, _rmsnorm(
@@ -261,11 +322,11 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
         normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
         if cfg.n_experts > 0:
             with jax.named_scope("moe"):
-                delta, aux = _moe_block(layer, normed, cfg)
+                delta, stats = _moe_block(layer, normed, cfg, mesh)
         else:
             with jax.named_scope("mlp"):
-                delta, aux = _mlp_block(layer, normed, cfg), 0.0
-        return _c(h + delta), aux
+                delta, stats = _mlp_block(layer, normed, cfg), {}
+        return _c(h + delta), stats
 
     policy = cfg.remat_policy or ("full" if cfg.remat else "none")
     if policy == "full":
@@ -277,11 +338,14 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     elif policy != "none":
         raise ValueError(f"unknown remat_policy {policy!r} "
                          "(expected 'full' | 'dots' | 'none')")
+    per_layer = []
     for layer in params["layers"]:
-        x, aux = layer_fn(x, layer)
-        aux_total = aux_total + aux
+        x, stats = layer_fn(x, layer)
+        per_layer.append(stats)
+    router = jax.tree_util.tree_map(
+        lambda *layers: sum(layers) / len(layers), *per_layer)
     return _rmsnorm(x, params["final_norm"]["scale"], cfg.rmsnorm_eps), \
-        aux_total
+        router
 
 
 def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
@@ -322,15 +386,21 @@ def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
     return total, denom
 
 
-def gpt_loss(params, batch, cfg: GPTConfig, mesh=None, act_sharding=None):
-    """batch: {"tokens": [B, S+1]} -> mean next-token cross-entropy.
+def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
+                     act_sharding=None):
+    """batch: {"tokens": [B, S+1]} -> (loss, aux): the mean next-token
+    cross-entropy, plus for a sparse model the router's two losses at the
+    configuration's weights; aux holds the cross-entropy alone ("xent")
+    and the router's statistics (the two losses unweighted, the largest
+    expert's load over the mean), for a step written with
+    jax.value_and_grad(..., has_aux=True).
 
     The LM-head matmul + softmax run chunked (chunked_xent) so the full
     fp32 logits tensor never exists in HBM.
     """
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, aux = gpt_backbone(params, inputs, cfg, mesh, act_sharding)
+    x, router = gpt_backbone(params, inputs, cfg, mesh, act_sharding)
     b, s, d = x.shape
     dt = cfg.dtype
     with jax.named_scope("head"):
@@ -342,10 +412,17 @@ def gpt_loss(params, batch, cfg: GPTConfig, mesh=None, act_sharding=None):
         total, denom = chunked_xent(x.reshape(b * s, d), w_head,
                                     targets.reshape(b * s),
                                     mask.reshape(b * s))
-    loss = total / jnp.maximum(denom, 1.0)
+    loss = xent = total / jnp.maximum(denom, 1.0)
     if cfg.n_experts > 0:
-        loss = loss + 0.01 * aux / cfg.n_layers
-    return loss
+        loss = (xent
+                + cfg.router_aux_loss_coef * router["router_balance_loss"]
+                + cfg.router_z_loss_coef * router["router_z_loss"])
+    return loss, {"xent": xent, **router}
+
+
+def gpt_loss(params, batch, cfg: GPTConfig, mesh=None, act_sharding=None):
+    """gpt_loss_and_aux's loss alone: what make_train_step differentiates."""
+    return gpt_loss_and_aux(params, batch, cfg, mesh, act_sharding)[0]
 
 
 def count_params(params) -> int:

@@ -335,6 +335,15 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 _GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
+# Heads of 128 and wider: blocks of 2048 x 128 beside the score tiles need
+# 16.7 MB, over the 16 MB of VMEM a kernel is given unless it asks.
+_GRID_SEMANTICS_WIDE = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=48 << 20)
+
+
+def _compiler_params(head_dim: int):
+    return _GRID_SEMANTICS if head_dim < LANES else _GRID_SEMANTICS_WIDE
 
 
 def _kv_index(causal, offset, bq, bkm, num_k):
@@ -383,7 +392,7 @@ def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret):
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
-        compiler_params=_GRID_SEMANTICS,
+        compiler_params=_compiler_params(d),
         interpret=interpret,
         name="flash_fwd",
     )(q.reshape(bh, seq_q, d), k.reshape(bh, seq_k, d),
@@ -419,7 +428,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_GRID_SEMANTICS,
+        compiler_params=_compiler_params(d),
         interpret=interpret,
         name="flash_bwd_dq",
     )(qr, kr, vr, gr, lse, delta)
@@ -450,7 +459,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_GRID_SEMANTICS,
+        compiler_params=_compiler_params(d),
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lse, delta)

@@ -1,0 +1,302 @@
+"""Sparse-expert dispatch: every token's k chosen experts, and only those.
+
+The token-slots (T tokens x k choices) are ordered by expert into a row
+space in which each expert owns whole tiles of `tile_rows` rows: its group
+is padded up to a tile boundary with zero rows, and an expert nobody chose
+still owns one (all zero) tile. Three things follow from that layout:
+
+  - a grouped matmul is a plain tiled matmul whose weight block is picked
+    by the row tile's expert (scalar prefetch): no mask, no tile computed
+    for two experts, no `[tokens, experts, width]` intermediate;
+  - no token is dropped under any imbalance (there is no capacity): the
+    row space has room for T x k rows plus one tile an expert, and the
+    tiles past the last used one are skipped, not computed;
+  - every data movement is a gather, forward and backward: `dispatch`
+    (tokens -> rows) and `combine` (rows -> tokens, weighted) are each
+    other's transposes and carry their own VJPs, because XLA's transpose
+    of a row gather is a scatter-add, which a TPU serialises.
+
+Padding rows are zeros in, zeros through SwiGLU, and never gathered back.
+
+Kernels (names in util/profiling.KERNELS): `moe_gmm` (rows x an expert's
+matrix, forward and the gradient of the rows) and `moe_tgmm` (rows^T x
+rows a group, the gradient of the matrices).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import _divisor
+
+# One weight block in VMEM (it is double-buffered): a whole 2048 x 1024
+# bf16 expert matrix, so the rows are read once and each matrix once a group.
+_WEIGHT_BLOCK_BYTES = 4 << 20
+_VMEM_LIMIT_BYTES = 64 << 20
+_MAX_TILE_ROWS = 256
+
+
+class Plan(NamedTuple):
+    """Where every token-slot sits in the expert-ordered, tile-padded rows.
+
+    row_slot    [tiles * tile_rows] flat slot (token * k + choice) held by
+                each row; T * k on a padding row
+    token_rows  [T, k] the row of each token's each choice
+    tile_group  [tiles] the expert that owns each row tile
+    tiles_used  [1] tiles up to the end of the last group; the rest are
+                never computed
+    """
+    row_slot: jax.Array
+    token_rows: jax.Array
+    tile_group: jax.Array
+    tiles_used: jax.Array
+
+
+def tile_rows(n_slots: int, n_groups: int, dtype) -> int:
+    """Rows of one tile, from the shape alone: about a quarter of the mean
+    group (so padding, half a tile a group on average, stays near an
+    eighth of the rows), between the type's sublane packing and 256."""
+    least = 32 // jnp.dtype(dtype).itemsize      # 8 rows of f32, 16 of bf16
+    rows = least
+    while rows * 2 <= min(_MAX_TILE_ROWS, n_slots // (4 * n_groups)):
+        rows *= 2
+    return rows
+
+
+def plan_dispatch(idx, n_groups: int, rows: int) -> Plan:
+    """idx [T, k] int: the experts each token chose. Two sorts of T x k
+    keys and small dense passes; integers only, nothing differentiated."""
+    t, k = idx.shape
+    n = t * k
+    flat = idx.reshape(n).astype(jnp.int32)
+    slots = jnp.arange(n, dtype=jnp.int32)
+    chose = flat[:, None] == jnp.arange(n_groups, dtype=jnp.int32)[None, :]
+    sizes = jnp.sum(chose, axis=0, dtype=jnp.int32)
+    # stable, so a group keeps token order: slot order[r] is r-th by expert
+    _, order = lax.sort((flat, slots), num_keys=1, is_stable=True)
+    _, rank = lax.sort((order, slots), num_keys=1)
+    group_tiles = jnp.maximum(-(-sizes // rows), 1)
+    tile_end = jnp.cumsum(group_tiles)
+    first_row = rows * (tile_end - group_tiles)
+    first_rank = jnp.cumsum(sizes) - sizes
+    # a slot's row: its rank within its group, after the group's first row
+    shift = jnp.sum(jnp.where(chose, (first_row - first_rank)[None, :], 0),
+                    axis=1)
+    token_rows = (rank + shift).reshape(t, k)
+
+    tiles = -(-n // rows) + n_groups
+    tile_group = jnp.minimum(
+        jnp.sum(jnp.arange(tiles, dtype=jnp.int32)[:, None]
+                >= tile_end[None, :], axis=1, dtype=jnp.int32),
+        n_groups - 1)
+    # a row's slot: tile by tile, the group's ranks from where the tile
+    # starts within its group; past the group's size the row is padding
+    within = (rows * jnp.arange(tiles, dtype=jnp.int32)
+              - first_row[tile_group])[:, None] \
+        + jnp.arange(rows, dtype=jnp.int32)[None, :]
+    held = jnp.clip(first_rank[tile_group][:, None] + within, 0, n - 1)
+    row_slot = jnp.where(within < sizes[tile_group][:, None], order[held], n)
+    return Plan(row_slot.reshape(-1), token_rows, tile_group, tile_end[-1:])
+
+
+def _take_rows(x, index):
+    """x[index], zeros where index is len(x) (a padding row): one gather
+    from x with a zero row appended, and no select over the result."""
+    zero = jnp.zeros((1,) + x.shape[1:], x.dtype)
+    return jnp.take(jnp.concatenate([x, zero]), index, axis=0, mode="clip")
+
+
+@jax.custom_vjp
+def dispatch(x, plan: Plan):
+    """Token rows [T, d] -> expert-ordered rows [tiles * tile_rows, d]."""
+    k = plan.token_rows.shape[1]
+    return _take_rows(x, plan.row_slot // k)
+
+
+def _dispatch_fwd(x, plan):
+    return dispatch(x, plan), plan
+
+
+def _dispatch_bwd(plan, g):
+    dx = jnp.sum(g[plan.token_rows], axis=1, dtype=jnp.float32)
+    return dx.astype(g.dtype), None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(z, weights, plan: Plan):
+    """Expert-ordered rows [rows, d] back to tokens [T, d]: each token's k
+    rows, weighted (weights [T, k] float32) and summed in float32."""
+    y = jnp.einsum("tk,tkd->td", weights, z[plan.token_rows],
+                   preferred_element_type=jnp.float32)
+    return y.astype(z.dtype)
+
+
+def _combine_fwd(z, weights, plan):
+    return combine(z, weights, plan), (z, weights, plan)
+
+
+def _combine_bwd(res, g):
+    # in row space, where g's rows come by the cheap gather (from [T, d]):
+    # dz = w g and dw = <g, z> row by row, then dw back to [T, k]
+    z, weights, plan = res
+    k = plan.token_rows.shape[1]
+    g_rows = _take_rows(g, plan.row_slot // k)
+    row_weight = _take_rows(weights.reshape(-1), plan.row_slot)
+    dz = g_rows * row_weight[:, None].astype(g.dtype)
+    dweights = jnp.sum(g_rows.astype(jnp.float32) * z.astype(jnp.float32),
+                       axis=1)[plan.token_rows]
+    return dz, dweights.astype(weights.dtype), None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The grouped matmuls
+# ---------------------------------------------------------------------------
+
+def _used(tile, used_ref):
+    """Index maps clamp to the last used tile: Pallas does not fetch a
+    block whose index did not change, nor write one back."""
+    return jnp.minimum(tile, used_ref[0] - 1)
+
+
+def _gmm_kernel(group_ref, used_ref, x_ref, w_ref, o_ref, *, transposed):
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _tile():
+        contract = (((1,), (1 if transposed else 0,)), ((), ()))
+        o_ref[...] = lax.dot_general(
+            x_ref[...], w_ref[0], contract,
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _gmm(x, w, plan: Plan, transposed: bool, interpret: bool):
+    """x [rows, K] times, row tile by row tile, its group's [K, N] matrix
+    (w [G, K, N], or [G, N, K] when `transposed`) -> [rows, N]. Grid
+    (column blocks, row tiles) with the tiles inside, so a group's weight
+    block stays in VMEM while its tiles pass."""
+    m, kdim = x.shape
+    n = w.shape[1] if transposed else w.shape[2]
+    tiles = plan.tile_group.shape[0]
+    tm = m // tiles
+    tn = _divisor(n, max(128, _WEIGHT_BLOCK_BYTES // (kdim * w.dtype.itemsize)))
+    if transposed:
+        w_spec = pl.BlockSpec(
+            (1, tn, kdim), lambda j, i, grp, used: (grp[_used(i, used)], j, 0))
+    else:
+        w_spec = pl.BlockSpec(
+            (1, kdim, tn), lambda j, i, grp, used: (grp[_used(i, used)], 0, j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transposed=transposed),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tn, tiles),
+            in_specs=[
+                pl.BlockSpec((tm, kdim),
+                             lambda j, i, grp, used: (_used(i, used), 0)),
+                w_spec,
+            ],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda j, i, grp, used: (_used(i, used), j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="moe_gmm",
+    )(plan.tile_group, plan.tiles_used, x, w)
+
+
+def _tgmm_kernel(group_ref, used_ref, x_ref, g_ref, o_ref, acc_ref):
+    i = pl.program_id(2)
+    last_tile = used_ref[0] - 1
+    group = group_ref[i]
+
+    @pl.when(i <= last_tile)
+    def _tile():
+        @pl.when((i == 0) | (group_ref[jnp.maximum(i - 1, 0)] != group))
+        def _first_of_group():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += lax.dot_general(
+            x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when((i == last_tile)
+                 | (group_ref[jnp.minimum(i + 1, last_tile)] != group))
+        def _last_of_group():
+            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _tgmm(x, g, plan: Plan, n_groups: int, out_dtype, interpret: bool):
+    """x [rows, K], g [rows, N] -> [G, K, N]: x^T g over each group's rows
+    (padding rows are zero; every group owns a tile, so every block of the
+    result is written). Grid (K blocks, N blocks, row tiles), the tiles
+    inside as the reduction."""
+    m, kdim = x.shape
+    n = g.shape[1]
+    tiles = plan.tile_group.shape[0]
+    tm = m // tiles
+    tk = _divisor(kdim, 1024)
+    tn = _divisor(n, 1024)
+    return pl.pallas_call(
+        _tgmm_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(kdim // tk, n // tn, tiles),
+            in_specs=[
+                pl.BlockSpec((tm, tk),
+                             lambda a, b, i, grp, used: (_used(i, used), a)),
+                pl.BlockSpec((tm, tn),
+                             lambda a, b, i, grp, used: (_used(i, used), b)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, tk, tn),
+                lambda a, b, i, grp, used: (grp[_used(i, used)], a, b)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_groups, kdim, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="moe_tgmm",
+    )(plan.tile_group, plan.tiles_used, x, g)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_grouped_matmul(interpret: bool):
+    @jax.custom_vjp
+    def f(x, w, plan):
+        return _gmm(x, w, plan, False, interpret)
+
+    def fwd(x, w, plan):
+        return f(x, w, plan), (x, w, plan)
+
+    def bwd(res, g):
+        x, w, plan = res
+        return (_gmm(g, w, plan, True, interpret),
+                _tgmm(x, g, plan, w.shape[0], w.dtype, interpret), None)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def grouped_matmul(x, w, plan: Plan, *, interpret: Optional[bool] = None):
+    """rows [R, K] (dispatch's order) x w [G, K, N] -> [R, N]: each row by
+    its own expert's matrix, float32 accumulation, the rows' type out."""
+    if interpret is None:
+        interpret = attention._default_interpret()
+    return _make_grouped_matmul(interpret)(x, w, plan)

@@ -107,7 +107,7 @@ class ShardingStrategy:
             (r"mlp/w_down", P(t, None)),
             (r"embed/table", P(t, None)),
             (r"lm_head", P(None, t)),
-            (r"moe/.*w_up", P("expert", None, t)),
+            (r"moe/.*w_(gate|up)", P("expert", None, t)),
             (r"moe/.*w_down", P("expert", t, None)),
             (r"moe/router", P(None, None)),
         ], default=P())
@@ -130,7 +130,7 @@ class ShardingStrategy:
             # all-reduce and reshards to the batch spec cheaply.
             (r"embed/table", P((t, f), None)),
             (r"lm_head", P(f, t)),
-            (r"moe/.*w_up", P("expert", f, t)),
+            (r"moe/.*w_(gate|up)", P("expert", f, t)),
             (r"moe/.*w_down", P("expert", t, f)),
             (r"moe/router", P(None, None)),
         ], default=FSDP_LARGEST)

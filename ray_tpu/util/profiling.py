@@ -110,13 +110,19 @@ def stack_dump() -> Dict[str, str]:
 # ---------------------------------------------------------------------------
 
 # The scopes models/gpt.py and train/train_step.py open, and the names
-# ops/attention.py gives its pallas_calls. An op belongs to the LAST of
-# these on its op_name path: `jit(_step)/loss_and_grad/jvp(mlp)/dot_general`
-# is `mlp`, and what `loss_and_grad` holds itself is the rest (residual
-# adds, casts of the gradients).
+# ops/attention.py and ops/moe.py give their pallas_calls. An op belongs to
+# the LAST of these on its op_name path:
+# `jit(_step)/loss_and_grad/jvp(mlp)/dot_general` is `mlp`, and what
+# `loss_and_grad` holds itself is the rest (residual adds, casts of the
+# gradients). `moe` is the experts' own arithmetic (grouped matmuls,
+# SwiGLU, the casts of their matrices); `moe_route`, nested in it, is what
+# exists only because the layer is sparse: router, top-k, ordering, the
+# gathers either side, both router losses.
 REGIONS = ("embed", "attn_proj", "attn_core", "attn_out", "mlp", "moe",
-           "norm", "head", "loss_and_grad", "grad_accum", "optimizer")
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+           "moe_route", "norm", "head", "loss_and_grad", "grad_accum",
+           "optimizer")
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
+           "moe_tgmm")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:")

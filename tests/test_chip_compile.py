@@ -14,8 +14,10 @@ import pytest
 # GPT-2 small attention shapes: [batch, heads, seq, head_dim].
 SHAPE = (8, 12, 1024, 64)
 # What the kernels' tiles are derived from, at the lengths the benchmark's
-# cells and the serve mixes run: gpt2s, smollm-1.7b, a 128-token score batch.
-KERNEL_SHAPES = [SHAPE, (4, 16, 2048, 64), (8, 32, 128, 64)]
+# cells and the serve mixes run: gpt2s, smollm-1.7b, a 128-token score batch,
+# olmoe-1b-7b (head width 128, two major blocks a row).
+KERNEL_SHAPES = [SHAPE, (4, 16, 2048, 64), (8, 32, 128, 64),
+                 (2, 16, 4096, 128)]
 
 
 @pytest.fixture(scope="module")
@@ -106,3 +108,40 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch):
     # the tensor-parallel out projection and the fsdp weights need them
     assert "all-reduce" in text or "reduce-scatter" in text
     assert "all-gather" in text
+
+
+def test_grouped_matmul_kernels_compile_for_v5e(v5e):
+    """olmoe_train_1chip's two grouped-matmul kernels, forward and both
+    gradients, at the cell's shape: 2 x 4096 tokens x 8 experts a token in
+    256-row tiles, 64 experts of 2048 x 1024, a whole expert matrix a block
+    (over the default 16 MB of scoped VMEM, hence their limit). The rest of
+    the dispatch is sorts and gathers, plain XLA."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops import moe
+
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    slots, experts = 2 * 4096 * 8, 64
+    rows = moe.tile_rows(slots, experts, jnp.bfloat16)
+    tiles = slots // rows + experts
+    plan = moe.Plan(shape((tiles * rows,), jnp.int32),
+                    shape((2 * 4096, 8), jnp.int32),
+                    shape((tiles,), jnp.int32), shape((1,), jnp.int32))
+
+    def grads(x, w, plan):
+        # squared, so that the gradients need the forward's result
+        return jax.grad(lambda x, w: (moe.grouped_matmul(
+            x, w, plan, interpret=False).astype(jnp.float32) ** 2).sum(),
+            argnums=(0, 1))(x, w)
+
+    text = jax.jit(grads).lower(
+        shape((tiles * rows, 2048), jnp.bfloat16),
+        shape((experts, 2048, 1024), jnp.bfloat16), plan).compile().as_text()
+    # forward, the rows' gradient, the matrices' gradient
+    assert text.count("tpu_custom_call") >= 3
+    assert "moe_gmm" in text and "moe_tgmm" in text
