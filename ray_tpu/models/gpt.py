@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Any, Dict, Optional
 
 import jax
@@ -27,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import moe
 from ray_tpu.ops.attention import flash_attention, mha_reference, ring_attention
+from ray_tpu.ops.rope import rope_split, rope_table
 
 
 @dataclass(frozen=True)
@@ -155,23 +155,39 @@ def _rope(x, theta: float, positions):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
-def _flash_on_mesh(q, k, v, cfg: GPTConfig, mesh):
-    """The flash kernel on [B, H, S, D]. On a TPU it is a Mosaic custom
-    call, which GSPMD cannot partition, so under a mesh of more than one
-    device it runs per shard: batch over 'data' x 'fsdp', heads over
-    'tensor', each device attending its own (batch, head) slice with no
-    collective."""
-    flash = partial(flash_attention, causal=True)
+def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh):
+    """The projections' outputs [B, S, H*D] through the head split, the
+    rotation (ops/rope.py: one pass a tensor, straight into the kernels'
+    [B, H, S, D]) and the flash kernel. On a TPU these are Mosaic custom
+    calls, which GSPMD cannot partition, so under a mesh of more than one
+    device they run per shard: batch over 'data' x 'fsdp', whole heads (the
+    H*D columns) over 'tensor', each device attending its own (batch,
+    head) slice with no collective."""
+    def split_and_attend(q, k, v, *table):
+        with jax.named_scope("attn_proj"):
+            q = rope_split(q, cfg.head_dim, table)
+            k = rope_split(k, cfg.head_dim, table)
+            v = rope_split(v, cfg.head_dim)
+        with jax.named_scope("attn_core"):
+            return flash_attention(q, k, v, causal=True)
+
     if mesh is None or mesh.size == 1:
-        return flash(q, k, v)
-    spec = P(("data", "fsdp"), "tensor", None, None)
+        return split_and_attend(q, k, v, *table)
+    columns = P(("data", "fsdp"), None, "tensor")
     # check_vma off: pallas_call declares no varying axes for its outputs,
     # and the Pallas interpreter the CPU tests use fails the check inside.
-    return shard_map(flash, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return shard_map(split_and_attend, mesh=mesh,
+                     in_specs=(columns, columns, columns, P(), P()),
+                     out_specs=P(("data", "fsdp"), "tensor", None, None),
+                     check_vma=False)(q, k, v, *table)
 
 
-def _attention_block(layer, x, cfg: GPTConfig, positions, mesh):
+def _attention_block(layer, x, cfg: GPTConfig, positions, mesh, table):
+    """table: rope_table(S, head_dim, theta), built once a step by the
+    caller (gpt_backbone, outside its rematted layers). The flash path
+    alone reads it: 'reference' and 'ring' keep the jnp `_rope` on
+    [B, H, S, D] (the oracle, and ring's sequence shards need their global
+    positions)."""
     b, s, d = x.shape
     h, hd = cfg.n_heads, cfg.head_dim
     dt = cfg.dtype
@@ -180,21 +196,27 @@ def _attention_block(layer, x, cfg: GPTConfig, positions, mesh):
         y = jnp.einsum("bsd,de->bse", x, w.astype(dt))
         if norm is not None:
             y = _rmsnorm(y, norm["scale"], cfg.rmsnorm_eps)
+        return y
+
+    def heads(y):
         return y.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
 
     with jax.named_scope("attn_proj"):
         q = proj(layer["attn"]["wq"], layer["attn"].get("q_norm"))
         k = proj(layer["attn"]["wk"], layer["attn"].get("k_norm"))
         v = proj(layer["attn"]["wv"])
-        q = _rope(q, cfg.rope_theta, positions)
-        k = _rope(k, cfg.rope_theta, positions)
-    with jax.named_scope("attn_core"):
-        if cfg.attention == "ring":
-            o = ring_attention(q, k, v, mesh=mesh, causal=True)
-        elif cfg.attention == "reference":
-            o = mha_reference(q, k, v, causal=True)
-        else:
-            o = _flash_on_mesh(q, k, v, cfg, mesh)
+    if cfg.attention not in ("ring", "reference"):
+        o = _flash_on_mesh(q, k, v, table, cfg, mesh)
+    else:
+        with jax.named_scope("attn_proj"):
+            q = _rope(heads(q), cfg.rope_theta, positions)
+            k = _rope(heads(k), cfg.rope_theta, positions)
+            v = heads(v)
+        with jax.named_scope("attn_core"):
+            if cfg.attention == "ring":
+                o = ring_attention(q, k, v, mesh=mesh, causal=True)
+            else:
+                o = mha_reference(q, k, v, causal=True)
     with jax.named_scope("attn_out"):
         o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
         return jnp.einsum("bsd,de->bse", o, layer["attn"]["wo"].astype(dt))
@@ -315,10 +337,14 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     with jax.named_scope("embed"):
         x = _c(params["embed"]["table"].astype(dt)[tokens])
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+    # once a step, not once a layer and recompute: outside the remat
+    with jax.named_scope("attn_proj"):
+        table = rope_table(s, cfg.head_dim, cfg.rope_theta)
 
     def layer_fn(x, layer):
         h = _c(x + _attention_block(layer, _rmsnorm(
-            x, layer["ln1"]["scale"], cfg.rmsnorm_eps), cfg, positions, mesh))
+            x, layer["ln1"]["scale"], cfg.rmsnorm_eps), cfg, positions, mesh,
+            table))
         normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
         if cfg.n_experts > 0:
             with jax.named_scope("moe"):

@@ -1,0 +1,223 @@
+"""The boundary between the attention projections and the flash kernels,
+one pass a tensor and direction.
+
+A projection leaves its matmul as [batch, seq, heads * head_dim], lane
+dense; the kernels of ops/attention.py take [batch, heads, seq, head_dim].
+`rope_split` writes the second from the first, rotating on the way (rotary
+position embeddings, "rotate-half") where it is handed the table; its
+transpose `rope_merge` is its backward. Two Pallas kernels, `rope_split`
+and `rope_merge` (names in util/profiling.KERNELS).
+
+The head split is the output BlockSpec's index map, not a transpose, and
+the rotation is done in registers on whole 128-lane tiles (two heads of 64
+or one of 128; a wider head takes a multiple):
+
+    out = x * cos + select(first half of its head,
+                           roll(x, -D/2), roll(x, +D/2)) * sin
+
+with cos = [cos, cos] and sin = [-sin, sin] a head (`rope_table`): no
+slice, no concatenate and no D/2-wide array anywhere. Products and the sum
+are float32, rounded once to the input's dtype: bit for bit what the jnp
+formulation (`_split_reference`, models/gpt.py:_rope) gives. The backward
+is the same body with sin negated.
+
+Blocks follow from the shape (`_rope_blocks`); a shape that does not tile
+(a head width that neither divides nor is a multiple of 128, a head count
+that does not fill whole lane tiles, a ragged sequence) takes the jnp
+formulation.
+"""
+
+from __future__ import annotations
+
+import collections
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import LANES
+
+# (rows, cols) of the [seq, heads * head_dim] plane one grid step moves.
+_RopeBlocks = collections.namedtuple("_RopeBlocks", "rows cols")
+
+# Elements of the plane a grid step moves: 1 MB of bf16 in, twice that out
+# where a 64-wide head owns a 128-lane tile, the table beside them, two
+# buffers each: 8 MB of VMEM at most (1024 x 2048 does not fit the 16 MB a
+# kernel is given). On a v5e every block of at least 256 rows x a whole row
+# of lane tiles runs within 3 % of the best; 256 rows x one lane tile a
+# step takes 2.2 x as long (PERF.md, PR 28).
+_STEP_ELEMENTS = 512 * 1024
+
+
+def _lane_tile(head_dim: int) -> Optional[int]:
+    """Lanes the rotation works on at once: whole heads in whole 128-lane
+    tiles. None for a head width that fits neither way."""
+    if head_dim % 2 or (LANES % head_dim and head_dim % LANES):
+        return None
+    return max(head_dim, LANES)
+
+
+def _largest_divisor(n: int, unit: int, cap: int) -> int:
+    """The largest multiple of unit that divides n and is <= cap; unit
+    itself under a smaller cap (unit divides n)."""
+    return max(d for d in range(unit, max(min(cap, n), unit) + 1, unit)
+               if n % d == 0)
+
+
+def _rope_blocks(seq: int, heads: int, head_dim: int,
+                 itemsize: int) -> Optional[_RopeBlocks]:
+    """Blocks of both kernels, from the shape alone; None for a shape the
+    kernels do not tile (the caller then takes the jnp formulation)."""
+    tile = _lane_tile(head_dim)
+    sublanes = 32 // itemsize          # rows of one register: 8 fp32, 16 bf16
+    if (tile is None or (heads * head_dim) % tile or seq % sublanes
+            or itemsize not in (2, 4)):
+        return None
+    # every lane tile of a row where that leaves at least 256 rows a step
+    cols = _largest_divisor(heads * head_dim, tile, _STEP_ELEMENTS // 256)
+    rows = _largest_divisor(seq, sublanes, _STEP_ELEMENTS // cols)
+    return _RopeBlocks(rows, cols)
+
+
+def rope_table(seq: int, head_dim: int, theta: float):
+    """(cos, sin) of positions 0..seq-1 as the rotation multiplies them,
+    float32 [seq, W]: a head's columns are [cos, cos] and [-sin, sin], and
+    where heads share a 128-lane tile the head repeats to fill it (W =
+    128), so that the kernels load whole tiles."""
+    half = head_dim // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * freqs
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    repeat = (_lane_tile(head_dim) or head_dim) // head_dim
+    return (jnp.tile(jnp.concatenate([cos, cos], axis=1), (1, repeat)),
+            jnp.tile(jnp.concatenate([-sin, sin], axis=1), (1, repeat)))
+
+
+def _rotate(x, cos, sin, head_dim: int):
+    """x [rows, W] float32, whole heads side by side: x * cos + (the other
+    half of each head) * sin."""
+    width = x.shape[1]
+    half = head_dim // 2
+    if head_dim == width:
+        other = pltpu.roll(x, half, 1)      # by half a head: either way
+    else:
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        other = jnp.where(lane % head_dim < half,
+                          pltpu.roll(x, width - half, 1),   # x[l + half]
+                          pltpu.roll(x, half, 1))           # x[l - half]
+    return x * cos + other * sin
+
+
+def _split_kernel(*refs, head_dim, rotate):
+    """Grid (batch, seq block, column block): x [1, rows, cols] ->
+    out [1, cols / head_dim, rows, head_dim]."""
+    x_ref, o_ref = refs[0], refs[-1]
+    tile = _lane_tile(head_dim)
+    per_tile = tile // head_dim
+    for t in range(x_ref.shape[2] // tile):
+        y = x_ref[0, :, t * tile:(t + 1) * tile]
+        if rotate:
+            y = _rotate(y.astype(jnp.float32), refs[1][...], refs[2][...],
+                        head_dim).astype(o_ref.dtype)
+        for i in range(per_tile):
+            o_ref[0, t * per_tile + i] = y[:, i * head_dim:(i + 1) * head_dim]
+
+
+def _merge_kernel(*refs, head_dim, rotate):
+    """_split_kernel's transpose: g [1, cols / head_dim, rows, head_dim] ->
+    out [1, rows, cols], rotated back (sin negated)."""
+    g_ref, o_ref = refs[0], refs[-1]
+    tile = _lane_tile(head_dim)
+    per_tile = tile // head_dim
+    for t in range(o_ref.shape[2] // tile):
+        heads = [g_ref[0, t * per_tile + i] for i in range(per_tile)]
+        y = heads[0] if per_tile == 1 else jnp.concatenate(heads, axis=1)
+        if rotate:
+            y = _rotate(y.astype(jnp.float32), refs[1][...], -refs[2][...],
+                        head_dim).astype(o_ref.dtype)
+        o_ref[0, :, t * tile:(t + 1) * tile] = y
+
+
+_PARALLEL = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel"))
+
+
+def _call(split: bool, x, table, head_dim, blocks, interpret):
+    """rope_split (x [B, S, H*D]) or rope_merge (x [B, H, S, D]) over the
+    whole tensor, on a grid (batch, seq block, column block). The
+    [B, S, H*D] side moves in (1, rows, cols) blocks, the [B, H, S, D] side
+    in the same rows of cols / D heads."""
+    if split:
+        batch, seq, width = x.shape
+    else:
+        batch, seq, width = x.shape[0], x.shape[2], x.shape[1] * head_dim
+    rows, cols = blocks
+    flat = pl.BlockSpec((1, rows, cols), lambda b, i, j: (b, i, j))
+    by_head = pl.BlockSpec((1, cols // head_dim, rows, head_dim),
+                           lambda b, i, j: (b, j, i, 0))
+    row_spec = pl.BlockSpec((rows, table[0].shape[1]),
+                            lambda b, i, j: (i, 0)) if table else None
+    out_shape = ((batch, width // head_dim, seq, head_dim) if split
+                 else (batch, seq, width))
+    return pl.pallas_call(
+        functools.partial(_split_kernel if split else _merge_kernel,
+                          head_dim=head_dim, rotate=bool(table)),
+        grid=(batch, seq // rows, width // cols),
+        in_specs=[flat if split else by_head] + [row_spec] * len(table),
+        out_specs=by_head if split else flat,
+        out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
+        compiler_params=_PARALLEL,
+        interpret=interpret,
+        name="rope_split" if split else "rope_merge",
+    )(x, *table)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_split_fn(head_dim, blocks, interpret):
+    """rope_split with rope_merge as its backward; no residual but the
+    table."""
+
+    @jax.custom_vjp
+    def f(x, *table):
+        return _call(True, x, table, head_dim, blocks, interpret)
+
+    def fwd(x, *table):
+        return f(x, *table), table
+
+    def bwd(table, g):
+        dx = _call(False, g, table, head_dim, blocks, interpret)
+        return (dx, *(jnp.zeros_like(t) for t in table))
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def _split_reference(x, table, head_dim: int):
+    """The jnp formulation: reshape, transpose, rotate halves."""
+    b, s, width = x.shape
+    y = x.reshape(b, s, width // head_dim, head_dim).transpose(0, 2, 1, 3)
+    if not table:
+        return y
+    half = head_dim // 2
+    cos, sin = table[0][:, :half], table[1][:, half:head_dim]
+    y1, y2 = y[..., :half], y[..., half:]
+    return jnp.concatenate(
+        [y1 * cos - y2 * sin, y1 * sin + y2 * cos], axis=-1).astype(x.dtype)
+
+
+def rope_split(x, head_dim: int, table=(), *,
+               interpret: Optional[bool] = None):
+    """x [B, S, H * head_dim] -> [B, H, S, head_dim], rotated by `table`
+    (`rope_table` of S and head_dim) if it is given: a projection's output
+    as the flash kernels read it."""
+    _, seq, width = x.shape
+    blocks = _rope_blocks(seq, width // head_dim, head_dim, x.dtype.itemsize)
+    if blocks is None:
+        return _split_reference(x, table, head_dim)
+    if interpret is None:
+        interpret = attention._default_interpret()
+    return _make_split_fn(head_dim, blocks, interpret)(x, *table)

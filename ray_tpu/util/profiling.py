@@ -110,7 +110,7 @@ def stack_dump() -> Dict[str, str]:
 # ---------------------------------------------------------------------------
 
 # The scopes models/gpt.py and train/train_step.py open, and the names
-# ops/attention.py and ops/moe.py give their pallas_calls. An op belongs to
+# ops/attention.py, ops/moe.py and ops/rope.py give their pallas_calls. An op belongs to
 # the LAST of these on its op_name path:
 # `jit(_step)/loss_and_grad/jvp(mlp)/dot_general` is `mlp`, and what
 # `loss_and_grad` holds itself is the rest (residual adds, casts of the
@@ -122,7 +122,7 @@ REGIONS = ("embed", "attn_proj", "attn_core", "attn_out", "mlp", "moe",
            "moe_route", "norm", "head", "loss_and_grad", "grad_accum",
            "optimizer")
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
-           "moe_tgmm")
+           "moe_tgmm", "rope_split", "rope_merge")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:")

@@ -67,9 +67,44 @@ def test_flash_kernel_compiles_for_v5e(v5e, backward, shape):
     assert text.count("tpu_custom_call") >= (3 if backward else 1)
 
 
-def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch):
-    """GPT-2 small's attention block, forward and backward, under
-    tp_fsdp on fsdp=2 x tensor=2. Without the shard_map around the flash
+@pytest.mark.parametrize("shape", KERNEL_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "forward_backward"])
+def test_rope_kernels_compile_for_v5e(v5e, backward, shape):
+    """ops/rope.py's pair at the same shapes: a projection's [B, S, H*D]
+    into the flash kernels' [B, H, S, D] with the rotation, and back. What
+    interpret mode cannot see: the roll on a 128-lane tile, the store of a
+    64-wide head from a lane offset, the (1, heads, rows, 64) block."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.rope import rope_split, rope_table
+
+    batch, heads, seq, head_dim = shape
+
+    def fwd(x):
+        table = rope_table(seq, head_dim, 10000.0)
+        return rope_split(x, head_dim, table, interpret=False)
+
+    fn = fwd
+    if backward:
+        fn = jax.grad(lambda x: (fwd(x).astype(jnp.float32) ** 2).sum())
+    x = jax.ShapeDtypeStruct((batch, seq, heads * head_dim), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    text = jax.jit(fn).lower(x).compile().as_text()
+    assert "rope_split" in text and ("rope_merge" in text) == backward
+    assert text.count("tpu_custom_call") >= (2 if backward else 1)
+
+
+@pytest.mark.parametrize("widths,batch,seq", [
+    (dict(), SHAPE[0], SHAPE[2]),
+    (dict(d_model=2048, n_heads=32, max_seq=2048), 4, 2048)],
+    ids=["gpt2s_6_heads_a_shard", "smollm_16_heads_a_shard"])
+def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
+                                                    batch, seq):
+    """The attention block at GPT-2 small's and at SmolLM-1.7B's widths,
+    forward and backward, under tp_fsdp on fsdp=2 x tensor=2. Without the shard_map around the flash
     call the chip's compiler refuses it ("Mosaic kernels cannot be
     automatically partitioned"); the CPU tests cannot see that, because
     the interpreted kernel is plain XLA ops that GSPMD partitions."""
@@ -77,15 +112,15 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch):
     import jax.numpy as jnp
     from ray_tpu.models import gpt
     from ray_tpu.ops import attention
+    from ray_tpu.ops.rope import rope_table
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
     from ray_tpu.parallel.sharding import strategy_from_name
 
     # jax.default_backend() is the CPU here; take the kernel's TPU branch.
     monkeypatch.setattr(attention, "_default_interpret", lambda: False)
-    cfg = gpt.GPTConfig.gpt2_small()
+    cfg = gpt.GPTConfig(**widths)
     mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2), devices=v5e)
     strategy = strategy_from_name("tp_fsdp")
-    batch, seq = SHAPE[0], SHAPE[2]
 
     layer = jax.eval_shape(
         lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"][0]
@@ -98,13 +133,17 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch):
 
     def loss(layer, x):
         positions = jnp.broadcast_to(jnp.arange(seq)[None, :], (batch, seq))
-        out = gpt._attention_block(layer, x, cfg, positions, mesh)
+        table = rope_table(seq, cfg.head_dim, cfg.rope_theta)
+        out = gpt._attention_block(layer, x, cfg, positions, mesh, table)
         return out.astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         layer, x).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
+    # three flash kernels, and q, k, v through rope_split and rope_merge
+    # inside the same shard_map, whole heads a shard
+    assert text.count("tpu_custom_call") >= 9
+    assert "rope_split" in text and "rope_merge" in text
     # the tensor-parallel out projection and the fsdp weights need them
     assert "all-reduce" in text or "reduce-scatter" in text
     assert "all-gather" in text
