@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -50,13 +50,9 @@ class GPTConfig:
     expert_top_k: int = 2
     router_aux_loss_coef: float = 0.01
     router_z_loss_coef: float = 0.001
-    remat: bool = True
-    # Remat granularity: None -> "full" if remat else "none".
-    #   "full": recompute the whole layer in backward (min HBM, max FLOPs)
-    #   "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable —
-    #           weight-matmul outputs saved, elementwise recomputed
-    #   "none": save everything (max HBM, min FLOPs)
-    remat_policy: Optional[str] = None
+    # "full": recompute the whole layer in backward (min HBM, max FLOPs)
+    # "none": save everything (max HBM, min FLOPs)
+    remat_policy: str = "full"
     attention: str = "flash"          # flash | reference | ring
     tie_embeddings: bool = False
 
@@ -135,10 +131,45 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
     return params
 
 
-def _rmsnorm(x, scale, eps):
+def _whole(y):
+    """Setting.psum where no shard holds a part only."""
+    return y
+
+
+class Setting(NamedTuple):
+    """What a layer body is told about where it runs. The block is one
+    piece of arithmetic under GSPMD (gpt_backbone: weights of global shape,
+    the partitioner places the collectives) and inside a shard_map (a stage
+    of parallel/pipeline.py: local shards of the weights, so the head count
+    is read off their shapes, and collectives by hand). What differs:
+
+    mesh: how a Mosaic kernel call is entered (`_per_shard`): through a
+      shard_map over this mesh, or, with None, as it is, because there is
+      one device or the caller holds its shard already.
+    psum: how a sum is finished of which every shard of 'tensor' holds a
+      part: the two row-parallel matmuls (attn/wo, mlp/w_down) and the q/k
+      norm's mean square over column-parallel projections. Nothing to do
+      under GSPMD; `lax.psum(y, "tensor")` inside a shard_map.
+    act_sharding: the residual stream's NamedSharding under GSPMD (see
+      gpt_backbone), None inside a shard_map."""
+    mesh: Any = None
+    act_sharding: Any = None
+    psum: Callable = _whole
+
+    def pin(self, x):
+        if self.act_sharding is None:
+            return x
+        return jax.lax.with_sharding_constraint(x, self.act_sharding)
+
+
+def _rmsnorm(x, scale, eps, psum=_whole):
+    """psum: Setting.psum where the row is split over 'tensor' (scale then
+    holds this shard's columns); a whole row needs none."""
     with jax.named_scope("norm"):
         x32 = x.astype(jnp.float32)
-        var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+        # of a Python int, psum is the int times the shards: the row's width
+        var = (psum(jnp.sum(x32 * x32, axis=-1, keepdims=True))
+               / psum(x.shape[-1]))
         return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
@@ -155,14 +186,32 @@ def _rope(x, theta: float, positions):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
+def _per_shard(fn, mesh, in_dims, out_dims):
+    """fn, entered as a Mosaic kernel call has to be. On a TPU the Pallas
+    kernels are Mosaic custom calls, which GSPMD cannot partition, so under
+    a mesh of more than one device they run per shard, inside a shard_map:
+    batch over 'data' x 'fsdp', whole heads over 'tensor', anything else
+    handed whole, each device on its own slice with no collective. in_dims
+    (one tuple an operand) and out_dims say which dimension is which:
+    "batch", "heads" or None. With no mesh (one device, or a caller inside
+    a shard_map of its own) nothing is wrapped."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    axes = {"batch": ("data", "fsdp"), "heads": "tensor", None: None}
+
+    def spec(dims):
+        return P(*(axes[d] for d in dims))
+    # check_vma off: pallas_call declares no varying axes for its outputs,
+    # and the Pallas interpreter the CPU tests use fails the check inside.
+    return shard_map(fn, mesh=mesh, in_specs=tuple(map(spec, in_dims)),
+                     out_specs=spec(out_dims), check_vma=False)
+
+
 def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh):
     """The projections' outputs [B, S, H*D] through the head split, the
     rotation (ops/rope.py: one pass a tensor, straight into the kernels'
-    [B, H, S, D]) and the flash kernel. On a TPU these are Mosaic custom
-    calls, which GSPMD cannot partition, so under a mesh of more than one
-    device they run per shard: batch over 'data' x 'fsdp', whole heads (the
-    H*D columns) over 'tensor', each device attending its own (batch,
-    head) slice with no collective."""
+    [B, H, S, D]) and the flash kernel, per shard: the H*D columns are
+    whole heads, each device attends its own (batch, head) slice."""
     def split_and_attend(q, k, v, *table):
         with jax.named_scope("attn_proj"):
             q = rope_split(q, cfg.head_dim, table)
@@ -171,64 +220,60 @@ def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh):
         with jax.named_scope("attn_core"):
             return flash_attention(q, k, v, causal=True)
 
-    if mesh is None or mesh.size == 1:
-        return split_and_attend(q, k, v, *table)
-    columns = P(("data", "fsdp"), None, "tensor")
-    # check_vma off: pallas_call declares no varying axes for its outputs,
-    # and the Pallas interpreter the CPU tests use fails the check inside.
-    return shard_map(split_and_attend, mesh=mesh,
-                     in_specs=(columns, columns, columns, P(), P()),
-                     out_specs=P(("data", "fsdp"), "tensor", None, None),
-                     check_vma=False)(q, k, v, *table)
+    columns = ("batch", None, "heads")
+    return _per_shard(split_and_attend, mesh,
+                      (columns,) * 3 + ((),) * len(table),
+                      ("batch", "heads", None, None))(q, k, v, *table)
 
 
-def _attention_block(layer, x, cfg: GPTConfig, positions, mesh, table):
+def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting):
     """table: rope_table(S, head_dim, theta), built once a step by the
-    caller (gpt_backbone, outside its rematted layers). The flash path
-    alone reads it: 'reference' and 'ring' keep the jnp `_rope` on
-    [B, H, S, D] (the oracle, and ring's sequence shards need their global
-    positions)."""
-    b, s, d = x.shape
-    h, hd = cfg.n_heads, cfg.head_dim
+    caller (layer_fn, outside the remat). The flash path alone reads it:
+    'reference' and 'ring' keep the jnp `_rope` on [B, H, S, D] (the
+    oracle, and ring's sequence shards need their global positions)."""
+    b, s, _ = x.shape
+    hd = cfg.head_dim
     dt = cfg.dtype
 
     def proj(w, norm=None):
         y = jnp.einsum("bsd,de->bse", x, w.astype(dt))
         if norm is not None:
-            y = _rmsnorm(y, norm["scale"], cfg.rmsnorm_eps)
+            y = _rmsnorm(y, norm["scale"], cfg.rmsnorm_eps, where.psum)
         return y
 
     def heads(y):
-        return y.reshape(b, s, h, hd).transpose(0, 2, 1, 3)
+        return y.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
 
     with jax.named_scope("attn_proj"):
         q = proj(layer["attn"]["wq"], layer["attn"].get("q_norm"))
         k = proj(layer["attn"]["wk"], layer["attn"].get("k_norm"))
         v = proj(layer["attn"]["wv"])
     if cfg.attention not in ("ring", "reference"):
-        o = _flash_on_mesh(q, k, v, table, cfg, mesh)
+        o = _flash_on_mesh(q, k, v, table, cfg, where.mesh)
     else:
         with jax.named_scope("attn_proj"):
+            positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
             q = _rope(heads(q), cfg.rope_theta, positions)
             k = _rope(heads(k), cfg.rope_theta, positions)
             v = heads(v)
         with jax.named_scope("attn_core"):
             if cfg.attention == "ring":
-                o = ring_attention(q, k, v, mesh=mesh, causal=True)
+                o = ring_attention(q, k, v, mesh=where.mesh, causal=True)
             else:
                 o = mha_reference(q, k, v, causal=True)
     with jax.named_scope("attn_out"):
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, d)
-        return jnp.einsum("bsd,de->bse", o, layer["attn"]["wo"].astype(dt))
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        return where.psum(
+            jnp.einsum("bsd,de->bse", o, layer["attn"]["wo"].astype(dt)))
 
 
-def _mlp_block(layer, x, cfg: GPTConfig):
+def _mlp_block(layer, x, cfg: GPTConfig, where: Setting):
     dt = cfg.dtype
     m = layer["mlp"]
     gate = jnp.einsum("bsd,df->bsf", x, m["w_gate"].astype(dt))
     up = jnp.einsum("bsd,df->bsf", x, m["w_up"].astype(dt))
-    return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                      m["w_down"].astype(dt))
+    return where.psum(jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                                 m["w_down"].astype(dt)))
 
 
 def _route(m, x, cfg: GPTConfig):
@@ -280,24 +325,53 @@ def _moe_block(layer, x, cfg: GPTConfig, mesh):
     """Sparse experts in the MLP's place: y = sum over a token's top-k of
     p_e x down_e(silu(gate_e x) * up_e x). No capacity and no dropped
     token: every token-slot is computed, by its own expert only. The
-    grouped matmuls are Mosaic custom calls, which GSPMD cannot partition,
-    so under a mesh each device dispatches its own tokens (batch over
-    'data' x 'fsdp', as _flash_on_mesh) to all the experts, whose matrices
-    it is handed whole; the router and its losses stay outside, over the
-    whole batch."""
+    grouped matmuls run per shard (`_per_shard`): each device dispatches
+    its own tokens to all the experts, whose matrices it is handed whole;
+    the router and its losses stay outside, over the whole batch."""
     dt = cfg.dtype
     m = layer["moe"]
     with jax.named_scope("moe_route"):
         weights, idx, stats = _route(m, x, cfg)
     matrices = [m[name].astype(dt) for name in ("w_gate", "w_up", "w_down")]
-    if mesh is None or mesh.size == 1:
-        return _experts(x, weights, idx, *matrices), stats
-    tokens = P(("data", "fsdp"), None, None)
-    y = shard_map(_experts, mesh=mesh,
-                  in_specs=(tokens, tokens, tokens, P(), P(), P()),
-                  out_specs=tokens, check_vma=False)(
-                      x, weights, idx, *matrices)
+    tokens = ("batch", None, None)
+    y = _per_shard(_experts, mesh, (tokens,) * 3 + ((),) * 3, tokens)(
+        x, weights, idx, *matrices)
     return y, stats
+
+
+def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
+    """(x [B, seq, D], one layer's parameters) -> (x, the router's
+    statistics: _route's dict for a sparse layer, {} for a dense one). The
+    one transformer block, rematted as cfg.remat_policy says, for whoever
+    walks the layers: gpt_backbone loops over their list, a stage of
+    parallel/pipeline.py scans over stacked ones."""
+    # once a step, not once a layer and recompute: outside the remat
+    with jax.named_scope("attn_proj"):
+        table = rope_table(seq, cfg.head_dim, cfg.rope_theta)
+
+    def block(x, layer):
+        h = where.pin(x + _attention_block(layer, _rmsnorm(
+            x, layer["ln1"]["scale"], cfg.rmsnorm_eps), cfg, table, where))
+        normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
+        if cfg.n_experts > 0:
+            with jax.named_scope("moe"):
+                delta, stats = _moe_block(layer, normed, cfg, where.mesh)
+        else:
+            with jax.named_scope("mlp"):
+                delta, stats = _mlp_block(layer, normed, cfg, where), {}
+        return where.pin(h + delta), stats
+
+    if cfg.remat_policy == "full":
+        return jax.checkpoint(block)
+    if cfg.remat_policy != "none":
+        raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
+                         "(expected 'full' | 'none')")
+    return block
+
+
+def final_norm(params, x, cfg: GPTConfig):
+    """The last layer's output -> what the head reads."""
+    return _rmsnorm(x, params["final_norm"]["scale"], cfg.rmsnorm_eps)
 
 
 def gpt_forward(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
@@ -326,52 +400,17 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     activation gradients (the "involuntary full rematerialization" failure
     mode on 2D tp_fsdp meshes).
     """
-    b, s = tokens.shape
-    dt = cfg.dtype
-
-    def _c(x):
-        if act_sharding is None:
-            return x
-        return jax.lax.with_sharding_constraint(x, act_sharding)
-
+    where = Setting(mesh, act_sharding)
     with jax.named_scope("embed"):
-        x = _c(params["embed"]["table"].astype(dt)[tokens])
-    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-    # once a step, not once a layer and recompute: outside the remat
-    with jax.named_scope("attn_proj"):
-        table = rope_table(s, cfg.head_dim, cfg.rope_theta)
-
-    def layer_fn(x, layer):
-        h = _c(x + _attention_block(layer, _rmsnorm(
-            x, layer["ln1"]["scale"], cfg.rmsnorm_eps), cfg, positions, mesh,
-            table))
-        normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
-        if cfg.n_experts > 0:
-            with jax.named_scope("moe"):
-                delta, stats = _moe_block(layer, normed, cfg, mesh)
-        else:
-            with jax.named_scope("mlp"):
-                delta, stats = _mlp_block(layer, normed, cfg), {}
-        return _c(h + delta), stats
-
-    policy = cfg.remat_policy or ("full" if cfg.remat else "none")
-    if policy == "full":
-        layer_fn = jax.checkpoint(layer_fn)
-    elif policy == "dots":
-        layer_fn = jax.checkpoint(
-            layer_fn,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    elif policy != "none":
-        raise ValueError(f"unknown remat_policy {policy!r} "
-                         "(expected 'full' | 'dots' | 'none')")
+        x = where.pin(params["embed"]["table"].astype(cfg.dtype)[tokens])
+    layer = layer_fn(cfg, tokens.shape[1], where)
     per_layer = []
-    for layer in params["layers"]:
-        x, stats = layer_fn(x, layer)
+    for layer_params in params["layers"]:
+        x, stats = layer(x, layer_params)
         per_layer.append(stats)
     router = jax.tree_util.tree_map(
         lambda *layers: sum(layers) / len(layers), *per_layer)
-    return _rmsnorm(x, params["final_norm"]["scale"], cfg.rmsnorm_eps), \
-        router
+    return final_norm(params, x, cfg), router
 
 
 def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
@@ -412,6 +451,22 @@ def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
     return total, denom
 
 
+def head_xent(params, x, targets, cfg: GPTConfig):
+    """x: the final hidden states [B, S, D] (after final_norm), targets
+    [B, S] with negatives left out -> (sum of the next-token
+    cross-entropies, how many). The LM-head matmul + softmax run chunked
+    (chunked_xent) so the full fp32 logits tensor never exists in HBM."""
+    b, s, d = x.shape
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            w_head = params["embed"]["table"].astype(cfg.dtype).T
+        else:
+            w_head = params["lm_head"].astype(cfg.dtype)
+        mask = (targets >= 0).astype(jnp.float32)
+        return chunked_xent(x.reshape(b * s, d), w_head,
+                            targets.reshape(b * s), mask.reshape(b * s))
+
+
 def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
                      act_sharding=None):
     """batch: {"tokens": [B, S+1]} -> (loss, aux): the mean next-token
@@ -419,25 +474,11 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
     configuration's weights; aux holds the cross-entropy alone ("xent")
     and the router's statistics (the two losses unweighted, the largest
     expert's load over the mean), for a step written with
-    jax.value_and_grad(..., has_aux=True).
-
-    The LM-head matmul + softmax run chunked (chunked_xent) so the full
-    fp32 logits tensor never exists in HBM.
-    """
+    jax.value_and_grad(..., has_aux=True)."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     x, router = gpt_backbone(params, inputs, cfg, mesh, act_sharding)
-    b, s, d = x.shape
-    dt = cfg.dtype
-    with jax.named_scope("head"):
-        if cfg.tie_embeddings:
-            w_head = params["embed"]["table"].astype(dt).T
-        else:
-            w_head = params["lm_head"].astype(dt)
-        mask = (targets >= 0).astype(jnp.float32)
-        total, denom = chunked_xent(x.reshape(b * s, d), w_head,
-                                    targets.reshape(b * s),
-                                    mask.reshape(b * s))
+    total, denom = head_xent(params, x, targets, cfg)
     loss = xent = total / jnp.maximum(denom, 1.0)
     if cfg.n_experts > 0:
         loss = (xent

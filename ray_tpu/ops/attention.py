@@ -86,7 +86,7 @@ LANES = 128
 _FlashBlocks = collections.namedtuple("_FlashBlocks", "fwd dq dkv")
 
 
-def _divisor(n: int, cap: int) -> int:
+def lane_divisor(n: int, cap: int) -> int:
     """The largest multiple of 128 that divides n and is <= cap; n itself
     below 128 (one block)."""
     if n < LANES:
@@ -113,11 +113,11 @@ def _block_sizes(seq_q: int, seq_k: int, head_dim: int) -> _FlashBlocks:
     # grid step where it fits. Row groups of 256 for the forward, whose
     # per-row statistics want a wide tile, and of 128 for the backward
     # kernels, which have none and two score-sized products a tile.
-    square = _divisor(math.gcd(seq_q, seq_k),
-                      2048 if head_dim <= LANES else 1024)
-    return _FlashBlocks(fwd=(square, square, _divisor(square, 256)),
-                        dq=(square, square, _divisor(square, LANES)),
-                        dkv=(square, square, _divisor(square, LANES)))
+    square = lane_divisor(math.gcd(seq_q, seq_k),
+                          2048 if head_dim <= LANES else 1024)
+    return _FlashBlocks(fwd=(square, square, lane_divisor(square, 256)),
+                        dq=(square, square, lane_divisor(square, LANES)),
+                        dkv=(square, square, lane_divisor(square, LANES)))
 
 
 def _lanes(x, n: int):

@@ -35,7 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import attention
-from ray_tpu.ops.attention import _divisor
+from ray_tpu.ops.attention import lane_divisor
 
 # One weight block in VMEM (it is double-buffered): a whole 2048 x 1024
 # bf16 expert matrix, so the rows are read once and each matrix once a group.
@@ -190,7 +190,8 @@ def _gmm(x, w, plan: Plan, transposed: bool, interpret: bool):
     n = w.shape[1] if transposed else w.shape[2]
     tiles = plan.tile_group.shape[0]
     tm = m // tiles
-    tn = _divisor(n, max(128, _WEIGHT_BLOCK_BYTES // (kdim * w.dtype.itemsize)))
+    tn = lane_divisor(
+        n, max(128, _WEIGHT_BLOCK_BYTES // (kdim * w.dtype.itemsize)))
     if transposed:
         w_spec = pl.BlockSpec(
             (1, tn, kdim), lambda j, i, grp, used: (grp[_used(i, used)], j, 0))
@@ -249,8 +250,8 @@ def _tgmm(x, g, plan: Plan, n_groups: int, out_dtype, interpret: bool):
     n = g.shape[1]
     tiles = plan.tile_group.shape[0]
     tm = m // tiles
-    tk = _divisor(kdim, 1024)
-    tn = _divisor(n, 1024)
+    tk = lane_divisor(kdim, 1024)
+    tn = lane_divisor(n, 1024)
     return pl.pallas_call(
         _tgmm_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

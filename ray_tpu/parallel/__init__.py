@@ -1,3 +1,4 @@
+from ray_tpu.dag.stage_pipeline import StagePipeline
 from ray_tpu.parallel.mesh import (MeshConfig, build_mesh, get_slice_info,
                                    fake_mesh)
 from ray_tpu.parallel.sharding import (ShardingRules, ShardingStrategy,
@@ -9,12 +10,3 @@ __all__ = [
     "ShardingRules", "ShardingStrategy", "shard_params", "batch_sharding",
     "strategy_from_name", "StagePipeline",
 ]
-
-
-def __getattr__(name):
-    # Lazy: StagePipeline pulls in the model stack via pipeline.py; the
-    # common mesh/sharding import path must not pay for it.
-    if name == "StagePipeline":
-        from ray_tpu.parallel.pipeline import StagePipeline
-        return StagePipeline
-    raise AttributeError(name)

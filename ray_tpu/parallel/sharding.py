@@ -151,6 +151,9 @@ class ShardingStrategy:
         pl = "pipeline"
         rules = ShardingRules(rules=[
             (r"stacked/attn/(wq|wk|wv)", P(pl, None, t)),
+            # the q/k norm runs over the projection's columns: its scale
+            # is cut as they are
+            (r"stacked/attn/(q|k)_norm", P(pl, t)),
             (r"stacked/attn/wo", P(pl, t, None)),
             (r"stacked/mlp/(w_gate|w_up)", P(pl, None, t)),
             (r"stacked/mlp/w_down", P(pl, t, None)),

@@ -1,12 +1,12 @@
-"""bench_smoke: a <60 s subset of bench.py covering the fan-in rows.
+"""bench_smoke: the core runtime's fan-in rows in <60 s, on any host.
 
 Runs the three control-plane shapes that collapse under multi-client
 load — multi-client task bursts, n:n actor calls, and placement-group
 create/remove — scaled down so the whole script finishes in well under a
-minute on a 1-vCPU box. Prints ONE JSON line using the same row names as
-bench.py (multi_client_tasks_async, n_n_actor_calls, pg_create_ms,
-pg_remove_ms), so perf PRs get a cheap directional signal without the
-full bench. Wired into tier-1 as a completion-only sanity test
+minute on a 1-vCPU box. Prints ONE JSON line (multi_client_tasks_async,
+n_n_actor_calls, pg_create_ms, pg_remove_ms, ...), so perf PRs get a cheap
+directional signal for the control plane; none of it is a device metric
+(benchmark/ is the benchmark). Wired into tier-1 as a completion-only sanity test
 (tests/test_bench_smoke.py): the numbers are printed, never asserted —
 a loaded CI box must not fail the suite on throughput noise.
 """
@@ -339,8 +339,8 @@ def main() -> dict:
     # the delta-frame MetricsAgent shipping every 0.5 s and one with
     # shipping fully off, plus the driver agent's own per-frame wire
     # cost. The overhead pct is tier-1-bounded (generously — CI noise)
-    # in tests/test_bench_smoke.py; the acceptance <= 2% bound is judged
-    # on the recorded BENCH_r*.json from an idle box.
+    # in tests/test_bench_smoke.py; the acceptance <= 2% bound needs an
+    # idle box.
     try:
         out.update(_telemetry_phase())
     except Exception as e:  # noqa: BLE001 — smoke must finish
@@ -349,7 +349,7 @@ def main() -> dict:
     # --- launch storm: cold vs warm actor creation on a 3-node fake ---
     # The fleet-scale launch row: a cold storm (pools at their base
     # floor) and a warm storm (prestart-hinted pools) of actor creates
-    # on the same bench.py topology, with the spawn-phase span breakdown
+    # on the same 3-node topology, with the spawn-phase span breakdown
     # (actor:spawn / actor:register / actor:ctor) proving where the time
     # went. The warm rate is tier-1-asserted against a conservative
     # floor (tests/test_bench_smoke.py) so the 0.05x row can't silently
@@ -1032,7 +1032,7 @@ def _launch_storm_phase() -> dict:
                         timeout=200)
             return n / (time.perf_counter() - t0), t_wall
 
-        # Cold-ish storm first (bench.py's exact shape: 8 warmed, then
+        # Cold-ish storm first (8 warmed, then
         # 40 creates against pools at their base prestart floor).
         warm8 = [Tiny.remote() for _ in range(8)]
         ray_tpu.get([a.ready.remote() for a in warm8], timeout=120)

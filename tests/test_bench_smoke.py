@@ -1,9 +1,8 @@
 """Tier-1 sanity run of scripts/bench_smoke.py.
 
 Completion-only: the smoke bench must run end to end and print one JSON
-line with the three fan-in rows (same names as bench.py). Throughput is
-NEVER asserted here — CI boxes are noisy; perf acceptance lives in the
-full bench. What this buys tier-1 is a cheap end-to-end drive of the
+line with the three fan-in rows. Throughput is NEVER asserted here — CI
+boxes are noisy. What this buys tier-1 is a cheap end-to-end drive of the
 batched control-plane paths (multi-driver fan-in, n:n actors, push-based
 PG readiness) in one subprocess.
 """
@@ -38,7 +37,6 @@ def test_bench_smoke_completes(jax_cpu):
     assert lines, proc.stdout
     row = json.loads(lines[-1])
     assert row.get("smoke") is True
-    # Same row names as bench.py so numbers are comparable by eye.
     # serve_requests_dropped is the serve-trajectory row: its presence
     # proves the serve request path (deploy, route, admission control)
     # ran end to end in the smoke.
@@ -57,8 +55,7 @@ def test_bench_smoke_completes(jax_cpu):
         assert key in row, (key, row)
     assert row["put_get_zero_copy"] is True, row
     # Serve large-body A/B (plane vs forced-inline): presence only —
-    # the p99 improvement is judged on the recorded BENCH_r*.json from
-    # an idle box, not under CI load.
+    # the p99 improvement needs an idle box, not CI load.
     for key in ("serve_lb_p99_ms", "serve_lb_inline_p99_ms",
                 "serve_lb_p99_speedup"):
         assert key in row, (key, row)
@@ -96,8 +93,7 @@ def test_bench_smoke_completes(jax_cpu):
     # post-recovery tick and the post/pre steady-state rate ratio.
     # Presence + a loose ratio floor are asserted (the recovery RAN and
     # the recovered pipeline is not degenerate); the 10%-of-pre-kill
-    # acceptance ratio is judged on the recorded BENCH_r*.json from an
-    # idle box, not under CI load.
+    # acceptance ratio needs an idle box, not CI load.
     for key in ("dag_recovery_ms", "dag_pre_kill_ticks_per_s",
                 "dag_post_recovery_ticks_per_s",
                 "dag_post_recovery_ratio", "dag_replayed_ticks"):
@@ -152,8 +148,8 @@ def test_bench_smoke_completes(jax_cpu):
     # Telemetry A/B (ISSUE 18): delta-frame shipping on vs off on fresh
     # clusters. Frames must actually have shipped (and stay small —
     # steady-state deltas are a few hundred bytes, not re-sent
-    # catalogs). The acceptance <= 2% overhead bound is judged on the
-    # recorded BENCH_r*.json from an idle box; here the bound is set at
+    # catalogs). The acceptance <= 2% overhead bound needs an idle
+    # box; here the bound is set at
     # the box's measured run-to-run burst noise so only a gross
     # regression (per-request shipping work) can trip it.
     for key in ("telemetry_off_rate", "telemetry_on_rate",

@@ -132,9 +132,8 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
                              sharding=strategy.activation_sharding(mesh))
 
     def loss(layer, x):
-        positions = jnp.broadcast_to(jnp.arange(seq)[None, :], (batch, seq))
         table = rope_table(seq, cfg.head_dim, cfg.rope_theta)
-        out = gpt._attention_block(layer, x, cfg, positions, mesh, table)
+        out = gpt._attention_block(layer, x, cfg, table, gpt.Setting(mesh))
         return out.astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(

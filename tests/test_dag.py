@@ -421,9 +421,9 @@ class TestCompiledDagSubsystem:
 
     @pytest.mark.timeout(120)
     def test_stage_pipeline_proof_workload(self, ray_shared):
-        """parallel.pipeline.StagePipeline: the MPMD stage graph compiled
+        """dag.stage_pipeline.StagePipeline: the MPMD stage graph compiled
         onto the substrate — pipelined map, order preserved."""
-        from ray_tpu.parallel.pipeline import StagePipeline
+        from ray_tpu.dag.stage_pipeline import StagePipeline
 
         @ray_shared.remote
         class Stage:
@@ -738,7 +738,7 @@ class TestCompiledDagRecovery:
         import time as _time
 
         from ray_tpu._private import worker_api
-        from ray_tpu.parallel.pipeline import StagePipeline
+        from ray_tpu.dag.stage_pipeline import StagePipeline
 
         @ray_start.remote(max_restarts=-1)
         class Stage:

@@ -139,7 +139,7 @@ def test_soak_under_dag_executor_killer(ray_cluster):
     in this module, not test_dag.py: the killer needs the fake Cluster,
     which cannot coexist with that module's shared single-node init.)"""
     import ray_tpu
-    from ray_tpu.parallel.pipeline import StagePipeline
+    from ray_tpu.dag.stage_pipeline import StagePipeline
     from ray_tpu.util.chaos import DagExecutorKiller, run_with_chaos
 
     ray_cluster.add_node(num_cpus=2)

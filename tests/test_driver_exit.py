@@ -6,7 +6,7 @@ gcs_actor_manager.h OnWorkerDead (its non-detached actors die with it;
 detached actors survive). Regression tests for the round-5 bug where
 every exiting driver (clean or crashed) leaked its active leases: three
 departed drivers pinned a 4-CPU node at 0 available CPUs forever (found
-by bench.py's multi-client phase wedging the 10k-args probe).
+by a multi-client benchmark phase wedging a 10k-args probe).
 """
 
 import os
