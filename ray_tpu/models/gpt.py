@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Callable, Dict, NamedTuple
 
 import jax
@@ -413,16 +414,9 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     return final_norm(params, x, cfg), router
 
 
-def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
-    """Next-token cross-entropy WITHOUT materializing full [N, vocab] fp32
-    logits (12.8 GB at bs=64/seq=1024/vocab=50k — an HBM-capacity bug for
-    any capacity-size batch). Rows are processed in chunks under
-    jax.checkpoint, so the backward recomputes each chunk's logits instead
-    of saving them. TPU-native analogue of fused linear+cross-entropy.
-
-    x: [N, D] (model dtype), w_head: [D, V], targets: [N] int32,
-    mask: [N] fp32. Returns (sum_nll, sum_mask).
-    """
+def _xent_chunks(x, targets, mask, chunk_rows):
+    """Rows [N, ...] -> chunks [n_chunks, chunk_rows, ...], padded with
+    masked-out rows."""
     n, d = x.shape
     # Never chunk coarser than the batch itself: padding a small batch up
     # to a full 16k-row chunk would both waste LM-head FLOPs and raise the
@@ -434,21 +428,121 @@ def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
         targets = jnp.pad(targets, (0, pad))
         mask = jnp.pad(mask, (0, pad))
     n_chunks = (n + pad) // chunk_rows
-    xc = x.reshape(n_chunks, chunk_rows, d)
-    tc = targets.reshape(n_chunks, chunk_rows)
-    mc = mask.reshape(n_chunks, chunk_rows)
+    return (x.reshape(n_chunks, chunk_rows, d),
+            targets.reshape(n_chunks, chunk_rows),
+            mask.reshape(n_chunks, chunk_rows))
+
+
+def _chunk_nll(xk, w_head, tk):
+    """One chunk's fp32 logits [chunk, V], their logsumexp and the rows'
+    negative log-likelihoods [chunk]: the arithmetic both formulations of
+    the loss share, so that they agree to the last bit."""
+    logits = xk @ w_head
+    # The target's logit is read from the matmul's result in the model
+    # dtype: the value the logsumexp sees. Read after the cast, XLA writes
+    # an fp32 copy of the logits to HBM for the gather alone (3.3 GB a
+    # 16 384-row chunk at a 50k vocabulary: it was the dense step's peak)
+    # and on the TPU fills it with the matmul's unrounded accumulator, so
+    # the two reads differ by one bf16 rounding a row (PERF.md, PR 30).
+    picked = jnp.take_along_axis(logits, tk[:, None], axis=-1)[:, 0]
+    logits = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    return logits, lse, lse - picked.astype(jnp.float32)
+
+
+def chunked_xent_recompute(x, w_head, targets, mask, chunk_rows: int = 16384):
+    """chunked_xent differentiated by autodiff: each chunk's body is under
+    jax.checkpoint, so the backward computes the chunk's logits and their
+    logsumexp a second time (four vocabulary matmuls a chunk) and a
+    differentiated call saves nothing but its inputs. For a caller that is
+    differentiated INSIDE a scan (parallel/pipeline.py's last rank, once a
+    tick): there chunked_xent's residuals would be saved once an iteration,
+    w_head's fp32 gradient among them."""
+    xc, tc, mc = _xent_chunks(x, targets, mask, chunk_rows)
 
     @jax.checkpoint
     def body(carry, args):
         xk, tk, mk = args
-        logits = (xk @ w_head).astype(jnp.float32)       # [chunk, V]
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, tk[:, None], axis=-1)[:, 0]
-        nll = lse - picked
+        _, _, nll = _chunk_nll(xk, w_head, tk)
         return (carry[0] + jnp.sum(nll * mk), carry[1] + jnp.sum(mk)), None
 
     (total, denom), _ = jax.lax.scan(body, (0.0, 0.0), (xc, tc, mc))
     return total, denom
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
+    """Next-token cross-entropy WITHOUT materializing full [N, vocab] fp32
+    logits (12.8 GB at bs=64/seq=1024/vocab=50k — an HBM-capacity bug for
+    any capacity-size batch): rows go through the head in chunks of a scan.
+    TPU-native analogue of fused linear+cross-entropy.
+
+    x: [N, D] (model dtype), w_head: [D, V], targets: [N] int32,
+    mask: [N] fp32. Returns (sum_nll, sum_mask).
+
+    Differentiated, the same scan takes the gradient while the chunk's
+    logits are there (_chunked_xent_fwd): p = (softmax - onehot) * mask
+    rounded to the model dtype, where autodiff rounds d logits, then
+    dx_k = p @ w_head.T and dW += xk.T @ p, both accumulated in fp32. That
+    is three vocabulary matmuls a chunk and one softmax; no jax.checkpoint,
+    no second logits matmul. The residuals are dx [N, D] (x's dtype),
+    dW [D, V] (fp32) and the rows' nll [N], and the backward rule scales
+    them by the cotangents, so nothing of a chunk outlives its iteration.
+    Evaluated only, no gradient is computed. Inside a differentiated scan
+    use chunked_xent_recompute (its docstring says why)."""
+    return chunked_xent_recompute(x, w_head, targets, mask, chunk_rows)
+
+
+def _chunked_xent_fwd(x, w_head, targets, mask, chunk_rows):
+    xc, tc, mc = _xent_chunks(x, targets, mask, chunk_rows)
+    vocab = w_head.shape[1]
+
+    def body(carry, args):
+        total, denom, dw = carry
+        xk, tk, mk = args
+        logits, lse, nll = _chunk_nll(xk, w_head, tk)
+        onehot = tk[:, None] == jnp.arange(vocab, dtype=tk.dtype)
+        p = ((jnp.exp(logits - lse[:, None]) - onehot) * mk[:, None]
+             ).astype(xk.dtype)
+        dxk = jnp.einsum("nv,dv->nd", p, w_head,
+                         preferred_element_type=jnp.float32).astype(xk.dtype)
+        dw = dw + jnp.einsum("nd,nv->dv", xk, p,
+                             preferred_element_type=jnp.float32)
+        return (total + jnp.sum(nll * mk), denom + jnp.sum(mk), dw), (dxk, nll)
+
+    (total, denom, dw), (dx, nll) = jax.lax.scan(
+        body, (0.0, 0.0, jnp.zeros(w_head.shape, jnp.float32)), (xc, tc, mc))
+    n = x.shape[0]
+    # (a residual has to be an array: w_head's dtype rides on an empty one)
+    return (total, denom), (dx.reshape(-1, x.shape[1])[:n], dw,
+                            nll.reshape(-1)[:n],
+                            jnp.zeros((0,), w_head.dtype))
+
+
+def _chunked_xent_bwd(chunk_rows, residuals, cotangents):
+    dx, dw, nll, w_like = residuals
+    g_total, g_denom = cotangents
+    return ((g_total * dx).astype(dx.dtype),
+            (g_total * dw).astype(w_like.dtype),
+            np.zeros(nll.shape, jax.dtypes.float0),     # targets: integers
+            g_total * nll + g_denom)
+
+
+chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)
+
+
+def _head_operands(params, x, targets, cfg: GPTConfig):
+    """head_xent's arguments as chunked_xent's: rows, the head's matrix in
+    the model dtype (the embedding table's transpose if tied), targets and
+    the mask that leaves negative targets out."""
+    b, s, d = x.shape
+    if cfg.tie_embeddings:
+        w_head = params["embed"]["table"].astype(cfg.dtype).T
+    else:
+        w_head = params["lm_head"].astype(cfg.dtype)
+    mask = (targets >= 0).astype(jnp.float32)
+    return (x.reshape(b * s, d), w_head, targets.reshape(b * s),
+            mask.reshape(b * s))
 
 
 def head_xent(params, x, targets, cfg: GPTConfig):
@@ -456,15 +550,16 @@ def head_xent(params, x, targets, cfg: GPTConfig):
     [B, S] with negatives left out -> (sum of the next-token
     cross-entropies, how many). The LM-head matmul + softmax run chunked
     (chunked_xent) so the full fp32 logits tensor never exists in HBM."""
-    b, s, d = x.shape
     with jax.named_scope("head"):
-        if cfg.tie_embeddings:
-            w_head = params["embed"]["table"].astype(cfg.dtype).T
-        else:
-            w_head = params["lm_head"].astype(cfg.dtype)
-        mask = (targets >= 0).astype(jnp.float32)
-        return chunked_xent(x.reshape(b * s, d), w_head,
-                            targets.reshape(b * s), mask.reshape(b * s))
+        return chunked_xent(*_head_operands(params, x, targets, cfg))
+
+
+def head_xent_recompute(params, x, targets, cfg: GPTConfig):
+    """head_xent over chunked_xent_recompute: the same loss to the bit, for
+    a caller inside a differentiated scan."""
+    with jax.named_scope("head"):
+        return chunked_xent_recompute(
+            *_head_operands(params, x, targets, cfg))
 
 
 def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,

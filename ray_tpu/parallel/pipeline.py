@@ -13,7 +13,8 @@ loss.
 
 The schedule only: what a stage computes is models/gpt.py's layer body,
 scanned over the stage's layers, and the last rank's loss is its
-head_xent. Composes with data parallel (batch sharded over 'data') and
+head_xent_recompute (the formulation that saves nothing a tick: the
+schedule differentiates a scan over ticks). Composes with data parallel (batch sharded over 'data') and
 tensor parallel (Megatron column/row sharding inside each stage: the
 parameters arrive as ShardingStrategy.pp_tp()'s rules cut them, and the
 body is told to psum over 'tensor').
@@ -32,8 +33,8 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.models.gpt import (GPTConfig, Setting, final_norm, head_xent,
-                                layer_fn)
+from ray_tpu.models.gpt import (GPTConfig, Setting, final_norm,
+                                head_xent_recompute, layer_fn)
 from ray_tpu.parallel.sharding import ShardingStrategy
 
 
@@ -116,8 +117,8 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
                 targets_mb, jnp.clip(out_idx, 0, M - 1), 0, keepdims=False)
             ls, lc = lax.cond(
                 valid,
-                lambda: head_xent(params, final_norm(params, y, cfg), tgt,
-                                  cfg),
+                lambda: head_xent_recompute(
+                    params, final_norm(params, y, cfg), tgt, cfg),
                 lambda: (jnp.float32(0), jnp.float32(0)))
             send = lax.ppermute(
                 y, "pipeline",

@@ -148,6 +148,72 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
     assert "all-gather" in text
 
 
+@pytest.mark.parametrize("widths,batch,seq,four_chips,parent_temp", [
+    (dict(), 64, 1024, False, 4_946_158_080),
+    (dict(d_model=2048, n_heads=16, max_seq=4096), 2, 4096, False,
+     2_472_800_256),
+    (dict(d_model=2048, n_heads=32, max_seq=2048, vocab_size=49152,
+          tie_embeddings=True), 32, 2048, True, 1_527_926_272)],
+    ids=["gpt2s_65536x768x50304", "olmoe_8192x2048x50304",
+         "smollm_tied_fsdp2_tensor2"])
+def test_head_compiles_with_three_vocabulary_matmuls(v5e, widths, batch, seq,
+                                                     four_chips, parent_temp):
+    """head_xent's value and gradient at each train cell's shape, the
+    four-chip one under tp_fsdp on fsdp=2 x tensor=2 with the tied table:
+    three matmuls over the vocabulary where autodiff through the rematted
+    body compiled to four, no collective it did not have, and temporaries
+    no larger than that body's (parent_temp: the same function compiled
+    here before the head's gradient moved into its forward pass; the fp32
+    logits of a chunk were most of it)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.models import gpt
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+
+    cfg = gpt.GPTConfig(**widths)
+    if cfg.tie_embeddings:
+        params = {"embed": {"table": jax.ShapeDtypeStruct(
+            (cfg.vocab_size, cfg.d_model), jnp.float32)}}
+    else:
+        params = {"lm_head": jax.ShapeDtypeStruct(
+            (cfg.d_model, cfg.vocab_size), jnp.float32)}
+    if four_chips:
+        mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2), devices=v5e)
+        strategy = strategy_from_name("tp_fsdp")
+        param_sh = strategy.param_shardings(mesh, params)
+        act = strategy.activation_sharding(mesh)
+        rows = NamedSharding(mesh, strategy.batch_spec)
+        scalar = NamedSharding(mesh, P())
+    else:
+        act = rows = scalar = SingleDeviceSharding(v5e[0])
+        param_sh = jax.tree_util.tree_map(lambda _: act, params)
+    params = jax.tree_util.tree_map(
+        lambda leaf, sh: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=sh), params, param_sh)
+    x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype,
+                             sharding=act)
+    targets = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=rows)
+
+    def loss(params, x, targets):
+        total, denom = gpt.head_xent(params, x, targets, cfg)
+        return total / jnp.maximum(denom, 1.0)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1)),
+                       out_shardings=(scalar, (param_sh, act))
+                       ).lower(params, x, targets).compile()
+    text = compiled.as_text()
+    assert text.count(" convolution(") == 3
+    assert "rematted_computation" not in text
+    assert "involuntary full rematerialization" not in text.lower()
+    # x's chunk is gathered once an iteration; the body that recomputed
+    # gathered it in the backward's scan again
+    assert text.count(" all-gather(") == (3 if four_chips else 0)
+    assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp
+
+
 def test_grouped_matmul_kernels_compile_for_v5e(v5e):
     """olmoe_train_1chip's two grouped-matmul kernels, forward and both
     gradients, at the cell's shape: 2 x 4096 tokens x 8 experts a token in
