@@ -5,7 +5,10 @@ ray_tpu.parallel.sharding rules: `layers/<i>/attn/wq`, `mlp/w_up`,
 `embed/table`, `lm_head`, `moe/...`. Design choices for the MXU/HBM:
 bfloat16 activations + params with fp32 softmax/layernorm accumulation,
 flash-attention Pallas kernel, optional ring attention (sequence sharded),
-optional sparse experts (top-k routing with real dispatch: ops/moe.py),
+optional sparse experts (top-k routing with real dispatch: ops/moe.py;
+softmax or sigmoid scores, a selection bias, shared experts, leading dense
+layers, and one chip's share of the experts: `experts_held`), optional
+latent attention (a low-rank k/v projection, q.k wider than v),
 per-layer jax.checkpoint (remat) for memory.
 
 Capability parity target: the models RLlib/Train wrap in the reference are
@@ -17,7 +20,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Callable, Dict, NamedTuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +29,8 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import moe
-from ray_tpu.ops.attention import flash_attention, mha_reference, ring_attention
+from ray_tpu.ops.attention import (flash_attention, mha_reference,
+                                   qk_padding, ring_attention)
 from ray_tpu.ops.rope import rope_split, rope_table
 
 
@@ -51,6 +55,44 @@ class GPTConfig:
     expert_top_k: int = 2
     router_aux_loss_coef: float = 0.01
     router_z_loss_coef: float = 0.001
+    # The second routing rule: "sigmoid" scores each expert on its own (no
+    # softmax over them, and no router loss: cross-entropy alone is the
+    # training loss). Either rule may pick by score + a per-expert bias
+    # (router_bias_scale > 0: the bias is a parameter, seeded normal at
+    # that scale; it enters the selection only, the kept weights are the
+    # unbiased scores, its gradient is zero and nothing here moves it),
+    # divide the kept weights by their sum, and scale them.
+    router_score: str = "softmax"     # softmax | sigmoid
+    router_bias_scale: float = 0.0
+    router_renormalise: bool = False
+    router_scale: float = 1.0
+    # A dense SwiGLU n_shared_experts x d_ff wide beside the routed sum,
+    # every token through it.
+    n_shared_experts: int = 0
+    # The layer pattern: this many leading layers keep a dense MLP, of
+    # width dense_d_ff, and the rest are sparse (n_experts > 0 only).
+    dense_layers: int = 0
+    dense_d_ff: int = 0
+    # (first, count): the experts whose matrices live here, one chip's
+    # share under expert parallelism. The router keeps its n_experts
+    # outputs and its expert_top_k a token; a slot whose expert is
+    # elsewhere is not gathered, multiplied or combined, and what it would
+    # have added is left out of the layer's result. None = all of them.
+    experts_held: Optional[Tuple[int, int]] = None
+    # Latent attention (kv_latent_dim > 0): k and v come from ONE low-rank
+    # projection (d_model -> kv_latent_dim, with its own RMSNorm, ->
+    # heads x (qk_nope_dim + v_head_dim)); a head's q.k runs over
+    # qk_nope_dim columns that carry no position and qk_rope_dim that are
+    # rotated, the rotated key columns being one set that all heads share
+    # (projected beside the latent); v and the output are v_head_dim wide.
+    # rope_interleaved: the rotated columns are pairs [x0, y0, x1, y1, ..]
+    # in the weights (de-interleaved there, once a step, then rotated as
+    # halves). 0 = multi-head attention at head_dim, q, k and v alike.
+    kv_latent_dim: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleaved: bool = False
     # "full": recompute the whole layer in backward (min HBM, max FLOPs)
     # "none": save everything (max HBM, min FLOPs)
     remat_policy: str = "full"
@@ -60,6 +102,13 @@ class GPTConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Columns of one head's q.k product."""
+        if self.kv_latent_dim:
+            return self.qk_nope_dim + self.qk_rope_dim
+        return self.head_dim
 
     @staticmethod
     def gpt2_small() -> "GPTConfig":
@@ -93,40 +142,68 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
         params["lm_head"] = _init_dense(keys[1], (cfg.d_model, cfg.vocab_size))
     layers = []
     d, ff, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    held = e if cfg.experts_held is None else cfg.experts_held[1]
+
+    def mlp(k, width):
+        return {
+            "w_gate": _init_dense(k[0], (d, width)),
+            "w_up": _init_dense(k[1], (d, width)),
+            "w_down": _init_dense(k[2], (width, d),
+                                  scale=1.0 / math.sqrt(2 * cfg.n_layers * width)),
+        }
+
     for i in range(cfg.n_layers):
         k = jax.random.split(keys[i + 2], 8)
         layer = {
             "ln1": {"scale": jnp.ones((d,), jnp.float32)},
             "ln2": {"scale": jnp.ones((d,), jnp.float32)},
-            "attn": {
+        }
+        if cfg.kv_latent_dim:
+            r, h = cfg.kv_latent_dim, cfg.n_heads
+            layer["attn"] = {
+                "wq": _init_dense(k[0], (d, h * cfg.qk_head_dim)),
+                "w_kva": _init_dense(k[1], (d, r + cfg.qk_rope_dim)),
+                "kv_norm": {"scale": jnp.ones((r,), jnp.float32)},
+                "w_kvb": _init_dense(
+                    k[2], (r, h * (cfg.qk_nope_dim + cfg.v_head_dim))),
+                "wo": _init_dense(
+                    k[3], (h * cfg.v_head_dim, d),
+                    scale=1.0 / math.sqrt(2 * cfg.n_layers * h * cfg.v_head_dim)),
+            }
+        else:
+            layer["attn"] = {
                 "wq": _init_dense(k[0], (d, d)),
                 "wk": _init_dense(k[1], (d, d)),
                 "wv": _init_dense(k[2], (d, d)),
                 "wo": _init_dense(k[3], (d, d),
                                   scale=1.0 / math.sqrt(2 * cfg.n_layers * d)),
-            },
-        }
+            }
         if cfg.qk_norm:
             layer["attn"]["q_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
             layer["attn"]["k_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
-        if e > 0:
-            # stacked [e, fan-in, fan-out]: the scale is the fan-in's
+        if e > 0 and i >= cfg.dense_layers:
+            # stacked [held, fan-in, fan-out]: the scale is the fan-in's
             layer["moe"] = {
                 "router": _init_dense(k[4], (d, e), scale=0.02),
-                "w_gate": _init_dense(k[5], (e, d, ff),
+                "w_gate": _init_dense(k[5], (held, d, ff),
                                       scale=1.0 / math.sqrt(d)),
-                "w_up": _init_dense(k[6], (e, d, ff),
+                "w_up": _init_dense(k[6], (held, d, ff),
                                     scale=1.0 / math.sqrt(d)),
-                "w_down": _init_dense(k[7], (e, ff, d),
+                "w_down": _init_dense(k[7], (held, ff, d),
                                       scale=1.0 / math.sqrt(2 * cfg.n_layers * ff)),
             }
+            # (keys of what only some configurations have are folded in, so
+            # that the others' weights stay what they were)
+            if cfg.router_bias_scale:
+                layer["moe"]["router_bias"] = _init_dense(
+                    jax.random.fold_in(keys[i + 2], 8), (e,),
+                    scale=cfg.router_bias_scale)
+            if cfg.n_shared_experts:
+                layer["moe"]["shared"] = mlp(
+                    jax.random.split(jax.random.fold_in(keys[i + 2], 9), 3),
+                    cfg.n_shared_experts * ff)
         else:
-            layer["mlp"] = {
-                "w_gate": _init_dense(k[5], (d, ff)),
-                "w_up": _init_dense(k[6], (d, ff)),
-                "w_down": _init_dense(k[7], (ff, d),
-                                      scale=1.0 / math.sqrt(2 * cfg.n_layers * ff)),
-            }
+            layer["mlp"] = mlp(k[5:], cfg.dense_d_ff if e > 0 else ff)
         layers.append(layer)
     params["layers"] = layers
     return params
@@ -227,11 +304,113 @@ def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh):
                       ("batch", "heads", None, None))(q, k, v, *table)
 
 
+def _deinterleaved(w, heads: int, keep: int, pairs: int):
+    """w [d, heads * (keep + pairs)]: in each head's last `pairs` columns,
+    interleaved pairs [x0, y0, x1, y1, ..] -> halves [x0, x1, .. | y0, y1,
+    ..]. Done to the weights, what it is to the activations they produce
+    (a column's dot product does not change with its place), at a
+    hundredth of the elements."""
+    d = w.shape[0]
+    w = w.reshape(d, heads, keep + pairs)
+    tail = w[..., keep:].reshape(d, heads, pairs // 2, 2)
+    tail = tail.swapaxes(-1, -2).reshape(d, heads, pairs)
+    return jnp.concatenate([w[..., :keep], tail], axis=-1).reshape(d, -1)
+
+
+def _rope_tail(t, table, n: int):
+    """t [B, S, heads, width]: the last n columns of every head rotated as
+    halves by `table` (rope_table of S and n), the rest as they are. The
+    jnp formulation: a head of 192 columns fills no whole lane tiles, so
+    ops/rope.py's kernels do not take it."""
+    cos, sin = (c[:, None, :n] for c in table)
+    x = t[..., -n:].astype(jnp.float32)
+    other = jnp.concatenate([x[..., n // 2:], x[..., :n // 2]], axis=-1)
+    return jnp.concatenate(
+        [t[..., :-n], (x * cos + other * sin).astype(t.dtype)], axis=-1)
+
+
+def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
+    """The attention of a latent block, up to the heads' outputs
+    [B, H, S, v_head_dim]: q straight from x, k and v up from one
+    normalised latent, RoPE on qk_rope_dim columns of q's heads and on the
+    one key part all heads share, then the flash kernels at q.k width
+    qk_nope_dim + qk_rope_dim and v width v_head_dim. Scope `attn_latent`
+    (inside `attn_proj`) holds what exists only because attention is
+    latent: both kv projections, their norm, the assembly of k and v."""
+    a, dt = layer["attn"], cfg.dtype
+    nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    latent = cfg.kv_latent_dim
+    wq, w_kva = a["wq"].astype(dt), a["w_kva"].astype(dt)
+    with jax.named_scope("attn_proj"):
+        if cfg.rope_interleaved:
+            wq = _deinterleaved(wq, wq.shape[1] // (nope + rope), nope, rope)
+        q = jnp.einsum("bsd,de->bse", x, wq)
+        with jax.named_scope("attn_latent"):
+            if cfg.rope_interleaved:
+                w_kva = _deinterleaved(w_kva, 1, latent, rope)
+            c = jnp.einsum("bsd,de->bse", x, w_kva)
+            kv = jnp.einsum(
+                "bsr,re->bse",
+                _rmsnorm(c[..., :latent], a["kv_norm"]["scale"],
+                         cfg.rmsnorm_eps), a["w_kvb"].astype(dt))
+            k_rope = c[..., latent:]
+
+    # the zero columns the flash kernels want after a head's q.k columns
+    # (ops/attention.py:qk_padding), written where q and k are assembled
+    # anyway and not in a pass of their own
+    fill = 0 if cfg.attention == "reference" else qk_padding(nope + rope)
+    sm_scale = 1.0 / math.sqrt(nope + rope)
+
+    def split_and_attend(q, kv, k_rope, *table):
+        b, s, _ = q.shape
+        with jax.named_scope("attn_proj"):
+            q = q.reshape(b, s, -1, nope + rope)
+            zeros = [jnp.zeros(q.shape[:3] + (fill,), q.dtype)] if fill else []
+            q = jnp.concatenate([_rope_tail(q, table, rope)] + zeros,
+                                axis=-1).transpose(0, 2, 1, 3)
+            with jax.named_scope("attn_latent"):
+                kv = kv.reshape(b, s, -1, nope + dv)
+                k_rope = _rope_tail(k_rope[:, :, None, :], table, rope)
+                k = jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(
+                        k_rope, kv.shape[:3] + (rope,))] + zeros, axis=-1)
+                k, v = k.transpose(0, 2, 1, 3), kv[..., nope:].transpose(
+                    0, 2, 1, 3)
+        with jax.named_scope("attn_core"):
+            if cfg.attention == "reference":
+                return mha_reference(q, k, v, causal=True, sm_scale=sm_scale)
+            return flash_attention(q, k, v, causal=True, sm_scale=sm_scale)
+
+    if cfg.attention == "ring":
+        raise ValueError("attention='ring' has no latent form: the shared "
+                         "rotated key part is not sharded over 'sequence'")
+    columns = ("batch", None, "heads")
+    return _per_shard(split_and_attend, where.mesh,
+                      (columns, columns, ("batch", None, None), (), ()),
+                      ("batch", "heads", None, None))(q, kv, k_rope, *table)
+
+
 def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting):
-    """table: rope_table(S, head_dim, theta), built once a step by the
-    caller (layer_fn, outside the remat). The flash path alone reads it:
+    """table: rope_table(S, the rotated width, theta), built once a step by
+    the caller (layer_fn, outside the remat). The flash path alone reads it:
     'reference' and 'ring' keep the jnp `_rope` on [B, H, S, D] (the
-    oracle, and ring's sequence shards need their global positions)."""
+    oracle, and ring's sequence shards need their global positions); a
+    latent block (`_latent_attention`) reads it on every path."""
+    b, s, _ = x.shape
+    dt = cfg.dtype
+    if cfg.kv_latent_dim:
+        o = _latent_attention(layer, x, cfg, table, where)
+    else:
+        o = _multi_head_attention(layer, x, cfg, table, where)
+    with jax.named_scope("attn_out"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        return where.psum(
+            jnp.einsum("bsd,de->bse", o, layer["attn"]["wo"].astype(dt)))
+
+
+def _multi_head_attention(layer, x, cfg: GPTConfig, table, where: Setting):
+    """q, k, v of one width from three projections -> the heads' outputs
+    [B, H, S, head_dim]."""
     b, s, _ = x.shape
     hd = cfg.head_dim
     dt = cfg.dtype
@@ -250,27 +429,22 @@ def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting):
         k = proj(layer["attn"]["wk"], layer["attn"].get("k_norm"))
         v = proj(layer["attn"]["wv"])
     if cfg.attention not in ("ring", "reference"):
-        o = _flash_on_mesh(q, k, v, table, cfg, where.mesh)
-    else:
-        with jax.named_scope("attn_proj"):
-            positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-            q = _rope(heads(q), cfg.rope_theta, positions)
-            k = _rope(heads(k), cfg.rope_theta, positions)
-            v = heads(v)
-        with jax.named_scope("attn_core"):
-            if cfg.attention == "ring":
-                o = ring_attention(q, k, v, mesh=where.mesh, causal=True)
-            else:
-                o = mha_reference(q, k, v, causal=True)
-    with jax.named_scope("attn_out"):
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
-        return where.psum(
-            jnp.einsum("bsd,de->bse", o, layer["attn"]["wo"].astype(dt)))
+        return _flash_on_mesh(q, k, v, table, cfg, where.mesh)
+    with jax.named_scope("attn_proj"):
+        positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+        q = _rope(heads(q), cfg.rope_theta, positions)
+        k = _rope(heads(k), cfg.rope_theta, positions)
+        v = heads(v)
+    with jax.named_scope("attn_core"):
+        if cfg.attention == "ring":
+            return ring_attention(q, k, v, mesh=where.mesh, causal=True)
+        return mha_reference(q, k, v, causal=True)
 
 
-def _mlp_block(layer, x, cfg: GPTConfig, where: Setting):
+def _mlp_block(m, x, cfg: GPTConfig, where: Setting):
+    """SwiGLU through m's three matrices: a dense layer's MLP, or the
+    shared expert of a sparse one."""
     dt = cfg.dtype
-    m = layer["mlp"]
     gate = jnp.einsum("bsd,df->bsf", x, m["w_gate"].astype(dt))
     up = jnp.einsum("bsd,df->bsf", x, m["w_up"].astype(dt))
     return where.psum(jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
@@ -278,41 +452,77 @@ def _mlp_block(layer, x, cfg: GPTConfig, where: Setting):
 
 
 def _route(m, x, cfg: GPTConfig):
-    """The router, in float32: probabilities over the experts, each token's
-    expert_top_k largest as they come (not renormalised), and the layer's
-    routing statistics: the load-balancing loss E x sum_e f_e P_e (f_e the
-    share of tokens that chose e among ALL their k choices, P_e the mean
-    probability of e), the z-loss mean(logsumexp(logits)^2), and the
-    largest expert's load over the mean load."""
+    """The router, in float32: a score for every expert (softmax over them,
+    or a sigmoid each: cfg.router_score), each token's expert_top_k largest
+    — by score plus the selection bias where the layer has one, the kept
+    weights being the scores without it —, the weights as they come or,
+    as the configuration says, divided by their sum and scaled. With them
+    the layer's routing statistics: the largest expert's load over the
+    mean load, the share of the token-slots that fall to the experts held
+    here and, under the softmax rule, its two losses: load balancing
+    E x sum_e f_e P_e (f_e the share of tokens that chose e among ALL
+    their k choices, P_e the mean probability of e) and the z-loss
+    mean(logsumexp(logits)^2)."""
     e, k = cfg.n_experts, cfg.expert_top_k
     # HIGHEST: at the default precision a TPU rounds a float32 matmul's
     # operands to bfloat16, and near-tied experts then swap
     logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
                         m["router"].astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, idx = jax.lax.top_k(probs, k)
+    if cfg.router_score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router_score {cfg.router_score!r} "
+                         "(expected 'softmax' | 'sigmoid')")
+    if "router_bias" in m:
+        _, idx = jax.lax.top_k(scores + m["router_bias"], k)
+        weights = jnp.take_along_axis(scores, idx, axis=-1)
+    else:
+        weights, idx = jax.lax.top_k(scores, k)
+    if cfg.router_renormalise:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    if cfg.router_scale != 1.0:
+        weights = weights * cfg.router_scale
     load = jnp.mean(jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32),
                             axis=2), axis=(0, 1))
-    stats = {
-        "router_balance_loss": e * jnp.sum(load * jnp.mean(probs, axis=(0, 1))),
-        "router_z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
-        "expert_load_max_over_mean": jnp.max(load) * e / k,
-    }
+    stats = {}
+    if cfg.router_score == "softmax":
+        stats.update(
+            router_balance_loss=e * jnp.sum(
+                load * jnp.mean(scores, axis=(0, 1))),
+            router_z_loss=jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2))
+    stats["expert_load_max_over_mean"] = jnp.max(load) * e / k
+    if cfg.experts_held is None:
+        stats["expert_slots_held_share"] = 1.0
+    else:
+        first, count = cfg.experts_held
+        stats["expert_slots_held_share"] = jnp.sum(
+            load[first:first + count]) / k
     return weights, idx, stats
 
 
-def _experts(x, weights, idx, w_gate, w_up, w_down):
+def _experts(x, weights, idx, w_gate, w_up, w_down, held=None):
     """x [b, s, d] through each token's chosen experts (ops/moe.py): rows
     ordered by expert, three grouped matmuls with SwiGLU between, weighted
     return. The gathers either side are the layer's sparsity, not its
-    arithmetic: scope `moe_route`."""
+    arithmetic: scope `moe_route`. held: None where the matrices are all
+    the experts', else (first, of how many): the matrices are experts
+    first .. first + len - 1, and a slot chosen for another is left out."""
     b, s, d = x.shape
     e = w_gate.shape[0]
     with jax.named_scope("moe_route"):
         idx = idx.reshape(b * s, -1)
-        plan = moe.plan_dispatch(
-            idx, e, moe.tile_rows(idx.size, e, x.dtype))
+        if held is None:
+            plan = moe.plan_dispatch(
+                idx, e, moe.tile_rows(idx.size, e, x.dtype))
+        else:
+            first, of = held
+            # tiles from the slots expected here; room for every slot
+            plan = moe.plan_dispatch(
+                idx - first, e,
+                moe.tile_rows(idx.size * e // of, e, x.dtype), partial=True)
         rows = moe.dispatch(x.reshape(b * s, d), plan)
     gate = moe.grouped_matmul(rows, w_gate, plan)
     up = moe.grouped_matmul(rows, w_up, plan)
@@ -322,44 +532,59 @@ def _experts(x, weights, idx, w_gate, w_up, w_down):
     return y.reshape(b, s, d)
 
 
-def _moe_block(layer, x, cfg: GPTConfig, mesh):
+def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
     """Sparse experts in the MLP's place: y = sum over a token's top-k of
     p_e x down_e(silu(gate_e x) * up_e x). No capacity and no dropped
     token: every token-slot is computed, by its own expert only. The
     grouped matmuls run per shard (`_per_shard`): each device dispatches
     its own tokens to all the experts, whose matrices it is handed whole;
-    the router and its losses stay outside, over the whole batch."""
+    the router and its losses stay outside, over the whole batch.
+
+    With cfg.experts_held the sum runs over the chosen experts that are
+    held here (one chip's share under expert parallelism, without its
+    exchange): the partial result, nothing standing in for the rest. With
+    cfg.n_shared_experts a dense SwiGLU of every token is added (scope
+    `moe_shared`)."""
     dt = cfg.dtype
     m = layer["moe"]
     with jax.named_scope("moe_route"):
         weights, idx, stats = _route(m, x, cfg)
     matrices = [m[name].astype(dt) for name in ("w_gate", "w_up", "w_down")]
+    held = (None if cfg.experts_held is None
+            else (cfg.experts_held[0], cfg.n_experts))
     tokens = ("batch", None, None)
-    y = _per_shard(_experts, mesh, (tokens,) * 3 + ((),) * 3, tokens)(
+    y = _per_shard(partial(_experts, held=held), where.mesh,
+                   (tokens,) * 3 + ((),) * 3, tokens)(
         x, weights, idx, *matrices)
+    if "shared" in m:
+        with jax.named_scope("moe_shared"):
+            y = y + _mlp_block(m["shared"], x, cfg, where)
     return y, stats
 
 
 def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     """(x [B, seq, D], one layer's parameters) -> (x, the router's
-    statistics: _route's dict for a sparse layer, {} for a dense one). The
+    statistics: _route's dict for a sparse layer, {} for a dense one; which
+    it is, the layer's own parameters say, as gpt_init built them). The
     one transformer block, rematted as cfg.remat_policy says, for whoever
     walks the layers: gpt_backbone loops over their list, a stage of
     parallel/pipeline.py scans over stacked ones."""
     # once a step, not once a layer and recompute: outside the remat
     with jax.named_scope("attn_proj"):
-        table = rope_table(seq, cfg.head_dim, cfg.rope_theta)
+        table = rope_table(seq, cfg.qk_rope_dim if cfg.kv_latent_dim
+                           else cfg.head_dim, cfg.rope_theta)
 
     def block(x, layer):
         h = where.pin(x + _attention_block(layer, _rmsnorm(
             x, layer["ln1"]["scale"], cfg.rmsnorm_eps), cfg, table, where))
         normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
-        if cfg.n_experts > 0:
+        if "moe" in layer:
             with jax.named_scope("moe"):
-                delta, stats = _moe_block(layer, normed, cfg, where.mesh)
+                delta, stats = _moe_block(layer, normed, cfg, where)
         else:
             with jax.named_scope("mlp"):
-                delta, stats = _mlp_block(layer, normed, cfg, where), {}
+                delta, stats = _mlp_block(
+                    layer["mlp"], normed, cfg, where), {}
         return where.pin(h + delta), stats
 
     if cfg.remat_policy == "full":
@@ -392,8 +617,8 @@ def gpt_forward(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
 
 def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     """tokens: [B, S] -> (final hidden states [B, S, D] (pre-LM-head), the
-    router's statistics averaged over the layers: _route's dict for a
-    sparse model, {} for a dense one).
+    router's statistics averaged over the sparse layers: _route's dict
+    for a sparse model, {} for a dense one).
 
     act_sharding (a NamedSharding for [B, S, D] activations, usually
     ``strategy.activation_sharding(mesh)``) pins the residual stream at
@@ -408,9 +633,10 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     per_layer = []
     for layer_params in params["layers"]:
         x, stats = layer(x, layer_params)
-        per_layer.append(stats)
+        if stats:
+            per_layer.append(stats)
     router = jax.tree_util.tree_map(
-        lambda *layers: sum(layers) / len(layers), *per_layer)
+        lambda *layers: sum(layers) / len(layers), *per_layer or [{}])
     return final_norm(params, x, cfg), router
 
 
@@ -565,8 +791,8 @@ def head_xent_recompute(params, x, targets, cfg: GPTConfig):
 def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
                      act_sharding=None):
     """batch: {"tokens": [B, S+1]} -> (loss, aux): the mean next-token
-    cross-entropy, plus for a sparse model the router's two losses at the
-    configuration's weights; aux holds the cross-entropy alone ("xent")
+    cross-entropy, plus under the softmax routing rule the router's two
+    losses at the configuration's weights; aux holds the cross-entropy alone ("xent")
     and the router's statistics (the two losses unweighted, the largest
     expert's load over the mean), for a step written with
     jax.value_and_grad(..., has_aux=True)."""
@@ -575,7 +801,7 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
     x, router = gpt_backbone(params, inputs, cfg, mesh, act_sharding)
     total, denom = head_xent(params, x, targets, cfg)
     loss = xent = total / jnp.maximum(denom, 1.0)
-    if cfg.n_experts > 0:
+    if "router_balance_loss" in router:
         loss = (xent
                 + cfg.router_aux_loss_coef * router["router_balance_loss"]
                 + cfg.router_z_loss_coef * router["router_z_loss"])

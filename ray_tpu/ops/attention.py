@@ -7,7 +7,9 @@ Net-new relative to the reference, which has no sequence-parallel support
 blockwise-softmax partials for its local Q shard — compute overlaps the
 ICI transfer, HBM never holds the full sequence.
 
-Layouts: q, k, v are [batch, num_heads, seq, head_dim].
+Layouts: q and k are [batch, num_heads, seq, Dqk], v and the output
+[batch, num_heads, seq, Dv]. The two widths may differ (latent attention:
+q.k over 192 columns, v 128 wide); the scale defaults to 1 / sqrt(Dqk).
 """
 
 from __future__ import annotations
@@ -94,9 +96,13 @@ def lane_divisor(n: int, cap: int) -> int:
     return max(b for b in range(LANES, min(cap, n) + 1, LANES) if n % b == 0)
 
 
-def _block_sizes(seq_q: int, seq_k: int, head_dim: int) -> _FlashBlocks:
+def _block_sizes(seq_q: int, seq_k: int, head_dim: int,
+                 v_dim: Optional[int] = None) -> _FlashBlocks:
     """Blocks of the three kernels, from the shape alone (PERF.md has the
-    sweep on a v5e behind the numbers)."""
+    sweep on a v5e behind the numbers). head_dim is q's and k's width,
+    v_dim v's and the output's (head_dim where it is not given): the wider
+    of the two decides."""
+    wide = max(head_dim, v_dim or head_dim)
     for seq in (seq_q, seq_k):
         if seq > LANES and seq % LANES:
             raise ValueError(
@@ -107,17 +113,20 @@ def _block_sizes(seq_q: int, seq_k: int, head_dim: int) -> _FlashBlocks:
         # no square block fits both: one block each
         return _FlashBlocks(fwd=(seq_q, seq_k, seq_q), dq=(seq_q, seq_k, seq_q),
                             dkv=(seq_k, seq_q, seq_k))
-    # Square blocks as large as the sequence, up to 2048 (1024 for heads
-    # wider than 128: six blocks x head_dim, two buffers each, share 16 MB
-    # of VMEM with the score tiles): a whole row of the score matrix in one
-    # grid step where it fits. Row groups of 256 for the forward, whose
-    # per-row statistics want a wide tile, and of 128 for the backward
-    # kernels, which have none and two score-sized products a tile.
-    square = lane_divisor(math.gcd(seq_q, seq_k),
-                          2048 if head_dim <= LANES else 1024)
+    # Square blocks as large as the sequence, up to 2048: a whole row of
+    # the score matrix in one grid step where it fits. The backward kernels
+    # stop at 1024 where q, k or v is wider than 128 (six blocks x their
+    # widths, two buffers each, beside the score tiles: at 256 / 128 wide
+    # they take 1.5 x as long with 2048, the forward 0.9 x; PERF.md, PR 31).
+    # Row groups of 256 for the forward, whose per-row statistics want a
+    # wide tile, and of 128 for the backward kernels, which have none and
+    # two score-sized products a tile.
+    seq = math.gcd(seq_q, seq_k)
+    square = lane_divisor(seq, 2048)
+    back = square if wide <= LANES else lane_divisor(seq, 1024)
     return _FlashBlocks(fwd=(square, square, lane_divisor(square, 256)),
-                        dq=(square, square, lane_divisor(square, LANES)),
-                        dkv=(square, square, lane_divisor(square, LANES)))
+                        dq=(back, back, lane_divisor(back, LANES)),
+                        dkv=(back, back, lane_divisor(back, LANES)))
 
 
 def _lanes(x, n: int):
@@ -213,7 +222,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     per-row logsumexp (lse) the backward kernels consume; a row with no key
     to attend gives zeros and lse = NEG_INF.
     """
-    bq, d = q_ref.shape[1:]
+    bq, d = o_ref.shape[1:]                 # d: v's and the output's width
     bk = k_ref.shape[1]
     qi, ki = pl.program_id(1), pl.program_id(2)
 
@@ -335,8 +344,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 _GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
-# Heads of 128 and wider: blocks of 2048 x 128 beside the score tiles need
-# 16.7 MB, over the 16 MB of VMEM a kernel is given unless it asks.
+# Heads of 128 and wider (q, k or v): blocks of 2048 x 128 beside the score
+# tiles need 16.7 MB, over the 16 MB of VMEM a kernel is given unless it asks.
 _GRID_SEMANTICS_WIDE = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"),
     vmem_limit_bytes=48 << 20)
@@ -361,74 +370,78 @@ def _kv_index(causal, offset, bq, bkm, num_k):
 
 def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret):
     batch, heads, seq_q, d = q.shape
-    seq_k = k.shape[2]
+    seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
     bq, bkm, group = blocks.fwd
     offset = seq_k - seq_q
     kernel = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
                                causal=causal, group=group, offset=offset)
-    kv_spec = pl.BlockSpec((1, bkm, d),
-                           _kv_index(causal, offset, bq, bkm, seq_k // bkm))
+    kv_index = _kv_index(causal, offset, bq, bkm, seq_k // bkm)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, seq_q // bq, seq_k // bkm),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            kv_spec,
-            kv_spec,
+            pl.BlockSpec((1, bkm, d), kv_index),
+            pl.BlockSpec((1, bkm, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
             # lse rides as [bh, 1, seq_q]: TPU Pallas needs the last two
             # block dims divisible by (8, 128) or equal to the array dims.
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
-        compiler_params=_compiler_params(d),
+        compiler_params=_compiler_params(max(d, dv)),
         interpret=interpret,
         name="flash_fwd",
     )(q.reshape(bh, seq_q, d), k.reshape(bh, seq_k, d),
-      v.reshape(bh, seq_k, d))
-    return out.reshape(batch, heads, seq_q, d), lse
+      v.reshape(bh, seq_k, dv))
+    return out.reshape(batch, heads, seq_q, dv), lse
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
                     interpret):
     batch, heads, seq_q, d = q.shape
-    seq_k = k.shape[2]
+    seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
     qr = q.reshape(bh, seq_q, d)
     kr = k.reshape(bh, seq_k, d)
-    vr = v.reshape(bh, seq_k, d)
-    gr = g.reshape(bh, seq_q, d)
+    vr = v.reshape(bh, seq_k, dv)
+    gr = g.reshape(bh, seq_q, dv)
     # delta_i = rowsum(dO_i * O_i): cheap elementwise, fused by XLA.
     delta = jnp.sum(gr.astype(jnp.float32)
-                    * out.reshape(bh, seq_q, d).astype(jnp.float32),
+                    * out.reshape(bh, seq_q, dv).astype(jnp.float32),
                     axis=-1).reshape(bh, 1, seq_q)
     offset = seq_k - seq_q
+    params = _compiler_params(max(d, dv))
 
+    # q, k and dq, dk move in blocks d wide; v, dO and dv in blocks dv wide
     bq, bkm, group = blocks.dq
-    q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, bkm, d),
-                           _kv_index(causal, offset, bq, bkm, seq_k // bkm))
+    kv_index = _kv_index(causal, offset, bq, bkm, seq_k // bkm)
+
+    def q_spec(width):
+        return pl.BlockSpec((1, bq, width), lambda b, i, j: (b, i, 0))
     row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
                           causal=causal, group=group, offset=offset),
         grid=(bh, seq_q // bq, seq_k // bkm),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        in_specs=[q_spec(d), pl.BlockSpec((1, bkm, d), kv_index),
+                  pl.BlockSpec((1, bkm, dv), kv_index), q_spec(dv),
+                  row_spec, row_spec],
+        out_specs=q_spec(d),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_compiler_params(d),
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dq",
     )(qr, kr, vr, gr, lse, delta)
@@ -444,28 +457,33 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
     else:
         def q_block(i, j):
             return j
-    q_spec = pl.BlockSpec((1, bqm, d), lambda b, i, j: (b, q_block(i, j), 0))
-    kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0))
+    def q_spec(width):
+        return pl.BlockSpec((1, bqm, width),
+                            lambda b, i, j: (b, q_block(i, j), 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, bk, width), lambda b, i, j: (b, i, 0))
     row_spec = pl.BlockSpec((1, 1, bqm), lambda b, i, j: (b, 0, q_block(i, j)))
-    dk, dv = pl.pallas_call(
+    dk, dvalue = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
                           causal=causal, group=group, offset=offset),
         grid=(bh, seq_k // bk, num_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
+                  row_spec, row_spec],
+        out_specs=[kv_spec(d), kv_spec(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq_k, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, seq_k, dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_compiler_params(d),
+                        pltpu.VMEM((bk, dv), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lse, delta)
     return (dq.reshape(batch, heads, seq_q, d),
             dk.reshape(batch, heads, seq_k, d),
-            dv.reshape(batch, heads, seq_k, d))
+            dvalue.reshape(batch, heads, seq_k, dv))
 
 
 @functools.lru_cache(maxsize=None)
@@ -496,6 +514,11 @@ def _make_flash_fn(causal, sm_scale, blocks, interpret):
     return f
 
 
+def qk_padding(d: int) -> int:
+    """Zero columns to append to q and k of width d before the kernels."""
+    return -d % LANES if d > LANES else 0
+
+
 def _default_interpret() -> bool:
     """Mosaic-compiled on a TPU backend, interpreted (the Pallas software
     emulator, what the CPU tests run) on every other."""
@@ -514,14 +537,26 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ragged shape raises instead of silently running another kernel.
     block_q / block_k are for tests that want several blocks of a short
     sequence: every kernel then tiles by exactly these.
+
+    q and k of a width over 128 that is not whole lane tiles (192) are
+    padded with zero columns to the next tile (256: `qk_padding`): no score
+    changes, and the kernels run a contraction of 256 faster than one of
+    192, forward by a fifth (PERF.md, PR 31). XLA runs that pad as a pass
+    of its own; a caller that assembles q and k itself appends the zero
+    columns there and passes the true width's sm_scale.
     """
+    d = q.shape[-1]
     if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+        sm_scale = 1.0 / math.sqrt(d)
+    pad = qk_padding(d)
+    if pad:
+        widths = ((0, 0),) * 3 + ((0, pad),)
+        q, k = jnp.pad(q, widths), jnp.pad(k, widths)
     seq_q, seq_k = q.shape[2], k.shape[2]
     if interpret is None:
         interpret = _default_interpret()
     if block_q is None and block_k is None:
-        blocks = _block_sizes(seq_q, seq_k, q.shape[-1])
+        blocks = _block_sizes(seq_q, seq_k, q.shape[-1], v.shape[-1])
     else:
         bq = min(block_q or seq_q, seq_q)
         bk = min(block_k or seq_k, seq_k)
@@ -600,7 +635,8 @@ def ring_attention(q, k, v, *, mesh, axis_name: str = "sequence",
                 (k_cur, v_cur))
             return acc, m, l, k_nxt, v_nxt
 
-        b, h, s, d = q_loc.shape
+        b, h, s = q_loc.shape[:3]
+        d = v_loc.shape[3]
         # Mark the accumulators device-varying so the loop carry's vma type
         # is stable across iterations (jax shard_map type system).
         acc0, m0, l0 = jax.lax.pvary(

@@ -18,6 +18,13 @@ still owns one (all zero) tile. Three things follow from that layout:
 
 Padding rows are zeros in, zeros through SwiGLU, and never gathered back.
 
+One chip's share of the experts (`plan_dispatch(..., partial=True)`): the
+groups are the experts held here and a slot may have chosen one that is
+not. Such a slot gets no row: it is not gathered in, multiplied or
+combined, and no gradient passes through it (`Plan.token_held`). How many
+slots fall here is known only on the device, so the row space keeps room
+for all of them and the tiles nobody fills are skipped like any other.
+
 Kernels (names in util/profiling.KERNELS): `moe_gmm` (rows x an expert's
 matrix, forward and the gradient of the rows) and `moe_tgmm` (rows^T x
 rows a group, the gradient of the matrices).
@@ -53,11 +60,16 @@ class Plan(NamedTuple):
     tile_group  [tiles] the expert that owns each row tile
     tiles_used  [1] tiles up to the end of the last group; the rest are
                 never computed
+    token_held  [T, k] bool, under `partial` only: whether the slot's
+                expert is one of the groups. Where it is not, token_rows
+                is 0 (a row that is always computed, so finite) and the
+                slot is masked out wherever token_rows is read
     """
     row_slot: jax.Array
     token_rows: jax.Array
     tile_group: jax.Array
     tiles_used: jax.Array
+    token_held: Optional[jax.Array] = None
 
 
 def tile_rows(n_slots: int, n_groups: int, dtype) -> int:
@@ -71,12 +83,18 @@ def tile_rows(n_slots: int, n_groups: int, dtype) -> int:
     return rows
 
 
-def plan_dispatch(idx, n_groups: int, rows: int) -> Plan:
+def plan_dispatch(idx, n_groups: int, rows: int,
+                  partial: bool = False) -> Plan:
     """idx [T, k] int: the experts each token chose. Two sorts of T x k
-    keys and small dense passes; integers only, nothing differentiated."""
+    keys and small dense passes; integers only, nothing differentiated.
+    partial: an idx outside 0 .. n_groups - 1 is an expert that is not
+    here; its slot sorts behind every group's and gets no row."""
     t, k = idx.shape
     n = t * k
     flat = idx.reshape(n).astype(jnp.int32)
+    if partial:
+        here = (flat >= 0) & (flat < n_groups)
+        flat = jnp.where(here, flat, n_groups)
     slots = jnp.arange(n, dtype=jnp.int32)
     chose = flat[:, None] == jnp.arange(n_groups, dtype=jnp.int32)[None, :]
     sizes = jnp.sum(chose, axis=0, dtype=jnp.int32)
@@ -104,6 +122,11 @@ def plan_dispatch(idx, n_groups: int, rows: int) -> Plan:
         + jnp.arange(rows, dtype=jnp.int32)[None, :]
     held = jnp.clip(first_rank[tile_group][:, None] + within, 0, n - 1)
     row_slot = jnp.where(within < sizes[tile_group][:, None], order[held], n)
+    if partial:
+        here = here.reshape(t, k)
+        token_rows = jnp.where(here, token_rows, 0)
+        return Plan(row_slot.reshape(-1), token_rows, tile_group,
+                    tile_end[-1:], here)
     return Plan(row_slot.reshape(-1), token_rows, tile_group, tile_end[-1:])
 
 
@@ -125,8 +148,19 @@ def _dispatch_fwd(x, plan):
     return dispatch(x, plan), plan
 
 
+def _held_only(per_slot, plan: Plan):
+    """per_slot [T, k, ...] with zeros in the slots whose expert is not
+    here (a plan of all the experts has none)."""
+    if plan.token_held is None:
+        return per_slot
+    held = plan.token_held.reshape(
+        plan.token_held.shape + (1,) * (per_slot.ndim - 2))
+    return jnp.where(held, per_slot, 0)
+
+
 def _dispatch_bwd(plan, g):
-    dx = jnp.sum(g[plan.token_rows], axis=1, dtype=jnp.float32)
+    dx = jnp.sum(_held_only(g[plan.token_rows], plan), axis=1,
+                 dtype=jnp.float32)
     return dx.astype(g.dtype), None
 
 
@@ -137,8 +171,8 @@ dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 def combine(z, weights, plan: Plan):
     """Expert-ordered rows [rows, d] back to tokens [T, d]: each token's k
     rows, weighted (weights [T, k] float32) and summed in float32."""
-    y = jnp.einsum("tk,tkd->td", weights, z[plan.token_rows],
-                   preferred_element_type=jnp.float32)
+    y = jnp.einsum("tk,tkd->td", _held_only(weights, plan),
+                   z[plan.token_rows], preferred_element_type=jnp.float32)
     return y.astype(z.dtype)
 
 
@@ -154,8 +188,9 @@ def _combine_bwd(res, g):
     g_rows = _take_rows(g, plan.row_slot // k)
     row_weight = _take_rows(weights.reshape(-1), plan.row_slot)
     dz = g_rows * row_weight[:, None].astype(g.dtype)
-    dweights = jnp.sum(g_rows.astype(jnp.float32) * z.astype(jnp.float32),
-                       axis=1)[plan.token_rows]
+    dweights = _held_only(
+        jnp.sum(g_rows.astype(jnp.float32) * z.astype(jnp.float32),
+                axis=1)[plan.token_rows], plan)
     return dz, dweights.astype(weights.dtype), None
 
 

@@ -69,9 +69,17 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
     if cfg.n_layers % n_stages != 0:
         raise ValueError(
             f"n_layers={cfg.n_layers} not divisible by pipeline={n_stages}")
+    if cfg.dense_layers:
+        raise ValueError(
+            f"dense_layers={cfg.dense_layers}: the pipeline preset scans "
+            "one stack of identical layers and has no layer pattern")
     if cfg.n_experts > 0:
         raise ValueError("pipeline preset supports dense MLP layers (use "
                          "'ep' compositions for MoE)")
+    if cfg.kv_latent_dim and tp > 1:
+        raise ValueError("ShardingStrategy.pp_tp() has no rule for latent "
+                         "attention's attn/w_kva, attn/w_kvb and "
+                         "attn/kv_norm")
     if cfg.n_heads % tp != 0:
         raise ValueError(f"n_heads={cfg.n_heads} not divisible by tp={tp}")
     M = num_microbatches

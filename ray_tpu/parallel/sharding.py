@@ -103,10 +103,20 @@ class ShardingStrategy:
         rules = ShardingRules(rules=[
             (r"attn/(wq|wk|wv)", P(None, t)),
             (r"attn/wo", P(t, None)),
+            # latent attention: whole heads of the up-projection's columns;
+            # the down-projection, its norm and the shared rotated key
+            # part are not divided
+            (r"attn/w_kvb", P(None, t)),
+            (r"attn/(w_kva|kv_norm)", P()),
             (r"mlp/(w_up|w_gate)", P(None, t)),
             (r"mlp/w_down", P(t, None)),
             (r"embed/table", P(t, None)),
             (r"lm_head", P(None, t)),
+            # the shared expert is a dense MLP (two dimensions), not a
+            # stack of experts
+            (r"moe/shared/(w_up|w_gate)", P(None, t)),
+            (r"moe/shared/w_down", P(t, None)),
+            (r"moe/router_bias", P()),
             (r"moe/.*w_(gate|up)", P("expert", None, t)),
             (r"moe/.*w_down", P("expert", t, None)),
             (r"moe/router", P(None, None)),
@@ -121,6 +131,8 @@ class ShardingStrategy:
         rules = ShardingRules(rules=[
             (r"attn/(wq|wk|wv)", P(f, t)),
             (r"attn/wo", P(t, f)),
+            (r"attn/w_kvb", P(f, t)),
+            (r"attn/(w_kva|kv_norm)", P()),
             (r"mlp/(w_up|w_gate)", P(f, t)),
             (r"mlp/w_down", P(t, f)),
             # Vocab over both axes, d_model replicated: a d-sharded gather
@@ -130,6 +142,9 @@ class ShardingStrategy:
             # all-reduce and reshards to the batch spec cheaply.
             (r"embed/table", P((t, f), None)),
             (r"lm_head", P(f, t)),
+            (r"moe/shared/(w_up|w_gate)", P(f, t)),
+            (r"moe/shared/w_down", P(t, f)),
+            (r"moe/router_bias", P()),
             (r"moe/.*w_(gate|up)", P("expert", f, t)),
             (r"moe/.*w_down", P("expert", t, f)),
             (r"moe/router", P(None, None)),

@@ -118,9 +118,9 @@ def stack_dump() -> Dict[str, str]:
 # SwiGLU, the casts of their matrices); `moe_route`, nested in it, is what
 # exists only because the layer is sparse: router, top-k, ordering, the
 # gathers either side, both router losses.
-REGIONS = ("embed", "attn_proj", "attn_core", "attn_out", "mlp", "moe",
-           "moe_route", "norm", "head", "loss_and_grad", "grad_accum",
-           "optimizer")
+REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_out",
+           "mlp", "moe", "moe_route", "moe_shared", "norm", "head",
+           "loss_and_grad", "grad_accum", "optimizer")
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
            "moe_tgmm", "rope_split", "rope_merge")
 UNATTRIBUTED = "unattributed"

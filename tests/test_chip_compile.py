@@ -8,6 +8,7 @@ topology at once collide on /tmp/libtpu_lockfile.
 """
 
 import os
+import re
 
 import pytest
 
@@ -249,3 +250,66 @@ def test_grouped_matmul_kernels_compile_for_v5e(v5e):
     # forward, the rows' gradient, the matrices' gradient
     assert text.count("tpu_custom_call") >= 3
     assert "moe_gmm" in text and "moe_tgmm" in text
+
+
+def test_latent_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch):
+    """kanana2_train_1chip's whole step (5 layers of latent attention at
+    q.k 192 padded to 256 / v 128 and 8192 positions, one dense and four
+    sparse with 16 of 128 experts held, adamw over fp32 masters) for one
+    described chip: every Mosaic call lays out, and arguments +
+    temporaries stay under the chip's 16.91 GB (10.98 GB when this was
+    written: 6.91 of state, 4.07 of temporaries)."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from benchmark import model
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel.sharding import strategy_from_name
+    from ray_tpu.train.train_step import TrainState, make_train_step
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kanana-2-30b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "train_b2_s8192_dp.json")) as f:
+        mix = json.load(f)
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    program = model.family(config).program(config)
+    mesh = Mesh(np.array(v5e[:1]), ("data",))
+    strategy = strategy_from_name(mix["strategy"])
+    optimizer = optax.adamw(config["train"]["learning_rate"])
+    whole = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=whole),
+            tree)
+    params = jax.eval_shape(lambda: program.init(jax.random.PRNGKey(0)))
+    state = TrainState(placed(params),
+                       placed(jax.eval_shape(optimizer.init, params)),
+                       jax.ShapeDtypeStruct((), jnp.int32, sharding=whole))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (mix["global_batch"], mix["seq"] + 1), jnp.int32,
+        sharding=NamedSharding(mesh, strategy.batch_spec))}
+    step = make_train_step(
+        lambda p, b: program.loss(p, b, mesh,
+                                  strategy.activation_sharding(mesh)),
+        optimizer, mesh, strategy, sample_params=params)
+    compiled = step.lower(state, batch).compile()
+    memory = compiled.memory_analysis()
+    peak = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert 0.55 * 16.91e9 < peak < 0.92 * 16.91e9, peak
+    text = compiled.as_text()
+    # 5 layers x (forward + recomputed forward + dQ + dK/dV) flash calls,
+    # 4 sparse layers x (9 grouped matmuls + 3 recomputed + 3 tgmm)
+    for kernel, calls in (("flash_fwd", 10), ("flash_bwd_dq", 5),
+                          ("flash_bwd_dkv", 5), ("moe_gmm", 36),
+                          ("moe_tgmm", 12)):
+        found = len(set(re.findall(rf"%({kernel}[.\d]*) = ", text)))
+        assert found == calls, (kernel, found)

@@ -1,0 +1,588 @@
+"""Latent attention, the layer pattern, sigmoid routing with a selection
+bias, shared experts and one chip's share of the experts (models/gpt.py,
+ops/attention.py, ops/moe.py) against the plain float32 reference of
+benchmark/families/kanana.py, at a small size on the CPU: seeded random
+weights, the kernels in interpret mode."""
+
+import copy
+import hashlib
+import json
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def _read(*parts):
+    with open(os.path.join(ROOT, *parts)) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """benchmark/rehearsal/configs/tiny-kanana.json: 1 dense + 2 sparse
+    layers, experts 4..7 of 16 held, 3 a token, heads of 32 + 16 / 32."""
+    return _read("benchmark", "rehearsal", "configs", "tiny-kanana.json")
+
+
+# ---------------------------------------------------------------------------
+# (a) the flash kernels where q.k and v differ in width
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dqk,dv,seq,blocks", [
+    (192, 128, 256, {"block_q": 128, "block_k": 128}),   # the cell's widths
+    (192, 128, 256, {}),                                  # one square block
+    (96, 64, 128, {"block_q": 64, "block_k": 32}),        # not square
+    (48, 32, 64, {}),                                     # below a lane tile
+], ids=["192_128_blocks_of_128", "192_128_one_block", "96_64_ragged_blocks",
+        "48_32_short"])
+def test_flash_forward_and_gradients_where_qk_is_wider_than_v(
+        jax_cpu, dqk, dv, seq, blocks):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.attention import flash_attention, mha_reference
+    q, k, v = (jax.random.normal(key, (2, 2, seq, d), jnp.float32)
+               for key, d in zip(jax.random.split(jax.random.PRNGKey(0), 3),
+                                 (dqk, dqk, dv)))
+
+    def loss(attend):
+        return lambda q, k, v: (attend(q, k, v) ** 2).sum()
+    got = jax.value_and_grad(
+        loss(lambda q, k, v: flash_attention(q, k, v, **blocks)),
+        (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(loss(mha_reference), (0, 1, 2))(q, k, v)
+    assert flash_attention(q, k, v, **blocks).shape == (2, 2, seq, dv)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, w in zip(got[1], want[1]):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=1e-4)
+
+
+@pytest.mark.parametrize("seq,width,fwd,bwd", [
+    (1024, 64, (1024, 1024, 256), (1024, 1024, 128)),     # gpt2s
+    (2048, 64, (2048, 2048, 256), (2048, 2048, 128)),     # smollm-1.7b
+    (4096, 128, (2048, 2048, 256), (2048, 2048, 128)),    # olmoe-1b-7b
+], ids=["gpt2s", "smollm", "olmoe"])
+def test_blocks_of_the_cells_with_one_width_are_what_they_were(seq, width,
+                                                               fwd, bwd):
+    from ray_tpu.ops.attention import _block_sizes
+    blocks = _block_sizes(seq, seq, width)
+    assert blocks == _block_sizes(seq, seq, width, width)
+    assert blocks.fwd == fwd and blocks.dq == blocks.dkv == bwd
+
+
+def test_blocks_follow_the_wider_of_the_two_widths():
+    from ray_tpu.ops.attention import _block_sizes
+    # kanana2_train_1chip: q.k 192, v 128, 8192 positions
+    # (flash_attention hands the kernels q and k padded to 256)
+    blocks = _block_sizes(8192, 8192, 256, 128)
+    assert blocks.fwd == (2048, 2048, 256)
+    assert blocks.dq == blocks.dkv == (1024, 1024, 128)
+    assert _block_sizes(8192, 8192, 128, 192) == blocks
+    assert _block_sizes(8192, 8192, 128, 128).dq == (2048, 2048, 128)
+
+
+def test_a_head_of_192_takes_the_jnp_rotation():
+    """ops/rope.py's kernels tile whole heads in whole 128-lane tiles: a
+    head of 192 columns is neither a divisor nor a multiple, and
+    models/gpt.py's latent block rotates in jnp (`_rope_tail`)."""
+    from ray_tpu.ops.rope import _lane_tile, _rope_blocks
+    assert _lane_tile(192) is None
+    assert _rope_blocks(8192, 32, 192, 2) is None
+    assert _rope_blocks(8192, 32, 128, 2) is not None
+
+
+def test_rope_tail_rotates_the_last_columns_only(jax_cpu):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import _rope, _rope_tail
+    from ray_tpu.ops.rope import rope_table
+    t = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 3, 48), jnp.float32)
+    got = _rope_tail(t, rope_table(16, 16, 1e6), 16)
+    np.testing.assert_array_equal(got[..., :32], t[..., :32])
+    positions = jnp.broadcast_to(jnp.arange(16)[None, :], (2, 16))
+    want = _rope(t[..., 32:].transpose(0, 2, 1, 3), 1e6, positions)
+    np.testing.assert_allclose(got[..., 32:].transpose(0, 2, 1, 3), want,
+                               atol=1e-6)
+
+
+def test_deinterleaved_weights_give_deinterleaved_activations(jax_cpu):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import _deinterleaved
+    w = jax.random.normal(jax.random.PRNGKey(2), (8, 3 * 12), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(3), (5, 8), jnp.float32)
+    y = (x @ w).reshape(5, 3, 12)
+    tail = y[..., 4:].reshape(5, 3, 4, 2).swapaxes(-1, -2).reshape(5, 3, 8)
+    want = jnp.concatenate([y[..., :4], tail], -1).reshape(5, 36)
+    np.testing.assert_array_equal(x @ _deinterleaved(w, 3, 4, 8), want)
+
+
+# ---------------------------------------------------------------------------
+# (b) the program against the reference: loss and gradients
+# ---------------------------------------------------------------------------
+
+def _program(jax, config, attention, dtype=None):
+    import jax.numpy as jnp
+    from benchmark.families import kanana
+    from ray_tpu.models.gpt import GPTConfig, gpt_init
+    cfg = GPTConfig(**kanana.gpt_config_kwargs(config), attention=attention,
+                    dtype=dtype or jnp.float32, remat_policy="none")
+    params = gpt_init(jax.random.PRNGKey(3), cfg)
+    # a router with an opinion: at the init's 0.02 every score is 1/2
+    for i, layer in enumerate(params["layers"]):
+        if "moe" in layer:
+            layer["moe"]["router"] = 0.3 * jax.random.normal(
+                jax.random.PRNGKey(100 + i), layer["moe"]["router"].shape)
+    tokens = np.random.default_rng(5).integers(
+        0, config["vocab_size"], (2, 129), dtype=np.int32)
+    return cfg, params, jnp.asarray(tokens)
+
+
+@pytest.fixture(scope="module")
+def reference(jax_cpu, tiny):
+    jax = jax_cpu
+    from benchmark.families import kanana
+    _cfg, params, tokens = _program(jax, tiny, "reference")
+    with jax.default_matmul_precision("highest"):
+        logits = jax.jit(lambda p, t: kanana.reference_logits(
+            p, t[:, :-1], tiny))(params, tokens)
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p, t: kanana.reference_loss(p, t, tiny)))(params, tokens)
+    return logits, loss, grads
+
+
+@pytest.mark.parametrize("attention", ["reference", "flash"])
+def test_logits_loss_and_gradients_match_the_reference(jax_cpu, tiny,
+                                                       reference, attention):
+    """The latent block, the dense-then-sparse pattern, the sigmoid rule,
+    the shared expert and the held experts, in float32: the whole tree of
+    gradients, the selection bias's (exactly zero) included."""
+    jax = jax_cpu
+    from ray_tpu.models.gpt import gpt_forward, gpt_loss_and_aux
+    cfg, params, tokens = _program(jax, tiny, attention)
+    assert [sorted(layer) for layer in params["layers"]] == [
+        ["attn", "ln1", "ln2", "mlp"]] + [["attn", "ln1", "ln2", "moe"]] * 2
+    assert params["layers"][0]["mlp"]["w_up"].shape == (128, 256)
+    assert params["layers"][1]["moe"]["w_up"].shape == (4, 128, 64)
+    assert params["layers"][1]["moe"]["router"].shape == (128, 16)
+    assert params["layers"][1]["moe"]["shared"]["w_up"].shape == (128, 128)
+    with jax.default_matmul_precision("highest"):
+        logits, _ = jax.jit(lambda p, t: gpt_forward(p, t, cfg))(
+            params, tokens[:, :-1])
+        (loss, aux), grads = jax.jit(jax.value_and_grad(
+            lambda p, t: gpt_loss_and_aux(p, {"tokens": t}, cfg),
+            has_aux=True))(params, tokens)
+    ref_logits, ref_loss, ref_grads = reference
+    np.testing.assert_allclose(logits, ref_logits, atol=2e-5)
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
+    assert float(loss) == float(aux["xent"])        # no router loss
+    assert "router_balance_loss" not in aux
+    assert 0.0 < float(aux["expert_slots_held_share"]) < 1.0
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree_util.tree_leaves(ref_grads)):
+        np.testing.assert_allclose(
+            g, r, atol=1e-5 * max(1.0, float(np.abs(r).max())),
+            err_msg=jax.tree_util.keystr(path))
+    for layer in grads["layers"][1:]:
+        assert not np.any(np.asarray(layer["moe"]["router_bias"]))
+
+
+def test_the_bias_changes_the_selection_and_not_the_weights(jax_cpu, tiny):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import _route
+    cfg, params, _tokens = _program(jax, tiny, "reference")
+    m = dict(params["layers"][1]["moe"])
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 32, 128), jnp.float32)
+    scores = np.asarray(jax.nn.sigmoid(jnp.einsum(
+        "bsd,de->bse", x, m["router"],
+        precision=jax.lax.Precision.HIGHEST)))
+    weights, idx, _stats = _route(m, x, cfg)
+    unbiased = np.argsort(-scores, axis=-1)[..., :3]
+    biased = np.argsort(-(scores + np.asarray(m["router_bias"])),
+                        axis=-1)[..., :3]
+    assert np.any(np.sort(biased, -1) != np.sort(unbiased, -1))
+    np.testing.assert_array_equal(np.sort(np.asarray(idx), -1),
+                                  np.sort(biased, -1))
+    kept = np.take_along_axis(scores, np.asarray(idx), axis=-1)
+    np.testing.assert_allclose(
+        weights, 2.448 * kept / kept.sum(-1, keepdims=True), rtol=1e-6)
+    # a bias that picks the same experts leaves everything as it is
+    m["router_bias"] = jnp.zeros_like(m["router_bias"])
+    weights0, idx0, _ = _route(m, x, cfg)
+    np.testing.assert_array_equal(np.sort(np.asarray(idx0), -1),
+                                  np.sort(unbiased, -1))
+    np.testing.assert_allclose(weights0.sum(-1), 2.448, rtol=1e-6)
+
+
+def test_bfloat16_step_passes_the_per_token_check(jax_cpu, tiny):
+    """reference_loss with a `program_check` answers the loss where the
+    program's own forward (bf16, flash, the grouped-matmul kernels) agrees
+    with the reference token by token, and nan where a bound is broken."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import kanana
+    _cfg, params, tokens = _program(jax, tiny, "flash")
+    checked = dict(tiny, program_check={"logprob_median_tol": 0.05,
+                                        "logprob_rms_tol": 0.2})
+    with jax.default_matmul_precision("highest"):
+        plain = float(jax.jit(lambda p, t: kanana.reference_loss(
+            p, t, tiny))(params, tokens))
+        held = float(jax.jit(lambda p, t: kanana.reference_loss(
+            p, t, checked))(params, tokens))
+        checked["program_check"]["logprob_median_tol"] = 1e-6
+        broken = float(jax.jit(lambda p, t: kanana.reference_loss(
+            p, t, checked))(params, tokens))
+    assert held == plain and np.isnan(broken)
+    del jnp
+
+
+# ---------------------------------------------------------------------------
+# (c) the share: the parts add up to the whole
+# ---------------------------------------------------------------------------
+
+def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(jax_cpu, tiny):
+    """model-configs guide, section 4: what the four shares of one sparse
+    layer give, each the routed part of its own four experts plus the
+    shared expert that every chip computes alike, add up, with the shared
+    expert counted once, to the uncut reference's layer."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import kanana
+    from ray_tpu.models.gpt import (GPTConfig, Setting, _mlp_block,
+                                    _moe_block, gpt_init)
+    whole = copy.deepcopy(tiny)
+    del whole["share"]
+    whole["n_routed_experts"] = 16
+    full_cfg = GPTConfig(**kanana.gpt_config_kwargs(whole),
+                         dtype=jnp.float32, attention="reference")
+    assert full_cfg.experts_held is None
+    layer = gpt_init(jax.random.PRNGKey(7), full_cfg)["layers"][1]
+    layer["moe"]["router"] = 0.3 * jax.random.normal(
+        jax.random.PRNGKey(8), (128, 16))
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 64, 128), jnp.float32)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.vmap(lambda h: kanana.reference_experts(
+            layer["moe"], h, whole))(x)
+        shared = _mlp_block(layer["moe"]["shared"], x, full_cfg, Setting())
+        parts, held_share = [], 0.0
+        for rank in range(4):
+            cut = dict(tiny, share=dict(tiny["share"], rank=rank))
+            cfg = GPTConfig(**kanana.gpt_config_kwargs(cut),
+                            dtype=jnp.float32, attention="reference")
+            assert cfg.experts_held == (4 * rank, 4)
+            mine = {"moe": dict(layer["moe"], **{
+                name: layer["moe"][name][4 * rank:4 * rank + 4]
+                for name in ("w_gate", "w_up", "w_down")})}
+            part, stats = _moe_block(mine, x, cfg, Setting())
+            # the reference, given the same share, gives the same part
+            np.testing.assert_allclose(
+                part, jax.vmap(lambda h: kanana.reference_experts(
+                    mine["moe"], h, cut))(x), atol=2e-5)
+            parts.append(part - shared)
+            held_share += float(stats["expert_slots_held_share"])
+    np.testing.assert_allclose(sum(parts) + shared, want, atol=5e-5)
+    assert abs(held_share - 1.0) < 1e-6
+    # and a part is not the whole: the absent experts' sum is left out
+    assert float(jnp.abs(parts[0] + shared - want).max()) > 1e-2
+
+
+def test_plan_with_tokens_that_have_no_slot_here(jax_cpu):
+    """plan_dispatch(partial=True): a slot whose expert is not among the
+    groups gets no row; dispatch, the grouped matmul and combine, forward
+    and gradients, equal the masked dense computation over the groups."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+    t, k, d, f, groups = 40, 3, 16, 8, 4
+    rng = np.random.default_rng(0)
+    idx = rng.integers(-4, 12, (t, k)).astype(np.int32)   # 0..3 are here
+    idx[:5] = 9                                           # no slot here
+    idx[5:8] = [0, 1, 2]                                  # every slot here
+    here = (idx >= 0) & (idx < groups)
+    assert not here[:5].any() and here[5:8].all()
+    plan = moe.plan_dispatch(jnp.asarray(idx), groups, 8, partial=True)
+    np.testing.assert_array_equal(plan.token_held, here)
+    slots = np.asarray(plan.row_slot)
+    real = slots[slots < t * k]
+    assert sorted(real) == sorted(np.flatnonzero(here.reshape(-1)))
+    rows_of = np.asarray(plan.token_rows)
+    np.testing.assert_array_equal(slots[rows_of[here]],
+                                  np.flatnonzero(here.reshape(-1)))
+    assert (rows_of[~here] == 0).all()
+    tile_group = np.asarray(plan.tile_group)
+    for row, slot in enumerate(slots):
+        if slot < t * k:
+            assert idx.reshape(-1)[slot] == tile_group[row // 8]
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (t, d), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (groups, d, f), jnp.float32)
+    weights = jax.random.uniform(jax.random.PRNGKey(2), (t, k), jnp.float32)
+
+    def sparse(x, w, weights):
+        out = moe.grouped_matmul(moe.dispatch(x, plan), w, plan)
+        return (moe.combine(out, weights, plan) ** 2).sum()
+
+    def dense(x, w, weights):
+        every = jnp.einsum("td,gdf->tgf", x, w)
+        mask = (jnp.asarray(idx)[..., None] == jnp.arange(groups)) \
+            * weights[..., None]                            # [t, k, g]
+        return (jnp.einsum("tkg,tgf->tf", mask, every) ** 2).sum()
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(sparse, (0, 1, 2))(x, w, weights)
+        want = jax.value_and_grad(dense, (0, 1, 2))(x, w, weights)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for g, r in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, r, atol=1e-4)
+    assert not np.any(np.asarray(got[1][2])[~here])
+
+
+def test_tile_rows_follow_from_the_held_count():
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+    # the cell: 2 x 8192 tokens x 6 a token, 16 of 128 experts held
+    slots = 2 * 8192 * 6
+    assert moe.tile_rows(slots * 16 // 128, 16, jnp.bfloat16) == 128
+    # all of them held: what olmoe's call passes is the slots themselves
+    assert slots * 128 // 128 == slots
+
+
+# ---------------------------------------------------------------------------
+# (d) what the other configurations run is what it was
+# ---------------------------------------------------------------------------
+
+# sha256 of tiny-olmoe's train step (dp, one CPU device, batch 4 x 129,
+# adamw), lowered to StableHLO with locations stripped, as the commit before
+# this file lowers it (0d59224; `git archive` of it and of this tree gave the
+# same text, and the same for the dense step under dp and tp_fsdp). A PR
+# that means to change OLMoE's program records the new text's hash here.
+OLMOE_STEP_SHA256 = (
+    "6f65ebfe91be2c45a5345cd1409dd4740121b06fe81650ee06b93095623879ff")
+
+
+def test_tiny_olmoe_step_lowers_to_the_parents_text(jax_cpu):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    import optax
+    from benchmark import model
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+    from ray_tpu.train.train_step import init_train_state, make_train_step
+    config = _read("benchmark", "rehearsal", "configs", "tiny-olmoe.json")
+    program = model.family(config).program(config)
+    mesh = build_mesh(MeshConfig(data=1), jax.devices()[:1])
+    strategy = strategy_from_name("dp")
+    act = strategy.activation_sharding(mesh)
+    optimizer = optax.adamw(3e-4)
+    state = init_train_state(lambda: program.init(jax.random.PRNGKey(0)),
+                             optimizer, mesh, strategy)
+    step = make_train_step(lambda p, b: program.loss(p, b, mesh, act),
+                           optimizer, mesh, strategy,
+                           sample_params=state.params)
+    text = step.lower(state, {"tokens": jnp.zeros((4, 129), jnp.int32)}
+                      ).as_text(debug_info=False)
+    text = re.sub(r"loc\([^)]*\)", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == OLMOE_STEP_SHA256
+
+
+# ---------------------------------------------------------------------------
+# (e) arithmetic, rules, names
+# ---------------------------------------------------------------------------
+
+def test_param_count_at_the_cell_and_at_the_published_counts(jax_cpu, tiny):
+    jax = jax_cpu
+    from benchmark.families import kanana
+    from ray_tpu.models.gpt import GPTConfig, count_params, gpt_init
+    cell = _read("benchmark", "configs", "kanana-2-30b-a3b.json")
+    assert kanana.param_count(cell) == 575_955_968            # 575.9M
+    assert kanana.share(cell) == (0, 16, 128)
+    published = {k: v for k, v in cell.items() if k != "share"}
+    published.update(cell["published"])
+
+    def layers(n):
+        return kanana.param_count(dict(published, num_hidden_layers=n))
+    # the catalog's 36M + 128 x 4.7M a sparse layer, 64.1M the dense one
+    assert layers(3) - layers(2) == 36_049_536 + 128 * 4_718_592
+    assert layers(1) == (26_345_472 + 512 + 4096 + 3 * 2048 * 6144
+                         + 2 * 128256 * 2048 + 2048)
+    # and the arithmetic counts the program's own tree
+    cfg = GPTConfig(**kanana.gpt_config_kwargs(tiny))
+    assert kanana.param_count(tiny) == count_params(
+        jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg)))
+
+
+def test_flops_count_what_is_computed_here():
+    from benchmark.families import kanana
+    cell = _read("benchmark", "configs", "kanana-2-30b-a3b.json")
+    attention = (2048 * 32 * 192 + 2048 * 576 + 512 * 32 * 256 + 4096 * 2048)
+    active = (5 * attention + 3 * 2048 * 6144
+              + 4 * (2048 * 128 + 3 * 2048 * 1536
+                     + 6 * 16 / 128 * 3 * 2048 * 768) + 2048 * 16032)
+    assert kanana.train_flops_per_token(cell, 8192) == pytest.approx(
+        6.0 * active + 3.0 * 5 * 32 * (192 + 128) * 8192)
+    assert kanana.forward_flops_per_token(cell, 8192) == pytest.approx(
+        0.93e9, rel=0.01)                    # ISSUE 31's reckoning
+
+
+def test_kernel_arithmetic_counts_the_published_widths():
+    from benchmark.kernels import mla_attention
+    cell = _read("benchmark", "configs", "kanana-2-30b-a3b.json")
+    mix = _read("benchmark", "traffic", "train_b2_s8192_dp.json")
+    square = 2 * 32 * 8192 * 8192
+    fwd, dq, dkv = (f(cell, mix) for f in (
+        mla_attention.flash_fwd, mla_attention.flash_bwd_dq,
+        mla_attention.flash_bwd_dkv))
+    assert fwd[0] == square * (192 + 128)
+    # the five products of the backward, each at its own width
+    assert dq[0] + dkv[0] == square * (3 * 192 + 2 * 128)
+    tensor = 2 * 32 * 8192 * 2
+    assert fwd[1] == tensor * (2 * 192 + 2 * 128)
+    assert dq[1] == tensor * (3 * 192 + 2 * 128)
+    assert dkv[1] == tensor * (3 * 192 + 3 * 128)
+
+
+@pytest.mark.parametrize("strategy,column,row", [
+    ("tp", (None, "tensor"), ("tensor", None)),
+    ("tp_fsdp", ("fsdp", "tensor"), ("tensor", "fsdp"))])
+def test_every_new_leaf_gets_its_rule(jax_cpu, tiny, strategy, column, row):
+    jax = jax_cpu
+    from jax.sharding import PartitionSpec as P
+    from benchmark.families import kanana
+    from ray_tpu.models.gpt import GPTConfig, gpt_init
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+    cfg = GPTConfig(**kanana.gpt_config_kwargs(tiny))
+    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+    mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2),
+                      devices=jax.devices()[:4])
+    specs = jax.tree_util.tree_map(
+        lambda s: s.spec,
+        strategy_from_name(strategy).param_shardings(mesh, params))
+    attn, moe = specs["layers"][1]["attn"], specs["layers"][1]["moe"]
+    assert attn["wq"] == attn["w_kvb"] == P(*column)
+    assert attn["wo"] == P(*row)
+    assert attn["w_kva"] == P(None, None) and attn["kv_norm"]["scale"] == P(None)
+    assert moe["router_bias"] == P(None)
+    # the shared expert is a dense MLP, not a stack of experts
+    assert moe["shared"]["w_gate"] == moe["shared"]["w_up"] == P(*column)
+    assert moe["shared"]["w_down"] == P(*row)
+    assert moe["w_up"] == P("expert", *column)
+
+
+def test_sharded_step_equals_one_device(jax_cpu, tiny):
+    """One step of the whole tiny model on fsdp=2 x tensor=2 (whole heads
+    of wq, w_kvb and wo over `tensor`, the kernels per shard) equals the
+    one-device step."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    import optax
+    from benchmark.families import kanana
+    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+    from ray_tpu.train.train_step import init_train_state, make_train_step
+    cfg = GPTConfig(**kanana.gpt_config_kwargs(tiny), dtype=jnp.float32,
+                    attention="flash")
+    tokens = jnp.asarray(np.random.default_rng(5).integers(
+        0, 512, (4, 129), dtype=np.int32))
+
+    def one_step(name, axes, n):
+        mesh = build_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
+        strategy = strategy_from_name(name)
+        optimizer = optax.sgd(0.1)
+        state = init_train_state(
+            lambda: gpt_init(jax.random.PRNGKey(3), cfg), optimizer, mesh,
+            strategy)
+        step = make_train_step(
+            lambda p, b: gpt_loss(
+                p, b, cfg, mesh=mesh,
+                act_sharding=strategy.activation_sharding(mesh)),
+            optimizer, mesh, strategy, sample_params=state.params)
+        with jax.default_matmul_precision("highest"):
+            state, metrics = step(state, {"tokens": tokens})
+        return float(metrics["loss"]), jax.device_get(state.params)
+
+    ref_loss, ref_params = one_step("dp", {"data": 1}, 1)
+    loss, params = one_step("tp_fsdp", {"data": 1, "fsdp": 2, "tensor": 2}, 4)
+    assert abs(loss - ref_loss) < 1e-5
+    for (path, p), r in zip(jax.tree_util.tree_flatten_with_path(params)[0],
+                            jax.tree_util.tree_leaves(ref_params)):
+        np.testing.assert_allclose(p, r, rtol=1e-4, atol=1e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def test_pipeline_refuses_a_layer_pattern_by_name(jax_cpu, tiny):
+    jax = jax_cpu
+    from benchmark.families import kanana
+    from ray_tpu.models.gpt import GPTConfig
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.pipeline import make_gpt_pp_loss
+    cfg = GPTConfig(**dict(kanana.gpt_config_kwargs(tiny), n_layers=4))
+    mesh = build_mesh(MeshConfig(data=1, pipeline=2),
+                      devices=jax.devices()[:2])
+    with pytest.raises(ValueError, match="dense_layers=1.*layer pattern"):
+        make_gpt_pp_loss(cfg, mesh, num_microbatches=2)
+
+
+def test_the_new_scopes_are_regions_and_reach_the_compiled_step(jax_cpu,
+                                                                tiny):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import kanana
+    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
+    from ray_tpu.util import profiling
+    assert {"attn_latent", "moe_shared"} <= set(profiling.REGIONS)
+    cfg = GPTConfig(**kanana.gpt_config_kwargs(tiny), attention="flash")
+    params = gpt_init(jax.random.PRNGKey(0), cfg)
+    text = jax.jit(jax.grad(lambda p, t: gpt_loss(p, {"tokens": t}, cfg))
+                   ).lower(params, jnp.zeros((2, 129), jnp.int32)
+                           ).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("attn_proj/attn_latent", "moe/moe_shared", "moe/moe_route",
+                  "attn_core", "mlp"):
+        assert any(scope in name for name in names), scope
+
+
+def test_configuration_file_keeps_the_catalog_and_states_the_cut():
+    cell = _read("benchmark", "configs", "kanana-2-30b-a3b.json")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(catalog):
+        pytest.skip("no catalog here")
+    with open(catalog) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == cell["source"])
+    changed = {k for k, v in row["config"].items() if cell.get(k, "?") != v}
+    assert changed == set(cell["reduced"]) == {
+        "num_hidden_layers", "n_routed_experts", "vocab_size"}
+    assert cell["published"] == {k: row["config"][k] for k in cell["reduced"]}
+    assert cell["share"]["chips_per_layer"] * cell["n_routed_experts"] \
+        == cell["share"]["n_routed_experts"] == 128
+    assert cell["share"]["chips_per_layer"] * cell["vocab_size"] == 128256
+
+
+# ---------------------------------------------------------------------------
+# (f) the benchmark's own checks that need no chip, through their commands
+# ---------------------------------------------------------------------------
+
+@pytest.mark.timeout(600)
+@pytest.mark.parametrize("command,says", [
+    (["benchmark/rehearse.py", "kanana2_train_1chip", "--seconds", "2"],
+     "rehearsal passed"),
+    (["benchmark/selftest.py"], "selftest passed")],
+    ids=["the_cell_rehearsed", "selftest"])
+def test_the_benchmarks_cpu_checks_pass(command, says):
+    import subprocess
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)      # rehearse.py asks for its own devices
+    proc = subprocess.run([sys.executable] + command, cwd=ROOT, env=env,
+                          capture_output=True, text=True, timeout=540)
+    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+    assert says in proc.stdout
