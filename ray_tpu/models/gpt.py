@@ -9,7 +9,8 @@ optional sparse experts (top-k routing with real dispatch: ops/moe.py;
 softmax or sigmoid scores, a selection bias, shared experts, leading dense
 layers, and one chip's share of the experts: `experts_held`), optional
 latent attention (a low-rank k/v projection, q.k wider than v),
-per-layer jax.checkpoint (remat) for memory.
+per-layer jax.checkpoint (remat) for memory: a layer keeps its input and
+the flash kernel's output and row statistics, and recomputes the rest.
 
 Capability parity target: the models RLlib/Train wrap in the reference are
 torch modules; here the model is a (init, apply) pair compatible with pjit.
@@ -29,8 +30,8 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import moe
-from ray_tpu.ops.attention import (flash_attention, mha_reference,
-                                   qk_padding, ring_attention)
+from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT, flash_attention,
+                                   mha_reference, qk_padding, ring_attention)
 from ray_tpu.ops.rope import rope_split, rope_table
 
 
@@ -93,7 +94,11 @@ class GPTConfig:
     qk_rope_dim: int = 0
     v_head_dim: int = 0
     rope_interleaved: bool = False
-    # "full": recompute the whole layer in backward (min HBM, max FLOPs)
+    # "full": keep a layer's input and, of its activations, the flash
+    #   forward kernel's output and row statistics (lse) alone; everything
+    #   XLA runs in the layer is recomputed in backward, the Pallas
+    #   attention forward is not run a second time. The reference and ring
+    #   paths name nothing to keep: the whole layer is recomputed there.
     # "none": save everything (max HBM, min FLOPs)
     remat_policy: str = "full"
     attention: str = "flash"          # flash | reference | ring
@@ -588,7 +593,9 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         return where.pin(h + delta), stats
 
     if cfg.remat_policy == "full":
-        return jax.checkpoint(block)
+        return jax.checkpoint(
+            block, policy=jax.checkpoint_policies.save_only_these_names(
+                FLASH_OUT, FLASH_LSE))
     if cfg.remat_policy != "none":
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(expected 'full' | 'none')")

@@ -22,11 +22,18 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
+
+# What the flash forward's two results are called under a jax.checkpoint
+# (jax.ad_checkpoint.checkpoint_name): a policy that saves both names keeps
+# the kernel from running a second time in the backward pass.
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
 
 
 # ---------------------------------------------------------------------------
@@ -503,6 +510,14 @@ def _make_flash_fn(causal, sm_scale, blocks, interpret):
     def fwd(q, k, v):
         out, lse = _flash_forward(q, k, v, causal, sm_scale, blocks,
                                   interpret)
+        # the NAMED values are both the primal output and the residuals: a
+        # remat policy that saves the two names then has all the forward
+        # kernel made (q, k, v come from the rematted projections), and the
+        # kernel is dead code in the recompute pass. Naming the output
+        # alone, outside, would leave lse to a second run of the kernel.
+        # Outside a jax.checkpoint the names are identities.
+        out = checkpoint_name(out, FLASH_OUT)
+        lse = checkpoint_name(lse, FLASH_LSE)
         return out, (q, k, v, out, lse)
 
     def bwd(res, g):

@@ -98,22 +98,18 @@ def test_rope_kernels_compile_for_v5e(v5e, backward, shape):
     assert text.count("tpu_custom_call") >= (2 if backward else 1)
 
 
-@pytest.mark.parametrize("widths,batch,seq", [
+BLOCK_WIDTHS = [
     (dict(), SHAPE[0], SHAPE[2]),
-    (dict(d_model=2048, n_heads=32, max_seq=2048), 4, 2048)],
-    ids=["gpt2s_6_heads_a_shard", "smollm_16_heads_a_shard"])
-def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
-                                                    batch, seq):
-    """The attention block at GPT-2 small's and at SmolLM-1.7B's widths,
-    forward and backward, under tp_fsdp on fsdp=2 x tensor=2. Without the shard_map around the flash
-    call the chip's compiler refuses it ("Mosaic kernels cannot be
-    automatically partitioned"); the CPU tests cannot see that, because
-    the interpreted kernel is plain XLA ops that GSPMD partitions."""
+    (dict(d_model=2048, n_heads=32, d_ff=8192, max_seq=2048), 4, 2048)]
+BLOCK_IDS = ["gpt2s_6_heads_a_shard", "smollm_16_heads_a_shard"]
+
+
+def _layer_on_four_chips(v5e, monkeypatch, widths, batch, seq):
+    """One layer's parameters and input as shapes under tp_fsdp on the
+    described fsdp=2 x tensor=2 mesh -> (cfg, mesh, strategy, layer, x)."""
     import jax
-    import jax.numpy as jnp
     from ray_tpu.models import gpt
     from ray_tpu.ops import attention
-    from ray_tpu.ops.rope import rope_table
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
     from ray_tpu.parallel.sharding import strategy_from_name
 
@@ -131,6 +127,24 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
                                               sharding=sh), layer, layer_sh)
     x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype,
                              sharding=strategy.activation_sharding(mesh))
+    return cfg, mesh, strategy, layer, x
+
+
+@pytest.mark.parametrize("widths,batch,seq", BLOCK_WIDTHS, ids=BLOCK_IDS)
+def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
+                                                    batch, seq):
+    """The attention block at GPT-2 small's and at SmolLM-1.7B's widths,
+    forward and backward, under tp_fsdp on fsdp=2 x tensor=2. Without the shard_map around the flash
+    call the chip's compiler refuses it ("Mosaic kernels cannot be
+    automatically partitioned"); the CPU tests cannot see that, because
+    the interpreted kernel is plain XLA ops that GSPMD partitions."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import gpt
+    from ray_tpu.ops.rope import rope_table
+
+    cfg, mesh, _, layer, x = _layer_on_four_chips(v5e, monkeypatch, widths,
+                                                  batch, seq)
 
     def loss(layer, x):
         table = rope_table(seq, cfg.head_dim, cfg.rope_theta)
@@ -147,6 +161,51 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
     # the tensor-parallel out projection and the fsdp weights need them
     assert "all-reduce" in text or "reduce-scatter" in text
     assert "all-gather" in text
+
+
+def _kernel_ops(text, kernel):
+    """The compiled text's lines that define a call of a Mosaic kernel."""
+    return [line for line in text.splitlines()
+            if re.match(rf"\s*%?{kernel}[.\d]* = ", line)]
+
+
+@pytest.mark.parametrize("widths,batch,seq", BLOCK_WIDTHS, ids=BLOCK_IDS)
+def test_rematted_layer_runs_the_flash_forward_once_on_four_chips(
+        v5e, monkeypatch, capfd, widths, batch, seq):
+    """The whole layer under remat_policy="full" (layer_fn's
+    jax.checkpoint), value and gradient, on the same mesh: the policy that
+    keeps the flash forward's output and lse reaches the names inside
+    _per_shard's shard_map, so the compiled program calls flash_fwd once
+    and not again under the backward's rematted computation, where
+    rope_split still is (what XLA and the cheap kernels run is
+    recomputed); and keeping two more sharded tensors a layer brings no
+    resharding of the partitioner's own."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import gpt
+
+    cfg, mesh, strategy, layer, x = _layer_on_four_chips(
+        v5e, monkeypatch, widths, batch, seq)
+    assert cfg.remat_policy == "full"
+
+    def loss(layer, x):
+        block = gpt.layer_fn(cfg, seq, gpt.Setting(
+            mesh, strategy.activation_sharding(mesh)))
+        return block(x, layer)[0].astype(jnp.float32).sum()
+
+    capfd.readouterr()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layer, x).compile().as_text()
+    logged = capfd.readouterr().err
+    forward = _kernel_ops(text, "flash_fwd")
+    assert len(forward) == 1, forward
+    assert "rematted_computation" not in forward[0]
+    for kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
+        assert len(_kernel_ops(text, kernel)) == 1, kernel
+    rope = _kernel_ops(text, "rope_split")
+    assert len(rope) == 6
+    assert sum("rematted_computation" in line for line in rope) == 3
+    assert "involuntary full rematerialization" not in (text + logged).lower()
 
 
 @pytest.mark.parametrize("widths,batch,seq,four_chips,parent_temp", [
@@ -306,10 +365,10 @@ def test_latent_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch):
             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
     assert 0.55 * 16.91e9 < peak < 0.92 * 16.91e9, peak
     text = compiled.as_text()
-    # 5 layers x (forward + recomputed forward + dQ + dK/dV) flash calls,
-    # 4 sparse layers x (9 grouped matmuls + 3 recomputed + 3 tgmm)
-    for kernel, calls in (("flash_fwd", 10), ("flash_bwd_dq", 5),
+    # 5 layers x (forward, kept through the remat, + dQ + dK/dV) flash
+    # calls, 4 sparse layers x (9 grouped matmuls + 3 recomputed + 3 tgmm)
+    for kernel, calls in (("flash_fwd", 5), ("flash_bwd_dq", 5),
                           ("flash_bwd_dkv", 5), ("moe_gmm", 36),
                           ("moe_tgmm", 12)):
-        found = len(set(re.findall(rf"%({kernel}[.\d]*) = ", text)))
+        found = len(_kernel_ops(text, kernel))
         assert found == calls, (kernel, found)

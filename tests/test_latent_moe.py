@@ -360,12 +360,12 @@ def test_tile_rows_follow_from_the_held_count():
 # ---------------------------------------------------------------------------
 
 # sha256 of tiny-olmoe's train step (dp, one CPU device, batch 4 x 129,
-# adamw), lowered to StableHLO with locations stripped, as the commit before
-# this file lowers it (0d59224; `git archive` of it and of this tree gave the
-# same text, and the same for the dense step under dp and tp_fsdp). A PR
-# that means to change OLMoE's program records the new text's hash here.
+# adamw), lowered to StableHLO with locations stripped. A PR that means to
+# change OLMoE's program records the new text's hash here: the layer's remat
+# keeps the flash forward's output and lse since PR 32 (6f65ebfe..9ff
+# before it, the text of every tree from 0d59224 on).
 OLMOE_STEP_SHA256 = (
-    "6f65ebfe91be2c45a5345cd1409dd4740121b06fe81650ee06b93095623879ff")
+    "470f200b9d74ed64e7f41af42c04bc49c90f7e650ac9d15073dcc05a65b4608e")
 
 
 def test_tiny_olmoe_step_lowers_to_the_parents_text(jax_cpu):
