@@ -8,7 +8,9 @@ flash-attention Pallas kernel, optional ring attention (sequence sharded),
 optional sparse experts (top-k routing with real dispatch: ops/moe.py;
 softmax or sigmoid scores, a selection bias, shared experts, leading dense
 layers, and one chip's share of the experts: `experts_held`), optional
-latent attention (a low-rank k/v projection, q.k wider than v),
+latent attention (a low-rank k/v projection, q.k wider than v), optional
+grouped-query attention with a norm a head, optional gated
+short-convolution layers among the attention layers (ops/short_conv.py),
 per-layer jax.checkpoint (remat) for memory: a layer keeps its input and
 the flash kernel's output and row statistics, and recomputes the rest.
 
@@ -33,6 +35,7 @@ from ray_tpu.ops import moe
 from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT, flash_attention,
                                    mha_reference, qk_padding, ring_attention)
 from ray_tpu.ops.rope import rope_split, rope_table
+from ray_tpu.ops.short_conv import short_conv
 
 
 @dataclass(frozen=True)
@@ -41,6 +44,9 @@ class GPTConfig:
     d_model: int = 768
     n_layers: int = 12
     n_heads: int = 12
+    # Key/value heads (grouped-query attention): query head h reads
+    # key/value head h // (n_heads // n_kv_heads). 0 = n_heads.
+    n_kv_heads: int = 0
     d_ff: int = 3072                  # the MLP's width; of ONE expert's, if sparse
     max_seq: int = 1024
     dtype: Any = jnp.bfloat16
@@ -48,6 +54,16 @@ class GPTConfig:
     rmsnorm_eps: float = 1e-5
     # RMSNorm over the whole q and k projections, before the head split.
     qk_norm: bool = False
+    # RMSNorm over each head's columns of q and of k, before the rotation:
+    # one learned scale of head_dim for q and one for k, shared by the heads.
+    qk_head_norm: bool = False
+    # The token mixer of each layer: "attention" | "conv", one a layer.
+    # None = attention everywhere. A "conv" layer is a gated short
+    # convolution: [B | C | X] = three projections of the normed input,
+    # C * filter(B * X) with a causal depthwise filter of conv_filter taps
+    # a channel, then an output projection. No bias, no activation.
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    conv_filter: int = 3
     # 0 = a dense MLP a layer; >0 = that many experts in its place, each
     # token through the expert_top_k the router gives the most probability
     # (used as they come out of the softmax, not renormalised). The loss
@@ -66,6 +82,7 @@ class GPTConfig:
     router_score: str = "softmax"     # softmax | sigmoid
     router_bias_scale: float = 0.0
     router_renormalise: bool = False
+    router_renormalise_eps: float = 1e-20   # added to the kept weights' sum
     router_scale: float = 1.0
     # A dense SwiGLU n_shared_experts x d_ff wide beside the routed sum,
     # every token through it.
@@ -104,9 +121,33 @@ class GPTConfig:
     attention: str = "flash"          # flash | reference | ring
     tie_embeddings: bool = False
 
+    def __post_init__(self):
+        kinds = self.layer_kinds
+        if kinds is not None and (
+                len(kinds) != self.n_layers
+                or set(kinds) - {"attention", "conv"}):
+            raise ValueError(
+                f"layer_kinds {kinds!r}: expected n_layers={self.n_layers} "
+                "of 'attention' | 'conv'")
+        if self.n_heads % self.kv_heads:
+            raise ValueError(f"n_kv_heads={self.n_kv_heads} does not divide "
+                             f"n_heads={self.n_heads}")
+        if self.kv_heads != self.n_heads and (self.kv_latent_dim
+                                              or self.attention == "ring"):
+            raise ValueError(
+                f"n_kv_heads={self.n_kv_heads} != n_heads={self.n_heads}: "
+                "grouped-query attention is built for the flash and "
+                "reference paths of multi-head attention, not for "
+                + ("a latent block" if self.kv_latent_dim
+                   else "attention='ring'"))
+
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
 
     @property
     def qk_head_dim(self) -> int:
@@ -163,7 +204,16 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
             "ln1": {"scale": jnp.ones((d,), jnp.float32)},
             "ln2": {"scale": jnp.ones((d,), jnp.float32)},
         }
-        if cfg.kv_latent_dim:
+        if cfg.layer_kinds and cfg.layer_kinds[i] == "conv":
+            # w_in: the published [d, 3d] as its three chunks B, C, X
+            layer["conv"] = {
+                "w_in": _init_dense(k[0], (3, d, d), scale=1.0 / math.sqrt(d)),
+                "filter": _init_dense(k[1], (d, cfg.conv_filter),
+                                      scale=1.0 / math.sqrt(cfg.conv_filter)),
+                "w_out": _init_dense(k[3], (d, d),
+                                     scale=1.0 / math.sqrt(2 * cfg.n_layers * d)),
+            }
+        elif cfg.kv_latent_dim:
             r, h = cfg.kv_latent_dim, cfg.n_heads
             layer["attn"] = {
                 "wq": _init_dense(k[0], (d, h * cfg.qk_head_dim)),
@@ -176,16 +226,21 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
                     scale=1.0 / math.sqrt(2 * cfg.n_layers * h * cfg.v_head_dim)),
             }
         else:
+            kv = cfg.kv_heads * cfg.head_dim
             layer["attn"] = {
                 "wq": _init_dense(k[0], (d, d)),
-                "wk": _init_dense(k[1], (d, d)),
-                "wv": _init_dense(k[2], (d, d)),
+                "wk": _init_dense(k[1], (d, kv)),
+                "wv": _init_dense(k[2], (d, kv)),
                 "wo": _init_dense(k[3], (d, d),
                                   scale=1.0 / math.sqrt(2 * cfg.n_layers * d)),
             }
-        if cfg.qk_norm:
+        if "attn" in layer and cfg.qk_norm:
             layer["attn"]["q_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
             layer["attn"]["k_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
+        if "attn" in layer and cfg.qk_head_norm:
+            for name in ("q_head_norm", "k_head_norm"):
+                layer["attn"][name] = {
+                    "scale": jnp.ones((cfg.head_dim,), jnp.float32)}
         if e > 0 and i >= cfg.dense_layers:
             # stacked [held, fan-in, fan-out]: the scale is the fan-in's
             layer["moe"] = {
@@ -256,6 +311,29 @@ def _rmsnorm(x, scale, eps, psum=_whole):
         return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
+def _head_rmsnorm(y, scale, eps):
+    """RMSNorm over each head's columns of y [B, S, heads * D], scale [D]
+    shared by the heads, with the columns left where they are. Through a
+    [.., heads, D] view a head of 64 columns lies on 128 lanes, and the
+    chip's compiler copies the float32 tensor into that layout and back,
+    in every phase (PERF.md, PR 33). Here the heads' mean squares are a
+    product with the 0/1 matrix that says which column is in which head,
+    and so is their way back to the columns: two thin matmuls, lane dense.
+    One bf16 pass is enough for the squares (64 roundings of 2^-9 average
+    out far below the result's own rounding); the way back takes three, so
+    that a head's factor reaches its columns to 2^-16."""
+    with jax.named_scope("norm"):
+        width, dim = y.shape[-1], scale.shape[0]
+        member = (jnp.arange(width)[:, None] // dim
+                  == jnp.arange(width // dim)[None, :]).astype(jnp.float32)
+        y32 = y.astype(jnp.float32)
+        mean_sq = jnp.einsum("bsw,wh->bsh", y32 * y32, member,
+                             precision=jax.lax.Precision.DEFAULT) / dim
+        factor = jnp.einsum("bsh,wh->bsw", jax.lax.rsqrt(mean_sq + eps),
+                            member, precision=jax.lax.Precision.HIGH)
+        return (y32 * factor * jnp.tile(scale, width // dim)).astype(y.dtype)
+
+
 def _rope(x, theta: float, positions):
     """Rotary position embeddings; x: [B, H, S, D]."""
     d = x.shape[-1]
@@ -291,10 +369,11 @@ def _per_shard(fn, mesh, in_dims, out_dims):
 
 
 def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh):
-    """The projections' outputs [B, S, H*D] through the head split, the
-    rotation (ops/rope.py: one pass a tensor, straight into the kernels'
-    [B, H, S, D]) and the flash kernel, per shard: the H*D columns are
-    whole heads, each device attends its own (batch, head) slice."""
+    """The projections' outputs (q [B, S, H*D], k and v [B, S, Hkv*D])
+    through the head split, the rotation (ops/rope.py: one pass a tensor,
+    straight into the kernels' [B, heads, S, D]) and the flash kernel, per
+    shard: the columns are whole heads, each device attends its own (batch,
+    head) slice, a key/value head with the query heads that read it."""
     def split_and_attend(q, k, v, *table):
         with jax.named_scope("attn_proj"):
             q = rope_split(q, cfg.head_dim, table)
@@ -414,25 +493,33 @@ def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting):
 
 
 def _multi_head_attention(layer, x, cfg: GPTConfig, table, where: Setting):
-    """q, k, v of one width from three projections -> the heads' outputs
-    [B, H, S, head_dim]."""
+    """q, k, v of one head width from three projections (k and v at the
+    key/value heads' count) -> the heads' outputs [B, H, S, head_dim]."""
     b, s, _ = x.shape
     hd = cfg.head_dim
     dt = cfg.dtype
+    tensor = 1 if where.mesh is None else where.mesh.shape.get("tensor", 1)
+    if cfg.kv_heads % tensor:
+        raise ValueError(f"n_kv_heads={cfg.kv_heads} is not whole key/value "
+                         f"heads over tensor={tensor}")
 
-    def proj(w, norm=None):
+    def proj(w, norm=None, head_norm=None):
         y = jnp.einsum("bsd,de->bse", x, w.astype(dt))
         if norm is not None:
             y = _rmsnorm(y, norm["scale"], cfg.rmsnorm_eps, where.psum)
+        if head_norm is not None:
+            # a head is whole wherever its columns are: no psum
+            y = _head_rmsnorm(y, head_norm["scale"], cfg.rmsnorm_eps)
         return y
 
     def heads(y):
         return y.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
 
     with jax.named_scope("attn_proj"):
-        q = proj(layer["attn"]["wq"], layer["attn"].get("q_norm"))
-        k = proj(layer["attn"]["wk"], layer["attn"].get("k_norm"))
-        v = proj(layer["attn"]["wv"])
+        a = layer["attn"]
+        q = proj(a["wq"], a.get("q_norm"), a.get("q_head_norm"))
+        k = proj(a["wk"], a.get("k_norm"), a.get("k_head_norm"))
+        v = proj(a["wv"])
     if cfg.attention not in ("ring", "reference"):
         return _flash_on_mesh(q, k, v, table, cfg, where.mesh)
     with jax.named_scope("attn_proj"):
@@ -444,6 +531,28 @@ def _multi_head_attention(layer, x, cfg: GPTConfig, table, where: Setting):
         if cfg.attention == "ring":
             return ring_attention(q, k, v, mesh=where.mesh, causal=True)
         return mha_reference(q, k, v, causal=True)
+
+
+def _conv_block(m, x, cfg: GPTConfig, where: Setting):
+    """The gated short convolution in attention's place: B, C, X = three
+    projections of x (the chunks of the published in_proj), C * filter(B *
+    X) along the sequence (ops/short_conv.py: a causal depthwise filter,
+    zeros before the sequence's start), then the output projection. The
+    filter works on a channel alone, so column-parallel w_in and
+    row-parallel w_out leave it local to a shard of 'tensor'. Scope `conv`
+    holds the projections, `conv_mix` (nested) the gates and the filter."""
+    dt = cfg.dtype
+    with jax.named_scope("conv"):
+        gate_in, gate_out, value = (
+            jnp.einsum("bsd,de->bse", x, m["w_in"][j].astype(dt))
+            for j in range(3))
+        with jax.named_scope("conv_mix"):
+            columns = ("batch", None, "heads")
+            y = _per_shard(short_conv, where.mesh,
+                           (columns,) * 3 + (("heads", None),), columns)(
+                gate_in, gate_out, value, m["filter"])
+        return where.psum(
+            jnp.einsum("bsd,de->bse", y, m["w_out"].astype(dt)))
 
 
 def _mlp_block(m, x, cfg: GPTConfig, where: Setting):
@@ -487,7 +596,8 @@ def _route(m, x, cfg: GPTConfig):
     else:
         weights, idx = jax.lax.top_k(scores, k)
     if cfg.router_renormalise:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + cfg.router_renormalise_eps)
     if cfg.router_scale != 1.0:
         weights = weights * cfg.router_scale
     load = jnp.mean(jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32),
@@ -570,8 +680,9 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
 def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     """(x [B, seq, D], one layer's parameters) -> (x, the router's
     statistics: _route's dict for a sparse layer, {} for a dense one; which
-    it is, the layer's own parameters say, as gpt_init built them). The
-    one transformer block, rematted as cfg.remat_policy says, for whoever
+    it is, and whether its mixer is attention or the short convolution, the
+    layer's own parameters say, as gpt_init built them). The one
+    transformer block, rematted as cfg.remat_policy says, for whoever
     walks the layers: gpt_backbone loops over their list, a stage of
     parallel/pipeline.py scans over stacked ones."""
     # once a step, not once a layer and recompute: outside the remat
@@ -580,8 +691,12 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
                            else cfg.head_dim, cfg.rope_theta)
 
     def block(x, layer):
-        h = where.pin(x + _attention_block(layer, _rmsnorm(
-            x, layer["ln1"]["scale"], cfg.rmsnorm_eps), cfg, table, where))
+        normed = _rmsnorm(x, layer["ln1"]["scale"], cfg.rmsnorm_eps)
+        if "conv" in layer:
+            mixed = _conv_block(layer["conv"], normed, cfg, where)
+        else:
+            mixed = _attention_block(layer, normed, cfg, table, where)
+        h = where.pin(x + mixed)
         normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
         if "moe" in layer:
             with jax.named_scope("moe"):

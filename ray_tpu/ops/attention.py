@@ -7,9 +7,13 @@ Net-new relative to the reference, which has no sequence-parallel support
 blockwise-softmax partials for its local Q shard — compute overlaps the
 ICI transfer, HBM never holds the full sequence.
 
-Layouts: q and k are [batch, num_heads, seq, Dqk], v and the output
-[batch, num_heads, seq, Dv]. The two widths may differ (latent attention:
-q.k over 192 columns, v 128 wide); the scale defaults to 1 / sqrt(Dqk).
+Layouts: q and the output are [batch, num_heads, seq, D], k and v
+[batch, num_kv_heads, seq, D]: q.k over Dqk columns, v and the output Dv
+wide. The two widths may differ (latent attention: q.k over 192 columns, v
+128 wide); the scale defaults to 1 / sqrt(Dqk). num_kv_heads divides
+num_heads (grouped-query attention): query head h reads key/value head
+h // (num_heads // num_kv_heads), through the kernels' index maps, so k and
+v are never repeated in HBM and dK, dV leave at num_kv_heads.
 """
 
 from __future__ import annotations
@@ -44,6 +48,10 @@ def mha_reference(q, k, v, *, causal: bool = True,
                   sm_scale: Optional[float] = None):
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if k.shape[1] != q.shape[1]:
+        # grouped queries: a key/value head for each of its query heads
+        k, v = (jnp.repeat(t, q.shape[1] // k.shape[1], axis=1)
+                for t in (k, v))
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if causal:
         qlen, klen = q.shape[2], k.shape[2]
@@ -60,9 +68,11 @@ def mha_reference(q, k, v, *, causal: bool = True,
 # Three kernels, FlashAttention-2: flash_fwd, flash_bwd_dq, flash_bwd_dkv.
 # Each runs on a grid (batch*head, outer block, major block). The last grid
 # dimension is the reduction — k blocks for the forward and dQ, q blocks for
-# dK/dV — with the accumulators in VMEM scratch, zeroed at its first step and
-# written out at its last, so VMEM holds blocks and never a whole sequence,
-# and the next major block is fetched while this one is computed. A grid
+# dK/dV (under grouped queries: batch*kv_head first, and the q blocks of each
+# of the group's query heads in turn last) — with the accumulators in VMEM
+# scratch, zeroed at its first step and written out at its last, so VMEM
+# holds blocks and never a whole sequence, and the next major block is
+# fetched while this one is computed. A grid
 # step costs about as much as a 128 x 128 tile's work, so blocks are large,
 # and inside a step a statically unrolled loop walks the outer block in row
 # groups, one score tile [group, major block] each: wide tiles, because the
@@ -313,17 +323,21 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, sm_scale,
-                          causal, group, offset):
-    """Grid (batch*head, k block, q major block): dK and dV of one k block,
-    on transposed tiles [keys, queries].
+                          causal, group, offset, q_blocks=None):
+    """Grid (batch*kv_head, k block, q major block): dK and dV of one k
+    block, on transposed tiles [keys, queries].
 
-    dV = Pᵀ·dO; dK = scale · dSᵀ·Q.
+    dV = Pᵀ·dO; dK = scale · dSᵀ·Q. q_blocks: None where a key/value head
+    has one query head; else the q blocks of one query head, and the last
+    grid dimension walks them once for each query head of the group, all
+    into the same accumulators.
     """
     bk = k_ref.shape[1]
     bq = q_ref.shape[1]
-    ki, qi = pl.program_id(1), pl.program_id(2)
+    ki, step = pl.program_id(1), pl.program_id(2)
+    qi = step if q_blocks is None else step % q_blocks
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
@@ -343,7 +357,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _for_tiles(causal, True, ki * bk - (qi * bq + offset), bk, bq, group, tile)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = (dk_acc_ref[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
@@ -358,32 +372,53 @@ _GRID_SEMANTICS_WIDE = pltpu.CompilerParams(
     vmem_limit_bytes=48 << 20)
 
 
-def _compiler_params(head_dim: int):
-    return _GRID_SEMANTICS if head_dim < LANES else _GRID_SEMANTICS_WIDE
+def _compiler_params(head_dim: int, seq: int, block: int):
+    """Narrower heads fit the default 16 MB in one block a row (the dense
+    cells: 1024 and 2048 positions); several blocks of 2048 a row do not
+    (16.9 MB at a head of 64 and 8192 positions: the straight-line and the
+    masked branch of a step both hold their tiles)."""
+    if head_dim < LANES and (seq == block or block < 2048):
+        return _GRID_SEMANTICS
+    return _GRID_SEMANTICS_WIDE
 
 
-def _kv_index(causal, offset, bq, bkm, num_k):
-    """Index map of a K/V major block on a (b, q block, k block) grid. Past
-    the diagonal it repeats the last block the q block needs: Pallas does
-    not fetch a block whose index did not change."""
+def _kv_index(causal, offset, bq, bkm, num_k, rep):
+    """Index map of a K/V major block on a (b, q block, k block) grid, b
+    over batch*head and the block of key/value head b // rep (rep query
+    heads a key/value head). Past the diagonal it repeats the last block
+    the q block needs: Pallas does not fetch a block whose index did not
+    change."""
+    def head(b):
+        return b if rep == 1 else b // rep
+
     if not causal:
-        return lambda b, i, j: (b, j, 0)
+        return lambda b, i, j: (head(b), j, 0)
 
     def index(b, i, j):
         last = jnp.maximum((i + 1) * bq - 1 + offset, 0) // bkm
-        return (b, jnp.minimum(j, jnp.minimum(last, num_k - 1)), 0)
+        return (head(b), jnp.minimum(j, jnp.minimum(last, num_k - 1)), 0)
     return index
+
+
+def _query_heads_a_kv_head(q, k, v) -> int:
+    heads, kv_heads = q.shape[1], k.shape[1]
+    if v.shape[1] != kv_heads or heads % kv_heads:
+        raise ValueError(
+            f"flash_attention needs k and v of one head count that divides "
+            f"q's, got q {heads}, k {kv_heads}, v {v.shape[1]}")
+    return heads // kv_heads
 
 
 def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret):
     batch, heads, seq_q, d = q.shape
     seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
+    rep = _query_heads_a_kv_head(q, k, v)
     bq, bkm, group = blocks.fwd
     offset = seq_k - seq_q
     kernel = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
                                causal=causal, group=group, offset=offset)
-    kv_index = _kv_index(causal, offset, bq, bkm, seq_k // bkm)
+    kv_index = _kv_index(causal, offset, bq, bkm, seq_k // bkm, rep)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, seq_q // bq, seq_k // bkm),
@@ -407,11 +442,11 @@ def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret):
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
-        compiler_params=_compiler_params(max(d, dv)),
+        compiler_params=_compiler_params(max(d, dv), seq_k, bkm),
         interpret=interpret,
         name="flash_fwd",
-    )(q.reshape(bh, seq_q, d), k.reshape(bh, seq_k, d),
-      v.reshape(bh, seq_k, dv))
+    )(q.reshape(bh, seq_q, d), k.reshape(bh // rep, seq_k, d),
+      v.reshape(bh // rep, seq_k, dv))
     return out.reshape(batch, heads, seq_q, dv), lse
 
 
@@ -420,20 +455,21 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
     batch, heads, seq_q, d = q.shape
     seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
+    rep = _query_heads_a_kv_head(q, k, v)
     qr = q.reshape(bh, seq_q, d)
-    kr = k.reshape(bh, seq_k, d)
-    vr = v.reshape(bh, seq_k, dv)
+    kr = k.reshape(bh // rep, seq_k, d)
+    vr = v.reshape(bh // rep, seq_k, dv)
     gr = g.reshape(bh, seq_q, dv)
     # delta_i = rowsum(dO_i * O_i): cheap elementwise, fused by XLA.
     delta = jnp.sum(gr.astype(jnp.float32)
                     * out.reshape(bh, seq_q, dv).astype(jnp.float32),
                     axis=-1).reshape(bh, 1, seq_q)
     offset = seq_k - seq_q
-    params = _compiler_params(max(d, dv))
+    params = _compiler_params(max(d, dv), seq_k, blocks.dq[1])
 
     # q, k and dq, dk move in blocks d wide; v, dO and dv in blocks dv wide
     bq, bkm, group = blocks.dq
-    kv_index = _kv_index(causal, offset, bq, bkm, seq_k // bkm)
+    kv_index = _kv_index(causal, offset, bq, bkm, seq_k // bkm, rep)
 
     def q_spec(width):
         return pl.BlockSpec((1, bq, width), lambda b, i, j: (b, i, 0))
@@ -453,34 +489,46 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
         name="flash_bwd_dq",
     )(qr, kr, vr, gr, lse, delta)
 
+    # dK, dV: a grid row a key/value head. Its rep query heads' q blocks
+    # follow one another on the last grid dimension (step -> query head
+    # step // num_q, q block step % num_q), summed in the same scratch, so
+    # dK and dV leave the kernel at the key/value heads' count.
     bk, bqm, group = blocks.dkv
     num_q = seq_q // bqm
+
+    def q_head(b, j):
+        return b if rep == 1 else b * rep + j // num_q
+
+    def q_of(j):
+        return j if rep == 1 else j % num_q
     if causal:
         # q major blocks above the diagonal are skipped, and not fetched:
         # the index repeats the first one this k block needs
         def q_block(i, j):
             first = jnp.maximum(i * bk - offset, 0) // bqm
-            return jnp.maximum(j, jnp.minimum(first, num_q - 1))
+            return jnp.maximum(q_of(j), jnp.minimum(first, num_q - 1))
     else:
         def q_block(i, j):
-            return j
+            return q_of(j)
     def q_spec(width):
         return pl.BlockSpec((1, bqm, width),
-                            lambda b, i, j: (b, q_block(i, j), 0))
+                            lambda b, i, j: (q_head(b, j), q_block(i, j), 0))
 
     def kv_spec(width):
         return pl.BlockSpec((1, bk, width), lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((1, 1, bqm), lambda b, i, j: (b, 0, q_block(i, j)))
+    row_spec = pl.BlockSpec(
+        (1, 1, bqm), lambda b, i, j: (q_head(b, j), 0, q_block(i, j)))
+    walk = {} if rep == 1 else {"q_blocks": num_q}
     dk, dvalue = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
-                          causal=causal, group=group, offset=offset),
-        grid=(bh, seq_k // bk, num_q),
+                          causal=causal, group=group, offset=offset, **walk),
+        grid=(bh // rep, seq_k // bk, rep * num_q),
         in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
                   row_spec, row_spec],
         out_specs=[kv_spec(d), kv_spec(dv)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq_k, dv), v.dtype),
+            jax.ShapeDtypeStruct((bh // rep, seq_k, d), k.dtype),
+            jax.ShapeDtypeStruct((bh // rep, seq_k, dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, dv), jnp.float32)],
@@ -489,8 +537,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
         name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lse, delta)
     return (dq.reshape(batch, heads, seq_q, d),
-            dk.reshape(batch, heads, seq_k, d),
-            dvalue.reshape(batch, heads, seq_k, dv))
+            dk.reshape(batch, heads // rep, seq_k, d),
+            dvalue.reshape(batch, heads // rep, seq_k, dv))
 
 
 @functools.lru_cache(maxsize=None)

@@ -1,0 +1,228 @@
+"""The operator between the two projections of a gated short-convolution
+layer: (B, C, X, w) -> C * filter(B * X).
+
+B, C and X are [batch, seq, channels], the layout the projections either
+side of it use; w is [channels, taps], one causal filter a channel:
+
+    u = B * X;   m_t = sum_j w[:, j] * u_{t - (taps - 1) + j};   out = C * m
+
+with u zero before the sequence's start (a cross-correlation, as a depthwise
+Conv1d with left padding taps - 1 cut to seq). The shift runs along seq, the
+second-minor dimension of the layout, so nothing is transposed. Products and
+sums are float32, rounded once to the inputs' dtype. No activation: both
+gates are products.
+
+Two formulations. `short_conv_reference` is jnp (the oracle, differentiated
+by autodiff; XLA fuses it into elementwise passes). `short_conv_fwd` and
+`short_conv_bwd` (names in util/profiling.KERNELS) are one Pallas pass each
+under a custom_vjp: the forward reads B, C, X and writes out; the backward
+reads them and the cotangent, recomputes u and m, and writes dB, dC, dX and
+the filter's gradient summed over its block's rows (the blocks' sums are
+added outside). A block is [rows, cols] of one sequence; the taps - 1 rows a
+shift needs from the next block along the sequence come through a second
+BlockSpec on the same array, one sublane tile of rows (`halo`), so the grid
+has no order and no carry. Blocks follow from the shape (`_conv_blocks`); a
+shape that does not tile takes the jnp formulation.
+"""
+
+from __future__ import annotations
+
+import collections
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import LANES, lane_divisor
+
+# (rows, cols, halo) of the [seq, channels] plane one grid step moves, and
+# the rows of the neighbouring block it reads beside them.
+_ConvBlocks = collections.namedtuple("_ConvBlocks", "rows cols halo")
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel"),
+    vmem_limit_bytes=48 << 20)
+
+
+def short_conv_reference(gate_in, gate_out, value, taps):
+    """gate_in (B), gate_out (C), value (X): [batch, seq, channels];
+    taps: [channels, L]. The filter as L shifted products."""
+    f32 = jnp.float32
+    u = gate_in.astype(f32) * value.astype(f32)
+    seq, n = u.shape[1], taps.shape[1]
+    # padded[t] = u[t - (n - 1)]: zeros before the sequence's start
+    padded = jnp.pad(u, ((0, 0), (n - 1, 0), (0, 0)))
+    mixed = sum(taps[:, j].astype(f32) * padded[:, j:j + seq]
+                for j in range(n))
+    return (gate_out.astype(f32) * mixed).astype(gate_in.dtype)
+
+
+def _conv_blocks(seq: int, channels: int, n_taps: int,
+                 itemsize: int) -> Optional[_ConvBlocks]:
+    """Blocks of both kernels, from the shape alone; None for a shape the
+    kernels do not tile. 512 x 512: seven two-byte blocks in flight, two
+    buffers each, beside the float32 intermediates, in 48 MB of VMEM."""
+    if itemsize not in (2, 4):
+        return None
+    halo = 32 // itemsize              # rows of one register: 8 fp32, 16 bf16
+    if (channels % LANES or seq % halo or seq < 2 * halo
+            or n_taps - 1 > halo):
+        return None
+    rows = max(r for r in range(halo, min(seq, 512) + 1, halo)
+               if seq % r == 0)
+    return _ConvBlocks(rows, lane_divisor(channels, 512), halo)
+
+
+def _shifted(u, beside, k: int, down: bool):
+    """u [rows, cols] moved k rows along the sequence: down, row t holds
+    u[t - k] and the first k rows come from the end of `beside` (the halo
+    before the block); up, row t holds u[t + k] and the last k come from
+    the start of `beside` (the halo after it)."""
+    if k == 0:
+        return u
+    rows, halo = u.shape[0], beside.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, beside.shape, 0)
+    if down:
+        moved = pltpu.roll(u, k, 0)
+        edge = jnp.where(row < k, pltpu.roll(beside, k, 0), moved[:halo])
+        return (edge if rows == halo
+                else jnp.concatenate([edge, moved[halo:]], axis=0))
+    moved = pltpu.roll(u, rows - k, 0)
+    edge = jnp.where(row >= halo - k, pltpu.roll(beside, halo - k, 0),
+                     moved[rows - halo:])
+    return (edge if rows == halo
+            else jnp.concatenate([moved[:rows - halo], edge], axis=0))
+
+
+def _fwd_kernel(b_ref, x_ref, c_ref, b_prev_ref, x_prev_ref, w_ref, o_ref):
+    """Grid (batch, seq block, channel block)."""
+    f32 = jnp.float32
+    n = w_ref.shape[0]
+    u = b_ref[0].astype(f32) * x_ref[0].astype(f32)
+    # the halo's rows where the block has a neighbour, zeros at the start
+    before = jnp.where(pl.program_id(1) > 0, b_prev_ref[0].astype(f32)
+                       * x_prev_ref[0].astype(f32), 0.0)
+    mixed = sum(w_ref[n - 1 - k:n - k, :] * _shifted(u, before, k, True)
+                for k in range(n))
+    o_ref[0] = (c_ref[0].astype(f32) * mixed).astype(o_ref.dtype)
+
+
+def _bwd_kernel(b_ref, x_ref, c_ref, g_ref, b_prev_ref, x_prev_ref,
+                c_next_ref, g_next_ref, w_ref,
+                db_ref, dx_ref, dc_ref, dw_ref):
+    """Grid (batch, seq block, channel block). m is computed again; dm = g
+    * C is shifted the other way, so it needs the rows after the block."""
+    f32 = jnp.float32
+    n = w_ref.shape[0]
+    i = pl.program_id(1)
+    b, x = b_ref[0].astype(f32), x_ref[0].astype(f32)
+    g = g_ref[0].astype(f32)
+    u = b * x
+    before = jnp.where(i > 0, b_prev_ref[0].astype(f32)
+                       * x_prev_ref[0].astype(f32), 0.0)
+    dm = g * c_ref[0].astype(f32)
+    after = jnp.where(i < pl.num_programs(1) - 1, g_next_ref[0].astype(f32)
+                      * c_next_ref[0].astype(f32), 0.0)
+    mixed, du = jnp.zeros_like(u), jnp.zeros_like(u)
+    for k in range(n):
+        tap = w_ref[n - 1 - k:n - k, :]
+        back = _shifted(u, before, k, True)
+        mixed = mixed + tap * back
+        du = du + tap * _shifted(dm, after, k, False)
+        dw_ref[0, 0, n - 1 - k:n - k, :] = jnp.sum(dm * back, axis=0,
+                                                   keepdims=True)
+    dc_ref[0] = (g * mixed).astype(dc_ref.dtype)
+    db_ref[0] = (du * x).astype(db_ref.dtype)
+    dx_ref[0] = (du * b).astype(dx_ref.dtype)
+
+
+def _specs(blocks: _ConvBlocks, seq: int):
+    """(a block of [B, S, d], the halo before it, the halo after it)."""
+    rows, cols, halo = blocks
+    per, last = rows // halo, seq // halo - 1
+    main = pl.BlockSpec((1, rows, cols), lambda b, i, j: (b, i, j))
+    prev = pl.BlockSpec((1, halo, cols),
+                        lambda b, i, j: (b, jnp.maximum(i * per - 1, 0), j))
+    nxt = pl.BlockSpec((1, halo, cols),
+                       lambda b, i, j: (b, jnp.minimum((i + 1) * per, last),
+                                        j))
+    return main, prev, nxt
+
+
+@functools.lru_cache(maxsize=None)
+def _make_conv_fn(blocks: _ConvBlocks, interpret: bool):
+    """short_conv_fwd with short_conv_bwd as its backward; the residuals
+    are the four inputs."""
+    rows, cols, _halo = blocks
+
+    def taps_spec(n):
+        return pl.BlockSpec((n, cols), lambda b, i, j: (0, j))
+
+    def forward(gate_in, gate_out, value, taps):
+        batch, seq, d = gate_in.shape
+        main, prev, _nxt = _specs(blocks, seq)
+        return pl.pallas_call(
+            _fwd_kernel,
+            grid=(batch, seq // rows, d // cols),
+            in_specs=[main, main, main, prev, prev, taps_spec(taps.shape[1])],
+            out_specs=main,
+            out_shape=jax.ShapeDtypeStruct(gate_in.shape, gate_in.dtype),
+            compiler_params=_PARAMS,
+            interpret=interpret,
+            name="short_conv_fwd",
+        )(gate_in, value, gate_out, gate_in, value,
+          taps.astype(jnp.float32).T)
+
+    @jax.custom_vjp
+    def f(gate_in, gate_out, value, taps):
+        return forward(gate_in, gate_out, value, taps)
+
+    def fwd(gate_in, gate_out, value, taps):
+        return forward(gate_in, gate_out, value, taps), (
+            gate_in, gate_out, value, taps)
+
+    def bwd(residuals, g):
+        gate_in, gate_out, value, taps = residuals
+        batch, seq, d = gate_in.shape
+        n = taps.shape[1]
+        main, prev, nxt = _specs(blocks, seq)
+        like = jax.ShapeDtypeStruct(gate_in.shape, gate_in.dtype)
+        d_in, d_value, d_out, d_taps = pl.pallas_call(
+            _bwd_kernel,
+            grid=(batch, seq // rows, d // cols),
+            in_specs=[main, main, main, main, prev, prev, nxt, nxt,
+                      taps_spec(n)],
+            out_specs=[main, main, main,
+                       pl.BlockSpec((1, 1, n, cols),
+                                    lambda b, i, j: (b, i, 0, j))],
+            out_shape=[like, like, like,
+                       jax.ShapeDtypeStruct((batch, seq // rows, n, d),
+                                            jnp.float32)],
+            compiler_params=_PARAMS,
+            interpret=interpret,
+            name="short_conv_bwd",
+        )(gate_in, value, gate_out, g, gate_in, value, gate_out, g,
+          taps.astype(jnp.float32).T)
+        return (d_in, d_out, d_value,
+                jnp.sum(d_taps, axis=(0, 1)).T.astype(taps.dtype))
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def short_conv(gate_in, gate_out, value, taps, *,
+               interpret: Optional[bool] = None):
+    """C * filter(B * X): see the module's docstring. gate_in (B), gate_out
+    (C), value (X): [batch, seq, channels]; taps: [channels, L]."""
+    _, seq, channels = gate_in.shape
+    blocks = _conv_blocks(seq, channels, taps.shape[1],
+                          gate_in.dtype.itemsize)
+    if blocks is None:
+        return short_conv_reference(gate_in, gate_out, value, taps)
+    if interpret is None:
+        interpret = attention._default_interpret()
+    return _make_conv_fn(blocks, interpret)(gate_in, gate_out, value, taps)

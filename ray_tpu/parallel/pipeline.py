@@ -73,6 +73,14 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
         raise ValueError(
             f"dense_layers={cfg.dense_layers}: the pipeline preset scans "
             "one stack of identical layers and has no layer pattern")
+    kinds = set(cfg.layer_kinds or ())
+    if len(kinds) > 1:
+        raise ValueError(
+            f"layer_kinds={cfg.layer_kinds}: the pipeline preset scans one "
+            "stack of identical layers and has no two kinds of layer")
+    if "conv" in kinds and tp > 1:
+        raise ValueError("ShardingStrategy.pp_tp() has no rule for conv/w_in, "
+                         "conv/filter and conv/w_out")
     if cfg.n_experts > 0:
         raise ValueError("pipeline preset supports dense MLP layers (use "
                          "'ep' compositions for MoE)")
@@ -82,6 +90,9 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
                          "attn/kv_norm")
     if cfg.n_heads % tp != 0:
         raise ValueError(f"n_heads={cfg.n_heads} not divisible by tp={tp}")
+    if cfg.kv_heads % tp != 0:
+        raise ValueError(f"n_kv_heads={cfg.kv_heads} is not whole key/value "
+                         f"heads over tp={tp}")
     M = num_microbatches
     dt = cfg.dtype
     if tp > 1:

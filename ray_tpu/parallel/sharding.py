@@ -108,6 +108,14 @@ class ShardingStrategy:
             # part are not divided
             (r"attn/w_kvb", P(None, t)),
             (r"attn/(w_kva|kv_norm)", P()),
+            # one scale of head_dim for all the heads
+            (r"attn/(q|k)_head_norm", P()),
+            # the short convolution: w_in is its three chunks [3, d, d],
+            # each column-parallel, so a shard holds the same channels of
+            # B, C and X, its channels' filters and w_out's rows
+            (r"conv/w_in", P(None, None, t)),
+            (r"conv/filter", P(t, None)),
+            (r"conv/w_out", P(t, None)),
             (r"mlp/(w_up|w_gate)", P(None, t)),
             (r"mlp/w_down", P(t, None)),
             (r"embed/table", P(t, None)),
@@ -133,6 +141,10 @@ class ShardingStrategy:
             (r"attn/wo", P(t, f)),
             (r"attn/w_kvb", P(f, t)),
             (r"attn/(w_kva|kv_norm)", P()),
+            (r"attn/(q|k)_head_norm", P()),
+            (r"conv/w_in", P(None, f, t)),
+            (r"conv/filter", P(t, None)),
+            (r"conv/w_out", P(t, f)),
             (r"mlp/(w_up|w_gate)", P(f, t)),
             (r"mlp/w_down", P(t, f)),
             # Vocab over both axes, d_model replicated: a d-sharded gather

@@ -110,19 +110,21 @@ def stack_dump() -> Dict[str, str]:
 # ---------------------------------------------------------------------------
 
 # The scopes models/gpt.py and train/train_step.py open, and the names
-# ops/attention.py, ops/moe.py and ops/rope.py give their pallas_calls. An op belongs to
-# the LAST of these on its op_name path:
+# ops/attention.py, ops/moe.py, ops/rope.py and ops/short_conv.py give their
+# pallas_calls. An op belongs to the LAST of these on its op_name path:
 # `jit(_step)/loss_and_grad/jvp(mlp)/dot_general` is `mlp`, and what
 # `loss_and_grad` holds itself is the rest (residual adds, casts of the
 # gradients). `moe` is the experts' own arithmetic (grouped matmuls,
 # SwiGLU, the casts of their matrices); `moe_route`, nested in it, is what
 # exists only because the layer is sparse: router, top-k, ordering, the
-# gathers either side, both router losses.
+# gathers either side, both router losses. `conv` is a short-convolution
+# layer's projections; `conv_mix`, nested in it, its gates and filter.
 REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_out",
-           "mlp", "moe", "moe_route", "moe_shared", "norm", "head",
-           "loss_and_grad", "grad_accum", "optimizer")
+           "conv", "conv_mix", "mlp", "moe", "moe_route", "moe_shared",
+           "norm", "head", "loss_and_grad", "grad_accum", "optimizer")
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
-           "moe_tgmm", "rope_split", "rope_merge")
+           "moe_tgmm", "rope_split", "rope_merge", "short_conv_fwd",
+           "short_conv_bwd")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:")

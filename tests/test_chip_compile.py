@@ -68,6 +68,57 @@ def test_flash_kernel_compiles_for_v5e(v5e, backward, shape):
     assert text.count("tpu_custom_call") >= (3 if backward else 1)
 
 
+@pytest.mark.parametrize("heads,kv_heads", [(32, 8), (32, 32)],
+                         ids=["32_on_8", "32_on_32"])
+def test_flash_kernels_compile_at_8192_positions_of_64(v5e, heads, kv_heads):
+    """lfm2_train_1chip's call, [2, 32 on 8, 8192, 64], forward and both
+    backward kernels: several blocks of 2048 a row at a head of 64 need
+    more than the default 16 MB of VMEM (`_compiler_params`), grouped or
+    not; dK and dV leave at the key/value heads' count."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.attention import flash_attention
+
+    def shape(h):
+        return jax.ShapeDtypeStruct((2, h, 8192, 64), jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+    grads = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))
+    compiled = grads.lower(shape(heads), shape(kv_heads),
+                           shape(kv_heads)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    dq, dk, dv = compiled.out_info
+    assert dq.shape == (2, heads, 8192, 64)
+    assert dk.shape == dv.shape == (2, kv_heads, 8192, 64)
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_short_conv_kernels_compile_for_v5e(v5e, backward):
+    """ops/short_conv.py's pair at lfm2_train_1chip's [2, 8192, 2048]: the
+    sublane rolls, the halo blocks and the single-row loads and stores of
+    the taps lay out."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.short_conv import short_conv
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+
+    def fn(b, c, x, w, g):
+        out, vjp = jax.vjp(lambda *a: short_conv(*a, interpret=False),
+                           b, c, x, w)
+        return vjp(g) if backward else out
+    x = shape((2, 8192, 2048))
+    text = jax.jit(fn).lower(x, x, x, shape((2048, 3), jnp.float32),
+                             x).compile().as_text()
+    assert ("short_conv_bwd" if backward else "short_conv_fwd") in text
+
+
 @pytest.mark.parametrize("shape", KERNEL_SHAPES,
                          ids=lambda s: "x".join(map(str, s)))
 @pytest.mark.parametrize("backward", [False, True],
@@ -311,13 +362,42 @@ def test_grouped_matmul_kernels_compile_for_v5e(v5e):
     assert "moe_gmm" in text and "moe_tgmm" in text
 
 
-def test_latent_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch):
-    """kanana2_train_1chip's whole step (5 layers of latent attention at
-    q.k 192 padded to 256 / v 128 and 8192 positions, one dense and four
-    sparse with 16 of 128 experts held, adamw over fp32 masters) for one
-    described chip: every Mosaic call lays out, and arguments +
-    temporaries stay under the chip's 16.91 GB (10.98 GB when this was
-    written: 6.91 of state, 4.07 of temporaries)."""
+# (configuration, kernel calls of the compiled step, arguments + temporaries
+# as a share of the chip's 16.91 GB)
+CELL_STEPS = [
+    # kanana2_train_1chip: 5 layers of latent attention at q.k 192 padded
+    # to 256 / v 128, one dense and four sparse with 16 of 128 experts held.
+    # 5 layers x (forward, kept through the remat, + dQ + dK/dV) flash
+    # calls, 4 sparse layers x (9 grouped matmuls + 3 recomputed + 3 tgmm).
+    # 10.98 GB when this was written: 6.91 of state, 4.07 of temporaries.
+    ("kanana-2-30b-a3b", {"flash_fwd": 5, "flash_bwd_dq": 5,
+                          "flash_bwd_dkv": 5, "moe_gmm": 36, "moe_tgmm": 12},
+     (0.55, 0.92)),
+    # lfm2_train_1chip: a convolution layer with the dense MLP, then
+    # attention (32 query heads on 8 key/value heads) and three convolution
+    # layers with 8 of 64 experts held. One attention layer: one call of
+    # each flash kernel; q, k, v through rope_split forward and recomputed,
+    # rope_merge backward; 4 convolution layers x (forward + recomputed)
+    # and x backward. 8.90 GB when this was written: 5.63 of state, 3.27 of
+    # temporaries.
+    ("lfm2-24b-a2b", {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+                      "rope_split": 6, "rope_merge": 3, "moe_gmm": 36,
+                      "moe_tgmm": 12, "short_conv_fwd": 8,
+                      "short_conv_bwd": 4},
+     (0.45, 0.75)),
+]
+
+
+@pytest.mark.parametrize("name,kernel_calls,share", CELL_STEPS,
+                         ids=[c[0] for c in CELL_STEPS])
+def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
+                                                   kernel_calls, share):
+    """A one-chip cell's whole step (2 x 8192 tokens, adamw over fp32
+    masters) for one described chip: every Mosaic call lays out, the
+    kernels are called as often as the layers say, and arguments +
+    temporaries stay under the chip's 16.91 GB. Under grouped queries k and
+    v exist at the key/value heads' count alone: no tensor of the step has
+    them at the query heads'."""
     import json
 
     import jax
@@ -332,7 +412,7 @@ def test_latent_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch):
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
-                           "kanana-2-30b-a3b.json")) as f:
+                           name + ".json")) as f:
         config = json.load(f)
     with open(os.path.join(root, "benchmark", "traffic",
                            "train_b2_s8192_dp.json")) as f:
@@ -363,12 +443,20 @@ def test_latent_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch):
     memory = compiled.memory_analysis()
     peak = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
-    assert 0.55 * 16.91e9 < peak < 0.92 * 16.91e9, peak
+    assert share[0] * 16.91e9 < peak < share[1] * 16.91e9, peak
     text = compiled.as_text()
-    # 5 layers x (forward, kept through the remat, + dQ + dK/dV) flash
-    # calls, 4 sparse layers x (9 grouped matmuls + 3 recomputed + 3 tgmm)
-    for kernel, calls in (("flash_fwd", 5), ("flash_bwd_dq", 5),
-                          ("flash_bwd_dkv", 5), ("moe_gmm", 36),
-                          ("moe_tgmm", 12)):
+    for kernel, calls in kernel_calls.items():
         found = len(_kernel_ops(text, kernel))
         assert found == calls, (kernel, found)
+    kv_heads = config.get("num_key_value_heads")
+    if kv_heads != config["num_attention_heads"]:
+        # dK and dV leave their kernel at the key/value heads' count, and
+        # the one tensor at the query heads' that enters it is q (with dO)
+        dkv, = _kernel_ops(text, "flash_bwd_dkv")
+        b, s = mix["global_batch"], mix["seq"]
+        assert dkv.count(f"bf16[{b * kv_heads},{s},64]") >= 4, dkv
+        by_head = f"bf16[{b},{config['num_attention_heads']},{s},64]"
+        made = [line for line in text.splitlines()
+                if f" = {by_head}" in line and "rope_split" in line]
+        # q alone is split at 32 heads: forward, and recomputed
+        assert len(made) == 2, made

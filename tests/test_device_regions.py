@@ -53,11 +53,13 @@ def dense_text(jax_cpu):
 # (a) every region of the vocabulary is on some op of a compiled step
 
 # (attn_latent and moe_shared: tests/test_latent_moe.py, on a step that has
-# a latent block and a shared expert)
+# a latent block and a shared expert; conv and conv_mix: tests/
+# test_conv_gqa.py, on a step with a short-convolution layer)
 @pytest.mark.parametrize("region", [r for r in REGIONS
                                     if r not in ("moe", "moe_route",
                                                  "grad_accum", "attn_latent",
-                                                 "moe_shared")])
+                                                 "moe_shared", "conv",
+                                                 "conv_mix")])
 def test_dense_step_names_region(dense_text, region):
     names = re.findall(r'op_name="([^"]*)"', dense_text)
     assert any(profiling._last_of(n, REGIONS) == region for n in names)
