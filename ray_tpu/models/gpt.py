@@ -353,14 +353,17 @@ def _per_shard(fn, mesh, in_dims, out_dims):
     a mesh of more than one device they run per shard, inside a shard_map:
     batch over 'data' x 'fsdp', whole heads over 'tensor', anything else
     handed whole, each device on its own slice with no collective. in_dims
-    (one tuple an operand) and out_dims say which dimension is which:
-    "batch", "heads" or None. With no mesh (one device, or a caller inside
-    a shard_map of its own) nothing is wrapped."""
+    (one tuple an operand) and out_dims (one tuple, or for a tuple of
+    results one each) say which dimension is which: "batch", "heads" or
+    None. With no mesh (one device, or a caller inside a shard_map of its
+    own) nothing is wrapped."""
     if mesh is None or mesh.size == 1:
         return fn
     axes = {"batch": ("data", "fsdp"), "heads": "tensor", None: None}
 
     def spec(dims):
+        if dims and isinstance(dims[0], tuple):
+            return tuple(map(spec, dims))
         return P(*(axes[d] for d in dims))
     # check_vma off: pallas_call declares no varying axes for its outputs,
     # and the Pallas interpreter the CPU tests use fails the check inside.
@@ -618,33 +621,45 @@ def _route(m, x, cfg: GPTConfig):
     return weights, idx, stats
 
 
+def _expert_rows(tiles, rows, order, x, weights, w_gate, w_up, w_down):
+    """_experts in a row space of `tiles` tiles of `rows` rows (which has to
+    hold the order's: moe.in_row_space): dispatch, three grouped matmuls
+    with SwiGLU between, weighted return."""
+    b, s, d = x.shape
+    with jax.named_scope("moe_route"):
+        plan = moe.lay_out(order, rows, tiles)
+        rows_in = moe.dispatch(x.reshape(b * s, d), plan)
+    gate = moe.grouped_matmul(rows_in, w_gate, plan)
+    up = moe.grouped_matmul(rows_in, w_up, plan)
+    out = moe.grouped_matmul(jax.nn.silu(gate) * up, w_down, plan)
+    with jax.named_scope("moe_route"):
+        y = moe.combine(out, weights.reshape(b * s, -1), plan)
+    return y.reshape(b, s, d)
+
+
 def _experts(x, weights, idx, w_gate, w_up, w_down, held=None):
     """x [b, s, d] through each token's chosen experts (ops/moe.py): rows
     ordered by expert, three grouped matmuls with SwiGLU between, weighted
     return. The gathers either side are the layer's sparsity, not its
     arithmetic: scope `moe_route`. held: None where the matrices are all
     the experts', else (first, of how many): the matrices are experts
-    first .. first + len - 1, and a slot chosen for another is left out."""
-    b, s, d = x.shape
+    first .. first + len - 1, and a slot chosen for another is left out;
+    the result is then (y, [1] whether the row space sized for the slots
+    expected here held them: moe.in_row_space)."""
     e = w_gate.shape[0]
     with jax.named_scope("moe_route"):
-        idx = idx.reshape(b * s, -1)
+        idx = idx.reshape(-1, idx.shape[-1])
         if held is None:
-            plan = moe.plan_dispatch(
-                idx, e, moe.tile_rows(idx.size, e, x.dtype))
+            expected = idx.size
         else:
             first, of = held
-            # tiles from the slots expected here; room for every slot
-            plan = moe.plan_dispatch(
-                idx - first, e,
-                moe.tile_rows(idx.size * e // of, e, x.dtype), partial=True)
-        rows = moe.dispatch(x.reshape(b * s, d), plan)
-    gate = moe.grouped_matmul(rows, w_gate, plan)
-    up = moe.grouped_matmul(rows, w_up, plan)
-    out = moe.grouped_matmul(jax.nn.silu(gate) * up, w_down, plan)
-    with jax.named_scope("moe_route"):
-        y = moe.combine(out, weights.reshape(b * s, -1), plan)
-    return y.reshape(b, s, d)
+            # the tiles, and the row space, from the slots expected here
+            expected, idx = idx.size * e // of, idx - first
+        rows = moe.tile_rows(expected, e, x.dtype)
+        order = moe.order_slots(idx, e, rows, partial=held is not None)
+    y, fitted = moe.in_row_space(_expert_rows, order, rows, expected,
+                                 x, weights, w_gate, w_up, w_down)
+    return y if held is None else (y, jnp.reshape(fitted, (1,)))
 
 
 def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
@@ -657,9 +672,13 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
 
     With cfg.experts_held the sum runs over the chosen experts that are
     held here (one chip's share under expert parallelism, without its
-    exchange): the partial result, nothing standing in for the rest. With
-    cfg.n_shared_experts a dense SwiGLU of every token is added (scope
-    `moe_shared`)."""
+    exchange): the partial result, nothing standing in for the rest, in a
+    row space sized for the slots expected here; `expert_rows_bounded`
+    joins _route's statistics: the share of the devices on which that row
+    space held the routing at hand (the others ran every slot's, the same
+    arithmetic: moe.in_row_space), the constant 1.0 where all the experts
+    are held. With cfg.n_shared_experts a dense SwiGLU of every token is
+    added (scope `moe_shared`)."""
     dt = cfg.dtype
     m = layer["moe"]
     with jax.named_scope("moe_route"):
@@ -669,8 +688,14 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
             else (cfg.experts_held[0], cfg.n_experts))
     tokens = ("batch", None, None)
     y = _per_shard(partial(_experts, held=held), where.mesh,
-                   (tokens,) * 3 + ((),) * 3, tokens)(
+                   (tokens,) * 3 + ((),) * 3,
+                   tokens if held is None else (tokens, ("batch",)))(
         x, weights, idx, *matrices)
+    if held is None:
+        stats["expert_rows_bounded"] = 1.0
+    else:
+        y, fitted = y
+        stats["expert_rows_bounded"] = jnp.mean(fitted)
     if "shared" in m:
         with jax.named_scope("moe_shared"):
             y = y + _mlp_block(m["shared"], x, cfg, where)
@@ -916,7 +941,9 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
     cross-entropy, plus under the softmax routing rule the router's two
     losses at the configuration's weights; aux holds the cross-entropy alone ("xent")
     and the router's statistics (the two losses unweighted, the largest
-    expert's load over the mean), for a step written with
+    expert's load over the mean, the share of the slots that fall to the
+    held experts and of the layers whose bounded row space held them), for
+    a step written with
     jax.value_and_grad(..., has_aux=True)."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]

@@ -9,8 +9,10 @@ still owns one (all zero) tile. Three things follow from that layout:
     by the row tile's expert (scalar prefetch): no mask, no tile computed
     for two experts, no `[tokens, experts, width]` intermediate;
   - no token is dropped under any imbalance (there is no capacity): the
-    row space has room for T x k rows plus one tile an expert, and the
-    tiles past the last used one are skipped, not computed;
+    row space has room for every row the routing at hand fills (T x k
+    rows plus one tile an expert where all the experts are here; for a
+    share of them see below), and the tiles past the last used one are
+    skipped, not computed;
   - every data movement is a gather, forward and backward: `dispatch`
     (tokens -> rows) and `combine` (rows -> tokens, weighted) are each
     other's transposes and carry their own VJPs, because XLA's transpose
@@ -22,8 +24,17 @@ One chip's share of the experts (`plan_dispatch(..., partial=True)`): the
 groups are the experts held here and a slot may have chosen one that is
 not. Such a slot gets no row: it is not gathered in, multiplied or
 combined, and no gradient passes through it (`Plan.token_held`). How many
-slots fall here is known only on the device, so the row space keeps room
-for all of them and the tiles nobody fills are skipped like any other.
+slots fall here is known only on the device, and the kernels skip the
+tiles nobody fills, but every XLA pass (the gathers, SwiGLU, the tables)
+takes its shape from the row space and runs over all of it. So the row
+space is sized for the rows that are expected, T x k x held / of, and
+not for every slot landing here: `in_row_space` lays the plan out over
+`_ROW_SPACE_FACTOR` times the tiles the expectation fills (plus one a
+group) when the routing at hand fits that, which it reads off the groups'
+sizes on the device, and over room for every slot when it does not
+(skewed routing, a collapsed router). Both are the same arithmetic on the
+same rows in the same order; the second is only slower. Nothing is
+dropped, clipped or approximated either way.
 
 Kernels (names in util/profiling.KERNELS): `moe_gmm` (rows x an expert's
 matrix, forward and the gradient of the rows) and `moe_tgmm` (rows^T x
@@ -49,6 +60,10 @@ from ray_tpu.ops.attention import lane_divisor
 _WEIGHT_BLOCK_BYTES = 4 << 20
 _VMEM_LIMIT_BYTES = 64 << 20
 _MAX_TILE_ROWS = 256
+# A share's row space, in tiles the expected rows fill (`in_row_space`).
+# Random routing puts 0.11-0.14 of the slots on an eighth of the experts
+# (PERF.md, PR 31); past twice the expectation the plan for every slot runs.
+_ROW_SPACE_FACTOR = 2
 
 
 class Plan(NamedTuple):
@@ -64,11 +79,36 @@ class Plan(NamedTuple):
                 expert is one of the groups. Where it is not, token_rows
                 is 0 (a row that is always computed, so finite) and the
                 slot is masked out wherever token_rows is read
+
+    `tiles` is every slot's worst case from `plan_dispatch` (T * k rows and
+    a tile a group) and whatever `lay_out` was given otherwise: at least
+    tiles_used, which is the caller's to see to (`in_row_space` does).
     """
     row_slot: jax.Array
     token_rows: jax.Array
     tile_group: jax.Array
     tiles_used: jax.Array
+    token_held: Optional[jax.Array] = None
+
+
+class Order(NamedTuple):
+    """The slots in expert order: what of a plan takes no shape from the
+    row space, so it is worked out once whatever the row space will be.
+
+    order       [T * k] the slot that is r-th by expert (stable)
+    sizes       [G] slots a group
+    tile_end    [G] tiles up to the end of each group; the last is
+                Plan.tiles_used
+    first_row, first_rank  [G] a group's first row, and its first slot's
+                place in `order`
+    token_rows, token_held  as in Plan
+    """
+    order: jax.Array
+    sizes: jax.Array
+    tile_end: jax.Array
+    first_row: jax.Array
+    first_rank: jax.Array
+    token_rows: jax.Array
     token_held: Optional[jax.Array] = None
 
 
@@ -83,8 +123,7 @@ def tile_rows(n_slots: int, n_groups: int, dtype) -> int:
     return rows
 
 
-def plan_dispatch(idx, n_groups: int, rows: int,
-                  partial: bool = False) -> Plan:
+def order_slots(idx, n_groups: int, rows: int, partial: bool = False) -> Order:
     """idx [T, k] int: the experts each token chose. Two sorts of T x k
     keys and small dense passes; integers only, nothing differentiated.
     partial: an idx outside 0 .. n_groups - 1 is an expert that is not
@@ -109,25 +148,101 @@ def plan_dispatch(idx, n_groups: int, rows: int,
     shift = jnp.sum(jnp.where(chose, (first_row - first_rank)[None, :], 0),
                     axis=1)
     token_rows = (rank + shift).reshape(t, k)
+    if not partial:
+        return Order(order, sizes, tile_end, first_row, first_rank,
+                     token_rows)
+    here = here.reshape(t, k)
+    return Order(order, sizes, tile_end, first_row, first_rank,
+                 jnp.where(here, token_rows, 0), here)
 
-    tiles = -(-n // rows) + n_groups
+
+def lay_out(order: Order, rows: int, tiles: int) -> Plan:
+    """The tables of a row space of `tiles` tiles of `rows` rows; it has to
+    hold order.tile_end[-1] of them."""
+    n = order.order.shape[0]
+    n_groups = order.sizes.shape[0]
     tile_group = jnp.minimum(
         jnp.sum(jnp.arange(tiles, dtype=jnp.int32)[:, None]
-                >= tile_end[None, :], axis=1, dtype=jnp.int32),
+                >= order.tile_end[None, :], axis=1, dtype=jnp.int32),
         n_groups - 1)
     # a row's slot: tile by tile, the group's ranks from where the tile
     # starts within its group; past the group's size the row is padding
     within = (rows * jnp.arange(tiles, dtype=jnp.int32)
-              - first_row[tile_group])[:, None] \
+              - order.first_row[tile_group])[:, None] \
         + jnp.arange(rows, dtype=jnp.int32)[None, :]
-    held = jnp.clip(first_rank[tile_group][:, None] + within, 0, n - 1)
-    row_slot = jnp.where(within < sizes[tile_group][:, None], order[held], n)
-    if partial:
-        here = here.reshape(t, k)
-        token_rows = jnp.where(here, token_rows, 0)
-        return Plan(row_slot.reshape(-1), token_rows, tile_group,
-                    tile_end[-1:], here)
-    return Plan(row_slot.reshape(-1), token_rows, tile_group, tile_end[-1:])
+    held = jnp.clip(order.first_rank[tile_group][:, None] + within, 0, n - 1)
+    row_slot = jnp.where(within < order.sizes[tile_group][:, None],
+                         order.order[held], n)
+    return Plan(row_slot.reshape(-1), order.token_rows, tile_group,
+                order.tile_end[-1:], order.token_held)
+
+
+def _every_slot(n_slots: int, n_groups: int, rows: int) -> int:
+    """Tiles that hold any routing of n_slots: their rows and, for the
+    groups' padding, a tile a group."""
+    return -(-n_slots // rows) + n_groups
+
+
+def plan_dispatch(idx, n_groups: int, rows: int,
+                  partial: bool = False) -> Plan:
+    """order_slots laid out over room for every slot of idx [T, k]."""
+    return lay_out(order_slots(idx, n_groups, rows, partial), rows,
+                   _every_slot(idx.size, n_groups, rows))
+
+
+def in_row_space(fn, order: Order, rows: int, expected_slots: int,
+                 *operands):
+    """fn(tiles, rows, order, *operands), which lays the plan out over
+    `tiles` tiles (`lay_out`) and takes every shape from it, in the row
+    space this routing needs -> (its result, whether the bounded row space
+    held it: a float32 0. or 1., the number 1.0 where there is nothing to
+    bound).
+
+    expected_slots: how many of the order's slots are expected on its
+    groups (all of them where every expert is here). The row space is
+    `_ROW_SPACE_FACTOR` times the tiles they fill and a tile a group; if
+    the groups at hand end past it (order.tile_end, read on the device),
+    fn runs over room for every slot instead: one `lax.cond`, each
+    branch whole, forward and backward. The backward pass recomputes fn
+    inside its own branch, since residuals shaped by a branch would have
+    to be written, as zeros, by the other one too; under a layer's remat
+    that is the recomputation the layer does anyway. Two row spaces are
+    twice the program to trace and lower, so a branch is a `jax.jit` of
+    fn: layers of one shape then share one trace and one lowering of it
+    (fn has to be the same function for them), and the compiler inlines
+    the calls."""
+    n = order.order.shape[0]
+    n_groups = order.sizes.shape[0]
+    every = _every_slot(n, n_groups, rows)
+    bounded = _ROW_SPACE_FACTOR * -(-expected_slots // rows) + n_groups
+    if bounded >= every:
+        return fn(every, rows, order, *operands), 1.0
+    run = jax.jit(fn, static_argnums=(0, 1))
+
+    def fits(order):
+        return order.tile_end[-1] <= bounded
+
+    @jax.custom_vjp
+    def either(order, *operands):
+        return lax.cond(fits(order), functools.partial(run, bounded, rows),
+                        functools.partial(run, every, rows), order, *operands)
+
+    def fwd(order, *operands):
+        return either(order, *operands), (order, operands)
+
+    def bwd(res, g):
+        order, operands = res
+
+        def pull(tiles, order, operands, g):
+            _, pulled = jax.vjp(functools.partial(run, tiles, rows, order),
+                                *operands)
+            return pulled(g)
+        return (None,) + lax.cond(
+            fits(order), functools.partial(pull, bounded),
+            functools.partial(pull, every), order, operands, g)
+
+    either.defvjp(fwd, bwd)
+    return either(order, *operands), fits(order).astype(jnp.float32)
 
 
 def _take_rows(x, index):

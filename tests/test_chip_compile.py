@@ -215,9 +215,11 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
 
 
 def _kernel_ops(text, kernel):
-    """The compiled text's lines that define a call of a Mosaic kernel."""
+    """The compiled text's lines that define a call of a Mosaic kernel (the
+    instruction takes the kernel's name, inside the transforms it was
+    traced under: `transpose_jvp_moe_gmm__.24`)."""
     return [line for line in text.splitlines()
-            if re.match(rf"\s*%?{kernel}[.\d]* = ", line)]
+            if re.match(rf"\s*%?(?:\w+_)?{kernel}_*[.\d]* = ", line)]
 
 
 @pytest.mark.parametrize("widths,batch,seq", BLOCK_WIDTHS, ids=BLOCK_IDS)
@@ -362,16 +364,88 @@ def test_grouped_matmul_kernels_compile_for_v5e(v5e):
     assert "moe_gmm" in text and "moe_tgmm" in text
 
 
+# (configuration, rows a tile, tiles of the share's bounded row space, tiles
+# for every slot)
+SHARE_ROW_SPACES = [
+    # 2 x 8192 tokens x 6 a token, 16 of 128 held: 12 288 slots expected in
+    # 128-row tiles, 2 x 96 + 16 = 208 tiles (26 624 rows) where every slot
+    # needs 784 (100 352)
+    ("kanana-2-30b-a3b", 128, 208, 784),
+    # x 4 a token, 8 of 64 held: 8192 expected in 256-row tiles, 2 x 32 + 8
+    # = 72 tiles (18 432 rows) against 264 (67 584)
+    ("lfm2-24b-a2b", 256, 72, 264),
+]
+
+
+@pytest.mark.parametrize("name,tile,bounded,every", SHARE_ROW_SPACES,
+                         ids=[c[0] for c in SHARE_ROW_SPACES])
+def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, name,
+                                                    tile, bounded, every):
+    """One sparse block of a cell that holds a share of the experts, its
+    gradients under the layer's remat, for one described chip: the text
+    holds the block over the bounded row space and over every slot's, one
+    conditional forward and one backward (the forward one's recomputation
+    under the remat is dead code), the kernels once a branch."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from benchmark import model
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    cfg = model.family(config)._train_config(config)
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+    layers = jax.eval_shape(
+        lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"]
+    sparse = next(layer for layer in layers if "moe" in layer)["moe"]
+    d = cfg.d_model
+
+    def loss(m, x):
+        block = jax.checkpoint(
+            lambda x, m: gpt._moe_block({"moe": m}, x, cfg, gpt.Setting())[0],
+            policy=jax.checkpoint_policies.save_only_these_names(
+                attention.FLASH_OUT, attention.FLASH_LSE))
+        return (block(x, m).astype(jnp.float32) ** 2).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        placed(sparse), jax.ShapeDtypeStruct((2, 8192, d), cfg.dtype,
+                                             sharding=one_chip)
+    ).compile().as_text()
+    assert len(re.findall(r" conditional\(", text)) == 2
+    # forward 3 + recomputed 3 + the rows' gradients 3, and 3: a branch
+    assert len(_kernel_ops(text, "moe_gmm")) == 18
+    assert len(_kernel_ops(text, "moe_tgmm")) == 6
+    for tiles in (bounded, every):
+        # the table of rows by tiles; the dispatched rows and the experts'
+        # outputs, forward and backward
+        assert f"s32[{tiles},{tile}]" in text, tiles
+        assert text.count(f" = bf16[{tiles * tile},{d}]") >= 4, tiles
+
+
 # (configuration, kernel calls of the compiled step, arguments + temporaries
 # as a share of the chip's 16.91 GB)
 CELL_STEPS = [
     # kanana2_train_1chip: 5 layers of latent attention at q.k 192 padded
     # to 256 / v 128, one dense and four sparse with 16 of 128 experts held.
     # 5 layers x (forward, kept through the remat, + dQ + dK/dV) flash
-    # calls, 4 sparse layers x (9 grouped matmuls + 3 recomputed + 3 tgmm).
-    # 10.98 GB when this was written: 6.91 of state, 4.07 of temporaries.
+    # calls, 4 sparse layers x (9 grouped matmuls + 3 recomputed + 3 tgmm),
+    # each in the text twice since PR 34: once for the bounded row space and
+    # once for every slot's (a step runs one of the two: test_sparse_layer_
+    # compiles_with_both_row_spaces). 10.98 GB when this was written: 6.91
+    # of state, 4.07 of temporaries.
     ("kanana-2-30b-a3b", {"flash_fwd": 5, "flash_bwd_dq": 5,
-                          "flash_bwd_dkv": 5, "moe_gmm": 36, "moe_tgmm": 12},
+                          "flash_bwd_dkv": 5, "moe_gmm": 72, "moe_tgmm": 24},
      (0.55, 0.92)),
     # lfm2_train_1chip: a convolution layer with the dense MLP, then
     # attention (32 query heads on 8 key/value heads) and three convolution
@@ -381,8 +455,8 @@ CELL_STEPS = [
     # and x backward. 8.90 GB when this was written: 5.63 of state, 3.27 of
     # temporaries.
     ("lfm2-24b-a2b", {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
-                      "rope_split": 6, "rope_merge": 3, "moe_gmm": 36,
-                      "moe_tgmm": 12, "short_conv_fwd": 8,
+                      "rope_split": 6, "rope_merge": 3, "moe_gmm": 72,
+                      "moe_tgmm": 24, "short_conv_fwd": 8,
                       "short_conv_bwd": 4},
      (0.45, 0.75)),
 ]

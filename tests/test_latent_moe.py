@@ -345,6 +345,256 @@ def test_plan_with_tokens_that_have_no_slot_here(jax_cpu):
     assert not np.any(np.asarray(got[1][2])[~here])
 
 
+# ---------------------------------------------------------------------------
+# (c2) the share's row space: sized for the rows expected, exact past it
+# ---------------------------------------------------------------------------
+
+def _masked_dense(x, weights, idx, w_gate, w_up, w_down, first):
+    """_experts by the book: every token through every held expert, the
+    slots that chose it weighted in, float32."""
+    import jax
+    import jax.numpy as jnp
+    e = w_gate.shape[0]
+    mask = jnp.sum((idx[..., None] - first == jnp.arange(e))
+                   * weights[..., None], axis=-2)             # [b, s, e]
+    act = jax.nn.silu(jnp.einsum("bsd,edf->bsef", x, w_gate)) \
+        * jnp.einsum("bsd,edf->bsef", x, w_up)
+    return jnp.einsum("bsef,efd,bse->bsd", act, w_down, mask)
+
+
+def _share_operands(jax, held, seed=0, b=2, s=64, k=4, d=16, f=8):
+    import jax.numpy as jnp
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(keys[0], (b, s, d), jnp.float32),
+            jax.random.uniform(keys[1], (b, s, k), jnp.float32),
+            *(0.3 * jax.random.normal(key, shape, jnp.float32)
+              for key, shape in zip(keys[2:], [(held, d, f), (held, d, f),
+                                               (held, f, d)])))
+
+
+def _distinct_choices(rng, tokens, k, of):
+    return np.stack([rng.permutation(of)[:k] for _ in range(tokens)]
+                    ).astype(np.int32)
+
+
+def _conditionals(jaxpr):
+    """`cond` equations of a jaxpr at any depth, the kernels' bodies apart
+    (a `pl.when` is one too)."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        found += eqn.primitive.name == "cond"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _conditionals(sub)
+    return found
+
+
+def _loss_and_grads(jax, fn, x, weights, idx, *matrices):
+    """sum(y^2) and its gradients by x, the weights and the matrices, with
+    whatever else fn returns."""
+    def loss(x, weights, *matrices):
+        y, *rest = fn(x, weights, idx, *matrices)
+        return (y ** 2).sum(), (y, rest)
+    with jax.default_matmul_precision("highest"):
+        (_, (y, rest)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(x, weights, *matrices)
+    return y, grads, rest
+
+
+@pytest.mark.parametrize("held,of", [(4, 32), (8, 32)],
+                         ids=["an_eighth", "a_quarter"])
+def test_bounded_row_space_equals_the_one_for_every_slot(jax_cpu, monkeypatch,
+                                                         held, of):
+    """Random routing lands near held / of of the slots here, the bounded
+    row space holds them, and _experts gives what it gives over room for
+    every slot (the factor out of reach: no check, the parent's code):
+    forward and all five gradients to float32 round-off."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import _experts
+    from ray_tpu.ops import moe
+    first = 8
+    x, weights, *matrices = _share_operands(jax, held)
+    idx = jnp.asarray(_distinct_choices(np.random.default_rng(of), 128, 4, of)
+                      ).reshape(2, 64, 4)
+
+    def share():       # a new function a call: no trace is found again
+        return lambda *operands: _experts(*operands, held=(first, of))
+    y, grads, (fitted,) = _loss_and_grads(jax, share(), x, weights, idx,
+                                          *matrices)
+    assert fitted.shape == (1,) and float(fitted[0]) == 1.0
+    assert _conditionals(
+        jax.make_jaxpr(share())(x, weights, idx, *matrices).jaxpr) == 1
+
+    monkeypatch.setattr(moe, "_ROW_SPACE_FACTOR", 1 << 20)
+    assert _conditionals(
+        jax.make_jaxpr(share())(x, weights, idx, *matrices).jaxpr) == 0
+    y_every, grads_every, (always,) = _loss_and_grads(
+        jax, share(), x, weights, idx, *matrices)
+    assert float(always[0]) == 1.0          # nothing to bound: the constant
+    np.testing.assert_allclose(y, y_every, rtol=1e-6, atol=1e-6)
+    for g, g_every in zip(grads, grads_every):
+        np.testing.assert_allclose(g, g_every, rtol=1e-6, atol=1e-6)
+    # and both are the masked dense computation
+    np.testing.assert_allclose(
+        y, _masked_dense(x, weights, idx, *matrices, first), atol=1e-5)
+
+
+# held 4 of 32, 512 slots, 8-row tiles: 64 slots expected = 8 tiles, so the
+# bounded row space is 2 x 8 + 4 = 20 tiles where every slot needs 68
+@pytest.mark.parametrize("here,fits", [
+    (512, 0.0),      # every token chose held experts alone
+    (17 * 8, 1.0),   # one group of 17 full tiles + 3 empty groups' = 20
+    (17 * 8 + 1, 0.0),                       # one row over: 21 tiles
+], ids=["every_slot_here", "exactly_at_the_bound", "one_row_over"])
+def test_past_the_bound_the_plan_for_every_slot_runs(jax_cpu, here, fits):
+    """No capacity: what does not fit the bounded row space runs over room
+    for every slot, and the result and its gradients are the masked dense
+    computation's either way; the flag says which ran."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import _experts
+    from ray_tpu.ops import moe
+    held, of, first = 4, 32, 8
+    assert moe.tile_rows(512 * held // of, held, jnp.float32) == 8
+    x, weights, *matrices = _share_operands(jax, held, seed=1)
+    flat = np.full(512, first + held + 3, np.int32)           # not here
+    if here == 512:
+        flat = first + np.random.default_rng(3).integers(0, held, 512)
+    else:
+        flat[:here] = first                                    # one group
+    idx = jnp.asarray(flat.astype(np.int32)).reshape(2, 64, 4)
+    y, grads, (fitted,) = _loss_and_grads(
+        jax, lambda *a: _experts(*a, held=(first, of)), x, weights, idx,
+        *matrices)
+    assert float(fitted[0]) == fits
+
+    def dense(x, weights, idx, *matrices):
+        return (_masked_dense(x, weights, idx, *matrices, first),)
+    want, want_grads, _ = _loss_and_grads(jax, dense, x, weights, idx,
+                                          *matrices)
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    for g, w in zip(grads, want_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
+def test_eight_shares_one_of_them_past_its_bound_add_up_to_the_whole(jax_cpu):
+    """Sixteen experts on eight chips, two each, and a router that sends
+    two of every token's four choices to experts 0 and 1: share 0 gets four
+    times its expectation and runs the plan for every slot, the other seven
+    run bounded, and the eight partial sums are the uncut layer's."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import _experts
+    of, held, k = 16, 2, 4
+    x, weights, *matrices = _share_operands(jax, of, seed=2, k=k)
+    rng = np.random.default_rng(5)
+    idx = np.stack([np.concatenate([[0, 1],
+                                    2 + rng.permutation(of - 2)[:k - 2]])
+                    for _ in range(128)]).astype(np.int32).reshape(2, 64, k)
+    idx = jnp.asarray(idx)
+    parts, fitted = [], []
+    with jax.default_matmul_precision("highest"):
+        for rank in range(of // held):
+            mine = [m[held * rank:held * (rank + 1)] for m in matrices]
+            y, flag = _experts(x, weights, idx, *mine,
+                               held=(held * rank, of))
+            parts.append(y)
+            fitted.append(float(flag[0]))
+        whole = _experts(x, weights, idx, *matrices)
+        want = _masked_dense(x, weights, idx, *matrices, 0)
+    assert fitted == [0.0] + [1.0] * 7
+    np.testing.assert_allclose(sum(parts), want, atol=2e-5)
+    np.testing.assert_allclose(whole, want, atol=2e-5)
+
+
+def _plan_by_hand(idx, n_groups, rows):
+    """The layout in numpy: slots in expert order (stable), each group
+    padded to whole tiles, an empty group one tile, room for every slot."""
+    n = idx.size
+    flat = idx.reshape(-1)
+    tiles = -(-n // rows) + n_groups
+    row_slot = np.full(tiles * rows, n, np.int32)
+    token_rows = np.zeros(n, np.int32)
+    tile_group = np.full(tiles, n_groups - 1, np.int32)
+    tile = 0
+    for group in range(n_groups):
+        members = np.flatnonzero(flat == group)
+        row_slot[tile * rows:tile * rows + len(members)] = members
+        token_rows[members] = tile * rows + np.arange(len(members))
+        took = max(-(-len(members) // rows), 1)
+        tile_group[tile:tile + took] = group
+        tile += took
+    return row_slot, token_rows.reshape(idx.shape), tile_group, tile
+
+
+@pytest.mark.parametrize("tokens,k,groups,rows", [
+    (37, 2, 8, 8), (128, 8, 64, 16), (40, 3, 4, 8)])
+def test_the_plan_of_all_the_experts_is_what_it_was(jax_cpu, tokens, k,
+                                                    groups, rows):
+    """plan_dispatch(partial=False): shapes and values as the layout says,
+    room for every slot, no token_held; nothing of the bound reaches it."""
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+    idx = np.random.default_rng(tokens).integers(0, groups, (tokens, k)
+                                                 ).astype(np.int32)
+    idx[: tokens // 4] = 0                                     # skewed
+    plan = moe.plan_dispatch(jnp.asarray(idx), groups, rows)
+    row_slot, token_rows, tile_group, used = _plan_by_hand(idx, groups, rows)
+    assert plan.token_held is None
+    assert plan.row_slot.shape == row_slot.shape
+    np.testing.assert_array_equal(plan.row_slot, row_slot)
+    np.testing.assert_array_equal(plan.token_rows, token_rows)
+    np.testing.assert_array_equal(plan.tile_group, tile_group)
+    np.testing.assert_array_equal(plan.tiles_used, [used])
+
+
+@pytest.mark.parametrize("whole_layer", [True, False],
+                         ids=["all_experts_held", "a_share_held"])
+def test_only_a_share_lowers_to_a_conditional(jax_cpu, tiny, monkeypatch,
+                                              whole_layer):
+    """Lowered for the TPU, where the kernels are Mosaic calls (interpreted,
+    every `pl.when` of theirs is a conditional too)."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import kanana
+    from ray_tpu.models.gpt import GPTConfig, Setting, _moe_block, gpt_init
+    from ray_tpu.ops import attention
+    config = copy.deepcopy(tiny)
+    if whole_layer:
+        del config["share"]
+        config["n_routed_experts"] = 16
+    cfg = GPTConfig(**kanana.gpt_config_kwargs(config), dtype=jnp.float32,
+                    attention="reference")
+    layer = gpt_init(jax.random.PRNGKey(0), cfg)["layers"][1]
+    x = jnp.zeros((2, 64, 128), jnp.float32)
+
+    # (another batch than the one lowered below: a share's branches are
+    # jitted, and a trace of these shapes with the kernels interpreted
+    # would be found again)
+    bounded = _moe_block(layer, x[:1], cfg, Setting())[1][
+        "expert_rows_bounded"]
+    if whole_layer:
+        assert bounded == 1.0 and isinstance(bounded, float)
+    else:
+        assert bounded.shape == () and float(bounded) in (0.0, 1.0)
+
+    def loss(layer, x):
+        y, stats = _moe_block(layer, x, cfg, Setting())
+        return (y ** 2).sum(), stats
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    text = jax.jit(jax.value_and_grad(loss, has_aux=True)).trace(
+        layer, x).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    conditionals = text.count("stablehlo.case") + text.count("stablehlo.if")
+    # a share: one in the forward pass, one in the backward rule
+    assert conditionals == (0 if whole_layer else 2)
+
+
 def test_tile_rows_follow_from_the_held_count():
     import jax.numpy as jnp
     from ray_tpu.ops import moe

@@ -116,8 +116,9 @@ def test_loss_and_aux_returns_the_routing_statistics(loss_aux_grads):
     (loss, aux), _grads = loss_aux_grads
     assert set(aux) == {"xent", "router_balance_loss", "router_z_loss",
                         "expert_load_max_over_mean",
-                        "expert_slots_held_share"}
+                        "expert_slots_held_share", "expert_rows_bounded"}
     assert aux["expert_slots_held_share"] == 1.0    # all the experts held
+    assert aux["expert_rows_bounded"] == 1.0        # so nothing to bound
     np.testing.assert_allclose(
         loss, aux["xent"] + 0.01 * aux["router_balance_loss"]
         + 0.001 * aux["router_z_loss"], rtol=1e-6)
