@@ -101,10 +101,20 @@ def _metric_handles() -> dict:
         _metrics = {
             "start": metrics.Gauge(
                 "ray_tpu_train_start_seconds",
-                "wall time of the last run's start-up on the driver: "
+                "wall time of the last run's start-up. On the driver: "
                 "Phase=workers (placement, actors answering, backend "
                 "hook), Phase=training (loop shipped, every worker's "
-                "start_run back)", tag_keys=("Phase",)),
+                "start_run back). In the slowest worker, carried by its "
+                "first message: Phase=first_report (start_run -> the "
+                "loop's first report()) and, inside it, what it compiled: "
+                "Phase=trace, lower, cache_load (loads from the persistent "
+                "cache), compile (backend compiles less those loads)",
+                tag_keys=("Phase",)),
+            "recompiles": metrics.Counter(
+                "ray_tpu_train_recompiles_total",
+                "programs a train worker compiled after its first "
+                "message: a shape or a static argument that changed "
+                "mid-run (the compile:* spans name the function)"),
             "report": metrics.Histogram(
                 "ray_tpu_train_report_seconds",
                 "what a train.report() round costs: Phase=blocked (the "
@@ -119,13 +129,14 @@ def _metric_handles() -> dict:
 
 
 def _export_span(name: str, start: float, end: float,
-                 only_if_traced: bool = False) -> None:
+                 only_if_traced: bool = False, **extra) -> None:
     try:
         from ray_tpu.util import tracing
         if only_if_traced and not tracing.is_enabled():
             return
         from ray_tpu._private import flightrec
-        tracing.export_span(flightrec.span_event(name, "train", start, end))
+        tracing.export_span(flightrec.span_event(name, "train", start, end,
+                                                 **extra))
     except Exception:  # noqa: BLE001 — observability never blocks
         pass
 
@@ -300,6 +311,7 @@ class BackendExecutor:
                        checkpoint: Optional[Checkpoint] = None,
                        datasets_per_worker: Optional[List[dict]] = None):
         started = time.time()
+        self._first_round = True
         fn_b = cloudpickle.dumps(train_fn)
         refs = []
         for i, (w, ctx) in enumerate(zip(self.worker_group.workers,
@@ -369,12 +381,51 @@ class BackendExecutor:
                     pending.discard(i)
         _export_span("train:round", started, time.time(),
                      only_if_traced=True)
+        self._fold_compiles({i: finished.get(i) or out
+                             for i, out in enumerate(results)})
         if finished and len(finished) == len(results):
             return None
         if finished:
             # Mixed done/report: treat stragglers' reports as the last round.
             return [r for r in results if r is not None] or None
         return results
+
+    def _fold_compiles(self, messages: Dict[int, dict]) -> None:
+        """What the workers compiled since their last message
+        (_private/compile_cache.py's records, carried by this round's
+        messages): every record a compile:<phase> span on its worker's
+        lane; the first round's are the run's start-up, in the gauge, and
+        any later one is a recompile, in the counter."""
+        handles = _metric_handles()
+        for i, out in messages.items():
+            pid = self.node_info_per_worker[i].get("pid")
+            for fun_name, phase, start, end, load_s in out.get("compiles", ()):
+                cache = ({"cache": "miss" if load_s is None else "hit"}
+                         if phase == "compile" else {})
+                _export_span("compile:" + phase, start, end, pid=pid,
+                             fun_name=fun_name, **cache)
+        if not self._first_round:
+            recompiles = sum(record[1] == "compile"
+                             for out in messages.values()
+                             for record in out.get("compiles", ()))
+            if recompiles:
+                handles["recompiles"].inc(recompiles)
+            return
+        self._first_round = False
+        slowest = max(messages.values(),
+                      key=lambda out: out.get("first_report_s", -1.0))
+        seconds = dict.fromkeys(("trace", "lower", "cache_load", "compile"),
+                                0.0)
+        for _name, phase, start, end, load_s in slowest.get("compiles", ()):
+            if phase == "compile":
+                seconds["cache_load"] += load_s or 0.0
+                seconds["compile"] += max(0.0, end - start - (load_s or 0.0))
+            else:
+                seconds[phase] += end - start
+        if "first_report_s" in slowest:     # a loop that never reports: none
+            seconds["first_report"] = slowest["first_report_s"]
+        for phase, value in seconds.items():
+            handles["start"].set(value, {"Phase": phase})
 
     def _interrupt(self):
         for w in self.worker_group.workers:

@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from ray_tpu._private import compile_cache
 from ray_tpu.train.checkpoint import Checkpoint
 
 
@@ -93,7 +94,8 @@ class _Session:
 
     def __init__(self, context: TrainContext,
                  checkpoint: Optional[Checkpoint] = None,
-                 datasets: Optional[Dict[str, Any]] = None):
+                 datasets: Optional[Dict[str, Any]] = None,
+                 started: Optional[float] = None):
         self.context = context
         self.starting_checkpoint = checkpoint
         self.datasets = datasets or {}
@@ -102,6 +104,9 @@ class _Session:
         # (the driver had not taken the round before); rides the NEXT
         # message, since it is known only once the put has returned.
         self._blocked_s: Optional[float] = None
+        # When TrainWorker.start_run was entered, on this process's
+        # monotonic clock: the first report says how long ago that was.
+        self._started = started
         self._stop = threading.Event()
         # Save-on-preempt: set by TrainWorker.request_save (driver push) or
         # implied by a drain notice for this worker's node; cleared when a
@@ -138,9 +143,13 @@ class _Session:
         jax = sys.modules.get("jax")
         with (jax.profiler.TraceAnnotation("train:report") if jax is not None
               else contextlib.nullcontext()):
-            self._put({"type": "report", "metrics": _host_value(metrics),
+            message = {"type": "report", "metrics": _host_value(metrics),
                        "checkpoint": checkpoint,
-                       "rank": self.context.world_rank})
+                       "rank": self.context.world_rank}
+            if self._started is not None:
+                message["first_report_s"] = time.monotonic() - self._started
+                self._started = None
+            self._put(message)
         # Block until consumed: put the *next* item only after the driver
         # drains; queue(maxsize=1) already provides that.
 
@@ -150,6 +159,11 @@ class _Session:
 
     def _put(self, message: dict) -> None:
         message["blocked_s"] = self._blocked_s
+        # what this process compiled since its last message (start-up's
+        # programs on the first, a recompile on a later one)
+        compiles = compile_cache.drain()
+        if compiles:
+            message["compiles"] = compiles
         t0 = time.perf_counter()
         self._results.put(message)
         self._blocked_s = time.perf_counter() - t0

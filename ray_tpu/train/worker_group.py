@@ -12,12 +12,14 @@ from __future__ import annotations
 
 import socket
 import threading
+import time
 import traceback
 from typing import Any, Callable, Dict, List, Optional
 
 import cloudpickle
 
 import ray_tpu
+from ray_tpu._private import compile_cache
 from ray_tpu.train import session as _session_mod
 from ray_tpu.train.session import TrainContext, _Session
 from ray_tpu.util.placement_group import placement_group, \
@@ -45,8 +47,12 @@ class TrainWorker:
     def start_run(self, fn_bytes: bytes, config: Optional[dict],
                   context: TrainContext,
                   checkpoint=None, datasets: Optional[dict] = None) -> None:
+        started = time.monotonic()
+        compile_cache.watch()
+        compile_cache.drain()       # an earlier run's, or the backend hook's
         fn = cloudpickle.loads(fn_bytes)
-        sess = _Session(context, checkpoint=checkpoint, datasets=datasets)
+        sess = _Session(context, checkpoint=checkpoint, datasets=datasets,
+                        started=started)
         self._session = sess
         _session_mod._set_session(sess)
 

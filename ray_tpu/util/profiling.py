@@ -127,7 +127,7 @@ KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
            "short_conv_bwd")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
-HOST_SPAN_PREFIXES = ("train:", "host:")
+HOST_SPAN_PREFIXES = ("train:", "host:", "compile:")
 
 _DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 _OPS_LINE = "XLA Ops"
@@ -170,8 +170,9 @@ def device_trace(log_dir: str):
 def _load_xplane(path: str) -> Dict[str, Any]:
     """{'devices': {chip: [Event]}, 'host': [Event]} of an .xplane.pb: each
     TPU plane's "XLA Ops" line (one event per executed HLO op, named by the
-    op's whole HLO text), and the host threads' spans named train:*, host:*
-    or STRETCH_SPAN."""
+    op's whole HLO text), and the host threads' spans named train:*, host:*,
+    compile:* (_private/compile_cache.py's, one a compile's phase) or
+    STRETCH_SPAN."""
     from jax.profiler import ProfileData
     devices: Dict[int, List[Event]] = {}
     host: List[Event] = []
@@ -392,8 +393,8 @@ def device_regions(trace, compiled) -> Dict[str, Any]:
                   collective ops (what the core waits in or runs, not what
                   compute hides);
       idle_gaps   [host span, seconds]: every gap of the op line by the
-                  train:* or host:* span that overlaps most of it (see
-                  `clock_skew_note`).
+                  train:*, host:* or compile:* span that overlaps most of
+                  it (see `clock_skew_note`).
     """
     if isinstance(trace, str):
         trace = _load_xplane(trace)
