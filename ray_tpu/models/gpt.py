@@ -595,7 +595,13 @@ def _route(m, x, cfg: GPTConfig):
                          "(expected 'softmax' | 'sigmoid')")
     if "router_bias" in m:
         _, idx = jax.lax.top_k(scores + m["router_bias"], k)
-        weights = jnp.take_along_axis(scores, idx, axis=-1)
+        # scores[idx] as a one-hot product (one term of each sum is not
+        # zero, and idx's k are distinct: the values and the gradient are
+        # take_along_axis's to the bit): the TPU serialises an element
+        # gather, and its transpose, a scatter-add
+        weights = jnp.sum(
+            jnp.where(idx[..., None] == jnp.arange(e), scores[..., None, :],
+                      0), axis=-1)
     else:
         weights, idx = jax.lax.top_k(scores, k)
     if cfg.router_renormalise:

@@ -36,9 +36,29 @@ sizes on the device, and over room for every slot when it does not
 same rows in the same order; the second is only slower. Nothing is
 dropped, clipped or approximated either way.
 
+The token side of a share (`rows_to_tokens`: `combine` forward and
+`dispatch` backward, one operation, rows -> tokens) is sized the same way.
+By the slots it gathers a row for each of the T x k slots, seven eighths
+of them masked out on a chip that holds an eighth of the experts, into
+`[T, k, d]` and sums over k. Where the row space and the tokens together
+are fewer rows than that (R + T < T x k: a share's bounded row space),
+`lay_out` adds a token-ordered view of the row space (`ByToken`: one sort
+of its R keys) and the sum goes by the rows: gather the R rows in token
+order, add each token's run of at most k rows onto its head
+(`moe_run_sum`), gather the T heads. Which of the two runs is read off the
+plan's static shapes, nothing else: every slot's row space, the fallback
+above included, and a plan of all the experts (every slot is a real row:
+T x k is the least there is to move) go by the slots, op for op as they
+did. The sum is the same float32 sum of the same rows, rounded once; by
+the rows it adds them in choice order from the first held one. Every data
+movement is still a gather, forward and backward: the view only changes
+which rows are gathered, and how many.
+
 Kernels (names in util/profiling.KERNELS): `moe_gmm` (rows x an expert's
-matrix, forward and the gradient of the rows) and `moe_tgmm` (rows^T x
-rows a group, the gradient of the matrices).
+matrix, forward and the gradient of the rows), `moe_tgmm` (rows^T x rows a
+group, the gradient of the matrices) and `moe_run_sum` (each row plus the
+rows after it that are of its token, weighted: one pass over the
+token-ordered rows).
 """
 
 from __future__ import annotations
@@ -52,7 +72,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, short_conv
 from ray_tpu.ops.attention import lane_divisor
 
 # One weight block in VMEM (it is double-buffered): a whole 2048 x 1024
@@ -64,6 +84,21 @@ _MAX_TILE_ROWS = 256
 # Random routing puts 0.11-0.14 of the slots on an eighth of the experts
 # (PERF.md, PR 31); past twice the expectation the plan for every slot runs.
 _ROW_SPACE_FACTOR = 2
+
+
+class ByToken(NamedTuple):
+    """The row space read token by token: its rows sorted by the slot they
+    hold, so a token's held rows are one run of at most k, its choices in
+    order, and the padding rows come last.
+
+    rows   [R] the row that is j-th by slot
+    slots  [R] the slot that row holds; T * k on a padding row
+    heads  [T] where each token's run starts; R (where `moe_run_sum`
+           writes zeros) for a token none of whose slots is held
+    """
+    rows: jax.Array
+    slots: jax.Array
+    heads: jax.Array
 
 
 class Plan(NamedTuple):
@@ -79,6 +114,13 @@ class Plan(NamedTuple):
                 expert is one of the groups. Where it is not, token_rows
                 is 0 (a row that is always computed, so finite) and the
                 slot is masked out wherever token_rows is read
+    by_token    the token-ordered view of the row space (`ByToken`), by
+                which `rows_to_tokens` moves R + T rows: made where that
+                is fewer than the T * k it moves through token_rows (a
+                share's bounded row space), None where it is not (every
+                slot's row space, all the experts held). `lay_out` reads
+                that off the shapes alone; like the other tables it is
+                integers and not differentiated
 
     `tiles` is every slot's worst case from `plan_dispatch` (T * k rows and
     a tile a group) and whatever `lay_out` was given otherwise: at least
@@ -89,6 +131,7 @@ class Plan(NamedTuple):
     tile_group: jax.Array
     tiles_used: jax.Array
     token_held: Optional[jax.Array] = None
+    by_token: Optional[ByToken] = None
 
 
 class Order(NamedTuple):
@@ -112,12 +155,16 @@ class Order(NamedTuple):
     token_held: Optional[jax.Array] = None
 
 
+def _sublanes(dtype) -> int:
+    """Rows of one register: 8 of float32, 16 of bfloat16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
 def tile_rows(n_slots: int, n_groups: int, dtype) -> int:
     """Rows of one tile, from the shape alone: about a quarter of the mean
     group (so padding, half a tile a group on average, stays near an
     eighth of the rows), between the type's sublane packing and 256."""
-    least = 32 // jnp.dtype(dtype).itemsize      # 8 rows of f32, 16 of bf16
-    rows = least
+    rows = _sublanes(dtype)
     while rows * 2 <= min(_MAX_TILE_ROWS, n_slots // (4 * n_groups)):
         rows *= 2
     return rows
@@ -172,9 +219,24 @@ def lay_out(order: Order, rows: int, tiles: int) -> Plan:
         + jnp.arange(rows, dtype=jnp.int32)[None, :]
     held = jnp.clip(order.first_rank[tile_group][:, None] + within, 0, n - 1)
     row_slot = jnp.where(within < order.sizes[tile_group][:, None],
-                         order.order[held], n)
-    return Plan(row_slot.reshape(-1), order.token_rows, tile_group,
-                order.tile_end[-1:], order.token_held)
+                         order.order[held], n).reshape(-1)
+    return Plan(row_slot, order.token_rows, tile_group, order.tile_end[-1:],
+                order.token_held, _by_token(row_slot, order.token_held))
+
+
+def _by_token(row_slot, token_held) -> Optional[ByToken]:
+    """The token-ordered view of a row space that is smaller than the slots
+    (one sort of its R keys), None of one that is not."""
+    if token_held is None:
+        return None
+    r, (t, k) = row_slot.shape[0], token_held.shape
+    if r + t >= t * k:
+        return None
+    slots, rows = lax.sort((row_slot, jnp.arange(r, dtype=jnp.int32)),
+                           num_keys=1)
+    count = jnp.sum(token_held, axis=1, dtype=jnp.int32)
+    return ByToken(rows, slots,
+                   jnp.where(count > 0, jnp.cumsum(count) - count, r))
 
 
 def _every_slot(n_slots: int, n_groups: int, rows: int) -> int:
@@ -273,10 +335,105 @@ def _held_only(per_slot, plan: Plan):
     return jnp.where(held, per_slot, 0)
 
 
+def _run_sum_kernel(x_ref, after_ref, c_ref, o_ref):
+    """Grid (row blocks and one more, column blocks). o[j] = sum_a c[j, a]
+    x[j + a], the rows past the block from `after_ref`, the next block's
+    first rows; the block past the last is zeros."""
+    last = pl.num_programs(0) - 1
+
+    @pl.when(pl.program_id(0) == last)
+    def _no_row():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(pl.program_id(0) < last)
+    def _runs():
+        x = x_ref[...].astype(jnp.float32)
+        after = after_ref[...].astype(jnp.float32)
+        total = jnp.zeros_like(x)
+        for ahead in range(c_ref.shape[1]):
+            c = c_ref[:, ahead:ahead + 1]
+            # select, never multiply by zero: a row past tiles_used was
+            # never computed and holds whatever the buffer held
+            total = total + jnp.where(
+                c != 0, c * short_conv._shifted(x, after, ahead, False), 0.0)
+        o_ref[...] = total.astype(o_ref.dtype)
+
+
+def _run_sum(x, c, block: int, interpret: bool):
+    """x [R, d], c [R, k] float32 -> [R + block, d]: row j is the float32
+    sum of c[j, a] x[j + a] over a < k, rounded once (c is zero wherever
+    j + a is past the end), and the last `block` rows are zeros. One pass
+    over x in blocks of `block` rows, the k - 1 rows a block needs of its
+    neighbour through a second BlockSpec of one sublane tile."""
+    r, d = x.shape
+    halo = _sublanes(x.dtype)
+    cols = lane_divisor(d, 2048)
+    per, blocks = block // halo, r // block
+    return pl.pallas_call(
+        _run_sum_kernel,
+        grid=(blocks + 1, d // cols),
+        in_specs=[
+            pl.BlockSpec((block, cols),
+                         lambda i, j: (jnp.minimum(i, blocks - 1), j)),
+            pl.BlockSpec((halo, cols),
+                         lambda i, j: (jnp.minimum((i + 1) * per,
+                                                   r // halo - 1), j)),
+            pl.BlockSpec((block, c.shape[1]),
+                         lambda i, j: (jnp.minimum(i, blocks - 1), 0)),
+        ],
+        out_specs=pl.BlockSpec((block, cols), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((r + block, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="moe_run_sum",
+    )(x, x, c)
+
+
+def rows_to_tokens(rows, plan: Plan, weights=None):
+    """Expert-ordered rows [R, d] -> tokens [T, d]: the float32 sum of each
+    token's held rows, weighted by weights [T, k] (float32) where given,
+    rounded once. `combine` forward and `dispatch` backward.
+
+    By the slots (plan.by_token is None: every slot is a row, or the row
+    space is as large as the slots): gather [T, k, d] through token_rows,
+    mask what is not held, sum over k. By the rows (a share's bounded row
+    space): gather the R rows in token order, add each run onto its head
+    (`moe_run_sum`: a row's coefficients are its followers' weights as far
+    as they are of its token), gather the T heads. R + T rows moved where
+    T * k were, and nothing shaped [T, k, d]."""
+    view = plan.by_token
+    k = plan.token_rows.shape[1]
+    tile = plan.row_slot.shape[0] // plan.tile_group.shape[0]
+    if (view is None or k - 1 > _sublanes(rows.dtype)
+            or tile % _sublanes(rows.dtype)):
+        per_slot = rows[plan.token_rows]
+        if weights is None:
+            y = jnp.sum(_held_only(per_slot, plan), axis=1,
+                        dtype=jnp.float32)
+        else:
+            y = jnp.einsum("tk,tkd->td", _held_only(weights, plan), per_slot,
+                           preferred_element_type=jnp.float32)
+        return y.astype(rows.dtype)
+    token = view.slots // k
+    # a row's weight, zero on a padding row (whose slot is T * k)
+    weight = ((view.slots < plan.token_held.size).astype(jnp.float32)
+              if weights is None
+              else _take_rows(weights.reshape(-1), view.slots))
+
+    def follower(ahead):
+        # the row `ahead` after each: its weight if it is of the same token
+        same = jnp.pad(token[ahead:], (0, ahead), constant_values=-1) == token
+        return jnp.where(same, jnp.pad(weight[ahead:], (0, ahead)), 0)
+    runs = _run_sum(rows[view.rows],
+                    jnp.stack([follower(a) for a in range(k)], axis=1),
+                    tile, attention._default_interpret())
+    return jnp.take(runs, view.heads, axis=0, mode="clip")
+
+
 def _dispatch_bwd(plan, g):
-    dx = jnp.sum(_held_only(g[plan.token_rows], plan), axis=1,
-                 dtype=jnp.float32)
-    return dx.astype(g.dtype), None
+    return rows_to_tokens(g, plan), None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -286,9 +443,7 @@ dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 def combine(z, weights, plan: Plan):
     """Expert-ordered rows [rows, d] back to tokens [T, d]: each token's k
     rows, weighted (weights [T, k] float32) and summed in float32."""
-    y = jnp.einsum("tk,tkd->td", _held_only(weights, plan),
-                   z[plan.token_rows], preferred_element_type=jnp.float32)
-    return y.astype(z.dtype)
+    return rows_to_tokens(z, plan, weights)
 
 
 def _combine_fwd(z, weights, plan):

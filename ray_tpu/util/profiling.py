@@ -123,8 +123,8 @@ REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_out",
            "conv", "conv_mix", "mlp", "moe", "moe_route", "moe_shared",
            "norm", "head", "loss_and_grad", "grad_accum", "optimizer")
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
-           "moe_tgmm", "rope_split", "rope_merge", "short_conv_fwd",
-           "short_conv_bwd")
+           "moe_tgmm", "moe_run_sum", "rope_split", "rope_merge",
+           "short_conv_fwd", "short_conv_bwd")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:", "compile:")

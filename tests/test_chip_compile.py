@@ -385,7 +385,8 @@ def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, name,
     gradients under the layer's remat, for one described chip: the text
     holds the block over the bounded row space and over every slot's, one
     conditional forward and one backward (the forward one's recomputation
-    under the remat is dead code), the kernels once a branch."""
+    under the remat is dead code), the kernels once a branch; and the token
+    side sized by the slots in every slot's branch alone."""
     import json
 
     import jax
@@ -431,6 +432,21 @@ def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, name,
         # outputs, forward and backward
         assert f"s32[{tiles},{tile}]" in text, tiles
         assert text.count(f" = bf16[{tiles * tile},{d}]") >= 4, tiles
+    # the token side (combine forward, dispatch backward) moves every slot's
+    # row, bf16[T, k, d], over every slot's row space only: once a
+    # conditional, in the branch the predicate's false picks. The bounded
+    # branch gathers its own rows in token order and the tokens' run heads
+    # out of moe_run_sum's result, which has a tile of zeros appended.
+    per_slot = [line for line in text.splitlines() if re.search(
+        rf" = bf16\[16384,{cfg.expert_top_k},{d}\]\S* gather\(", line)]
+    assert len(per_slot) == 2
+    assert all("/branch_0_fun/" in line for line in per_slot)
+    assert len(_kernel_ops(text, "moe_run_sum")) == 2
+    runs = f"bf16[{(bounded + 1) * tile},{d}]"
+    assert all(runs in line and "/branch_1_fun/" in line
+               for line in _kernel_ops(text, "moe_run_sum"))
+    # and no element gather or scatter-add of the kept weights
+    assert not re.search(r" scatter\(", text)
 
 
 # (configuration, kernel calls of the compiled step, arguments + temporaries

@@ -222,6 +222,44 @@ def test_the_bias_changes_the_selection_and_not_the_weights(jax_cpu, tiny):
     np.testing.assert_allclose(weights0.sum(-1), 2.448, rtol=1e-6)
 
 
+def test_the_kept_weights_are_take_along_axis_to_the_bit(jax_cpu, tiny):
+    """scores[idx] comes by a one-hot product (the TPU serialises an
+    element gather and its scatter-add): the weights, and the gradients
+    that reach the router and x through the scores, are those of the
+    gather, bit for bit."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import _route
+    cfg, params, _tokens = _program(jax, tiny, "reference")
+    m = dict(params["layers"][1]["moe"])
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 32, 128), jnp.float32)
+    cotangent = jax.random.normal(jax.random.PRNGKey(10), (2, 32, 3))
+
+    def by_gather(router, x):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "bsd,de->bse", x, router, precision=jax.lax.Precision.HIGHEST))
+        _, idx = jax.lax.top_k(scores + m["router_bias"], 3)
+        kept = jnp.take_along_axis(scores, idx, axis=-1)
+        return kept / (jnp.sum(kept, axis=-1, keepdims=True)
+                       + cfg.router_renormalise_eps) * cfg.router_scale
+
+    def by_route(router, x):
+        return _route({**m, "router": router}, x, cfg)[0]
+    want, want_vjp = jax.vjp(by_gather, m["router"], x)
+    got, got_vjp = jax.vjp(by_route, m["router"], x)
+    np.testing.assert_array_equal(got, want)
+    for g, w in zip(got_vjp(cotangent), want_vjp(cotangent)):
+        assert np.abs(np.asarray(w)).max() > 0
+        np.testing.assert_array_equal(g, w)
+    # no element gather of the scores and no scatter in the router
+    text = jax.jit(jax.grad(lambda r, x: (by_route(r, x) * cotangent).sum(),
+                            argnums=(0, 1))).lower(m["router"], x).as_text()
+    assert "scatter" not in text and "stablehlo.gather" not in text
+    assert "scatter" in jax.jit(jax.grad(
+        lambda r, x: (by_gather(r, x) * cotangent).sum(), argnums=(0, 1))
+    ).lower(m["router"], x).as_text()
+
+
 def test_bfloat16_step_passes_the_per_token_check(jax_cpu, tiny):
     """reference_loss with a `program_check` answers the loss where the
     program's own forward (bf16, flash, the grouped-matmul kernels) agrees
@@ -345,6 +383,100 @@ def test_plan_with_tokens_that_have_no_slot_here(jax_cpu):
     assert not np.any(np.asarray(got[1][2])[~here])
 
 
+def _share_plans(jnp, moe, rows=8):
+    """A share's plan over a row space smaller than the slots, which
+    `rows_to_tokens` reads by the rows, and the same plan without the
+    token-ordered view, which it reads by the slots. 64 tokens x 4 choices,
+    4 groups of 16 experts held, tiles of 8 rows (16 for bfloat16): tokens
+    with none, one, two and all four of their slots here, group 2 chosen by
+    nobody, and 160 rows of which the routing fills fewer."""
+    t, k, groups, tiles = 64, 4, 4, 160 // rows
+    rng = np.random.default_rng(11)
+    idx = np.full((t, k), 9, np.int32)                     # not here
+    idx[8:24, 0] = rng.choice([0, 1, 3], 16)               # one slot here
+    idx[24:40, 1:3] = [[0, 3]] * 8 + [[1, 0]] * 8          # two
+    idx[40:44] = [3, 1, 0, 1]                              # all four
+    idx[44:, 3] = rng.choice([0, 1, 3, 9, 12], 20)         # one or none
+    order = moe.order_slots(jnp.asarray(idx), groups, rows, partial=True)
+    plan = moe.lay_out(order, rows, tiles)
+    held = np.asarray(plan.token_held).sum(1)
+    assert set(held) == {0, 1, 2, 4} and int(order.sizes[2]) == 0
+    assert int(plan.tiles_used[0]) < tiles - 1             # padding tiles
+    assert plan.by_token is not None
+    assert tiles * rows + t < t * k
+    return plan, plan._replace(by_token=None), idx
+
+
+def test_the_token_ordered_view_lists_every_held_row_once(jax_cpu):
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+    plan, _by_slots, idx = _share_plans(jnp, moe)
+    t, k = idx.shape
+    view = plan.by_token
+    slots, row_slot = np.asarray(view.slots), np.asarray(plan.row_slot)
+    np.testing.assert_array_equal(slots, row_slot[np.asarray(view.rows)])
+    assert (np.diff(slots) >= 0).all()
+    here = np.asarray(plan.token_held)
+    np.testing.assert_array_equal(slots[:here.sum()],
+                                  np.flatnonzero(here.reshape(-1)))
+    assert (slots[here.sum():] == t * k).all()             # padding, last
+    heads = np.asarray(view.heads)
+    for token in range(t):
+        if here[token].any():
+            run = slots[heads[token]:heads[token] + here[token].sum()]
+            assert (run // k == token).all()
+        else:
+            assert heads[token] == len(slots)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("what", ["combine", "dispatch_vjp", "combine_vjp"])
+def test_by_the_rows_equals_by_the_slots(jax_cpu, what, dtype):
+    """rows_to_tokens over the token-ordered view against the gather of
+    every slot: combine's forward, dispatch's backward (the same sum with
+    no weights) and, through them, combine's own VJP. The rows past
+    tiles_used are never computed on the chip: they hold NaN here and must
+    not reach a token."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+    d, dt = 256, jnp.dtype(dtype)
+    tile = 32 // dt.itemsize
+    plan, by_slots, idx = _share_plans(jnp, moe, tile)
+    t, k = idx.shape
+    r = plan.row_slot.shape[0]
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    z = jax.random.normal(keys[0], (r, d), jnp.float32).astype(dt)
+    never = int(plan.tiles_used[0]) * tile
+    z = jnp.where(jnp.arange(r)[:, None] < never, z, jnp.nan)
+    weights = jax.random.uniform(keys[1], (t, k), jnp.float32)
+    g = jax.random.normal(keys[2], (t, d), jnp.float32).astype(dt)
+    # a sum of at most four terms in another order: a rounding of the result
+    tol = dict(rtol=2e-6, atol=2e-6) if dtype == "float32" \
+        else dict(rtol=1e-2, atol=1e-2)
+
+    def both(fn):
+        return [np.asarray(x, np.float32) for x in fn(plan)], \
+            [np.asarray(x, np.float32) for x in fn(by_slots)]
+    if what == "combine":
+        got, want = both(lambda p: [moe.rows_to_tokens(z, p, weights),
+                                    moe.combine(z, weights, p)])
+    elif what == "dispatch_vjp":
+        got, want = both(lambda p: jax.vjp(
+            lambda x: moe.dispatch(x, p), g)[1](z))
+    else:
+        z = jnp.nan_to_num(z)        # dz is taken at every row
+        got, want = both(lambda p: jax.vjp(
+            lambda z, w: moe.combine(z, w, p), z, weights)[1](g))
+    for a, b in zip(got, want):
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a, b, **tol)
+    if what != "combine_vjp":
+        # a token with nothing here gets zeros, one with one row that row
+        here = np.asarray(plan.token_held)
+        assert not got[0][here.sum(1) == 0].any()
+
+
 # ---------------------------------------------------------------------------
 # (c2) the share's row space: sized for the rows expected, exact past it
 # ---------------------------------------------------------------------------
@@ -436,9 +568,11 @@ def test_bounded_row_space_equals_the_one_for_every_slot(jax_cpu, monkeypatch,
     y_every, grads_every, (always,) = _loss_and_grads(
         jax, share(), x, weights, idx, *matrices)
     assert float(always[0]) == 1.0          # nothing to bound: the constant
-    np.testing.assert_allclose(y, y_every, rtol=1e-6, atol=1e-6)
+    # (the bounded row space adds a token's rows in choice order, from its
+    # first held one: an ulp or two of float32 from the einsum's order)
+    np.testing.assert_allclose(y, y_every, rtol=5e-6, atol=1e-6)
     for g, g_every in zip(grads, grads_every):
-        np.testing.assert_allclose(g, g_every, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(g, g_every, rtol=5e-6, atol=1e-6)
     # and both are the masked dense computation
     np.testing.assert_allclose(
         y, _masked_dense(x, weights, idx, *matrices, first), atol=1e-5)
@@ -450,7 +584,12 @@ def test_bounded_row_space_equals_the_one_for_every_slot(jax_cpu, monkeypatch,
     (512, 0.0),      # every token chose held experts alone
     (17 * 8, 1.0),   # one group of 17 full tiles + 3 empty groups' = 20
     (17 * 8 + 1, 0.0),                       # one row over: 21 tiles
-], ids=["every_slot_here", "exactly_at_the_bound", "one_row_over"])
+    # the token side goes by the rows where the bounded row space runs and
+    # by the slots past it: two and three slots a token here, in runs
+    ("two_a_token", 1.0),     # 32 tokens x 2: two groups of 8 tiles + 2
+    ("three_a_token", 0.0),   # 64 tokens x 3: three groups of 8 + 1 = 25
+], ids=["every_slot_here", "exactly_at_the_bound", "one_row_over",
+        "two_slots_a_token_fit", "three_slots_a_token_do_not"])
 def test_past_the_bound_the_plan_for_every_slot_runs(jax_cpu, here, fits):
     """No capacity: what does not fit the bounded row space runs over room
     for every slot, and the result and its gradients are the masked dense
@@ -465,6 +604,10 @@ def test_past_the_bound_the_plan_for_every_slot_runs(jax_cpu, here, fits):
     flat = np.full(512, first + held + 3, np.int32)           # not here
     if here == 512:
         flat = first + np.random.default_rng(3).integers(0, held, 512)
+    elif here == "two_a_token":
+        flat.reshape(128, 4)[16:80:2, 1:3] = [first + 2, first]
+    elif here == "three_a_token":
+        flat.reshape(128, 4)[:64, :3] = [first + 1, first + 3, first]
     else:
         flat[:here] = first                                    # one group
     idx = jnp.asarray(flat.astype(np.int32)).reshape(2, 64, 4)
@@ -482,19 +625,28 @@ def test_past_the_bound_the_plan_for_every_slot_runs(jax_cpu, here, fits):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
 
 
-def test_eight_shares_one_of_them_past_its_bound_add_up_to_the_whole(jax_cpu):
+@pytest.mark.parametrize("fixed,fitted_want", [
+    ((0, 1), [0.0] + [1.0] * 7),
+    ((0, 1, 14, 15), [0.0] + [1.0] * 6 + [0.0]),
+], ids=["two_on_share_0", "a_collapsed_router"])
+def test_eight_shares_one_of_them_past_its_bound_add_up_to_the_whole(
+        jax_cpu, fixed, fitted_want):
     """Sixteen experts on eight chips, two each, and a router that sends
     two of every token's four choices to experts 0 and 1: share 0 gets four
     times its expectation and runs the plan for every slot, the other seven
-    run bounded, and the eight partial sums are the uncut layer's."""
+    run bounded (their token side by the rows), and the eight partial sums
+    are the uncut layer's. Or all four to experts 0, 1, 14 and 15, a
+    collapsed router: shares 0 and 7 are past their bound and no token has
+    a row on the other six, whose bounded row spaces are all padding."""
     jax = jax_cpu
     import jax.numpy as jnp
     from ray_tpu.models.gpt import _experts
     of, held, k = 16, 2, 4
     x, weights, *matrices = _share_operands(jax, of, seed=2, k=k)
     rng = np.random.default_rng(5)
-    idx = np.stack([np.concatenate([[0, 1],
-                                    2 + rng.permutation(of - 2)[:k - 2]])
+    free = np.setdiff1d(np.arange(of), fixed)
+    idx = np.stack([np.concatenate([fixed,
+                                    rng.permutation(free)[:k - len(fixed)]])
                     for _ in range(128)]).astype(np.int32).reshape(2, 64, k)
     idx = jnp.asarray(idx)
     parts, fitted = [], []
@@ -507,7 +659,7 @@ def test_eight_shares_one_of_them_past_its_bound_add_up_to_the_whole(jax_cpu):
             fitted.append(float(flag[0]))
         whole = _experts(x, weights, idx, *matrices)
         want = _masked_dense(x, weights, idx, *matrices, 0)
-    assert fitted == [0.0] + [1.0] * 7
+    assert fitted == fitted_want
     np.testing.assert_allclose(sum(parts), want, atol=2e-5)
     np.testing.assert_allclose(whole, want, atol=2e-5)
 
@@ -546,6 +698,14 @@ def test_the_plan_of_all_the_experts_is_what_it_was(jax_cpu, tokens, k,
     plan = moe.plan_dispatch(jnp.asarray(idx), groups, rows)
     row_slot, token_rows, tile_group, used = _plan_by_hand(idx, groups, rows)
     assert plan.token_held is None
+    # every slot is a row: no token-ordered view, the two sorts of
+    # order_slots and no third; nor over a share's room for every slot
+    assert plan.by_token is None
+    assert str(jax_cpu.make_jaxpr(
+        lambda i: moe.plan_dispatch(i, groups, rows))(idx)).count(
+            " sort[") == 2
+    assert moe.plan_dispatch(jnp.asarray(idx), groups // 2, rows,
+                             partial=True).by_token is None
     assert plan.row_slot.shape == row_slot.shape
     np.testing.assert_array_equal(plan.row_slot, row_slot)
     np.testing.assert_array_equal(plan.token_rows, token_rows)
@@ -590,6 +750,9 @@ def test_only_a_share_lowers_to_a_conditional(jax_cpu, tiny, monkeypatch,
     text = jax.jit(jax.value_and_grad(loss, has_aux=True)).trace(
         layer, x).lower(lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
+    # every data movement is a gather: none transposed into a scatter-add,
+    # the kept weights' (the layer has a selection bias) included
+    assert "scatter" not in text
     conditionals = text.count("stablehlo.case") + text.count("stablehlo.if")
     # a share: one in the forward pass, one in the backward rule
     assert conditionals == (0 if whole_layer else 2)

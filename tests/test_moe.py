@@ -351,6 +351,26 @@ def test_grouped_matmul_kernels_carry_their_names(jax_cpu, kernel):
     assert kernel in KERNELS and f"name={kernel}" in jaxpr
 
 
+def test_run_sum_kernel_carries_its_name(jax_cpu):
+    """A share's bounded row space sums a token's rows in `moe_run_sum`,
+    in combine's forward and in dispatch's backward."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+    from ray_tpu.util.profiling import KERNELS
+    order = moe.order_slots(jnp.full((32, 4), 7, jnp.int32).at[:, 0].set(1),
+                            2, 8, partial=True)
+    plan = moe.lay_out(order, 8, 8)
+    assert plan.by_token is not None
+    z = jnp.zeros((64, 16))
+    forward = str(jax.make_jaxpr(
+        lambda z, w: moe.combine(z, w, plan))(z, jnp.ones((32, 4))))
+    backward = str(jax.make_jaxpr(jax.grad(
+        lambda x: moe.dispatch(x, plan).sum()))(jnp.zeros((32, 16))))
+    assert "moe_run_sum" in KERNELS
+    assert "name=moe_run_sum" in forward and "name=moe_run_sum" in backward
+
+
 # (g) the family's own half of `correct` (reference_loss's program_check:
 # per-token log-probabilities of the step's forward against the reference),
 # at the rehearsal size with its bound: the sound program gets the
