@@ -11,6 +11,9 @@ layers, and one chip's share of the experts: `experts_held`), optional
 latent attention (a low-rank k/v projection, q.k wider than v), optional
 grouped-query attention with a norm a head, optional gated
 short-convolution layers among the attention layers (ops/short_conv.py),
+optional sliding-window layers among the full-attention layers (a head
+count and a rotation of their own a kind, a gate a head on attention's
+output),
 per-layer jax.checkpoint (remat) for memory: a layer keeps its input and
 the flash kernel's output and row statistics, and recomputes the rest.
 
@@ -34,7 +37,8 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.ops import moe
 from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT, flash_attention,
                                    mha_reference, qk_padding, ring_attention)
-from ray_tpu.ops.rope import rope_split, rope_table
+from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart,
+                              rope_frequencies, rope_split, rope_table)
 from ray_tpu.ops.short_conv import short_conv
 
 
@@ -47,6 +51,9 @@ class GPTConfig:
     # Key/value heads (grouped-query attention): query head h reads
     # key/value head h // (n_heads // n_kv_heads). 0 = n_heads.
     n_kv_heads: int = 0
+    # One head's width. 0 = d_model // n_heads; given, heads x head_dim
+    # need not be d_model (wq [d, H * head_dim], wo [H * head_dim, d]).
+    head_dim: int = 0
     d_ff: int = 3072                  # the MLP's width; of ONE expert's, if sparse
     max_seq: int = 1024
     dtype: Any = jnp.bfloat16
@@ -57,13 +64,26 @@ class GPTConfig:
     # RMSNorm over each head's columns of q and of k, before the rotation:
     # one learned scale of head_dim for q and one for k, shared by the heads.
     qk_head_norm: bool = False
-    # The token mixer of each layer: "attention" | "conv", one a layer.
-    # None = attention everywhere. A "conv" layer is a gated short
+    # The token mixer of each layer: "attention" | "conv" | "window", one a
+    # layer. None = attention everywhere. A "conv" layer is a gated short
     # convolution: [B | C | X] = three projections of the normed input,
     # C * filter(B * X) with a causal depthwise filter of conv_filter taps
     # a channel, then an output projection. No bias, no activation.
     layer_kinds: Optional[Tuple[str, ...]] = None
     conv_filter: int = 3
+    # A "window" layer is attention in which a query sees itself and the
+    # attention_window - 1 positions before it (flash and reference paths).
+    # The two kinds of attention layer share n_kv_heads and head_dim; each
+    # has its own query heads (window_heads, 0 = n_heads) and its own
+    # rotation (rope / window_rope, None = every column rotated as halves
+    # at rope_theta): a table a kind, built once a step.
+    attention_window: int = 0
+    window_heads: int = 0
+    rope: Optional[RopeSpec] = None
+    window_rope: Optional[RopeSpec] = None
+    # A gate a head on attention's output: sigmoid(normed input x wg
+    # [d, heads]) times the head's output, before the output projection.
+    attention_gate: bool = False
     # 0 = a dense MLP a layer; >0 = that many experts in its place, each
     # token through the expert_top_k the router gives the most probability
     # (used as they come out of the softmax, not renormalised). The loss
@@ -122,16 +142,34 @@ class GPTConfig:
     tie_embeddings: bool = False
 
     def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         kinds = self.layer_kinds
         if kinds is not None and (
                 len(kinds) != self.n_layers
-                or set(kinds) - {"attention", "conv"}):
+                or set(kinds) - {"attention", "conv", "window"}):
             raise ValueError(
                 f"layer_kinds {kinds!r}: expected n_layers={self.n_layers} "
-                "of 'attention' | 'conv'")
-        if self.n_heads % self.kv_heads:
-            raise ValueError(f"n_kv_heads={self.n_kv_heads} does not divide "
-                             f"n_heads={self.n_heads}")
+                "of 'attention' | 'conv' | 'window'")
+        for heads in {self.n_heads, self.heads_of("window")}:
+            if heads % self.kv_heads:
+                raise ValueError(f"n_kv_heads={self.n_kv_heads} does not "
+                                 f"divide n_heads={heads}")
+        if "window" in (kinds or ()):
+            if self.attention_window < 1:
+                raise ValueError("layer_kinds has 'window' layers and "
+                                 f"attention_window={self.attention_window}")
+            if (self.kv_latent_dim or self.attention == "ring"
+                    or self.qk_norm or self.qk_head_norm):
+                raise ValueError(
+                    "a 'window' layer is built for the flash and reference "
+                    "paths of multi-head attention, not for "
+                    + ("a latent block" if self.kv_latent_dim
+                       else "attention='ring'" if self.attention == "ring"
+                       else "qk_norm or qk_head_norm"))
+        if self.kv_latent_dim and not (self.rope is None or self.rope.plain):
+            raise ValueError("a latent block rotates qk_rope_dim columns at "
+                             "rope_theta: it reads no RopeSpec")
         if self.kv_heads != self.n_heads and (self.kv_latent_dim
                                               or self.attention == "ring"):
             raise ValueError(
@@ -142,12 +180,17 @@ class GPTConfig:
                    else "attention='ring'"))
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
-
-    @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    def heads_of(self, kind: str) -> int:
+        """Query heads of an "attention" or a "window" layer."""
+        return (self.window_heads if kind == "window" else 0) or self.n_heads
+
+    def rope_of(self, kind: str) -> RopeSpec:
+        """The rotation of an "attention" or a "window" layer."""
+        spec = self.window_rope if kind == "window" else self.rope
+        return RopeSpec(theta=self.rope_theta) if spec is None else spec
 
     @property
     def qk_head_dim(self) -> int:
@@ -168,6 +211,10 @@ class GPTConfig:
     def tiny() -> "GPTConfig":
         return GPTConfig(vocab_size=512, d_model=128, n_layers=2, n_heads=4,
                          d_ff=256, max_seq=128)
+
+
+# The name of an attention layer's parameters, by its kind.
+_GROUP = {"attention": "attn", "window": "window_attn"}
 
 
 def _init_dense(key, shape, scale=None, dtype=jnp.float32):
@@ -226,14 +273,22 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
                     scale=1.0 / math.sqrt(2 * cfg.n_layers * h * cfg.v_head_dim)),
             }
         else:
+            # a window layer's matrices lie under a name of their own: the
+            # block reads a layer's kind off its parameters
+            kind = cfg.layer_kinds[i] if cfg.layer_kinds else "attention"
             kv = cfg.kv_heads * cfg.head_dim
-            layer["attn"] = {
-                "wq": _init_dense(k[0], (d, d)),
+            wide = cfg.heads_of(kind) * cfg.head_dim    # d, but for head_dim
+            layer[_GROUP[kind]] = {
+                "wq": _init_dense(k[0], (d, wide)),
                 "wk": _init_dense(k[1], (d, kv)),
                 "wv": _init_dense(k[2], (d, kv)),
-                "wo": _init_dense(k[3], (d, d),
-                                  scale=1.0 / math.sqrt(2 * cfg.n_layers * d)),
+                "wo": _init_dense(k[3], (wide, d),
+                                  scale=1.0 / math.sqrt(2 * cfg.n_layers * wide)),
             }
+            if cfg.attention_gate:
+                layer[_GROUP[kind]]["wg"] = _init_dense(
+                    jax.random.fold_in(keys[i + 2], 10),
+                    (d, cfg.heads_of(kind)))
         if "attn" in layer and cfg.qk_norm:
             layer["attn"]["q_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
             layer["attn"]["k_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
@@ -334,17 +389,23 @@ def _head_rmsnorm(y, scale, eps):
         return (y32 * factor * jnp.tile(scale, width // dim)).astype(y.dtype)
 
 
-def _rope(x, theta: float, positions):
-    """Rotary position embeddings; x: [B, H, S, D]."""
-    d = x.shape[-1]
-    half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[:, :, None].astype(jnp.float32) * freqs  # [B,S,half]
+def _rope(x, rope, positions):
+    """Rotary position embeddings; x: [B, H, S, D]. rope: a theta, or a
+    RopeSpec: the head's first spec.columns(D) columns are rotated as
+    halves, the rest pass."""
+    spec = as_spec(rope)
+    rotated = spec.columns(x.shape[-1])
+    half = rotated // 2
+    angles = (positions[:, :, None].astype(jnp.float32)
+              * rope_frequencies(spec, x.shape[-1]))          # [B,S,half]
     cos = jnp.cos(angles)[:, None, :, :]
     sin = jnp.sin(angles)[:, None, :, :]
-    x1, x2 = x[..., :half], x[..., half:]
+    if spec.attention_factor != 1.0:
+        cos, sin = cos * spec.attention_factor, sin * spec.attention_factor
+    x1, x2 = x[..., :half], x[..., half:rotated]
     return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, x[..., rotated:]],
+        axis=-1).astype(x.dtype)
 
 
 def _per_shard(fn, mesh, in_dims, out_dims):
@@ -371,19 +432,23 @@ def _per_shard(fn, mesh, in_dims, out_dims):
                      out_specs=spec(out_dims), check_vma=False)
 
 
-def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh):
+def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh, window=None):
     """The projections' outputs (q [B, S, H*D], k and v [B, S, Hkv*D])
     through the head split, the rotation (ops/rope.py: one pass a tensor,
     straight into the kernels' [B, heads, S, D]) and the flash kernel, per
     shard: the columns are whole heads, each device attends its own (batch,
-    head) slice, a key/value head with the query heads that read it."""
+    head) slice, a key/value head with the query heads that read it. A
+    window layer's kernels run under scope `attn_window` (in `attn_core`)."""
     def split_and_attend(q, k, v, *table):
         with jax.named_scope("attn_proj"):
             q = rope_split(q, cfg.head_dim, table)
             k = rope_split(k, cfg.head_dim, table)
             v = rope_split(v, cfg.head_dim)
         with jax.named_scope("attn_core"):
-            return flash_attention(q, k, v, causal=True)
+            if window is None:
+                return flash_attention(q, k, v, causal=True)
+            with jax.named_scope("attn_window"):
+                return flash_attention(q, k, v, causal=True, window=window)
 
     columns = ("batch", None, "heads")
     return _per_shard(split_and_attend, mesh,
@@ -477,30 +542,47 @@ def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
                       ("batch", "heads", None, None))(q, kv, k_rope, *table)
 
 
-def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting):
-    """table: rope_table(S, the rotated width, theta), built once a step by
-    the caller (layer_fn, outside the remat). The flash path alone reads it:
-    'reference' and 'ring' keep the jnp `_rope` on [B, H, S, D] (the
-    oracle, and ring's sequence shards need their global positions); a
-    latent block (`_latent_attention`) reads it on every path."""
+def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting,
+                     kind: str = "attention"):
+    """table: rope_table(S, the rotated width, the kind's rotation), built
+    once a step by the caller (layer_fn, outside the remat). The flash path
+    alone reads it: 'reference' and 'ring' keep the jnp `_rope` on
+    [B, H, S, D] (the oracle, and ring's sequence shards need their global
+    positions); a latent block (`_latent_attention`) reads it on every
+    path. kind: "attention" | "window", which the layer's parameters are
+    named by. Where the layer has a gate (`wg`), scope `attn_gate` holds
+    its matmul, its sigmoid and the product with the heads' outputs."""
     b, s, _ = x.shape
     dt = cfg.dtype
+    a = layer[_GROUP[kind]]
     if cfg.kv_latent_dim:
         o = _latent_attention(layer, x, cfg, table, where)
     else:
-        o = _multi_head_attention(layer, x, cfg, table, where)
+        o = _multi_head_attention(a, x, cfg, table, where, kind)
     with jax.named_scope("attn_out"):
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, -1)
-        return where.psum(
-            jnp.einsum("bsd,de->bse", o, layer["attn"]["wo"].astype(dt)))
+        o = o.transpose(0, 2, 1, 3)
+        if "wg" in a:
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(jnp.einsum(
+                    "bsd,dh->bsh", x, a["wg"].astype(dt),
+                    preferred_element_type=jnp.float32))
+                o = (o * gate[..., None]).astype(dt)
+        return where.psum(jnp.einsum("bsd,de->bse", o.reshape(b, s, -1),
+                                     a["wo"].astype(dt)))
 
 
-def _multi_head_attention(layer, x, cfg: GPTConfig, table, where: Setting):
-    """q, k, v of one head width from three projections (k and v at the
-    key/value heads' count) -> the heads' outputs [B, H, S, head_dim]."""
+def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
+                          kind: str = "attention"):
+    """q, k, v of one head width from the three projections of `a` (an
+    attention layer's matrices; the query heads are wq's columns over
+    head_dim, k and v at the key/value heads' count) -> the heads' outputs
+    [B, H, S, head_dim]. kind "window": under the sliding window, and in
+    either kind the rotation is the kind's (cfg.rope_of)."""
     b, s, _ = x.shape
     hd = cfg.head_dim
     dt = cfg.dtype
+    spec = cfg.rope_of(kind)
+    window = cfg.attention_window if kind == "window" else None
     tensor = 1 if where.mesh is None else where.mesh.shape.get("tensor", 1)
     if cfg.kv_heads % tensor:
         raise ValueError(f"n_kv_heads={cfg.kv_heads} is not whole key/value "
@@ -518,22 +600,29 @@ def _multi_head_attention(layer, x, cfg: GPTConfig, table, where: Setting):
     def heads(y):
         return y.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
 
+    flash = cfg.attention not in ("ring", "reference")
     with jax.named_scope("attn_proj"):
-        a = layer["attn"]
-        q = proj(a["wq"], a.get("q_norm"), a.get("q_head_norm"))
-        k = proj(a["wk"], a.get("k_norm"), a.get("k_head_norm"))
+        wq, wk = a["wq"], a["wk"]
+        if flash and spec.columns(hd) != hd:
+            # the kernels rotate partners half a head apart: a head's
+            # columns in that order, in the weights (ops/rope.py)
+            order = jnp.asarray(halves_apart(hd, spec.columns(hd)))
+            wq, wk = (w.astype(dt).reshape(w.shape[0], -1, hd)[
+                ..., order].reshape(w.shape) for w in (wq, wk))
+        q = proj(wq, a.get("q_norm"), a.get("q_head_norm"))
+        k = proj(wk, a.get("k_norm"), a.get("k_head_norm"))
         v = proj(a["wv"])
-    if cfg.attention not in ("ring", "reference"):
-        return _flash_on_mesh(q, k, v, table, cfg, where.mesh)
+    if flash:
+        return _flash_on_mesh(q, k, v, table, cfg, where.mesh, window)
     with jax.named_scope("attn_proj"):
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-        q = _rope(heads(q), cfg.rope_theta, positions)
-        k = _rope(heads(k), cfg.rope_theta, positions)
+        q = _rope(heads(q), spec, positions)
+        k = _rope(heads(k), spec, positions)
         v = heads(v)
     with jax.named_scope("attn_core"):
         if cfg.attention == "ring":
             return ring_attention(q, k, v, mesh=where.mesh, causal=True)
-        return mha_reference(q, k, v, causal=True)
+        return mha_reference(q, k, v, causal=True, window=window)
 
 
 def _conv_block(m, x, cfg: GPTConfig, where: Setting):
@@ -716,17 +805,23 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     transformer block, rematted as cfg.remat_policy says, for whoever
     walks the layers: gpt_backbone loops over their list, a stage of
     parallel/pipeline.py scans over stacked ones."""
-    # once a step, not once a layer and recompute: outside the remat
+    # once a step, not once a layer and recompute: outside the remat; one
+    # table for each kind of attention layer the stack has
+    kinds = set(cfg.layer_kinds or ("attention",)) - {"conv"}
     with jax.named_scope("attn_proj"):
-        table = rope_table(seq, cfg.qk_rope_dim if cfg.kv_latent_dim
-                           else cfg.head_dim, cfg.rope_theta)
+        tables = {
+            kind: rope_table(seq, cfg.qk_rope_dim if cfg.kv_latent_dim
+                             else cfg.head_dim, cfg.rope_of(kind))
+            for kind in sorted(kinds)}
 
     def block(x, layer):
         normed = _rmsnorm(x, layer["ln1"]["scale"], cfg.rmsnorm_eps)
         if "conv" in layer:
             mixed = _conv_block(layer["conv"], normed, cfg, where)
         else:
-            mixed = _attention_block(layer, normed, cfg, table, where)
+            kind = "window" if _GROUP["window"] in layer else "attention"
+            mixed = _attention_block(layer, normed, cfg, tables[kind], where,
+                                     kind)
         h = where.pin(x + mixed)
         normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
         if "moe" in layer:

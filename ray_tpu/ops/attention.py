@@ -14,6 +14,10 @@ wide. The two widths may differ (latent attention: q.k over 192 columns, v
 num_heads (grouped-query attention): query head h reads key/value head
 h // (num_heads // num_kv_heads), through the kernels' index maps, so k and
 v are never repeated in HBM and dK, dV leave at num_kv_heads.
+
+A sliding window (`window`: query i sees keys j with 0 <= i - j < window)
+runs the same three kernel bodies under names of their own (flash_win_*)
+on a grid whose reduced dimension covers only the blocks the band touches.
 """
 
 from __future__ import annotations
@@ -45,7 +49,12 @@ FLASH_LSE = "flash_lse"
 # ---------------------------------------------------------------------------
 
 def mha_reference(q, k, v, *, causal: bool = True,
-                  sm_scale: Optional[float] = None):
+                  sm_scale: Optional[float] = None,
+                  window: Optional[int] = None):
+    """window (causal only): query i sees keys j with 0 <= i - j < window,
+    itself and the window - 1 before it."""
+    if window is not None and not causal:
+        raise ValueError("a window is a band under the causal mask")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if k.shape[1] != q.shape[1]:
@@ -56,6 +65,9 @@ def mha_reference(q, k, v, *, causal: bool = True,
     if causal:
         qlen, klen = q.shape[2], k.shape[2]
         mask = jnp.tril(jnp.ones((qlen, klen), dtype=bool), klen - qlen)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((qlen, klen), dtype=bool),
+                              klen - qlen - window)
         logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
@@ -146,6 +158,12 @@ def _block_sizes(seq_q: int, seq_k: int, head_dim: int,
                         dkv=(back, back, lane_divisor(back, LANES)))
 
 
+def _band_steps(outer: int, major: int, window: int) -> int:
+    """Major blocks the band of one outer block touches (major divides
+    outer): the outer block's own and those the window reaches into."""
+    return outer // major + -(-(window - 1) // major)
+
+
 def _lanes(x, n: int):
     """A lane-replicated [rows, 128] statistic as [rows, n]."""
     if n <= LANES:
@@ -205,6 +223,95 @@ def _for_tiles(causal, upper, delta, outer, major, group, tile):
                 functools.partial(tile, rows, whole, d))
 
 
+# A score tile's place in the band: query position less key position at the
+# tile's first row and first column, and the window.
+_Band = collections.namedtuple("_Band", "diff window")
+
+
+def _for_band(upper, step, first, blocks, outer, major, group, window, tile):
+    """_for_tiles under a sliding window: run tile(rows, cols, mask) for
+    the score tiles of this grid step that the band 0 <= query - key <
+    window leaves something of. The reduced grid dimension walks only the
+    major blocks the band of an outer block touches (`_band_steps`), so
+    which step this is says statically where the band lies in it: one
+    branch a step, each with the column range of every row group cut to
+    the band at lane tiles, and the mask (`_Band`) on the tiles an edge
+    crosses alone.
+
+    Keys as columns (`upper` false: the forward, dQ): step j holds major
+    block first - j, the outer block's last one first, so that the first
+    tile a row group meets holds its diagonal and every row has a key
+    before a tile in which the window leaves it none. Queries as columns
+    (`upper`: dK/dV): step j holds major block first + j. A block before
+    the sequence's start or past its end (of `blocks`) is skipped."""
+    groups = [slice(i * group, (i + 1) * group)
+              for i in range(outer // group)]
+    align = LANES if major % LANES == 0 else major
+    for j in range(_band_steps(outer, major, window)):
+        # query - key at row 0, column 0 of the step's [outer, major]
+        at_origin = j * major if upper else (j + 1) * major - outer
+        tiles = []
+        for rows in groups:
+            if upper:       # diff = at_origin + col - row
+                lo = rows.start - at_origin
+                hi = rows.stop - 1 - at_origin + window - 1
+            else:           # diff = at_origin + row - col
+                lo = rows.start + at_origin - window + 1
+                hi = rows.stop - 1 + at_origin
+            lo, hi = max(lo, 0), min(hi, major - 1)
+            if lo > hi:
+                continue
+            cols = slice(lo // align * align,
+                         min(-(-(hi + 1) // align) * align, major))
+            diff = at_origin + (cols.start - rows.start if upper
+                                else rows.start - cols.start)
+            tiles.append((rows, cols, _Band(diff, window)))
+        block = first + j if upper else first - j
+
+        def run(tiles=tiles):
+            for t in tiles:
+                tile(*t)
+        if tiles:
+            pl.when((step == j) & (block >= 0) & (block < blocks))(run)
+
+
+def _band_mask(s, band, upper):
+    """s [rows, width] with NEG_INF outside the band, masked a lane tile of
+    columns at a time and only where an edge crosses: the causal edge
+    (query - key >= 0), the window's (query - key < window), or both."""
+    rows, width = s.shape
+    chunk = LANES if width % LANES == 0 else width
+    pieces = []
+    for x0 in range(0, width, chunk):
+        x1 = x0 + chunk
+        if upper:
+            least, most = band.diff + x0 - (rows - 1), band.diff + x1 - 1
+        else:
+            least, most = band.diff - (x1 - 1), band.diff + rows - 1 - x0
+        edges = (least < 0, most >= band.window)
+        if pieces and pieces[-1][0] == edges:
+            pieces[-1][2] = x1
+        else:
+            pieces.append([edges, x0, x1])
+    out = []
+    for (causal_edge, window_edge), x0, x1 in pieces:
+        part = s[:, x0:x1]
+        if causal_edge or window_edge:
+            col = jax.lax.broadcasted_iota(jnp.int32, part.shape, 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, part.shape, 0)
+            diff = band.diff + x0 + col - row if upper \
+                else band.diff - x0 + row - col
+            keep = None
+            if causal_edge:
+                keep = diff >= 0
+            if window_edge:
+                inside = diff < band.window
+                keep = inside if keep is None else keep & inside
+            part = jnp.where(keep, part, NEG_INF)
+        out.append(part)
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+
 def _mask(s, mask, upper):
     """(s with NEG_INF where the causal mask says so, what was kept or None
     where no row can have lost every column)."""
@@ -215,6 +322,9 @@ def _mask(s, mask, upper):
 
     if mask is None:
         return s, None
+    if isinstance(mask, _Band):
+        # no row can have lost every column it has MET: _for_band's order
+        return _band_mask(s, mask, upper), None
     if isinstance(mask, str):
         group, width = s.shape
         corner = s[:, :group] if upper else s[:, width - group:]
@@ -228,11 +338,26 @@ def _mask(s, mask, upper):
     return jnp.where(keep, s, NEG_INF), keep
 
 
+def _walk(upper, band, causal, delta, step, at, outer, major, group, tile):
+    """The tiles of one grid step: under the causal mask alone (or none),
+    or, with band = (window, major blocks in the sequence), in the band of
+    outer block `at`, whose first step holds its own last major block
+    (keys as columns) or its own first (`upper`)."""
+    if band is None:
+        _for_tiles(causal, upper, delta, outer, major, group, tile)
+    else:
+        first = at * (outer // major) if upper \
+            else (at + 1) * (outer // major) - 1
+        _for_band(upper, step, first, band[1], outer, major, group, band[0],
+                  tile)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *, sm_scale, causal, group,
-                      offset):
+                      offset, band=None):
     """Grid (batch*head, q block, k major block): online softmax over the k
-    blocks of one q block.
+    blocks of one q block (with `band`, over those its window touches:
+    `_for_band`).
 
     offset = seq_k - seq_q: masking is bottom-right aligned, matching
     mha_reference (query i attends keys <= i + offset). Also emits the
@@ -268,7 +393,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_ref[rows, :] = (acc_ref[rows, :] * _lanes(alpha, d)
                             + _dot(p.astype(v.dtype), v, (1, 0)))
 
-    _for_tiles(causal, False, qi * bq + offset - ki * bk, bq, bk, group, tile)
+    _walk(False, band, causal, qi * bq + offset - ki * bk, ki, qi, bq, bk,
+          group, tile)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
@@ -287,7 +413,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, acc_ref, *, sm_scale, causal, group,
-                         offset):
+                         offset, band=None):
     """Grid (batch*head, q block, k major block): dQ of one q block.
 
     p = exp(s - lse); dS = p * (dO·Vᵀ - delta); dQ = scale · dS·K
@@ -314,7 +440,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta)
         acc_ref[rows, :] += _dot(ds.astype(k.dtype), k, (1, 0))
 
-    _for_tiles(causal, False, qi * bq + offset - ki * bk, bq, bk, group, tile)
+    _walk(False, band, causal, qi * bq + offset - ki * bk, ki, qi, bq, bk,
+          group, tile)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
@@ -323,14 +450,15 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, sm_scale,
-                          causal, group, offset, q_blocks=None):
+                          causal, group, offset, q_blocks=None, band=None):
     """Grid (batch*kv_head, k block, q major block): dK and dV of one k
     block, on transposed tiles [keys, queries].
 
     dV = Pᵀ·dO; dK = scale · dSᵀ·Q. q_blocks: None where a key/value head
     has one query head; else the q blocks of one query head, and the last
     grid dimension walks them once for each query head of the group, all
-    into the same accumulators.
+    into the same accumulators. With `band` the q blocks of one query head
+    are the q_blocks steps of the band (`_for_band`), not all of them.
     """
     bk = k_ref.shape[1]
     bq = q_ref.shape[1]
@@ -355,7 +483,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dst = pt * (dpt - delta)
         dk_acc_ref[rows, :] += _dot(dst.astype(q.dtype), q, (1, 0))
 
-    _for_tiles(causal, True, ki * bk - (qi * bq + offset), bk, bq, group, tile)
+    _walk(True, band, causal, ki * bk - (qi * bq + offset), qi, ki, bk, bq,
+          group, tile)
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
@@ -400,6 +529,20 @@ def _kv_index(causal, offset, bq, bkm, num_k, rep):
     return index
 
 
+def _band_index(outer, major, blocks, rep):
+    """_kv_index under a window: step j of the reduced dimension holds the
+    major block `_for_band` says (keys: the outer block's last one less
+    j), held inside the sequence so that a skipped step fetches nothing
+    new."""
+    def head(b):
+        return b if rep == 1 else b // rep
+
+    def index(b, i, j):
+        block = (i + 1) * (outer // major) - 1 - j
+        return (head(b), jnp.clip(block, 0, blocks - 1), 0)
+    return index
+
+
 def _query_heads_a_kv_head(q, k, v) -> int:
     heads, kv_heads = q.shape[1], k.shape[1]
     if v.shape[1] != kv_heads or heads % kv_heads:
@@ -409,7 +552,7 @@ def _query_heads_a_kv_head(q, k, v) -> int:
     return heads // kv_heads
 
 
-def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret):
+def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret, window=None):
     batch, heads, seq_q, d = q.shape
     seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
@@ -418,10 +561,15 @@ def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret):
     offset = seq_k - seq_q
     kernel = functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
                                causal=causal, group=group, offset=offset)
-    kv_index = _kv_index(causal, offset, bq, bkm, seq_k // bkm, rep)
+    steps, kv_index = seq_k // bkm, _kv_index(causal, offset, bq, bkm,
+                                              seq_k // bkm, rep)
+    if window is not None:
+        kernel = functools.partial(kernel, band=(window, seq_k // bkm))
+        steps, kv_index = (_band_steps(bq, bkm, window),
+                           _band_index(bq, bkm, seq_k // bkm, rep))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, seq_q // bq, seq_k // bkm),
+        grid=(bh, seq_q // bq, steps),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bkm, d), kv_index),
@@ -444,14 +592,14 @@ def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret):
         ],
         compiler_params=_compiler_params(max(d, dv), seq_k, bkm),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_win_fwd",
     )(q.reshape(bh, seq_q, d), k.reshape(bh // rep, seq_k, d),
       v.reshape(bh // rep, seq_k, dv))
     return out.reshape(batch, heads, seq_q, dv), lse
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
-                    interpret):
+                    interpret, window=None):
     batch, heads, seq_q, d = q.shape
     seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
@@ -469,15 +617,24 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
 
     # q, k and dq, dk move in blocks d wide; v, dO and dv in blocks dv wide
     bq, bkm, group = blocks.dq
-    kv_index = _kv_index(causal, offset, bq, bkm, seq_k // bkm, rep)
+    steps, kv_index = seq_k // bkm, _kv_index(causal, offset, bq, bkm,
+                                              seq_k // bkm, rep)
+    if window is not None:
+        steps, kv_index = (_band_steps(bq, bkm, window),
+                           _band_index(bq, bkm, seq_k // bkm, rep))
+
+    def band(blocks_of_major):
+        """The kernels' `band`, where there is a window."""
+        return {} if window is None else {"band": (window, blocks_of_major)}
 
     def q_spec(width):
         return pl.BlockSpec((1, bq, width), lambda b, i, j: (b, i, 0))
     row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
-                          causal=causal, group=group, offset=offset),
-        grid=(bh, seq_q // bq, seq_k // bkm),
+                          causal=causal, group=group, offset=offset,
+                          **band(seq_k // bkm)),
+        grid=(bh, seq_q // bq, steps),
         in_specs=[q_spec(d), pl.BlockSpec((1, bkm, d), kv_index),
                   pl.BlockSpec((1, bkm, dv), kv_index), q_spec(dv),
                   row_spec, row_spec],
@@ -486,7 +643,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if window is None else "flash_win_bwd_dq",
     )(qr, kr, vr, gr, lse, delta)
 
     # dK, dV: a grid row a key/value head. Its rep query heads' q blocks
@@ -495,13 +652,18 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
     # dK and dV leave the kernel at the key/value heads' count.
     bk, bqm, group = blocks.dkv
     num_q = seq_q // bqm
+    # the steps of one query head: every q block, or the band's
+    steps = num_q if window is None else _band_steps(bk, bqm, window)
 
     def q_head(b, j):
-        return b if rep == 1 else b * rep + j // num_q
+        return b if rep == 1 else b * rep + j // steps
 
     def q_of(j):
-        return j if rep == 1 else j % num_q
-    if causal:
+        return j if rep == 1 else j % steps
+    if window is not None:
+        def q_block(i, j):
+            return jnp.minimum(i * (bk // bqm) + q_of(j), num_q - 1)
+    elif causal:
         # q major blocks above the diagonal are skipped, and not fetched:
         # the index repeats the first one this k block needs
         def q_block(i, j):
@@ -518,11 +680,12 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
         return pl.BlockSpec((1, bk, width), lambda b, i, j: (b, i, 0))
     row_spec = pl.BlockSpec(
         (1, 1, bqm), lambda b, i, j: (q_head(b, j), 0, q_block(i, j)))
-    walk = {} if rep == 1 else {"q_blocks": num_q}
+    walk = {} if rep == 1 else {"q_blocks": steps}
     dk, dvalue = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
-                          causal=causal, group=group, offset=offset, **walk),
-        grid=(bh // rep, seq_k // bk, rep * num_q),
+                          causal=causal, group=group, offset=offset, **walk,
+                          **band(num_q)),
+        grid=(bh // rep, seq_k // bk, rep * steps),
         in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
                   row_spec, row_spec],
         out_specs=[kv_spec(d), kv_spec(dv)],
@@ -534,7 +697,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
                         pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if window is None else "flash_win_bwd_dkv",
     )(qr, kr, vr, gr, lse, delta)
     return (dq.reshape(batch, heads, seq_q, d),
             dk.reshape(batch, heads // rep, seq_k, d),
@@ -542,7 +705,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
 
 
 @functools.lru_cache(maxsize=None)
-def _make_flash_fn(causal, sm_scale, blocks, interpret):
+def _make_flash_fn(causal, sm_scale, blocks, interpret, window=None):
     """Pallas forward + Pallas backward under jax.custom_vjp.
 
     The backward is the flash-attention recompute form (dQ kernel + dK/dV
@@ -552,12 +715,13 @@ def _make_flash_fn(causal, sm_scale, blocks, interpret):
 
     @jax.custom_vjp
     def f(q, k, v):
-        out, _ = _flash_forward(q, k, v, causal, sm_scale, blocks, interpret)
+        out, _ = _flash_forward(q, k, v, causal, sm_scale, blocks, interpret,
+                                window)
         return out
 
     def fwd(q, k, v):
         out, lse = _flash_forward(q, k, v, causal, sm_scale, blocks,
-                                  interpret)
+                                  interpret, window)
         # the NAMED values are both the primal output and the residuals: a
         # remat policy that saves the two names then has all the forward
         # kernel made (q, k, v come from the rematted projections), and the
@@ -571,7 +735,7 @@ def _make_flash_fn(causal, sm_scale, blocks, interpret):
     def bwd(res, g):
         q, k, v, out, lse = res
         return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                               blocks, interpret)
+                               blocks, interpret, window)
 
     f.defvjp(fwd, bwd)
     return f
@@ -592,8 +756,17 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
     """Fused attention on the MXU; O(seq) memory via online softmax.
+
+    window (causal self-attention only): query i sees keys j with 0 <= i -
+    j < window. The kernels then run as flash_win_fwd / flash_win_bwd_dq /
+    flash_win_bwd_dkv, with the blocks of the shape (`_block_sizes`: the
+    window does not move them) and a grid whose reduced dimension covers
+    the blocks the band touches alone (`_band_steps`). A window
+    that reaches the sequence's start from its end is the causal mask, and
+    runs as that.
 
     The kernels' blocks follow from the shape (`_block_sizes`). A sequence
     length is below 128 (one block) or a multiple of 128: pad upstream; a
@@ -616,9 +789,19 @@ def flash_attention(q, k, v, *, causal: bool = True,
         widths = ((0, 0),) * 3 + ((0, pad),)
         q, k = jnp.pad(q, widths), jnp.pad(k, widths)
     seq_q, seq_k = q.shape[2], k.shape[2]
+    if window is not None:
+        if not causal or seq_q != seq_k or window < 1:
+            raise ValueError(
+                f"window={window} needs causal self-attention (seq_q == "
+                f"seq_k, got {seq_q} and {seq_k}) and at least the query's "
+                "own position")
+        if window >= seq_k:
+            window = None
     if interpret is None:
         interpret = _default_interpret()
     if block_q is None and block_k is None:
+        # the window does not move them: square blocks of 2048 read as
+        # fast as any at windows of 128, 512 and 2048 (PERF.md, PR 37)
         blocks = _block_sizes(seq_q, seq_k, q.shape[-1], v.shape[-1])
     else:
         bq = min(block_q or seq_q, seq_q)
@@ -628,9 +811,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
                 f"flash_attention needs seq_q={seq_q} and seq_k={seq_k} to "
                 f"be multiples of block_q={bq} and block_k={bk}; pad the "
                 "sequence or call mha_reference")
+        if window is not None and bq != bk:
+            raise ValueError(f"under a window the forward's and dK/dV's "
+                             f"major block each divide the outer one: "
+                             f"block_q={bq} == block_k={bk}")
         blocks = _FlashBlocks(fwd=(bq, bk, bq), dq=(bq, bk, bq),
                               dkv=(bk, bq, bk))
-    fn = _make_flash_fn(causal, float(sm_scale), blocks, interpret)
+    fn = _make_flash_fn(causal, float(sm_scale), blocks, interpret, window)
     return fn(q, k, v)
 
 

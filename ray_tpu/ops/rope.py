@@ -31,10 +31,13 @@ from __future__ import annotations
 
 import collections
 import functools
-from typing import Optional
+import math
+from dataclasses import dataclass
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -83,15 +86,101 @@ def _rope_blocks(seq: int, heads: int, head_dim: int,
     return _RopeBlocks(rows, cols)
 
 
-def rope_table(seq: int, head_dim: int, theta: float):
+@dataclass(frozen=True)
+class RopeSpec:
+    """How one kind of attention layer rotates q and k.
+
+    rotated: the share of a head's columns that is rotated, counted from
+      the head's first column, as halves of that part (a head of 128 at
+      0.5: columns 0..31 with 32..63; 64..127 pass as they are).
+    yarn: None, or (factor, original positions, beta_fast, beta_slow):
+      every frequency a blend of itself and itself / factor, by how many
+      turns it makes over the original positions (`yarn_frequencies`).
+    attention_factor: times cos and sin both, on the rotated columns."""
+    theta: float = 10000.0
+    rotated: float = 1.0
+    yarn: Optional[Tuple[float, int, float, float]] = None
+    attention_factor: float = 1.0
+
+    @property
+    def plain(self) -> bool:
+        return (self.rotated == 1.0 and self.yarn is None
+                and self.attention_factor == 1.0)
+
+    def columns(self, head_dim: int) -> int:
+        """Rotated columns of a head, an even count."""
+        return int(head_dim * self.rotated) // 2 * 2
+
+
+def yarn_frequencies(theta: float, dim: int, factor: float, original: int,
+                     beta_fast: float, beta_slow: float):
+    """The dim / 2 frequencies of YaRN, float32 numpy, as the Hugging Face
+    `_compute_yarn_parameters` blends them (its `truncate` default): pair i
+    turns `original` x theta^(-2i/dim) / 2 pi times over the original
+    positions; pairs that turn more than beta_fast times keep their
+    frequency, pairs that turn fewer than beta_slow times have it divided
+    by `factor`, and between the two (the pair numbers floored and ceiled)
+    a linear ramp blends them."""
+    def pair_of(turns):
+        return (dim * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    plain = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    return (plain / factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def as_spec(rope) -> RopeSpec:
+    """A RopeSpec as it is; a bare theta as the plain rotation of every
+    column at it."""
+    return rope if isinstance(rope, RopeSpec) else RopeSpec(theta=rope)
+
+
+def rope_frequencies(rope, head_dim: int):
+    """The frequencies of a head's rotated pairs, float32 [columns / 2]."""
+    spec = as_spec(rope)
+    rotated = spec.columns(head_dim)
+    if spec.yarn is not None:
+        return jnp.asarray(yarn_frequencies(spec.theta, rotated, *spec.yarn))
+    return spec.theta ** (-jnp.arange(0, rotated // 2, dtype=jnp.float32)
+                          / (rotated // 2))
+
+
+def halves_apart(head_dim: int, rotated: int):
+    """The order of a head's columns in which a part rotated as halves
+    (columns 0..rotated/2-1 with rotated/2..rotated-1, the rest passing)
+    lies as the kernels rotate: partners head_dim / 2 apart. q.k does not
+    change with its columns' order, so it is done to wq's and wk's columns
+    (models/gpt.py), and `rope_table` lays cos 1 and sin 0 on the
+    columns that pass."""
+    r, passing = rotated // 2, (head_dim - rotated) // 2
+    return (list(range(r)) + list(range(rotated, rotated + passing))
+            + list(range(r, rotated)) + list(range(rotated + passing,
+                                                   head_dim)))
+
+
+def rope_table(seq: int, head_dim: int, rope):
     """(cos, sin) of positions 0..seq-1 as the rotation multiplies them,
     float32 [seq, W]: a head's columns are [cos, cos] and [-sin, sin], and
     where heads share a 128-lane tile the head repeats to fill it (W =
-    128), so that the kernels load whole tiles."""
-    half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    128), so that the kernels load whole tiles. rope: a theta, or a
+    RopeSpec: its frequencies and attention factor on the first columns of
+    each half, cos 1 and sin 0 on those that pass (the head's columns in
+    `halves_apart`'s order)."""
+    spec = as_spec(rope)
+    freqs = rope_frequencies(spec, head_dim)
     angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * freqs
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if not spec.plain:
+        passing = head_dim // 2 - freqs.shape[0]
+        cos = jnp.concatenate([cos * spec.attention_factor,
+                               jnp.ones((seq, passing), jnp.float32)], 1)
+        sin = jnp.concatenate([sin * spec.attention_factor,
+                               jnp.zeros((seq, passing), jnp.float32)], 1)
     repeat = (_lane_tile(head_dim) or head_dim) // head_dim
     return (jnp.tile(jnp.concatenate([cos, cos], axis=1), (1, repeat)),
             jnp.tile(jnp.concatenate([-sin, sin], axis=1), (1, repeat)))

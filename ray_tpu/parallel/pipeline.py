@@ -78,6 +78,11 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
         raise ValueError(
             f"layer_kinds={cfg.layer_kinds}: the pipeline preset scans one "
             "stack of identical layers and has no two kinds of layer")
+    if "window" in kinds or cfg.attention_gate:
+        raise ValueError(
+            "the pipeline preset has no sliding-window layers (its scan "
+            "carries no period of kinds, head counts or rope tables) and "
+            "no rule for a gate a head (attn/wg)")
     if "conv" in kinds and tp > 1:
         raise ValueError("ShardingStrategy.pp_tp() has no rule for conv/w_in, "
                          "conv/filter and conv/w_out")

@@ -101,8 +101,11 @@ class ShardingStrategy:
         column-parallel qkv/up projections, row-parallel out/down."""
         t = "tensor"
         rules = ShardingRules(rules=[
+            # (a window layer's `window_attn/..` finds the same rules)
             (r"attn/(wq|wk|wv)", P(None, t)),
             (r"attn/wo", P(t, None)),
+            # the gate a head: its columns are heads
+            (r"attn/wg", P(None, t)),
             # latent attention: whole heads of the up-projection's columns;
             # the down-projection, its norm and the shared rotated key
             # part are not divided
@@ -139,6 +142,7 @@ class ShardingStrategy:
         rules = ShardingRules(rules=[
             (r"attn/(wq|wk|wv)", P(f, t)),
             (r"attn/wo", P(t, f)),
+            (r"attn/wg", P(f, t)),
             (r"attn/w_kvb", P(f, t)),
             (r"attn/(w_kva|kv_norm)", P()),
             (r"attn/(q|k)_head_norm", P()),

@@ -119,12 +119,18 @@ def stack_dump() -> Dict[str, str]:
 # exists only because the layer is sparse: router, top-k, ordering, the
 # gathers either side, both router losses. `conv` is a short-convolution
 # layer's projections; `conv_mix`, nested in it, its gates and filter.
-REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_out",
-           "conv", "conv_mix", "mlp", "moe", "moe_route", "moe_shared",
-           "norm", "head", "loss_and_grad", "grad_accum", "optimizer")
-KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
-           "moe_tgmm", "moe_run_sum", "rope_split", "rope_merge",
-           "short_conv_fwd", "short_conv_bwd")
+# `attn_window`, nested in `attn_core`, is the sliding-window layers' flash
+# call (so `attn_core` keeps the full layers' alone); `attn_gate`, nested in
+# `attn_out`, the gate a head on attention's output (matmul, sigmoid,
+# product).
+REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_window",
+           "attn_out", "attn_gate", "conv", "conv_mix", "mlp", "moe",
+           "moe_route", "moe_shared", "norm", "head", "loss_and_grad",
+           "grad_accum", "optimizer")
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
+           "flash_win_bwd_dq", "flash_win_bwd_dkv", "moe_gmm", "moe_tgmm",
+           "moe_run_sum", "rope_split", "rope_merge", "short_conv_fwd",
+           "short_conv_bwd")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:", "compile:")

@@ -94,6 +94,31 @@ def test_flash_kernels_compile_at_8192_positions_of_64(v5e, heads, kv_heads):
     assert dk.shape == dv.shape == (2, kv_heads, 8192, 64)
 
 
+def test_window_kernels_compile_at_8192_positions_of_128(v5e):
+    """laguna_train_1chip's sliding layers' call, [2, 64 on 8, 8192, 128]
+    under a window of 512, forward and both backward kernels: two blocks of
+    2048 a grid row where the causal kernels walk up to four; dK and dV
+    leave at the key/value heads' count."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.attention import flash_attention
+
+    def shape(h):
+        return jax.ShapeDtypeStruct((2, h, 8192, 128), jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+    grads = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=512,
+        interpret=False).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+    compiled = grads.lower(shape(64), shape(8), shape(8)).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv"):
+        assert len(_kernel_ops(text, kernel)) == 1, kernel
+    dq, dk, dv = compiled.out_info
+    assert dq.shape == (2, 64, 8192, 128)
+    assert dk.shape == dv.shape == (2, 8, 8192, 128)
+
+
 @pytest.mark.parametrize("backward", [False, True],
                          ids=["forward", "backward"])
 def test_short_conv_kernels_compile_for_v5e(v5e, backward):
@@ -475,6 +500,18 @@ CELL_STEPS = [
                       "moe_tgmm": 24, "short_conv_fwd": 8,
                       "short_conv_bwd": 4},
      (0.45, 0.75)),
+    # laguna_train_1chip: full attention (48 query heads on 8) with the
+    # dense MLP, three sliding-window layers (64 on 8, window 512) and a
+    # full one with 32 of 256 experts held. The window layers' kernels
+    # carry names of their own and run, like the full layers', once a layer
+    # (kept through the remat); q, k, v through rope_split at three head
+    # counts. 14.22 GB when this was written: 8.30 of state, 5.92 of
+    # temporaries.
+    ("laguna-xs.2", {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2,
+                     "flash_win_fwd": 3, "flash_win_bwd_dq": 3,
+                     "flash_win_bwd_dkv": 3, "rope_split": 30,
+                     "rope_merge": 15, "moe_gmm": 72, "moe_tgmm": 24},
+     (0.78, 0.92)),
 ]
 
 
@@ -542,11 +579,15 @@ def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
     if kv_heads != config["num_attention_heads"]:
         # dK and dV leave their kernel at the key/value heads' count, and
         # the one tensor at the query heads' that enters it is q (with dO)
-        dkv, = _kernel_ops(text, "flash_bwd_dkv")
+        heads = config["num_attention_heads"]
+        dim = config.get("head_dim", config["hidden_size"] // heads)
         b, s = mix["global_batch"], mix["seq"]
-        assert dkv.count(f"bf16[{b * kv_heads},{s},64]") >= 4, dkv
-        by_head = f"bf16[{b},{config['num_attention_heads']},{s},64]"
+        for kernel in ("flash_bwd_dkv", "flash_win_bwd_dkv"):
+            for dkv in _kernel_ops(text, kernel):
+                assert dkv.count(f"bf16[{b * kv_heads},{s},{dim}]") >= 4, dkv
+        by_head = f"bf16[{b},{heads},{s},{dim}]"
         made = [line for line in text.splitlines()
                 if f" = {by_head}" in line and "rope_split" in line]
-        # q alone is split at 32 heads: forward, and recomputed
-        assert len(made) == 2, made
+        # q alone is split at the query heads' count: forward, and
+        # recomputed, in every full-attention layer
+        assert len(made) == 2 * kernel_calls["flash_fwd"], made

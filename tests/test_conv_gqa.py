@@ -592,7 +592,7 @@ def test_sharded_step_equals_one_device(jax_cpu, tiny):
       "v_head_dim": 16}, "n_kv_heads=2 != n_heads=8.*a latent block"),
     ({"n_kv_heads": 3}, "n_kv_heads=3 does not divide n_heads=8"),
     ({"layer_kinds": ("conv", "attention")}, "layer_kinds.*n_layers=4"),
-    ({"layer_kinds": ("conv", "window", "conv", "conv")},
+    ({"layer_kinds": ("conv", "mamba", "conv", "conv")},
      "'attention' | 'conv'"),
 ], ids=["ring", "latent", "kv_heads", "kinds_length", "kinds_names"])
 def test_the_configuration_refuses_by_name(tiny, change, says):
