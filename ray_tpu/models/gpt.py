@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.ops import moe
 from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT, flash_attention,
                                    mha_reference, qk_padding, ring_attention)
-from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart,
+from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
                               rope_frequencies, rope_split, rope_table)
 from ray_tpu.ops.short_conv import short_conv
 
@@ -472,13 +472,38 @@ def _deinterleaved(w, heads: int, keep: int, pairs: int):
 def _rope_tail(t, table, n: int):
     """t [B, S, heads, width]: the last n columns of every head rotated as
     halves by `table` (rope_table of S and n), the rest as they are. The
-    jnp formulation: a head of 192 columns fills no whole lane tiles, so
-    ops/rope.py's kernels do not take it."""
+    jnp formulation of what ops/rope.py's latent kernels do in registers
+    (`_latent_heads` says when it runs)."""
     cos, sin = (c[:, None, :n] for c in table)
     x = t[..., -n:].astype(jnp.float32)
     other = jnp.concatenate([x[..., n // 2:], x[..., :n // 2]], axis=-1)
     return jnp.concatenate(
         [t[..., :-n], (x * cos + other * sin).astype(t.dtype)], axis=-1)
+
+
+def _latent_heads(q, kv, k_rope, table, nope: int, rope: int, dv: int,
+                  fill: int):
+    """The jnp formulation of ops/rope.py:latent_split, its fallback (head
+    parts that do not fill whole or half lane tiles: the test sizes) and
+    its oracle (`attention="reference"`): q [B, S, H * (nope + rope)], kv
+    [B, S, H * (nope + dv)] and the shared k_rope [B, S, rope] -> q and k
+    [B, H, S, nope + rope + fill] and v [B, H, S, dv], the rope columns
+    rotated (`_rope_tail`), the one key part repeated to every head, `fill`
+    zero columns after them. On the chip it is slices and concatenates at
+    64-column granularity, float32 in the backward, and four transposes of
+    [B, S, H, 256 | 128] tensors (PERF.md, PR 39)."""
+    b, s, _ = q.shape
+    q = q.reshape(b, s, -1, nope + rope)
+    zeros = [jnp.zeros(q.shape[:3] + (fill,), q.dtype)] if fill else []
+    q = jnp.concatenate([_rope_tail(q, table, rope)] + zeros,
+                        axis=-1).transpose(0, 2, 1, 3)
+    with jax.named_scope("attn_latent"):
+        kv = kv.reshape(b, s, -1, nope + dv)
+        k_rope = _rope_tail(k_rope[:, :, None, :], table, rope)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(
+                k_rope, kv.shape[:3] + (rope,))] + zeros, axis=-1)
+        return q, k.transpose(0, 2, 1, 3), kv[..., nope:].transpose(0, 2, 1, 3)
 
 
 def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
@@ -488,7 +513,13 @@ def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
     one key part all heads share, then the flash kernels at q.k width
     qk_nope_dim + qk_rope_dim and v width v_head_dim. Scope `attn_latent`
     (inside `attn_proj`) holds what exists only because attention is
-    latent: both kv projections, their norm, the assembly of k and v."""
+    latent: both kv projections, their norm, the assembly of k and v.
+    Between the projections and the flash kernels: ops/rope.py's latent
+    kernels, one pass a tensor and direction (`latent_q_split` under
+    `attn_proj`, `latent_kv_split` under `attn_latent`, their merges
+    backward), where the shape a shard holds tiles (`latent_split`: nope
+    and v of whole lane tiles, rope of a half or a whole one); else, and
+    for `attention="reference"`, the jnp assembly (`_latent_heads`)."""
     a, dt = layer["attn"], cfg.dtype
     nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     latent = cfg.kv_latent_dim
@@ -514,20 +545,17 @@ def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
     sm_scale = 1.0 / math.sqrt(nope + rope)
 
     def split_and_attend(q, kv, k_rope, *table):
-        b, s, _ = q.shape
+        kernels = None if cfg.attention == "reference" else latent_split(
+            q.shape[1], q.shape[2] // (nope + rope), nope, rope, dv, q.dtype)
         with jax.named_scope("attn_proj"):
-            q = q.reshape(b, s, -1, nope + rope)
-            zeros = [jnp.zeros(q.shape[:3] + (fill,), q.dtype)] if fill else []
-            q = jnp.concatenate([_rope_tail(q, table, rope)] + zeros,
-                                axis=-1).transpose(0, 2, 1, 3)
-            with jax.named_scope("attn_latent"):
-                kv = kv.reshape(b, s, -1, nope + dv)
-                k_rope = _rope_tail(k_rope[:, :, None, :], table, rope)
-                k = jnp.concatenate(
-                    [kv[..., :nope], jnp.broadcast_to(
-                        k_rope, kv.shape[:3] + (rope,))] + zeros, axis=-1)
-                k, v = k.transpose(0, 2, 1, 3), kv[..., nope:].transpose(
-                    0, 2, 1, 3)
+            if kernels is None:
+                q, k, v = _latent_heads(q, kv, k_rope, table, nope, rope, dv,
+                                        fill)
+            else:
+                q_split, kv_split = kernels
+                q = q_split(q, *table)
+                with jax.named_scope("attn_latent"):
+                    k, v = kv_split(kv, k_rope, *table)
         with jax.named_scope("attn_core"):
             if cfg.attention == "reference":
                 return mha_reference(q, k, v, causal=True, sm_scale=sm_scale)

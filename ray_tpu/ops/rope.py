@@ -25,6 +25,15 @@ Blocks follow from the shape (`_rope_blocks`); a shape that does not tile
 (a head width that neither divides nor is a multiple of 128, a head count
 that does not fill whole lane tiles, a ragged sequence) takes the jnp
 formulation.
+
+A latent block's head of q.k is two parts, 128 columns that carry no
+position and 64 that are rotated: 192 fills no whole lane tiles and
+`rope_split` refuses it. `latent_split` takes the head by its parts, with
+four kernels of the same kind (`latent_q_split`, `latent_kv_split` and their
+transposes `latent_q_merge`, `latent_kv_merge`: the second half of this
+file) wherever the parts fill whole or half lane tiles; anything else (heads
+of 32 + 16, `attention="reference"`) keeps models/gpt.py's jnp assembly
+(`_rope_tail`, `_latent_heads`).
 """
 
 from __future__ import annotations
@@ -310,3 +319,271 @@ def rope_split(x, head_dim: int, table=(), *,
     if interpret is None:
         interpret = attention._default_interpret()
     return _make_split_fn(head_dim, blocks, interpret)(x, *table)
+
+
+# ---------------------------------------------------------------------------
+# A latent block's q, k and v (models/gpt.py:_latent_attention)
+# ---------------------------------------------------------------------------
+# A head of q.k there is `nope` columns that carry no position and `rope`
+# that are rotated as halves (128 + 64): no whole lane tiles a head, but a
+# group of 128 / rope heads is (two heads: three tiles), and the kernels
+# below move whole groups. They write what the flash kernels read, q and k
+# [B, H, S, nope + rope + fill] (fill: the zero columns of
+# attention.qk_padding) and v [B, H, S, dv]:
+#
+#   latent_q_split   q [B, S, H * (nope + rope)] -> q
+#   latent_kv_split  kv [B, S, H * (nope + dv)], k_rope [B, S, rope] -> k, v
+#                    (the one rotated part repeated to every head)
+#   latent_q_merge, latent_kv_merge: their transposes, d k_rope the sum
+#                    over the heads of dk's rotated columns, added up in
+#                    float32 over the heads of a block and over the grid's
+#                    head axis, then rotated back and rounded once.
+
+# (rows, cols) of the q plane and of the kv plane one grid step moves.
+_LatentBlocks = collections.namedtuple("_LatentBlocks", "q kv")
+
+
+def _latent_blocks(seq: int, heads: int, nope: int, rope: int, dv: int,
+                   itemsize: int) -> Optional[_LatentBlocks]:
+    """Blocks of the four latent kernels, by `_rope_blocks`' rule over
+    planes whose unit is a group of heads; None for a shape they do not
+    tile: nope and dv whole lane tiles, the rotated parts of a group of
+    heads a whole lane tile, whole groups, a sequence of whole registers."""
+    tile = _lane_tile(rope)
+    sublanes = 32 // itemsize
+    if (tile is None or not nope or nope % LANES or dv % LANES
+            or (heads * rope) % tile or seq % sublanes
+            or itemsize not in (2, 4)):
+        return None
+
+    def plane(width, unit):
+        cols = _largest_divisor(width, unit, _STEP_ELEMENTS // 256)
+        return _RopeBlocks(
+            _largest_divisor(seq, sublanes, _STEP_ELEMENTS // cols), cols)
+    group = tile // rope
+    return _LatentBlocks(plane(heads * (nope + rope), group * (nope + rope)),
+                         plane(heads * (nope + dv), nope + dv))
+
+
+def _side_by_side(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
+def _rotated_tail(y, i: int, rope: int, fill: int):
+    """Head i's rotated columns out of the group's tile y, and the zero
+    columns after them."""
+    tail = y[:, i * rope:(i + 1) * rope]
+    if not fill:
+        return tail
+    return jnp.concatenate(
+        [tail, jnp.zeros((y.shape[0], fill), y.dtype)], axis=1)
+
+
+def _latent_q_split_kernel(x_ref, cos_ref, sin_ref, o_ref, *, nope, rope):
+    """Grid (batch, seq block, head block): x [1, rows, heads * (nope +
+    rope)] -> out [1, heads, rows, nope + rope + fill]."""
+    head = nope + rope
+    fill = o_ref.shape[3] - head
+    group = _lane_tile(rope) // rope
+    for g in range(o_ref.shape[1] // group):
+        x = x_ref[0, :, g * group * head:(g + 1) * group * head]
+        y = _side_by_side([x[:, i * head + nope:(i + 1) * head]
+                           for i in range(group)])
+        y = _rotate(y.astype(jnp.float32), cos_ref[...], sin_ref[...],
+                    rope).astype(o_ref.dtype)
+        for i in range(group):
+            o_ref[0, g * group + i, :, :nope] = x[:, i * head:i * head + nope]
+            o_ref[0, g * group + i, :, nope:] = _rotated_tail(y, i, rope, fill)
+
+
+def _latent_q_merge_kernel(g_ref, cos_ref, sin_ref, o_ref, *, nope, rope):
+    """_latent_q_split_kernel's transpose: g [1, heads, rows, nope + rope +
+    fill] -> out [1, rows, heads * (nope + rope)], rotated back."""
+    head = nope + rope
+    group = _lane_tile(rope) // rope
+    for g in range(g_ref.shape[1] // group):
+        grads = [g_ref[0, g * group + i] for i in range(group)]
+        y = _side_by_side([t[:, nope:head] for t in grads])
+        y = _rotate(y.astype(jnp.float32), cos_ref[...], -sin_ref[...],
+                    rope).astype(o_ref.dtype)
+        o_ref[0, :, g * group * head:(g + 1) * group * head] = _side_by_side(
+            [part for i, t in enumerate(grads)
+             for part in (t[:, :nope], y[:, i * rope:(i + 1) * rope])])
+
+
+def _latent_kv_split_kernel(kv_ref, kr_ref, cos_ref, sin_ref, k_ref, v_ref,
+                            *, nope, rope):
+    """Grid (batch, seq block, head block): kv [1, rows, heads * (nope +
+    dv)], k_rope [1, rows, rope] -> k [1, heads, rows, nope + rope + fill],
+    v [1, heads, rows, dv]."""
+    dv = v_ref.shape[3]
+    fill = k_ref.shape[3] - nope - rope
+    group = _lane_tile(rope) // rope
+    y = _side_by_side([kr_ref[0]] * group)
+    y = _rotate(y.astype(jnp.float32), cos_ref[...], sin_ref[...],
+                rope).astype(k_ref.dtype)
+    tail = _rotated_tail(y, 0, rope, fill)
+    for h in range(k_ref.shape[1]):
+        at = h * (nope + dv)
+        k_ref[0, h, :, :nope] = kv_ref[0, :, at:at + nope]
+        k_ref[0, h, :, nope:] = tail
+        v_ref[0, h] = kv_ref[0, :, at + nope:at + nope + dv]
+
+
+def _latent_kv_merge_kernel(dk_ref, dv_ref, cos_ref, sin_ref, dkv_ref,
+                            dkr_ref, sum_ref, *, nope, rope):
+    """_latent_kv_split_kernel's transpose. The head block is the grid's
+    last, sequential axis: sum_ref [rows, rope] float32 carries the heads'
+    rotated columns of dk from block to block, and the last one rotates
+    the sum back into d k_rope [1, rows, rope]."""
+    dv = dv_ref.shape[3]
+    group = _lane_tile(rope) // rope
+    j = pl.program_id(2)
+    total = jnp.where(j == 0, 0.0, sum_ref[...])
+    for h in range(dk_ref.shape[1]):
+        dk = dk_ref[0, h]
+        at = h * (nope + dv)
+        dkv_ref[0, :, at:at + nope] = dk[:, :nope]
+        dkv_ref[0, :, at + nope:at + nope + dv] = dv_ref[0, h]
+        total = total + dk[:, nope:nope + rope].astype(jnp.float32)
+    sum_ref[...] = total
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        y = _rotate(_side_by_side([total] * group), cos_ref[...],
+                    -sin_ref[...], rope)
+        dkr_ref[0] = y[:, :rope].astype(dkr_ref.dtype)
+
+
+def _latent_specs(rows, heads, table):
+    """BlockSpecs of a grid (batch, seq block, head block), each by its
+    last dimension: a plane [B, S, H * width] in `heads` heads' columns, a
+    by-head tensor [B, H, S, width] in the same rows of those heads, a
+    plane that every head block reads or writes whole, the table."""
+    return (lambda width: pl.BlockSpec((1, rows, heads * width),
+                                       lambda b, i, j: (b, i, j)),
+            lambda width: pl.BlockSpec((1, heads, rows, width),
+                                       lambda b, i, j: (b, j, i, 0)),
+            lambda width: pl.BlockSpec((1, rows, width),
+                                       lambda b, i, j: (b, i, 0)),
+            [pl.BlockSpec((rows, t.shape[1]), lambda b, i, j: (i, 0))
+             for t in table])
+
+
+def _latent_q_call(split: bool, x, table, nope, rope, blocks, interpret):
+    """latent_q_split (x [B, S, H * (nope + rope)]) or latent_q_merge
+    (x [B, H, S, nope + rope + fill]) over the whole tensor."""
+    head = nope + rope
+    padded = head + attention.qk_padding(head)
+    if split:
+        batch, seq, n_heads = x.shape[0], x.shape[1], x.shape[2] // head
+    else:
+        batch, n_heads, seq = x.shape[:3]
+    rows, cols = blocks
+    plane, by_head, _, rows_of_table = _latent_specs(rows, cols // head, table)
+    out_shape = ((batch, n_heads, seq, padded) if split
+                 else (batch, seq, n_heads * head))
+    return pl.pallas_call(
+        functools.partial(
+            _latent_q_split_kernel if split else _latent_q_merge_kernel,
+            nope=nope, rope=rope),
+        grid=(batch, seq // rows, n_heads * head // cols),
+        in_specs=[plane(head) if split else by_head(padded)] + rows_of_table,
+        out_specs=by_head(padded) if split else plane(head),
+        out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
+        compiler_params=_PARALLEL,
+        interpret=interpret,
+        name="latent_q_split" if split else "latent_q_merge",
+    )(x, *table)
+
+
+def _latent_kv_call(split: bool, operands, table, nope, rope, dv, blocks,
+                    interpret):
+    """latent_kv_split (operands kv [B, S, H * (nope + dv)], k_rope [B, S,
+    rope] -> k, v) or latent_kv_merge (operands dk [B, H, S, nope + rope +
+    fill], dv [B, H, S, dv] -> d kv, d k_rope) over the whole tensors. The
+    merge walks the head blocks in order (its sum over the heads)."""
+    padded = nope + rope + attention.qk_padding(nope + rope)
+    if split:
+        batch, seq, n_heads = (*operands[0].shape[:2],
+                               operands[0].shape[2] // (nope + dv))
+    else:
+        batch, n_heads, seq = operands[0].shape[:3]
+    rows, cols = blocks
+    plane, by_head, shared, rows_of_table = _latent_specs(
+        rows, cols // (nope + dv), table)
+    flat = [plane(nope + dv), shared(rope)]
+    heads = [by_head(padded), by_head(dv)]
+    dtype = operands[0].dtype
+    flat_shapes = [jax.ShapeDtypeStruct((batch, seq, w), dtype)
+                   for w in (n_heads * (nope + dv), rope)]
+    head_shapes = [jax.ShapeDtypeStruct((batch, n_heads, seq, w), dtype)
+                   for w in (padded, dv)]
+    return pl.pallas_call(
+        functools.partial(
+            _latent_kv_split_kernel if split else _latent_kv_merge_kernel,
+            nope=nope, rope=rope),
+        grid=(batch, seq // rows, n_heads * (nope + dv) // cols),
+        in_specs=(flat if split else heads) + rows_of_table,
+        out_specs=heads if split else flat,
+        out_shape=head_shapes if split else flat_shapes,
+        scratch_shapes=[] if split else [pltpu.VMEM((rows, rope),
+                                                    jnp.float32)],
+        compiler_params=_PARALLEL if split else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="latent_kv_split" if split else "latent_kv_merge",
+    )(*operands, *table)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_latent_fns(nope, rope, dv, blocks, interpret):
+    """(latent_q_split, latent_kv_split), each with its merge as its
+    backward; no residual but the table."""
+    def no_gradient(table):
+        return tuple(jnp.zeros_like(t) for t in table)
+
+    @jax.custom_vjp
+    def q_split(q, *table):
+        return _latent_q_call(True, q, table, nope, rope, blocks.q, interpret)
+
+    def q_bwd(table, g):
+        return (_latent_q_call(False, g, table, nope, rope, blocks.q,
+                               interpret), *no_gradient(table))
+
+    @jax.custom_vjp
+    def kv_split(kv, k_rope, *table):
+        return tuple(_latent_kv_call(True, (kv, k_rope), table, nope, rope,
+                                     dv, blocks.kv, interpret))
+
+    def kv_bwd(table, g):
+        return (*_latent_kv_call(False, g, table, nope, rope, dv, blocks.kv,
+                                 interpret), *no_gradient(table))
+
+    q_split.defvjp(lambda q, *table: (q_split(q, *table), table), q_bwd)
+    kv_split.defvjp(
+        lambda kv, k_rope, *table: (kv_split(kv, k_rope, *table), table),
+        kv_bwd)
+    return q_split, kv_split
+
+
+def latent_split(seq: int, heads: int, nope: int, rope: int, dv: int, dtype,
+                 *, interpret: Optional[bool] = None):
+    """The latent block's way into the flash kernels at this shape:
+    (q_split, kv_split), or None where the kernels do not tile it
+    (`_latent_blocks`) and the caller keeps the jnp assembly.
+
+    q_split(q [B, S, heads * (nope + rope)], cos, sin) -> q [B, heads, S,
+      nope + rope + fill]: a head's nope columns, its rope columns rotated
+      as halves by (cos, sin) = rope_table(S, rope, theta), and the fill
+      zero columns of attention.qk_padding.
+    kv_split(kv [B, S, heads * (nope + dv)], k_rope [B, S, rope], cos, sin)
+      -> (k [B, heads, S, nope + rope + fill], v [B, heads, S, dv]): k_rope
+      rotated once and repeated to every head."""
+    blocks = _latent_blocks(seq, heads, nope, rope, dv,
+                            jnp.dtype(dtype).itemsize)
+    if blocks is None:
+        return None
+    if interpret is None:
+        interpret = attention._default_interpret()
+    return _make_latent_fns(nope, rope, dv, blocks, interpret)

@@ -130,7 +130,8 @@ REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_window",
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
            "flash_win_bwd_dq", "flash_win_bwd_dkv", "moe_gmm", "moe_tgmm",
            "moe_run_sum", "rope_split", "rope_merge", "short_conv_fwd",
-           "short_conv_bwd")
+           "short_conv_bwd", "latent_q_split", "latent_kv_split",
+           "latent_q_merge", "latent_kv_merge")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:", "compile:")

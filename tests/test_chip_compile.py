@@ -174,6 +174,109 @@ def test_rope_kernels_compile_for_v5e(v5e, backward, shape):
     assert text.count("tpu_custom_call") >= (2 if backward else 1)
 
 
+# kanana2_train_1chip's latent block: [batch, seq, heads, nope, rope, dv].
+LATENT_SHAPE = (2, 8192, 32, 128, 64, 128)
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "forward_backward"])
+def test_latent_kernels_compile_for_v5e(v5e, backward):
+    """ops/rope.py's latent pair of pairs at the cell's shape: q's heads of
+    128 + 64 columns and kv's of 128 + 128 with the shared rotated key part
+    into the flash kernels' [B, H, S, 256 | 128], and back. What interpret
+    mode cannot see: a pair of heads cut out of three lane tiles at lane
+    offset 64, the (1, rows, 64) block of k_rope, the float32 sum over the
+    heads carried across the grid's sequential head axis."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.rope import latent_split, rope_table
+
+    batch, seq, heads, nope, rope, dv = LATENT_SHAPE
+    q_split, kv_split = latent_split(seq, heads, nope, rope, dv,
+                                     jnp.bfloat16, interpret=False)
+
+    def fwd(q, kv, k_rope):
+        table = rope_table(seq, rope, 1e6)
+        return (q_split(q, *table), *kv_split(kv, k_rope, *table))
+
+    fn = fwd
+    if backward:
+        fn = jax.grad(lambda *x: sum((t.astype(jnp.float32) ** 2).sum()
+                                     for t in fwd(*x)), argnums=(0, 1, 2))
+    one_chip = SingleDeviceSharding(v5e[0])
+    text = jax.jit(fn).lower(*(
+        jax.ShapeDtypeStruct((batch, seq, width), jnp.bfloat16,
+                             sharding=one_chip)
+        for width in (heads * (nope + rope), heads * (nope + dv), rope))
+    ).compile().as_text()
+    for kernel, there in (("latent_q_split", True), ("latent_kv_split", True),
+                          ("latent_q_merge", backward),
+                          ("latent_kv_merge", backward)):
+        assert bool(_kernel_ops(text, kernel)) == there, kernel
+    assert text.count("tpu_custom_call") >= (4 if backward else 2)
+
+
+def test_latent_block_reaches_the_flash_kernels_without_a_layout_pass(
+        v5e, monkeypatch):
+    """kanana2_train_1chip's attention block, forward and backward, for one
+    described chip: under `attn_proj` / `attn_latent` the only tensors by
+    head are the four latent kernels' own results. No `copy`, transpose or
+    fusion writes a [2, 8192, 32, 256 | 192 | 64]-shaped tensor (the jnp
+    assembly's `fusion -> [2, 8192, 32, 256] -> copy -> [2, 32, 8192, 256]`
+    for q and again for k), and no activation there is float32."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from benchmark.families import kanana
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.rope import rope_table
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kanana-2-30b-a3b.json")) as f:
+        cfg = gpt.GPTConfig(**kanana.gpt_config_kwargs(json.load(f)))
+    batch, seq, heads = LATENT_SHAPE[:3]
+    assert (cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim,
+            cfg.v_head_dim) == LATENT_SHAPE[2:]
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    one_chip = SingleDeviceSharding(v5e[0])
+    layer = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(
+            lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"][0])
+    x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype,
+                             sharding=one_chip)
+
+    def loss(layer, x):
+        table = rope_table(seq, cfg.qk_rope_dim, cfg.rope_theta)
+        return gpt._attention_block(layer, x, cfg, table,
+                                    gpt.Setting()).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layer, x).compile().as_text()
+    for kernel in ("latent_q_split", "latent_kv_split", "latent_q_merge",
+                   "latent_kv_merge", "flash_fwd", "flash_bwd_dq",
+                   "flash_bwd_dkv"):
+        assert len(_kernel_ops(text, kernel)) == 1, kernel
+    # the entry computation's instructions: what is written to memory (an
+    # instruction inside a fused computation lives in registers)
+    under = [line for line in text[text.index("\nENTRY "):].splitlines()
+             if re.search(r'op_name="[^"]*attn_proj', line)]
+    assert len(under) > 20
+    by_head = re.compile(rf"\[{batch},(?:{seq},{heads}|{heads},{seq}),\d+\]")
+    for line in under:
+        made = line.split(" = ", 1)[-1].split("(", 1)[0]
+        if by_head.search(line.split(" = ", 1)[-1]):
+            # a kernel's call, or an element of its results
+            assert re.search(r"/latent_(q|kv)_(split|merge)/pallas_call",
+                             line), line
+        assert not re.search(rf"f32\[{batch},{seq},\d", made), line
+
+
 BLOCK_WIDTHS = [
     (dict(), SHAPE[0], SHAPE[2]),
     (dict(d_model=2048, n_heads=32, d_ff=8192, max_seq=2048), 4, 2048)]
@@ -480,13 +583,16 @@ CELL_STEPS = [
     # kanana2_train_1chip: 5 layers of latent attention at q.k 192 padded
     # to 256 / v 128, one dense and four sparse with 16 of 128 experts held.
     # 5 layers x (forward, kept through the remat, + dQ + dK/dV) flash
-    # calls, 4 sparse layers x (9 grouped matmuls + 3 recomputed + 3 tgmm),
+    # calls and x (forward + recomputed, backward) of q's and kv's latent
+    # kernels, 4 sparse layers x (9 grouped matmuls + 3 recomputed + 3 tgmm),
     # each in the text twice since PR 34: once for the bounded row space and
     # once for every slot's (a step runs one of the two: test_sparse_layer_
     # compiles_with_both_row_spaces). 10.98 GB when this was written: 6.91
     # of state, 4.07 of temporaries.
     ("kanana-2-30b-a3b", {"flash_fwd": 5, "flash_bwd_dq": 5,
-                          "flash_bwd_dkv": 5, "moe_gmm": 72, "moe_tgmm": 24},
+                          "flash_bwd_dkv": 5, "moe_gmm": 72, "moe_tgmm": 24,
+                          "latent_q_split": 10, "latent_kv_split": 10,
+                          "latent_q_merge": 5, "latent_kv_merge": 5},
      (0.55, 0.92)),
     # lfm2_train_1chip: a convolution layer with the dense MLP, then
     # attention (32 query heads on 8 key/value heads) and three convolution

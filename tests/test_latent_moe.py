@@ -88,14 +88,28 @@ def test_blocks_follow_the_wider_of_the_two_widths():
     assert _block_sizes(8192, 8192, 128, 128).dq == (2048, 2048, 128)
 
 
-def test_a_head_of_192_takes_the_jnp_rotation():
-    """ops/rope.py's kernels tile whole heads in whole 128-lane tiles: a
-    head of 192 columns is neither a divisor nor a multiple, and
-    models/gpt.py's latent block rotates in jnp (`_rope_tail`)."""
-    from ray_tpu.ops.rope import _lane_tile, _rope_blocks
-    assert _lane_tile(192) is None
-    assert _rope_blocks(8192, 32, 192, 2) is None
-    assert _rope_blocks(8192, 32, 128, 2) is not None
+@pytest.mark.parametrize("heads,nope,rope,dv,whole_head,latent", [
+    (32, 128, 64, 128, False, True),    # kanana2_train_1chip
+    (16, 128, 64, 128, False, True),    # its heads over tensor = 2
+    (4, 32, 16, 32, False, False),      # tiny-kanana: nope below a lane tile
+    (32, 128, 64, 64, False, False),    # v of half a lane tile
+    (32, 64, 64, 128, True, False),     # a head of 128, but nope of half a tile
+], ids=["kanana", "kanana_tensor_2", "tiny", "v_64", "nope_64"])
+def test_a_head_of_192_tiles_as_128_and_64(heads, nope, rope, dv, whole_head,
+                                           latent):
+    """ops/rope.py's `rope_split` tiles whole heads in whole 128-lane
+    tiles: a head of 192 columns is neither a divisor nor a multiple, and
+    it is still refused there. The latent block's kernels take the head as
+    its two parts, nope of whole lane tiles and the rotated parts of a
+    group of heads a tile; where they do not, models/gpt.py keeps the jnp
+    assembly (`_rope_tail`, `_latent_heads`)."""
+    from ray_tpu.ops.rope import (_lane_tile, _latent_blocks, _rope_blocks,
+                                  latent_split)
+    assert (_lane_tile(nope + rope) is not None) == whole_head
+    assert (_rope_blocks(8192, heads, nope + rope, 2) is not None) == whole_head
+    assert (_latent_blocks(8192, heads, nope, rope, dv, 2) is not None) == latent
+    assert (latent_split(8192, heads, nope, rope, dv, "bfloat16")
+            is not None) == latent
 
 
 def test_rope_tail_rotates_the_last_columns_only(jax_cpu):
@@ -192,6 +206,94 @@ def test_logits_loss_and_gradients_match_the_reference(jax_cpu, tiny,
             err_msg=jax.tree_util.keystr(path))
     for layer in grads["layers"][1:]:
         assert not np.any(np.asarray(layer["moe"]["router_bias"]))
+
+
+def _at_the_cells_head_widths(tiny, interleaved, heads=2):
+    """tiny-kanana with a head of 128 + 64 / 128, the cell's: the widths at
+    which ops/rope.py's latent kernels engage. One dense layer."""
+    return dict(tiny, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                v_head_dim=128, num_attention_heads=heads,
+                num_key_value_heads=heads, num_hidden_layers=1,
+                rope_interleave=interleaved)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("interleaved", [True, False],
+                         ids=["interleaved", "halves"])
+def test_the_latent_kernels_give_the_jnp_paths_loss_and_gradients(
+        jax_cpu, tiny, interleaved, dtype):
+    """attention="flash" at the cell's head widths (q, k and v through
+    latent_q_split / latent_kv_split, the gradients through their merges)
+    against attention="reference" (`_rope_tail`, the jnp assembly,
+    mha_reference): in float32 only the formulation differs; in bfloat16
+    the two round in different places."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import gpt_loss
+    config = _at_the_cells_head_widths(tiny, interleaved)
+
+    def loss_and_grads(attention):
+        cfg, params, tokens = _program(jax, config, attention,
+                                       jnp.dtype(dtype))
+        fn = jax.value_and_grad(lambda p, t: gpt_loss(p, {"tokens": t}, cfg))
+        return str(jax.make_jaxpr(fn)(params, tokens)), jax.jit(fn)(
+            params, tokens)
+    jaxpr, (flash, g_flash) = loss_and_grads("flash")
+    for kernel in ("latent_q_split", "latent_kv_split", "latent_q_merge",
+                   "latent_kv_merge"):
+        assert f"name={kernel}" in jaxpr, kernel
+    jaxpr, (ref, g_ref) = loss_and_grads("reference")
+    assert "pallas_call" not in jaxpr
+    exact = dtype == "float32"
+    np.testing.assert_allclose(flash, ref, rtol=1e-5 if exact else 2e-3)
+    for (path, a), r in zip(jax.tree_util.tree_flatten_with_path(g_flash)[0],
+                            jax.tree_util.tree_leaves(g_ref)):
+        if exact:
+            np.testing.assert_allclose(a, r, rtol=2e-3, atol=2e-5,
+                                       err_msg=jax.tree_util.keystr(path))
+        else:
+            a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+            assert (np.linalg.norm(a - r) <= 0.05 * np.linalg.norm(r) + 1e-6
+                    ), jax.tree_util.keystr(path)
+
+
+def test_a_tiny_latent_block_keeps_the_jnp_assembly(jax_cpu, tiny):
+    """Heads of 32 + 16 / 32 fill no lane tiles: no latent kernel, the
+    flash kernels alone."""
+    jax = jax_cpu
+    from ray_tpu.models.gpt import gpt_loss
+    cfg, params, tokens = _program(jax, tiny, "flash")
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda p, t: gpt_loss(p, {"tokens": t}, cfg)))(params, tokens))
+    assert "name=flash_fwd" in jaxpr and "name=latent_" not in jaxpr
+
+
+def test_the_latent_kernels_run_whole_groups_of_heads_per_shard(jax_cpu,
+                                                                tiny):
+    """Under fsdp x tensor the kernels run inside the flash call's
+    shard_map on their shard's columns: two of four heads a shard, one
+    group (two rotated parts of 64 fill a lane tile), and k_rope whole on
+    every shard of 'tensor'."""
+    jax = jax_cpu
+    from ray_tpu.models.gpt import gpt_loss
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four virtual devices")
+    cfg, params, tokens = _program(
+        jax, _at_the_cells_head_widths(tiny, True, heads=4), "flash")
+    tokens = np.concatenate([tokens, tokens[::-1]])
+    mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2),
+                      devices=jax.devices()[:4])
+    fn = jax.value_and_grad(
+        lambda p, mesh: gpt_loss(p, {"tokens": tokens}, cfg, mesh))
+    with mesh:
+        sharded, g_sharded = jax.jit(lambda p: fn(p, mesh))(params)
+    single, g_single = jax.jit(lambda p: fn(p, None))(params)
+    np.testing.assert_allclose(sharded, single, rtol=1e-5)
+    for name in ("wq", "w_kva", "w_kvb"):
+        np.testing.assert_allclose(g_sharded["layers"][0]["attn"][name],
+                                   g_single["layers"][0]["attn"][name],
+                                   rtol=2e-3, atol=2e-5, err_msg=name)
 
 
 def test_the_bias_changes_the_selection_and_not_the_weights(jax_cpu, tiny):

@@ -1,7 +1,9 @@
 """ops/rope.py: the head split and the rotation between the attention
 projections and the flash kernels, as one pass (Pallas, interpreted here on
 the CPU), against the jnp formulation it replaces on the flash path:
-models/gpt.py:_rope after reshape + transpose."""
+models/gpt.py:_rope after reshape + transpose, and for a latent block's q,
+k and v models/gpt.py:_latent_heads (`_rope_tail`, concatenates,
+transposes)."""
 
 import numpy as np
 import pytest
@@ -238,6 +240,192 @@ def test_rope_table_is_the_rotation_of_each_head(jax_cpu):
     np.testing.assert_array_equal(sin[:, :32], sin[:, 96:])
     # a width that fits no lane tile keeps its own
     assert rope.rope_table(64, 48, THETA)[0].shape == (64, 48)
+
+
+# ------------------------------------------------- a latent block's q, k, v
+# [batch, heads, seq, nope, rope, dv]: kanana2_train_1chip's widths at two
+# head blocks a plane (the sum over the heads crosses the grid), a head
+# block, four heads of 32 rotated columns a lane tile, a rotated part of
+# a whole lane tile (no zero columns).
+LATENT_SHAPES = [(1, 16, 32, 128, 64, 128), (2, 4, 64, 128, 64, 128),
+                 (1, 4, 32, 128, 32, 256), (1, 2, 32, 256, 128, 128)]
+LATENT_IDS = ["x".join(map(str, s)) for s in LATENT_SHAPES]
+
+
+@pytest.mark.parametrize("seq,heads,nope,rope,dv,itemsize,q,kv", [
+    (8192, 32, 128, 64, 128, 2, (256, 1536), (256, 2048)),   # kanana2_train_1chip
+    (8192, 16, 128, 64, 128, 2, (256, 1536), (256, 2048)),   # tensor = 2
+    (8192, 32, 128, 64, 128, 4, (256, 1536), (256, 2048)),   # fp32 activations
+    (32, 16, 128, 64, 128, 2, (32, 1536), (32, 2048)),
+    (64, 4, 128, 64, 128, 2, (64, 768), (64, 1024)),
+    (32, 4, 128, 32, 256, 2, (32, 640), (32, 1536)),
+    (32, 2, 256, 128, 128, 4, (32, 768), (32, 768)),
+])
+def test_latent_blocks_follow_from_the_shape(seq, heads, nope, rope, dv,
+                                             itemsize, q, kv):
+    from ray_tpu.ops.rope import _lane_tile, _latent_blocks
+    got = _latent_blocks(seq, heads, nope, rope, dv, itemsize)
+    assert tuple(got.q) == q and tuple(got.kv) == kv
+    # whole groups of heads (their rotated parts fill a lane tile), whole
+    # lane tiles of the plane, blocks that divide it
+    group = _lane_tile(rope) // rope
+    assert got.q.cols % (group * (nope + rope)) == 0 == got.q.cols % 128
+    assert got.kv.cols % (nope + dv) == 0
+    assert (heads * (nope + rope)) % got.q.cols == 0 == seq % got.q.rows
+    assert (heads * (nope + dv)) % got.kv.cols == 0 == seq % got.kv.rows
+
+
+@pytest.mark.parametrize("seq,heads,nope,rope,dv,itemsize", [
+    (128, 4, 32, 16, 32, 2),      # tiny-kanana's heads: nope below a lane tile
+    (128, 4, 128, 64, 64, 2),     # v of half a lane tile
+    (128, 3, 128, 64, 128, 2),    # three rotated parts: a lane tile and a half
+    (128, 4, 128, 48, 128, 2),    # a rotated part that fits no lane tile
+    (100, 4, 128, 64, 128, 2),    # a ragged sequence
+    (128, 4, 0, 64, 128, 2),      # no unrotated part: ops/rope.py's rope_split
+])
+def test_latent_blocks_refuse_what_does_not_tile(seq, heads, nope, rope, dv,
+                                                 itemsize):
+    from ray_tpu.ops import rope as ops
+    assert ops._latent_blocks(seq, heads, nope, rope, dv, itemsize) is None
+    assert ops.latent_split(seq, heads, nope, rope, dv,
+                            "bfloat16" if itemsize == 2 else "float32") is None
+
+
+def _latent_inputs(jax, jnp, shape, dtype):
+    b, h, s, nope, rope, dv = shape
+    keys = jax.random.split(jax.random.PRNGKey(h + s + rope), 6)
+    widths = (nope + rope, nope + dv)
+    padded = nope + rope + (-rope % 128)
+
+    def normal(key, *shape):
+        return jax.random.normal(key, shape, jnp.float32).astype(dtype)
+    operands = (normal(keys[0], b, s, h * widths[0]),
+                normal(keys[1], b, s, h * widths[1]),
+                normal(keys[2], b, s, rope))
+    cotangents = (normal(keys[3], b, h, s, padded),
+                  normal(keys[4], b, h, s, padded),
+                  normal(keys[5], b, h, s, dv))
+    return operands, cotangents
+
+
+def _latent_forms(jnp, shape, dtype):
+    """(the kernels, the jnp assembly of models/gpt.py), each (q, kv,
+    k_rope) -> (q, k, v) as the flash kernels read them."""
+    from ray_tpu.models.gpt import _latent_heads
+    from ray_tpu.ops import rope as ops
+    _, h, s, nope, rope, dv = shape
+    table = ops.rope_table(s, rope, THETA)
+    q_split, kv_split = ops.latent_split(s, h, nope, rope, dv, dtype)
+
+    def kernels(q, kv, k_rope):
+        return (q_split(q, *table), *kv_split(kv, k_rope, *table))
+
+    def assembly(q, kv, k_rope):
+        return _latent_heads(q, kv, k_rope, table, nope, rope, dv,
+                             -rope % 128)
+    return kernels, assembly
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("shape", LATENT_SHAPES, ids=LATENT_IDS)
+def test_latent_split_forward_has_the_assemblys_bits(jax_cpu, shape, dtype):
+    """q, k and v out of the kernels against `_rope_tail` + the jnp
+    assembly: the columns that are moved bit for bit; the rotated ones too,
+    but for the FMAs XLA's CPU backend contracts in one of the two and not
+    in the other (test_rope_split_forward_has_ropes_bits)."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    b, h, s, nope, rope, dv = shape
+    (q, kv, k_rope), _ = _latent_inputs(jax, jnp, shape, dtype)
+    kernels, assembly = _latent_forms(jnp, shape, dtype)
+    got = jax.jit(kernels)(q, kv, k_rope)
+    want = jax.jit(assembly)(q, kv, k_rope)
+    for a, w, width in zip(got, want, (nope + rope + -rope % 128,) * 2
+                           + (dv,)):
+        assert a.shape == w.shape == (b, h, s, width)
+        assert a.dtype == w.dtype == jnp.dtype(dtype)
+    for a, w in zip(got[:2], want[:2]):
+        assert bool(jnp.array_equal(a[..., :nope], w[..., :nope]))
+        assert not np.asarray(a[..., nope + rope:]).any()
+        a, w = (np.asarray(t[..., nope:nope + rope].astype(jnp.float32))
+                for t in (a, w))
+        differs = a != w
+        if dtype == "bfloat16":
+            assert differs.mean() < 1e-3
+        ulp = 2.0 ** -7 if dtype == "bfloat16" else 2.0 ** -22
+        assert (np.abs(a - w)[differs] <= ulp * np.maximum(
+            np.abs(a), np.abs(w))[differs] + 1e-6).all()
+    assert bool(jnp.array_equal(got[2], want[2]))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("shape", LATENT_SHAPES, ids=LATENT_IDS)
+def test_latent_merge_is_the_gradient_within_a_rounding(jax_cpu, shape,
+                                                        dtype):
+    """d q, d kv and d k_rope (the sum over the heads of dk's rotated
+    columns, added up in float32 and rounded once) against the assembly's
+    gradient taken in float32: the kernels' error is one rounding of the
+    value, and no larger than the jnp path's, which rounds every product
+    and adds the heads in the activations' dtype."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    operands, cotangents = _latent_inputs(jax, jnp, shape, dtype)
+    kernels, assembly = _latent_forms(jnp, shape, dtype)
+    in_float32 = _latent_forms(jnp, shape, "float32")[1]
+
+    def pulled_back(f, cast=lambda t: t):
+        return jax.jit(lambda x, g: jax.vjp(f, *x)[1](g))(
+            tuple(map(cast, operands)), tuple(map(cast, cotangents)))
+    got = pulled_back(kernels)
+    jnp_way = pulled_back(assembly)
+    true = pulled_back(in_float32, lambda t: t.astype(jnp.float32))
+    half_ulp = 2.0 ** -8 if dtype == "bfloat16" else 2.0 ** -22
+    for a, j, t, x in zip(got, jnp_way, true, operands):
+        assert a.shape == x.shape and a.dtype == x.dtype
+        err = jnp.abs(a.astype(jnp.float32) - t)
+        # (a float32 ulp or two of the sum over the heads beside it)
+        assert bool(jnp.all(err <= jnp.abs(t) * half_ulp + 4e-6))
+        if dtype == "bfloat16":
+            assert float(err.mean()) <= float(jnp.abs(
+                j.astype(jnp.float32) - t).mean())
+    # what is moved and not rotated comes back to the bit
+    assert bool(jnp.array_equal(got[1], jnp_way[1]))
+
+
+@pytest.mark.parametrize("kernel", ["latent_q_split", "latent_kv_split",
+                                    "latent_q_merge", "latent_kv_merge"])
+def test_latent_kernels_carry_their_names(jax_cpu, kernel):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.util.profiling import KERNELS
+    shape = LATENT_SHAPES[1]
+    operands, _ = _latent_inputs(jax, jnp, shape, "bfloat16")
+    kernels, _ = _latent_forms(jnp, shape, "bfloat16")
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda x: sum(
+        t.astype(jnp.float32).sum() for t in kernels(*x))))(operands))
+    assert kernel in KERNELS and f"name={kernel}" in jaxpr
+
+
+def test_latent_split_under_checkpoint_gives_the_same_gradients(jax_cpu):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    shape = LATENT_SHAPES[1]
+    operands, cotangents = _latent_inputs(jax, jnp, shape, "bfloat16")
+    kernels, _ = _latent_forms(jnp, shape, "bfloat16")   # table closed over
+
+    def block(x):
+        return sum((t.astype(jnp.float32) * g).sum()
+                   for t, g in zip(kernels(*x), cotangents))
+    plain = jax.jit(jax.grad(block))(operands)
+    rematted = jax.jit(jax.grad(jax.checkpoint(block)))(operands)
+    for a, b in zip(plain, rematted):
+        assert bool(jnp.array_equal(a, b))
+    # and the recompute is there to be seen: the forward kernels, which a
+    # gradient of this block needs for nothing else
+    jaxpr = str(jax.make_jaxpr(jax.grad(jax.checkpoint(block)))(operands))
+    for kernel in ("latent_q_split", "latent_kv_split", "latent_q_merge",
+                   "latent_kv_merge"):
+        assert f"name={kernel}" in jaxpr, kernel
 
 
 # -------------------------------------------------------- through the model

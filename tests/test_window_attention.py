@@ -784,13 +784,17 @@ def test_configuration_file_keeps_the_catalog_and_states_the_cut():
 # real sizes, Mosaic calls in it (their backend_config masked, locations
 # stripped), nothing compiled: the parent's (708aa58), read before this
 # PR's first edit. A PR that means to change a cell's step replaces its
-# line; one that does not finds out here.
+# line; one that does not finds out here. PR 39 (parent 8427b12) meant to
+# change kanana's (f5d072a05f3156d1 there: its latent block reaches the
+# flash kernels through ops/rope.py's latent kernels) and no other; laguna's
+# is its parent's.
 LOWERED = {
     "gpt2s_train_1chip": "0a5e354a41f2d309",
     "olmoe_train_1chip": "d6965f84c9d53df6",
-    "kanana2_train_1chip": "f5d072a05f3156d1",
+    "kanana2_train_1chip": "98e222f1ff7314ba",
     "lfm2_train_1chip": "d3285b6a8612132b",
     "smollm17_train_4chip": "3d347ff7870a2d4a",
+    "laguna_train_1chip": "7cca2436db9dc5e9",
 }
 
 
