@@ -13,7 +13,9 @@ grouped-query attention with a norm a head, optional gated
 short-convolution layers among the attention layers (ops/short_conv.py),
 optional sliding-window layers among the full-attention layers (a head
 count and a rotation of their own a kind, a gate a head on attention's
-output),
+output), an optional learned sparse-attention indexer on the attention
+layers (ops/indexer.py: it chooses the keys a query sees, and is trained by
+a loss of its own),
 per-layer jax.checkpoint (remat) for memory: a layer keeps its input and
 the flash kernel's output and row statistics, and recomputes the rest.
 
@@ -34,7 +36,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops import moe
+from ray_tpu.ops import indexer, moe
 from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT, flash_attention,
                                    mha_reference, qk_padding, ring_attention)
 from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
@@ -84,6 +86,22 @@ class GPTConfig:
     # A gate a head on attention's output: sigmoid(normed input x wg
     # [d, heads]) times the head's output, before the output projection.
     attention_gate: bool = False
+    # index_topk > 0: every attention layer carries an indexer (`attn/index`:
+    # index_heads thin heads of index_head_dim on ONE key head, which has a
+    # LayerNorm; both rotated whole by the layer's rotation at that width; a
+    # weight a head and token) that reads the layer's normed input DETACHED
+    # and scores every causal key, I[t, s] = sum_j w[t, j] relu(qI[t, j] .
+    # kI[s]); a query then sees its index_topk best keys and no others (all
+    # of them while there are no more: a sequence of at most index_topk
+    # positions runs the plain causal kernels). The choice carries no
+    # gradient; the indexer learns from its KL to the attention's own
+    # probabilities over the chosen keys (detached, averaged over the
+    # heads), which the loss adds, summed over the layers, at
+    # index_loss_coef. 0 = no indexer. Flash and reference paths.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_loss_coef: float = 1.0
     # 0 = a dense MLP a layer; >0 = that many experts in its place, each
     # token through the expert_top_k the router gives the most probability
     # (used as they come out of the softmax, not renormalised). The loss
@@ -167,6 +185,22 @@ class GPTConfig:
                     + ("a latent block" if self.kv_latent_dim
                        else "attention='ring'" if self.attention == "ring"
                        else "qk_norm or qk_head_norm"))
+        if self.index_topk:
+            if self.index_heads < 1 or self.index_head_dim < 1:
+                raise ValueError(
+                    f"index_topk={self.index_topk} needs index_heads and "
+                    f"index_head_dim, got {self.index_heads} and "
+                    f"{self.index_head_dim}")
+            if (self.kv_latent_dim or "window" in (kinds or ())
+                    or self.attention == "ring"):
+                raise ValueError(
+                    "an indexer (index_topk > 0) is built for the flash and "
+                    "reference paths of full multi-head attention layers, "
+                    "not for "
+                    + ("a latent block" if self.kv_latent_dim
+                       else "'window' layers" if "window" in (kinds or ())
+                       else "attention='ring': the selection is not sharded "
+                            "over 'sequence'"))
         if self.kv_latent_dim and not (self.rope is None or self.rope.plain):
             raise ValueError("a latent block rotates qk_rope_dim columns at "
                              "rope_theta: it reads no RopeSpec")
@@ -289,6 +323,16 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
                 layer[_GROUP[kind]]["wg"] = _init_dense(
                     jax.random.fold_in(keys[i + 2], 10),
                     (d, cfg.heads_of(kind)))
+        if "attn" in layer and cfg.index_topk:
+            hi, di = cfg.index_heads, cfg.index_head_dim
+            ik = jax.random.split(jax.random.fold_in(keys[i + 2], 11), 3)
+            layer["attn"]["index"] = {
+                "wq": _init_dense(ik[0], (d, hi * di)),
+                "wk": _init_dense(ik[1], (d, di)),
+                "k_norm": {"scale": jnp.ones((di,), jnp.float32),
+                           "bias": jnp.zeros((di,), jnp.float32)},
+                "ww": _init_dense(ik[2], (d, hi)),
+            }
         if "attn" in layer and cfg.qk_norm:
             layer["attn"]["q_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
             layer["attn"]["k_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
@@ -456,6 +500,72 @@ def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh, window=None):
                       ("batch", "heads", None, None))(q, k, v, *table)
 
 
+def _index_projections(ix, x, cfg: GPTConfig):
+    """The indexer's three projections of the layer's normed input, read
+    DETACHED (no gradient of the indexer's loss reaches the block, and the
+    block's reaches no parameter of the indexer): qI [B, S, Hi * Di], the
+    one key head kI [B, S, Di] under its LayerNorm (weight and bias), and
+    the heads' weights w [B, S, Hi] float32, times Hi^-1/2 Di^-1/2."""
+    dt = cfg.dtype
+    m = jax.lax.stop_gradient(x)
+    qi = jnp.einsum("bsd,de->bse", m, ix["wq"].astype(dt))
+    ki = jnp.einsum("bsd,de->bse", m, ix["wk"].astype(dt)).astype(jnp.float32)
+    ki = ki - jnp.mean(ki, axis=-1, keepdims=True)
+    ki = (ki * jax.lax.rsqrt(jnp.mean(ki * ki, axis=-1, keepdims=True)
+                             + cfg.rmsnorm_eps)
+          * ix["k_norm"]["scale"] + ix["k_norm"]["bias"]).astype(dt)
+    w = jnp.einsum("bsd,dh->bsh", m, ix["ww"].astype(dt),
+                   preferred_element_type=jnp.float32)
+    return qi, ki, w * (cfg.index_heads * cfg.index_head_dim) ** -0.5
+
+
+def _selected_attention(q, k, v, table, index, cfg: GPTConfig, mesh):
+    """_flash_on_mesh for a layer with an indexer (index: its qI [B, S,
+    Hi*Di], kI [B, S, Di], w [B, S, Hi] and its rope table): after the head
+    split and the rotation, the indexer's heads are rotated whole by its
+    table, ops/indexer.py chooses each query's index_topk keys from them
+    and takes the indexer's KL against q and k (scope `attn_index`), and
+    the `flash_sel_*` kernels attend over the chosen keys, under
+    `attn_core`. A sequence of at most index_topk positions has every
+    causal key chosen and runs the plain causal kernels. -> (the heads'
+    outputs [B, H, S, D], the KL [shards], the selected pairs over the
+    causal pairs [shards])."""
+    qi, ki, w, index_table = index
+    if mesh is not None and mesh.shape.get("tensor", 1) > 1:
+        raise ValueError(
+            "an indexer's selection is the same for every head of a token: "
+            "its scores are a sum over index heads that 'tensor' > 1 would "
+            "divide, and no psum of them is built")
+    sm_scale = 1.0 / math.sqrt(cfg.head_dim)
+
+    n_table = len(table)
+
+    def split_and_attend(q, k, v, qi, ki, w, *tables):
+        table, index_table = tables[:n_table], tables[n_table:]
+        with jax.named_scope("attn_proj"):
+            q = rope_split(q, cfg.head_dim, table)
+            k = rope_split(k, cfg.head_dim, table)
+            v = rope_split(v, cfg.head_dim)
+        with jax.named_scope("attn_index"):
+            qi = rope_split(qi, cfg.index_head_dim, index_table)
+            ki = rope_split(ki, cfg.index_head_dim, index_table)[:, 0]
+            selected, kl, share = indexer.select_and_kl(
+                qi, ki, w, q, k, topk=cfg.index_topk, sm_scale=sm_scale)
+        with jax.named_scope("attn_core"):
+            if q.shape[2] <= cfg.index_topk:
+                selected = None         # every causal key: the plain kernels
+            out = flash_attention(q, k, v, causal=True, selected=selected)
+        return out, kl.reshape(1), share.reshape(1)
+
+    columns, whole = ("batch", None, "heads"), ("batch", None, None)
+    return _per_shard(
+        split_and_attend, mesh,
+        (columns,) * 4 + (whole,) * 2
+        + ((),) * (n_table + len(index_table)),
+        (("batch", "heads", None, None), ("batch",), ("batch",)))(
+        q, k, v, qi, ki, w, *table, *index_table)
+
+
 def _deinterleaved(w, heads: int, keep: int, pairs: int):
     """w [d, heads * (keep + pairs)]: in each head's last `pairs` columns,
     interleaved pairs [x0, y0, x1, y1, ..] -> halves [x0, x1, .. | y0, y1,
@@ -571,8 +681,13 @@ def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
 
 
 def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting,
-                     kind: str = "attention"):
-    """table: rope_table(S, the rotated width, the kind's rotation), built
+                     kind: str = "attention", index_table=()):
+    """-> (what attention adds to the residual stream, the layer's
+    statistics: {} but for a layer with an indexer, which gives `index_kl`
+    (its loss, unweighted) and `index_selected_share` (selected pairs over
+    causal pairs); index_table: rope_table at the indexer's head width).
+
+    table: rope_table(S, the rotated width, the kind's rotation), built
     once a step by the caller (layer_fn, outside the remat). The flash path
     alone reads it: 'reference' and 'ring' keep the jnp `_rope` on
     [B, H, S, D] (the oracle, and ring's sequence shards need their global
@@ -583,8 +698,14 @@ def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting,
     b, s, _ = x.shape
     dt = cfg.dtype
     a = layer[_GROUP[kind]]
+    stats = {}
     if cfg.kv_latent_dim:
         o = _latent_attention(layer, x, cfg, table, where)
+    elif "index" in a:
+        o, kl, share = _multi_head_attention(a, x, cfg, table, where, kind,
+                                             index_table)
+        stats = {"index_kl": jnp.mean(kl),
+                 "index_selected_share": jnp.mean(share)}
     else:
         o = _multi_head_attention(a, x, cfg, table, where, kind)
     with jax.named_scope("attn_out"):
@@ -596,16 +717,18 @@ def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting,
                     preferred_element_type=jnp.float32))
                 o = (o * gate[..., None]).astype(dt)
         return where.psum(jnp.einsum("bsd,de->bse", o.reshape(b, s, -1),
-                                     a["wo"].astype(dt)))
+                                     a["wo"].astype(dt))), stats
 
 
 def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
-                          kind: str = "attention"):
+                          kind: str = "attention", index_table=()):
     """q, k, v of one head width from the three projections of `a` (an
     attention layer's matrices; the query heads are wq's columns over
     head_dim, k and v at the key/value heads' count) -> the heads' outputs
     [B, H, S, head_dim]. kind "window": under the sliding window, and in
-    either kind the rotation is the kind's (cfg.rope_of)."""
+    either kind the rotation is the kind's (cfg.rope_of). Where `a` has an
+    indexer (`index`) the result is (the heads' outputs over the keys it
+    chose, its KL [shards], its selected share [shards])."""
     b, s, _ = x.shape
     hd = cfg.head_dim
     dt = cfg.dtype
@@ -640,6 +763,13 @@ def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
         q = proj(wq, a.get("q_norm"), a.get("q_head_norm"))
         k = proj(wk, a.get("k_norm"), a.get("k_head_norm"))
         v = proj(a["wv"])
+    index = None
+    if "index" in a:
+        with jax.named_scope("attn_index"):
+            index = _index_projections(a["index"], x, cfg)
+    if flash and index is not None:
+        return _selected_attention(q, k, v, table, index + (index_table,),
+                                   cfg, where.mesh)
     if flash:
         return _flash_on_mesh(q, k, v, table, cfg, where.mesh, window)
     with jax.named_scope("attn_proj"):
@@ -647,6 +777,21 @@ def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
         q = _rope(heads(q), spec, positions)
         k = _rope(heads(k), spec, positions)
         v = heads(v)
+    if index is not None:
+        # the oracle of `_selected_attention`: the jnp rotation, and the
+        # reference attention under the same selection
+        with jax.named_scope("attn_index"):
+            qi, ki, w = index
+            di = cfg.index_head_dim
+            qi = _rope(qi.reshape(b, s, -1, di).transpose(0, 2, 1, 3), spec,
+                       positions)
+            ki = _rope(ki[:, None], spec, positions)[:, 0]
+            selected, kl, share = indexer.select_and_kl(
+                qi, ki, w, q, k, topk=cfg.index_topk,
+                sm_scale=1.0 / math.sqrt(hd))
+        with jax.named_scope("attn_core"):
+            return (mha_reference(q, k, v, causal=True, selected=selected),
+                    kl.reshape(1), share.reshape(1))
     with jax.named_scope("attn_core"):
         if cfg.attention == "ring":
             return ring_attention(q, k, v, mesh=where.mesh, causal=True)
@@ -826,8 +971,9 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
 
 
 def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
-    """(x [B, seq, D], one layer's parameters) -> (x, the router's
-    statistics: _route's dict for a sparse layer, {} for a dense one; which
+    """(x [B, seq, D], one layer's parameters) -> (x, the layer's
+    statistics: _route's dict for a sparse layer, {} for a dense one, and
+    an indexer's two where the layer has one; which
     it is, and whether its mixer is attention or the short convolution, the
     layer's own parameters say, as gpt_init built them). The one
     transformer block, rematted as cfg.remat_policy says, for whoever
@@ -841,15 +987,21 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
             kind: rope_table(seq, cfg.qk_rope_dim if cfg.kv_latent_dim
                              else cfg.head_dim, cfg.rope_of(kind))
             for kind in sorted(kinds)}
+    index_table = ()
+    if cfg.index_topk:
+        with jax.named_scope("attn_index"):
+            index_table = rope_table(seq, cfg.index_head_dim,
+                                     cfg.rope_of("attention"))
 
     def block(x, layer):
         normed = _rmsnorm(x, layer["ln1"]["scale"], cfg.rmsnorm_eps)
+        mixer_stats = {}
         if "conv" in layer:
             mixed = _conv_block(layer["conv"], normed, cfg, where)
         else:
             kind = "window" if _GROUP["window"] in layer else "attention"
-            mixed = _attention_block(layer, normed, cfg, tables[kind], where,
-                                     kind)
+            mixed, mixer_stats = _attention_block(
+                layer, normed, cfg, tables[kind], where, kind, index_table)
         h = where.pin(x + mixed)
         normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
         if "moe" in layer:
@@ -859,12 +1011,15 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
             with jax.named_scope("mlp"):
                 delta, stats = _mlp_block(
                     layer["mlp"], normed, cfg, where), {}
-        return where.pin(h + delta), stats
+        return where.pin(h + delta), {**stats, **mixer_stats}
 
     if cfg.remat_policy == "full":
+        # (of an indexer, its selection and its loss's gradients: the walk
+        # over the score rows then runs once a layer and step)
         return jax.checkpoint(
             block, policy=jax.checkpoint_policies.save_only_these_names(
-                FLASH_OUT, FLASH_LSE))
+                FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK,
+                indexer.INDEX_GRADS))
     if cfg.remat_policy != "none":
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(expected 'full' | 'none')")
@@ -893,8 +1048,10 @@ def gpt_forward(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
 
 def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     """tokens: [B, S] -> (final hidden states [B, S, D] (pre-LM-head), the
-    router's statistics averaged over the sparse layers: _route's dict
-    for a sparse model, {} for a dense one).
+    layers' statistics, each averaged over the layers that have it:
+    _route's dict for a sparse model, an indexer's two (`index_kl`,
+    `index_selected_share`) where the layers have one, {} for a dense
+    model).
 
     act_sharding (a NamedSharding for [B, S, D] activations, usually
     ``strategy.activation_sharding(mesh)``) pins the residual stream at
@@ -911,8 +1068,11 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
         x, stats = layer(x, layer_params)
         if stats:
             per_layer.append(stats)
-    router = jax.tree_util.tree_map(
-        lambda *layers: sum(layers) / len(layers), *per_layer or [{}])
+    # each statistic over the layers that have it
+    router = {}
+    for name in sorted({name for stats in per_layer for name in stats}):
+        layers = [stats[name] for stats in per_layer if name in stats]
+        router[name] = sum(layers) / len(layers)
     return final_norm(params, x, cfg), router
 
 
@@ -1068,7 +1228,9 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
                      act_sharding=None):
     """batch: {"tokens": [B, S+1]} -> (loss, aux): the mean next-token
     cross-entropy, plus under the softmax routing rule the router's two
-    losses at the configuration's weights; aux holds the cross-entropy alone ("xent")
+    losses at the configuration's weights, plus the indexers' KL summed
+    over the layers at index_loss_coef; aux holds the cross-entropy alone
+    ("xent")
     and the router's statistics (the two losses unweighted, the largest
     expert's load over the mean, the share of the slots that fall to the
     held experts and of the layers whose bounded row space held them), for
@@ -1083,6 +1245,12 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
         loss = (xent
                 + cfg.router_aux_loss_coef * router["router_balance_loss"]
                 + cfg.router_z_loss_coef * router["router_z_loss"])
+    if "index_kl" in router:
+        # a loss of the layers' own: the mean over the layers (what the
+        # statistics hold) times how many have an indexer is their sum
+        indexed = sum("index" in layer.get("attn", ())
+                      for layer in params["layers"])
+        loss = loss + cfg.index_loss_coef * indexed * router["index_kl"]
     return loss, {"xent": xent, **router}
 
 

@@ -18,6 +18,10 @@ v are never repeated in HBM and dK, dV leave at num_kv_heads.
 A sliding window (`window`: query i sees keys j with 0 <= i - j < window)
 runs the same three kernel bodies under names of their own (flash_win_*)
 on a grid whose reduced dimension covers only the blocks the band touches.
+
+A selection (`selected`: [batch, seq, seq] int8, 1 where a query may see a
+key, the same for every head; ops/indexer.py makes one from the data) runs
+them as flash_sel_*: softmax, lse and delta over the selected keys alone.
 """
 
 from __future__ import annotations
@@ -50,9 +54,11 @@ FLASH_LSE = "flash_lse"
 
 def mha_reference(q, k, v, *, causal: bool = True,
                   sm_scale: Optional[float] = None,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, selected=None):
     """window (causal only): query i sees keys j with 0 <= i - j < window,
-    itself and the window - 1 before it."""
+    itself and the window - 1 before it. selected ([B, Sq, Sk], not zero
+    where query i may see key j, for every head alike): the softmax runs
+    over those keys, under the causal mask if there is one."""
     if window is not None and not causal:
         raise ValueError("a window is a band under the causal mask")
     if sm_scale is None:
@@ -69,6 +75,8 @@ def mha_reference(q, k, v, *, causal: bool = True,
             mask &= ~jnp.tril(jnp.ones((qlen, klen), dtype=bool),
                               klen - qlen - window)
         logits = jnp.where(mask, logits, NEG_INF)
+    if selected is not None:
+        logits = jnp.where(selected[:, None] != 0, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
@@ -338,6 +346,13 @@ def _mask(s, mask, upper):
     return jnp.where(keep, s, NEG_INF), keep
 
 
+def _select(s, sel_ref, rows, cols):
+    """(s with NEG_INF where the selection's tile says so, what was kept):
+    the tile of a [1, outer, major] int8 block that lies like s."""
+    keep = sel_ref[0, rows, cols].astype(jnp.int32) != 0
+    return jnp.where(keep, s, NEG_INF), keep
+
+
 def _walk(upper, band, causal, delta, step, at, outer, major, group, tile):
     """The tiles of one grid step: under the causal mask alone (or none),
     or, with band = (window, major blocks in the sequence), in the band of
@@ -354,7 +369,7 @@ def _walk(upper, band, causal, delta, step, at, outer, major, group, tile):
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *, sm_scale, causal, group,
-                      offset, band=None):
+                      offset, band=None, sel_ref=None):
     """Grid (batch*head, q block, k major block): online softmax over the k
     blocks of one q block (with `band`, over those its window touches:
     `_for_band`).
@@ -362,7 +377,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     offset = seq_k - seq_q: masking is bottom-right aligned, matching
     mha_reference (query i attends keys <= i + offset). Also emits the
     per-row logsumexp (lse) the backward kernels consume; a row with no key
-    to attend gives zeros and lse = NEG_INF.
+    to attend gives zeros and lse = NEG_INF. With `sel_ref` (a selection's
+    [1, bq, bk] block, a subset of the causal pairs) the walk is the causal
+    one and a tile's mask is the selection's: a row may meet its first key
+    in any tile, so what is not kept is 0 by decree there too.
     """
     bq, d = o_ref.shape[1:]                 # d: v's and the output's width
     bk = k_ref.shape[1]
@@ -379,11 +397,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # done on the small side
         q = q_ref[0, rows, :] * sm_scale
         k, v = k_ref[0, cols, :], v_ref[0, cols, :]
-        s, keep = _mask(_dot(q, k, (1, 1)), mask, False)  # [group, width]
+        s = _dot(q, k, (1, 1))                            # [group, width]
+        if sel_ref is None:
+            s, keep = _mask(s, mask, False)
+        else:
+            s, keep = _select(s, sel_ref, rows, cols)
         m_prev, l_prev = m_ref[rows, :], l_ref[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - _lanes(m_new, s.shape[1]))
-        if keep is not None and offset < 0:
+        if keep is not None and (offset < 0 or sel_ref is not None):
             # a row with no key at all (seq_q > seq_k) has m = NEG_INF and
             # exp(NEG_INF - NEG_INF) = 1: its p is 0 by decree
             p = jnp.where(keep, p, 0.0)
@@ -413,7 +435,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, acc_ref, *, sm_scale, causal, group,
-                         offset, band=None):
+                         offset, band=None, sel_ref=None):
     """Grid (batch*head, q block, k major block): dQ of one q block.
 
     p = exp(s - lse); dS = p * (dO·Vᵀ - delta); dQ = scale · dS·K
@@ -432,7 +454,12 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k, v = k_ref[0, cols, :], v_ref[0, cols, :]
         # lse and delta lie in memory as rows; here they are columns
         lse, delta = lse_ref[0, 0, rows][:, None], delta_ref[0, 0, rows][:, None]
-        s, keep = _mask(_dot(q, k, (1, 1)), mask, False)  # [group, width]
+        s = _dot(q, k, (1, 1))                            # [group, width]
+        if sel_ref is None:
+            s, keep = _mask(s, mask, False)
+        else:
+            # every row has a key: exp(NEG_INF - lse) is 0
+            s, keep = _select(s, sel_ref, rows, cols)[0], None
         p = jnp.exp(s - lse)
         if keep is not None and offset < 0:
             p = jnp.where(keep, p, 0.0)     # lse = NEG_INF: see the forward
@@ -450,7 +477,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *, sm_scale,
-                          causal, group, offset, q_blocks=None, band=None):
+                          causal, group, offset, q_blocks=None, band=None,
+                          sel_ref=None):
     """Grid (batch*kv_head, k block, q major block): dK and dV of one k
     block, on transposed tiles [keys, queries].
 
@@ -459,6 +487,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     grid dimension walks them once for each query head of the group, all
     into the same accumulators. With `band` the q blocks of one query head
     are the q_blocks steps of the band (`_for_band`), not all of them.
+    `sel_ref`: a [1, bk, bq] block of the selection TRANSPOSED (keys first).
     """
     bk = k_ref.shape[1]
     bq = q_ref.shape[1]
@@ -474,7 +503,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k, v = k_ref[0, rows, :] * sm_scale, v_ref[0, rows, :]
         q, do = q_ref[0, cols, :], do_ref[0, cols, :]
         lse, delta = lse_ref[0, :, cols], delta_ref[0, :, cols]  # [1, width]
-        st, keep = _mask(_dot(k, q, (1, 1)), mask, True)  # [group, width]
+        st = _dot(k, q, (1, 1))                           # [group, width]
+        if sel_ref is None:
+            st, keep = _mask(st, mask, True)
+        else:
+            st, keep = _select(st, sel_ref, rows, cols)[0], None
         pt = jnp.exp(st - lse)
         if keep is not None and offset < 0:
             pt = jnp.where(keep, pt, 0.0)   # lse = NEG_INF: see the forward
@@ -490,6 +523,19 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _finish():
         dk_ref[0] = (dk_acc_ref[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
+
+
+def _flash_sel_fwd_kernel(q_ref, k_ref, v_ref, sel_ref, *refs, **static):
+    _flash_fwd_kernel(q_ref, k_ref, v_ref, *refs, sel_ref=sel_ref, **static)
+
+
+def _flash_sel_bwd_kernel(body, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                          delta_ref, sel_ref, *refs, **static):
+    """body: _flash_bwd_dq_kernel | _flash_bwd_dkv_kernel, with the
+    selection's block (for dK/dV the transposed selection's) as the last
+    operand."""
+    body(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+         sel_ref=sel_ref, **static)
 
 
 _GRID_SEMANTICS = pltpu.CompilerParams(
@@ -552,7 +598,15 @@ def _query_heads_a_kv_head(q, k, v) -> int:
     return heads // kv_heads
 
 
-def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret, window=None):
+def _kernel_name(kernel: str, window, selected) -> str:
+    """flash_<kernel>, flash_win_<kernel> under a window, flash_sel_<kernel>
+    under a selection: a trace row, and a roofline, is of one shape."""
+    kind = "" if window is None else "win_"
+    return f"flash_{kind if selected is None else 'sel_'}{kernel}"
+
+
+def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret, window=None,
+                   selected=None):
     batch, heads, seq_q, d = q.shape
     seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
@@ -567,14 +621,24 @@ def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret, window=None):
         kernel = functools.partial(kernel, band=(window, seq_k // bkm))
         steps, kv_index = (_band_steps(bq, bkm, window),
                            _band_index(bq, bkm, seq_k // bkm, rep))
+    in_specs = [
+        pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, bkm, d), kv_index),
+        pl.BlockSpec((1, bkm, dv), kv_index),
+    ]
+    operands = (q.reshape(bh, seq_q, d), k.reshape(bh // rep, seq_k, d),
+                v.reshape(bh // rep, seq_k, dv))
+    if selected is not None:
+        # one tile for all the heads of a batch row, the key block k's
+        kernel = functools.partial(_flash_sel_fwd_kernel, **kernel.keywords)
+        in_specs.append(pl.BlockSpec(
+            (1, bq, bkm),
+            lambda b, i, j: (b // heads, i, kv_index(b, i, j)[1])))
+        operands += (selected,)
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, seq_q // bq, steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bkm, d), kv_index),
-            pl.BlockSpec((1, bkm, dv), kv_index),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
             # lse rides as [bh, 1, seq_q]: TPU Pallas needs the last two
@@ -592,14 +656,13 @@ def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret, window=None):
         ],
         compiler_params=_compiler_params(max(d, dv), seq_k, bkm),
         interpret=interpret,
-        name="flash_fwd" if window is None else "flash_win_fwd",
-    )(q.reshape(bh, seq_q, d), k.reshape(bh // rep, seq_k, d),
-      v.reshape(bh // rep, seq_k, dv))
+        name=_kernel_name("fwd", window, selected),
+    )(*operands)
     return out.reshape(batch, heads, seq_q, dv), lse
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
-                    interpret, window=None):
+                    interpret, window=None, selected=None):
     batch, heads, seq_q, d = q.shape
     seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
@@ -630,21 +693,31 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
     def q_spec(width):
         return pl.BlockSpec((1, bq, width), lambda b, i, j: (b, i, 0))
     row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+    kernel = functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
+                               causal=causal, group=group, offset=offset,
+                               **band(seq_k // bkm))
+    in_specs = [q_spec(d), pl.BlockSpec((1, bkm, d), kv_index),
+                pl.BlockSpec((1, bkm, dv), kv_index), q_spec(dv),
+                row_spec, row_spec]
+    operands = (qr, kr, vr, gr, lse, delta)
+    if selected is not None:
+        kernel = functools.partial(_flash_sel_bwd_kernel,
+                                   _flash_bwd_dq_kernel, **kernel.keywords)
+        in_specs.append(pl.BlockSpec(
+            (1, bq, bkm),
+            lambda b, i, j: (b // heads, i, kv_index(b, i, j)[1])))
+        operands += (selected,)
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
-                          causal=causal, group=group, offset=offset,
-                          **band(seq_k // bkm)),
+        kernel,
         grid=(bh, seq_q // bq, steps),
-        in_specs=[q_spec(d), pl.BlockSpec((1, bkm, d), kv_index),
-                  pl.BlockSpec((1, bkm, dv), kv_index), q_spec(dv),
-                  row_spec, row_spec],
+        in_specs=in_specs,
         out_specs=q_spec(d),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name="flash_bwd_dq" if window is None else "flash_win_bwd_dq",
-    )(qr, kr, vr, gr, lse, delta)
+        name=_kernel_name("bwd_dq", window, selected),
+    )(*operands)
 
     # dK, dV: a grid row a key/value head. Its rep query heads' q blocks
     # follow one another on the last grid dimension (step -> query head
@@ -681,13 +754,24 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
     row_spec = pl.BlockSpec(
         (1, 1, bqm), lambda b, i, j: (q_head(b, j), 0, q_block(i, j)))
     walk = {} if rep == 1 else {"q_blocks": steps}
+    kernel = functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
+                               causal=causal, group=group, offset=offset,
+                               **walk, **band(num_q))
+    in_specs = [q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
+                row_spec, row_spec]
+    if selected is not None:
+        # dK/dV's tiles are [keys, queries]: the selection transposed, one
+        # XLA pass over a byte a pair, so that no tile is turned in VMEM
+        kernel = functools.partial(_flash_sel_bwd_kernel,
+                                   _flash_bwd_dkv_kernel, **kernel.keywords)
+        in_specs.append(pl.BlockSpec(
+            (1, bk, bqm),
+            lambda b, i, j: (b // (heads // rep), i, q_block(i, j))))
+        operands = operands[:-1] + (jnp.swapaxes(selected, 1, 2),)
     dk, dvalue = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
-                          causal=causal, group=group, offset=offset, **walk,
-                          **band(num_q)),
+        kernel,
         grid=(bh // rep, seq_k // bk, rep * steps),
-        in_specs=[q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
-                  row_spec, row_spec],
+        in_specs=in_specs,
         out_specs=[kv_spec(d), kv_spec(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((bh // rep, seq_k, d), k.dtype),
@@ -697,8 +781,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
                         pltpu.VMEM((bk, dv), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name="flash_bwd_dkv" if window is None else "flash_win_bwd_dkv",
-    )(qr, kr, vr, gr, lse, delta)
+        name=_kernel_name("bwd_dkv", window, selected),
+    )(*operands)
     return (dq.reshape(batch, heads, seq_q, d),
             dk.reshape(batch, heads // rep, seq_k, d),
             dvalue.reshape(batch, heads // rep, seq_k, dv))
@@ -741,6 +825,34 @@ def _make_flash_fn(causal, sm_scale, blocks, interpret, window=None):
     return f
 
 
+@functools.lru_cache(maxsize=None)
+def _make_flash_sel_fn(sm_scale, blocks, interpret):
+    """_make_flash_fn for causal self-attention under a selection, the
+    fourth operand ([B, S, S] int8, no gradient): the same two names on the
+    forward's results, so a policy that keeps them keeps this kernel from a
+    second run too."""
+
+    @jax.custom_vjp
+    def f(q, k, v, selected):
+        return _flash_forward(q, k, v, True, sm_scale, blocks, interpret,
+                              selected=selected)[0]
+
+    def fwd(q, k, v, selected):
+        out, lse = _flash_forward(q, k, v, True, sm_scale, blocks, interpret,
+                                  selected=selected)
+        out = checkpoint_name(out, FLASH_OUT)
+        lse = checkpoint_name(lse, FLASH_LSE)
+        return out, (q, k, v, out, lse, selected)
+
+    def bwd(res, g):
+        q, k, v, out, lse, selected = res
+        return _flash_backward(q, k, v, out, lse, g, True, sm_scale, blocks,
+                               interpret, selected=selected) + (None,)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
 def qk_padding(d: int) -> int:
     """Zero columns to append to q and k of width d before the kernels."""
     return -d % LANES if d > LANES else 0
@@ -757,8 +869,25 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, selected=None):
     """Fused attention on the MXU; O(seq) memory via online softmax.
+
+    selected (causal self-attention only, no window): [B, S, S] int8, 1
+    where query i may see key j and 0 elsewhere, a subset of the causal
+    pairs, at least one key a query, the same for every head of a batch
+    row (ops/indexer.py:select_and_kl makes it). The kernels run as
+    flash_sel_fwd / flash_sel_bwd_dq / flash_sel_bwd_dkv with the blocks of
+    the shape and the causal walk: a [block, block] tile of the selection
+    comes in beside a tile's K and V (the backward's dK/dV kernel reads the
+    transposed selection, its tiles lying keys first), every tile at or
+    under the diagonal is computed and masked by it, and softmax, lse and
+    delta run over the selected keys alone. One byte a pair was chosen over
+    a threshold a row with the indexer's scores recomputed in the kernel:
+    the tile is 4 MB a grid step beside 2 MB of q, k, v and o, fetched
+    while the step before computes, and the kernels need nothing of the
+    indexer. No table of empty tiles is kept: a tile of 2048 x 2048 pairs
+    under the diagonal has none selected only if 2048 queries in a row
+    choose none of 2048 keys in a row.
 
     window (causal self-attention only): query i sees keys j with 0 <= i -
     j < window. The kernels then run as flash_win_fwd / flash_win_bwd_dq /
@@ -797,6 +926,15 @@ def flash_attention(q, k, v, *, causal: bool = True,
                 "own position")
         if window >= seq_k:
             window = None
+    if selected is not None:
+        if (not causal or window is not None or pad
+                or selected.shape != (q.shape[0], seq_q, seq_k)
+                or seq_q != seq_k):
+            raise ValueError(
+                "selected needs causal self-attention without a window, q "
+                f"and k of whole lane tiles, and a selection [B, S, S]: got "
+                f"causal={causal}, window={window}, q {q.shape}, k "
+                f"{k.shape}, selected {selected.shape}")
     if interpret is None:
         interpret = _default_interpret()
     if block_q is None and block_k is None:
@@ -817,6 +955,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
                              f"block_q={bq} == block_k={bk}")
         blocks = _FlashBlocks(fwd=(bq, bk, bq), dq=(bq, bk, bq),
                               dkv=(bk, bq, bk))
+    if selected is not None:
+        return _make_flash_sel_fn(float(sm_scale), blocks, interpret)(
+            q, k, v, selected.astype(jnp.int8))
     fn = _make_flash_fn(causal, float(sm_scale), blocks, interpret, window)
     return fn(q, k, v)
 

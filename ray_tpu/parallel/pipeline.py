@@ -83,6 +83,11 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
             "the pipeline preset has no sliding-window layers (its scan "
             "carries no period of kinds, head counts or rope tables) and "
             "no rule for a gate a head (attn/wg)")
+    if cfg.index_topk:
+        raise ValueError(
+            "the pipeline preset has no indexer (index_topk > 0): its scan "
+            "carries no loss of a layer's own, and a stage's statistics do "
+            "not reach the last rank's loss")
     if "conv" in kinds and tp > 1:
         raise ValueError("ShardingStrategy.pp_tp() has no rule for conv/w_in, "
                          "conv/filter and conv/w_out")

@@ -106,6 +106,12 @@ class ShardingStrategy:
             (r"attn/wo", P(t, None)),
             # the gate a head: its columns are heads
             (r"attn/wg", P(None, t)),
+            # an indexer: whole index heads of wq's columns; its one key
+            # head, that head's norm and the heads' weights are not divided
+            # (the scores' sum over the heads would then be a psum, which
+            # models/gpt.py does not build: it refuses 'tensor' > 1)
+            (r"attn/index/wq", P(None, t)),
+            (r"attn/index/(wk|k_norm|ww)", P()),
             # latent attention: whole heads of the up-projection's columns;
             # the down-projection, its norm and the shared rotated key
             # part are not divided
@@ -143,6 +149,9 @@ class ShardingStrategy:
             (r"attn/(wq|wk|wv)", P(f, t)),
             (r"attn/wo", P(t, f)),
             (r"attn/wg", P(f, t)),
+            (r"attn/index/wq", P(f, t)),
+            (r"attn/index/(wk|ww)", P(f, None)),
+            (r"attn/index/k_norm", P()),
             (r"attn/w_kvb", P(f, t)),
             (r"attn/(w_kva|kv_norm)", P()),
             (r"attn/(q|k)_head_norm", P()),

@@ -122,13 +122,18 @@ def stack_dump() -> Dict[str, str]:
 # `attn_window`, nested in `attn_core`, is the sliding-window layers' flash
 # call (so `attn_core` keeps the full layers' alone); `attn_gate`, nested in
 # `attn_out`, the gate a head on attention's output (matmul, sigmoid,
-# product).
+# product). `attn_index` is a learned sparse-attention indexer: its
+# projections, norm and rotation, the walk of ops/indexer.py (scores, each
+# row's selection, the KL's target from the main q and k, the KL and its
+# gradient) and the rope table at its head width; the attention under the
+# selection (`flash_sel_*`) stays in `attn_core`.
 REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_window",
-           "attn_out", "attn_gate", "conv", "conv_mix", "mlp", "moe",
-           "moe_route", "moe_shared", "norm", "head", "loss_and_grad",
+           "attn_index", "attn_out", "attn_gate", "conv", "conv_mix", "mlp",
+           "moe", "moe_route", "moe_shared", "norm", "head", "loss_and_grad",
            "grad_accum", "optimizer")
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
-           "flash_win_bwd_dq", "flash_win_bwd_dkv", "moe_gmm", "moe_tgmm",
+           "flash_win_bwd_dq", "flash_win_bwd_dkv", "flash_sel_fwd",
+           "flash_sel_bwd_dq", "flash_sel_bwd_dkv", "moe_gmm", "moe_tgmm",
            "moe_run_sum", "rope_split", "rope_merge", "short_conv_fwd",
            "short_conv_bwd", "latent_q_split", "latent_kv_split",
            "latent_q_merge", "latent_kv_merge")

@@ -254,7 +254,7 @@ def test_latent_block_reaches_the_flash_kernels_without_a_layout_pass(
     def loss(layer, x):
         table = rope_table(seq, cfg.qk_rope_dim, cfg.rope_theta)
         return gpt._attention_block(layer, x, cfg, table,
-                                    gpt.Setting()).astype(jnp.float32).sum()
+                                    gpt.Setting())[0].astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         layer, x).compile().as_text()
@@ -327,7 +327,8 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
 
     def loss(layer, x):
         table = rope_table(seq, cfg.head_dim, cfg.rope_theta)
-        out = gpt._attention_block(layer, x, cfg, table, gpt.Setting(mesh))
+        out, _stats = gpt._attention_block(layer, x, cfg, table,
+                                           gpt.Setting(mesh))
         return out.astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
@@ -340,6 +341,52 @@ def test_attention_block_compiles_on_four_chip_mesh(v5e, monkeypatch, widths,
     # the tensor-parallel out projection and the fsdp weights need them
     assert "all-reduce" in text or "reduce-scatter" in text
     assert "all-gather" in text
+
+
+def test_selected_kernels_and_the_walk_compile_at_8192_positions(v5e):
+    """keye2_train_1chip's call, [2, 32 on 4, 8192, 128] under a selection
+    of one byte a pair ([2, 8192, 8192] int8: a tile of 2048 x 2048 bytes a
+    grid step, forward and both backward kernels, dK/dV on the transposed
+    selection), and the indexer's walk that makes it (ops/indexer.py), whose
+    rows' statistics stay reductions: the chip's compiler fuses a row's
+    reduction with its broadcast into a window reduction 16 383 wide (47 ms
+    a block where 1.5 do) unless a barrier stands between."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops import indexer
+    from ray_tpu.ops.attention import flash_attention
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+    grads = jax.jit(jax.grad(lambda q, k, v, selected: flash_attention(
+        q, k, v, causal=True, selected=selected,
+        interpret=False).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+    compiled = grads.lower(shape(2, 32, 8192, 128), shape(2, 4, 8192, 128),
+                           shape(2, 4, 8192, 128),
+                           shape(2, 8192, 8192, dtype=jnp.int8)).compile()
+    text = compiled.as_text()
+    for kernel in ("flash_sel_fwd", "flash_sel_bwd_dq", "flash_sel_bwd_dkv"):
+        assert len(_kernel_ops(text, kernel)) == 1, kernel
+    dq, dk, dv = compiled.out_info
+    assert dq.shape == (2, 32, 8192, 128)
+    assert dk.shape == dv.shape == (2, 4, 8192, 128)
+
+    def walk(qi, ki, w, q, k):
+        def loss(qi, ki, w):
+            selected, kl, _share = indexer.select_and_kl(
+                qi, ki, w, q, k, topk=2048, sm_scale=128 ** -0.5)
+            return kl, selected
+        return jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(qi, ki, w)
+    compiled = jax.jit(walk).lower(
+        shape(2, 16, 8192, 64), shape(2, 8192, 64),
+        shape(2, 8192, 16, dtype=jnp.float32), shape(2, 32, 8192, 128),
+        shape(2, 4, 8192, 128)).compile()
+    windows = re.findall(r"reduce-window\([^\n]*window=\{size=([0-9x]+)",
+                         compiled.as_text())
+    assert all(int(w.split("x")[-1]) <= 128 for w in windows), windows
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
 
 
 def _kernel_ops(text, kernel):
@@ -618,6 +665,19 @@ CELL_STEPS = [
                      "flash_win_bwd_dkv": 3, "rope_split": 30,
                      "rope_merge": 15, "moe_gmm": 72, "moe_tgmm": 24},
      (0.78, 0.92)),
+    # keye2_train_1chip: five layers alike, 32 query heads on 4 with a norm
+    # a head, an indexer a layer (16 heads of 64 on one key head) whose walk
+    # and selection are kept through the remat (11 while loops: 5 walks of
+    # two and the head's scan), 16 of 128 experts held. One call a layer of
+    # each kernel under the selection and none of the plain ones; q, k, v
+    # and the indexer's q through rope_split forward (its one key head takes
+    # the jnp form), q, k, v again in the recompute. 13.13 GB when this was
+    # written: 6.75 of state, 6.38 of temporaries.
+    ("keye-vl-2.0-30b-a3b", {"flash_sel_fwd": 5, "flash_sel_bwd_dq": 5,
+                             "flash_sel_bwd_dkv": 5, "flash_fwd": 0,
+                             "rope_split": 35, "rope_merge": 20,
+                             "moe_gmm": 90, "moe_tgmm": 30},
+     (0.70, 0.85)),
 ]
 
 
@@ -688,7 +748,8 @@ def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
         heads = config["num_attention_heads"]
         dim = config.get("head_dim", config["hidden_size"] // heads)
         b, s = mix["global_batch"], mix["seq"]
-        for kernel in ("flash_bwd_dkv", "flash_win_bwd_dkv"):
+        for kernel in ("flash_bwd_dkv", "flash_win_bwd_dkv",
+                       "flash_sel_bwd_dkv"):
             for dkv in _kernel_ops(text, kernel):
                 assert dkv.count(f"bf16[{b * kv_heads},{s},{dim}]") >= 4, dkv
         by_head = f"bf16[{b},{heads},{s},{dim}]"
@@ -696,4 +757,5 @@ def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
                 if f" = {by_head}" in line and "rope_split" in line]
         # q alone is split at the query heads' count: forward, and
         # recomputed, in every full-attention layer
-        assert len(made) == 2 * kernel_calls["flash_fwd"], made
+        assert len(made) == 2 * (kernel_calls["flash_fwd"]
+                                 or kernel_calls["flash_sel_fwd"]), made

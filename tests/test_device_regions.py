@@ -56,13 +56,14 @@ def dense_text(jax_cpu):
 # a latent block and a shared expert; conv and conv_mix: tests/
 # test_conv_gqa.py, on a step with a short-convolution layer; attn_window
 # and attn_gate: tests/test_window_attention.py, on a step with
-# sliding-window layers and a gate a head)
+# sliding-window layers and a gate a head; attn_index: tests/
+# test_selected_attention.py, on a step whose layers carry an indexer)
 @pytest.mark.parametrize("region", [r for r in REGIONS
                                     if r not in ("moe", "moe_route",
                                                  "grad_accum", "attn_latent",
                                                  "moe_shared", "conv",
                                                  "conv_mix", "attn_window",
-                                                 "attn_gate")])
+                                                 "attn_gate", "attn_index")])
 def test_dense_step_names_region(dense_text, region):
     names = re.findall(r'op_name="([^"]*)"', dense_text)
     assert any(profiling._last_of(n, REGIONS) == region for n in names)
@@ -90,10 +91,11 @@ def test_dense_step_names_phase(dense_text, phase):
     assert phase in mlp
 
 
-# (the window kernels' names: tests/test_window_attention.py)
+# (the window kernels' names: tests/test_window_attention.py; the names
+# under a selection: tests/test_selected_attention.py)
 @pytest.mark.parametrize("kernel",
                          [k for k in KERNELS if k.startswith("flash_")
-                          and not k.startswith("flash_win_")])
+                          and not k.startswith(("flash_win_", "flash_sel_"))])
 def test_flash_kernels_carry_their_names(jax_cpu, kernel):
     import jax.numpy as jnp
     from ray_tpu.ops.attention import flash_attention
