@@ -524,12 +524,14 @@ def _selected_attention(q, k, v, table, index, cfg: GPTConfig, mesh):
     Hi*Di], kI [B, S, Di], w [B, S, Hi] and its rope table): after the head
     split and the rotation, the indexer's heads are rotated whole by its
     table, ops/indexer.py chooses each query's index_topk keys from them
-    and takes the indexer's KL against q and k (scope `attn_index`), and
-    the `flash_sel_*` kernels attend over the chosen keys, under
-    `attn_core`. A sequence of at most index_topk positions has every
-    causal key chosen and runs the plain causal kernels. -> (the heads'
-    outputs [B, H, S, D], the KL [shards], the selected pairs over the
-    causal pairs [shards])."""
+    (`select`: kernels, scope `attn_index`), the `flash_sel_*` kernels
+    attend over the chosen keys under `attn_core` and hand out each head's
+    log-sum-exp over them, and the indexer's KL is taken against q, k and
+    that (`kl`: kernels, `attn_index` again). A sequence of at most
+    index_topk positions has every causal key chosen and runs the plain
+    causal kernels beside the plain walk. -> (the heads' outputs [B, H, S,
+    D], the KL [shards], the selected pairs over the causal pairs
+    [shards])."""
     qi, ki, w, index_table = index
     if mesh is not None and mesh.shape.get("tensor", 1) > 1:
         raise ValueError(
@@ -549,12 +551,25 @@ def _selected_attention(q, k, v, table, index, cfg: GPTConfig, mesh):
         with jax.named_scope("attn_index"):
             qi = rope_split(qi, cfg.index_head_dim, index_table)
             ki = rope_split(ki, cfg.index_head_dim, index_table)[:, 0]
-            selected, kl, share = indexer.select_and_kl(
-                qi, ki, w, q, k, topk=cfg.index_topk, sm_scale=sm_scale)
+        if q.shape[2] <= cfg.index_topk:
+            # every causal key is chosen: the plain kernels, and the plain
+            # walk for the indexer's loss
+            with jax.named_scope("attn_index"):
+                _, kl, share = indexer.select_and_kl(
+                    qi, ki, w, q, k, topk=cfg.index_topk, sm_scale=sm_scale)
+            with jax.named_scope("attn_core"):
+                out = flash_attention(q, k, v, causal=True)
+            return out, kl.reshape(1), share.reshape(1)
+        with jax.named_scope("attn_index"):
+            selected, kept, share = indexer.select(qi, ki, w,
+                                                   topk=cfg.index_topk)
         with jax.named_scope("attn_core"):
-            if q.shape[2] <= cfg.index_topk:
-                selected = None         # every causal key: the plain kernels
-            out = flash_attention(q, k, v, causal=True, selected=selected)
+            out, lse = flash_attention(q, k, v, causal=True,
+                                       selected=selected, with_lse=True)
+        with jax.named_scope("attn_index"):
+            # the target's softmax is the kernel's: its lse over the chosen
+            kl = indexer.kl(qi, ki, w, q, k, lse, selected, kept,
+                            sm_scale=sm_scale)
         return out, kl.reshape(1), share.reshape(1)
 
     columns, whole = ("batch", None, "heads"), ("batch", None, None)
@@ -1015,7 +1030,7 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
 
     if cfg.remat_policy == "full":
         # (of an indexer, its selection and its loss's gradients: the walk
-        # over the score rows then runs once a layer and step)
+        # over the score tiles then runs once a layer and step)
         return jax.checkpoint(
             block, policy=jax.checkpoint_policies.save_only_these_names(
                 FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK,

@@ -830,24 +830,30 @@ def _make_flash_sel_fn(sm_scale, blocks, interpret):
     """_make_flash_fn for causal self-attention under a selection, the
     fourth operand ([B, S, S] int8, no gradient): the same two names on the
     forward's results, so a policy that keeps them keeps this kernel from a
-    second run too."""
+    second run too. -> (out, lse [B, H, S]: each head's log-sum-exp over the
+    query's selected keys, the one the backward kernels read, handed out
+    detached for the indexer's KL, ops/indexer.py:kl)."""
+
+    def heads_apart(lse, q):
+        return lse.reshape(q.shape[:3])
 
     @jax.custom_vjp
     def f(q, k, v, selected):
-        return _flash_forward(q, k, v, True, sm_scale, blocks, interpret,
-                              selected=selected)[0]
+        out, lse = _flash_forward(q, k, v, True, sm_scale, blocks, interpret,
+                                  selected=selected)
+        return out, heads_apart(lse, q)
 
     def fwd(q, k, v, selected):
         out, lse = _flash_forward(q, k, v, True, sm_scale, blocks, interpret,
                                   selected=selected)
         out = checkpoint_name(out, FLASH_OUT)
         lse = checkpoint_name(lse, FLASH_LSE)
-        return out, (q, k, v, out, lse, selected)
+        return (out, heads_apart(lse, q)), (q, k, v, out, lse, selected)
 
     def bwd(res, g):
         q, k, v, out, lse, selected = res
-        return _flash_backward(q, k, v, out, lse, g, True, sm_scale, blocks,
-                               interpret, selected=selected) + (None,)
+        return _flash_backward(q, k, v, out, lse, g[0], True, sm_scale,
+                               blocks, interpret, selected=selected) + (None,)
 
     f.defvjp(fwd, bwd)
     return f
@@ -869,7 +875,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    window: Optional[int] = None, selected=None):
+                    window: Optional[int] = None, selected=None,
+                    with_lse: bool = False):
     """Fused attention on the MXU; O(seq) memory via online softmax.
 
     selected (causal self-attention only, no window): [B, S, S] int8, 1
@@ -887,7 +894,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     while the step before computes, and the kernels need nothing of the
     indexer. No table of empty tiles is kept: a tile of 2048 x 2048 pairs
     under the diagonal has none selected only if 2048 queries in a row
-    choose none of 2048 keys in a row.
+    choose none of 2048 keys in a row. with_lse (under a selection only):
+    -> (out, lse [B, H, S] float32, each head's log-sum-exp over the query's
+    selected keys as the backward kernels read it, without a gradient).
 
     window (causal self-attention only): query i sees keys j with 0 <= i -
     j < window. The kernels then run as flash_win_fwd / flash_win_bwd_dq /
@@ -956,8 +965,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
         blocks = _FlashBlocks(fwd=(bq, bk, bq), dq=(bq, bk, bq),
                               dkv=(bk, bq, bk))
     if selected is not None:
-        return _make_flash_sel_fn(float(sm_scale), blocks, interpret)(
+        out, lse = _make_flash_sel_fn(float(sm_scale), blocks, interpret)(
             q, k, v, selected.astype(jnp.int8))
+        return (out, lse) if with_lse else out
+    if with_lse:
+        raise ValueError("with_lse is the selected kernels' alone")
     fn = _make_flash_fn(causal, float(sm_scale), blocks, interpret, window)
     return fn(q, k, v)
 

@@ -136,7 +136,8 @@ KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
            "flash_sel_bwd_dq", "flash_sel_bwd_dkv", "moe_gmm", "moe_tgmm",
            "moe_run_sum", "rope_split", "rope_merge", "short_conv_fwd",
            "short_conv_bwd", "latent_q_split", "latent_kv_split",
-           "latent_q_merge", "latent_kv_merge")
+           "latent_q_merge", "latent_kv_merge", "index_scores",
+           "index_search", "index_kl", "index_grad_q", "index_grad_k")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:", "compile:")

@@ -350,7 +350,9 @@ def test_selected_kernels_and_the_walk_compile_at_8192_positions(v5e):
     selection), and the indexer's walk that makes it (ops/indexer.py), whose
     rows' statistics stay reductions: the chip's compiler fuses a row's
     reduction with its broadcast into a window reduction 16 383 wide (47 ms
-    a block where 1.5 do) unless a barrier stands between."""
+    a block where 1.5 do) unless a barrier stands between; and the same walk
+    as the flash path runs it, five kernels (PR 41) with no such row left
+    to XLA."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -383,10 +385,38 @@ def test_selected_kernels_and_the_walk_compile_at_8192_positions(v5e):
         shape(2, 16, 8192, 64), shape(2, 8192, 64),
         shape(2, 8192, 16, dtype=jnp.float32), shape(2, 32, 8192, 128),
         shape(2, 4, 8192, 128)).compile()
-    windows = re.findall(r"reduce-window\([^\n]*window=\{size=([0-9x]+)",
-                         compiled.as_text())
+    windows = _windows(compiled.as_text())
     assert all(int(w.split("x")[-1]) <= 128 for w in windows), windows
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
+
+    # the same walk as kernels (the flash path): each compiles for the chip
+    # once, the rows of 8192 keys stay inside them, and what crosses HBM
+    # between them (I, g, d w's partial sums) is under the jnp walk's blocks
+    def kernels(qi, ki, w, q, k, lse):
+        selected, kept, share = indexer.select(qi, ki, w, topk=2048,
+                                               interpret=False)
+
+        def loss(qi, ki, w):
+            return indexer.kl(qi, ki, w, q, k, lse, selected, kept,
+                              sm_scale=128 ** -0.5, interpret=False)
+        return selected, share, jax.value_and_grad(loss, (0, 1, 2))(qi, ki, w)
+    compiled = jax.jit(kernels).lower(
+        shape(2, 16, 8192, 64), shape(2, 8192, 64),
+        shape(2, 8192, 16, dtype=jnp.float32), shape(2, 32, 8192, 128),
+        shape(2, 4, 8192, 128),
+        shape(2, 32, 8192, dtype=jnp.float32)).compile()
+    text = compiled.as_text()
+    for kernel in ("index_scores", "index_search", "index_kl",
+                   "index_grad_q", "index_grad_k"):
+        assert len(_kernel_ops(text, kernel)) == 1, kernel
+    assert all(int(w.split("x")[-1]) <= 128 for w in _windows(text))
+    assert "while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.4e9
+
+
+def _windows(text):
+    """The window sizes ("1x1x255") of the compiled text's reduce-windows."""
+    return re.findall(r"reduce-window\([^\n]*window=\{size=([0-9x]+)", text)
 
 
 def _kernel_ops(text, kernel):
@@ -667,14 +697,19 @@ CELL_STEPS = [
      (0.78, 0.92)),
     # keye2_train_1chip: five layers alike, 32 query heads on 4 with a norm
     # a head, an indexer a layer (16 heads of 64 on one key head) whose walk
-    # and selection are kept through the remat (11 while loops: 5 walks of
-    # two and the head's scan), 16 of 128 experts held. One call a layer of
-    # each kernel under the selection and none of the plain ones; q, k, v
-    # and the indexer's q through rope_split forward (its one key head takes
-    # the jnp form), q, k, v again in the recompute. 13.13 GB when this was
-    # written: 6.75 of state, 6.38 of temporaries.
+    # is five kernels since PR 41 (scores, search, KL, the gradient by query
+    # and by key: one call a layer each, the selection and the gradients
+    # kept through the remat, no loop of the jnp walk left), 16 of 128
+    # experts held. One call a layer of each kernel under the selection and
+    # none of the plain ones; q, k, v and the indexer's q through rope_split
+    # forward (its one key head takes the jnp form), q, k, v again in the
+    # recompute. 13.13 GB when this was written: 6.75 of state, 6.38 of
+    # temporaries (PR 40's walk: the same 6.38).
     ("keye-vl-2.0-30b-a3b", {"flash_sel_fwd": 5, "flash_sel_bwd_dq": 5,
                              "flash_sel_bwd_dkv": 5, "flash_fwd": 0,
+                             "index_scores": 5, "index_search": 5,
+                             "index_kl": 5, "index_grad_q": 5,
+                             "index_grad_k": 5,
                              "rope_split": 35, "rope_merge": 20,
                              "moe_gmm": 90, "moe_tgmm": 30},
      (0.70, 0.85)),
@@ -739,8 +774,17 @@ def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
     assert share[0] * 16.91e9 < peak < share[1] * 16.91e9, peak
     text = compiled.as_text()
     for kernel, calls in kernel_calls.items():
-        found = len(_kernel_ops(text, kernel))
-        assert found == calls, (kernel, found)
+        found = _kernel_ops(text, kernel)
+        assert len(found) == calls, (kernel, len(found))
+        if kernel.startswith("index_"):
+            # once a layer: the walk's results are kept through the remat
+            assert not any("rematted_computation" in op for op in found)
+    if "index_kl" in kernel_calls:
+        # the indexer's walk left no row of 8192 keys to XLA: no window
+        # reduction over them (the router's own is 255 wide), and the
+        # step's temporaries are at or under those of PR 40's jnp walk
+        assert all(int(w.split("x")[-1]) < 512 for w in _windows(text))
+        assert memory.temp_size_in_bytes <= 6.39e9
     kv_heads = config.get("num_key_value_heads")
     if kv_heads != config["num_attention_heads"]:
         # dK and dV leave their kernel at the key/value heads' count, and

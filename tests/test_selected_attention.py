@@ -254,6 +254,166 @@ def test_the_kl_and_its_gradient_are_autodiffs_of_the_plain_form(
     assert not np.any(grads[3]) and not np.any(grads[4])
 
 
+@pytest.mark.parametrize("k", [1, 8, 63, 64, 100])
+@pytest.mark.parametrize("quantum", [0.0, 0.25, 4.0],
+                         ids=["no_ties", "some_ties", "mostly_ties"])
+def test_the_search_kernel_is_top_k_mask_byte_for_byte(jax_cpu, k, quantum):
+    """`index_search` on given scores against `top_k_mask` over the causal
+    keys: ties at the topk-th largest (the lower key stays: the second
+    search, for the last index that may), rows with fewer than k causal
+    keys, four blocks of rows and two passes' columns a row; and the rows'
+    log-sum-exp and count over the chosen."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import indexer
+    n = 64
+    scores = jax.random.normal(jax.random.PRNGKey(k), (3, n, n))
+    if quantum:
+        scores = jnp.round(scores / quantum) * quantum
+    valid = jnp.tril(jnp.ones((n, n), bool))[None]
+    want = indexer.top_k_mask(scores, k, valid)
+    tiles = indexer._Tiles(block=32, group=32, rows=16, chunk=32)
+    # what lies past the diagonal is never read: NaN there
+    got, lse, count = indexer._search(jnp.where(valid, scores, jnp.nan), k,
+                                      tiles, True)
+    assert got.dtype == jnp.int8 and bool(jnp.all((got != 0) == want))
+    np.testing.assert_array_equal(count[:, :, 0], want.sum(-1))
+    np.testing.assert_allclose(
+        lse[:, :, 0], jax.scipy.special.logsumexp(
+            jnp.where(want, scores, -jnp.inf), axis=-1), rtol=1e-6)
+    assert bool(jnp.all(lse == lse[:, :, :1]))
+
+
+def _whole_numbers(operands):
+    """The indexer's operands as small whole numbers (w in eighths): every
+    product and sum of the scores is exact in float32 in any order, and
+    scores tie at the threshold."""
+    import jax.numpy as jnp
+    qi, ki, w, q, k = operands
+    return (jnp.round(2 * qi), jnp.round(2 * ki), jnp.round(40 * w) / 8, q, k)
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 2), (2, 2)],
+                         ids=["grouped", "a_head_each"])
+@pytest.mark.parametrize("whole", [False, True],
+                         ids=["drawn", "whole_numbers"])
+@pytest.mark.parametrize("topk,block", [(24, 32), (128, 128), (200, 64)],
+                         ids=["selects", "topk_is_the_sequence", "over_it"])
+def test_the_kernels_are_the_walk(jax_cpu, topk, block, whole, heads,
+                                  kv_heads):
+    """`select`, the flash kernel under the selection and `kl` (the five
+    `index_*` kernels, interpreted) against `select_and_kl`'s jnp walk: the
+    selection byte for byte, the KL, the selected share and the three
+    gradients to float32 tolerance; q and k get none."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import indexer
+    from ray_tpu.ops.attention import flash_attention
+    qi, ki, w, q, k = _walk_operands(jax, jnp.float32)
+    q, k = q[:, :heads], k[:, :kv_heads]
+    if whole:
+        qi, ki, w, q, k = _whole_numbers((qi, ki, w, q, k))
+    scale = 1.0 / math.sqrt(32)
+
+    def walk(qi, ki, w, q, k):
+        selected, kl, share = indexer.select_and_kl(
+            qi, ki, w, q, k, topk=topk, sm_scale=scale, block=block)
+        return kl, (selected, share)
+
+    def kernels(qi, ki, w, q, k):
+        selected, kept, share = indexer.select(qi, ki, w, topk=topk,
+                                               block=block)
+        _, lse = flash_attention(q, k, k, causal=True, sm_scale=scale,
+                                 selected=selected, with_lse=True,
+                                 block_q=block, block_k=block)
+        kl = indexer.kl(qi, ki, w, q, k, lse, selected, kept, sm_scale=scale,
+                        block=block)
+        return kl, (selected, share)
+    with jax.default_matmul_precision("highest"):
+        (want, (chosen, want_share)), want_grads = jax.value_and_grad(
+            walk, (0, 1, 2, 3, 4), has_aux=True)(qi, ki, w, q, k)
+        (kl, (selected, share)), grads = jax.value_and_grad(
+            kernels, (0, 1, 2, 3, 4), has_aux=True)(qi, ki, w, q, k)
+    assert selected.dtype == jnp.int8
+    np.testing.assert_array_equal(selected, chosen)
+    if whole:
+        # some row's topk-th largest score is shared beyond what it keeps
+        scores = indexer.index_scores(qi, ki, w)[0]
+        kth = jnp.min(jnp.where(chosen != 0, scores, jnp.inf), -1)
+        spare = jnp.tril(scores == kth[..., None]) & (chosen == 0)
+        assert topk >= 128 or bool(spare.any())
+    np.testing.assert_allclose(kl, want, rtol=3e-6)
+    np.testing.assert_allclose(share, want_share, rtol=1e-6)
+    for got, ref in zip(grads[:3], want_grads):
+        assert float(jnp.abs(ref).max()) > 1e-5
+        np.testing.assert_allclose(got, ref, rtol=1e-4,
+                                   atol=2e-6 * float(jnp.abs(ref).max()))
+    assert not np.any(grads[3]) and not np.any(grads[4])
+
+
+@pytest.mark.parametrize("seq,tiles", [
+    (8192, (512, 256, 128, 128)), (1024, (512, 256, 128, 128)),
+    (384, (384, 128, 128, 128)), (128, (128, 128, 128, 128)),
+    (64, (64, 64, 64, 64))], ids=lambda v: str(v) if isinstance(v, int) else "")
+def test_the_walks_tiles_follow_from_the_shape(jax_cpu, seq, tiles):
+    """(square tile of the pair-space kernels, rows of it a score tile
+    covers, rows a step of the search, columns a pass takes at a time):
+    whole lane tiles that divide the sequence, one tile below 128
+    positions; a ragged sequence is refused by name, and a test's `block`
+    tiles a short sequence by exactly that."""
+    from ray_tpu.ops import indexer
+    assert tuple(indexer._tiles(seq)) == tiles
+    assert tuple(indexer._tiles(seq, 32)) == (32, 32, 32, 32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        indexer._tiles(seq + 200)
+    with pytest.raises(ValueError, match="whole tiles"):
+        indexer._tiles(seq, seq - 8)
+
+
+def test_the_selected_call_hands_out_the_lse_its_backward_reads(jax_cpu):
+    """flash_attention(selected=, with_lse=True): the lse [B, H, S] beside
+    the output is the forward kernel's own (the backward's residual, named
+    FLASH_LSE), each head's log-sum-exp over the query's selected keys; it
+    carries no gradient and the output's gradients do not move."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import attention
+    seq, dim = 256, 16
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(keys[0], (2, 4, seq, dim))
+    k, v = (jax.random.normal(key, (2, 2, seq, dim)) for key in keys[1:])
+    selected = _selections(jax, 2, seq)["a_random_set"]
+    out, lse = attention.flash_attention(q, k, v, causal=True,
+                                         selected=selected, with_lse=True)
+    assert lse.shape == (2, 4, seq) and lse.dtype == jnp.float32
+    blocks = attention._block_sizes(seq, seq, dim, dim)
+    scale = 1.0 / math.sqrt(dim)
+    _, vjp = jax.vjp(attention._make_flash_sel_fn(scale, blocks, True),
+                     q, k, v, selected)
+    residual = [x for x in jax.tree_util.tree_leaves(vjp)
+                if getattr(x, "shape", None) == (2 * 4, 1, seq)]
+    assert len(residual) == 1
+    np.testing.assert_array_equal(lse, residual[0].reshape(2, 4, seq))
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, axis=1))
+    want = jax.scipy.special.logsumexp(
+        jnp.where(selected[:, None] != 0, logits * scale, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(lse, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(out, attention.flash_attention(
+        q, k, v, causal=True, selected=selected))
+
+    def both(q, k, v):
+        out, lse = attention.flash_attention(q, k, v, causal=True,
+                                             selected=selected, with_lse=True)
+        return jnp.sum(out * out) + jnp.sum(lse)
+    grads = jax.grad(both, (0, 1, 2))(q, k, v)
+    alone = jax.grad(lambda q, k, v: jnp.sum(attention.flash_attention(
+        q, k, v, causal=True, selected=selected) ** 2), (0, 1, 2))(q, k, v)
+    for got, ref in zip(grads, alone):
+        np.testing.assert_array_equal(got, ref)
+    with pytest.raises(ValueError, match="with_lse"):
+        attention.flash_attention(q, k, v, causal=True, with_lse=True)
+
+
 # ---------------------------------------------------------------------------
 # (c) the whole model against the family's reference
 # ---------------------------------------------------------------------------
@@ -637,6 +797,62 @@ def test_flops_and_kernel_arithmetic_count_what_is_computed_here():
     assert 1.5e9 < flops < 1.7e9
 
 
+@pytest.mark.parametrize("seq,topk", [(64, 8), (32, 32), (16, 40)])
+def test_the_indexer_kernels_arithmetic_is_a_brute_force_count(tiny, seq,
+                                                               topk):
+    """benchmark/kernels/indexer.py, a function a kernel name, against
+    loops over the pairs at a tiny shape: the index heads' scores over
+    every causal pair, the target and the gradient over the selected pairs
+    alone, every tensor once."""
+    from benchmark.kernels import indexer
+    config = copy.deepcopy(tiny)
+    config["sa_config"]["topk"] = topk
+    mix = {"global_batch": 3, "seq": seq, "mesh": {"data": 1}}
+    batch, heads, kv_heads, dim = 3, 4, 2, 32
+    index_heads, index_dim = 4, 16
+    causal = selected = 0
+    for t in range(seq):
+        causal += batch * (t + 1)
+        selected += batch * min(t + 1, topk)
+    positions = batch * seq
+    operands = (positions * index_heads * index_dim * 2      # qI
+                + positions * index_dim * 2                  # kI
+                + positions * index_heads * 4)               # w
+    index_product = lambda pairs: 2.0 * pairs * index_dim * index_heads
+    assert indexer.index_scores(config, mix) == (
+        index_product(causal), operands + causal * 4)
+    assert indexer.index_search(config, mix) == (0.0, causal * 4 + causal)
+    assert indexer.index_kl(config, mix) == (
+        2.0 * selected * dim * heads,
+        positions * dim * heads * 2 + positions * dim * kv_heads * 2
+        + positions * heads * 4 + selected * 4 + selected * 4 + causal)
+    assert indexer.index_grad_q(config, mix) == (
+        2 * index_product(selected),
+        operands + selected * 4 + positions * index_heads * index_dim * 2
+        + positions * index_heads * 4)
+    assert indexer.index_grad_k(config, mix) == (
+        2 * index_product(selected),
+        operands + selected * 4 + positions * index_dim * 2)
+
+
+def test_the_indexer_kernels_least_times_at_the_cell():
+    """At keye2_train_1chip: what each yardstick says a call takes at 197
+    TFLOP/s and 819 GB/s, against the kernels' first traced times (1.74,
+    4.12, 3.61, 3.43, 3.12 ms: PERF.md, PR 41): every share under 100."""
+    from benchmark.kernels import indexer
+    cell = _read("benchmark", "configs", "keye-vl-2.0-30b-a3b.json")
+    mix = _read("benchmark", "traffic", "train_b2_s8192_dp.json")
+    least = {}
+    for kernel in ("index_scores", "index_search", "index_kl",
+                   "index_grad_q", "index_grad_k"):
+        flops, hbm_bytes = getattr(indexer, kernel)(cell, mix)
+        least[kernel] = 1e3 * max(flops / 197e12, hbm_bytes / 819e9)
+    assert 0.69 < least["index_scores"] < 0.71          # the MXU's
+    assert 0.40 < least["index_search"] < 0.42          # the memory's
+    assert 1.21 < least["index_kl"] < 1.23
+    assert 0.60 < least["index_grad_q"] == least["index_grad_k"] < 0.62
+
+
 @pytest.mark.parametrize("strategy,column", [
     ("tp", (None, "tensor")), ("tp_fsdp", ("fsdp", "tensor"))])
 def test_every_new_leaf_gets_its_rule(jax_cpu, tiny, strategy, column):
@@ -750,24 +966,31 @@ def _kernel_calls(jax, jaxpr, rematted=False):
             yield from _kernel_calls(jax, sub, inner)
 
 
-def _scans(jax, jaxpr, rematted=False):
+def _loops_outside_kernels(jax, jaxpr, path=""):
+    """The scope path of every scan and while of jaxpr and of what its
+    equations hold, a kernel's body left out."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn.params["length"], rematted
-        inner = rematted or eqn.params.get("differentiated", False)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _scans(jax, sub, inner)
+        here = f"{path}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name in ("scan", "while"):
+            yield here
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _loops_outside_kernels(jax, sub, here)
 
 
 def test_the_selection_engages_and_the_walk_runs_once_a_layer(jax_cpu, tiny):
     """The step's kernel calls are the counter: 3 of each flash_sel_* and
-    no flash_*, and under remat_policy="full" neither the forward kernel
-    nor the indexer's walk (a scan over blocks of queries) in a recompute
-    pass: FLASH_OUT, FLASH_LSE, INDEX_MASK and INDEX_GRADS are kept."""
+    no flash_*, 3 of each of the walk's five kernels (128 positions, 32
+    keys a query: the kernels' side of `_selected_attention`), and under
+    remat_policy="full" neither the forward kernel nor any of the walk's
+    in a recompute pass: FLASH_OUT, FLASH_LSE, INDEX_MASK and INDEX_GRADS
+    are kept. The jnp walk (a scan over blocks of queries around a search
+    of 32 passes) is not in the step."""
     jax = jax_cpu
     from collections import Counter
     from benchmark.families import keye
     from ray_tpu.models.gpt import gpt_init, gpt_loss
+    from ray_tpu.util import profiling
     cfg = keye._train_config(tiny)
     assert cfg.remat_policy == "full" and cfg.index_topk == 32
     params = gpt_init(jax.random.PRNGKey(0), cfg)
@@ -781,9 +1004,14 @@ def test_the_selection_engages_and_the_walk_runs_once_a_layer(jax_cpu, tiny):
         assert calls[(kernel, False)] + calls[(kernel, True)] == 3
     assert not any(name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
                    for name, _ in calls)
-    # (a walk's search for the topk-th largest is its own scan of 32)
-    scans = Counter(_scans(jax, jaxpr.jaxpr))
-    assert scans[(32, False)] >= 3 and scans[(32, True)] == 0
+    for kernel in ("index_scores", "index_search", "index_kl",
+                   "index_grad_q", "index_grad_k"):
+        assert kernel in profiling.KERNELS
+        assert calls[(kernel, False)] == 3 and calls[(kernel, True)] == 0
+    # (the search's 32 passes are a loop inside its kernel: the walk's own
+    # scans stood in the layer, under `attn_index`)
+    loops = list(_loops_outside_kernels(jax, jaxpr.jaxpr))
+    assert loops and not any("attn_index" in path for path in loops), loops
 
 
 def test_the_new_scope_is_a_region_and_reaches_the_compiled_step(jax_cpu,

@@ -795,8 +795,9 @@ LOWERED = {
     "lfm2_train_1chip": "d3285b6a8612132b",
     "smollm17_train_4chip": "3d347ff7870a2d4a",
     "laguna_train_1chip": "7cca2436db9dc5e9",
-    # PR 40's cell, as that PR left it (the six above are its parent's too)
-    "keye2_train_1chip": "e078cd70110bc468",
+    # PR 40's cell with PR 41's kernels in the indexer's walk (the six above
+    # are both parents')
+    "keye2_train_1chip": "100ea29abfc532ef",
 }
 
 
