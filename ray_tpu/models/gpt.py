@@ -950,7 +950,8 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
     p_e x down_e(silu(gate_e x) * up_e x). No capacity and no dropped
     token: every token-slot is computed, by its own expert only. The
     grouped matmuls run per shard (`_per_shard`): each device dispatches
-    its own tokens to all the experts, whose matrices it is handed whole;
+    its own tokens to all the experts, whose matrices it is handed whole
+    (in cfg.dtype under a mesh, as they are kept on one device);
     the router and its losses stay outside, over the whole batch.
 
     With cfg.experts_held the sum runs over the chosen experts that are
@@ -966,7 +967,12 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
     m = layer["moe"]
     with jax.named_scope("moe_route"):
         weights, idx, stats = _route(m, x, cfg)
-    matrices = [m[name].astype(dt) for name in ("w_gate", "w_up", "w_down")]
+    matrices = [m[name] for name in ("w_gate", "w_up", "w_down")]
+    if where.mesh is not None and where.mesh.size > 1:
+        # handed whole to every device: gather them in the rows' type. On
+        # one device the kernels read the masters and round a block in VMEM
+        # (moe.grouped_matmul), and no cast pass runs here
+        matrices = [w.astype(dt) for w in matrices]
     held = (None if cfg.experts_held is None
             else (cfg.experts_held[0], cfg.n_experts))
     tokens = ("batch", None, None)

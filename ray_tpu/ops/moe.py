@@ -75,8 +75,10 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops import attention, short_conv
 from ray_tpu.ops.attention import lane_divisor
 
-# One weight block in VMEM (it is double-buffered): a whole 2048 x 1024
-# bf16 expert matrix, so the rows are read once and each matrix once a group.
+# One weight block in VMEM as the MXU reads it (in the rows' type): a whole
+# 2048 x 1024 bf16 expert matrix, so the rows are read once and each matrix
+# once a group. A BlockSpec double-buffers it; float32 masters land in one
+# block of twice the bytes beside their rounded copy (`_gmm`).
 _WEIGHT_BLOCK_BYTES = 4 << 20
 _VMEM_LIMIT_BYTES = 64 << 20
 _MAX_TILE_ROWS = 256
@@ -455,6 +457,12 @@ def _combine_bwd(res, g):
     # dz = w g and dw = <g, z> row by row, then dw back to [T, k]
     z, weights, plan = res
     k = plan.token_rows.shape[1]
+    # g meets z here, and not before: left to itself the scheduler may pad
+    # g as soon as it exists, ahead of the kernels that recompute z; a copy
+    # that lives across them stays in HBM, and the gather below reads it
+    # five times slower than from VMEM (2.87 ms for 0.52 at olmoe:
+    # PERF.md, PR 42)
+    g, z = lax.optimization_barrier((g, z))
     g_rows = _take_rows(g, plan.row_slot // k)
     row_weight = _take_rows(weights.reshape(-1), plan.row_slot)
     dz = g_rows * row_weight[:, None].astype(g.dtype)
@@ -477,34 +485,96 @@ def _used(tile, used_ref):
     return jnp.minimum(tile, used_ref[0] - 1)
 
 
+def _first_of_group(i, group_ref):
+    """Whether row tile i opens its group's run of tiles (the plan keeps a
+    group's tiles together), or the walk itself."""
+    return (i == 0) | (group_ref[jnp.maximum(i - 1, 0)] != group_ref[i])
+
+
+def _tile_product(x_ref, w, o_ref, transposed):
+    contract = (((1,), (1 if transposed else 0,)), ((), ()))
+    o_ref[...] = lax.dot_general(
+        x_ref[...], w, contract,
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
 def _gmm_kernel(group_ref, used_ref, x_ref, w_ref, o_ref, *, transposed):
     @pl.when(pl.program_id(1) < used_ref[0])
     def _tile():
-        contract = (((1,), (1 if transposed else 0,)), ((), ()))
-        o_ref[...] = lax.dot_general(
-            x_ref[...], w_ref[0], contract,
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        _tile_product(x_ref, w_ref[0], o_ref, transposed)
+
+
+def _gmm_masters_kernel(group_ref, used_ref, x_ref, w_hbm, o_ref, landing,
+                        block, arrived, *, transposed):
+    """_gmm_kernel where the matrices are kept in another type than the
+    rows' (float32 masters under bfloat16 rows) and stay in HBM: a group's
+    block is fetched into `landing` as it is kept and rounded into `block`,
+    the rows' type, at the group's first tile; its tiles share the one
+    rounding. The next group's fetch starts as soon as `landing` has been
+    read, so it has the whole group's products to arrive under (a BlockSpec
+    would start it one tile ahead, and a block of twice the bytes does not
+    arrive under one tile's product: 2.27 ms a call at olmoe where this
+    takes 2.01; a second landing slot bought nothing: PERF.md, PR 42). The
+    plan gives every group a tile, in order (`lay_out`): the group after g
+    is g + 1."""
+    j, i = pl.program_id(0), pl.program_id(1)
+    n_groups = w_hbm.shape[0]
+
+    def fetch(group):
+        tn = block.shape[0] if transposed else block.shape[1]
+        cols = pl.ds(pl.multiple_of(j * tn, tn), tn)
+        return pltpu.make_async_copy(
+            w_hbm.at[group, cols, :] if transposed else w_hbm.at[group, :, cols],
+            landing, arrived)
+
+    @pl.when(i < used_ref[0])
+    def _tile():
+        group = group_ref[i]
+
+        @pl.when(i == 0)
+        def _first_of_walk():
+            fetch(group).start()
+
+        @pl.when(_first_of_group(i, group_ref))
+        def _round():
+            fetch(group).wait()
+            block[...] = landing[...].astype(block.dtype)
+
+            @pl.when(group + 1 < n_groups)
+            def _next():
+                fetch(group + 1).start()
+
+        _tile_product(x_ref, block[...], o_ref, transposed)
 
 
 def _gmm(x, w, plan: Plan, transposed: bool, interpret: bool):
     """x [rows, K] times, row tile by row tile, its group's [K, N] matrix
     (w [G, K, N], or [G, N, K] when `transposed`) -> [rows, N]. Grid
     (column blocks, row tiles) with the tiles inside, so a group's weight
-    block stays in VMEM while its tiles pass."""
+    block stays in VMEM while its tiles pass. w in the rows' type, or in
+    the type it is kept in (float32 masters): the block is then rounded to
+    the rows' type in VMEM (`_gmm_masters_kernel`), the same
+    round-to-nearest an `astype` ahead of the call would make, without its
+    pass over HBM. The block is sized by the elements the MXU reads,
+    whatever w's type: a narrower block would read the rows again."""
     m, kdim = x.shape
     n = w.shape[1] if transposed else w.shape[2]
     tiles = plan.tile_group.shape[0]
     tm = m // tiles
     tn = lane_divisor(
-        n, max(128, _WEIGHT_BLOCK_BYTES // (kdim * w.dtype.itemsize)))
-    if transposed:
-        w_spec = pl.BlockSpec(
-            (1, tn, kdim), lambda j, i, grp, used: (grp[_used(i, used)], j, 0))
+        n, max(128, _WEIGHT_BLOCK_BYTES // (kdim * x.dtype.itemsize)))
+    block = (tn, kdim) if transposed else (kdim, tn)
+    if w.dtype != x.dtype:
+        kernel, w_spec = _gmm_masters_kernel, pl.BlockSpec(memory_space=pl.ANY)
+        scratch = [pltpu.VMEM(block, w.dtype), pltpu.VMEM(block, x.dtype),
+                   pltpu.SemaphoreType.DMA(())]
     else:
-        w_spec = pl.BlockSpec(
-            (1, kdim, tn), lambda j, i, grp, used: (grp[_used(i, used)], 0, j))
+        kernel, scratch = _gmm_kernel, []
+        w_spec = pl.BlockSpec((1,) + block, lambda j, i, grp, used: (
+            (grp[_used(i, used)], j, 0) if transposed
+            else (grp[_used(i, used)], 0, j)))
     return pl.pallas_call(
-        functools.partial(_gmm_kernel, transposed=transposed),
+        functools.partial(kernel, transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n // tn, tiles),
@@ -515,6 +585,7 @@ def _gmm(x, w, plan: Plan, transposed: bool, interpret: bool):
             ],
             out_specs=pl.BlockSpec(
                 (tm, tn), lambda j, i, grp, used: (_used(i, used), j)),
+            scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -532,8 +603,8 @@ def _tgmm_kernel(group_ref, used_ref, x_ref, g_ref, o_ref, acc_ref):
 
     @pl.when(i <= last_tile)
     def _tile():
-        @pl.when((i == 0) | (group_ref[jnp.maximum(i - 1, 0)] != group))
-        def _first_of_group():
+        @pl.when(_first_of_group(i, group_ref))
+        def _zero():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
         acc_ref[...] += lax.dot_general(
@@ -546,11 +617,11 @@ def _tgmm_kernel(group_ref, used_ref, x_ref, g_ref, o_ref, acc_ref):
             o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _tgmm(x, g, plan: Plan, n_groups: int, out_dtype, interpret: bool):
-    """x [rows, K], g [rows, N] -> [G, K, N]: x^T g over each group's rows
-    (padding rows are zero; every group owns a tile, so every block of the
-    result is written). Grid (K blocks, N blocks, row tiles), the tiles
-    inside as the reduction."""
+def _tgmm(x, g, plan: Plan, n_groups: int, interpret: bool):
+    """x [rows, K], g [rows, N] -> [G, K, N] in the rows' type: x^T g over
+    each group's rows (padding rows are zero; every group owns a tile, so
+    every block of the result is written). Grid (K blocks, N blocks, row
+    tiles), the tiles inside as the reduction."""
     m, kdim = x.shape
     n = g.shape[1]
     tiles = plan.tile_group.shape[0]
@@ -573,7 +644,7 @@ def _tgmm(x, g, plan: Plan, n_groups: int, out_dtype, interpret: bool):
                 lambda a, b, i, grp, used: (grp[_used(i, used)], a, b)),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_groups, kdim, n), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((n_groups, kdim, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
@@ -593,8 +664,13 @@ def _make_grouped_matmul(interpret: bool):
 
     def bwd(res, g):
         x, w, plan = res
+        # the matrices' gradient in the rows' type, widened where they are
+        # masters: XLA fuses that into whatever reads it (the optimizer's
+        # update), where a float32 result of the kernel would be twice the
+        # bytes written here and read there (PERF.md, PR 42)
         return (_gmm(g, w, plan, True, interpret),
-                _tgmm(x, g, plan, w.shape[0], w.dtype, interpret), None)
+                _tgmm(x, g, plan, w.shape[0], interpret).astype(w.dtype),
+                None)
 
     f.defvjp(fwd, bwd)
     return f
@@ -602,7 +678,11 @@ def _make_grouped_matmul(interpret: bool):
 
 def grouped_matmul(x, w, plan: Plan, *, interpret: Optional[bool] = None):
     """rows [R, K] (dispatch's order) x w [G, K, N] -> [R, N]: each row by
-    its own expert's matrix, float32 accumulation, the rows' type out."""
+    its own expert's matrix, float32 accumulation, the rows' type out. w in
+    the rows' type or in the type it is kept in (float32 masters): `moe_gmm`
+    rounds it a block at a time as `w.astype(x.dtype)` ahead of the call
+    would, and w's gradient comes back in w's type, rounded to the rows':
+    the same bits either way."""
     if interpret is None:
         interpret = attention._default_interpret()
     return _make_grouped_matmul(interpret)(x, w, plan)

@@ -532,11 +532,28 @@ def test_head_compiles_with_three_vocabulary_matmuls(v5e, widths, batch, seq,
     assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp
 
 
-def test_grouped_matmul_kernels_compile_for_v5e(v5e):
-    """olmoe_train_1chip's two grouped-matmul kernels, forward and both
-    gradients, at the cell's shape: 2 x 4096 tokens x 8 experts a token in
-    256-row tiles, 64 experts of 2048 x 1024, a whole expert matrix a block
-    (over the default 16 MB of scoped VMEM, hence their limit). The rest of
+# (tokens, experts a token, groups, rows expected here, d, an expert's width)
+GROUPED_SHAPES = {
+    # olmoe_train_1chip: 2 x 4096 tokens x 8 over all 64 experts
+    "olmoe": (2 * 4096, 8, 64, 2 * 4096 * 8, 2048, 1024),
+    # lfm2_train_1chip: 8 of 64 held, x 4 a token; the widest expert (1536:
+    # blocks of 768 and 1024 columns)
+    "lfm2": (2 * 8192, 4, 8, 8192, 2048, 1536),
+    # laguna_train_1chip: 32 of 256 held, x 8 a token; the narrowest (512)
+    "laguna": (2 * 8192, 8, 32, 2 * 8192, 2048, 512),
+}
+
+
+@pytest.mark.parametrize("matrices", ["bfloat16", "float32"])
+@pytest.mark.parametrize("cell", list(GROUPED_SHAPES))
+def test_grouped_matmul_kernels_compile_for_v5e(v5e, cell, matrices):
+    """A cell's two grouped-matmul kernels, forward and both gradients, at
+    the cell's shape (olmoe: 2 x 4096 tokens x 8 experts a token in 256-row
+    tiles, 64 experts of 2048 x 1024), gate / up and down, a whole expert
+    matrix a block. With float32 masters the block lands at 4 bytes an
+    element (8 MB at olmoe) beside its rounded copy (4 MB), with the rows
+    and the result twice: near the default 16 MB of scoped VMEM and inside
+    the kernels' own limit, which is what compiling here shows. The rest of
     the dispatch is sorts and gathers, plain XLA."""
     import jax
     import jax.numpy as jnp
@@ -548,25 +565,32 @@ def test_grouped_matmul_kernels_compile_for_v5e(v5e):
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    slots, experts = 2 * 4096 * 8, 64
+    tokens, k, experts, slots, d, width = GROUPED_SHAPES[cell]
     rows = moe.tile_rows(slots, experts, jnp.bfloat16)
     tiles = slots // rows + experts
     plan = moe.Plan(shape((tiles * rows,), jnp.int32),
-                    shape((2 * 4096, 8), jnp.int32),
+                    shape((tokens, k), jnp.int32),
                     shape((tiles,), jnp.int32), shape((1,), jnp.int32))
 
-    def grads(x, w, plan):
+    def grads(x, w_up, w_down, plan):
         # squared, so that the gradients need the forward's result
-        return jax.grad(lambda x, w: (moe.grouped_matmul(
-            x, w, plan, interpret=False).astype(jnp.float32) ** 2).sum(),
-            argnums=(0, 1))(x, w)
+        def loss(x, w_up, w_down):
+            up = moe.grouped_matmul(x, w_up, plan, interpret=False)
+            return (moe.grouped_matmul(up, w_down, plan, interpret=False)
+                    .astype(jnp.float32) ** 2).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(x, w_up, w_down)
 
     text = jax.jit(grads).lower(
-        shape((tiles * rows, 2048), jnp.bfloat16),
-        shape((experts, 2048, 1024), jnp.bfloat16), plan).compile().as_text()
-    # forward, the rows' gradient, the matrices' gradient
-    assert text.count("tpu_custom_call") >= 3
-    assert "moe_gmm" in text and "moe_tgmm" in text
+        shape((tiles * rows, d), jnp.bfloat16),
+        shape((experts, d, width), matrices),
+        shape((experts, width, d), matrices), plan).compile().as_text()
+    # forward and the rows' gradient of each, the matrices' gradients
+    assert len(_kernel_ops(text, "moe_gmm")) == 4
+    tgmm = _kernel_ops(text, "moe_tgmm")
+    assert len(tgmm) == 2
+    # the matrices' gradients leave their kernel in the rows' type
+    assert any(f" = bf16[{experts},{d},{width}]" in op for op in tgmm)
+    assert any(f" = bf16[{experts},{width},{d}]" in op for op in tgmm)
 
 
 # (configuration, rows a tile, tiles of the share's bounded row space, tiles
@@ -657,6 +681,15 @@ def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, name,
 # (configuration, kernel calls of the compiled step, arguments + temporaries
 # as a share of the chip's 16.91 GB)
 CELL_STEPS = [
+    # olmoe_train_1chip (2 x 4096 tokens): one layer, all 64 experts held,
+    # so no conditional and every kernel once: 3 grouped matmuls forward, 3
+    # recomputed, 3 for the rows' gradients, 3 tgmm; the float32 masters
+    # reach `moe_gmm` as they are kept (PR 42). 11.2 GB when this was
+    # written: 7.51 of state, 3.7 of temporaries.
+    ("olmoe-1b-7b", {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+                     "rope_split": 6, "rope_merge": 3, "moe_gmm": 9,
+                     "moe_tgmm": 3},
+     (0.55, 0.75)),
     # kanana2_train_1chip: 5 layers of latent attention at q.k 192 padded
     # to 256 / v 128, one dense and four sparse with 16 of 128 experts held.
     # 5 layers x (forward, kept through the remat, + dQ + dK/dV) flash
@@ -720,8 +753,9 @@ CELL_STEPS = [
                          ids=[c[0] for c in CELL_STEPS])
 def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
                                                    kernel_calls, share):
-    """A one-chip cell's whole step (2 x 8192 tokens, adamw over fp32
-    masters) for one described chip: every Mosaic call lays out, the
+    """A one-chip cell's whole step (the cell's own traffic: 2 x 8192
+    tokens, 2 x 4096 at olmoe; adamw over fp32 masters) for one described
+    chip: every Mosaic call lays out, the
     kernels are called as often as the layers say, and arguments +
     temporaries stay under the chip's 16.91 GB. Under grouped queries k and
     v exist at the key/value heads' count alone: no tensor of the step has
@@ -742,8 +776,11 @@ def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
     with open(os.path.join(root, "benchmark", "configs",
                            name + ".json")) as f:
         config = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        traffic = next(w["traffic"] for w in json.load(f)["workloads"]
+                       if w["config"] == name)
     with open(os.path.join(root, "benchmark", "traffic",
-                           "train_b2_s8192_dp.json")) as f:
+                           traffic + ".json")) as f:
         mix = json.load(f)
     monkeypatch.setattr(attention, "_default_interpret", lambda: False)
     program = model.family(config).program(config)

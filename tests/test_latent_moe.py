@@ -878,9 +878,11 @@ def test_tile_rows_follow_from_the_held_count():
 # adamw), lowered to StableHLO with locations stripped. A PR that means to
 # change OLMoE's program records the new text's hash here: the layer's remat
 # keeps the flash forward's output and lse since PR 32 (6f65ebfe..9ff
-# before it, the text of every tree from 0d59224 on).
+# before it, the text of every tree from 0d59224 on), and since PR 42 the
+# experts' float32 masters reach `moe_gmm` uncast and `combine`'s backward
+# holds g until z is there (470f200b..608e before it).
 OLMOE_STEP_SHA256 = (
-    "470f200b9d74ed64e7f41af42c04bc49c90f7e650ac9d15073dcc05a65b4608e")
+    "a8b7902ce70f6192c91bd7386c3167fe5a74a61b71f503bd12751f51fbd9dd7c")
 
 
 def test_tiny_olmoe_step_lowers_to_the_parents_text(jax_cpu):

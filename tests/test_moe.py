@@ -351,6 +351,202 @@ def test_grouped_matmul_kernels_carry_their_names(jax_cpu, kernel):
     assert kernel in KERNELS and f"name={kernel}" in jaxpr
 
 
+# The matrices in the type they are kept in: a float32 master under
+# bfloat16 rows is rounded a block at a time inside `moe_gmm`, and its
+# gradient comes back as float32, widened from `moe_tgmm`'s rounding of its
+# accumulator. Every case is the bits of `w.astype(bfloat16)` ahead of the
+# call with the gradient widened after it.
+# (routing of 48 tokens x 2 over 4 groups in 16-row tiles, held groups or
+# None for all of them, tiles of the row space or None for every slot's)
+def _masters_case(case):
+    rng = np.random.default_rng(17)
+    if case == "a_group_of_several_tiles":    # 80 slots of expert 2: 5 tiles
+        idx = np.where(rng.random((48, 2)) < 0.8, 2,
+                       rng.integers(0, 4, (48, 2)))
+        return idx.astype(np.int32), False, None
+    if case == "groups_of_one_tile":          # 3 slots an expert
+        return (np.arange(12, dtype=np.int32).reshape(6, 2) % 4), False, None
+    if case == "unused_tiles_at_the_end":     # 10 tiles laid out, 4 used
+        return np.tile(np.array([[0, 3]], np.int32), (4, 1)), False, 10
+    # a share: experts 0..3 of 16 held, the bounded row space
+    return rng.integers(0, 16, (48, 2)).astype(np.int32), True, 8
+
+
+@pytest.mark.parametrize("case", [
+    "a_group_of_several_tiles", "groups_of_one_tile",
+    "unused_tiles_at_the_end", "a_shares_partial_order"])
+@pytest.mark.parametrize("kdim,n", [(64, 256), (256, 64)],
+                         ids=["gate_up", "down"])
+def test_float32_masters_give_the_bits_of_their_bfloat16_copies(
+        jax_cpu, monkeypatch, kdim, n, case):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+    # blocks of 128 columns: two column blocks of the wide side, forward
+    # (gate_up) and in the rows' gradient (down)
+    monkeypatch.setattr(moe, "_WEIGHT_BLOCK_BYTES", 0)
+    idx, partial, tiles = _masters_case(case)
+    order = moe.order_slots(jnp.asarray(idx), 4, 16, partial=partial)
+    plan = moe.lay_out(order, 16, tiles or moe._every_slot(idx.size, 4, 16))
+    used = int(plan.tiles_used[0])
+    assert used <= plan.tile_group.shape[0]
+    if case == "a_group_of_several_tiles":
+        assert int(np.sum(np.asarray(plan.tile_group)[:used] == 2)) >= 4
+    if case == "unused_tiles_at_the_end":
+        assert used < plan.tile_group.shape[0] - 2
+    rng = np.random.default_rng(23)
+    x = moe.dispatch(jnp.asarray(rng.standard_normal((idx.shape[0], kdim)),
+                                 jnp.bfloat16), plan)
+    w = jnp.asarray(rng.standard_normal((4, kdim, n)), jnp.float32)
+    g = jnp.asarray(rng.standard_normal((x.shape[0], n)), jnp.bfloat16)
+
+    def run(x, w):
+        y, pull = jax.vjp(
+            lambda x, w: moe.grouped_matmul(x, w, plan, interpret=True), x, w)
+        return (y,) + pull(g)
+    y, dx, dw = jax.jit(run)(x, w)
+    y_copy, dx_copy, dw_copy = jax.jit(run)(x, w.astype(jnp.bfloat16))
+    assert dw.dtype == jnp.float32 and dw_copy.dtype == jnp.bfloat16
+    rows = used * 16        # the tiles past the last used one are not written
+    for ours, copys in ((y[:rows], y_copy[:rows]), (dx[:rows], dx_copy[:rows]),
+                        (dw, dw_copy.astype(jnp.float32))):
+        assert ours.dtype == copys.dtype
+        np.testing.assert_array_equal(
+            np.asarray(ours.astype(jnp.float32)),
+            np.asarray(copys.astype(jnp.float32)))
+    assert float(jnp.abs(dw).sum()) > 0 and float(jnp.abs(y[:rows]).sum()) > 0
+
+
+@pytest.mark.parametrize("held", [None, (4, 16)],
+                         ids=["all_the_experts", "a_share"])
+def test_the_sparse_block_on_masters_is_the_block_on_their_copies(jax_cpu,
+                                                                  held):
+    """`_experts` whole (dispatch, the three grouped matmuls with SwiGLU
+    between, the weighted return; for a share through `in_row_space`'s
+    conditional and its recomputing backward): float32 masters in, the
+    value, the rows' and the weights' gradients and the three matrices'
+    gradients are those of bfloat16 copies made ahead of it, bit for bit."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import _experts
+    e, of, k, d, f = 4, 4 if held is None else held[1], 2, 128, 256
+    rng = np.random.default_rng(29)
+    x = jnp.asarray(rng.standard_normal((2, 48, d)), jnp.bfloat16)
+    idx = jnp.asarray(np.argsort(rng.random((2, 48, of)), axis=2)[..., :k]
+                      .astype(np.int32))
+    weights = jnp.asarray(rng.random((2, 48, k)), jnp.float32)
+    mats = [jnp.asarray(rng.standard_normal(s) / 8, jnp.float32)
+            for s in ((e, d, f), (e, d, f), (e, f, d))]
+
+    def run(x, weights, *mats):
+        def block(x, weights, *mats):
+            y = _experts(x, weights, idx, *mats, held=held)
+            return y if held is None else y[0]
+        y, pull = jax.vjp(block, x, weights, *mats)
+        return (y,) + pull(jnp.ones_like(y))
+    ours = jax.jit(run)(x, weights, *mats)
+    copies = jax.jit(run)(x, weights, *[w.astype(jnp.bfloat16) for w in mats])
+    assert all(g.dtype == jnp.float32 for g in ours[3:])
+    assert all(g.dtype == jnp.bfloat16 for g in copies[3:])
+    for a, b in zip(ours, copies):
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+        assert float(jnp.abs(a.astype(jnp.float32)).sum()) > 0
+
+
+def _kernel_calls(jax, jaxpr, found=None):
+    """{kernel name: [scratch operands of each call]} of a jaxpr, through
+    its sub-jaxprs but not into the kernels' own."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.setdefault(eqn.params["name"], []).append(
+                eqn.params["grid_mapping"].num_scratch_operands)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _kernel_calls(jax, sub, found)
+    return found
+
+
+@pytest.mark.parametrize("matrices,scratch", [("bfloat16", 0),
+                                              ("float32", 3)])
+def test_matrices_of_the_rows_type_run_the_kernel_as_it_was(jax_cpu, matrices,
+                                                            scratch):
+    """No rounded block where there is nothing to round: bfloat16 matrices
+    under bfloat16 rows (a model kept in bfloat16) get `moe_gmm` without a
+    scratch, forward and in the rows' gradient; float32 masters get the
+    block as it is kept, its rounded copy and the fetch's semaphore."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+    plan = moe.plan_dispatch(jnp.zeros((8, 1), jnp.int32), 2, 16)
+    x = jnp.zeros((plan.row_slot.shape[0], 16), jnp.bfloat16)
+    w = jnp.zeros((2, 16, 16), matrices)
+    calls = _kernel_calls(jax, jax.make_jaxpr(jax.grad(
+        lambda x, w: moe.grouped_matmul(x, w, plan).astype(
+            jnp.float32).sum(), argnums=(0, 1)))(x, w).jaxpr)
+    assert calls == {"moe_gmm": [scratch, scratch], "moe_tgmm": [1]}
+
+
+def _master_casts(jax, jaxpr, shapes, inside=(), found=None):
+    """The primitives around every cast of a float32 operand of one of
+    `shapes` to bfloat16 in a jaxpr, the kernels' own bodies left out."""
+    import jax.numpy as jnp
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        aval = eqn.invars[0].aval if eqn.invars else None
+        if (eqn.primitive.name == "convert_element_type"
+                and eqn.params["new_dtype"] == jnp.bfloat16
+                and aval.dtype == jnp.float32 and aval.shape in shapes):
+            found.append(inside)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _master_casts(jax, sub, shapes, inside + (eqn.primitive.name,),
+                          found)
+    return found
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+@pytest.mark.parametrize("config", ["tiny-olmoe", "tiny-lfm2"])
+def test_the_masters_are_cast_only_where_they_are_gathered(jax_cpu, config,
+                                                           devices):
+    """On one device the step holds no cast of the experts' float32 masters
+    to bfloat16 outside the kernels (all the experts held, and a share):
+    `moe_gmm` reads them as they are kept. Under a mesh of two the matrices
+    are handed whole to every device, and the cast stays ahead of the
+    `shard_map`: three a sparse layer, none inside it."""
+    import json
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark import model
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+    with open(os.path.join(ROOT, "benchmark", "rehearsal", "configs",
+                           config + ".json")) as f:
+        config = json.load(f)
+    program = model.family(config).program(config)
+    mesh = build_mesh(MeshConfig(data=devices),
+                      devices=jax.devices()[:devices])
+    strategy = strategy_from_name("dp")
+    params = jax.eval_shape(lambda: program.init(jax.random.PRNGKey(0)))
+    sparse = [layer["moe"] for layer in params["layers"] if "moe" in layer]
+    masters = [m[name] for m in sparse
+               for name in ("w_gate", "w_up", "w_down")]
+    assert masters and all(w.dtype == jnp.float32 for w in masters)
+    # six rows: no activation has the shape of a stack of matrices
+    batch = {"tokens": jax.ShapeDtypeStruct((6, 129), jnp.int32)}
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, b: program.loss(
+        p, b, mesh, strategy.activation_sharding(mesh))))(params, batch)
+    casts = _master_casts(jax, jaxpr.jaxpr, {w.shape for w in masters})
+    if devices == 1:
+        assert casts == []
+    else:
+        # the forward's and, under the layer's remat, the recomputation's
+        assert len(casts) >= 3 * len(sparse)
+        assert not any("shard_map" in inside for inside in casts)
+
+
 def test_run_sum_kernel_carries_its_name(jax_cpu):
     """A share's bounded row space sums a token's rows in `moe_run_sum`,
     in combine's forward and in dispatch's backward."""
