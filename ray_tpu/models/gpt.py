@@ -42,6 +42,7 @@ from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT, flash_attention,
 from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
                               rope_frequencies, rope_split, rope_table)
 from ray_tpu.ops.short_conv import short_conv
+from ray_tpu.parallel.sharding import MESH_AXES
 
 
 @dataclass(frozen=True)
@@ -456,7 +457,8 @@ def _per_shard(fn, mesh, in_dims, out_dims):
     """fn, entered as a Mosaic kernel call has to be. On a TPU the Pallas
     kernels are Mosaic custom calls, which GSPMD cannot partition, so under
     a mesh of more than one device they run per shard, inside a shard_map:
-    batch over 'data' x 'fsdp', whole heads over 'tensor', anything else
+    the batch and whole heads over the axes parallel/sharding.py's
+    MESH_AXES gives them ('data' x 'fsdp', 'tensor'), anything else
     handed whole, each device on its own slice with no collective. in_dims
     (one tuple an operand) and out_dims (one tuple, or for a tuple of
     results one each) say which dimension is which: "batch", "heads" or
@@ -464,12 +466,11 @@ def _per_shard(fn, mesh, in_dims, out_dims):
     own) nothing is wrapped."""
     if mesh is None or mesh.size == 1:
         return fn
-    axes = {"batch": ("data", "fsdp"), "heads": "tensor", None: None}
 
     def spec(dims):
         if dims and isinstance(dims[0], tuple):
             return tuple(map(spec, dims))
-        return P(*(axes[d] for d in dims))
+        return P(*(MESH_AXES[d] for d in dims))
     # check_vma off: pallas_call declares no varying axes for its outputs,
     # and the Pallas interpreter the CPU tests use fails the check inside.
     return shard_map(fn, mesh=mesh, in_specs=tuple(map(spec, in_dims)),
@@ -533,7 +534,7 @@ def _selected_attention(q, k, v, table, index, cfg: GPTConfig, mesh):
     D], the KL [shards], the selected pairs over the causal pairs
     [shards])."""
     qi, ki, w, index_table = index
-    if mesh is not None and mesh.shape.get("tensor", 1) > 1:
+    if mesh is not None and mesh.shape.get(MESH_AXES["heads"], 1) > 1:
         raise ValueError(
             "an indexer's selection is the same for every head of a token: "
             "its scores are a sum over index heads that 'tensor' > 1 would "
@@ -749,7 +750,8 @@ def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
     dt = cfg.dtype
     spec = cfg.rope_of(kind)
     window = cfg.attention_window if kind == "window" else None
-    tensor = 1 if where.mesh is None else where.mesh.shape.get("tensor", 1)
+    tensor = (1 if where.mesh is None
+              else where.mesh.shape.get(MESH_AXES["heads"], 1))
     if cfg.kv_heads % tensor:
         raise ValueError(f"n_kv_heads={cfg.kv_heads} is not whole key/value "
                          f"heads over tensor={tensor}")
@@ -962,7 +964,11 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
     space held the routing at hand (the others ran every slot's, the same
     arithmetic: moe.in_row_space), the constant 1.0 where all the experts
     are held. With cfg.n_shared_experts a dense SwiGLU of every token is
-    added (scope `moe_shared`)."""
+    added (scope `moe_shared`). Scope `moe` (layer_fn's, around this) keeps
+    the experts' own arithmetic: grouped matmuls, SwiGLU, the casts of
+    their matrices; `moe_route`, nested, what exists only because the layer
+    is sparse: router, top-k, ordering, the gathers either side, both
+    router losses."""
     dt = cfg.dtype
     m = layer["moe"]
     with jax.named_scope("moe_route"):

@@ -33,9 +33,10 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.models.gpt import (GPTConfig, Setting, final_norm,
+from ray_tpu.models.gpt import (GPTConfig, Setting, final_norm, gpt_init,
                                 head_xent_recompute, layer_fn)
-from ray_tpu.parallel.sharding import ShardingStrategy
+from ray_tpu.parallel.sharding import (MESH_AXES, ShardingStrategy,
+                                       _path_str)
 
 
 def gpt_params_to_pp(params: Dict) -> Dict:
@@ -58,6 +59,55 @@ def pp_params_to_gpt(pp_params: Dict, n_layers: int) -> Dict:
     return out
 
 
+def _refuse_what_a_stage_cannot_run(cfg: GPTConfig, mesh: Mesh,
+                                    strategy: ShardingStrategy):
+    """The schedule scans one stack of identical layers, carries the
+    residual stream alone from stage to stage, and cuts over 'tensor' what
+    `strategy` has a rule for. Whether the model fits is read off what
+    gpt_init and the block make of cfg (shapes only, nothing computed), so
+    a mechanism the schedule has never heard of is refused by what it
+    does."""
+    def leaves(tree):
+        return {_path_str(path): (leaf.shape, leaf.dtype) for path, leaf
+                in jax.tree_util.tree_flatten_with_path(tree)[0]}
+    layers = jax.eval_shape(
+        lambda: gpt_init(jax.random.PRNGKey(0), cfg))["layers"]
+    first = leaves(layers[0])
+    for i, layer in enumerate(layers[1:], 1):
+        other = leaves(layer)
+        differ = sorted(path for path in first.keys() | other.keys()
+                        if first.get(path) != other.get(path))
+        if differ:
+            raise ValueError(
+                f"layer {i}'s parameters are not layer 0's ({', '.join(differ)}"
+                " differ): the pipeline preset scans one stack of identical "
+                "layers")
+    x = jax.ShapeDtypeStruct((1, cfg.max_seq, cfg.d_model), cfg.dtype)
+    _, stats = jax.eval_shape(layer_fn(cfg, cfg.max_seq, Setting()),
+                              x, layers[0])
+    if stats:
+        raise ValueError(
+            f"the block hands back statistics ({', '.join(sorted(stats))}): "
+            "the pipeline's scan carries no loss of a layer's own, and a "
+            "stage's statistics do not reach the last rank's loss")
+    tensor_axis = MESH_AXES["heads"]
+    if mesh.shape.get(tensor_axis, 1) > 1:
+        stacked = {"stacked": jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                (cfg.n_layers,) + leaf.shape, leaf.dtype), layers[0])}
+        # (a spec has its leaf's rank: behind the layer dimension a matrix
+        # has three entries or more, a scale two)
+        whole = [_path_str(path)[len("stacked/"):] for path, sharding
+                 in jax.tree_util.tree_flatten_with_path(
+                     strategy.param_shardings(mesh, stacked))[0]
+                 if len(sharding.spec) > 2 and tensor_axis not in sharding.spec]
+        if whole:
+            raise ValueError(
+                f"ShardingStrategy.{strategy.name}() has no rule for "
+                f"{', '.join(whole)}: every shard of '{tensor_axis}' would "
+                "hold them whole and none its heads' part")
+
+
 def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
     """Build loss_fn(pp_params, batch) running the GPipe schedule.
 
@@ -65,39 +115,11 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
     The per-data-shard batch must divide num_microbatches.
     """
     n_stages = mesh.shape["pipeline"]
-    tp = mesh.shape.get("tensor", 1)
+    tensor_axis = MESH_AXES["heads"]
+    tp = mesh.shape.get(tensor_axis, 1)
     if cfg.n_layers % n_stages != 0:
         raise ValueError(
             f"n_layers={cfg.n_layers} not divisible by pipeline={n_stages}")
-    if cfg.dense_layers:
-        raise ValueError(
-            f"dense_layers={cfg.dense_layers}: the pipeline preset scans "
-            "one stack of identical layers and has no layer pattern")
-    kinds = set(cfg.layer_kinds or ())
-    if len(kinds) > 1:
-        raise ValueError(
-            f"layer_kinds={cfg.layer_kinds}: the pipeline preset scans one "
-            "stack of identical layers and has no two kinds of layer")
-    if "window" in kinds or cfg.attention_gate:
-        raise ValueError(
-            "the pipeline preset has no sliding-window layers (its scan "
-            "carries no period of kinds, head counts or rope tables) and "
-            "no rule for a gate a head (attn/wg)")
-    if cfg.index_topk:
-        raise ValueError(
-            "the pipeline preset has no indexer (index_topk > 0): its scan "
-            "carries no loss of a layer's own, and a stage's statistics do "
-            "not reach the last rank's loss")
-    if "conv" in kinds and tp > 1:
-        raise ValueError("ShardingStrategy.pp_tp() has no rule for conv/w_in, "
-                         "conv/filter and conv/w_out")
-    if cfg.n_experts > 0:
-        raise ValueError("pipeline preset supports dense MLP layers (use "
-                         "'ep' compositions for MoE)")
-    if cfg.kv_latent_dim and tp > 1:
-        raise ValueError("ShardingStrategy.pp_tp() has no rule for latent "
-                         "attention's attn/w_kva, attn/w_kvb and "
-                         "attn/kv_norm")
     if cfg.n_heads % tp != 0:
         raise ValueError(f"n_heads={cfg.n_heads} not divisible by tp={tp}")
     if cfg.kv_heads % tp != 0:
@@ -107,10 +129,18 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
     dt = cfg.dtype
     if tp > 1:
         strategy = ShardingStrategy.pp_tp()
-        where = Setting(psum=lambda y: lax.psum(y, "tensor"))
+        where = Setting(psum=lambda y: lax.psum(y, tensor_axis))
     else:
         strategy = ShardingStrategy.pp()
         where = Setting()
+    _refuse_what_a_stage_cannot_run(cfg, mesh, strategy)
+    # (what those observations let through and nothing has shown right: a
+    # stack of window layers alone, a gate a head on one shard of 'tensor')
+    if "window" in (cfg.layer_kinds or ()) or cfg.attention_gate:
+        raise ValueError(
+            "the pipeline preset has no sliding-window layers (its scan "
+            "carries no period of kinds, head counts or rope tables) and "
+            "no rule for a gate a head (attn/wg)")
 
     def body(params, inputs, targets):
         # Per-device blocks: params["stacked"] [L/S, ...] (+tensor-sharded

@@ -7,6 +7,9 @@ parameter pytree + a batch sharding, compiled by XLA/GSPMD — no runtime
 process-group object.
 
 Rules match on the parameter's path (joined with '/'); first match wins.
+The transformer's (ray_tpu.models.gpt) are written once, as one table of
+paths and the role of each dimension (_TRANSFORMER); `tp`, `tp_fsdp` and
+`pp_tp` say which mesh axes the roles mean.
 """
 
 from __future__ import annotations
@@ -62,6 +65,94 @@ def _truncate_spec(spec: P, shape: Tuple[int, ...]) -> P:
     return P(*parts)
 
 
+# What a dimension's role means on the mesh, for whoever cuts by role: the
+# presets below, a Mosaic kernel entered per shard (models/gpt.py:
+# _per_shard) and a pipeline stage's psum (parallel/pipeline.py).
+MESH_AXES = {"batch": ("data", "fsdp"), "heads": "tensor",
+             "expert": "expert", None: None}
+
+# How a transformer's parameters (ray_tpu.models.gpt) are divided: path ->
+# the role of each dimension, first match wins (a window layer's
+# `window_attn/..` finds the `attn/..` rows). A preset says which mesh axes
+# the roles mean (MESH_AXES and, of its own, "model" and "vocab"); a row
+# with a role the preset does not know is not the preset's. Roles:
+#   "model"   the d_model side of a matrix (for w_kvb the latent's): what
+#             'fsdp' takes under tp_fsdp, whole under tp
+#   "heads"   whole heads, an MLP's columns, a convolution's channels
+#   "expert"  the experts of a stack
+#   "vocab"   embed/table's rows
+#   None      not divided; a row of no roles leaves every dimension whole
+# A new matrix takes one row here. What has no row falls to the preset's
+# default (P() under tp, FSDP_LARGEST under tp_fsdp), which is meant for
+# scales of d_model: tests/test_param_specs.py lists what may land there.
+_COLUMN, _ROW = ("model", "heads"), ("heads", "model")
+# The rows a stage of parallel/pipeline.py cuts too: the block finishes
+# their sums over 'tensor' itself where it is told how (models/gpt.py:
+# Setting.psum). `columns_scale` is a role of pp_tp alone: under GSPMD the
+# q/k norm's scale falls to the default like any other scale.
+_STAGE_ROWS = (
+    (r"attn/(wq|wk|wv)", _COLUMN),
+    (r"attn/(q|k)_norm", ("columns_scale",)),
+    (r"attn/wo", _ROW),
+    (r"mlp/(w_up|w_gate)", _COLUMN),
+    (r"mlp/w_down", _ROW),
+)
+_TRANSFORMER = _STAGE_ROWS + (
+    # the gate a head: its columns are heads
+    (r"attn/wg", _COLUMN),
+    # an indexer: whole index heads of wq's columns; its one key head, that
+    # head's norm and the heads' weights are not cut over 'tensor' (the
+    # scores' sum over the heads would then be a psum, which models/gpt.py
+    # does not build: it refuses 'tensor' > 1)
+    (r"attn/index/wq", _COLUMN),
+    (r"attn/index/(wk|ww)", ("model", None)),
+    (r"attn/index/k_norm", ()),
+    # latent attention: whole heads of the up-projection's columns; the
+    # down-projection, its norm and the shared rotated key part are not
+    # divided
+    (r"attn/w_kvb", _COLUMN),
+    (r"attn/(w_kva|kv_norm)", ()),
+    # one scale of head_dim for all the heads
+    (r"attn/(q|k)_head_norm", ()),
+    # the short convolution: w_in is its three chunks [3, d, d], each
+    # column-parallel, so a shard holds the same channels of B, C and X,
+    # its channels' filters and w_out's rows
+    (r"conv/w_in", (None, "model", "heads")),
+    (r"conv/filter", ("heads", None)),
+    (r"conv/w_out", _ROW),
+    # Vocab over both axes under tp_fsdp, d_model replicated: a d-sharded
+    # gather output cannot transition to batch-sharded activations without
+    # an involuntary full rematerialization (permuted tile order), while a
+    # vocab-sharded gather resolves via masked lookup + all-reduce and
+    # reshards to the batch spec cheaply.
+    (r"embed/table", ("vocab", None)),
+    # (its vocabulary is columns like any other: over 'tensor' alone)
+    (r"lm_head", _COLUMN),
+    # the shared expert is a dense MLP (two dimensions), not a stack of
+    # experts
+    (r"moe/shared/(w_up|w_gate)", _COLUMN),
+    (r"moe/shared/w_down", _ROW),
+    (r"moe/router_bias", ()),
+    (r"moe/.*w_(gate|up)", ("expert",) + _COLUMN),
+    (r"moe/.*w_down", ("expert",) + _ROW),
+    (r"moe/router", ()),
+)
+
+
+def _transformer_rules(axes: Dict, rows=_TRANSFORMER,
+                       stacked: Optional[str] = None) -> List[Tuple[str, P]]:
+    """The table x a preset's map of roles to mesh axes; with `stacked`,
+    behind a leading layer dimension cut over that axis."""
+    rules = []
+    for pattern, roles in rows:
+        if all(role in axes for role in roles):
+            spec = tuple(axes[role] for role in roles)
+            if stacked:
+                pattern, spec = "stacked/" + pattern, (stacked,) + spec
+            rules.append((pattern, P(*spec)))
+    return rules
+
+
 class ShardingStrategy:
     """A named parallelism strategy = param rules + batch spec + remat policy.
 
@@ -99,82 +190,20 @@ class ShardingStrategy:
     def tp_transformer() -> "ShardingStrategy":
         """Megatron TP for the transformer layout in ray_tpu.models.gpt:
         column-parallel qkv/up projections, row-parallel out/down."""
-        t = "tensor"
-        rules = ShardingRules(rules=[
-            # (a window layer's `window_attn/..` finds the same rules)
-            (r"attn/(wq|wk|wv)", P(None, t)),
-            (r"attn/wo", P(t, None)),
-            # the gate a head: its columns are heads
-            (r"attn/wg", P(None, t)),
-            # an indexer: whole index heads of wq's columns; its one key
-            # head, that head's norm and the heads' weights are not divided
-            # (the scores' sum over the heads would then be a psum, which
-            # models/gpt.py does not build: it refuses 'tensor' > 1)
-            (r"attn/index/wq", P(None, t)),
-            (r"attn/index/(wk|k_norm|ww)", P()),
-            # latent attention: whole heads of the up-projection's columns;
-            # the down-projection, its norm and the shared rotated key
-            # part are not divided
-            (r"attn/w_kvb", P(None, t)),
-            (r"attn/(w_kva|kv_norm)", P()),
-            # one scale of head_dim for all the heads
-            (r"attn/(q|k)_head_norm", P()),
-            # the short convolution: w_in is its three chunks [3, d, d],
-            # each column-parallel, so a shard holds the same channels of
-            # B, C and X, its channels' filters and w_out's rows
-            (r"conv/w_in", P(None, None, t)),
-            (r"conv/filter", P(t, None)),
-            (r"conv/w_out", P(t, None)),
-            (r"mlp/(w_up|w_gate)", P(None, t)),
-            (r"mlp/w_down", P(t, None)),
-            (r"embed/table", P(t, None)),
-            (r"lm_head", P(None, t)),
-            # the shared expert is a dense MLP (two dimensions), not a
-            # stack of experts
-            (r"moe/shared/(w_up|w_gate)", P(None, t)),
-            (r"moe/shared/w_down", P(t, None)),
-            (r"moe/router_bias", P()),
-            (r"moe/.*w_(gate|up)", P("expert", None, t)),
-            (r"moe/.*w_down", P("expert", t, None)),
-            (r"moe/router", P(None, None)),
-        ], default=P())
-        return ShardingStrategy("tp", rules, P("data"))
+        axes = {**MESH_AXES, "model": None, "vocab": MESH_AXES["heads"]}
+        return ShardingStrategy(
+            "tp", ShardingRules(_transformer_rules(axes), default=P()),
+            P("data"))
 
     @staticmethod
     def tp_fsdp() -> "ShardingStrategy":
         """2D: TP inner + FSDP outer on the complementary dim."""
-        t = "tensor"
-        f = "fsdp"
-        rules = ShardingRules(rules=[
-            (r"attn/(wq|wk|wv)", P(f, t)),
-            (r"attn/wo", P(t, f)),
-            (r"attn/wg", P(f, t)),
-            (r"attn/index/wq", P(f, t)),
-            (r"attn/index/(wk|ww)", P(f, None)),
-            (r"attn/index/k_norm", P()),
-            (r"attn/w_kvb", P(f, t)),
-            (r"attn/(w_kva|kv_norm)", P()),
-            (r"attn/(q|k)_head_norm", P()),
-            (r"conv/w_in", P(None, f, t)),
-            (r"conv/filter", P(t, None)),
-            (r"conv/w_out", P(t, f)),
-            (r"mlp/(w_up|w_gate)", P(f, t)),
-            (r"mlp/w_down", P(t, f)),
-            # Vocab over both axes, d_model replicated: a d-sharded gather
-            # output cannot transition to batch-sharded activations without
-            # an involuntary full rematerialization (permuted tile order),
-            # while a vocab-sharded gather resolves via masked lookup +
-            # all-reduce and reshards to the batch spec cheaply.
-            (r"embed/table", P((t, f), None)),
-            (r"lm_head", P(f, t)),
-            (r"moe/shared/(w_up|w_gate)", P(f, t)),
-            (r"moe/shared/w_down", P(t, f)),
-            (r"moe/router_bias", P()),
-            (r"moe/.*w_(gate|up)", P("expert", f, t)),
-            (r"moe/.*w_down", P("expert", t, f)),
-            (r"moe/router", P(None, None)),
-        ], default=FSDP_LARGEST)
-        return ShardingStrategy("tp_fsdp", rules, P(("data", "fsdp")))
+        axes = {**MESH_AXES, "model": "fsdp",
+                "vocab": (MESH_AXES["heads"], "fsdp")}
+        return ShardingStrategy(
+            "tp_fsdp",
+            ShardingRules(_transformer_rules(axes), default=FSDP_LARGEST),
+            P(MESH_AXES["batch"]))
 
     @staticmethod
     def pp() -> "ShardingStrategy":
@@ -186,20 +215,17 @@ class ShardingStrategy:
 
     @staticmethod
     def pp_tp() -> "ShardingStrategy":
-        """Pipeline outer + Megatron tensor parallel inside each stage."""
-        t = "tensor"
-        pl = "pipeline"
-        rules = ShardingRules(rules=[
-            (r"stacked/attn/(wq|wk|wv)", P(pl, None, t)),
-            # the q/k norm runs over the projection's columns: its scale
-            # is cut as they are
-            (r"stacked/attn/(q|k)_norm", P(pl, t)),
-            (r"stacked/attn/wo", P(pl, t, None)),
-            (r"stacked/mlp/(w_gate|w_up)", P(pl, None, t)),
-            (r"stacked/mlp/w_down", P(pl, t, None)),
-            (r"stacked/", PP_STACKED),
-        ], default=P())
-        return ShardingStrategy("pp_tp", rules, P("data"))
+        """Pipeline outer + Megatron tensor parallel inside each stage: the
+        rows of the table a stage cuts, behind the stacked layer dimension.
+        A stage holds its shard and no partitioner, so the scale of a norm
+        over a cut projection's columns is cut as they are."""
+        axes = {**MESH_AXES, "model": None,
+                "columns_scale": MESH_AXES["heads"]}
+        rules = _transformer_rules(axes, _STAGE_ROWS, stacked="pipeline")
+        return ShardingStrategy(
+            "pp_tp",
+            ShardingRules(rules + [(r"stacked/", PP_STACKED)], default=P()),
+            P("data"))
 
     @staticmethod
     def sp() -> "ShardingStrategy":

@@ -107,6 +107,8 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
         strategy = strategy_from_name(strategy)
 
     def _grads(params, batch):
+        # (the region's own row in a trace is what no scope of the model
+        # holds: residual adds, casts of the gradients)
         with jax.named_scope("loss_and_grad"):
             return jax.value_and_grad(loss_fn)(params, batch)
 

@@ -109,24 +109,14 @@ def stack_dump() -> Dict[str, str]:
 # Device time by region (jax.profiler trace + the compiled step)
 # ---------------------------------------------------------------------------
 
-# The scopes models/gpt.py and train/train_step.py open, and the names
-# ops/attention.py, ops/moe.py, ops/rope.py and ops/short_conv.py give their
-# pallas_calls. An op belongs to the LAST of these on its op_name path:
-# `jit(_step)/loss_and_grad/jvp(mlp)/dot_general` is `mlp`, and what
-# `loss_and_grad` holds itself is the rest (residual adds, casts of the
-# gradients). `moe` is the experts' own arithmetic (grouped matmuls,
-# SwiGLU, the casts of their matrices); `moe_route`, nested in it, is what
-# exists only because the layer is sparse: router, top-k, ordering, the
-# gathers either side, both router losses. `conv` is a short-convolution
-# layer's projections; `conv_mix`, nested in it, its gates and filter.
-# `attn_window`, nested in `attn_core`, is the sliding-window layers' flash
-# call (so `attn_core` keeps the full layers' alone); `attn_gate`, nested in
-# `attn_out`, the gate a head on attention's output (matmul, sigmoid,
-# product). `attn_index` is a learned sparse-attention indexer: its
-# projections, norm and rotation, the walk of ops/indexer.py (scores, each
-# row's selection, the KL's target from the main q and k, the KL and its
-# gradient) and the rope table at its head width; the attention under the
-# selection (`flash_sel_*`) stays in `attn_core`.
+# The scopes models/gpt.py and train/train_step.py open and the names
+# ray_tpu/ops/*.py give their pallas_calls: tests/test_device_regions.py
+# reads both off the code and holds these tuples to them. An op belongs to
+# the LAST of these on its op_name path
+# (`jit(_step)/loss_and_grad/jvp(mlp)/dot_general` is `mlp`), so a scope
+# nested in another (`moe_route` in `moe`, `attn_window` in `attn_core`)
+# takes its ops out of the outer one's row. What each name holds is said
+# where it is opened.
 REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_window",
            "attn_index", "attn_out", "attn_gate", "conv", "conv_mix", "mlp",
            "moe", "moe_route", "moe_shared", "norm", "head", "loss_and_grad",

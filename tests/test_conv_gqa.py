@@ -604,10 +604,10 @@ def test_the_configuration_refuses_by_name(tiny, change, says):
 
 @pytest.mark.parametrize("change,mesh_axes,says", [
     ({"n_experts": 0, "dense_layers": 0, "experts_held": None},
-     {"pipeline": 2}, "layer_kinds=.*no two kinds of layer"),
+     {"pipeline": 2}, "parameters are not layer 0's.*conv/w_in"),
     ({"layer_kinds": ("conv",) * 4, "n_experts": 0, "dense_layers": 0,
       "experts_held": None}, {"pipeline": 2, "tensor": 2},
-     "no rule for conv/w_in"),
+     "no rule for conv/filter, conv/w_in, conv/w_out"),
     ({"layer_kinds": None, "n_experts": 0, "dense_layers": 0,
       "experts_held": None}, {"pipeline": 1, "tensor": 4},
      "n_kv_heads=2 is not whole key/value heads over tp=4"),

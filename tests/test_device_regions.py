@@ -4,6 +4,7 @@ util/profiling.device_regions reduces a profiler trace plus the compiled
 step's HLO text to a table by region. All on the CPU: the v5e side is a
 recorded trace (benchmark/fixtures) and hand-made events."""
 
+import ast
 import contextlib
 import json
 import os
@@ -14,8 +15,8 @@ import pytest
 from ray_tpu.util import profiling
 from ray_tpu.util.profiling import KERNELS, REGIONS, UNATTRIBUTED
 
-FIXTURES = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "benchmark", "fixtures")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FIXTURES = os.path.join(REPO, "benchmark", "fixtures")
 DASH = "—"
 
 
@@ -50,20 +51,71 @@ def dense_text(jax_cpu):
     return _step_text(jax_cpu)
 
 
-# (a) every region of the vocabulary is on some op of a compiled step
+# (a) the vocabulary is the names the code opens, and every region of it is
+# on some op of a compiled step
 
-# (attn_latent and moe_shared: tests/test_latent_moe.py, on a step that has
-# a latent block and a shared expert; conv and conv_mix: tests/
-# test_conv_gqa.py, on a step with a short-convolution layer; attn_window
-# and attn_gate: tests/test_window_attention.py, on a step with
-# sliding-window layers and a gate a head; attn_index: tests/
-# test_selected_attention.py, on a step whose layers carry an indexer)
-@pytest.mark.parametrize("region", [r for r in REGIONS
-                                    if r not in ("moe", "moe_route",
-                                                 "grad_accum", "attn_latent",
-                                                 "moe_shared", "conv",
-                                                 "conv_mix", "attn_window",
-                                                 "attn_gate", "attn_index")])
+def _calls(path, attribute):
+    """The calls of `<anything>.<attribute>(..)` in a source file."""
+    with open(os.path.join(REPO, *path.split("/"))) as f:
+        tree = ast.parse(f.read())
+    return [node for node in ast.walk(tree) if isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr == attribute]
+
+
+def _strings(node, path):
+    """The strings a name's expression can be: a literal, either arm of
+    `a if .. else b`, or ops/attention.py's _kernel_name over its cases."""
+    if isinstance(node, ast.Constant) and isinstance(node.value, str):
+        return {node.value}
+    if isinstance(node, ast.IfExp):
+        return _strings(node.body, path) | _strings(node.orelse, path)
+    if isinstance(node, ast.Call) and getattr(node.func, "id", "") \
+            == "_kernel_name" and path.endswith("ops/attention.py"):
+        from ray_tpu.ops.attention import _kernel_name
+        (kernel,) = _strings(node.args[0], path)
+        return {_kernel_name(kernel, window, selected)
+                for window in (None, 128) for selected in (None, object())}
+    raise AssertionError(
+        f"{path}:{node.lineno}: a name this test cannot read off the code; "
+        "give it a literal, or teach _strings the function that makes it")
+
+
+def test_the_vocabulary_is_the_names_the_code_opens(jax_cpu):
+    """profiling.KERNELS and .REGIONS are a copy the traced run is read by
+    (benchmark/worker.py): a kernel or a scope missing there has no row,
+    and its metric is absent. Exactly the `name=`s of ray_tpu/ops/*.py's
+    pallas_calls and the scopes models/gpt.py and train/train_step.py
+    open, both ways."""
+    ops = sorted("ray_tpu/ops/" + name for name
+                 in os.listdir(os.path.join(REPO, "ray_tpu", "ops"))
+                 if name.endswith(".py"))
+    kernels = set()
+    for path in ops:
+        for call in _calls(path, "pallas_call"):
+            names = [k.value for k in call.keywords if k.arg == "name"]
+            assert names, f"{path}:{call.lineno}: a pallas_call of no name"
+            kernels |= _strings(names[0], path)
+    regions = set()
+    for path in ("ray_tpu/models/gpt.py", "ray_tpu/train/train_step.py"):
+        for call in _calls(path, "named_scope"):
+            regions |= _strings(call.args[0], path)
+    assert len(set(KERNELS)) == len(KERNELS)
+    assert len(set(REGIONS)) == len(REGIONS)
+    assert kernels == set(KERNELS), kernels ^ set(KERNELS)
+    assert regions == set(REGIONS), regions ^ set(REGIONS)
+    # a name on both sides would be counted as a region and as a kernel
+    assert not set(KERNELS) & set(REGIONS)
+
+
+# The regions a dense step has. (attn_latent and moe_shared: tests/
+# test_latent_moe.py, on a step that has a latent block and a shared
+# expert; conv and conv_mix: tests/test_conv_gqa.py; attn_window and
+# attn_gate: tests/test_window_attention.py; attn_index: tests/
+# test_selected_attention.py; moe, moe_route and grad_accum: below)
+@pytest.mark.parametrize("region", [
+    "embed", "attn_proj", "attn_core", "attn_out", "mlp", "norm", "head",
+    "loss_and_grad", "optimizer"])
 def test_dense_step_names_region(dense_text, region):
     names = re.findall(r'op_name="([^"]*)"', dense_text)
     assert any(profiling._last_of(n, REGIONS) == region for n in names)
@@ -94,8 +146,7 @@ def test_dense_step_names_phase(dense_text, phase):
 # (the window kernels' names: tests/test_window_attention.py; the names
 # under a selection: tests/test_selected_attention.py)
 @pytest.mark.parametrize("kernel",
-                         [k for k in KERNELS if k.startswith("flash_")
-                          and not k.startswith(("flash_win_", "flash_sel_"))])
+                         ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"])
 def test_flash_kernels_carry_their_names(jax_cpu, kernel):
     import jax.numpy as jnp
     from ray_tpu.ops.attention import flash_attention

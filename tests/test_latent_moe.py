@@ -1045,7 +1045,7 @@ def test_pipeline_refuses_a_layer_pattern_by_name(jax_cpu, tiny):
     cfg = GPTConfig(**dict(kanana.gpt_config_kwargs(tiny), n_layers=4))
     mesh = build_mesh(MeshConfig(data=1, pipeline=2),
                       devices=jax.devices()[:2])
-    with pytest.raises(ValueError, match="dense_layers=1.*layer pattern"):
+    with pytest.raises(ValueError, match="layer 1's parameters are not layer 0's.*moe/router"):
         make_gpt_pp_loss(cfg, mesh, num_microbatches=2)
 
 

@@ -950,8 +950,33 @@ def test_pipeline_refuses_by_name(jax_cpu, tiny):
                            experts_held=None, n_layers=2))
     mesh = build_mesh(MeshConfig(data=1, pipeline=1),
                       devices=jax.devices()[:1])
-    with pytest.raises(ValueError, match="no indexer"):
+    with pytest.raises(ValueError, match="hands back statistics .*index_kl"):
         make_gpt_pp_loss(cfg, mesh, num_microbatches=2)
+
+
+def test_pipeline_runs_an_indexer_no_layer_carries(jax_cpu, tiny):
+    """The pipeline refuses by what the block hands back, not by
+    index_topk: a stack of short-convolution layers alone has no attention
+    for an indexer to sit in, and runs as that stack does without the
+    field."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import keye
+    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.pipeline import gpt_params_to_pp, make_gpt_pp_loss
+    kwargs = dict(keye.gpt_config_kwargs(tiny), n_experts=0,
+                  experts_held=None, dtype=jnp.float32)
+    cfg = GPTConfig(**dict(kwargs, layer_kinds=("conv",) * kwargs["n_layers"]))
+    assert cfg.index_topk
+    params = gpt_init(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jnp.array(np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (4, 33)), jnp.int32)}
+    mesh = build_mesh(MeshConfig(data=1, pipeline=cfg.n_layers),
+                      devices=jax.devices()[:cfg.n_layers])
+    loss = make_gpt_pp_loss(cfg, mesh, num_microbatches=2)(
+        gpt_params_to_pp(params), batch)
+    assert abs(float(loss) - float(gpt_loss(params, batch, cfg))) < 1e-5
 
 
 def _kernel_calls(jax, jaxpr, rematted=False):
