@@ -12,12 +12,16 @@ latent attention (a low-rank k/v projection, q.k wider than v), optional
 grouped-query attention with a norm a head, optional gated
 short-convolution layers among the attention layers (ops/short_conv.py),
 optional sliding-window layers among the full-attention layers (a head
-count and a rotation of their own a kind, a gate a head on attention's
-output), an optional learned sparse-attention indexer on the attention
+count and a rotation of their own a kind, or no rotation at all, a gate a
+head or an element on attention's output), optional gated delta-rule
+linear-attention layers among them (ops/linear_attention.py: a state a head
+that the data decays a channel at a time and overwrites along the key), an
+optional learned sparse-attention indexer on the attention
 layers (ops/indexer.py: it chooses the keys a query sees, and is trained by
 a loss of its own),
 per-layer jax.checkpoint (remat) for memory: a layer keeps its input and
-the flash kernel's output and row statistics, and recomputes the rest.
+the flash kernel's output and row statistics (a linear-attention layer's
+output), and recomputes the rest.
 
 Capability parity target: the models RLlib/Train wrap in the reference are
 torch modules; here the model is a (init, apply) pair compatible with pjit.
@@ -39,9 +43,10 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.ops import indexer, moe
 from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT, flash_attention,
                                    mha_reference, qk_padding, ring_attention)
+from ray_tpu.ops.linear_attention import KDA_OUT, chunk_log_decay, kda
 from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
                               rope_frequencies, rope_split, rope_table)
-from ray_tpu.ops.short_conv import short_conv
+from ray_tpu.ops.short_conv import short_conv, silu_conv
 from ray_tpu.parallel.sharding import MESH_AXES
 
 
@@ -67,8 +72,8 @@ class GPTConfig:
     # RMSNorm over each head's columns of q and of k, before the rotation:
     # one learned scale of head_dim for q and one for k, shared by the heads.
     qk_head_norm: bool = False
-    # The token mixer of each layer: "attention" | "conv" | "window", one a
-    # layer. None = attention everywhere. A "conv" layer is a gated short
+    # The token mixer of each layer: "attention" | "conv" | "window" | "kda",
+    # one a layer. None = attention everywhere. A "conv" layer is a gated short
     # convolution: [B | C | X] = three projections of the normed input,
     # C * filter(B * X) with a causal depthwise filter of conv_filter taps
     # a channel, then an output projection. No bias, no activation.
@@ -84,9 +89,24 @@ class GPTConfig:
     window_heads: int = 0
     rope: Optional[RopeSpec] = None
     window_rope: Optional[RopeSpec] = None
-    # A gate a head on attention's output: sigmoid(normed input x wg
-    # [d, heads]) times the head's output, before the output projection.
-    attention_gate: bool = False
+    # False: no attention layer rotates q or k (no position enters the
+    # scores but through the causal mask), and no rope table is built.
+    use_rope: bool = True
+    # A gate on attention's output, from the normed input, before the
+    # output projection: True, a gate a head, sigmoid(x wg [d, heads])
+    # times the head's output; "element", a gate an element, wg [d, heads x
+    # head_dim]. The block reads which off wg's shape.
+    attention_gate: Any = False
+    # A "kda" layer is gated delta-rule linear attention with a decay a
+    # channel (`_kda_block`; ops/linear_attention.py): n_heads heads of
+    # head_dim for q, k and v alike, a causal depthwise filter of
+    # conv_filter taps and a SiLU on each of the three projections,
+    # q and k normalised a head, a log-decay a channel and a beta a head
+    # from the normed input (beta in (0, 1), or (0, 2) with
+    # kda_neg_eigval), the heads' outputs under an RMSNorm a head times a
+    # sigmoid gate an element, then the output projection. The decay and
+    # the gate come through low-rank pairs of rank head_dim.
+    kda_neg_eigval: bool = False
     # index_topk > 0: every attention layer carries an indexer (`attn/index`:
     # index_heads thin heads of index_head_dim on ONE key head, which has a
     # LayerNorm; both rotated whole by the layer's rotation at that width; a
@@ -166,10 +186,22 @@ class GPTConfig:
         kinds = self.layer_kinds
         if kinds is not None and (
                 len(kinds) != self.n_layers
-                or set(kinds) - {"attention", "conv", "window"}):
+                or set(kinds) - {"attention", "conv", "window", "kda"}):
             raise ValueError(
                 f"layer_kinds {kinds!r}: expected n_layers={self.n_layers} "
-                "of 'attention' | 'conv' | 'window'")
+                "of 'attention' | 'conv' | 'window' | 'kda'")
+        if self.attention_gate not in (False, True, "element"):
+            raise ValueError(f"attention_gate={self.attention_gate!r}: "
+                             "expected False | True | 'element'")
+        if "kda" in (kinds or ()) and self.attention == "ring":
+            raise ValueError(
+                "a 'kda' layer's state runs along the whole sequence: it is "
+                "not sharded over 'sequence' (attention='ring')")
+        if not self.use_rope and (self.kv_latent_dim or self.index_topk):
+            raise ValueError(
+                "use_rope=False is built for multi-head attention layers, "
+                "not for " + ("a latent block" if self.kv_latent_dim
+                              else "an indexer"))
         for heads in {self.n_heads, self.heads_of("window")}:
             if heads % self.kv_heads:
                 raise ValueError(f"n_kv_heads={self.n_kv_heads} does not "
@@ -222,8 +254,11 @@ class GPTConfig:
         """Query heads of an "attention" or a "window" layer."""
         return (self.window_heads if kind == "window" else 0) or self.n_heads
 
-    def rope_of(self, kind: str) -> RopeSpec:
-        """The rotation of an "attention" or a "window" layer."""
+    def rope_of(self, kind: str) -> Optional[RopeSpec]:
+        """The rotation of an "attention" or a "window" layer; None where
+        the layers carry none (use_rope False)."""
+        if not self.use_rope:
+            return None
         spec = self.window_rope if kind == "window" else self.rope
         return RopeSpec(theta=self.rope_theta) if spec is None else spec
 
@@ -295,6 +330,8 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
                 "w_out": _init_dense(k[3], (d, d),
                                      scale=1.0 / math.sqrt(2 * cfg.n_layers * d)),
             }
+        elif cfg.layer_kinds and cfg.layer_kinds[i] == "kda":
+            layer["kda"] = _init_kda(jax.random.fold_in(keys[i + 2], 12), cfg)
         elif cfg.kv_latent_dim:
             r, h = cfg.kv_latent_dim, cfg.n_heads
             layer["attn"] = {
@@ -323,7 +360,8 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
             if cfg.attention_gate:
                 layer[_GROUP[kind]]["wg"] = _init_dense(
                     jax.random.fold_in(keys[i + 2], 10),
-                    (d, cfg.heads_of(kind)))
+                    (d, wide if cfg.attention_gate == "element"
+                     else cfg.heads_of(kind)))
         if "attn" in layer and cfg.index_topk:
             hi, di = cfg.index_heads, cfg.index_head_dim
             ik = jax.random.split(jax.random.fold_in(keys[i + 2], 11), 3)
@@ -367,6 +405,37 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
         layers.append(layer)
     params["layers"] = layers
     return params
+
+
+def _init_kda(key, cfg: GPTConfig) -> Dict:
+    """A "kda" layer's parameters. The projections at their fan-in's scale;
+    a filter's taps at 1 / sqrt(taps); the decay's two seeded ranges as the
+    delta-rule papers publish them: exp(a_log), a head's decay rate,
+    log-uniform over 1..16, and dt_bias the inverse softplus of a step
+    log-uniform over 1e-3..0.1, a channel."""
+    d, hd, h = cfg.d_model, cfg.head_dim, cfg.n_heads
+    wide, taps = h * hd, cfg.conv_filter
+    k = jax.random.split(key, 13)
+    dt = jnp.exp(jax.random.uniform(k[11], (wide,), minval=math.log(1e-3),
+                                    maxval=math.log(0.1)))
+    layer = {
+        "wq": _init_dense(k[0], (d, wide)),
+        "wk": _init_dense(k[1], (d, wide)),
+        "wv": _init_dense(k[2], (d, wide)),
+        "wf_down": _init_dense(k[6], (d, hd)),
+        "wf_up": _init_dense(k[7], (hd, wide)),
+        "a_log": jax.random.uniform(k[10], (h,), maxval=math.log(16.0)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "w_beta": _init_dense(k[8], (d, h)),
+        "wg_down": _init_dense(k[9], (d, hd)),
+        "wg_up": _init_dense(k[12], (hd, wide)),
+        "o_norm": {"scale": jnp.ones((hd,), jnp.float32)},
+        "wo": _init_dense(k[3], (wide, d),
+                          scale=1.0 / math.sqrt(2 * cfg.n_layers * wide)),
+    }
+    filters = _init_dense(k[4], (3, wide, taps), scale=1.0 / math.sqrt(taps))
+    layer.update(q_conv=filters[0], k_conv=filters[1], v_conv=filters[2])
+    return layer
 
 
 def _whole(y):
@@ -709,8 +778,9 @@ def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting,
     [B, H, S, D] (the oracle, and ring's sequence shards need their global
     positions); a latent block (`_latent_attention`) reads it on every
     path. kind: "attention" | "window", which the layer's parameters are
-    named by. Where the layer has a gate (`wg`), scope `attn_gate` holds
-    its matmul, its sigmoid and the product with the heads' outputs."""
+    named by. Where the layer has a gate (`wg`: a column a head, or a column
+    an element of the heads' outputs), scope `attn_gate` holds its matmul,
+    its sigmoid and the product with the heads' outputs."""
     b, s, _ = x.shape
     dt = cfg.dtype
     a = layer[_GROUP[kind]]
@@ -731,7 +801,10 @@ def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting,
                 gate = jax.nn.sigmoid(jnp.einsum(
                     "bsd,dh->bsh", x, a["wg"].astype(dt),
                     preferred_element_type=jnp.float32))
-                o = (o * gate[..., None]).astype(dt)
+                # a column a head, or one an element of the heads' outputs
+                gate = (gate[..., None] if gate.shape[-1] == o.shape[2]
+                        else gate.reshape(o.shape))
+                o = (o * gate).astype(dt)
         return where.psum(jnp.einsum("bsd,de->bse", o.reshape(b, s, -1),
                                      a["wo"].astype(dt))), stats
 
@@ -742,7 +815,8 @@ def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
     attention layer's matrices; the query heads are wq's columns over
     head_dim, k and v at the key/value heads' count) -> the heads' outputs
     [B, H, S, head_dim]. kind "window": under the sliding window, and in
-    either kind the rotation is the kind's (cfg.rope_of). Where `a` has an
+    either kind the rotation is the kind's (cfg.rope_of; none where it
+    gives None: q and k go to the kernels as projected). Where `a` has an
     indexer (`index`) the result is (the heads' outputs over the keys it
     chose, its KL [shards], its selected share [shards])."""
     b, s, _ = x.shape
@@ -771,7 +845,7 @@ def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
     flash = cfg.attention not in ("ring", "reference")
     with jax.named_scope("attn_proj"):
         wq, wk = a["wq"], a["wk"]
-        if flash and spec.columns(hd) != hd:
+        if flash and spec is not None and spec.columns(hd) != hd:
             # the kernels rotate partners half a head apart: a head's
             # columns in that order, in the weights (ops/rope.py)
             order = jnp.asarray(halves_apart(hd, spec.columns(hd)))
@@ -791,9 +865,9 @@ def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
         return _flash_on_mesh(q, k, v, table, cfg, where.mesh, window)
     with jax.named_scope("attn_proj"):
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-        q = _rope(heads(q), spec, positions)
-        k = _rope(heads(k), spec, positions)
-        v = heads(v)
+        q, k, v = heads(q), heads(k), heads(v)
+        if spec is not None:
+            q, k = _rope(q, spec, positions), _rope(k, spec, positions)
     if index is not None:
         # the oracle of `_selected_attention`: the jnp rotation, and the
         # reference attention under the same selection
@@ -835,6 +909,72 @@ def _conv_block(m, x, cfg: GPTConfig, where: Setting):
                 gate_in, gate_out, value, m["filter"])
         return where.psum(
             jnp.einsum("bsd,de->bse", y, m["w_out"].astype(dt)))
+
+
+def _kda_block(m, x, cfg: GPTConfig, where: Setting):
+    """Gated delta-rule linear attention in attention's place (KDA; the
+    recurrence and its chunked form: ops/linear_attention.py). From the
+    normed input x, a head h of head_dim columns:
+
+      q, k, v = silu(filter(x wq | wk | wv)), a causal depthwise filter a
+            channel (ops/short_conv.py:silu_conv); q and k divided by their
+            head's norm, q times head_dim^-1/2
+      log-decay a channel: -exp(a_log_h) softplus((x wf_down) wf_up + dt_bias)
+      beta a head: sigmoid(x w_beta), doubled under cfg.kda_neg_eigval
+      o = kda(q, k, v, log-decay, beta)
+      y = [RMSNorm_head(o) * sigmoid((x wg_down) wg_up)] wo
+
+    -> (y, the layer's statistics: `kda_log_decay_min`, the smallest
+    cumulative log-decay inside a chunk, and `kda_beta_mean`). The filters,
+    the norms and the state work on a head's own columns, so
+    column-parallel projections and a row-parallel wo leave them local to a
+    shard of 'tensor'. Scope `kda` holds the layer; `kda_core`, nested, the
+    delta rule alone."""
+    dt, f32 = cfg.dtype, jnp.float32
+    b, s, _ = x.shape
+    hd = cfg.head_dim
+    columns = ("batch", None, "heads")
+    conv = _per_shard(silu_conv, where.mesh, (columns, ("heads", None)),
+                      columns)
+
+    def heads(y):                        # [B, S, H * hd] -> [B, H, S, hd]
+        return y.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
+
+    def unit(y):
+        y = y.astype(f32)
+        return y * jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
+
+    def low_rank(down, up):
+        return jnp.einsum(
+            "bsr,re->bse", jnp.einsum("bsd,dr->bsr", x, m[down].astype(dt)),
+            m[up].astype(dt), preferred_element_type=f32)
+
+    with jax.named_scope("kda"):
+        q, k, v = (heads(conv(jnp.einsum("bsd,de->bse", x, m[w].astype(dt)),
+                              m[taps]))
+                   for w, taps in (("wq", "q_conv"), ("wk", "k_conv"),
+                                   ("wv", "v_conv")))
+        q = (unit(q) * hd ** -0.5).astype(dt)
+        k = unit(k).astype(dt)
+        rate = jnp.repeat(jnp.exp(m["a_log"].astype(f32)), hd)
+        log_decay = heads(-rate * jax.nn.softplus(
+            low_rank("wf_down", "wf_up") + m["dt_bias"]))
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "bsd,dh->bsh", x, m["w_beta"].astype(dt),
+            preferred_element_type=f32)).transpose(0, 2, 1)
+        if cfg.kda_neg_eigval:
+            beta = 2.0 * beta
+        with jax.named_scope("kda_core"):
+            o = kda(q, k, v, log_decay, beta)
+        stats = {"kda_log_decay_min": jnp.min(chunk_log_decay(log_decay)),
+                 "kda_beta_mean": jnp.mean(beta)}
+        # a head is whole wherever its columns are: no psum
+        o = _rmsnorm(o.transpose(0, 2, 1, 3).astype(f32),      # [B, S, H, hd]
+                     m["o_norm"]["scale"], cfg.rmsnorm_eps)
+        gate = jax.nn.sigmoid(low_rank("wg_down", "wg_up"))
+        o = (o.reshape(b, s, -1) * gate).astype(dt)
+        return where.psum(jnp.einsum("bsd,de->bse", o,
+                                     m["wo"].astype(dt))), stats
 
 
 def _mlp_block(m, x, cfg: GPTConfig, where: Setting):
@@ -1000,19 +1140,22 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
 def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     """(x [B, seq, D], one layer's parameters) -> (x, the layer's
     statistics: _route's dict for a sparse layer, {} for a dense one, and
-    an indexer's two where the layer has one; which
-    it is, and whether its mixer is attention or the short convolution, the
-    layer's own parameters say, as gpt_init built them). The one
+    an indexer's two or a delta-rule layer's two where the layer has
+    them; which it is, and whether its mixer is attention, the short
+    convolution or the delta rule, the layer's own parameters say, as
+    gpt_init built them). The one
     transformer block, rematted as cfg.remat_policy says, for whoever
     walks the layers: gpt_backbone loops over their list, a stage of
     parallel/pipeline.py scans over stacked ones."""
     # once a step, not once a layer and recompute: outside the remat; one
     # table for each kind of attention layer the stack has
-    kinds = set(cfg.layer_kinds or ("attention",)) - {"conv"}
+    kinds = set(cfg.layer_kinds or ("attention",)) - {"conv", "kda"}
     with jax.named_scope("attn_proj"):
+        # (no table for a kind that does not rotate)
         tables = {
             kind: rope_table(seq, cfg.qk_rope_dim if cfg.kv_latent_dim
                              else cfg.head_dim, cfg.rope_of(kind))
+            if cfg.use_rope else ()
             for kind in sorted(kinds)}
     index_table = ()
     if cfg.index_topk:
@@ -1025,6 +1168,8 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         mixer_stats = {}
         if "conv" in layer:
             mixed = _conv_block(layer["conv"], normed, cfg, where)
+        elif "kda" in layer:
+            mixed, mixer_stats = _kda_block(layer["kda"], normed, cfg, where)
         else:
             kind = "window" if _GROUP["window"] in layer else "attention"
             mixed, mixer_stats = _attention_block(
@@ -1042,11 +1187,13 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
 
     if cfg.remat_policy == "full":
         # (of an indexer, its selection and its loss's gradients: the walk
-        # over the score tiles then runs once a layer and step)
+        # over the score tiles then runs once a layer and step; of a
+        # delta-rule layer, its output: the backward runs the chunked form
+        # again itself)
         return jax.checkpoint(
             block, policy=jax.checkpoint_policies.save_only_these_names(
                 FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK,
-                indexer.INDEX_GRADS))
+                indexer.INDEX_GRADS, KDA_OUT))
     if cfg.remat_policy != "none":
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(expected 'full' | 'none')")
@@ -1077,8 +1224,9 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     """tokens: [B, S] -> (final hidden states [B, S, D] (pre-LM-head), the
     layers' statistics, each averaged over the layers that have it:
     _route's dict for a sparse model, an indexer's two (`index_kl`,
-    `index_selected_share`) where the layers have one, {} for a dense
-    model).
+    `index_selected_share`) and a delta-rule layer's two
+    (`kda_log_decay_min`, `kda_beta_mean`) where the layers have them, {}
+    for a dense model).
 
     act_sharding (a NamedSharding for [B, S, D] activations, usually
     ``strategy.activation_sharding(mesh)``) pins the residual stream at

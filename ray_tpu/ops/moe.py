@@ -82,6 +82,9 @@ from ray_tpu.ops.attention import lane_divisor
 _WEIGHT_BLOCK_BYTES = 4 << 20
 _VMEM_LIMIT_BYTES = 64 << 20
 _MAX_TILE_ROWS = 256
+# The MXU's height: a tile of fewer rows loads every 128 x 128 weight tile
+# for a fraction of a pass (`tile_rows`).
+_MXU_ROWS = 128
 # A share's row space, in tiles the expected rows fill (`in_row_space`).
 # Random routing puts 0.11-0.14 of the slots on an eighth of the experts
 # (PERF.md, PR 31); past twice the expectation the plan for every slot runs.
@@ -165,10 +168,17 @@ def _sublanes(dtype) -> int:
 def tile_rows(n_slots: int, n_groups: int, dtype) -> int:
     """Rows of one tile, from the shape alone: about a quarter of the mean
     group (so padding, half a tile a group on average, stays near an
-    eighth of the rows), between the type's sublane packing and 256."""
+    eighth of the rows), between the type's sublane packing and 256; and
+    never under the MXU's 128 rows once the mean group outgrows them: a
+    group of 205 rows in tiles of 32 kept the kernels at a quarter of the
+    MXU's rate (5 ms of a 310 ms step at 8 of 320 experts held, and the
+    seed's share of the step 0.6 % for 0.4: PERF.md section 6, PR 46); in
+    tiles of 128 it pads to 256."""
     rows = _sublanes(dtype)
     while rows * 2 <= min(_MAX_TILE_ROWS, n_slots // (4 * n_groups)):
         rows *= 2
+    if rows < _MXU_ROWS < n_slots // n_groups:
+        rows = _MXU_ROWS
     return rows
 
 

@@ -23,6 +23,14 @@ shift needs from the next block along the sequence come through a second
 BlockSpec on the same array, one sublane tile of rows (`halo`), so the grid
 has no order and no carry. Blocks follow from the shape (`_conv_blocks`); a
 shape that does not tile takes the jnp formulation.
+
+The plain form beside it, for a layer that filters a projection and gates
+nothing (`silu_conv`: x, w -> silu(filter(x)), the convolution a gated
+delta-rule layer puts on q, k and v): the same blocks, halo and shifts under
+names of their own, `conv_silu_fwd` (reads x, writes the result) and
+`conv_silu_bwd` (reads x and the cotangent, computes the filtered x again,
+in the block and in the rows after it whose cotangents reach back into it,
+and writes dx and the filter's gradient); `silu_conv_reference` is its jnp.
 """
 
 from __future__ import annotations
@@ -153,14 +161,16 @@ def _specs(blocks: _ConvBlocks, seq: int):
     return main, prev, nxt
 
 
+def _taps_spec(n: int, cols: int):
+    """The taps [n, channels] of a channel block, whatever the row block."""
+    return pl.BlockSpec((n, cols), lambda b, i, j: (0, j))
+
+
 @functools.lru_cache(maxsize=None)
 def _make_conv_fn(blocks: _ConvBlocks, interpret: bool):
     """short_conv_fwd with short_conv_bwd as its backward; the residuals
     are the four inputs."""
     rows, cols, _halo = blocks
-
-    def taps_spec(n):
-        return pl.BlockSpec((n, cols), lambda b, i, j: (0, j))
 
     def forward(gate_in, gate_out, value, taps):
         batch, seq, d = gate_in.shape
@@ -168,7 +178,8 @@ def _make_conv_fn(blocks: _ConvBlocks, interpret: bool):
         return pl.pallas_call(
             _fwd_kernel,
             grid=(batch, seq // rows, d // cols),
-            in_specs=[main, main, main, prev, prev, taps_spec(taps.shape[1])],
+            in_specs=[main, main, main, prev, prev,
+                      _taps_spec(taps.shape[1], cols)],
             out_specs=main,
             out_shape=jax.ShapeDtypeStruct(gate_in.shape, gate_in.dtype),
             compiler_params=_PARAMS,
@@ -195,7 +206,7 @@ def _make_conv_fn(blocks: _ConvBlocks, interpret: bool):
             _bwd_kernel,
             grid=(batch, seq // rows, d // cols),
             in_specs=[main, main, main, main, prev, prev, nxt, nxt,
-                      taps_spec(n)],
+                      _taps_spec(n, cols)],
             out_specs=[main, main, main,
                        pl.BlockSpec((1, 1, n, cols),
                                     lambda b, i, j: (b, i, 0, j))],
@@ -226,3 +237,128 @@ def short_conv(gate_in, gate_out, value, taps, *,
     if interpret is None:
         interpret = attention._default_interpret()
     return _make_conv_fn(blocks, interpret)(gate_in, gate_out, value, taps)
+
+
+# ---------------------------------------------------------------------------
+# The plain form: silu(filter(x))
+# ---------------------------------------------------------------------------
+
+def silu_conv_reference(x, taps):
+    """x: [batch, seq, channels]; taps: [channels, L]. silu of the filter
+    as L shifted products, zeros before the sequence's start."""
+    f32 = jnp.float32
+    seq, n = x.shape[1], taps.shape[1]
+    padded = jnp.pad(x.astype(f32), ((0, 0), (n - 1, 0), (0, 0)))
+    mixed = sum(taps[:, j].astype(f32) * padded[:, j:j + seq]
+                for j in range(n))
+    return jax.nn.silu(mixed).astype(x.dtype)
+
+
+def _filtered(u, before, w_ref):
+    """(the filter's output on u [rows, cols] with `before` the halo ahead
+    of it, u moved down by each tap's rows)."""
+    n = w_ref.shape[0]
+    moved = [_shifted(u, before, k, True) for k in range(n)]
+    return sum(w_ref[n - 1 - k:n - k, :] * moved[k] for k in range(n)), moved
+
+
+def _silu_fwd_kernel(x_ref, x_prev_ref, w_ref, o_ref):
+    """Grid (batch, seq block, channel block)."""
+    f32 = jnp.float32
+    before = jnp.where(pl.program_id(1) > 0, x_prev_ref[0].astype(f32), 0.0)
+    mixed, _ = _filtered(x_ref[0].astype(f32), before, w_ref)
+    o_ref[0] = (mixed * jax.nn.sigmoid(mixed)).astype(o_ref.dtype)
+
+
+def _silu_slope(mixed):
+    """d silu(m) / d m."""
+    s = jax.nn.sigmoid(mixed)
+    return s * (1.0 + mixed * (1.0 - s))
+
+
+def _silu_bwd_kernel(x_ref, g_ref, x_prev_ref, x_next_ref, g_next_ref, w_ref,
+                     dx_ref, dw_ref):
+    """Grid (batch, seq block, channel block). dm = g * silu'(m) is shifted
+    the other way, so it needs m in the rows after the block: the filter
+    over the halo after it, whose own rows before are the block's last."""
+    f32 = jnp.float32
+    n = w_ref.shape[0]
+    i = pl.program_id(1)
+    x = x_ref[0].astype(f32)
+    halo = x_prev_ref.shape[1]
+    before = jnp.where(i > 0, x_prev_ref[0].astype(f32), 0.0)
+    mixed, moved = _filtered(x, before, w_ref)
+    dm = g_ref[0].astype(f32) * _silu_slope(mixed)
+    mixed_after, _ = _filtered(x_next_ref[0].astype(f32),
+                               x[x.shape[0] - halo:], w_ref)
+    after = jnp.where(i < pl.num_programs(1) - 1, g_next_ref[0].astype(f32)
+                      * _silu_slope(mixed_after), 0.0)
+    dx = jnp.zeros_like(x)
+    for k in range(n):
+        dx = dx + w_ref[n - 1 - k:n - k, :] * _shifted(dm, after, k, False)
+        dw_ref[0, 0, n - 1 - k:n - k, :] = jnp.sum(dm * moved[k], axis=0,
+                                                   keepdims=True)
+    dx_ref[0] = dx.astype(dx_ref.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_silu_conv_fn(blocks: _ConvBlocks, interpret: bool):
+    """conv_silu_fwd with conv_silu_bwd as its backward; the residuals are
+    the two inputs."""
+    rows, cols, _halo = blocks
+
+    def forward(x, taps):
+        batch, seq, d = x.shape
+        main, prev, _nxt = _specs(blocks, seq)
+        return pl.pallas_call(
+            _silu_fwd_kernel,
+            grid=(batch, seq // rows, d // cols),
+            in_specs=[main, prev, _taps_spec(taps.shape[1], cols)],
+            out_specs=main,
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            compiler_params=_PARAMS,
+            interpret=interpret,
+            name="conv_silu_fwd",
+        )(x, x, taps.astype(jnp.float32).T)
+
+    @jax.custom_vjp
+    def f(x, taps):
+        return forward(x, taps)
+
+    def fwd(x, taps):
+        return forward(x, taps), (x, taps)
+
+    def bwd(residuals, g):
+        x, taps = residuals
+        batch, seq, d = x.shape
+        n = taps.shape[1]
+        main, prev, nxt = _specs(blocks, seq)
+        dx, d_taps = pl.pallas_call(
+            _silu_bwd_kernel,
+            grid=(batch, seq // rows, d // cols),
+            in_specs=[main, main, prev, nxt, nxt, _taps_spec(n, cols)],
+            out_specs=[main, pl.BlockSpec((1, 1, n, cols),
+                                          lambda b, i, j: (b, i, 0, j))],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                       jax.ShapeDtypeStruct((batch, seq // rows, n, d),
+                                            jnp.float32)],
+            compiler_params=_PARAMS,
+            interpret=interpret,
+            name="conv_silu_bwd",
+        )(x, g, x, x, g, taps.astype(jnp.float32).T)
+        return dx, jnp.sum(d_taps, axis=(0, 1)).T.astype(taps.dtype)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def silu_conv(x, taps, *, interpret: Optional[bool] = None):
+    """silu(filter(x)): the plain form of the module's docstring. x:
+    [batch, seq, channels]; taps: [channels, L]."""
+    _, seq, channels = x.shape
+    blocks = _conv_blocks(seq, channels, taps.shape[1], x.dtype.itemsize)
+    if blocks is None:
+        return silu_conv_reference(x, taps)
+    if interpret is None:
+        interpret = attention._default_interpret()
+    return _make_silu_conv_fn(blocks, interpret)(x, taps)

@@ -120,6 +120,18 @@ _TRANSFORMER = _STAGE_ROWS + (
     (r"conv/w_in", (None, "model", "heads")),
     (r"conv/filter", ("heads", None)),
     (r"conv/w_out", _ROW),
+    # a delta-rule layer: whole heads of the three projections' columns, of
+    # beta's, and of the up-halves of the decay's and the gate's low-rank
+    # pairs (their down-halves are of rank head_dim: not cut over 'tensor');
+    # a shard holds its heads' filters, decay rates and step biases and
+    # wo's rows; the norm's one scale of head_dim is every head's
+    (r"kda/(wq|wk|wv|w_beta)", _COLUMN),
+    (r"kda/(wf|wg)_up", (None, "heads")),
+    (r"kda/(wf|wg)_down", ("model", None)),
+    (r"kda/(q|k|v)_conv", ("heads", None)),
+    (r"kda/(a_log|dt_bias)", ("heads",)),
+    (r"kda/o_norm", ()),
+    (r"kda/wo", _ROW),
     # Vocab over both axes under tp_fsdp, d_model replicated: a d-sharded
     # gather output cannot transition to batch-sharded activations without
     # an involuntary full rematerialization (permuted tile order), while a

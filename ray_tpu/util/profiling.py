@@ -118,7 +118,8 @@ def stack_dump() -> Dict[str, str]:
 # takes its ops out of the outer one's row. What each name holds is said
 # where it is opened.
 REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_window",
-           "attn_index", "attn_out", "attn_gate", "conv", "conv_mix", "mlp",
+           "attn_index", "attn_out", "attn_gate", "conv", "conv_mix", "kda",
+           "kda_core", "mlp",
            "moe", "moe_route", "moe_shared", "norm", "head", "loss_and_grad",
            "grad_accum", "optimizer")
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
@@ -127,7 +128,8 @@ KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
            "moe_run_sum", "rope_split", "rope_merge", "short_conv_fwd",
            "short_conv_bwd", "latent_q_split", "latent_kv_split",
            "latent_q_merge", "latent_kv_merge", "index_scores",
-           "index_search", "index_kl", "index_grad_q", "index_grad_k")
+           "index_search", "index_kl", "index_grad_q", "index_grad_k",
+           "conv_silu_fwd", "conv_silu_bwd")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:", "compile:")

@@ -144,6 +144,61 @@ def test_short_conv_kernels_compile_for_v5e(v5e, backward):
     assert ("short_conv_bwd" if backward else "short_conv_fwd") in text
 
 
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_plain_filter_kernels_compile_for_v5e(v5e, backward):
+    """ops/short_conv.py's plain pair (silu of a 4-tap filter) at one
+    projection of solar2_train_1chip, [1, 8192, 8 heads x 128]: the halo
+    after a block filtered from the block's own last rows lays out too."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.short_conv import silu_conv
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+
+    def fn(x, w, g):
+        out, vjp = jax.vjp(lambda *a: silu_conv(*a, interpret=False), x, w)
+        return vjp(g) if backward else out
+    x = shape((1, 8192, 1024))
+    text = jax.jit(fn).lower(x, shape((1024, 4), jnp.float32),
+                             x).compile().as_text()
+    assert ("conv_silu_bwd" if backward else "conv_silu_fwd") in text
+
+
+def test_delta_rule_compiles_at_8192_positions_of_128(v5e):
+    """ops/linear_attention.py's chunked form and its backward at a
+    delta-rule layer of solar2_train_1chip, [1, 8, 8192, 128]: XLA alone
+    (no Mosaic call), two loops each way (the tree's levels, the chunk
+    states), the sequential one 128 chunks long, never the 8192 tokens;
+    under a gigabyte and a half of temporaries."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.linear_attention import kda
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+    x = shape((1, 8, 8192, 128))
+    args = (x, x, x, shape((1, 8, 8192, 128), jnp.float32),
+            shape((1, 8, 8192), jnp.float32))
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(kda(*a).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    # the tree's levels and the chunk states, forward and transposed; the
+    # sequential one walks 128 stacked chunks, and none the tokens
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 4, len(loops)
+    assert sum("f32[128,1,8," in line for line in loops) == 2
+    assert not any("[8192,1,8" in line for line in loops)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 @pytest.mark.parametrize("shape", KERNEL_SHAPES,
                          ids=lambda s: "x".join(map(str, s)))
 @pytest.mark.parametrize("backward", [False, True],
@@ -746,11 +801,25 @@ CELL_STEPS = [
                              "rope_split": 35, "rope_merge": 20,
                              "moe_gmm": 90, "moe_tgmm": 30},
      (0.70, 0.85)),
+    # solar2_train_1chip (1 x 8192 tokens): a grouped-query layer that
+    # rotates nothing (8 query heads on 1 at head 128: one call of each
+    # flash kernel; q, k, v through rope_split without a table, forward and
+    # recomputed) and three delta-rule layers, each with the plain filter
+    # on q, k and v (forward + recomputed, backward) and the delta rule as
+    # XLA; 8 of 320 experts held in all four layers, their rows in tiles
+    # of 128. 14.66 GB when this was written: 10.09 of state, 4.57 of
+    # temporaries. (The compile takes
+    # ~105 s alone here: a time limit of its own.)
+    pytest.param("solar-open2-250b",
+                 {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+                  "rope_split": 6, "rope_merge": 3, "moe_gmm": 72,
+                  "moe_tgmm": 24, "conv_silu_fwd": 18, "conv_silu_bwd": 9},
+                 (0.80, 0.93), marks=pytest.mark.timeout(900)),
 ]
 
 
 @pytest.mark.parametrize("name,kernel_calls,share", CELL_STEPS,
-                         ids=[c[0] for c in CELL_STEPS])
+                         ids=[getattr(c, "values", c)[0] for c in CELL_STEPS])
 def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
                                                    kernel_calls, share):
     """A one-chip cell's whole step (the cell's own traffic: 2 x 8192

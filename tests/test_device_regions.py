@@ -112,7 +112,8 @@ def test_the_vocabulary_is_the_names_the_code_opens(jax_cpu):
 # test_latent_moe.py, on a step that has a latent block and a shared
 # expert; conv and conv_mix: tests/test_conv_gqa.py; attn_window and
 # attn_gate: tests/test_window_attention.py; attn_index: tests/
-# test_selected_attention.py; moe, moe_route and grad_accum: below)
+# test_selected_attention.py; kda and kda_core: tests/
+# test_linear_attention.py; moe, moe_route and grad_accum: below)
 @pytest.mark.parametrize("region", [
     "embed", "attn_proj", "attn_core", "attn_out", "mlp", "norm", "head",
     "loss_and_grad", "optimizer"])

@@ -1,0 +1,779 @@
+"""The gated delta rule with a decay a channel (ops/linear_attention.py), the
+plain filter of ops/short_conv.py, and a stack of delta-rule layers and a
+grouped-query layer without rotation under an element gate (models/gpt.py)
+against the plain float32 reference of benchmark/families/solar.py, at a
+small size on the CPU: seeded random weights, the kernels in interpret
+mode."""
+
+import copy
+import json
+import math
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def _read(*parts):
+    with open(os.path.join(ROOT, *parts)) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """benchmark/rehearsal/configs/tiny-solar.json: a grouped-query layer (4
+    query heads of 32 on 2, no rotation, a gate an element) then three
+    delta-rule layers of 4 heads of 32 with 4-tap filters, every layer with
+    experts 4..7 of 16 held, 2 a token, beside a shared one."""
+    return _read("benchmark", "rehearsal", "configs", "tiny-solar.json")
+
+
+# ---------------------------------------------------------------------------
+# (a) the delta rule: chunked against a token a step
+# ---------------------------------------------------------------------------
+
+def _delta_inputs(jax, seq, dim, decay, beta_top, seed=0):
+    """Normalised q and k, log-decays of about -decay a token and channel,
+    beta near beta_top."""
+    import jax.numpy as jnp
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k, v = (jax.random.normal(key, (2, 3, seq, dim)) for key in keys[:3])
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dim ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    log_decay = -decay * jax.nn.softplus(
+        jax.random.normal(keys[3], (2, 3, seq, dim)))
+    beta = beta_top * jax.nn.sigmoid(
+        jax.random.normal(keys[4], (2, 3, seq)) + 3.0)
+    return q, k, v, log_decay, beta
+
+
+@pytest.mark.parametrize("seq,chunk,decay,beta_top", [
+    (128, 64, 0.05, 2.0),       # whole chunks, beta near 2
+    (100, 64, 1e-3, 2.0),       # a ragged tail, decays near 1
+    (192, 64, 8.0, 1.0),        # a chunk's decay underflows float32
+    (64, 64, 0.0, 2.0),         # no decay at all: the plain delta rule
+    (40, 16, 0.3, 1.5),         # another chunk size, a ragged tail
+], ids=["whole", "ragged_slow", "underflow", "no_decay", "chunk16"])
+def test_chunked_delta_rule_matches_the_recurrence(jax_cpu, seq, chunk, decay,
+                                                   beta_top):
+    """Values and all five gradients. No chunk divides by a decay: where a
+    chunk's cumulative log-decay passes float32's range (8 a token over 64
+    tokens) the chunked form still has the recurrence's numbers."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.linear_attention import (chunk_log_decay, kda,
+                                              kda_reference)
+    args = _delta_inputs(jax, seq, 32, decay, beta_top)
+    if decay == 8.0:
+        assert float(chunk_log_decay(args[3]).min()) < -200.0
+    weight = jnp.cos(0.37 * jnp.arange(seq * 32).reshape(seq, 32))
+
+    def run(fn):
+        def scalar(*a):
+            out = fn(*a)
+            return jnp.sum(out * weight), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+        return out, grads
+    out, grads = run(lambda *a: kda(*a, chunk=chunk))
+    ref, ref_grads = run(kda_reference)
+    assert out.shape == (2, 3, seq, 32) and np.isfinite(out).all()
+    np.testing.assert_allclose(out, ref, atol=5e-6)
+    for name, g, r in zip(("q", "k", "v", "log_decay", "beta"), grads,
+                          ref_grads):
+        assert np.any(np.asarray(r)), name
+        np.testing.assert_allclose(
+            g, r, atol=1e-5 * max(1.0, float(np.abs(r).max())), err_msg=name)
+
+
+def test_delta_rule_keeps_the_inputs_type_and_refuses_an_odd_chunk(jax_cpu):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.linear_attention import kda, kda_reference
+    q, k, v, log_decay, beta = _delta_inputs(jax, 64, 32, 0.1, 2.0)
+    half = [x.astype(jnp.bfloat16) for x in (q, k, v)]
+    out = kda(*half, log_decay, beta)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        out.astype(jnp.float32),
+        kda_reference(*half, log_decay, beta).astype(jnp.float32), atol=2e-2)
+    with pytest.raises(ValueError, match="power of two"):
+        kda(q, k, v, log_decay, beta, chunk=48)
+
+
+def test_a_negative_eigenvalue_flips_what_the_state_holds(jax_cpu):
+    """beta = 2 on a unit key reflects the state along it: reading the same
+    key back gives the value written less the value held, and not, as at
+    beta = 1, the value written."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.linear_attention import kda
+    k = jnp.zeros((1, 1, 2, 8)).at[..., 0].set(1.0)
+    v = jnp.stack([jnp.full((8,), 3.0), jnp.full((8,), 5.0)])[None, None]
+    zeros = jnp.zeros((1, 1, 2, 8))
+    for beta, second in ((1.0, 5.0), (2.0, 2.0 * 5.0 - 2.0 * 3.0)):
+        out = kda(k, k, v, zeros, jnp.full((1, 1, 2), beta), chunk=2)
+        np.testing.assert_allclose(out[0, 0, 0], beta * 3.0, atol=1e-6)
+        np.testing.assert_allclose(out[0, 0, 1], second, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# (b) the plain filter
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype,shape,kernels", [
+    ("float32", (2, 1024, 256), True), ("float32", (1, 64, 128), True),
+    ("bfloat16", (1, 2048, 1024), True), ("bfloat16", (2, 96, 128), True),
+    ("float32", (2, 50, 96), False),
+], ids=["f32", "f32_one_block", "bf16_cell_width", "bf16_small", "jnp_form"])
+def test_plain_filter_matches_jnp(jax_cpu, dtype, shape, kernels):
+    """silu of a causal 4-tap depthwise filter, forward and both gradients,
+    the kernels against jnp written out here; a shape that does not tile
+    takes the jnp form."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import short_conv
+    dt = jnp.dtype(dtype)
+    x = jax.random.normal(jax.random.PRNGKey(0), shape).astype(dt)
+    taps = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (shape[2], 4))
+    ct = jax.random.normal(jax.random.PRNGKey(2), shape)
+    tiled = short_conv._conv_blocks(shape[1], shape[2], 4, dt.itemsize)
+    assert (tiled is not None) == kernels
+
+    def plain(x, taps):
+        # y_t = silu(sum_j w_j x_{t - 3 + j}), zeros before the start
+        seq = x.shape[1]
+        padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (3, 0), (0, 0)))
+        return jax.nn.silu(sum(taps[:, j] * padded[:, j:j + seq]
+                               for j in range(4))).astype(x.dtype)
+
+    def run(fn):
+        def scalar(x, taps):
+            out = fn(x, taps)
+            return jnp.sum(out.astype(jnp.float32) * ct), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1), has_aux=True))(x, taps)
+        return out, grads
+    out, (dx, dw) = run(short_conv.silu_conv)
+    ref, (rx, rw) = run(plain)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(out.astype(np.float32), ref.astype(np.float32),
+                               atol=tol)
+    np.testing.assert_allclose(dx.astype(np.float32), rx.astype(np.float32),
+                               atol=tol)
+    np.testing.assert_allclose(dw, rw, rtol=1e-4,
+                               atol=1e-4 * float(np.abs(rw).max()))
+    names = re.findall(r"name=(conv_silu_\w+)", str(jax.make_jaxpr(
+        jax.grad(lambda x: jnp.sum(short_conv.silu_conv(x, taps).astype(
+            jnp.float32))))(x)))
+    assert names == (["conv_silu_fwd", "conv_silu_bwd"] if kernels else [])
+
+
+# ---------------------------------------------------------------------------
+# (c) the family's program against its reference
+# ---------------------------------------------------------------------------
+
+def _program(jax, config, attention, dtype=None):
+    import jax.numpy as jnp
+    from benchmark.families import solar
+    from ray_tpu.models.gpt import GPTConfig, gpt_init
+    cfg = GPTConfig(**solar.gpt_config_kwargs(config), attention=attention,
+                    dtype=dtype or jnp.float32, remat_policy="none")
+    params = gpt_init(jax.random.PRNGKey(3), cfg)
+    for i, layer in enumerate(params["layers"]):
+        # a router with an opinion: at the init's 0.02 every score is 1/2
+        layer["moe"]["router"] = 0.3 * jax.random.normal(
+            jax.random.PRNGKey(100 + i), layer["moe"]["router"].shape)
+    tokens = np.random.default_rng(5).integers(
+        0, config["vocab_size"], (2, 129), dtype=np.int32)
+    return cfg, params, jnp.asarray(tokens)
+
+
+@pytest.fixture(scope="module")
+def reference(jax_cpu, tiny):
+    jax = jax_cpu
+    from benchmark.families import solar
+    _cfg, params, tokens = _program(jax, tiny, "reference")
+    with jax.default_matmul_precision("highest"):
+        logits = jax.jit(lambda p, t: solar.reference_logits(
+            p, t[:, :-1], tiny))(params, tokens)
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p, t: solar.reference_loss(p, t, tiny)))(params, tokens)
+    return logits, loss, grads
+
+
+def test_logits_loss_and_gradients_match_the_reference(jax_cpu, tiny,
+                                                       reference):
+    """A grouped-query layer that rotates nothing under a gate an element,
+    three delta-rule layers (the chunked form against the reference's token
+    a step; the filter and flash kernels), experts beside a shared
+    one in every layer, in float32: the whole tree of gradients. The
+    tolerance is float32's own over 128 tokens of a state that is decayed
+    and overwritten (the two forms sum in another order), five layers deep:
+    5e-5 in a logit of about 5."""
+    jax = jax_cpu
+    from ray_tpu.models.gpt import gpt_forward, gpt_loss_and_aux
+    cfg, params, tokens = _program(jax, tiny, "flash")
+    assert [sorted(layer) for layer in params["layers"]] == [
+        ["attn", "ln1", "ln2", "moe"]] + [["kda", "ln1", "ln2", "moe"]] * 3
+    gqa, kda = params["layers"][0]["attn"], params["layers"][1]["kda"]
+    assert cfg.head_dim == 32 and cfg.d_model == 128 and not cfg.use_rope
+    assert gqa["wq"].shape == gqa["wg"].shape == (128, 4 * 32)   # an element
+    assert gqa["wk"].shape == gqa["wv"].shape == (128, 2 * 32)
+    assert kda["wq"].shape == kda["wk"].shape == kda["wv"].shape == (128, 128)
+    assert kda["q_conv"].shape == (128, 4) and kda["a_log"].shape == (4,)
+    assert kda["wf_down"].shape == (128, 32) == kda["wg_up"].shape[::-1]
+    assert kda["w_beta"].shape == (128, 4) and kda["dt_bias"].shape == (128,)
+    assert params["layers"][1]["moe"]["w_up"].shape == (4, 128, 64)
+    assert params["layers"][1]["moe"]["router"].shape == (128, 16)
+    with jax.default_matmul_precision("highest"):
+        logits, _ = jax.jit(lambda p, t: gpt_forward(p, t, cfg))(
+            params, tokens[:, :-1])
+        (loss, aux), grads = jax.jit(jax.value_and_grad(
+            lambda p, t: gpt_loss_and_aux(p, {"tokens": t}, cfg),
+            has_aux=True))(params, tokens)
+    ref_logits, ref_loss, ref_grads = reference
+    np.testing.assert_allclose(logits, ref_logits, atol=5e-5)
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
+    assert float(loss) == float(aux["xent"])        # no router loss
+    assert 0.0 < float(aux["expert_slots_held_share"]) < 1.0
+    # beta up to 2; some channel's decay over a chunk passes float32's
+    # range at the seeded rates (the chunked form divides by none)
+    assert 0.5 < float(aux["kda_beta_mean"]) < 1.5
+    assert -1e4 < float(aux["kda_log_decay_min"]) < -87.0
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree_util.tree_leaves(ref_grads)):
+        name = jax.tree_util.keystr(path)
+        # the selection bias enters the choice alone: no gradient
+        assert np.any(np.asarray(r)) != ("router_bias" in name), name
+        np.testing.assert_allclose(
+            g, r, atol=2e-5 * max(1.0, float(np.abs(r).max())), err_msg=name)
+
+
+def _faulty_kda(solar, fault):
+    """benchmark/families/solar.py:reference_kda with one thing wrong."""
+    import jax
+    import jax.numpy as jnp
+
+    def kda(m, n, config):
+        f32 = jnp.float32
+        dim = config["linear_attn_config"]["head_dim"]
+        s = n.shape[0]
+
+        def filtered(x, taps):
+            if fault == "filter_turned_round":      # reaches forward in time
+                return solar._filtered(x[::-1], taps)[::-1]
+            return solar._filtered(x, taps)
+        q, k, v = (filtered(n @ m[w].astype(f32), m[taps]).reshape(s, -1, dim)
+                   for w, taps in (("wq", "q_conv"), ("wk", "k_conv"),
+                                   ("wv", "v_conv")))
+        q = solar._unit(q) / math.sqrt(dim)
+        k = k if fault == "keys_not_normalised" else solar._unit(k)
+        step = jax.nn.softplus(n @ m["wf_down"].astype(f32)
+                               @ m["wf_up"].astype(f32) + m["dt_bias"])
+        log_decay = (-jnp.exp(m["a_log"].astype(f32))[None, :, None]
+                     * step.reshape(s, -1, dim))
+        if fault == "no_decay":
+            log_decay = jnp.zeros_like(log_decay)
+        beta = 2.0 * jax.nn.sigmoid(n @ m["w_beta"].astype(f32))
+        o = solar.reference_delta_rule(q, k, v, log_decay, beta)
+        o = solar._norm(o, m["o_norm"]["scale"], float(config["rms_norm_eps"]))
+        gate = jax.nn.sigmoid(n @ m["wg_down"].astype(f32)
+                              @ m["wg_up"].astype(f32))
+        if fault == "no_norm_gate":
+            gate = jnp.ones_like(gate)
+        return (o.reshape(s, -1) * gate) @ m["wo"].astype(f32)
+    return kda
+
+
+def test_the_reference_tells_each_mechanism_apart(jax_cpu, tiny, reference,
+                                                  monkeypatch):
+    """What `program_check` rests on: the reference with one mechanism
+    changed gives other logits (and the faulty copy of the delta-rule layer
+    with nothing changed gives the reference's)."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import solar
+    _cfg, params, tokens = _program(jax, tiny, "reference")
+    sound = reference[0]
+
+    def logits_of(config):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(lambda p, t: solar.reference_logits(
+                p, t[:, :-1], config))(params, tokens)
+    for name, config in {
+            "beta_not_doubled": dict(tiny, kda_allow_neg_eigval=False),
+            "no_gqa_gate": dict(tiny, use_gqa_gate=False),
+            "a_rotation": dict(tiny, use_rope=True),
+    }.items():
+        assert float(jnp.abs(logits_of(config) - sound).max()) > 1e-3, name
+    for fault in (None, "no_decay", "keys_not_normalised",
+                  "filter_turned_round", "no_norm_gate"):
+        monkeypatch.setattr(solar, "reference_kda", _faulty_kda(solar, fault))
+        gap = float(jnp.abs(logits_of(tiny) - sound).max())
+        # (keys that are not unit vectors make the transition expand under
+        # beta > 1: the state overflows, and nan is told apart too)
+        assert (gap < 1e-5) if fault is None else not gap < 1e-3, fault
+
+
+def test_bfloat16_step_passes_the_per_token_check(jax_cpu, tiny):
+    """reference_loss with a `program_check` answers the loss where the
+    program's own forward (bf16, the flash and filter kernels, the chunked
+    delta rule, the grouped-matmul kernels) agrees with the reference token
+    by token, and nan where any of its three bounds is broken."""
+    jax = jax_cpu
+    from benchmark.families import solar
+    _cfg, params, tokens = _program(jax, tiny, "flash")
+    checked = dict(tiny, program_check={"logprob_median_tol": 0.15,
+                                        "logprob_rms_tol": 0.6,
+                                        "logprob_p99_tol": 3.0})
+    with jax.default_matmul_precision("highest"):
+        plain = float(jax.jit(lambda p, t: solar.reference_loss(
+            p, t, tiny))(params, tokens))
+        held = float(jax.jit(lambda p, t: solar.reference_loss(
+            p, t, checked))(params, tokens))
+        broken = []
+        for key in ("logprob_median_tol", "logprob_rms_tol",
+                    "logprob_p99_tol"):
+            one = dict(checked, program_check=dict(checked["program_check"],
+                                                   **{key: 1e-6}))
+            broken.append(float(jax.jit(lambda p, t: solar.reference_loss(
+                p, t, one))(params, tokens)))
+    assert held == plain and np.isnan(broken).all()
+
+
+# ---------------------------------------------------------------------------
+# (d) the share: the parts add up to the whole
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind", ["kda", "attention"])
+def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(jax_cpu, tiny,
+                                                             kind):
+    """model-configs guide, section 4, with the heads shared too: a whole
+    layer of 16 experts and 8 mixer heads (4 key/value heads in the
+    grouped-query layer) over 4 chips that hold 4 experts each and, in pairs
+    (tensor parallel 2 inside each of two groups), 4 heads each. A head
+    share's mixer output is its heads' rows of the output projection's sum,
+    so the two head shares add up to the uncut mixer; the residual and the
+    shared expert are every chip's alike and count once; what the four
+    expert shares add (each from the SAME input, the full mixer's result,
+    as it has it after the all-reduce) adds up with them to the uncut
+    reference's layer."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import solar
+    from ray_tpu.models import gpt
+    from ray_tpu.models.gpt import GPTConfig, Setting, gpt_init
+    whole = copy.deepcopy(tiny)
+    del whole["share"]
+    whole.update(n_routed_experts=16, num_attention_heads=8,
+                 num_key_value_heads=4,
+                 gqa_layers=[0] if kind == "attention" else [])
+    whole["linear_attn_config"]["num_heads"] = 8
+    full_cfg = GPTConfig(**solar.gpt_config_kwargs(whole), dtype=jnp.float32,
+                         attention="reference", remat_policy="none")
+    assert full_cfg.experts_held is None
+    layer = gpt_init(jax.random.PRNGKey(7), full_cfg)["layers"][0]
+    group = "attn" if kind == "attention" else "kda"
+    assert sorted(layer) == sorted([group, "ln1", "ln2", "moe"])
+    layer["moe"]["router"] = 0.3 * jax.random.normal(
+        jax.random.PRNGKey(8), (128, 16))
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 64, 128), jnp.float32)
+    eps = float(tiny["rms_norm_eps"])
+
+    def reference_layer(h):
+        h = h + solar.reference_mixer(
+            layer, solar._norm(h, layer["ln1"]["scale"], eps), whole)
+        m = solar._norm(h, layer["ln2"]["scale"], eps)
+        shared = solar._swiglu(layer["moe"]["shared"], m, jnp.float32)
+        return h, h + shared, h + solar.reference_experts(layer["moe"], m,
+                                                          whole)
+
+    def head_share(rank):
+        """The mixer's parameters a chip of tensor rank `rank` holds: heads
+        4 rank .. 4 rank + 3 (key/value heads 2 rank, 2 rank + 1)."""
+        def cut(name, leaf):
+            heads = {"wk": 4, "wv": 4}.get(name, 8) if kind == "attention" \
+                else 8
+            if name in ("wo",):
+                return leaf.reshape(heads, -1, 128)[
+                    heads // 2 * rank:heads // 2 * (rank + 1)].reshape(-1, 128)
+            if name in ("wf_down", "wg_down", "o_norm"):
+                return leaf                     # every head's
+            if name in ("q_conv", "k_conv", "v_conv", "dt_bias", "a_log"):
+                parts = leaf.reshape((heads, -1) + leaf.shape[1:])
+                return parts[heads // 2 * rank:heads // 2 * (rank + 1)
+                             ].reshape((-1,) + leaf.shape[1:])
+            parts = leaf.reshape(leaf.shape[0], heads, -1)
+            return parts[:, heads // 2 * rank:heads // 2 * (rank + 1)
+                         ].reshape(leaf.shape[0], -1)
+        return {name: cut(name, leaf) for name, leaf in layer[group].items()}
+
+    held_heads = dict(tiny, num_attention_heads=4, num_key_value_heads=2,
+                      gqa_layers=whole["gqa_layers"])
+    with jax.default_matmul_precision("highest"):
+        mixed, alike, want = jax.vmap(reference_layer)(x)
+        # the eight heads' mixer, from its two shares of four
+        cfg = GPTConfig(**solar.gpt_config_kwargs(held_heads),
+                        dtype=jnp.float32, attention="reference",
+                        remat_policy="none")
+        assert cfg.n_heads == 4
+        normed = gpt._rmsnorm(x, layer["ln1"]["scale"], eps)
+        shares = []
+        for rank in range(2):
+            if kind == "kda":
+                part, _ = gpt._kda_block(head_share(rank), normed, cfg,
+                                         Setting())
+            else:
+                part, _ = gpt._attention_block({"attn": head_share(rank)},
+                                               normed, cfg, (), Setting())
+            shares.append(part)
+        np.testing.assert_allclose(x + sum(shares), mixed, atol=2e-5)
+        assert float(jnp.abs(x + shares[0] - mixed).max()) > 1e-2
+        # the sixteen experts, from the four shares of four, each on the
+        # all-reduced mixer output
+        parts, held_share = [], 0.0
+        normed = gpt._rmsnorm(mixed, layer["ln2"]["scale"], eps)
+        for rank in range(4):
+            cut = dict(held_heads, share=dict(tiny["share"], rank=rank))
+            cfg = GPTConfig(**solar.gpt_config_kwargs(cut),
+                            dtype=jnp.float32, attention="reference",
+                            remat_policy="none")
+            assert cfg.experts_held == (4 * rank, 4)
+            mine = {"moe": dict(layer["moe"], **{
+                name: layer["moe"][name][4 * rank:4 * rank + 4]
+                for name in ("w_gate", "w_up", "w_down")})}
+            out, stats = gpt._moe_block(mine, normed, cfg, Setting())
+            # the shared expert, the same on every chip, taken off
+            parts.append(mixed + out - alike)
+            held_share += float(stats["expert_slots_held_share"])
+    np.testing.assert_allclose(alike + sum(parts), want, atol=5e-5)
+    assert abs(held_share - 1.0) < 1e-6
+    assert float(jnp.abs(alike + parts[0] - want).max()) > 1e-2
+
+
+# ---------------------------------------------------------------------------
+# (e) arithmetic, rules, refusals, names
+# ---------------------------------------------------------------------------
+
+def test_param_count_is_the_cut_and_the_programs_tree(jax_cpu, tiny):
+    jax = jax_cpu
+    from benchmark.families import solar
+    from ray_tpu.models.gpt import GPTConfig, count_params, gpt_init
+    cell = _read("benchmark", "configs", "solar-open2-250b.json")
+    assert solar.param_count(cell) == 840_872_600        # ISSUE 46's 840.8M
+    assert solar.share(cell) == (0, 8, 320)
+    for config in (cell, tiny):
+        cfg = GPTConfig(**solar.gpt_config_kwargs(config))
+        tree = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+        assert solar.param_count(config) == count_params(tree)
+    # the mixers at the heads held, by ISSUE 46's arithmetic
+    gqa = tree["layers"][0]["attn"]
+    assert count_params(gqa) == 128 * 32 * (3 * 4 + 2 * 2)
+    m = solar._matrices(cell)
+    assert m["attention"] == 13_631_488 and m["kda"] == 18_120_704
+    assert m["expert"] == 15_728_640 == m["shared"]
+    # the published model: 250B, 15B a token, its name (250B-A15B); a gate a
+    # head for the element gate (assumed) would be 0.40B fewer over 12 layers
+    published = {k: v for k, v in cell.items() if k != "share"}
+    published.update(cell["published"])
+    assert round(solar.param_count(published) / 1e9) == 250
+    assert round(solar.active_param_count(published) / 1e9) == 15
+    assert round(12 * 4096 * (64 * 128 - 64) / 1e9, 2) == 0.40
+
+
+def test_flops_and_kernel_arithmetic_count_what_is_computed_here():
+    from benchmark.families import solar
+    from benchmark.kernels import gqa_attention, kda
+    cell = _read("benchmark", "configs", "solar-open2-250b.json")
+    mix = _read("benchmark", "traffic", "train_b1_s8192_dp.json")
+    d, s = 4096, 8192
+    expert = 3 * d * 1280
+    active = (13_631_488 + 3 * 18_120_704
+              + 4 * (d * 320 + expert + 8 * 8 / 320 * expert) + d * 24576)
+    rule = 5 * 64 * 128 + 6 * 128 * 128         # a token and head, forward
+    assert kda.delta_rule_flops_per_token(64, 128, 128) == rule == 139_264
+    assert solar.train_flops_per_token(cell, s) == pytest.approx(
+        6.0 * active + 3.0 * (8 * 4 * 128 * s / 2 + 3 * 8 * rule))
+    assert solar.forward_flops_per_token(cell, s) == pytest.approx(
+        0.52e9, rel=0.02)                  # ISSUE 46's ~0.52 GFLOP a token
+    assert solar.attention_call(cell, mix) == {
+        "batch": 1, "heads": 8, "kv_heads": 1, "seq": s, "head_dim": 128}
+    assert solar.kda_call(cell, mix) == {
+        "batch": 1, "heads": 8, "seq": s, "head_dim": 128, "taps": 4,
+        "chunk": 64}
+    assert gqa_attention.flash_fwd(cell, mix)[0] == 2 * 8 * s * s * 128
+    elements = s * 1024
+    assert kda.conv_silu_fwd(cell, mix) == (11 * elements, 4 * elements)
+    assert kda.conv_silu_bwd(cell, mix) == (32 * elements, 6 * elements)
+    flops, moved = kda.delta_rule(cell, mix)
+    assert flops == 8 * s * rule
+    assert moved == 8 * s * (8 * 128 + 4 * 128 + 4 + 2 * 4 * 128 * 128 / 64)
+
+
+@pytest.mark.parametrize("strategy,column,row", [
+    ("tp", (None, "tensor"), ("tensor", None)),
+    ("tp_fsdp", ("fsdp", "tensor"), ("tensor", "fsdp"))])
+def test_every_new_leaf_gets_its_rule(jax_cpu, tiny, strategy, column, row):
+    jax = jax_cpu
+    from jax.sharding import PartitionSpec as P
+    from benchmark.families import solar
+    from ray_tpu.models.gpt import GPTConfig, gpt_init
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+    cfg = GPTConfig(**solar.gpt_config_kwargs(tiny))
+    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+    mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2),
+                      devices=jax.devices()[:4])
+    specs = jax.tree_util.tree_map(
+        lambda s: s.spec,
+        strategy_from_name(strategy).param_shardings(mesh, params))
+    attn, kda = specs["layers"][0]["attn"], specs["layers"][1]["kda"]
+    # the gate an element: its columns are whole heads too
+    assert attn["wq"] == attn["wk"] == attn["wg"] == P(*column)
+    assert kda["wq"] == kda["wk"] == kda["wv"] == kda["w_beta"] == P(*column)
+    assert kda["wo"] == attn["wo"] == P(*row)
+    assert kda["wf_up"] == kda["wg_up"] == P(None, "tensor")
+    assert kda["wf_down"] == kda["wg_down"] == P(column[0], None)
+    assert kda["q_conv"] == kda["k_conv"] == kda["v_conv"] \
+        == P("tensor", None)
+    assert kda["a_log"] == kda["dt_bias"] == P("tensor")
+    assert kda["o_norm"]["scale"] == P(None)
+
+
+def test_sharded_step_equals_one_device(jax_cpu, tiny):
+    """One step of a grouped-query and a delta-rule layer on tensor=2 (two
+    delta-rule heads with their filters, decay rates and step biases, and a
+    key/value head with its two query heads and their gates, on a shard of
+    `tensor`; the filter and flash kernels per shard) equals the one-device
+    step: the `kda/*` rows of parallel/sharding.py's table."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    import optax
+    from benchmark.families import solar
+    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+    from ray_tpu.train.train_step import init_train_state, make_train_step
+    cfg = GPTConfig(**solar.gpt_config_kwargs(
+        dict(tiny, num_hidden_layers=2)), dtype=jnp.float32,
+        attention="flash")
+    tokens = jnp.asarray(np.random.default_rng(5).integers(
+        0, 512, (2, 129), dtype=np.int32))
+
+    def one_step(name, axes, n):
+        mesh = build_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
+        strategy = strategy_from_name(name)
+        optimizer = optax.sgd(0.1)
+        state = init_train_state(
+            lambda: gpt_init(jax.random.PRNGKey(3), cfg), optimizer, mesh,
+            strategy)
+        step = make_train_step(
+            lambda p, b: gpt_loss(
+                p, b, cfg, mesh=mesh,
+                act_sharding=strategy.activation_sharding(mesh)),
+            optimizer, mesh, strategy, sample_params=state.params)
+        with jax.default_matmul_precision("highest"):
+            state, metrics = step(state, {"tokens": tokens})
+        return float(metrics["loss"]), jax.device_get(state.params)
+
+    ref_loss, ref_params = one_step("dp", {"data": 1}, 1)
+    loss, params = one_step("tp", {"data": 1, "tensor": 2}, 2)
+    assert abs(loss - ref_loss) < 1e-5
+    for (path, p), r in zip(jax.tree_util.tree_flatten_with_path(params)[0],
+                            jax.tree_util.tree_leaves(ref_params)):
+        np.testing.assert_allclose(p, r, rtol=1e-4, atol=1e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("change,says", [
+    ({"attention": "ring"}, "'kda' layer's state.*attention='ring'"),
+    ({"attention_gate": "head"}, "attention_gate='head'"),
+    ({"use_rope": False, "index_topk": 4, "index_heads": 2,
+      "index_head_dim": 16, "layer_kinds": None}, "use_rope=False.*indexer"),
+    ({"layer_kinds": ("attention", "gdn", "gdn", "gdn")},
+     "'conv' | 'window' | 'kda'"),
+], ids=["ring", "gate_name", "indexer_unrotated", "kinds_names"])
+def test_the_configuration_refuses_by_name(tiny, change, says):
+    from benchmark.families import solar
+    from ray_tpu.models.gpt import GPTConfig
+    with pytest.raises(ValueError, match=says):
+        GPTConfig(**dict(solar.gpt_config_kwargs(tiny), **change))
+
+
+def test_the_kind_is_read_off_the_parameters_and_no_table_is_built(jax_cpu,
+                                                                   tiny):
+    """A layer's mixer is what its parameters hold (`kda` | `attn`), and a
+    stack that rotates nothing builds no rope table: no cosine in the
+    forward's jaxpr, where the same stack with use_rope has them."""
+    jax = jax_cpu
+    import dataclasses
+    import jax.numpy as jnp
+    from benchmark.families import solar
+    from ray_tpu.models.gpt import GPTConfig, gpt_forward, gpt_init
+    cfg = GPTConfig(**solar.gpt_config_kwargs(tiny), dtype=jnp.float32)
+    assert cfg.rope_of("attention") is None
+    params = gpt_init(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((1, 128), jnp.int32)
+
+    def text(c, p):
+        return str(jax.make_jaxpr(lambda p: gpt_forward(p, tokens, c)[0])(p))
+    assert " cos " not in text(cfg, params)
+    rotating = dataclasses.replace(cfg, use_rope=True)
+    assert rotating.rope_of("attention").plain
+    assert " cos " in text(rotating, params)
+    # the same configuration walks the layers in the other order when their
+    # parameters are: nothing reads layer_kinds after gpt_init
+    swapped = dict(params, layers=params["layers"][::-1])
+    out, stats = jax.jit(lambda p: gpt_forward(p, tokens, cfg))(swapped)
+    assert np.isfinite(out).all() and "kda_beta_mean" in stats
+    # and a stack without delta-rule layers hands back none of their
+    # statistics
+    plain = dataclasses.replace(cfg, layer_kinds=("attention",) * 4)
+    _, stats = jax.jit(lambda p: gpt_forward(p, tokens, plain))(
+        gpt_init(jax.random.PRNGKey(0), plain))
+    assert "kda_beta_mean" not in stats
+
+
+def test_pipeline_refuses_by_what_it_observes(jax_cpu, tiny):
+    """parallel/pipeline.py has never heard of a delta-rule layer: it
+    refuses the cell's stack because its layers are not alike, and a stack
+    of delta-rule layers alone because the block hands back statistics."""
+    jax = jax_cpu
+    from benchmark.families import solar
+    from ray_tpu.models.gpt import GPTConfig
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.pipeline import make_gpt_pp_loss
+    mesh = build_mesh(MeshConfig(pipeline=1), devices=jax.devices()[:1])
+    kwargs = dict(solar.gpt_config_kwargs(tiny), max_seq=64)
+    with pytest.raises(ValueError, match="are not layer 0's"):
+        make_gpt_pp_loss(GPTConfig(**kwargs), mesh, 1)
+    alike = dict(kwargs, layer_kinds=("kda",) * 4, n_experts=0,
+                 experts_held=None, n_shared_experts=0)
+    with pytest.raises(ValueError, match="statistics.*kda_beta_mean"):
+        make_gpt_pp_loss(GPTConfig(**alike), mesh, 1)
+
+
+def _kernel_calls(jax, jaxpr, rematted=False):
+    """(kernel name, whether it runs in a layer's recompute pass: under a
+    checkpoint equation of the backward) for every pallas_call of jaxpr
+    (tests/test_flash_remat.py's walk)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"], rematted
+        inner = rematted or eqn.params.get("differentiated", False)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_calls(jax, sub, inner)
+
+
+def _scans(jax, jaxpr, rematted=False):
+    """(length, whether in a recompute pass) of every scan of jaxpr."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn.params["length"], rematted
+        inner = rematted or eqn.params.get("differentiated", False)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(jax, sub, inner)
+
+
+def test_the_delta_rule_runs_its_forward_once_a_layer(jax_cpu, tiny):
+    """The step's calls are the counter. Under remat_policy="full" a
+    delta-rule layer's output is kept (KDA_OUT), so the scan over the chunk
+    states runs forward once a layer, once more inside the backward rule
+    (the chunked form differentiated again) and once transposed; the filter
+    kernels, which XLA's recompute pass holds, run forward and recomputed;
+    the grouped-query layer's forward kernel once."""
+    jax = jax_cpu
+    from collections import Counter
+    from benchmark.families import solar
+    from ray_tpu.models.gpt import gpt_init, gpt_loss
+    cfg = solar._train_config(tiny)
+    assert cfg.remat_policy == "full"
+    params = gpt_init(jax.random.PRNGKey(0), cfg)
+    tokens = np.zeros((2, 129), np.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: gpt_loss(p, {"tokens": tokens}, cfg)))(params)
+    calls = Counter(_kernel_calls(jax, jaxpr.jaxpr))
+    assert calls[("flash_fwd", False)] == 1 and calls[("flash_fwd", True)] == 0
+    assert calls[("conv_silu_fwd", False)] == calls[("conv_silu_fwd", True)] \
+        == 9
+    assert calls[("conv_silu_bwd", False)] + calls[("conv_silu_bwd", True)] \
+        == 9
+    # 128 tokens are two chunk states a layer
+    scans = Counter(_scans(jax, jaxpr.jaxpr))
+    assert scans[(2, False)] == 3 and scans[(2, True)] == 6
+
+
+def test_the_new_scopes_are_regions_and_reach_the_compiled_step(jax_cpu,
+                                                                tiny):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import solar
+    from ray_tpu.models.gpt import gpt_init, gpt_loss
+    from ray_tpu.util import profiling
+    assert {"kda", "kda_core"} <= set(profiling.REGIONS)
+    assert {"conv_silu_fwd", "conv_silu_bwd"} <= set(profiling.KERNELS)
+    cfg = solar._train_config(dict(tiny, num_hidden_layers=2))
+    params = gpt_init(jax.random.PRNGKey(0), cfg)
+    text = jax.jit(jax.grad(lambda p, t: gpt_loss(p, {"tokens": t}, cfg))
+                   ).lower(params, jnp.zeros((2, 129), jnp.int32)
+                           ).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    regions = {profiling._last_of(n, profiling.REGIONS) for n in names}
+    assert {"kda", "kda_core", "attn_gate", "attn_proj", "attn_core",
+            "attn_out", "moe", "moe_route", "moe_shared"} <= regions
+    # the delta rule's ops are kda_core's in every phase, the scan's body
+    # among them; the filters' are kda's
+    core = [n for n in names
+            if profiling._last_of(n, profiling.REGIONS) == "kda_core"]
+    assert any("transpose(" in n for n in core)
+    assert any("while" in n for n in core)
+    for n in names:
+        if "conv_silu" in n:
+            assert profiling._last_of(n, profiling.REGIONS) == "kda"
+
+
+def test_configuration_file_keeps_the_catalog_and_states_the_cut():
+    cell = _read("benchmark", "configs", "solar-open2-250b.json")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(catalog):
+        pytest.skip("no catalog here")
+    with open(catalog) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == cell["source"])
+    changed = {k for k, v in row["config"].items() if cell.get(k, "?") != v}
+    assert changed == set(cell["reduced"]) == {
+        "num_hidden_layers", "n_routed_experts", "vocab_size",
+        "num_attention_heads", "num_key_value_heads", "linear_attn_config",
+        "gqa_layers"}
+    assert cell["published"] == {k: row["config"][k] for k in cell["reduced"]}
+    # of the group, the head count alone: no width moves
+    assert dict(cell["linear_attn_config"], num_heads=64) \
+        == row["config"]["linear_attn_config"]
+    # published layers 0..3: one whole period
+    assert cell["gqa_layers"] == [0] == [
+        i for i in row["config"]["gqa_layers"] if i < 4]
+    share = cell["share"]
+    assert share["expert_parallel"] == share["chips_per_layer"] == 40
+    assert share["expert_parallel"] * cell["n_routed_experts"] \
+        == share["n_routed_experts"] == 320
+    assert share["tensor_parallel"] * cell["vocab_size"] == 196608
+    assert share["tensor_parallel"] * cell["num_attention_heads"] \
+        == share["num_attention_heads"] == 64
+    assert share["tensor_parallel"] * cell["num_key_value_heads"] == 8
+    assert share["tensor_parallel"] * cell["linear_attn_config"][
+        "num_heads"] == share["linear_attn_heads"] == 64
+    assert {"kda_form", "gqa_gate", "router_score", "decay_init",
+            "sequence_length"} <= set(cell["assumed"])
+    bench = _read("BENCHMARK.json")
+    entry = next(c for c in bench["configs"] if c["name"] == cell["name"])
+    assert entry["reduced"] == cell["reduced"]
+    assert entry["source"] == cell["source"]
+    peak = cell["reduced_why"]["memory_peak_bytes"]
+    assert 0.25 * 16.91e9 < peak["chip"] < 16.91e9

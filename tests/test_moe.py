@@ -197,6 +197,11 @@ def test_plan_holds_every_slot_once(jax_cpu):
 
 @pytest.mark.parametrize("slots,experts,dtype,rows", [
     (65536, 64, "bfloat16", 256),     # olmoe_train_1chip
+    # solar2_train_1chip's share (8192 x 8 slots, 8 of 320 experts held): a
+    # mean group of 204 rows fills the MXU's 128, so no tile is under them
+    (1638, 8, "bfloat16", 128),
+    # a mean group of at most 128 rows keeps the quarter
+    (800, 8, "bfloat16", 16), (1024, 8, "bfloat16", 32),
     (192, 8, "float32", 8), (192, 8, "bfloat16", 16), (8, 4, "float32", 8)])
 def test_tile_rows_follow_from_the_shape(slots, experts, dtype, rows):
     from ray_tpu.ops import moe

@@ -1,9 +1,10 @@
 """How a transformer's parameters are divided over the mesh, leaf for leaf:
-every parameter of the benchmark's architectures (the six rehearsal
-configurations hold every parameter name of the seven cells) under `tp` and
+every parameter of the benchmark's architectures (the seven rehearsal
+configurations hold every parameter name of the eight cells) under `tp` and
 `tp_fsdp` on fsdp=2 x tensor=2, and the dense one, stacked, under `pp` and
 `pp_tp`. The expectations were recorded at PR 42, before
-parallel/sharding.py's rule lists became one table: a change of that table
+parallel/sharding.py's rule lists became one table (a delta-rule layer's
+`kda/*` at PR 46, with its rows): a change of that table
 that moves a leaf's PartitionSpec shows here, whichever architecture the
 leaf belongs to."""
 
@@ -49,6 +50,19 @@ GSPMD = {
     "layers/*/conv/w_in": ((None, None, T), (None, F, T)),
     "layers/*/conv/filter": ((T, None), (T, None)),
     "layers/*/conv/w_out": ROW,
+    "layers/*/kda/wq": COLUMN, "layers/*/kda/wk": COLUMN,
+    "layers/*/kda/wv": COLUMN, "layers/*/kda/w_beta": COLUMN,
+    "layers/*/kda/wo": ROW,
+    "layers/*/kda/wf_up": ((None, T), (None, T)),
+    "layers/*/kda/wg_up": ((None, T), (None, T)),
+    "layers/*/kda/wf_down": ((None, None), (F, None)),
+    "layers/*/kda/wg_down": ((None, None), (F, None)),
+    "layers/*/kda/q_conv": ((T, None), (T, None)),
+    "layers/*/kda/k_conv": ((T, None), (T, None)),
+    "layers/*/kda/v_conv": ((T, None), (T, None)),
+    "layers/*/kda/a_log": ((T,), (T,)),
+    "layers/*/kda/dt_bias": ((T,), (T,)),
+    "layers/*/kda/o_norm/scale": WHOLE_VECTOR,
     "layers/*/mlp/w_gate": COLUMN, "layers/*/mlp/w_up": COLUMN,
     "layers/*/mlp/w_down": ROW,
     "layers/*/moe/router": WHOLE_MATRIX,
@@ -89,7 +103,7 @@ EXPECTED = {"tp": (GSPMD, 0, NO_ROW), "tp_fsdp": (GSPMD, 1, NO_ROW),
 
 CASES = [(name, strategy)
          for name in ("tiny", "tiny-olmoe", "tiny-kanana", "tiny-lfm2",
-                      "tiny-laguna", "tiny-keye")
+                      "tiny-laguna", "tiny-keye", "tiny-solar")
          for strategy in ("tp", "tp_fsdp")] + [("tiny", "pp"),
                                                ("tiny", "pp_tp")]
 
