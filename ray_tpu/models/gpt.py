@@ -965,7 +965,10 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
         if cfg.kda_neg_eigval:
             beta = 2.0 * beta
         with jax.named_scope("kda_core"):
-            o = kda(q, k, v, log_decay, beta)
+            whole_heads = ("batch", "heads", None, None)
+            o = _per_shard(kda, where.mesh,
+                           (whole_heads,) * 4 + (("batch", "heads", None),),
+                           whole_heads)(q, k, v, log_decay, beta)
         stats = {"kda_log_decay_min": jnp.min(chunk_log_decay(log_decay)),
                  "kda_beta_mean": jnp.mean(beta)}
         # a head is whole wherever its columns are: no psum
@@ -1188,8 +1191,8 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     if cfg.remat_policy == "full":
         # (of an indexer, its selection and its loss's gradients: the walk
         # over the score tiles then runs once a layer and step; of a
-        # delta-rule layer, its output: the backward runs the chunked form
-        # again itself)
+        # delta-rule layer, its output and its chunks' states: `kda_bwd`
+        # computes a chunk again from them)
         return jax.checkpoint(
             block, policy=jax.checkpoint_policies.save_only_these_names(
                 FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK,

@@ -15,8 +15,9 @@ state float32. The caller normalises and scales q and k.
 
 Two formulations. `kda_reference` is the recurrence as it stands, a token a
 step of a `lax.scan` (the oracle, never the timed path). `kda` is its
-chunked form under a custom_vjp. With g_t the cumulative log-decay inside a
-chunk of C tokens (g <= 0, falling) and S_0 the state the chunk starts from,
+chunked form as two Pallas kernels under a custom_vjp. With g_t the
+cumulative log-decay inside a chunk of C tokens (g <= 0, falling) and S_0
+the state the chunk starts from,
 
     S_t = Diag(e^{g_t}) S_0 + sum_{s<=t} Diag(e^{g_t - g_s}) k_s u_s^T,
     u_t = beta_t (v_t - (Diag(alpha_t) S_{t-1})^T k_t)
@@ -27,7 +28,7 @@ k_t[c] k_s[c] e^{g_t[c] - g_s[c]} below the diagonal and Kbar_t = e^{g_t} k_t
 Diag(beta) [V | Kbar], which needs no state), O = Qbar S_0 + Aqk U with Aqk
 the same products of q with k on and below the diagonal, and the next
 chunk's S_0 = Diag(e^{g_C}) S_0 + Ktilde^T U, Ktilde_s = e^{g_C - g_s} k_s.
-Only the last line is sequential: a `lax.scan` over the S / C chunk states.
+Only the last line is sequential.
 
 No product divides by a decay. e^{g_t - g_s} does not factor into e^{g_t}
 e^{-g_s} safely (e^{-g_s} overflows where the decay underflows), so A is
@@ -41,23 +42,34 @@ Diag(beta) A, which is unit lower triangular: with X the inverse of its
 diagonal blocks of b tokens and M the level's pairs, X - X M X is the
 inverse of the blocks of 2b (block forward substitution, as matmuls).
 
-The backward differentiates the chunked form again from the five inputs
-(`jax.vjp` inside the rule: the chunk states are recomputed, none is kept),
-each level's scaled copies under a `jax.checkpoint` of their own; the
-levels are a `lax.scan`, one body for the compiler. The
-output carries the name KDA_OUT, so that a remat policy that saves it runs
-the forward once a layer and step, as FLASH_OUT does for the flash kernels.
-All of it is XLA: einsums over [B, H, chunks] and one scan.
+`_chunk` states all of that once, for one chunk of a few heads, as a jnp
+function of values that live in VMEM (a chunk's q, k, v, g are 32 KB each
+at [64, 128], its A and inverse 16 KB, a state 64 KB). `kda_fwd` runs it
+over a grid (heads / h, chunks), the chunks in order, the state in VMEM
+scratch: a chunk's operands cross HBM once and every intermediate stays on
+the chip. `kda_bwd` runs `jax.vjp` of the same function over the chunks
+from the last to the first, the state's cotangent in scratch: it computes
+the chunk again from its inputs and the state it started from, which the
+forward keeps ([heads, chunks, dv, dk] float32, 67 MB a layer at [1, 8,
+8192, 128]), and writes the five gradients, the log-decay's among them
+(the doubling of `_chunk` is linear in it). The output and the kept states
+carry the name KDA_OUT, so that a remat policy that saves it runs the
+forward once a layer and step, as FLASH_OUT does for the flash kernels.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import LANES
 
 # The name the output carries (jax.ad_checkpoint.checkpoint_name).
 KDA_OUT = "kda_out"
@@ -100,129 +112,225 @@ def chunk_log_decay(log_decay, chunk: int = 64):
     return jnp.cumsum(a.reshape(b, h, (s + pad) // chunk, chunk, dk), axis=3)
 
 
-def _tree(chunk: int):
-    """The binary tree of a chunk, a row a level (blocks of 2b = 2 << level
-    tokens): which tokens lie in their block's right half [L, C], which
-    pairs share a block [L, C, C], and, as 0 / 1 rows, each token's
-    reference, its block's last token of the left half [L, C, C]."""
-    at = np.arange(chunk)
-    half = 1 << np.arange(chunk.bit_length() - 1)[:, None]
-    block = at[None] // (2 * half)
-    reference = block * 2 * half + half - 1
-    return ((at[None] // half) % 2 == 1,
-            block[:, :, None] == block[:, None, :],
-            (reference[:, :, None] == at[None, None, :]).astype(np.float32))
+def _dot(x, y, contract):
+    """x . y over `contract` (one dimension of each) a head, the heads
+    leading both: float32, full precision."""
+    return jax.lax.dot_general(
+        x, y, ((contract[:1], contract[1:]), ((0,), (0,))),
+        precision=_PRECISION, preferred_element_type=jnp.float32)
 
 
-def _within_chunks(q, k, v, g, beta):
-    """What needs no state, for every chunk at once: q, k [.., C, dk], v
-    [.., C, dv], g the cumulative log-decay [.., C, dk], beta [.., C] ->
-    (Wv [.., C, dv], Wk [.., C, dk], Aqk [.., C, C]). The levels of the
-    tree are a scan (one body for the compiler, whatever the chunk), each
-    under a jax.checkpoint: its scaled copies are made again backward."""
-    chunk, dv = g.shape[-2], v.shape[-1]
-
-    def level(carry, tree):
-        inverse, aqk = carry
-        right, same_block, reference = tree
-        # (a 0 / 1 product at full precision picks the row to the bit)
-        ref = jnp.einsum("ts,...sd->...td", reference, g,
-                         precision=jax.lax.Precision.HIGHEST)
-        rows = jnp.exp(jnp.where(right[:, None], g - ref, -jnp.inf))
-        cols = k * jnp.exp(jnp.where(right[:, None], -jnp.inf, ref - g))
-
-        def pairs(x):
-            # (t in a block's right half, s in its left); zero elsewhere
-            return jnp.where(same_block, jnp.einsum(
-                "...td,...sd->...ts", x * rows, cols, precision=_PRECISION),
-                0.0)
-        m = beta[..., None] * pairs(k)
-        inverse = inverse - jnp.einsum(
-            "...ts,...su,...uw->...tw", inverse, m, inverse,
-            precision=_PRECISION)
-        return (inverse, aqk + pairs(q)), None
-
-    eye = jnp.eye(chunk, dtype=g.dtype)
-    on_diagonal = eye * jnp.sum(q * k, axis=-1)[..., None]
-    (inverse, aqk), _ = jax.lax.scan(
-        jax.checkpoint(level),
-        (jnp.broadcast_to(eye, on_diagonal.shape), on_diagonal),
-        tuple(map(jnp.asarray, _tree(chunk))))
-    solved = jnp.einsum(
-        "...ts,...sd->...td", inverse,
-        beta[..., None] * jnp.concatenate([v, k * jnp.exp(g)], axis=-1),
-        precision=_PRECISION)
-    return solved[..., :dv], solved[..., dv:], aqk
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rows_down(x, shift: int):
+    """x [h, C, d] with row t holding x[t - shift], around the end (a
+    sublane rotation; its transpose is the rotation back)."""
+    return pltpu.roll(x, shift, 1)
 
 
-def _across_chunks(wv, wk, k_end, decay):
-    """The sequential part: wv [B, H, N, C, dv], wk and k_end (Ktilde) [B,
-    H, N, C, dk], decay (a chunk's whole) [B, H, N, dk] -> (the state each
-    chunk starts from [B, H, N, dk, dv], U [B, H, N, C, dv])."""
-    def step(state, x):
-        wv_n, wk_n, k_n, decay_n = x
-        u = wv_n - jnp.einsum("bhck,bhkv->bhcv", wk_n, state,
-                              precision=_PRECISION)
-        after = state * decay_n[..., None] + jnp.einsum(
-            "bhck,bhcv->bhkv", k_n, u, precision=_PRECISION)
-        return after, (state, u)
-
-    b, h, _, _, dv = wv.shape
-    by_chunk = tuple(jnp.moveaxis(x, 2, 0) for x in (wv, wk, k_end, decay))
-    _, (states, u) = jax.lax.scan(
-        step, jnp.zeros((b, h, wk.shape[-1], dv), wv.dtype), by_chunk)
-    return jnp.moveaxis(states, 0, 2), jnp.moveaxis(u, 0, 2)
+def _rows_down_fwd(x, shift):
+    return _rows_down(x, shift), None
 
 
-def _kda_chunked(q, k, v, log_decay, beta, chunk: int):
-    """The chunked form, forward."""
-    if chunk & (chunk - 1):
-        raise ValueError(f"chunk={chunk} is not a power of two")
+def _rows_down_bwd(shift, _, g):
+    return (pltpu.roll(g, g.shape[1] - shift, 1),)
+
+
+_rows_down.defvjp(_rows_down_fwd, _rows_down_bwd)
+
+
+def _chunk(q, k, v, a, beta, state):
+    """One chunk of h heads, on values that live in VMEM: q, k, the
+    log-decay a [h, C, dk], v [h, C, dv], beta [h, 1, C] (a row: tokens
+    along the lanes) and the state the chunk starts from, transposed [h,
+    dv, dk], all float32 -> (o [h, C, dv], the state after the chunk). The
+    one statement of the chunked form: the forward kernel runs it and the
+    backward kernel runs its jax.vjp.
+
+    The decays between the pairs of a level need no cumulative sum and no
+    reference row: with F_b[t] the log-decay summed from the start of t's
+    block of b tokens up to t, B_b[t] from after t to the block's end and
+    T_b their sum (the block's whole), e^{g_t - g_r} = e^{F_b[t]} for t in a
+    right half and e^{g_r - g_s} = e^{B_b[s]} for s in a left half, and a
+    level doubles them by adding the sibling block's whole, T_b moved b
+    rows (F_1 = T_1 = a, B_1 = 0; at the top F = g, B = g_C - g, T =
+    g_C). Every exponent is <= 0."""
+    chunk = q.shape[1]
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, 1), 1)
+    t = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 1)
+    s = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 2)
+    eye = t == s
+    # beta a row in, a column here: the diagonal's row sums
+    beta = jnp.sum(jnp.where(eye, beta, 0.0), axis=2, keepdims=True)
+    since, until, whole = a, jnp.zeros_like(a), a
+    aqk = jnp.where(eye, jnp.sum(q * k, axis=2, keepdims=True), 0.0)
+    inverse = jnp.broadcast_to(eye.astype(jnp.float32), aqk.shape)
+    for level in range(chunk.bit_length() - 1):
+        half = 1 << level
+        # (t in a block's right half, s in its left), zero elsewhere
+        pairs = (((t ^ s) >> level) == 1) & (t > s)
+        rows, cols = jnp.exp(since), k * jnp.exp(until)
+        m = jnp.where(pairs, beta * _dot(k * rows, cols, (2, 2)), 0.0)
+        aqk = aqk + jnp.where(pairs, _dot(q * rows, cols, (2, 2)), 0.0)
+        inverse = inverse - (m if level == 0 else _dot(
+            _dot(inverse, m, (2, 1)), inverse, (2, 1)))
+        right = ((at >> level) & 1) == 1
+        before = _rows_down(whole, half)
+        after = _rows_down(whole, chunk - half)
+        since = since + jnp.where(right, before, 0.0)
+        until = until + jnp.where(right, 0.0, after)
+        whole = whole + jnp.where(right, before, after)
+    decayed = jnp.exp(since)                        # e^g, the chunk's own
+    wv = _dot(inverse, beta * v, (2, 1))
+    wk = _dot(inverse, beta * (k * decayed), (2, 1))
+    u = wv - _dot(wk, state, (2, 2))
+    o = _dot(q * decayed, state, (2, 2)) + _dot(aqk, u, (2, 1))
+    after = (state * jnp.exp(whole[:, :1])
+             + _dot(u, k * jnp.exp(until), (1, 1)))
+    return o, after
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, states_ref,
+                state):
+    """Grid (heads / h, chunks), the chunks in order: `state` [h, dv, dk]
+    carries each head's state; states_ref keeps what a chunk started from
+    for the backward."""
     f32 = jnp.float32
-    b, h, s, _ = q.shape
-    dv = v.shape[-1]
-    pad = -s % chunk
-    n = (s + pad) // chunk
 
-    def chunks(x):
-        # a padded token has k = 0, beta = 0, no decay: the state passes it
-        x = jnp.pad(x.astype(f32), ((0, 0), (0, 0), (0, pad))
-                    + ((0, 0),) * (x.ndim - 3))
-        return x.reshape((b, h, n, chunk) + x.shape[3:])
-    out_dtype = q.dtype
-    q, k, v, beta = chunks(q), chunks(k), chunks(v), chunks(beta)
-    g = chunk_log_decay(log_decay, chunk)
-    wv, wk, aqk = _within_chunks(q, k, v, g, beta)
-    states, u = _across_chunks(wv, wk, k * jnp.exp(g[..., -1:, :] - g),
-                               jnp.exp(g[..., -1, :]))
-    o = (jnp.einsum("...tk,...kv->...tv", q * jnp.exp(g), states,
-                    precision=_PRECISION)
-         + jnp.einsum("...ts,...sv->...tv", aqk, u, precision=_PRECISION))
-    return o.reshape(b, h, n * chunk, dv)[:, :, :s].astype(out_dtype)
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+    states_ref[:, 0] = state[...]
+    o, state[...] = _chunk(q_ref[...].astype(f32), k_ref[...].astype(f32),
+                           v_ref[...].astype(f32), a_ref[...],
+                           beta_ref[:, 0], state[...])
+    o_ref[...] = o.astype(o_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kda(q, k, v, log_decay, beta, chunk):
-    return _kda_chunked(q, k, v, log_decay, beta, chunk)
+def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, da_ref, dbeta_ref, dstate):
+    """Grid (heads / h, chunks), the chunks from the last to the first:
+    `dstate` [h, dv, dk] carries the cotangent of the state a chunk hands
+    on. A chunk is computed again from its inputs and the state it started
+    from, and transposed."""
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+    _, pull = jax.vjp(_chunk, q_ref[...].astype(f32), k_ref[...].astype(f32),
+                      v_ref[...].astype(f32), a_ref[...], beta_ref[:, 0],
+                      states_ref[:, 0])
+    dq, dk, dv, da, dbeta, dstate[...] = pull(
+        (do_ref[...].astype(f32), dstate[...]))
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+    da_ref[...] = da
+    dbeta_ref[:, 0] = dbeta
 
 
-def _kda_fwd(q, k, v, log_decay, beta, chunk):
-    out = checkpoint_name(_kda_chunked(q, k, v, log_decay, beta, chunk),
-                          KDA_OUT)
-    return out, (q, k, v, log_decay, beta)
+# Heads a grid step, at most: their products are batched, and the chains of
+# dependent ones interleave (at [1, 8, 8192, 128] on a v5e 2.03 / 6.29 ms
+# forward / backward at 4, 3.05 / 9.19 at 1, 2.01 / 7.49 at 8).
+_HEADS = 4
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=64 << 20)
 
 
-def _kda_bwd(chunk, inputs, g):
-    _, pull = jax.vjp(functools.partial(_kda_chunked, chunk=chunk), *inputs)
-    return pull(g)
+@functools.lru_cache(maxsize=None)
+def _make_kda_fn(chunk: int, interpret: bool):
+    """kda_fwd with kda_bwd as its backward, on [heads, tokens, width]
+    operands of whole chunks and whole lane tiles; beta [heads, chunks, 1,
+    chunk]. The residuals are the five inputs and the chunks' states."""
+
+    def specs(heads, dk, dv, order):
+        h = max(d for d in range(1, _HEADS + 1) if heads % d == 0)
+        wide = lambda d: pl.BlockSpec((h, chunk, d),
+                                      lambda i, j: (i, order(j), 0))
+        beta = pl.BlockSpec((h, 1, 1, chunk),
+                            lambda i, j: (i, order(j), 0, 0))
+        states = pl.BlockSpec((h, 1, dv, dk),
+                              lambda i, j: (i, order(j), 0, 0))
+        return h, wide(dk), wide(dv), beta, states
+
+    def forward(q, k, v, a, beta):
+        heads, tokens, dk = q.shape
+        dv, n = v.shape[-1], tokens // chunk
+        h, key, value, row, states = specs(heads, dk, dv, lambda j: j)
+        return pl.pallas_call(
+            _fwd_kernel,
+            grid=(heads // h, n),
+            in_specs=[key, key, value, key, row],
+            out_specs=[value, states],
+            out_shape=[jax.ShapeDtypeStruct(v.shape, q.dtype),
+                       jax.ShapeDtypeStruct((heads, n, dv, dk), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((h, dv, dk), jnp.float32)],
+            compiler_params=_PARAMS,
+            interpret=interpret,
+            name="kda_fwd",
+        )(q, k, v, a, beta)
+
+    @jax.custom_vjp
+    def f(q, k, v, a, beta):
+        return forward(q, k, v, a, beta)[0]
+
+    def fwd(q, k, v, a, beta):
+        o, states = forward(q, k, v, a, beta)
+        # both kept by a remat policy that saves the name: the forward runs
+        # once a layer and step
+        return checkpoint_name(o, KDA_OUT), (
+            q, k, v, a, beta, checkpoint_name(states, KDA_OUT))
+
+    def bwd(residuals, g):
+        q, k, v, a, beta, kept = residuals
+        heads, tokens, dk = q.shape
+        dv, n = v.shape[-1], tokens // chunk
+        h, key, value, row, states = specs(heads, dk, dv,
+                                           lambda j: n - 1 - j)
+        return tuple(pl.pallas_call(
+            _bwd_kernel,
+            grid=(heads // h, n),
+            in_specs=[key, key, value, key, row, states, value],
+            out_specs=[key, key, value, key, row],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                       for x in (q, k, v, a, beta)],
+            scratch_shapes=[pltpu.VMEM((h, dv, dk), jnp.float32)],
+            compiler_params=_PARAMS,
+            interpret=interpret,
+            name="kda_bwd",
+        )(q, k, v, a, beta, kept, g))
+
+    f.defvjp(fwd, bwd)
+    return f
 
 
-_kda.defvjp(_kda_fwd, _kda_bwd)
-
-
-def kda(q, k, v, log_decay, beta, *, chunk: int = 64):
+def kda(q, k, v, log_decay, beta, *, chunk: int = 64,
+        interpret: Optional[bool] = None):
     """The gated delta rule with a decay a channel, chunked: see the
     module's docstring. q, k [B, H, S, dk], v [B, H, S, dv], log_decay
     [B, H, S, dk], beta [B, H, S] -> o [B, H, S, dv]. S need not be whole
-    chunks (the tail is padded with tokens that leave the state alone)."""
-    return _kda(q, k, v, log_decay, beta, chunk)
+    chunks (the tail is padded with tokens that leave the state alone), nor
+    the widths whole lane tiles (padded with channels that hold nothing)."""
+    if chunk & (chunk - 1):
+        raise ValueError(f"chunk={chunk} is not a power of two")
+    if interpret is None:
+        interpret = attention._default_interpret()
+    b, h, s, dk = q.shape
+    dv = v.shape[-1]
+    n = -(-s // chunk)
+
+    def laid_out(x):
+        # [B, H, S, d] -> [B * H, whole chunks, whole lane tiles]. A padded
+        # token has k = 0, beta = 0, no decay: the state passes it
+        pad = ((0, 0), (0, 0), (0, n * chunk - s), (0, -x.shape[3] % LANES))
+        if any(p for _, p in pad):
+            x = jnp.pad(x, pad)
+        return x.reshape((b * h,) + x.shape[2:])
+    f32 = jnp.float32
+    rows = jnp.pad(beta.astype(f32), ((0, 0), (0, 0), (0, n * chunk - s)))
+    o = _make_kda_fn(chunk, interpret)(
+        laid_out(q), laid_out(k), laid_out(v), laid_out(log_decay.astype(f32)),
+        rows.reshape(b * h, n, 1, chunk))
+    return o.reshape(b, h, n * chunk, -1)[:, :, :s, :dv]

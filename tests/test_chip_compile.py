@@ -169,11 +169,13 @@ def test_plain_filter_kernels_compile_for_v5e(v5e, backward):
 
 
 def test_delta_rule_compiles_at_8192_positions_of_128(v5e):
-    """ops/linear_attention.py's chunked form and its backward at a
-    delta-rule layer of solar2_train_1chip, [1, 8, 8192, 128]: XLA alone
-    (no Mosaic call), two loops each way (the tree's levels, the chunk
-    states), the sequential one 128 chunks long, never the 8192 tokens;
-    under a gigabyte and a half of temporaries."""
+    """ops/linear_attention.py's two kernels at a delta-rule layer of
+    solar2_train_1chip, [1, 8, 8192, 128]: `kda_fwd` and `kda_bwd` (the
+    chunk function's jax.vjp: the transposed products, the rotations back)
+    compile inside their VMEM limit, one Mosaic call each and no XLA loop
+    beside them, neither over the 128 chunks nor the 8192 tokens; the
+    temporaries are the chunks' kept states (67 MB), far under the gigabyte
+    and a half the XLA form was held to."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -186,17 +188,50 @@ def test_delta_rule_compiles_at_8192_positions_of_128(v5e):
     args = (x, x, x, shape((1, 8, 8192, 128), jnp.float32),
             shape((1, 8, 8192), jnp.float32))
     compiled = jax.jit(jax.grad(
-        lambda *a: jnp.sum(kda(*a).astype(jnp.float32)),
+        lambda *a: jnp.sum(kda(*a, interpret=False).astype(jnp.float32)),
         argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
-    # the tree's levels and the chunk states, forward and transposed; the
-    # sequential one walks 128 stacked chunks, and none the tokens
-    loops = [line for line in text.splitlines() if " while(" in line]
-    assert len(loops) == 4, len(loops)
-    assert sum("f32[128,1,8," in line for line in loops) == 2
-    assert not any("[8192,1,8" in line for line in loops)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    calls = re.findall(r"%(\S*kda_(?:fwd|bwd)\S*) = .*custom-call\(", text)
+    assert len(calls) == 2 and "fwd" in calls[0] and "bwd" in calls[1], calls
+    assert text.count("tpu_custom_call") == 2
+    assert " while(" not in text
+    # the chunks' states, [8, 128, 128, 128] float32, and little else
+    states = 8 * 128 * 128 * 128 * 4
+    assert states <= compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * states
+
+
+def test_delta_rule_layer_compiles_on_four_chip_mesh(v5e, monkeypatch):
+    """A delta-rule layer at solar2_train_1chip's widths (8 heads of 128 on
+    4096) under tp_fsdp on fsdp=2 x tensor=2, forward and backward: the two
+    kernels run per shard (`gpt.py:_per_shard`: a batch row and four whole
+    heads a device), as the filters beside them do; GSPMD would refuse the
+    Mosaic calls as they stand."""
+    import json
+    import jax
+    import jax.numpy as jnp
+    from benchmark.families import solar
+    from ray_tpu.models import gpt
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "solar-open2-250b.json")) as f:
+        widths = dict(solar.gpt_config_kwargs(json.load(f)), n_layers=1,
+                      layer_kinds=("kda",), n_experts=0, experts_held=None,
+                      n_shared_experts=0, max_seq=2048)
+    cfg, mesh, _, layer, x = _layer_on_four_chips(v5e, monkeypatch, widths,
+                                                  2, 2048)
+
+    def loss(layer, x):
+        out, _stats = gpt._kda_block(layer["kda"], x, cfg, gpt.Setting(mesh))
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layer, x).compile().as_text()
+    calls = re.findall(r"%(\S*kda_(?:fwd|bwd)\S*) = .*custom-call\(", text)
+    assert len(calls) == 2, calls
+    # a shard's own slice: a batch row of four heads
+    assert re.search(r"kda_fwd\S* = .*bf16\[4,2048,128\]", text)
+    assert "conv_silu_fwd" in text and "all-reduce" in text
 
 
 @pytest.mark.parametrize("shape", KERNEL_SHAPES,

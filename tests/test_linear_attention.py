@@ -107,6 +107,49 @@ def test_delta_rule_keeps_the_inputs_type_and_refuses_an_odd_chunk(jax_cpu):
         kda(q, k, v, log_decay, beta, chunk=48)
 
 
+@pytest.mark.parametrize("seq,decay,beta_top,dtype", [
+    (128, 0.05, 2.0, "float32"),     # whole chunks, a mild decay, beta near 2
+    (128, 8.0, 2.0, "float32"),      # past -87 inside a chunk, beta near 2
+    (80, 0.05, 1.0, "bfloat16"),     # a ragged tail, two-byte q, k and v
+    (80, 8.0, 2.0, "bfloat16"),      # all of it at once
+], ids=["mild", "underflow", "ragged_bf16", "underflow_ragged_bf16"])
+def test_delta_rule_gradients_are_the_recurrences(jax_cpu, seq, decay,
+                                                  beta_top, dtype):
+    """All five gradients of the kernels (`kda_bwd`: the chunk function's
+    jax.vjp walked from the last chunk to the first) against jax.grad of the
+    recurrence on the same inputs, one head of two, under a cotangent of its
+    own: the decay mild and underflowing inside a chunk, beta up to 2, a
+    sequence that is not whole chunks, inputs of four bytes and of two."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.linear_attention import (chunk_log_decay, kda,
+                                              kda_reference)
+    q, k, v, log_decay, beta = (x[:1, :2] for x in _delta_inputs(
+        jax, seq, 32, decay, beta_top, seed=1))
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    if decay == 8.0:
+        assert float(chunk_log_decay(log_decay).min()) < -87.0
+    ct = jax.random.normal(jax.random.PRNGKey(7), (1, 2, seq, 32))
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * ct),
+            argnums=(0, 1, 2, 3, 4)))(q, k, v, log_decay, beta)
+    # the two kernels are all of it: no scan is left beside them
+    text = str(jax.make_jaxpr(jax.grad(lambda q: jnp.sum(kda(
+        q, k, v, log_decay, beta).astype(jnp.float32))))(q))
+    assert re.findall(r"name=(kda_fwd|kda_bwd)\b", text) == ["kda_fwd",
+                                                             "kda_bwd"]
+    assert " scan[" not in text and " while[" not in text
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for name, g, r in zip(("q", "k", "v", "log_decay", "beta"), grads(kda),
+                          grads(kda_reference)):
+        g, r = (np.asarray(x.astype(jnp.float32)) for x in (g, r))
+        assert g.dtype == r.dtype and np.isfinite(g).all() and np.any(r), name
+        np.testing.assert_allclose(g, r, atol=tol * max(1.0, np.abs(r).max()),
+                                   err_msg=name)
+
+
 def test_a_negative_eigenvalue_flips_what_the_state_holds(jax_cpu):
     """beta = 2 on a unit key reflects the state along it: reading the same
     key back gives the value written less the value held, and not, as at
@@ -517,6 +560,50 @@ def test_flops_and_kernel_arithmetic_count_what_is_computed_here():
     assert moved == 8 * s * (8 * 128 + 4 * 128 + 4 + 2 * 4 * 128 * 128 / 64)
 
 
+@pytest.mark.parametrize("batch,seq", [(3, 128), (2, 64)])
+def test_the_delta_rule_kernels_arithmetic_is_a_brute_force_count(tiny, batch,
+                                                                  seq):
+    """benchmark/kernels/delta_rule.py, a function a kernel name, against
+    loops over the chunks at a tiny shape (4 heads of 32, chunks of 64):
+    the forward is kda.py:delta_rule's products and no more than its bytes
+    (the chunks' states cross once a kernel), the backward twice the
+    products, every tensor once."""
+    from benchmark.kernels import delta_rule, kda
+    mix = {"global_batch": batch, "seq": seq, "mesh": {"data": 1}}
+    heads, dim, chunk = 4, 32, 64
+    flops = fwd_bytes = bwd_bytes = 0
+    for _ in range(batch * heads):
+        for _ in range(seq // chunk):
+            flops += (2 * chunk * chunk * dim // 2) * 2       # A, Aqk: halves
+            flops += 2 * chunk * chunk * (dim + dim) // 2     # the solve's
+            flops += 3 * 2 * chunk * dim * dim + 2 * chunk * chunk * dim // 2
+            moved = chunk * dim * 2                  # a two-byte tensor's rows
+            fwd_bytes += 4 * moved + chunk * dim * 4 + chunk * 4 \
+                + dim * dim * 4                      # q k v o; a; beta; state
+            bwd_bytes += 7 * moved + 2 * (chunk * dim * 4 + chunk * 4) \
+                + dim * dim * 4
+    assert delta_rule.kda_fwd(tiny, mix) == (flops, fwd_bytes)
+    assert delta_rule.kda_bwd(tiny, mix) == (2 * flops, bwd_bytes)
+    whole, whole_bytes = kda.delta_rule(tiny, mix)
+    assert flops == whole and fwd_bytes < whole_bytes
+    assert whole_bytes - fwd_bytes == batch * heads * (seq // chunk) \
+        * dim * dim * 4
+
+
+def test_the_delta_rule_kernels_least_times_at_the_cell():
+    """Both are bound by bytes on the mathematics' count, a fifth and a
+    third of a millisecond a call at [1, 8, 8192, 128]."""
+    from benchmark.kernels import delta_rule, kda
+    cell = _read("benchmark", "configs", "solar-open2-250b.json")
+    mix = _read("benchmark", "traffic", "train_b1_s8192_dp.json")
+    for fn, ms in ((delta_rule.kda_fwd, 0.205), (delta_rule.kda_bwd, 0.308)):
+        flops, moved = fn(cell, mix)
+        assert flops / 197e12 < moved / 819e9
+        assert 1e3 * moved / 819e9 == pytest.approx(ms, rel=0.01)
+    assert delta_rule.kda_fwd(cell, mix)[0] == kda.delta_rule(cell, mix)[0]
+    assert delta_rule.kda_fwd(cell, mix)[1] < kda.delta_rule(cell, mix)[1]
+
+
 @pytest.mark.parametrize("strategy,column,row", [
     ("tp", (None, "tensor"), ("tensor", None)),
     ("tp_fsdp", ("fsdp", "tensor"), ("tensor", "fsdp"))])
@@ -672,23 +759,13 @@ def _kernel_calls(jax, jaxpr, rematted=False):
             yield from _kernel_calls(jax, sub, inner)
 
 
-def _scans(jax, jaxpr, rematted=False):
-    """(length, whether in a recompute pass) of every scan of jaxpr."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn.params["length"], rematted
-        inner = rematted or eqn.params.get("differentiated", False)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _scans(jax, sub, inner)
-
-
 def test_the_delta_rule_runs_its_forward_once_a_layer(jax_cpu, tiny):
     """The step's calls are the counter. Under remat_policy="full" a
-    delta-rule layer's output is kept (KDA_OUT), so the scan over the chunk
-    states runs forward once a layer, once more inside the backward rule
-    (the chunked form differentiated again) and once transposed; the filter
-    kernels, which XLA's recompute pass holds, run forward and recomputed;
-    the grouped-query layer's forward kernel once."""
+    delta-rule layer's output and its chunks' states are kept (KDA_OUT), so
+    `kda_fwd` runs once a layer and never in the recompute pass, and
+    `kda_bwd` once a layer; the filter kernels, which XLA's recompute pass
+    holds, run forward and recomputed; the grouped-query layer's forward
+    kernel once."""
     jax = jax_cpu
     from collections import Counter
     from benchmark.families import solar
@@ -705,9 +782,8 @@ def test_the_delta_rule_runs_its_forward_once_a_layer(jax_cpu, tiny):
         == 9
     assert calls[("conv_silu_bwd", False)] + calls[("conv_silu_bwd", True)] \
         == 9
-    # 128 tokens are two chunk states a layer
-    scans = Counter(_scans(jax, jaxpr.jaxpr))
-    assert scans[(2, False)] == 3 and scans[(2, True)] == 6
+    assert calls[("kda_fwd", False)] == 3 and calls[("kda_fwd", True)] == 0
+    assert calls[("kda_bwd", False)] + calls[("kda_bwd", True)] == 3
 
 
 def test_the_new_scopes_are_regions_and_reach_the_compiled_step(jax_cpu,
@@ -718,7 +794,8 @@ def test_the_new_scopes_are_regions_and_reach_the_compiled_step(jax_cpu,
     from ray_tpu.models.gpt import gpt_init, gpt_loss
     from ray_tpu.util import profiling
     assert {"kda", "kda_core"} <= set(profiling.REGIONS)
-    assert {"conv_silu_fwd", "conv_silu_bwd"} <= set(profiling.KERNELS)
+    assert {"conv_silu_fwd", "conv_silu_bwd", "kda_fwd", "kda_bwd"} <= set(
+        profiling.KERNELS)
     cfg = solar._train_config(dict(tiny, num_hidden_layers=2))
     params = gpt_init(jax.random.PRNGKey(0), cfg)
     text = jax.jit(jax.grad(lambda p, t: gpt_loss(p, {"tokens": t}, cfg))
@@ -728,12 +805,15 @@ def test_the_new_scopes_are_regions_and_reach_the_compiled_step(jax_cpu,
     regions = {profiling._last_of(n, profiling.REGIONS) for n in names}
     assert {"kda", "kda_core", "attn_gate", "attn_proj", "attn_core",
             "attn_out", "moe", "moe_route", "moe_shared"} <= regions
-    # the delta rule's ops are kda_core's in every phase, the scan's body
-    # among them; the filters' are kda's
+    # the delta rule's two kernels are kda_core's, the forward's in phase
+    # forward and the backward's in the transpose; the filters' are kda's
     core = [n for n in names
             if profiling._last_of(n, profiling.REGIONS) == "kda_core"]
-    assert any("transpose(" in n for n in core)
-    assert any("while" in n for n in core)
+    assert any("/kda_fwd/" in n and "transpose(" not in n for n in core)
+    assert any("/kda_bwd/" in n and "transpose(" in n for n in core)
+    for n in names:
+        if "kda_fwd" in n or "kda_bwd" in n:
+            assert profiling._last_of(n, profiling.REGIONS) == "kda_core"
     for n in names:
         if "conv_silu" in n:
             assert profiling._last_of(n, profiling.REGIONS) == "kda"

@@ -804,10 +804,12 @@ LOWERED = {
     "lfm2_train_1chip": "6d8075c1983c7f5a",
     "laguna_train_1chip": "a13b1de35328fc71",
     "keye2_train_1chip": "2420d0b4f00749c5",
-    # PR 46's cell, recorded with it: the delta rule's scans, the plain
-    # filter's kernels, a grouped-query layer that rotates nothing, the
-    # held experts' rows in tiles of 128 (ops/moe.py:tile_rows), 1e-7
-    "solar2_train_1chip": "25d26e1bbba2bec5",
+    # PR 46's cell (the plain filter's kernels, a grouped-query layer that
+    # rotates nothing, the held experts' rows in tiles of 128, 1e-7),
+    # recorded anew by PR 47: the delta rule is `kda_fwd` / `kda_bwd` in
+    # place of its XLA scans (25d26e1bbba2bec5 before it), and no other
+    # cell's text moved
+    "solar2_train_1chip": "aa90d217a4905e58",
 }
 
 
