@@ -41,8 +41,10 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import indexer, moe
-from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT, flash_attention,
-                                   mha_reference, qk_padding, ring_attention)
+from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT,
+                                   flash_attention_native, head_columns,
+                                   mha_reference, qk_padding, ring_attention,
+                                   tokens_first)
 from ray_tpu.ops.linear_attention import KDA_OUT, chunk_log_decay, kda
 from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
                               rope_frequencies, rope_split, rope_table)
@@ -482,19 +484,16 @@ def _rmsnorm(x, scale, eps, psum=_whole):
 
 def _head_rmsnorm(y, scale, eps):
     """RMSNorm over each head's columns of y [B, S, heads * D], scale [D]
-    shared by the heads, with the columns left where they are. Through a
-    [.., heads, D] view a head of 64 columns lies on 128 lanes, and the
-    chip's compiler copies the float32 tensor into that layout and back,
-    in every phase (PERF.md, PR 33). Here the heads' mean squares are a
-    product with the 0/1 matrix that says which column is in which head,
-    and so is their way back to the columns: two thin matmuls, lane dense.
+    shared by the heads, with the columns left where they are
+    (`head_columns`): the heads' mean squares are a product with the 0/1
+    matrix that says which column is in which head, and so is their way
+    back to the columns: two thin matmuls, lane dense.
     One bf16 pass is enough for the squares (64 roundings of 2^-9 average
     out far below the result's own rounding); the way back takes three, so
     that a head's factor reaches its columns to 2^-16."""
     with jax.named_scope("norm"):
         width, dim = y.shape[-1], scale.shape[0]
-        member = (jnp.arange(width)[:, None] // dim
-                  == jnp.arange(width // dim)[None, :]).astype(jnp.float32)
+        member = head_columns(width, dim)
         y32 = y.astype(jnp.float32)
         mean_sq = jnp.einsum("bsw,wh->bsh", y32 * y32, member,
                              precision=jax.lax.Precision.DEFAULT) / dim
@@ -546,13 +545,39 @@ def _per_shard(fn, mesh, in_dims, out_dims):
                      out_specs=spec(out_dims), check_vma=False)
 
 
+def _heads_dims(by_kernels: bool):
+    """`_per_shard`'s dims of the heads' outputs as a shard's flash kernels
+    leave them: [B, S, H * Dv] where they write tokens first
+    (ops/attention.py:tokens_first), else [B, H, S, Dv]."""
+    return (("batch", None, "heads") if by_kernels
+            else ("batch", "heads", None, None))
+
+
+def _tokens_first(o, by_kernels: bool = False):
+    """The heads' outputs as `attn_out` reads them, [B, S, H * Dv]: as they
+    are where the kernels wrote them so, else [B, H, S, Dv] turned by XLA
+    (the 64-wide cells, 'reference', 'ring'): outside any shard_map and
+    under the scope `attn_out`, whose first ops these two have always
+    been."""
+    if by_kernels:
+        return o
+    with jax.named_scope("attn_out"):
+        o = o.transpose(0, 2, 1, 3)
+        return o.reshape(*o.shape[:2], -1)
+
+
 def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh, window=None):
     """The projections' outputs (q [B, S, H*D], k and v [B, S, Hkv*D])
     through the head split, the rotation (ops/rope.py: one pass a tensor,
     straight into the kernels' [B, heads, S, D]) and the flash kernel, per
     shard: the columns are whole heads, each device attends its own (batch,
     head) slice, a key/value head with the query heads that read it. A
-    window layer's kernels run under scope `attn_window` (in `attn_core`)."""
+    window layer's kernels run under scope `attn_window` (in `attn_core`).
+    -> the heads' outputs [B, S, H * D]: at heads of whole lane tiles (128)
+    the layout that leaves the kernels and the shard_map; at narrower ones
+    (64) the kernels leave [B, H, S, D] and `_tokens_first` turns it."""
+    by_kernels = tokens_first(cfg.head_dim)
+
     def split_and_attend(q, k, v, *table):
         with jax.named_scope("attn_proj"):
             q = rope_split(q, cfg.head_dim, table)
@@ -560,14 +585,16 @@ def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh, window=None):
             v = rope_split(v, cfg.head_dim)
         with jax.named_scope("attn_core"):
             if window is None:
-                return flash_attention(q, k, v, causal=True)
+                return flash_attention_native(q, k, v, causal=True)
             with jax.named_scope("attn_window"):
-                return flash_attention(q, k, v, causal=True, window=window)
+                return flash_attention_native(q, k, v, causal=True,
+                                              window=window)
 
     columns = ("batch", None, "heads")
-    return _per_shard(split_and_attend, mesh,
-                      (columns,) * 3 + ((),) * len(table),
-                      ("batch", "heads", None, None))(q, k, v, *table)
+    o = _per_shard(split_and_attend, mesh,
+                   (columns,) * 3 + ((),) * len(table),
+                   _heads_dims(by_kernels))(q, k, v, *table)
+    return _tokens_first(o, by_kernels)
 
 
 def _index_projections(ix, x, cfg: GPTConfig):
@@ -599,9 +626,9 @@ def _selected_attention(q, k, v, table, index, cfg: GPTConfig, mesh):
     log-sum-exp over them, and the indexer's KL is taken against q, k and
     that (`kl`: kernels, `attn_index` again). A sequence of at most
     index_topk positions has every causal key chosen and runs the plain
-    causal kernels beside the plain walk. -> (the heads' outputs [B, H, S,
-    D], the KL [shards], the selected pairs over the causal pairs
-    [shards])."""
+    causal kernels beside the plain walk. -> (the heads' outputs [B, S,
+    H * D] (as `_flash_on_mesh` has them), the KL [shards], the selected
+    pairs over the causal pairs [shards])."""
     qi, ki, w, index_table = index
     if mesh is not None and mesh.shape.get(MESH_AXES["heads"], 1) > 1:
         raise ValueError(
@@ -609,6 +636,7 @@ def _selected_attention(q, k, v, table, index, cfg: GPTConfig, mesh):
             "its scores are a sum over index heads that 'tensor' > 1 would "
             "divide, and no psum of them is built")
     sm_scale = 1.0 / math.sqrt(cfg.head_dim)
+    by_kernels = tokens_first(cfg.head_dim)
 
     n_table = len(table)
 
@@ -628,14 +656,14 @@ def _selected_attention(q, k, v, table, index, cfg: GPTConfig, mesh):
                 _, kl, share = indexer.select_and_kl(
                     qi, ki, w, q, k, topk=cfg.index_topk, sm_scale=sm_scale)
             with jax.named_scope("attn_core"):
-                out = flash_attention(q, k, v, causal=True)
+                out = flash_attention_native(q, k, v, causal=True)
             return out, kl.reshape(1), share.reshape(1)
         with jax.named_scope("attn_index"):
             selected, kept, share = indexer.select(qi, ki, w,
                                                    topk=cfg.index_topk)
         with jax.named_scope("attn_core"):
-            out, lse = flash_attention(q, k, v, causal=True,
-                                       selected=selected, with_lse=True)
+            out, lse = flash_attention_native(
+                q, k, v, causal=True, selected=selected, with_lse=True)
         with jax.named_scope("attn_index"):
             # the target's softmax is the kernel's: its lse over the chosen
             kl = indexer.kl(qi, ki, w, q, k, lse, selected, kept,
@@ -643,12 +671,13 @@ def _selected_attention(q, k, v, table, index, cfg: GPTConfig, mesh):
         return out, kl.reshape(1), share.reshape(1)
 
     columns, whole = ("batch", None, "heads"), ("batch", None, None)
-    return _per_shard(
+    o, kl, share = _per_shard(
         split_and_attend, mesh,
         (columns,) * 4 + (whole,) * 2
         + ((),) * (n_table + len(index_table)),
-        (("batch", "heads", None, None), ("batch",), ("batch",)))(
+        (_heads_dims(by_kernels), ("batch",), ("batch",)))(
         q, k, v, qi, ki, w, *table, *index_table)
+    return _tokens_first(o, by_kernels), kl, share
 
 
 def _deinterleaved(w, heads: int, keep: int, pairs: int):
@@ -703,7 +732,8 @@ def _latent_heads(q, kv, k_rope, table, nope: int, rope: int, dv: int,
 
 def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
     """The attention of a latent block, up to the heads' outputs
-    [B, H, S, v_head_dim]: q straight from x, k and v up from one
+    [B, S, H * v_head_dim] (as `_flash_on_mesh` has them, by v's width; the
+    reference's are turned): q straight from x, k and v up from one
     normalised latent, RoPE on qk_rope_dim columns of q's heads and on the
     one key part all heads share, then the flash kernels at q.k width
     qk_nope_dim + qk_rope_dim and v width v_head_dim. Scope `attn_latent`
@@ -738,6 +768,7 @@ def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
     # anyway and not in a pass of their own
     fill = 0 if cfg.attention == "reference" else qk_padding(nope + rope)
     sm_scale = 1.0 / math.sqrt(nope + rope)
+    by_kernels = cfg.attention != "reference" and tokens_first(dv)
 
     def split_and_attend(q, kv, k_rope, *table):
         kernels = None if cfg.attention == "reference" else latent_split(
@@ -754,15 +785,17 @@ def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
         with jax.named_scope("attn_core"):
             if cfg.attention == "reference":
                 return mha_reference(q, k, v, causal=True, sm_scale=sm_scale)
-            return flash_attention(q, k, v, causal=True, sm_scale=sm_scale)
+            return flash_attention_native(q, k, v, causal=True,
+                                          sm_scale=sm_scale)
 
     if cfg.attention == "ring":
         raise ValueError("attention='ring' has no latent form: the shared "
                          "rotated key part is not sharded over 'sequence'")
     columns = ("batch", None, "heads")
-    return _per_shard(split_and_attend, where.mesh,
-                      (columns, columns, ("batch", None, None), (), ()),
-                      ("batch", "heads", None, None))(q, kv, k_rope, *table)
+    o = _per_shard(split_and_attend, where.mesh,
+                   (columns, columns, ("batch", None, None), (), ()),
+                   _heads_dims(by_kernels))(q, kv, k_rope, *table)
+    return _tokens_first(o, by_kernels)
 
 
 def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting,
@@ -780,8 +813,14 @@ def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting,
     path. kind: "attention" | "window", which the layer's parameters are
     named by. Where the layer has a gate (`wg`: a column a head, or a column
     an element of the heads' outputs), scope `attn_gate` holds its matmul,
-    its sigmoid and the product with the heads' outputs."""
-    b, s, _ = x.shape
+    its sigmoid and the product with the heads' outputs. Those arrive
+    tokens first, [B, S, H * Dv], from every path, so `wo` reads them as
+    they are, and a gate a head reaches a head's columns through
+    `head_columns` (its gradient back the same way), never through a
+    [B, S, H, Dv] view: at heads of whole lane tiles nothing turns or copies
+    them between the flash kernels and `wo`, in either direction
+    (ops/attention.py:tokens_first); at 64 `_tokens_first` turns them,
+    under this block's scope `attn_out`."""
     dt = cfg.dtype
     a = layer[_GROUP[kind]]
     stats = {}
@@ -795,17 +834,21 @@ def _attention_block(layer, x, cfg: GPTConfig, table, where: Setting,
     else:
         o = _multi_head_attention(a, x, cfg, table, where, kind)
     with jax.named_scope("attn_out"):
-        o = o.transpose(0, 2, 1, 3)
         if "wg" in a:
             with jax.named_scope("attn_gate"):
                 gate = jax.nn.sigmoid(jnp.einsum(
                     "bsd,dh->bsh", x, a["wg"].astype(dt),
                     preferred_element_type=jnp.float32))
-                # a column a head, or one an element of the heads' outputs
-                gate = (gate[..., None] if gate.shape[-1] == o.shape[2]
-                        else gate.reshape(o.shape))
+                if gate.shape[-1] != o.shape[-1]:
+                    # a column a head: three bf16 passes hand the head's
+                    # columns 16 bits of it, under a product rounded to 8
+                    gate = jnp.einsum(
+                        "bsh,wh->bsw", gate,
+                        head_columns(o.shape[-1],
+                                      o.shape[-1] // gate.shape[-1]),
+                        precision=jax.lax.Precision.HIGH)
                 o = (o * gate).astype(dt)
-        return where.psum(jnp.einsum("bsd,de->bse", o.reshape(b, s, -1),
+        return where.psum(jnp.einsum("bsd,de->bse", o,
                                      a["wo"].astype(dt))), stats
 
 
@@ -814,7 +857,9 @@ def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
     """q, k, v of one head width from the three projections of `a` (an
     attention layer's matrices; the query heads are wq's columns over
     head_dim, k and v at the key/value heads' count) -> the heads' outputs
-    [B, H, S, head_dim]. kind "window": under the sliding window, and in
+    [B, S, H * head_dim] (tokens first: as the flash kernels leave them at
+    heads of 128, turned by XLA at 64 and on the 'reference' and 'ring'
+    paths: `_tokens_first`). kind "window": under the sliding window, and in
     either kind the rotation is the kind's (cfg.rope_of; none where it
     gives None: q and k go to the kernels as projected). Where `a` has an
     indexer (`index`) the result is (the heads' outputs over the keys it
@@ -881,12 +926,14 @@ def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
                 qi, ki, w, q, k, topk=cfg.index_topk,
                 sm_scale=1.0 / math.sqrt(hd))
         with jax.named_scope("attn_core"):
-            return (mha_reference(q, k, v, causal=True, selected=selected),
-                    kl.reshape(1), share.reshape(1))
+            o = mha_reference(q, k, v, causal=True, selected=selected)
+        return _tokens_first(o), kl.reshape(1), share.reshape(1)
     with jax.named_scope("attn_core"):
         if cfg.attention == "ring":
-            return ring_attention(q, k, v, mesh=where.mesh, causal=True)
-        return mha_reference(q, k, v, causal=True, window=window)
+            o = ring_attention(q, k, v, mesh=where.mesh, causal=True)
+        else:
+            o = mha_reference(q, k, v, causal=True, window=window)
+    return _tokens_first(o)
 
 
 def _conv_block(m, x, cfg: GPTConfig, where: Setting):

@@ -15,6 +15,13 @@ num_heads (grouped-query attention): query head h reads key/value head
 h // (num_heads // num_kv_heads), through the kernels' index maps, so k and
 v are never repeated in HBM and dK, dV leave at num_kv_heads.
 
+Where a head's output is whole lane tiles wide (`tokens_first`: Dv a
+multiple of 128) the kernels write it, and read its cotangent, as [batch,
+seq, num_heads * Dv], a head's block placed by the block maps: the layout
+the output projection reads, with no transpose on either side
+(`flash_attention_native`). `flash_attention` turns that back to [batch,
+num_heads, seq, Dv].
+
 A sliding window (`window`: query i sees keys j with 0 <= i - j < window)
 runs the same three kernel bodies under names of their own (flash_win_*)
 on a grid whose reduced dimension covers only the blocks the band touches.
@@ -43,7 +50,9 @@ NEG_INF = -1e30
 
 # What the flash forward's two results are called under a jax.checkpoint
 # (jax.ad_checkpoint.checkpoint_name): a policy that saves both names keeps
-# the kernel from running a second time in the backward pass.
+# the kernel from running a second time in the backward pass. FLASH_OUT names
+# the output as the kernels wrote it (`tokens_first`), so what a policy keeps
+# is what the output projection reads.
 FLASH_OUT = "flash_out"
 FLASH_LSE = "flash_lse"
 
@@ -131,6 +140,15 @@ def lane_divisor(n: int, cap: int) -> int:
     if n < LANES:
         return n
     return max(b for b in range(LANES, min(cap, n) + 1, LANES) if n % b == 0)
+
+
+def tokens_first(v_dim: int) -> bool:
+    """Whether the kernels write the heads' outputs (and read their
+    cotangent) as [batch, seq, heads * v_dim] rather than [batch * heads,
+    seq, v_dim]: where a head's block of v_dim columns is whole lane tiles
+    of that array. A narrower head (64) would share a lane tile with its
+    neighbour, which a block map cannot address."""
+    return v_dim % LANES == 0
 
 
 def _block_sizes(seq_q: int, seq_k: int, head_dim: int,
@@ -598,6 +616,32 @@ def _query_heads_a_kv_head(q, k, v) -> int:
     return heads // kv_heads
 
 
+def head_columns(width: int, dim: int):
+    """[width, width // dim] float32, 1 where a column lies in a head (of
+    dim columns each): a product with it sums each head's columns, one with
+    its transpose hands a number a head to the head's columns, and both
+    leave the columns where they are. A [.., heads, dim] view does not: the
+    chip tiles 8 rows x 128 lanes, so a head of 64 lies on 128 lanes, and
+    at 128 the view's tile is 8 heads of a token where the columns' is 8
+    tokens of a head; either way the compiler copies the tensor into the
+    other layout and back (PERF.md, PRs 33 and 48)."""
+    return (jnp.arange(width)[:, None] // dim
+            == jnp.arange(width // dim)[None, :]).astype(jnp.float32)
+
+
+def _head_dots(a, b, heads: int):
+    """a, b [B, S, heads * D] -> each head's sum of a * b over its D
+    columns, [B, S, heads] float32, the columns left where they are
+    (`head_columns`). Three bf16 passes carry 16 bits of a product, every
+    bit of one of two bf16 numbers; float32 factors take six."""
+    exact = (jax.lax.Precision.HIGH if a.dtype.itemsize <= 2
+             else jax.lax.Precision.HIGHEST)
+    return jnp.einsum("bsw,wh->bsh",
+                      a.astype(jnp.float32) * b.astype(jnp.float32),
+                      head_columns(a.shape[-1], a.shape[-1] // heads),
+                      precision=exact)
+
+
 def _kernel_name(kernel: str, window, selected) -> str:
     """flash_<kernel>, flash_win_<kernel> under a window, flash_sel_<kernel>
     under a selection: a trace row, and a roofline, is of one shape."""
@@ -607,6 +651,8 @@ def _kernel_name(kernel: str, window, selected) -> str:
 
 def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret, window=None,
                    selected=None):
+    """-> (the heads' outputs, [B, S, H * Dv] where `tokens_first(Dv)` and
+    [B, H, S, Dv] where not; lse [B * H, 1, S])."""
     batch, heads, seq_q, d = q.shape
     seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
@@ -635,18 +681,30 @@ def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret, window=None,
             (1, bq, bkm),
             lambda b, i, j: (b // heads, i, kv_index(b, i, j)[1])))
         operands += (selected,)
+    if tokens_first(dv):
+        # grid row b's [bq, dv] block is head b % heads' columns of batch
+        # row b // heads: the body writes o_ref[0] either way
+        out_shape = (batch, seq_q, heads * dv)
+
+        def out_index(b, i, j):
+            return b // heads, i, b % heads
+    else:
+        out_shape = (bh, seq_q, dv)
+
+        def out_index(b, i, j):
+            return b, i, 0
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, seq_q // bq, steps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), out_index),
             # lse rides as [bh, 1, seq_q]: TPU Pallas needs the last two
             # block dims divisible by (8, 128) or equal to the array dims.
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, dv), q.dtype),
+            jax.ShapeDtypeStruct(out_shape, q.dtype),
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
         scratch_shapes=[
@@ -658,11 +716,14 @@ def _flash_forward(q, k, v, causal, sm_scale, blocks, interpret, window=None,
         interpret=interpret,
         name=_kernel_name("fwd", window, selected),
     )(*operands)
-    return out.reshape(batch, heads, seq_q, dv), lse
+    return (out if tokens_first(dv)
+            else out.reshape(batch, heads, seq_q, dv)), lse
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
                     interpret, window=None, selected=None):
+    """out and its cotangent g in the layout the forward wrote
+    (`tokens_first`)."""
     batch, heads, seq_q, d = q.shape
     seq_k, dv = k.shape[2], v.shape[3]
     bh = batch * heads
@@ -670,11 +731,25 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
     qr = q.reshape(bh, seq_q, d)
     kr = k.reshape(bh // rep, seq_k, d)
     vr = v.reshape(bh // rep, seq_k, dv)
-    gr = g.reshape(bh, seq_q, dv)
     # delta_i = rowsum(dO_i * O_i): cheap elementwise, fused by XLA.
-    delta = jnp.sum(gr.astype(jnp.float32)
-                    * out.reshape(bh, seq_q, dv).astype(jnp.float32),
-                    axis=-1).reshape(bh, 1, seq_q)
+    if tokens_first(dv):
+        # dO stays where the output projection's backward wrote it, a
+        # head's block found by the block maps (`do_index`); only delta
+        # [B, S, H], float32, is turned to the rows the kernels read
+        gr = g
+        delta = _head_dots(g, out, heads).transpose(0, 2, 1).reshape(
+            bh, 1, seq_q)
+
+        def do_index(head, block):
+            return head // heads, block, head % heads
+    else:
+        gr = g.reshape(bh, seq_q, dv)
+        delta = jnp.sum(gr.astype(jnp.float32)
+                        * out.reshape(bh, seq_q, dv).astype(jnp.float32),
+                        axis=-1).reshape(bh, 1, seq_q)
+
+        def do_index(head, block):
+            return head, block, 0
     offset = seq_k - seq_q
     params = _compiler_params(max(d, dv), seq_k, blocks.dq[1])
 
@@ -697,7 +772,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
                                causal=causal, group=group, offset=offset,
                                **band(seq_k // bkm))
     in_specs = [q_spec(d), pl.BlockSpec((1, bkm, d), kv_index),
-                pl.BlockSpec((1, bkm, dv), kv_index), q_spec(dv),
+                pl.BlockSpec((1, bkm, dv), kv_index),
+                pl.BlockSpec((1, bq, dv), lambda b, i, j: do_index(b, i)),
                 row_spec, row_spec]
     operands = (qr, kr, vr, gr, lse, delta)
     if selected is not None:
@@ -757,7 +833,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, blocks,
     kernel = functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
                                causal=causal, group=group, offset=offset,
                                **walk, **band(num_q))
-    in_specs = [q_spec(d), kv_spec(d), kv_spec(dv), q_spec(dv),
+    do_spec = pl.BlockSpec(
+        (1, bqm, dv),
+        lambda b, i, j: do_index(q_head(b, j), q_block(i, j)))
+    in_specs = [q_spec(d), kv_spec(d), kv_spec(dv), do_spec,
                 row_spec, row_spec]
     if selected is not None:
         # dK/dV's tiles are [keys, queries]: the selection transposed, one
@@ -870,14 +949,42 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def flash_attention(q, k, v, *, causal: bool = True,
-                    sm_scale: Optional[float] = None,
-                    block_q: Optional[int] = None,
-                    block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None,
-                    window: Optional[int] = None, selected=None,
-                    with_lse: bool = False):
-    """Fused attention on the MXU; O(seq) memory via online softmax.
+def flash_attention(q, k, v, **options):
+    """`flash_attention_native` with the heads' outputs [B, H, S, Dv] at
+    every width, for a caller that wants them by head (the tests, the
+    oracle's layout): where the kernels wrote them tokens first, turned
+    back, a pass of XLA's that the model's path (models/gpt.py) never
+    runs."""
+    result = flash_attention_native(q, k, v, **options)
+    if not tokens_first(v.shape[-1]):
+        return result
+
+    def by_head(out):
+        return out.reshape(*out.shape[:2], q.shape[1], -1).transpose(
+            0, 2, 1, 3)
+    if options.get("with_lse"):
+        return by_head(result[0]), result[1]
+    return by_head(result)
+
+
+def flash_attention_native(q, k, v, *, causal: bool = True,
+                           sm_scale: Optional[float] = None,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None, selected=None,
+                           with_lse: bool = False):
+    """Fused attention on the MXU; O(seq) memory via online softmax. q
+    [B, H, S, D], k and v [B, Hkv, S, D | Dv] -> the heads' outputs in the
+    layout the kernels write, which is the one their backward reads the
+    cotangent in: [B, S, H * Dv] where a head is whole lane tiles wide
+    (`tokens_first(Dv)`: its [block, Dv] tile is placed among a token's
+    H * Dv columns by the block maps, so the output projection reads it as
+    it is and no transpose runs forward, recomputed or backward), and
+    [B, H, S, Dv] at any other width (64: the kernels write [B * H, S, Dv]
+    and the caller turns it). The shape decides, nothing else. A caller
+    keeps the columns as they are: a [.., H, Dv] view is another layout on
+    the chip (`head_columns`).
 
     selected (causal self-attention only, no window): [B, S, S] int8, 1
     where query i may see key j and 0 elsewhere, a subset of the causal
@@ -895,8 +1002,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     indexer. No table of empty tiles is kept: a tile of 2048 x 2048 pairs
     under the diagonal has none selected only if 2048 queries in a row
     choose none of 2048 keys in a row. with_lse (under a selection only):
-    -> (out, lse [B, H, S] float32, each head's log-sum-exp over the query's
-    selected keys as the backward kernels read it, without a gradient).
+    -> (out as above, lse [B, H, S] float32, each head's log-sum-exp over
+    the query's selected keys as the backward kernels read it, without a
+    gradient).
 
     window (causal self-attention only): query i sees keys j with 0 <= i -
     j < window. The kernels then run as flash_win_fwd / flash_win_bwd_dq /

@@ -7,6 +7,7 @@ All compiles stay in this one process: two processes that ask for the TPU
 topology at once collide on /tmp/libtpu_lockfile.
 """
 
+import math
 import os
 import re
 
@@ -365,6 +366,111 @@ def test_latent_block_reaches_the_flash_kernels_without_a_layout_pass(
             assert re.search(r"/latent_(q|kv)_(split|merge)/pallas_call",
                              line), line
         assert not re.search(rf"f32\[{batch},{seq},\d", made), line
+
+
+@pytest.mark.parametrize("kind,heads", [("attention", 48), ("window", 64)])
+def test_heads_of_128_reach_wo_without_a_layout_pass(v5e, monkeypatch, kind,
+                                                    heads):
+    """laguna_train_1chip's two kinds of attention layer, [2, 48 | 64 on 8,
+    8192, 128], through the flash kernels, the gate a head and `wo`, value
+    and gradient under the layer's remat policy, for one described chip:
+    the three kernels once each (the forward's kept results reach the
+    backward), and in the entry computation no `copy`, `transpose` or
+    `reshape` writes a tensor of o's element count: the kernels write o and
+    read dO as [2, 8192, H * 128], and the gate, its gradient and delta
+    reach a head's columns where they lie (`ops/attention.py:head_columns`). A
+    [2, 8192, H, 128] view anywhere between the kernels and `wo` brings the
+    copies back: the chip tiles that view 8 heads x 128 lanes of one token,
+    the columns 8 tokens x 128 lanes."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from benchmark.families import laguna
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.rope import rope_table
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-xs.2.json")) as f:
+        cfg = gpt.GPTConfig(**laguna.gpt_config_kwargs(json.load(f)),
+                            attention="flash")
+    batch, seq = 2, 8192
+    assert (cfg.heads_of(kind), cfg.kv_heads, cfg.head_dim) == (heads, 8, 128)
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    one_chip = SingleDeviceSharding(v5e[0])
+    layers = jax.eval_shape(
+        lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"]
+    group = gpt._GROUP[kind]
+    layer = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        {group: next(layer[group] for layer in layers if group in layer)})
+    assert layer[group]["wg"].shape == (cfg.d_model, heads)
+    x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype,
+                             sharding=one_chip)
+
+    def loss(layer, x):
+        table = rope_table(seq, cfg.head_dim, cfg.rope_of(kind))
+        block = jax.checkpoint(
+            lambda layer, x: gpt._attention_block(
+                layer, x, cfg, table, gpt.Setting(), kind)[0],
+            policy=jax.checkpoint_policies.save_only_these_names(
+                attention.FLASH_OUT, attention.FLASH_LSE))
+        return block(layer, x).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layer, x).compile().as_text()
+    name = "flash_win_" if kind == "window" else "flash_"
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert len(_kernel_ops(text, name + kernel)) == 1, kernel
+    written = re.compile(
+        r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+        r"(copy|transpose|reshape)\(")
+    for line in text[text.index("\nENTRY "):].splitlines():
+        made = written.match(line)
+        if made:
+            dims = [int(d) for d in made.group(1).split(",")]
+            assert math.prod(dims) != batch * seq * heads * 128, line
+
+
+def test_tokens_first_heads_are_whole_per_shard_on_a_2x2_mesh(jax_cpu):
+    """No cell runs heads of 128 under `tensor` > 1, so this holds
+    `_per_shard`'s dims of the tokens-first output ("batch", None, "heads")
+    on the CPU (interpreted kernels): four heads of 128 on two key/value
+    heads over fsdp=2 x tensor=2, each shard writing its own two heads'
+    columns of its own batch row, give the one-device values and the three
+    gradients."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    import numpy as np
+    from ray_tpu.models import gpt
+    from ray_tpu.ops.rope import rope_table
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    cfg = gpt.GPTConfig(vocab_size=64, d_model=512, n_layers=1, n_heads=4,
+                        n_kv_heads=2, d_ff=64, max_seq=128,
+                        dtype=jnp.float32)
+    assert cfg.head_dim == 128
+    mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2),
+                      devices=jax.devices()[:4])
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, g = (jax.random.normal(key, (2, 128, 4 * 128)) for key in keys[:2])
+    k, v = (jax.random.normal(key, (2, 128, 2 * 128)) for key in keys[2:])
+    table = rope_table(128, cfg.head_dim, cfg.rope_of("attention"))
+
+    def attend(mesh):
+        def loss(q, k, v):
+            out = gpt._flash_on_mesh(q, k, v, table, cfg, mesh)
+            assert out.shape == (2, 128, 4 * 128)
+            return jnp.sum(out * g), out
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
+    (_, out), grads = attend(mesh)(q, k, v)
+    (_, want), want_grads = attend(None)(q, k, v)
+    np.testing.assert_allclose(out, want, atol=1e-6)
+    for got, ref in zip(grads, want_grads):
+        np.testing.assert_allclose(got, ref, atol=1e-5)
 
 
 BLOCK_WIDTHS = [

@@ -201,6 +201,50 @@ def test_flash_forward_and_gradients_under_grouped_queries(
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_heads_of_128_leave_the_kernels_tokens_first(jax_cpu, dtype):
+    """6 query heads on 2 key/value heads, 128 wide, two blocks a row: the
+    kernels write o as [B, S, H * 128] and read dO so, a head's block placed
+    by the block maps (dK/dV's over a group's three query heads in turn)."""
+    import jax.numpy as jnp
+    from helpers.flash_layout import check_tokens_first
+    check_tokens_first(jax_cpu, jnp.dtype(dtype).type)
+
+
+# sha256 of the jaxpr of the value and gradients of the parent's
+# (8caba70) flash_attention at heads of 64, 8 on 2, [1, 8 | 2, 256, 64]
+# float32 in blocks of 128: what `flash_attention_native` traces there, so a
+# head narrower than a lane tile runs the parent's kernels, block maps,
+# reshapes and delta op for op, and the caller turns [B, H, S, 64] as it did.
+NARROW_HEADS_JAXPR_SHA256 = {
+    "grouped": (
+        {}, "9100c184863fbb861ccecb2874ff34cb147dfa93efae6957b231cbb36e4a09c9"),
+    "window": (
+        {"window": 100},
+        "037d008fc5dc2bc2cdd7c9d3b5264ed76a51371b53fa8ba1b26a3da3c3051ec1"),
+    "selected": (
+        {"selected": True},
+        "51b3e122e67c55fb344b7cdb29812a45a22a33465ca8f1181ca0540d96109adc"),
+}
+
+
+@pytest.mark.parametrize("kind", list(NARROW_HEADS_JAXPR_SHA256))
+def test_heads_of_64_trace_to_the_parents_ops(jax_cpu, kind):
+    import jax.numpy as jnp
+    from helpers.flash_layout import traced_sha
+    from ray_tpu.ops.attention import (flash_attention,
+                                       flash_attention_native, tokens_first)
+    options, sha = NARROW_HEADS_JAXPR_SHA256[kind]
+    if "selected" in options:
+        options = {"selected": jnp.tril(jnp.ones((1, 256, 256), jnp.int8))}
+    assert not tokens_first(64) and tokens_first(128) and tokens_first(256)
+    for attend in (flash_attention_native, flash_attention):
+        assert traced_sha(
+            jax_cpu, lambda q, k, v: attend(q, k, v, block_q=128,
+                                            block_k=128, **options),
+            8, 2, 256, 64) == sha
+
+
 def test_flash_refuses_head_counts_that_do_not_group(jax_cpu):
     import jax.numpy as jnp
     from ray_tpu.ops.attention import flash_attention

@@ -61,6 +61,19 @@ def test_full_remat_gives_the_bits_of_none(jax_cpu, block):
                                       err_msg=jax.tree_util.keystr(path))
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["causal", "window"])
+def test_the_kept_tokens_first_output_gives_the_gradients(jax_cpu, kind,
+                                                          dtype):
+    """Under a jax.checkpoint that keeps FLASH_OUT and FLASH_LSE the kept
+    value is the tokens-first one ([B, S, H * 128]: delta is taken from it
+    where it lies), and the three gradients are the reference's."""
+    import jax.numpy as jnp
+    from helpers.flash_layout import check_tokens_first
+    check_tokens_first(jax_cpu, jnp.dtype(dtype).type, keep=True,
+                       **({"window": 100} if kind == "window" else {}))
+
+
 def _kernel_calls(jax, jaxpr, rematted=False):
     """(kernel name, whether it runs in a layer's recompute pass: under a
     checkpoint equation of the backward) for every pallas_call of jaxpr."""
@@ -139,10 +152,13 @@ def test_flash_forward_is_recomputed_where_nothing_is_kept(jax_cpu):
 @pytest.mark.parametrize("block", list(BLOCKS))
 def test_a_layer_keeps_its_input_the_output_and_lse(jax_cpu, capsys, block):
     """Of a layer's activations the backward pass is handed the layer's
-    input, flash_fwd's output [B, H, S, v width] and lse [B*H, 1, S], and
-    nothing else: not q, k, v in the kernels' layout, no projection."""
+    input, flash_fwd's output as the kernel wrote it ([B, S, H * v width] at
+    heads of whole lane tiles, what `attn_out` reads; [B, H, S, v width] at
+    narrower ones) and lse [B*H, 1, S], and nothing else: not q, k, v in the
+    kernels' layout, no projection."""
     jax = jax_cpu
     from ray_tpu.models import gpt
+    from ray_tpu.ops.attention import tokens_first
     cfg, params, _ = _setup(jax, block)
     batch, seq = 2, 32
     x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype)
@@ -154,9 +170,11 @@ def test_a_layer_keeps_its_input_the_output_and_lse(jax_cpu, capsys, block):
             if line and not re.search(r"from the argument p\[|from a constant",
                                       line)]
     v_width = cfg.v_head_dim or cfg.head_dim
+    out = ((batch, seq, cfg.n_heads * v_width) if tokens_first(v_width)
+           else (batch, cfg.n_heads, seq, v_width))
     assert sorted(line.split()[0] for line in kept) == sorted([
         f"bf16[{batch},{seq},{cfg.d_model}]",
-        f"bf16[{batch},{cfg.n_heads},{seq},{v_width}]",
+        f"bf16[{','.join(map(str, out))}]",
         f"f32[{batch * cfg.n_heads},1,{seq}]"]), kept
     assert any("from the argument x" in line for line in kept), kept
     assert any("named 'flash_lse'" in line for line in kept), kept

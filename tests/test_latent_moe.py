@@ -64,6 +64,16 @@ def test_flash_forward_and_gradients_where_qk_is_wider_than_v(
         np.testing.assert_allclose(g, w, atol=1e-4)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_v_of_128_under_qk_of_256_leaves_tokens_first(jax_cpu, dtype):
+    """The latent cell's call as its kernels see it (q.k 256 wide after the
+    fill, v 128): v's width decides where o goes, [B, S, H * 128]; q, k and
+    their gradients stay by head."""
+    import jax.numpy as jnp
+    from helpers.flash_layout import check_tokens_first
+    check_tokens_first(jax_cpu, jnp.dtype(dtype).type, dqk=256)
+
+
 @pytest.mark.parametrize("seq,width,fwd,bwd", [
     (1024, 64, (1024, 1024, 256), (1024, 1024, 128)),     # gpt2s
     (2048, 64, (2048, 2048, 256), (2048, 2048, 128)),     # smollm-1.7b

@@ -97,6 +97,28 @@ def test_selected_kernels_match_the_reference(jax_cpu, name, heads, kv_heads,
                                             block_k=block), atol=2e-6)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_selected_kernels_write_heads_of_128_tokens_first(jax_cpu, dtype):
+    """flash_sel_* at heads of 128 (6 on 2, two blocks a row, a random set
+    with an empty tile): o leaves and dO arrives as [B, S, H * 128], and the
+    lse handed out beside it stays [B, H, S]."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from helpers.flash_layout import check_tokens_first, operands
+    from ray_tpu.ops.attention import (flash_attention,
+                                       flash_attention_native)
+    selected = _selections(jax, 2, 256)["an_empty_tile"]
+    check_tokens_first(jax, jnp.dtype(dtype).type, selected=selected)
+    q, k, v, _ = operands(jax, jnp.dtype(dtype).type, 6, 2, 256, 128, 128)
+    out, lse = flash_attention_native(q, k, v, selected=selected,
+                                      with_lse=True)
+    assert out.shape == (2, 256, 6 * 128) and lse.shape == (2, 6, 256)
+    turned, same = flash_attention(q, k, v, selected=selected, with_lse=True)
+    np.testing.assert_array_equal(
+        turned, out.reshape(2, 256, 6, 128).transpose(0, 2, 1, 3))
+    np.testing.assert_array_equal(same, lse)
+
+
 def test_the_rule_blocks_and_unequal_blocks_run_the_selection(jax_cpu):
     """The shape's own blocks (one of 512) and a test's unequal ones."""
     jax = jax_cpu

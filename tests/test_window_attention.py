@@ -80,6 +80,16 @@ def test_window_kernels_match_the_reference(jax_cpu, heads, kv_heads, seq,
         np.testing.assert_allclose(a, b, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_window_kernels_write_heads_of_128_tokens_first(jax_cpu, dtype):
+    """flash_win_* are the same bodies under the same specs: at heads of
+    128 (6 on 2, two blocks a row, a window of 100) o leaves and dO arrives
+    as [B, S, H * 128]."""
+    import jax.numpy as jnp
+    from helpers.flash_layout import check_tokens_first
+    check_tokens_first(jax_cpu, jnp.dtype(dtype).type, window=100)
+
+
 def test_the_reference_window_is_itself_and_the_ones_before(jax_cpu):
     """mha_reference(window=3) written out: query i mixes v_{i-2..i}."""
     jax = jax_cpu
@@ -789,27 +799,24 @@ def test_configuration_file_keeps_the_catalog_and_states_the_cut():
 # flash kernels through ops/rope.py's latent kernels) and no other; laguna's
 # is its parent's.
 LOWERED = {
-    # no sparse layer: the parent's text, untouched by PR 42 (which is the
-    # proof that the two cells bypass it)
+    # heads of 64: the parent's text, untouched by PR 48 (which is the proof
+    # that the three cells bypass it: the kernels write [B * H, S, 64], XLA
+    # turns it under `attn_out`, outside the shard_map; the first two also
+    # untouched by PR 42, having no sparse layer)
     "gpt2s_train_1chip": "0a5e354a41f2d309",
     "smollm17_train_4chip": "3d347ff7870a2d4a",
-    # the five sparse cells, recorded anew by PR 42: the experts' float32
-    # masters reach `moe_gmm` as they are kept (no cast ahead of the
-    # kernels), and `combine`'s backward holds g until z is there
-    # (d6965f84c9d53df6, 98e222f1ff7314ba, d3285b6a8612132b,
-    # 7cca2436db9dc5e9 and, with PR 41's kernels in the indexer's walk,
-    # 100ea29abfc532ef before it)
-    "olmoe_train_1chip": "a875c8421b01b065",
-    "kanana2_train_1chip": "64dedc5a37df64cf",
     "lfm2_train_1chip": "6d8075c1983c7f5a",
-    "laguna_train_1chip": "a13b1de35328fc71",
-    "keye2_train_1chip": "2420d0b4f00749c5",
-    # PR 46's cell (the plain filter's kernels, a grouped-query layer that
-    # rotates nothing, the held experts' rows in tiles of 128, 1e-7),
-    # recorded anew by PR 47: the delta rule is `kda_fwd` / `kda_bwd` in
-    # place of its XLA scans (25d26e1bbba2bec5 before it), and no other
-    # cell's text moved
-    "solar2_train_1chip": "aa90d217a4905e58",
+    # heads (v's) of 128, recorded anew by PR 48: the flash kernels write o
+    # and read dO as [B, S, H * 128], `wo` reads that as it is, delta and a
+    # gate a head go through `head_columns` (a875c8421b01b065,
+    # 64dedc5a37df64cf, a13b1de35328fc71, 2420d0b4f00749c5 and
+    # aa90d217a4905e58 before it: PR 42's masters in `moe_gmm`, and at
+    # solar PR 47's `kda_fwd` / `kda_bwd`)
+    "olmoe_train_1chip": "de1ac5dddca614d0",
+    "kanana2_train_1chip": "ccd30b735ee79374",
+    "laguna_train_1chip": "2c9cca064dffa677",
+    "keye2_train_1chip": "c7b4fffd346aa0f2",
+    "solar2_train_1chip": "830c2fd63a15f131",
 }
 
 
