@@ -7,7 +7,9 @@ bfloat16 activations + params with fp32 softmax/layernorm accumulation,
 flash-attention Pallas kernel, optional ring attention (sequence sharded),
 optional sparse experts (top-k routing with real dispatch: ops/moe.py;
 softmax or sigmoid scores, a selection bias, shared experts, leading dense
-layers, and one chip's share of the experts: `experts_held`), optional
+layers, one chip's share of the experts: `experts_held`, a router that
+reads the layer's normed input ahead of the mixer: `route_from`, and the
+gate's activation: `gate_activation`), optional
 latent attention (a low-rank k/v projection, q.k wider than v), optional
 grouped-query attention with a norm a head, optional gated
 short-convolution layers among the attention layers (ops/short_conv.py),
@@ -31,7 +33,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial, update_wrapper
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -50,6 +52,9 @@ from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
                               rope_frequencies, rope_split, rope_table)
 from ray_tpu.ops.short_conv import short_conv, silu_conv
 from ray_tpu.parallel.sharding import MESH_AXES
+
+# GPTConfig.gate_activation: relu's derivative at 0 is 0 (jax.nn.relu's).
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 @dataclass(frozen=True)
@@ -86,7 +91,10 @@ class GPTConfig:
     # The two kinds of attention layer share n_kv_heads and head_dim; each
     # has its own query heads (window_heads, 0 = n_heads) and its own
     # rotation (rope / window_rope, None = every column rotated as halves
-    # at rope_theta): a table a kind, built once a step.
+    # at rope_theta): a table a kind, built once a step. A kind whose
+    # RopeSpec rotates no column (rotated=0) rotates nothing beside one
+    # that does: rope_of gives None for it, no table is built for it and
+    # its q and k reach the kernels as projected.
     attention_window: int = 0
     window_heads: int = 0
     rope: Optional[RopeSpec] = None
@@ -145,7 +153,19 @@ class GPTConfig:
     router_renormalise: bool = False
     router_renormalise_eps: float = 1e-20   # added to the kept weights' sum
     router_scale: float = 1.0
-    # A dense SwiGLU n_shared_experts x d_ff wide beside the routed sum,
+    # What the router reads: "mixed", the normed residual stream after the
+    # mixer (what the experts read); "input", the layer's normed INPUT,
+    # ahead of the mixer: the routing (scores, top-k, weights, statistics,
+    # the slots' order) is then worked out before attention in a scope of
+    # its own, `route_ahead`, carried across the mixer, and the experts
+    # gather their rows from the normed stream after it.
+    route_from: str = "mixed"         # mixed | input
+    # The activation on the gate of every gated MLP (dense, shared and
+    # routed experts): down(act(gate x) * up x). "relu": its derivative at
+    # 0 is 0, and a sparse layer's statistics gain
+    # `expert_hidden_zero_share`.
+    gate_activation: str = "silu"     # silu | relu
+    # A dense gated MLP n_shared_experts x d_ff wide beside the routed sum,
     # every token through it.
     n_shared_experts: int = 0
     # The layer pattern: this many leading layers keep a dense MLP, of
@@ -195,15 +215,24 @@ class GPTConfig:
         if self.attention_gate not in (False, True, "element"):
             raise ValueError(f"attention_gate={self.attention_gate!r}: "
                              "expected False | True | 'element'")
+        if self.route_from not in ("mixed", "input"):
+            raise ValueError(f"route_from={self.route_from!r}: expected "
+                             "'mixed' | 'input'")
+        if self.gate_activation not in _ACTIVATIONS:
+            raise ValueError(f"gate_activation={self.gate_activation!r}: "
+                             f"expected {' | '.join(map(repr, _ACTIVATIONS))}")
         if "kda" in (kinds or ()) and self.attention == "ring":
             raise ValueError(
                 "a 'kda' layer's state runs along the whole sequence: it is "
                 "not sharded over 'sequence' (attention='ring')")
-        if not self.use_rope and (self.kv_latent_dim or self.index_topk):
+        if (self.rope_of("attention") is None
+                and (self.kv_latent_dim or self.index_topk)):
             raise ValueError(
-                "use_rope=False is built for multi-head attention layers, "
-                "not for " + ("a latent block" if self.kv_latent_dim
-                              else "an indexer"))
+                "attention layers that rotate nothing (use_rope=False, or "
+                "a rope that rotates no column) are built for multi-head "
+                "attention layers, not for "
+                + ("a latent block" if self.kv_latent_dim
+                   else "an indexer"))
         for heads in {self.n_heads, self.heads_of("window")}:
             if heads % self.kv_heads:
                 raise ValueError(f"n_kv_heads={self.n_kv_heads} does not "
@@ -258,11 +287,14 @@ class GPTConfig:
 
     def rope_of(self, kind: str) -> Optional[RopeSpec]:
         """The rotation of an "attention" or a "window" layer; None where
-        the layers carry none (use_rope False)."""
+        the kind carries none (use_rope False: no kind does; a RopeSpec
+        that rotates no column of the head: this kind does not)."""
         if not self.use_rope:
             return None
         spec = self.window_rope if kind == "window" else self.rope
-        return RopeSpec(theta=self.rope_theta) if spec is None else spec
+        if spec is None:
+            return RopeSpec(theta=self.rope_theta)
+        return spec if spec.columns(self.head_dim) else None
 
     @property
     def qk_head_dim(self) -> int:
@@ -1028,17 +1060,22 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
 
 
 def _mlp_block(m, x, cfg: GPTConfig, where: Setting):
-    """SwiGLU through m's three matrices: a dense layer's MLP, or the
-    shared expert of a sparse one."""
+    """The gated MLP through m's three matrices, down(act(gate x) * up x)
+    with cfg.gate_activation (SwiGLU by default): a dense layer's MLP, or
+    the shared expert of a sparse one."""
     dt = cfg.dtype
+    act = _ACTIVATIONS[cfg.gate_activation]
     gate = jnp.einsum("bsd,df->bsf", x, m["w_gate"].astype(dt))
     up = jnp.einsum("bsd,df->bsf", x, m["w_up"].astype(dt))
-    return where.psum(jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+    return where.psum(jnp.einsum("bsf,fd->bsd", act(gate) * up,
                                  m["w_down"].astype(dt)))
 
 
 def _route(m, x, cfg: GPTConfig):
-    """The router, in float32: a score for every expert (softmax over them,
+    """The router, in float32, over x: the tensor the configuration names
+    (cfg.route_from: the normed stream after the mixer, or the layer's
+    normed input ahead of it; `_routing` is its one caller). A score for
+    every expert (softmax over them,
     or a sigmoid each: cfg.router_score), each token's expert_top_k largest
     — by score plus the selection bias where the layer has one, the kept
     weights being the scores without it —, the weights as they come or,
@@ -1096,55 +1133,135 @@ def _route(m, x, cfg: GPTConfig):
     return weights, idx, stats
 
 
-def _expert_rows(tiles, rows, order, x, weights, w_gate, w_up, w_down):
+def _expert_rows(tiles, rows, order, x, weights, w_gate, w_up, w_down,
+                 activation="silu"):
     """_experts in a row space of `tiles` tiles of `rows` rows (which has to
     hold the order's: moe.in_row_space): dispatch, three grouped matmuls
-    with SwiGLU between, weighted return."""
+    with the gated activation between, weighted return. Under "relu" the
+    result is (y, [zeros, units] float32): how many of the held experts'
+    hidden units relu(gate) are exactly 0 over the rows that hold a slot,
+    and how many such units there are (the padding rows, zeros all, and
+    the tiles nobody computes are not counted)."""
     b, s, d = x.shape
     with jax.named_scope("moe_route"):
         plan = moe.lay_out(order, rows, tiles)
         rows_in = moe.dispatch(x.reshape(b * s, d), plan)
     gate = moe.grouped_matmul(rows_in, w_gate, plan)
     up = moe.grouped_matmul(rows_in, w_up, plan)
-    out = moe.grouped_matmul(jax.nn.silu(gate) * up, w_down, plan)
+    hidden = _ACTIVATIONS[activation](gate)
+    out = moe.grouped_matmul(hidden * up, w_down, plan)
     with jax.named_scope("moe_route"):
         y = moe.combine(out, weights.reshape(b * s, -1), plan)
-    return y.reshape(b, s, d)
+    y = y.reshape(b, s, d)
+    if activation != "relu":
+        return y
+    real = plan.row_slot < order.order.shape[0]
+    zeros = jnp.sum(jnp.where(real[:, None], hidden == 0, False),
+                    dtype=jnp.float32)
+    units = jnp.sum(real, dtype=jnp.float32) * hidden.shape[1]
+    return y, jax.lax.stop_gradient(jnp.stack([zeros, units]))
 
 
-def _experts(x, weights, idx, w_gate, w_up, w_down, held=None):
+@lru_cache(maxsize=None)
+def _expert_rows_with(activation: str):
+    """_expert_rows at a gate activation, ONE function an activation:
+    moe.in_row_space jits what it is handed, and layers of one shape share
+    one trace of it only if they hand it the same function (under
+    _expert_rows' own name, which the lowered step's text carries)."""
+    return update_wrapper(partial(_expert_rows, activation=activation),
+                          _expert_rows)
+
+
+def _held(cfg: GPTConfig):
+    """None where the layer's matrices are all the experts, else (the first
+    one held here, of how many there are)."""
+    if cfg.experts_held is None:
+        return None
+    return cfg.experts_held[0], cfg.n_experts
+
+
+def _order_dims(held):
+    """`_per_shard`'s dims of `_slot_order`'s arrays: each shard's own,
+    side by side along their first dimension."""
+    return ((("batch",),) * 5
+            + (("batch", None),) * (1 if held is None else 2))
+
+
+def _row_space(n_slots: int, groups: int, held, dtype):
+    """(the slots expected on the `groups` experts whose matrices are here,
+    the rows of a tile), from shapes alone. held: None where those are all
+    the experts, else (first, of how many)."""
+    expected = n_slots if held is None else n_slots * groups // held[1]
+    return expected, moe.tile_rows(expected, groups, dtype)
+
+
+def _slot_order(idx, groups: int, held, dtype):
+    """idx [b, s, k], each token's chosen experts -> the slots in expert
+    order (moe.order_slots) over the `groups` experts here, as a tuple of
+    its arrays: what of the dispatch depends on the routing decision alone,
+    on no row. held as in `_row_space`: a slot chosen for an expert that is
+    not here gets no row."""
+    idx = idx.reshape(-1, idx.shape[-1])
+    _, rows = _row_space(idx.size, groups, held, dtype)
+    if held is not None:
+        idx = idx - held[0]
+    order = moe.order_slots(idx, groups, rows, partial=held is not None)
+    return tuple(field for field in order if field is not None)
+
+
+def _routing(m, x, cfg: GPTConfig, where: Setting):
+    """A sparse layer's routing from x [b, s, d], the tensor the
+    configuration names (cfg.route_from), under the caller's scope
+    (`moe_route` in `moe`, or `route_ahead` ahead of the mixer): _route's
+    weights and statistics over the whole batch, and per shard
+    (`_per_shard`) the order of each device's own slots over the experts
+    here. -> (weights [b, s, k] float32, the order's arrays, the
+    statistics): all `_moe_block` needs of the decision."""
+    weights, idx, stats = _route(m, x, cfg)
+    held = _held(cfg)
+    order = _per_shard(
+        partial(_slot_order, groups=m["w_gate"].shape[0], held=held,
+                dtype=x.dtype),
+        where.mesh, (("batch", None, None),), _order_dims(held))(idx)
+    return weights, order, stats
+
+
+def _experts(x, weights, order, w_gate, w_up, w_down, held=None,
+             activation="silu"):
     """x [b, s, d] through each token's chosen experts (ops/moe.py): rows
-    ordered by expert, three grouped matmuls with SwiGLU between, weighted
-    return. The gathers either side are the layer's sparsity, not its
-    arithmetic: scope `moe_route`. held: None where the matrices are all
-    the experts', else (first, of how many): the matrices are experts
-    first .. first + len - 1, and a slot chosen for another is left out;
-    the result is then (y, [1] whether the row space sized for the slots
-    expected here held them: moe.in_row_space)."""
-    e = w_gate.shape[0]
-    with jax.named_scope("moe_route"):
-        idx = idx.reshape(-1, idx.shape[-1])
-        if held is None:
-            expected = idx.size
-        else:
-            first, of = held
-            # the tiles, and the row space, from the slots expected here
-            expected, idx = idx.size * e // of, idx - first
-        rows = moe.tile_rows(expected, e, x.dtype)
-        order = moe.order_slots(idx, e, rows, partial=held is not None)
-    y, fitted = moe.in_row_space(_expert_rows, order, rows, expected,
-                                 x, weights, w_gate, w_up, w_down)
-    return y if held is None else (y, jnp.reshape(fitted, (1,)))
+    in the order `_slot_order` gave the slots, three grouped matmuls with
+    the gated activation between, weighted return. The gathers either side
+    are the layer's sparsity, not its arithmetic: scope `moe_route`. held:
+    None where the matrices are all the experts', else (first, of how
+    many): the matrices are experts first .. first + len - 1, and a slot
+    chosen for another is left out. -> (y, and then, each where it
+    exists: [1] whether the row space sized for the slots expected here
+    held them (a share: moe.in_row_space); [1, 2] `_expert_rows`' zero
+    and all hidden units ("relu"))."""
+    groups = w_gate.shape[0]
+    order = moe.Order(*order)
+    expected, rows = _row_space(order.order.shape[0], groups, held, x.dtype)
+    out, fitted = moe.in_row_space(_expert_rows_with(activation), order,
+                                   rows, expected, x, weights, w_gate, w_up,
+                                   w_down)
+    y, *hidden = out if activation == "relu" else (out,)
+    flag = [jnp.reshape(fitted, (1,))] if held is not None else []
+    return (y, *flag, *(h[None] for h in hidden))
 
 
-def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
+def _moe_block(layer, x, cfg: GPTConfig, where: Setting, routing=None):
     """Sparse experts in the MLP's place: y = sum over a token's top-k of
-    p_e x down_e(silu(gate_e x) * up_e x). No capacity and no dropped
-    token: every token-slot is computed, by its own expert only. The
-    grouped matmuls run per shard (`_per_shard`): each device dispatches
-    its own tokens to all the experts, whose matrices it is handed whole
-    (in cfg.dtype under a mesh, as they are kept on one device);
-    the router and its losses stay outside, over the whole batch.
+    p_e x down_e(act(gate_e x) * up_e x), act = cfg.gate_activation. No
+    capacity and no dropped token: every token-slot is computed, by its
+    own expert only. The grouped matmuls run per shard (`_per_shard`): each
+    device dispatches its own tokens to all the experts, whose matrices it
+    is handed whole (in cfg.dtype under a mesh, as they are kept on one
+    device); the router and its losses stay outside, over the whole batch.
+
+    routing: `_routing`'s result where the block worked it out ahead of the
+    mixer from the layer's normed input (cfg.route_from "input", scope
+    `route_ahead`); None: it is worked out here from x, the tensor the rows
+    come from, under `moe_route`. Either way `_route` runs once a layer.
 
     With cfg.experts_held the sum runs over the chosen experts that are
     held here (one chip's share under expert parallelism, without its
@@ -1153,34 +1270,42 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting):
     joins _route's statistics: the share of the devices on which that row
     space held the routing at hand (the others ran every slot's, the same
     arithmetic: moe.in_row_space), the constant 1.0 where all the experts
-    are held. With cfg.n_shared_experts a dense SwiGLU of every token is
-    added (scope `moe_shared`). Scope `moe` (layer_fn's, around this) keeps
-    the experts' own arithmetic: grouped matmuls, SwiGLU, the casts of
-    their matrices; `moe_route`, nested, what exists only because the layer
-    is sparse: router, top-k, ordering, the gathers either side, both
-    router losses."""
+    are held. Under gate_activation "relu", `expert_hidden_zero_share`
+    joins them: the share of the experts' hidden units relu(gate) that are
+    exactly 0, over the rows computed. With cfg.n_shared_experts a dense
+    gated MLP of every token is added (scope `moe_shared`). Scope `moe`
+    (layer_fn's, around this) keeps the experts' own arithmetic: grouped
+    matmuls, the activation, the casts of their matrices; `moe_route`,
+    nested, what exists only because the layer is sparse and needs the
+    rows: the gathers either side, the tables of the row space and, unless
+    the routing came from ahead, router, top-k, ordering, both router
+    losses."""
     dt = cfg.dtype
     m = layer["moe"]
-    with jax.named_scope("moe_route"):
-        weights, idx, stats = _route(m, x, cfg)
+    if routing is None:
+        with jax.named_scope("moe_route"):
+            routing = _routing(m, x, cfg, where)
+    weights, order, stats = routing
     matrices = [m[name] for name in ("w_gate", "w_up", "w_down")]
     if where.mesh is not None and where.mesh.size > 1:
         # handed whole to every device: gather them in the rows' type. On
         # one device the kernels read the masters and round a block in VMEM
         # (moe.grouped_matmul), and no cast pass runs here
         matrices = [w.astype(dt) for w in matrices]
-    held = (None if cfg.experts_held is None
-            else (cfg.experts_held[0], cfg.n_experts))
+    held, relu = _held(cfg), cfg.gate_activation == "relu"
     tokens = ("batch", None, None)
-    y = _per_shard(partial(_experts, held=held), where.mesh,
-                   (tokens,) * 3 + ((),) * 3,
-                   tokens if held is None else (tokens, ("batch",)))(
-        x, weights, idx, *matrices)
-    if held is None:
-        stats["expert_rows_bounded"] = 1.0
-    else:
-        y, fitted = y
-        stats["expert_rows_bounded"] = jnp.mean(fitted)
+    # what `_experts` hands back beside y: a share's flag, relu's counts
+    extra_dims = ([("batch",)] if held is not None else []) \
+        + ([("batch", None)] if relu else [])
+    y, *extras = _per_shard(
+        partial(_experts, held=held, activation=cfg.gate_activation),
+        where.mesh, (tokens, tokens, _order_dims(held)) + ((),) * 3,
+        (tokens, *extra_dims))(x, weights, order, *matrices)
+    stats = dict(stats, expert_rows_bounded=(
+        1.0 if held is None else jnp.mean(extras[0])))
+    if relu:
+        zeros, units = jnp.sum(extras[-1], axis=0)
+        stats["expert_hidden_zero_share"] = zeros / jnp.maximum(units, 1.0)
     if "shared" in m:
         with jax.named_scope("moe_shared"):
             y = y + _mlp_block(m["shared"], x, cfg, where)
@@ -1196,7 +1321,12 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     gpt_init built them). The one
     transformer block, rematted as cfg.remat_policy says, for whoever
     walks the layers: gpt_backbone loops over their list, a stage of
-    parallel/pipeline.py scans over stacked ones."""
+    parallel/pipeline.py scans over stacked ones. Under cfg.route_from
+    "input" a sparse layer's routing (`_routing`) is worked out from the
+    normed INPUT under scope `route_ahead`, before the mixer, and handed
+    across it to `_moe_block`, whose rows come from the normed stream
+    after the mixer; by default the block routes from that stream, inside
+    `moe`."""
     # once a step, not once a layer and recompute: outside the remat; one
     # table for each kind of attention layer the stack has
     kinds = set(cfg.layer_kinds or ("attention",)) - {"conv", "kda"}
@@ -1205,7 +1335,7 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         tables = {
             kind: rope_table(seq, cfg.qk_rope_dim if cfg.kv_latent_dim
                              else cfg.head_dim, cfg.rope_of(kind))
-            if cfg.use_rope else ()
+            if cfg.rope_of(kind) is not None else ()
             for kind in sorted(kinds)}
     index_table = ()
     if cfg.index_topk:
@@ -1215,7 +1345,10 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
 
     def block(x, layer):
         normed = _rmsnorm(x, layer["ln1"]["scale"], cfg.rmsnorm_eps)
-        mixer_stats = {}
+        mixer_stats, routing = {}, None
+        if cfg.route_from == "input" and "moe" in layer:
+            with jax.named_scope("route_ahead"):
+                routing = _routing(layer["moe"], normed, cfg, where)
         if "conv" in layer:
             mixed = _conv_block(layer["conv"], normed, cfg, where)
         elif "kda" in layer:
@@ -1228,7 +1361,7 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
         if "moe" in layer:
             with jax.named_scope("moe"):
-                delta, stats = _moe_block(layer, normed, cfg, where)
+                delta, stats = _moe_block(layer, normed, cfg, where, routing)
         else:
             with jax.named_scope("mlp"):
                 delta, stats = _mlp_block(

@@ -120,6 +120,97 @@ def test_window_kernels_compile_at_8192_positions_of_128(v5e):
     assert dk.shape == dv.shape == (2, 8, 8192, 128)
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_flash_kernels_compile_at_16384_positions_in_groups_of_7(v5e,
+                                                                  window):
+    """smallthinker_train_1chip's two calls, [1, 28 on 4, 16384, 128]: the
+    causal kernels and the window kernels at a band of 4096 = two major
+    blocks of 2048 (three steps a grid row: the block wholly inside the
+    band runs unmasked), groups of 7 query heads a key/value head through
+    the index maps and `flash_bwd_dkv`'s walk, o written tokens first at 28
+    heads of 128; dK and dV leave at the key/value heads' count."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops import attention
+
+    def shape(h):
+        return jax.ShapeDtypeStruct((1, h, 16384, 128), jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+    if window:
+        outer, major, _ = attention._block_sizes(16384, 16384, 128).fwd
+        assert attention._band_steps(outer, major, window) == 3
+    grads = jax.jit(jax.grad(lambda q, k, v: attention.flash_attention_native(
+        q, k, v, causal=True, window=window,
+        interpret=False).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+    compiled = grads.lower(shape(28), shape(4), shape(4)).compile()
+    text = compiled.as_text()
+    name = "flash_win_" if window else "flash_"
+    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        assert len(_kernel_ops(text, name + kernel)) == 1, kernel
+    dq, dk, dv = compiled.out_info
+    assert dq.shape == (1, 28, 16384, 128)
+    assert dk.shape == dv.shape == (1, 4, 16384, 128)
+    # the forward's output leaves the kernel tokens first: [1, 16384, 28 x 128]
+    assert "bf16[1,16384,3584]" in _kernel_ops(text, name + "fwd")[0]
+
+
+@pytest.mark.parametrize("kind,rotates", [("attention", False),
+                                          ("window", True)])
+def test_a_kind_that_rotates_nothing_compiles_without_a_rotation(
+        v5e, monkeypatch, kind, rotates):
+    """smallthinker_train_1chip's two kinds of attention layer, [1, 28 on
+    4, 16384, 128], value and gradient for one described chip. The full
+    layer rotates nothing: no cosine or sine is computed for it, and its
+    head splits (`rope_split`, `rope_merge`) take no table. The window layer
+    beside it builds one table and hands it to q's and k's, not to v's."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from benchmark.families import smallthinker
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.rope import rope_table
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "smallthinker-21b-a3b.json")) as f:
+        cfg = gpt.GPTConfig(**smallthinker.gpt_config_kwargs(json.load(f)),
+                            attention="flash")
+    seq = 16384
+    assert (cfg.rope_of(kind) is not None) == rotates
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    one_chip = SingleDeviceSharding(v5e[0])
+    layers = jax.eval_shape(
+        lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"]
+    group = gpt._GROUP[kind]
+    layer = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        {group: next(layer[group] for layer in layers if group in layer)})
+    x = jax.ShapeDtypeStruct((1, seq, cfg.d_model), cfg.dtype,
+                             sharding=one_chip)
+
+    def loss(layer, x):
+        # layer_fn's own rule: no table for a kind that does not rotate
+        table = (rope_table(seq, cfg.head_dim, cfg.rope_of(kind))
+                 if rotates else ())
+        return gpt._attention_block(layer, x, cfg, table, gpt.Setting(),
+                                    kind)[0].astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layer, x).compile().as_text()
+    table = f"f32[{seq},128]"
+    splits = _kernel_ops(text, "rope_split")
+    merges = _kernel_ops(text, "rope_merge")
+    assert len(splits) == len(merges) == 3                 # q, k, v
+    with_table = [op for op in splits + merges if table in op]
+    assert len(with_table) == (4 if rotates else 0)        # q and k, each way
+    trig = re.findall(r" (?:cosine|sine)\(", text)
+    assert len(trig) == (2 if rotates else 0), trig
+
+
 @pytest.mark.parametrize("backward", [False, True],
                          ids=["forward", "backward"])
 def test_short_conv_kernels_compile_for_v5e(v5e, backward):
@@ -956,6 +1047,22 @@ CELL_STEPS = [
                   "rope_split": 6, "rope_merge": 3, "moe_gmm": 72,
                   "moe_tgmm": 24, "conv_silu_fwd": 18, "conv_silu_bwd": 9},
                  (0.80, 0.93), marks=pytest.mark.timeout(900)),
+    # smallthinker_train_1chip (1 x 16 384 tokens): a full layer that
+    # rotates nothing and three window layers (4096: a band of two major
+    # blocks) at 28 query heads on 4, each layer's routing worked out ahead
+    # of its mixer; 16 of 64 ReLU-gated experts held in all four layers
+    # (both row spaces in the text, as above). q, k, v through rope_split
+    # forward and recomputed in every layer (the full layer's without a
+    # table). 11.61 GB when this was written: 7.88 of state, 3.73 of
+    # temporaries (benchmark/configs/smallthinker-21b-a3b.json:
+    # memory_peak_bytes.described_chip_compile; ~50 s alone here).
+    pytest.param("smallthinker-21b-a3b",
+                 {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+                  "flash_win_fwd": 3, "flash_win_bwd_dq": 3,
+                  "flash_win_bwd_dkv": 3, "rope_split": 24,
+                  "rope_merge": 12, "moe_gmm": 72, "moe_tgmm": 24,
+                  "moe_run_sum": 8},
+                 (0.60, 0.80), marks=pytest.mark.timeout(900)),
 ]
 
 
@@ -1047,6 +1154,9 @@ def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
         made = [line for line in text.splitlines()
                 if f" = {by_head}" in line and "rope_split" in line]
         # q alone is split at the query heads' count: forward, and
-        # recomputed, in every full-attention layer
-        assert len(made) == 2 * (kernel_calls["flash_fwd"]
-                                 or kernel_calls["flash_sel_fwd"]), made
+        # recomputed, in every full-attention layer (and in every window
+        # layer where both kinds have the one head count)
+        layers = kernel_calls["flash_fwd"] or kernel_calls["flash_sel_fwd"]
+        if "num_attention_heads_per_layer" not in config:
+            layers += kernel_calls.get("flash_win_fwd", 0)
+        assert len(made) == 2 * layers, made

@@ -593,6 +593,18 @@ def test_by_the_rows_equals_by_the_slots(jax_cpu, what, dtype):
 # (c2) the share's row space: sized for the rows expected, exact past it
 # ---------------------------------------------------------------------------
 
+def _experts(x, weights, idx, *matrices, held=None):
+    """models/gpt.py's two halves of the sparse block as `_moe_block` joins
+    them on one device: the slots' order from the routing decision
+    (`_slot_order`: all that needs no row, so that a router ahead of the
+    mixer can hand it across), then the experts over it. -> y, or with a
+    share (y, [1] whether the bounded row space held the routing)."""
+    from ray_tpu.models import gpt
+    order = gpt._slot_order(idx, matrices[0].shape[0], held, x.dtype)
+    out = gpt._experts(x, weights, order, *matrices, held=held)
+    return out[0] if held is None else out
+
+
 def _masked_dense(x, weights, idx, w_gate, w_up, w_down, first):
     """_experts by the book: every token through every held expert, the
     slots that chose it weighted in, float32."""
@@ -659,7 +671,6 @@ def test_bounded_row_space_equals_the_one_for_every_slot(jax_cpu, monkeypatch,
     forward and all five gradients to float32 round-off."""
     jax = jax_cpu
     import jax.numpy as jnp
-    from ray_tpu.models.gpt import _experts
     from ray_tpu.ops import moe
     first = 8
     x, weights, *matrices = _share_operands(jax, held)
@@ -708,7 +719,6 @@ def test_past_the_bound_the_plan_for_every_slot_runs(jax_cpu, here, fits):
     computation's either way; the flag says which ran."""
     jax = jax_cpu
     import jax.numpy as jnp
-    from ray_tpu.models.gpt import _experts
     from ray_tpu.ops import moe
     held, of, first = 4, 32, 8
     assert moe.tile_rows(512 * held // of, held, jnp.float32) == 8
@@ -752,7 +762,6 @@ def test_eight_shares_one_of_them_past_its_bound_add_up_to_the_whole(
     a row on the other six, whose bounded row spaces are all padding."""
     jax = jax_cpu
     import jax.numpy as jnp
-    from ray_tpu.models.gpt import _experts
     of, held, k = 16, 2, 4
     x, weights, *matrices = _share_operands(jax, of, seed=2, k=k)
     rng = np.random.default_rng(5)

@@ -126,6 +126,17 @@ def test_loss_and_aux_returns_the_routing_statistics(loss_aux_grads):
 
 
 # (c) dispatch alone against the masked dense form: no token dropped
+def _experts(x, weights, idx, *matrices, held=None):
+    """models/gpt.py's two halves of the sparse block as `_moe_block` joins
+    them on one device: the slots' order from the routing decision
+    (`_slot_order`), then the experts over it. -> y, or with a share (y,
+    [1] whether the bounded row space held the routing)."""
+    from ray_tpu.models import gpt
+    order = gpt._slot_order(idx, matrices[0].shape[0], held, x.dtype)
+    out = gpt._experts(x, weights, order, *matrices, held=held)
+    return out[0] if held is None else out
+
+
 def _dense_experts(x, weights, idx, w_gate, w_up, w_down):
     import jax
     import jax.numpy as jnp
@@ -151,7 +162,6 @@ def _routing(case, tokens, e, k, rng):
 def test_dispatch_equals_masked_dense(jax_cpu, case, batch, seq):
     jax = jax_cpu
     import jax.numpy as jnp
-    from ray_tpu.models.gpt import _experts
     e, k, d, f = 8, 2, 32, 16
     rng = np.random.default_rng(11)
     x = jnp.asarray(rng.standard_normal((batch, seq, d)), jnp.float32)
@@ -433,7 +443,6 @@ def test_the_sparse_block_on_masters_is_the_block_on_their_copies(jax_cpu,
     gradients are those of bfloat16 copies made ahead of it, bit for bit."""
     jax = jax_cpu
     import jax.numpy as jnp
-    from ray_tpu.models.gpt import _experts
     e, of, k, d, f = 4, 4 if held is None else held[1], 2, 128, 256
     rng = np.random.default_rng(29)
     x = jnp.asarray(rng.standard_normal((2, 48, d)), jnp.bfloat16)

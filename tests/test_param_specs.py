@@ -1,6 +1,6 @@
 """How a transformer's parameters are divided over the mesh, leaf for leaf:
-every parameter of the benchmark's architectures (the seven rehearsal
-configurations hold every parameter name of the eight cells) under `tp` and
+every parameter of the benchmark's architectures (the eight rehearsal
+configurations hold every parameter name of the nine cells) under `tp` and
 `tp_fsdp` on fsdp=2 x tensor=2, and the dense one, stacked, under `pp` and
 `pp_tp`. The expectations were recorded at PR 42, before
 parallel/sharding.py's rule lists became one table (a delta-rule layer's
@@ -103,7 +103,8 @@ EXPECTED = {"tp": (GSPMD, 0, NO_ROW), "tp_fsdp": (GSPMD, 1, NO_ROW),
 
 CASES = [(name, strategy)
          for name in ("tiny", "tiny-olmoe", "tiny-kanana", "tiny-lfm2",
-                      "tiny-laguna", "tiny-keye", "tiny-solar")
+                      "tiny-laguna", "tiny-keye", "tiny-solar",
+                      "tiny-smallthinker")
          for strategy in ("tp", "tp_fsdp")] + [("tiny", "pp"),
                                                ("tiny", "pp_tp")]
 

@@ -224,8 +224,12 @@ def test_gspmd_enters_every_kernel_per_shard(jax_cpu, env):
             assert entered["mesh"].shape == mesh.shape
             assert not entered["check_vma"]
             specs = [*entered["in_specs"], *entered["out_specs"]]
-            if name == "moe_gmm":      # tokens, then the experts whole
-                assert specs == [P(batch_axes, None, None)] * 3 \
+            if name == "moe_gmm":
+                # tokens and their weights, the slots' order (each shard's
+                # own six arrays, handed over from the routing's
+                # shard_map), then the experts whole
+                assert specs == [P(batch_axes, None, None)] * 2 \
+                    + [P(batch_axes)] * 5 + [P(batch_axes, None)] \
                     + [P()] * 3 + [P(batch_axes, None, None)], specs
             else:                      # columns of heads, then the table
                 assert specs == [P(batch_axes, None, "tensor")] * 3 \
