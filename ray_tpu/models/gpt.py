@@ -47,6 +47,7 @@ from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT,
                                    flash_attention_native, head_columns,
                                    mha_reference, qk_padding, ring_attention,
                                    tokens_first)
+from ray_tpu.ops.embedding import embed_lookup
 from ray_tpu.ops.linear_attention import KDA_OUT, chunk_log_decay, kda
 from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
                               rope_frequencies, rope_split, rope_table)
@@ -1419,7 +1420,8 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     """
     where = Setting(mesh, act_sharding)
     with jax.named_scope("embed"):
-        x = where.pin(params["embed"]["table"].astype(cfg.dtype)[tokens])
+        x = where.pin(embed_lookup(params["embed"]["table"], tokens,
+                                   cfg.dtype, mesh))
     layer = layer_fn(cfg, tokens.shape[1], where)
     per_layer = []
     for layer_params in params["layers"]:

@@ -16,7 +16,10 @@ still owns one (all zero) tile. Three things follow from that layout:
   - every data movement is a gather, forward and backward: `dispatch`
     (tokens -> rows) and `combine` (rows -> tokens, weighted) are each
     other's transposes and carry their own VJPs, because XLA's transpose
-    of a row gather is a scatter-add, which a TPU serialises.
+    of a row gather is a scatter-add, which a TPU serialises. (The other
+    row gather of a step, the token-embedding lookup, goes by the same
+    rule with these very pieces: ops/embedding.py, whose "experts" are
+    groups of vocabulary rows.)
 
 Padding rows are zeros in, zeros through SwiGLU, and never gathered back.
 
@@ -606,7 +609,10 @@ def _gmm(x, w, plan: Plan, transposed: bool, interpret: bool):
     )(plan.tile_group, plan.tiles_used, x, w)
 
 
-def _tgmm_kernel(group_ref, used_ref, x_ref, g_ref, o_ref, acc_ref):
+def _tgmm_kernel(group_ref, used_ref, x_ref, g_ref, o_ref, acc_ref, *,
+                 whole):
+    """whole: the result's block of o_ref, the first of a [1, K, N] block
+    (0) or all of a [K, N] one (...)."""
     i = pl.program_id(2)
     last_tile = used_ref[0] - 1
     group = group_ref[i]
@@ -624,22 +630,43 @@ def _tgmm_kernel(group_ref, used_ref, x_ref, g_ref, o_ref, acc_ref):
         @pl.when((i == last_tile)
                  | (group_ref[jnp.minimum(i + 1, last_tile)] != group))
         def _last_of_group():
-            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+            o_ref[whole] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _tgmm(x, g, plan: Plan, n_groups: int, interpret: bool):
+def _tgmm(x, g, plan: Plan, n_groups: int, interpret: bool,
+          table_rows: int = 0):
     """x [rows, K], g [rows, N] -> [G, K, N] in the rows' type: x^T g over
     each group's rows (padding rows are zero; every group owns a tile, so
     every block of the result is written). Grid (K blocks, N blocks, row
-    tiles), the tiles inside as the reduction."""
+    tiles), the tiles inside as the reduction.
+
+    `table_rows` is ops/embedding.py's, where a group is K consecutive rows
+    of a table of that many and x marks each row's id: the result is then
+    the table's own shape, [table_rows, N], the groups' blocks one under the
+    other and the last one cut where the table ends (a reshape and a slice
+    after the call would stand between it and the optimizer's fusion:
+    PERF.md, PR 51), in blocks of whole rows of N where the float32 block
+    and its copy fit, and the call runs under a name of its own,
+    `embed_grad`, so that a trace counts it apart."""
     m, kdim = x.shape
     n = g.shape[1]
     tiles = plan.tile_group.shape[0]
     tm = m // tiles
     tk = lane_divisor(kdim, 1024)
-    tn = lane_divisor(n, 1024)
+    if table_rows:
+        tn = lane_divisor(n, max(128, _WEIGHT_BLOCK_BYTES // (4 * tk)))
+        shape, whole = (table_rows, n), ...
+        out_spec = pl.BlockSpec(
+            (tk, tn), lambda a, b, i, grp, used: (
+                grp[_used(i, used)] * (kdim // tk) + a, b))
+    else:
+        tn = lane_divisor(n, 1024)
+        shape, whole = (n_groups, kdim, n), 0
+        out_spec = pl.BlockSpec(
+            (1, tk, tn),
+            lambda a, b, i, grp, used: (grp[_used(i, used)], a, b))
     return pl.pallas_call(
-        _tgmm_kernel,
+        functools.partial(_tgmm_kernel, whole=whole),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(kdim // tk, n // tn, tiles),
@@ -649,17 +676,15 @@ def _tgmm(x, g, plan: Plan, n_groups: int, interpret: bool):
                 pl.BlockSpec((tm, tn),
                              lambda a, b, i, grp, used: (_used(i, used), b)),
             ],
-            out_specs=pl.BlockSpec(
-                (1, tk, tn),
-                lambda a, b, i, grp, used: (grp[_used(i, used)], a, b)),
+            out_specs=out_spec,
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_groups, kdim, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct(shape, x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name="moe_tgmm",
+        name="embed_grad" if table_rows else "moe_tgmm",
     )(plan.tile_group, plan.tiles_used, x, g)
 
 

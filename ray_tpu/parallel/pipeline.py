@@ -162,6 +162,9 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
             recv, loss_sum, loss_cnt = carry
             inject_idx = jnp.clip(t, 0, M - 1)
             # Only rank 0 pays for the embedding lookup (real branch on TPU).
+            # Written out, not ops/embedding.py's embed_lookup: a branch a
+            # tick inside the stages' shard_map, where XLA's gather and its
+            # transpose stay (no cell runs a pipeline).
             injected = lax.cond(
                 rank == 0,
                 lambda: embed_tbl.astype(dt)[

@@ -129,7 +129,8 @@ KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
            "short_conv_bwd", "latent_q_split", "latent_kv_split",
            "latent_q_merge", "latent_kv_merge", "index_scores",
            "index_search", "index_kl", "index_grad_q", "index_grad_k",
-           "conv_silu_fwd", "conv_silu_bwd", "kda_fwd", "kda_bwd")
+           "conv_silu_fwd", "conv_silu_bwd", "kda_fwd", "kda_bwd",
+           "embed_grad")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:", "compile:")

@@ -880,6 +880,49 @@ def test_grouped_matmul_kernels_compile_for_v5e(v5e, cell, matrices):
     assert any(f" = bf16[{experts},{width},{d}]" in op for op in tgmm)
 
 
+# (table, tokens): smallthinker's, olmoe's and gpt2s' (50 257 rows: no whole
+# number of groups)
+LOOKUP_SHAPES = {
+    "smallthinker": ((37984, 2560), (1, 16384)),
+    "olmoe": ((50304, 2048), (2, 4096)),
+    "gpt2s": ((50257, 768), (64, 1024)),
+}
+
+
+@pytest.mark.parametrize("cell", list(LOOKUP_SHAPES))
+def test_embedding_lookup_compiles_without_a_scatter(v5e, monkeypatch, cell):
+    """ops/embedding.py's lookup on one described chip at a cell's table and
+    batch: the forward is a gather and no Mosaic call, the gradient adds
+    exactly one (`embed_grad`: a group's block of float32 beside its
+    rounded copy has to fit the kernel's VMEM at whole rows of d), and no
+    scatter is left for the chip to serialise."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.embedding import embed_lookup
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    one_chip = SingleDeviceSharding(v5e[0])
+    (vocab, d), (batch, seq) = LOOKUP_SHAPES[cell]
+    table = jax.ShapeDtypeStruct((vocab, d), jnp.float32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+
+    def forward(table, tokens):
+        return embed_lookup(table, tokens, jnp.bfloat16)
+
+    def both(table, tokens):
+        # squared, so that the gradient needs the forward's rows
+        rows, pull = jax.vjp(lambda t: forward(t, tokens), table)
+        return pull(rows * rows)[0]
+    alone = jax.jit(forward).lower(table, tokens).compile().as_text()
+    text = jax.jit(both).lower(table, tokens).compile().as_text()
+    assert alone.count("tpu_custom_call") == 0
+    assert text.count("tpu_custom_call") == 1
+    assert len(_kernel_ops(text, "embed_grad")) == 1
+    assert " scatter(" not in text and " scatter(" not in alone
+    assert f" = f32[{vocab},{d}]" in text
+
+
 # (configuration, rows a tile, tiles of the share's bounded row space, tiles
 # for every slot)
 SHARE_ROW_SPACES = [
@@ -975,7 +1018,7 @@ CELL_STEPS = [
     # written: 7.51 of state, 3.7 of temporaries.
     ("olmoe-1b-7b", {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
                      "rope_split": 6, "rope_merge": 3, "moe_gmm": 9,
-                     "moe_tgmm": 3},
+                     "moe_tgmm": 3, "embed_grad": 1},
      (0.55, 0.75)),
     # kanana2_train_1chip: 5 layers of latent attention at q.k 192 padded
     # to 256 / v 128, one dense and four sparse with 16 of 128 experts held.
@@ -988,6 +1031,7 @@ CELL_STEPS = [
     # of state, 4.07 of temporaries.
     ("kanana-2-30b-a3b", {"flash_fwd": 5, "flash_bwd_dq": 5,
                           "flash_bwd_dkv": 5, "moe_gmm": 72, "moe_tgmm": 24,
+                          "embed_grad": 1,
                           "latent_q_split": 10, "latent_kv_split": 10,
                           "latent_q_merge": 5, "latent_kv_merge": 5},
      (0.55, 0.92)),
@@ -1000,7 +1044,7 @@ CELL_STEPS = [
     # temporaries.
     ("lfm2-24b-a2b", {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
                       "rope_split": 6, "rope_merge": 3, "moe_gmm": 72,
-                      "moe_tgmm": 24, "short_conv_fwd": 8,
+                      "moe_tgmm": 24, "embed_grad": 1, "short_conv_fwd": 8,
                       "short_conv_bwd": 4},
      (0.45, 0.75)),
     # laguna_train_1chip: full attention (48 query heads on 8) with the
@@ -1013,7 +1057,8 @@ CELL_STEPS = [
     ("laguna-xs.2", {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2,
                      "flash_win_fwd": 3, "flash_win_bwd_dq": 3,
                      "flash_win_bwd_dkv": 3, "rope_split": 30,
-                     "rope_merge": 15, "moe_gmm": 72, "moe_tgmm": 24},
+                     "rope_merge": 15, "moe_gmm": 72, "moe_tgmm": 24,
+                     "embed_grad": 1},
      (0.78, 0.92)),
     # keye2_train_1chip: five layers alike, 32 query heads on 4 with a norm
     # a head, an indexer a layer (16 heads of 64 on one key head) whose walk
@@ -1031,7 +1076,7 @@ CELL_STEPS = [
                              "index_kl": 5, "index_grad_q": 5,
                              "index_grad_k": 5,
                              "rope_split": 35, "rope_merge": 20,
-                             "moe_gmm": 90, "moe_tgmm": 30},
+                             "moe_gmm": 90, "moe_tgmm": 30, "embed_grad": 1},
      (0.70, 0.85)),
     # solar2_train_1chip (1 x 8192 tokens): a grouped-query layer that
     # rotates nothing (8 query heads on 1 at head 128: one call of each
@@ -1045,7 +1090,8 @@ CELL_STEPS = [
     pytest.param("solar-open2-250b",
                  {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
                   "rope_split": 6, "rope_merge": 3, "moe_gmm": 72,
-                  "moe_tgmm": 24, "conv_silu_fwd": 18, "conv_silu_bwd": 9},
+                  "moe_tgmm": 24, "embed_grad": 1, "conv_silu_fwd": 18,
+                  "conv_silu_bwd": 9},
                  (0.80, 0.93), marks=pytest.mark.timeout(900)),
     # smallthinker_train_1chip (1 x 16 384 tokens): a full layer that
     # rotates nothing and three window layers (4096: a band of two major
@@ -1061,7 +1107,7 @@ CELL_STEPS = [
                   "flash_win_fwd": 3, "flash_win_bwd_dq": 3,
                   "flash_win_bwd_dkv": 3, "rope_split": 24,
                   "rope_merge": 12, "moe_gmm": 72, "moe_tgmm": 24,
-                  "moe_run_sum": 8},
+                  "embed_grad": 1, "moe_run_sum": 8},
                  (0.60, 0.80), marks=pytest.mark.timeout(900)),
 ]
 
@@ -1073,8 +1119,9 @@ def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
     """A one-chip cell's whole step (the cell's own traffic: 2 x 8192
     tokens, 2 x 4096 at olmoe; adamw over fp32 masters) for one described
     chip: every Mosaic call lays out, the
-    kernels are called as often as the layers say, and arguments +
-    temporaries stay under the chip's 16.91 GB. Under grouped queries k and
+    kernels are called as often as the layers say (`embed_grad` once a
+    step, the embedding lookup's backward, and never under `moe_tgmm`'s
+    name), and arguments + temporaries stay under the chip's 16.91 GB. Under grouped queries k and
     v exist at the key/value heads' count alone: no tensor of the step has
     them at the query heads'."""
     import json

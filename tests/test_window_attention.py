@@ -799,24 +799,31 @@ def test_configuration_file_keeps_the_catalog_and_states_the_cut():
 # flash kernels through ops/rope.py's latent kernels) and no other; laguna's
 # is its parent's.
 LOWERED = {
-    # heads of 64: the parent's text, untouched by PR 48 (which is the proof
+    # PR 51 recorded every one-chip cell anew and the four-chip cell not:
+    # on one device the embedding's lookup is ops/embedding.py's (a gather
+    # of the master rows, `embed_grad` backward), under the four-chip mesh
+    # it is the parent's expression and the step the parent's text. Before
+    # it: gpt2s 0a5e354a41f2d309, lfm2 6d8075c1983c7f5a, olmoe
+    # de1ac5dddca614d0, kanana ccd30b735ee79374, laguna 2c9cca064dffa677,
+    # keye c7b4fffd346aa0f2, solar 830c2fd63a15f131.
+    # heads of 64: untouched by PR 48 (which is the proof
     # that the three cells bypass it: the kernels write [B * H, S, 64], XLA
     # turns it under `attn_out`, outside the shard_map; the first two also
     # untouched by PR 42, having no sparse layer)
-    "gpt2s_train_1chip": "0a5e354a41f2d309",
+    "gpt2s_train_1chip": "e83fa754d169a879",
     "smollm17_train_4chip": "3d347ff7870a2d4a",
-    "lfm2_train_1chip": "6d8075c1983c7f5a",
+    "lfm2_train_1chip": "ee4dcfeb681f3aa3",
     # heads (v's) of 128, recorded anew by PR 48: the flash kernels write o
     # and read dO as [B, S, H * 128], `wo` reads that as it is, delta and a
     # gate a head go through `head_columns` (a875c8421b01b065,
     # 64dedc5a37df64cf, a13b1de35328fc71, 2420d0b4f00749c5 and
     # aa90d217a4905e58 before it: PR 42's masters in `moe_gmm`, and at
     # solar PR 47's `kda_fwd` / `kda_bwd`)
-    "olmoe_train_1chip": "de1ac5dddca614d0",
-    "kanana2_train_1chip": "ccd30b735ee79374",
-    "laguna_train_1chip": "2c9cca064dffa677",
-    "keye2_train_1chip": "c7b4fffd346aa0f2",
-    "solar2_train_1chip": "830c2fd63a15f131",
+    "olmoe_train_1chip": "37f86a82ad7f1fa7",
+    "kanana2_train_1chip": "8e6cd298cd0a8c16",
+    "laguna_train_1chip": "e425c199e1b22258",
+    "keye2_train_1chip": "b478ecfb41cd7a16",
+    "solar2_train_1chip": "42d57e72e853172f",
 }
 
 
