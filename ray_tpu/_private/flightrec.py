@@ -22,6 +22,7 @@ directly comparable; within-process durations are exact.
 
 from __future__ import annotations
 
+import os
 import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -223,18 +224,24 @@ def request_phase_durations(rec: Sequence) -> List[Tuple[str, float]]:
     return out
 
 
+def new_trace_id() -> str:
+    """An id for a trace or a span: 8 random bytes, as util/tracing draws."""
+    return os.urandom(8).hex()
+
+
 def span_event(name: str, trace_id: str, start: float, end: float,
-               **extra) -> dict:
+               parent_id: str = "", span_id: str = "", **extra) -> dict:
     """One kind:"span" task-event record — the wire shape get_spans()
     and the timeline consume — for spans recorded OUTSIDE util/tracing's
-    contextvar machinery: the GCS gang-drain spans and the compiled-DAG
-    dag:compile / dag:tick spans build these directly (a contextvar span
-    would mis-parent them under whatever task happens to be running)."""
-    import os as _os
+    contextvar machinery: the GCS gang-drain spans, the compiled-DAG
+    dag:compile / dag:tick spans and a train run's tree build these
+    directly (a contextvar span would mis-parent them under whatever task
+    happens to be running). `span_id` is for a parent whose children are
+    exported before it ends; `extra` keys become the slice's `args`."""
     return {"kind": "span", "trace_id": trace_id,
-            "span_id": _os.urandom(8).hex(), "parent_id": "",
+            "span_id": span_id or new_trace_id(), "parent_id": parent_id,
             "name": name, "task_id": trace_id, "start": start, "end": end,
-            "pid": _os.getpid(), **extra}
+            "pid": os.getpid(), **extra}
 
 
 # Worker-lane sub-slices drawn inside the task slice on the timeline.
@@ -258,7 +265,9 @@ def build_trace(events: List[dict]) -> List[dict]:
       - a "submit" slice on the owner's lane covering submit->dispatch;
       - one flow-event pair (ph "s"/"f", shared id) connecting the submit
         on the owner to the execution start on the worker across pids.
-    Span records (tracing.enable()) are skipped — get_spans() owns those.
+    And one slice ("X", cat "span") per finished span record, whoever
+    exported it (_span_slices); a serve request's spans are drawn with
+    their request (_build_serve_trace).
     """
     trace: List[dict] = []
     starts: Dict[str, dict] = {}
@@ -266,6 +275,10 @@ def build_trace(events: List[dict]) -> List[dict]:
                     and e.get("kind") == "serve_request"]
     if serve_events:
         trace.extend(_build_serve_trace(serve_events, events))
+    served = {e["request_id"] for e in serve_events if e.get("request_id")}
+    trace.extend(_span_slices([
+        e for e in events if isinstance(e, dict) and e.get("kind") == "span"
+        and e.get("end") is not None and e.get("trace_id") not in served]))
     for e in events:
         if not isinstance(e, dict) or e.get("kind") in (
                 "span", "serve_request"):
@@ -341,6 +354,61 @@ def build_trace(events: List[dict]) -> List[dict]:
             "tid": 0, "task_id": task_id,
         })
     return trace
+
+
+# Rows 0-2 of a lane are the tasks' and the serve hops'; a span's row is
+# this plus its depth in its tree, so a child draws under its parent.
+SPAN_ROW = 3
+_SPAN_FIELDS = frozenset((
+    "kind", "trace_id", "span_id", "parent_id", "name", "task_id", "start",
+    "end", "pid", "node_id"))
+
+
+def _span_slices(spans: List[dict]) -> List[dict]:
+    """One "X" slice per finished span record. Lane: the recording
+    process's pid (a raylet's span carries its node instead: `node:<id>`).
+    Row: SPAN_ROW + the span's depth under the parents that are on the
+    page. A child on its parent's lane is clamped into the parent's slice
+    (the task sub-slices' rule); one on another lane (a worker's
+    train:loop under the driver's train:round) keeps its own clock's
+    extent. Whatever else the record holds (fun_name, cache, call_s, ...)
+    is the slice's `args`."""
+    by_id = {s["span_id"]: s for s in spans}
+    placed: Dict[str, tuple] = {}        # span_id -> (lane, depth, ts, end)
+
+    def place(span: dict) -> tuple:
+        sid = span["span_id"]
+        if sid in placed:
+            return placed[sid]
+        lane = (str(span["pid"]) if span.get("pid") is not None
+                else "node:" + str(span.get("node_id", ""))[:8])
+        ts = span["start"] * 1e6
+        end = max(span["end"] * 1e6, ts)
+        placed[sid] = (lane, 0, ts, end)     # a cycle of parents ends here
+        parent = by_id.get(span.get("parent_id") or "")
+        depth = 0
+        if parent is not None:
+            p_lane, p_depth, p_ts, p_end = place(parent)
+            depth = p_depth + 1
+            if p_lane == lane:
+                ts = min(max(ts, p_ts), p_end)
+                end = min(max(end, ts), p_end)
+        placed[sid] = (lane, depth, ts, end)
+        return placed[sid]
+
+    out: List[dict] = []
+    for span in spans:
+        lane, depth, ts, end = place(span)
+        args = {k: v for k, v in span.items() if k not in _SPAN_FIELDS}
+        if span.get("task_id") not in (None, span.get("trace_id")):
+            args["task_id"] = span["task_id"]
+        out.append({
+            "cat": "span", "name": span.get("name", ""), "ph": "X",
+            "ts": ts, "dur": end - ts, "pid": lane, "tid": SPAN_ROW + depth,
+            "trace_id": span.get("trace_id"), "span_id": span["span_id"],
+            "parent_id": span.get("parent_id") or "", "args": args,
+        })
+    return out
 
 
 def _build_serve_trace(serve_events: List[dict],
@@ -431,9 +499,10 @@ def _build_serve_trace(serve_events: List[dict],
                         "pid": str(r.get("pid", "")), "tid": 0,
                         "request_id": rid,
                     })
-    # Spans belonging to serve traces: drawn here (build_trace skips
-    # spans otherwise) so the handler's spawned tasks / nested calls
-    # appear in the same chrome trace on their own pids.
+    # Spans belonging to serve traces: drawn here, stamped with their
+    # request (build_trace draws every other span), so the handler's
+    # spawned tasks / nested calls appear in the same chrome trace on
+    # their own pids.
     for e in all_events:
         if not isinstance(e, dict) or e.get("kind") != "span":
             continue

@@ -47,14 +47,21 @@ class HeadNode:
         self._resources = resources
         self._labels = labels
         self._object_store_memory = object_store_memory
+        # phase -> (start, end) on time.time(), stamped by start():
+        # worker_api.init's gauge and runtime:* spans read them
+        self.boot_phases: Dict[str, tuple] = {}
 
     async def start(self, port: int = 0) -> str:
+        t_gcs = time.time()
         gcs_address = await self.gcs.start(port=port)
+        t_raylet = time.time()
         self.raylet = Raylet(
             self.config, gcs_address, self.session_dir,
             resources=self._resources, labels=self._labels, is_head=True,
             object_store_memory=self._object_store_memory, node_name="head")
         await self.raylet.start()
+        self.boot_phases = {"gcs": (t_gcs, t_raylet),
+                            "raylet": (t_raylet, time.time())}
         return gcs_address
 
     async def stop(self):

@@ -943,15 +943,16 @@ class Raylet:
                      end: float):
         """Launch-path flight-recorder span (actor:spawn / actor:register
         / actor:ctor): buffered here, flushed to the GCS task-event ring
-        by the heartbeat loop so `ray_tpu timeline` shows where a slow
-        actor launch spent its time."""
+        by the heartbeat loop so `ray_tpu timeline` shows, on this node's
+        lane (`node_id`: the record carries no pid), where a slow actor
+        launch spent its time."""
         if not self.config.task_events_enabled:
             return
         self._pending_spans.append({
             "kind": "span", "trace_id": trace_id,
             "span_id": os.urandom(8).hex(), "parent_id": "",
             "name": name, "task_id": trace_id,
-            "start": start, "end": end})
+            "start": start, "end": end, "node_id": self.node_id.hex()})
 
     async def _flush_spans(self):
         if not self._pending_spans:

@@ -142,6 +142,9 @@ def init(address: Optional[str] = None, *,
     _ensure_loop()
     _state.namespace = namespace
 
+    # phase -> (start, end) on time.time(), stamped at the boundaries below
+    phases: Dict[str, tuple] = {}
+
     async def _boot():
         if address is None:
             res = detect_node_resources(num_cpus, num_tpus, resources, config)
@@ -150,6 +153,7 @@ def init(address: Optional[str] = None, *,
             gcs_address = await head.start()
             raylet_address = head.raylet.address
             _state.head = head
+            phases.update(head.boot_phases)
         else:
             gcs_address = address
             from ray_tpu._private import rpc
@@ -162,6 +166,7 @@ def init(address: Optional[str] = None, *,
             heads = [n for n in alive if n.is_head]
             raylet_address = (heads[0] if heads else alive[0]).address
         from ray_tpu._private import rpc
+        t_connect = time.time()
         conn = await rpc.connect(gcs_address)
         job_id = await conn.request("register_job",
                                     {"driver_address": "", "entrypoint": ""})
@@ -169,6 +174,7 @@ def init(address: Optional[str] = None, *,
         core = CoreWorker("driver", gcs_address, raylet_address, config,
                           job_id=job_id)
         await core.start_async()
+        phases["connect"] = (t_connect, time.time())
         _state.core = core
         _state.gcs_address = gcs_address
         return gcs_address
@@ -176,14 +182,21 @@ def init(address: Optional[str] = None, *,
     _state.run(_boot(), timeout=60)
     _state.initialized = True
     atexit.register(shutdown)
-    _record_init(started, time.time())
+    _record_init(started, time.time(), phases)
     return _state
 
 
-def _record_init(start: float, end: float) -> None:
-    """init() as the caller saw it: ray_tpu_init_seconds in this process's
-    registry and, where tracing is enabled (a driver with tracing off
-    records no span at all), the `runtime:init` flight-recorder span."""
+_INIT_PHASE_SPANS = {"gcs": "runtime:gcs_start",
+                     "raylet": "runtime:raylet_start",
+                     "connect": "runtime:connect"}
+
+
+def _record_init(start: float, end: float, phases: Dict[str, tuple]) -> None:
+    """init() as the caller saw it: ray_tpu_init_seconds and, by phase,
+    ray_tpu_init_phase_seconds in this process's registry (gcs and raylet
+    read 0.0 where init joined a cluster that was there) and, where tracing
+    is enabled (a driver with tracing off records no span at all), the
+    `runtime:init` flight-recorder span with one child a phase that ran."""
     try:
         from ray_tpu._private import flightrec
         from ray_tpu.util import metrics, tracing
@@ -192,9 +205,24 @@ def _record_init(start: float, end: float) -> None:
             "wall time of this process's last ray_tpu.init(): head or "
             "connection up and the driver's core worker started"
         ).set(end - start)
+        by_phase = metrics.Gauge(
+            "ray_tpu_init_phase_seconds",
+            "inside ray_tpu_init_seconds: Phase=gcs (GcsServer.start), "
+            "Phase=raylet (the head Raylet.start), both 0.0 where init() "
+            "joined a running cluster, and Phase=connect (register_job and "
+            "the driver's CoreWorker.start_async)",
+            tag_keys=("Phase",))
+        for phase in _INIT_PHASE_SPANS:
+            a, b = phases.get(phase, (0.0, 0.0))
+            by_phase.set(b - a, {"Phase": phase})
         if tracing.is_enabled():
-            tracing.export_span(flightrec.span_event(
-                "runtime:init", "runtime", start, end))
+            root = flightrec.span_event("runtime:init",
+                                        flightrec.new_trace_id(), start, end)
+            tracing.export_span(root)
+            for phase, (a, b) in phases.items():
+                tracing.export_span(flightrec.span_event(
+                    _INIT_PHASE_SPANS[phase], root["trace_id"], a, b,
+                    parent_id=root["span_id"]))
     except Exception:  # noqa: BLE001 — observability never blocks init
         logger.debug("init span/metric not recorded", exc_info=True)
 
@@ -518,7 +546,7 @@ async def internal_kv_keys_async(core, prefix: bytes = b"",
         "namespace": namespace, "prefix": prefix})
 
 
-def timeline(job_id=None) -> List[dict]:
+def timeline(job_id=None, device_trace: Optional[str] = None) -> List[dict]:
     """Chrome-trace-format task timeline (reference: ray.timeline).
 
     Flight-recorder upgrade: besides one "X" slice per completed task,
@@ -526,10 +554,20 @@ def timeline(job_id=None) -> List[dict]:
     result_put on the executing worker's lane, submit->dispatch on the
     owner's) and `ph:"s"/"f"` flow events that connect a submission on
     the driver to its execution on the worker across pids — load the
-    file in chrome://tracing or Perfetto to follow a task hop by hop."""
+    file in chrome://tracing or Perfetto to follow a task hop by hop.
+    Every exported span is a slice too (a train run's tree, the raylet's
+    actor launches, traced tasks). `device_trace`: a directory that
+    util/profiling.device_trace wrote, with the compiled step's HLO text
+    beside the trace (profiling.trace_files): adds a lane a chip with the
+    device's ops by region, on the spans' clock."""
     from ray_tpu._private import flightrec
     core = get_core()
     events = _call_on_core_loop(
         core, core.gcs.request("get_task_events",
                                {"job_id": job_id, "limit": 100000}), 30)
-    return flightrec.build_trace(events)
+    trace = flightrec.build_trace(events)
+    if device_trace:
+        from ray_tpu.util import profiling
+        trace.extend(profiling.device_slices(
+            *profiling.trace_files(device_trace)))
+    return trace

@@ -142,7 +142,7 @@ def cmd_summary(args):
 
 def cmd_timeline(args):
     ray_tpu = _connect(args)
-    trace = ray_tpu.timeline()
+    trace = ray_tpu.timeline(device_trace=args.device_trace)
     rid = getattr(args, "request", None)
     if rid:
         # One serve request's trace only: every row stamped with the
@@ -432,6 +432,10 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("-o", "--output", default=None)
     s.add_argument("--request", default=None,
                    help="filter to one serve request id (X-Request-Id)")
+    s.add_argument("--device-trace", default=None, metavar="DIR",
+                   help="a util/profiling.device_trace directory with the "
+                        "compiled step's *.hlo.txt in it: adds a lane a "
+                        "chip, the device's ops by region")
     s.set_defaults(fn=cmd_timeline)
 
     s = sub.add_parser("top", help="live cluster dashboard "

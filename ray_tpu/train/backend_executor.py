@@ -18,10 +18,12 @@ from typing import Any, Callable, Dict, List, Optional
 
 import cloudpickle
 
+from ray_tpu._private import flightrec
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.config import ScalingConfig
 from ray_tpu.train.session import TrainContext
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import tracing
 
 
 @dataclass
@@ -88,9 +90,12 @@ class JaxBackendConfig(BackendConfig):
         ray_tpu.get(refs, timeout=180)
 
 
-# The driver-side phases of a run, in the runtime's idiom: a
-# `train:<phase>` flight-recorder span (ray_tpu timeline) and a catalogued
-# ray_tpu_train_* metric in this process's registry.
+# The phases of a run, in the runtime's idiom: one tree of flight-recorder
+# spans a run (root `train:run`, every span under the run's id as its
+# trace_id, drawn by `ray_tpu timeline`) and catalogued ray_tpu_train_*
+# metrics in this process's registry. What is measured in a worker is
+# stamped there, rides the message the worker sends anyway, and is folded
+# and exported here.
 _metrics: Optional[dict] = None
 
 
@@ -102,8 +107,11 @@ def _metric_handles() -> dict:
             "start": metrics.Gauge(
                 "ray_tpu_train_start_seconds",
                 "wall time of the last run's start-up. On the driver: "
-                "Phase=workers (placement, actors answering, backend "
-                "hook), Phase=training (loop shipped, every worker's "
+                "Phase=workers (BackendExecutor.start) and inside it "
+                "Phase=placement (placement group asked for -> placed), "
+                "Phase=actors (first train-worker actor asked for -> "
+                "every node_info back), Phase=hook (backend.on_start); "
+                "Phase=training (loop shipped, every worker's "
                 "start_run back). In the slowest worker, carried by its "
                 "first message: Phase=first_report (start_run -> the "
                 "loop's first report()) and, inside it, what it compiled: "
@@ -117,28 +125,19 @@ def _metric_handles() -> dict:
                 "mid-run (the compile:* spans name the function)"),
             "report": metrics.Histogram(
                 "ray_tpu_train_report_seconds",
-                "what a train.report() round costs: Phase=blocked (the "
-                "loop's put waited for the driver to take the round "
-                "before), Phase=poll (result queued on the worker -> held "
-                "by the driver; worker's and driver's wall clocks)",
+                "what a train.report() round costs. In the worker, one "
+                "process's clock each: Phase=call (report() entry -> "
+                "return), inside it Phase=blocked (the loop's put waited "
+                "for the driver to take the round before), Phase=wake "
+                "(result queued by the loop's thread -> taken by the "
+                "RPC thread that serves poll). Across processes: "
+                "Phase=poll (result queued on the worker -> held by the "
+                "driver; worker's and driver's wall clocks)",
                 boundaries=[0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
                             0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 5.0],
                 tag_keys=("Phase",)),
         }
     return _metrics
-
-
-def _export_span(name: str, start: float, end: float,
-                 only_if_traced: bool = False, **extra) -> None:
-    try:
-        from ray_tpu.util import tracing
-        if only_if_traced and not tracing.is_enabled():
-            return
-        from ray_tpu._private import flightrec
-        tracing.export_span(flightrec.span_event(name, "train", start, end,
-                                                 **extra))
-    except Exception:  # noqa: BLE001 — observability never blocks
-        pass
 
 
 class TrainingFailedError(RuntimeError):
@@ -166,18 +165,43 @@ class BackendExecutor:
         self.world_size = scaling.num_workers
 
     def start(self):
-        self._started_at = time.time()
+        self._started_at = started = time.time()
         self._save_pushed = False
+        # the run's trace: every span of the run carries run_id, and
+        # train:run (exported by shutdown) is the root of its tree
+        self.run_id = flightrec.new_trace_id()
+        self._run_span = flightrec.new_trace_id()
+        workers_span = flightrec.new_trace_id()
+        self._last_taken: Dict[int, tuple] = {}
         self.worker_group = WorkerGroup(
             self.scaling.num_workers, self.scaling.worker_resources(),
             self.scaling.placement_strategy)
+        placed = self.worker_group.placed_at
         self.node_info_per_worker = self.worker_group.node_infos()
+        answered = time.time()
         self.backend.on_start(self)
+        hooked = time.time()
         self._start_preempt_watcher()
         now = time.time()
-        _metric_handles()["start"].set(now - self._started_at,
-                                       {"Phase": "workers"})
-        _export_span("train:start_workers", self._started_at, now)
+        gauge = _metric_handles()["start"]
+        for phase, name, a, b in (
+                ("placement", "train:placement", started, placed),
+                ("actors", "train:actors", placed, answered),
+                ("hook", "train:backend_hook", answered, hooked)):
+            gauge.set(b - a, {"Phase": phase})
+            self._span(name, a, b, workers_span)
+        gauge.set(now - started, {"Phase": "workers"})
+        self._span("train:start_workers", started, now, self._run_span,
+                   span_id=workers_span)
+
+    def _span(self, name: str, start: float, end: float, parent_id: str,
+              **extra) -> None:
+        """Export a span of this run's tree."""
+        try:
+            tracing.export_span(flightrec.span_event(
+                name, self.run_id, start, end, parent_id=parent_id, **extra))
+        except Exception:  # noqa: BLE001 — observability never blocks
+            pass
 
     # ---- driver-side preemption watcher ----
 
@@ -323,7 +347,7 @@ class BackendExecutor:
         ray_tpu.get(refs, timeout=60)
         now = time.time()
         _metric_handles()["start"].set(now - started, {"Phase": "training"})
-        _export_span("train:start_training", started, now)
+        self._span("train:start_training", started, now, self._run_span)
 
     def get_next_results(self, timeout: float = 600.0) -> Optional[List[dict]]:
         """One result per worker for this round, or None when all done.
@@ -365,9 +389,14 @@ class BackendExecutor:
                     report_seconds.observe(
                         max(0.0, time.time() - out["queued_at"]),
                         {"Phase": "poll"})
-                if out.get("blocked_s") is not None:
-                    report_seconds.observe(out["blocked_s"],
-                                           {"Phase": "blocked"})
+                    if out.get("taken_at") is not None:
+                        report_seconds.observe(
+                            max(0.0, out["taken_at"] - out["queued_at"]),
+                            {"Phase": "wake"})
+                for phase in ("blocked", "call"):
+                    if out.get(phase + "_s") is not None:
+                        report_seconds.observe(out[phase + "_s"],
+                                               {"Phase": phase})
                 if out["type"] == "error":
                     self._interrupt()
                     raise TrainingFailedError(
@@ -379,10 +408,17 @@ class BackendExecutor:
                 else:
                     results[i] = out
                     pending.discard(i)
-        _export_span("train:round", started, time.time(),
-                     only_if_traced=True)
-        self._fold_compiles({i: finished.get(i) or out
-                             for i, out in enumerate(results)})
+        messages = {i: finished.get(i) or out
+                    for i, out in enumerate(results)}
+        # per-round spans only where tracing is on; a compile's span hangs
+        # under the round whose message carried it, or under the run
+        parent = self._run_span
+        if tracing.is_enabled():
+            parent = flightrec.new_trace_id()
+            self._span("train:round", started, time.time(), self._run_span,
+                       span_id=parent)
+            self._export_report_spans(messages, parent)
+        self._fold_compiles(messages, parent)
         if finished and len(finished) == len(results):
             return None
         if finished:
@@ -390,11 +426,35 @@ class BackendExecutor:
             return [r for r in results if r is not None] or None
         return results
 
-    def _fold_compiles(self, messages: Dict[int, dict]) -> None:
+    def _export_report_spans(self, messages: Dict[int, dict],
+                             round_span: str) -> None:
+        """The worker's side of a round, on the worker's lane, from the
+        stamps its messages carry. A message says when it took the queue's
+        slot (`queued_at`, the worker's wall clock: microseconds before its
+        report() returned) and, on the worker's perf_counter, how long the
+        report BEFORE it took (`call_s`) and how long the loop then ran
+        until it entered this one (`loop_s`). So the message before dates
+        both: train:report ends where it was queued, under the round that
+        took it, and train:loop starts there, under this round."""
+        for i, out in messages.items():
+            pid = self.node_info_per_worker[i].get("pid")
+            before = self._last_taken.get(i)
+            if before is not None and out.get("call_s") is not None:
+                queued_at, parent = before
+                self._span("train:report", queued_at - out["call_s"],
+                           queued_at, parent, pid=pid, call_s=out["call_s"],
+                           blocked_s=out.get("blocked_s"))
+                self._span("train:loop", queued_at,
+                           queued_at + out["loop_s"], round_span, pid=pid,
+                           loop_s=out["loop_s"])
+            self._last_taken[i] = (out.get("queued_at"), round_span)
+
+    def _fold_compiles(self, messages: Dict[int, dict],
+                       parent: str) -> None:
         """What the workers compiled since their last message
         (_private/compile_cache.py's records, carried by this round's
         messages): every record a compile:<phase> span on its worker's
-        lane; the first round's are the run's start-up, in the gauge, and
+        lane, under `parent`; the first round's are the run's start-up, in the gauge, and
         any later one is a recompile, in the counter."""
         handles = _metric_handles()
         for i, out in messages.items():
@@ -402,8 +462,8 @@ class BackendExecutor:
             for fun_name, phase, start, end, load_s in out.get("compiles", ()):
                 cache = ({"cache": "miss" if load_s is None else "hit"}
                          if phase == "compile" else {})
-                _export_span("compile:" + phase, start, end, pid=pid,
-                             fun_name=fun_name, **cache)
+                self._span("compile:" + phase, start, end, parent, pid=pid,
+                           fun_name=fun_name, **cache)
         if not self._first_round:
             recompiles = sum(record[1] == "compile"
                              for out in messages.values()
@@ -440,3 +500,5 @@ class BackendExecutor:
             self.backend.on_shutdown(self)
             self.worker_group.shutdown()
             self.worker_group = None
+            self._span("train:run", self._started_at, time.time(), "",
+                       span_id=self._run_span)

@@ -104,6 +104,11 @@ class _Session:
         # (the driver had not taken the round before); rides the NEXT
         # message, since it is known only once the put has returned.
         self._blocked_s: Optional[float] = None
+        # The last report(), entry -> return, and the loop's stretch from
+        # that return to the next entry, on time.perf_counter(): they ride
+        # the next message too (blocked_s lies inside call_s).
+        self._call_s: Optional[float] = None
+        self._returned: Optional[float] = None
         # When TrainWorker.start_run was entered, on this process's
         # monotonic clock: the first report says how long ago that was.
         self._started = started
@@ -134,6 +139,7 @@ class _Session:
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None):
+        entered = time.perf_counter()
         if self._stop.is_set():
             raise _StopTraining()
         if checkpoint is not None:
@@ -149,16 +155,22 @@ class _Session:
             if self._started is not None:
                 message["first_report_s"] = time.monotonic() - self._started
                 self._started = None
-            self._put(message)
+            self._put(message, entered)
         # Block until consumed: put the *next* item only after the driver
         # drains; queue(maxsize=1) already provides that.
+        self._returned = time.perf_counter()
+        self._call_s = self._returned - entered
 
     def finish(self, value: Any = None, error: Optional[str] = None):
         self._put({"type": "error", "error": error}
-                  if error else {"type": "done", "value": value})
+                  if error else {"type": "done", "value": value},
+                  time.perf_counter())
 
-    def _put(self, message: dict) -> None:
+    def _put(self, message: dict, entered: float) -> None:
         message["blocked_s"] = self._blocked_s
+        message["call_s"] = self._call_s
+        message["loop_s"] = (None if self._returned is None
+                             else entered - self._returned)
         # what this process compiled since its last message (start-up's
         # programs on the first, a recompile on a later one)
         compiles = compile_cache.drain()

@@ -77,8 +77,12 @@ class TrainWorker:
         if self._session is None:
             return {"type": "error", "error": "worker not started"}
         out = self._session.next_result(timeout)
-        if out is not None and out["type"] in ("done", "error"):
-            _session_mod._set_session(None)
+        if out is not None:
+            # this process's clock, as `queued_at`: the hand-over from the
+            # loop's thread to this RPC thread
+            out["taken_at"] = time.time()
+            if out["type"] in ("done", "error"):
+                _session_mod._set_session(None)
         return out
 
     def interrupt(self) -> None:
@@ -111,6 +115,7 @@ class WorkerGroup:
             raise TimeoutError(
                 f"placement group for {num_workers} train workers "
                 f"({resources_per_worker} each) not placeable")
+        self.placed_at = time.time()    # BackendExecutor.start's boundary
         cls = ray_tpu.remote(TrainWorker)
         self.workers = []
         for i in range(num_workers):

@@ -14,11 +14,16 @@ flash kernels' names (KERNELS). A v5e trace carries no scope: an event on a
 chip's "XLA Ops" line is named by its instruction's HLO text and nothing
 else, so the region comes from the compiled step, whose HLO text gives every
 instruction's `op_name` (the scope path it was traced under).
+`device_trace` also dates its stretch on the wall clock, so `device_slices`
+can lay the same ops, by region, beside the flight recorder's spans
+(`ray_tpu timeline --device-trace`).
 """
 
 from __future__ import annotations
 
 import contextlib
+import glob
+import os
 import re
 import statistics
 import sys
@@ -152,6 +157,8 @@ CLOCK_SKEW_NOTE = (
     "millisecond (the device clock ran 1.3 ms ahead of the host's in a "
     "recorded v5e trace), so an idle gap under 3 ms is not named by a host "
     "span: it is summed under 'short gaps'")
+WALL_STAT = "wall_ns"       # STRETCH_SPAN's: time.time_ns() at its start
+HLO_SUFFIX = ".hlo.txt"     # the compiled step's text beside a trace
 
 Event = Tuple[str, float, float]     # name, start_ns, end_ns
 
@@ -160,28 +167,34 @@ Event = Tuple[str, float, float]     # name, start_ns, end_ns
 def device_trace(log_dir: str):
     """Record a jax.profiler trace of the enclosed stretch under `log_dir`
     (the Python tracer off: it slows the host), with one TraceAnnotation,
-    STRETCH_SPAN, around it: the window `device_regions` reduces. Only the
-    process that holds the chip can trace it."""
+    STRETCH_SPAN, around it: the window `device_regions` reduces. The
+    annotation carries the wall clock at its start (WALL_STAT), the one
+    event dated on the profiler's time base and on the clock the flight
+    recorder's spans use. Only the process that holds the chip can trace
+    it."""
     import jax
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
-        with jax.profiler.TraceAnnotation(STRETCH_SPAN):
+        with jax.profiler.TraceAnnotation(
+                STRETCH_SPAN, **{WALL_STAT: time.time_ns()}):
             yield
     finally:
         jax.profiler.stop_trace()
 
 
 def _load_xplane(path: str) -> Dict[str, Any]:
-    """{'devices': {chip: [Event]}, 'host': [Event]} of an .xplane.pb: each
-    TPU plane's "XLA Ops" line (one event per executed HLO op, named by the
-    op's whole HLO text), and the host threads' spans named train:*, host:*,
-    compile:* (_private/compile_cache.py's, one a compile's phase) or
-    STRETCH_SPAN."""
+    """{'devices': {chip: [Event]}, 'host': [Event], 'wall_offset_ns'} of
+    an .xplane.pb: each TPU plane's "XLA Ops" line (one event per executed
+    HLO op, named by the op's whole HLO text), the host threads' spans
+    named train:*, host:*, compile:* (_private/compile_cache.py's, one a
+    compile's phase) or STRETCH_SPAN, and what to add to the trace's
+    nanoseconds to get time.time_ns() (None where no STRETCH_SPAN says)."""
     from jax.profiler import ProfileData
     devices: Dict[int, List[Event]] = {}
     host: List[Event] = []
+    offset: Optional[float] = None
     for plane in ProfileData.from_file(path).planes:
         m = _DEVICE_PLANE.match(plane.name)
         if m:
@@ -197,7 +210,11 @@ def _load_xplane(path: str) -> Dict[str, Any]:
                             or e.name.startswith(HOST_SPAN_PREFIXES)):
                         host.append((e.name, e.start_ns,
                                      e.start_ns + e.duration_ns))
-    return {"devices": devices, "host": host}
+                    if e.name == STRETCH_SPAN:
+                        wall = dict(e.stats).get(WALL_STAT)
+                        if wall is not None:
+                            offset = int(wall) - e.start_ns
+    return {"devices": devices, "host": host, "wall_offset_ns": offset}
 
 
 def _instruction_op_names(hlo_text: str
@@ -278,6 +295,14 @@ def _is_collective(event_name: str) -> bool:
                 or _COLLECTIVE.match(_instruction(event_name)))
 
 
+def _region_of(event_name: str, named: Dict[str, str],
+               inherits: Dict[str, str]) -> Tuple[str, str]:
+    """An op line's event -> (region, the op_name path it is read from)."""
+    instruction = _instruction(event_name)
+    path = named.get(instruction) or inherits.get(instruction, "")
+    return _last_of(path, REGIONS) or UNATTRIBUTED, path
+
+
 def _self_times(events: List[Event]) -> List[float]:
     """Each event's duration less that of the events nested in it (a
     `while` spans its body's ops on the same line), so that every busy
@@ -323,13 +348,11 @@ def _chip_tables(events: List[Event], host: List[Event], start: float,
     collectives: Table = {}
     gaps: Table = {}
     for (name, a, b), own in zip(events, _self_times(events)):
-        instruction = _instruction(name)
-        path = named.get(instruction) or inherits.get(instruction, "")
-        region = _last_of(path, REGIONS) or UNATTRIBUTED
+        region, path = _region_of(name, named, inherits)
         row = rows.setdefault((region, _phase(path)), [0.0, 0])
         row[0] += own
         row[1] += 1
-        kernel = _last_of(named.get(instruction, ""), KERNELS)
+        kernel = _last_of(named.get(_instruction(name), ""), KERNELS)
         if kernel:
             calls = kernels.setdefault((kernel, _phase(path)), [0.0, 0])
             calls[0] += b - a
@@ -379,10 +402,32 @@ def _median_tables(chips: List[Dict[str, Table]]) -> Dict[str, Table]:
     return out
 
 
-def device_regions(trace, compiled) -> Dict[str, Any]:
+def _wall_offset_ns(trace: Dict[str, Any]) -> float:
+    offset_ns = trace.get("wall_offset_ns")
+    if offset_ns is None:
+        raise ValueError("the trace does not say when it was taken on the "
+                         "wall clock (device_trace records it), so it "
+                         "cannot be read against wall-clock spans")
+    return offset_ns
+
+
+def _joined(trace, compiled) -> Tuple[Dict[str, Any], Dict[str, str],
+                                      Dict[str, str]]:
+    """device_regions' inputs -> the loaded trace and the compiled step's
+    instruction -> op_name maps."""
+    if isinstance(trace, str):
+        trace = _load_xplane(trace)
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    return (trace,) + _instruction_op_names(text)
+
+
+def device_regions(trace, compiled, spans=()) -> Dict[str, Any]:
     """Where the device's time went, by region: `trace` is the path of an
     .xplane.pb (or what `_load_xplane` makes of one), `compiled` the
-    `jax.stages.Compiled` of the step that ran in it, or its HLO text.
+    `jax.stages.Compiled` of the step that ran in it, or its HLO text,
+    `spans` flight-recorder records (tracing.get_spans(): a train run's
+    train:loop / train:report) that may name an idle gap beside the
+    trace's own host spans.
 
     The window is the longest STRETCH_SPAN of the trace (device_trace
     opens it), or without one the extent of the device's ops. Returns
@@ -401,24 +446,79 @@ def device_regions(trace, compiled) -> Dict[str, Any]:
       idle_gaps   [host span, seconds]: every gap of the op line by the
                   train:*, host:* or compile:* span that overlaps most of
                   it (see `clock_skew_note`).
+    `wall_clock_offset_s` is what to add to the trace's seconds to get
+    time.time(), None for a trace `device_trace` did not date.
     """
-    if isinstance(trace, str):
-        trace = _load_xplane(trace)
+    trace, named, inherits = _joined(trace, compiled)
     if not trace["devices"]:
         raise ValueError("the trace has no TPU plane: nothing ran on a chip")
-    text = compiled if isinstance(compiled, str) else compiled.as_text()
-    named, inherits = _instruction_op_names(text)
     stretches = [(a, b) for n, a, b in trace["host"] if n == STRETCH_SPAN]
     if stretches:
         start, end = max(stretches, key=lambda s: s[1] - s[0])
     else:
         ops = [e for events in trace["devices"].values() for e in events]
         start, end = min(a for _n, a, _b in ops), max(b for _n, _a, b in ops)
+    offset_ns = trace.get("wall_offset_ns")
     host = [h for h in trace["host"] if h[0] != STRETCH_SPAN]
+    if spans:       # wall-clock seconds -> the trace's time base
+        shift = _wall_offset_ns(trace)
+        host += [(s["name"], s["start"] * 1e9 - shift,
+                  s["end"] * 1e9 - shift) for s in spans]
     chips = {chip: _chip_tables(events, host, start, end, named, inherits)
              for chip, events in sorted(trace["devices"].items())}
     return {"median": _listed(_median_tables(list(chips.values())),
                               end - start),
             "per_chip": {str(chip): _listed(tables, end - start)
                          for chip, tables in chips.items()},
+            "wall_clock_offset_s": (None if offset_ns is None
+                                    else offset_ns / 1e9),
             "clock_skew_note": CLOCK_SKEW_NOTE}
+
+
+def trace_files(log_dir: str) -> Tuple[str, str]:
+    """What `device_trace(log_dir)` left and the caller put beside it: the
+    newest .xplane.pb under `log_dir` and the text of the one *.hlo.txt in
+    it (`step.as_text()` of the jax.stages.Compiled that ran)."""
+    planes = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                       recursive=True)
+    texts = glob.glob(os.path.join(log_dir, "*" + HLO_SUFFIX))
+    if not planes or len(texts) != 1:
+        raise ValueError(
+            f"{log_dir}: {len(planes)} .xplane.pb and {len(texts)} "
+            f"*{HLO_SUFFIX} files; a device lane needs a trace and the one "
+            "compiled step's HLO text")
+    with open(texts[0]) as f:
+        return max(planes, key=os.path.getmtime), f.read()
+
+
+def device_slices(trace, compiled) -> List[dict]:
+    """The trace's device ops as Chrome-trace slices on the wall clock, a
+    lane a chip (`chip:<n>`): runs of back-to-back ops of one region and
+    phase are one slice, named by the region, so the flight recorder's
+    spans (worker_api.timeline) can be read against what the chip did.
+    Inputs as `device_regions`; the trace must be one `device_trace`
+    dated."""
+    trace, named, inherits = _joined(trace, compiled)
+    offset_ns = _wall_offset_ns(trace)
+    out: List[dict] = []
+    for chip, events in sorted(trace["devices"].items()):
+        events = sorted(events, key=lambda e: (e[1], -e[2]))
+        runs: List[list] = []       # [region, phase, start, end, ops]
+        for i, (name, a, b) in enumerate(events):
+            if i + 1 < len(events) and events[i + 1][1] < b:
+                continue        # spans the ops nested in it: they draw it
+            region, path = _region_of(name, named, inherits)
+            phase = _phase(path)
+            if (runs and runs[-1][:2] == [region, phase]
+                    and a - runs[-1][3] < _MIN_GAP_NS):
+                runs[-1][3] = max(runs[-1][3], b)
+                runs[-1][4] += 1
+            else:
+                runs.append([region, phase, a, b, 1])
+        out.extend({
+            "cat": "device", "name": region, "ph": "X",
+            "ts": (a + offset_ns) / 1e3, "dur": (b - a) / 1e3,
+            "pid": f"chip:{chip}", "tid": 0,
+            "args": {"phase": phase, "ops": ops}}
+            for region, phase, a, b, ops in runs)
+    return out

@@ -329,3 +329,91 @@ def test_v5e_fixture_without_scopes_is_all_unattributed():
 def test_trace_without_a_device_plane_is_refused():
     with pytest.raises(ValueError, match="no TPU plane"):
         profiling.device_regions({"devices": {}, "host": []}, HLO)
+
+
+# (d) one clock with the flight recorder's spans
+
+WALL_S = 1_790_000_000.0        # the trace's zero on time.time()
+DATED = dict(HAND_MADE, host=[HAND_MADE["host"][0]],
+             wall_offset_ns=WALL_S * 1e9)
+
+
+def _wall_span(name, start_ms, end_ms):
+    return {"name": name, "start": WALL_S + start_ms / 1e3,
+            "end": WALL_S + end_ms / 1e3}
+
+
+def test_a_dated_trace_reports_its_offset_and_spans_name_its_gaps():
+    """The hand-made trace without its host spans, dated: flight-recorder
+    spans on the wall clock are shifted onto the trace's base and name the
+    gaps the trace's own spans named."""
+    out = profiling.device_regions(DATED, HLO, spans=[
+        _wall_span("train:report", 58, 72), _wall_span("train:loop", 72, 100)])
+    assert out["wall_clock_offset_s"] == pytest.approx(WALL_S)
+    _assert_same(out["median"]["idle_gaps"], [
+        ["train:report", 0.01], ["train:loop", 0.008], ["short gaps", 0.002]])
+    assert profiling.device_regions(HAND_MADE, HLO)[
+        "wall_clock_offset_s"] is None
+    with pytest.raises(ValueError, match="wall clock"):
+        profiling.device_regions(HAND_MADE, HLO, spans=[
+            _wall_span("train:loop", 72, 100)])
+
+
+def test_v5e_fixture_gap_is_named_by_a_span_shifted_onto_its_base():
+    """The recorded trace predates the note: given an offset, a wall-clock
+    span over its longest gap names it in place of host:batch_wait."""
+    trace = profiling._load_xplane(os.path.join(FIXTURES, "trace.xplane.pb"))
+    assert trace["wall_offset_ns"] is None
+    text = "HloModule jit_chain\n\nENTRY %main () -> f32[] {\n}\n"
+    ops = sorted(trace["devices"][0], key=lambda e: e[1])
+    gap = max(zip(ops, ops[1:]), key=lambda p: p[1][1] - p[0][2])
+    trace["wall_offset_ns"] = WALL_S * 1e9
+    span = {"name": "train:loop", "start": WALL_S + gap[0][2] / 1e9 - 1.0,
+            "end": WALL_S + gap[1][1] / 1e9 + 1.0}
+    out = profiling.device_regions(trace, text, spans=[span])
+    assert out["median"]["idle_gaps"][0][0] == "train:loop"
+    assert out["wall_clock_offset_s"] == pytest.approx(WALL_S)
+
+
+def test_device_slices_lay_the_ops_by_region_on_the_wall_clock():
+    """A lane a chip; back-to-back ops of one region and phase are one
+    slice; a while is drawn by the ops nested in it; a gap ends a run."""
+    slices = profiling.device_slices(DATED, HLO)
+    assert {e["pid"] for e in slices} == {"chip:0"}
+    assert all(e["cat"] == "device" and e["ph"] == "X" for e in slices)
+    got = [(e["name"], e["args"]["phase"], round(e["ts"] / 1e3 - WALL_S * 1e3),
+            round(e["dur"] / 1e3), e["args"]["ops"]) for e in slices]
+    assert got == [
+        ("mlp", "forward", 0, 10, 1), ("attn_core", "recompute", 10, 10, 1),
+        ("attn_core", "backward", 20, 20, 1), ("head", "forward", 45, 10, 1),
+        ("mlp", "backward", 70, 5, 1), (UNATTRIBUTED, DASH, 75, 5, 1),
+        ("optimizer", DASH, 82, 5, 1), ("attn_core", "recompute", 95, 25, 1)]
+    merged = profiling.device_slices(dict(DATED, devices={0: [
+        _event("fusion.1", "fusion", 0, 10),
+        _event("fusion.1", "fusion", 10, 15),
+        _event("fusion.1", "fusion", 16, 20)]}), HLO)
+    assert [(round(e["dur"] / 1e3), e["args"]["ops"]) for e in merged] == [
+        (15, 2), (4, 1)]
+    with pytest.raises(ValueError, match="wall clock"):
+        profiling.device_slices(HAND_MADE, HLO)
+
+
+def test_device_trace_dates_its_stretch(jax_cpu, tmp_path):
+    """The stretch's annotation carries time.time_ns() at its start: the
+    loaded trace's offset turns the stretch's own start into that wall
+    time; trace_files finds the trace and the one HLO text beside it."""
+    import time
+    log_dir = str(tmp_path / "trace")
+    before = time.time_ns()
+    with profiling.device_trace(log_dir):
+        jax_cpu.numpy.ones(4).block_until_ready()
+    after = time.time_ns()
+    with pytest.raises(ValueError, match="0 \\*.hlo.txt"):
+        profiling.trace_files(log_dir)
+    with open(os.path.join(log_dir, "step" + profiling.HLO_SUFFIX), "w") as f:
+        f.write(HLO)
+    path, text = profiling.trace_files(log_dir)
+    assert text == HLO and path.endswith(".xplane.pb")
+    trace = profiling._load_xplane(path)
+    (stretch,) = [h for h in trace["host"] if h[0] == profiling.STRETCH_SPAN]
+    assert before <= stretch[1] + trace["wall_offset_ns"] <= after

@@ -346,3 +346,98 @@ def test_pubsub_drop_connection_clears_outbox():
 
     pubsub = asyncio.run(run())
     assert pubsub.outbox_depths() == {}
+
+
+# ---------------------------------------------------------------------------
+# spans on the timeline (build_trace on a recorded event list, no cluster)
+# ---------------------------------------------------------------------------
+
+def _recorded_events():
+    """A finished task with its phase record, and a train run's spans as
+    BackendExecutor and the raylet export them: driver pid 100, worker
+    pid 200, a raylet span that carries its node."""
+    from ray_tpu._private import flightrec
+    phases = flightrec.new_record()
+    for i, t in enumerate((10.0, 10.1, 10.2, 10.3, 10.4, 10.5, 10.6, 10.8,
+                           10.9, 11.0)):
+        phases[i] = t
+    phases[flightrec.IDX_WORKER] = "feedfacecafe"
+    task = {"task_id": "t1", "name": "poll", "state": "FINISHED",
+            "time": 11.0, "worker_id": "0123456789ab", "phases": phases}
+
+    def span(name, sid, parent, start, end, pid=100, **extra):
+        return {"kind": "span", "trace_id": "run1", "span_id": sid,
+                "parent_id": parent, "name": name, "task_id": "run1",
+                "start": start, "end": end, "pid": pid, **extra}
+    spans = [
+        span("train:run", "a", "", 1.0, 9.0),
+        span("train:start_workers", "b", "a", 1.0, 4.0),
+        # stamped a hair outside its parent: clamped, as a sub-slice is
+        span("train:actors", "c", "b", 1.5, 4.5),
+        span("train:round", "d", "a", 5.0, 6.0),
+        # the worker's clock, on the worker's lane: not clamped
+        span("train:loop", "e", "d", 4.9, 5.8, pid=200, loop_s=0.9),
+        span("compile:compile", "f", "d", 5.1, 5.2, pid=200,
+             fun_name="step", cache="hit"),
+        {"kind": "span", "trace_id": "actor:ff", "span_id": "g",
+         "parent_id": "", "name": "actor:spawn", "task_id": "actor:ff",
+         "start": 1.6, "end": 3.0, "node_id": "0011223344556677"},
+        span("unfinished", "h", "a", 7.0, None),
+    ]
+    return [task], spans
+
+
+def test_build_trace_draws_spans_nested_on_their_lanes():
+    from ray_tpu._private import flightrec
+    task, spans = _recorded_events()
+    trace = flightrec.build_trace(spans[:3] + task + spans[3:])
+    slices = {e["span_id"]: e for e in trace if e["cat"] == "span"}
+    assert sorted(slices) == list("abcdefg")      # finished spans only
+    assert all(e["ph"] == "X" for e in slices.values())
+    lanes = {sid: (e["pid"], e["tid"] - flightrec.SPAN_ROW)
+             for sid, e in slices.items()}
+    assert lanes == {"a": ("100", 0), "b": ("100", 1), "c": ("100", 2),
+                     "d": ("100", 1), "e": ("200", 2), "f": ("200", 2),
+                     "g": ("node:00112233", 0)}
+    for sid, e in slices.items():
+        parent = slices.get(e["parent_id"])
+        assert (parent is None) == (sid in "ag")
+        if parent is not None and parent["pid"] == e["pid"]:
+            assert parent["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"]
+    assert slices["c"]["ts"] == 1.5e6 and slices["c"]["dur"] == 2.5e6
+    assert slices["e"]["ts"] == 4.9e6 and slices["e"]["dur"] == pytest.approx(
+        0.9e6)
+    assert slices["e"]["args"] == {"loop_s": 0.9}
+    assert slices["f"]["args"] == {"fun_name": "step", "cache": "hit"}
+    assert slices["a"]["args"] == {} and slices["a"]["trace_id"] == "run1"
+
+
+def test_build_trace_without_spans_is_what_it_was():
+    """The task's slices are the same with the spans beside them, and the
+    list without spans yields no span slice."""
+    from ray_tpu._private import flightrec
+    task, spans = _recorded_events()
+    alone = flightrec.build_trace(task)
+    assert [(e["cat"], e["name"], e["ph"]) for e in alone] == [
+        ("task", "poll", "X"), ("phase", "args_resolve", "X"),
+        ("phase", "exec", "X"), ("phase", "result_put", "X"),
+        ("phase", "submit", "X"), ("flow", "task_flow", "s"),
+        ("flow", "task_flow", "f")]
+    together = flightrec.build_trace(spans + task)
+    assert [e for e in together if e["cat"] != "span"] == alone
+
+
+def test_build_trace_leaves_a_serve_requests_spans_to_its_request():
+    """A span of a serve request's trace is drawn once, as a serve_span
+    stamped with the request, not again as a span."""
+    from ray_tpu._private import flightrec
+    hop = {"kind": "serve_request", "request_id": "req1", "hop": "proxy",
+           "pid": 7, "deployment": "d",
+           "phases": [1.0, None, None, 1.1, None, None, None, 1.5, None]}
+    span = {"kind": "span", "trace_id": "req1", "span_id": "s1",
+            "parent_id": "", "name": "handler", "task_id": "x",
+            "start": 1.1, "end": 1.4, "pid": 8}
+    trace = flightrec.build_trace([hop, span])
+    assert [e["cat"] for e in trace if e.get("span_id") == "s1"] == [
+        "serve_span"]
