@@ -52,10 +52,65 @@ from ray_tpu.ops.linear_attention import KDA_OUT, chunk_log_decay, kda
 from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
                               rope_frequencies, rope_split, rope_table)
 from ray_tpu.ops.short_conv import short_conv, silu_conv
+from ray_tpu.ops.state_space import SSD_OUT, ssd
+from ray_tpu.ops.state_space import chunk_log_decay as ssm_log_decay
 from ray_tpu.parallel.sharding import MESH_AXES
 
-# GPTConfig.gate_activation: relu's derivative at 0 is 0 (jax.nn.relu's).
-_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# GPTConfig.gate_activation and ExpertForm.activation: relu's derivative at
+# 0 is 0 (jax.nn.relu's), and so is relu2's, relu(.)^2.
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+                "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+
+# A layer_kinds entry -> (the mixer's kind or None, whether a feed-forward
+# follows it). The four first are the block as it always was.
+_HALVES = {"attention": ("attention", True), "conv": ("conv", True),
+           "window": ("window", True), "kda": ("kda", True),
+           "attention_alone": ("attention", False),
+           "ssm": ("ssm", False), "ff": (None, True)}
+
+
+@dataclass(frozen=True)
+class StateSpace:
+    """An "ssm" layer's sizes (GPTConfig.ssm): Mamba-2's mixer
+    (`_ssm_block`; ops/state_space.py). heads x head_dim inner channels,
+    `groups` pairs of input and output directions of `state` numbers that
+    the heads of a group share, a causal depthwise filter of
+    GPTConfig.conv_filter taps with a bias on [x | B | C], the scan in
+    chunks of `chunk`, the gated norm over a group's inner channels."""
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    chunk: int = 128
+
+
+@dataclass(frozen=True)
+class ExpertForm:
+    """What a feed-forward computes, where it is not the gated MLP of three
+    matrices under GPTConfig.gate_activation (GPTConfig.expert_form; it
+    holds for the dense MLP, the shared expert and the routed experts
+    alike). matrices 3: down(act(gate x) * up x); 2: down(act(up x)), no
+    gate matrix. latent_dim > 0: the ROUTED experts read and write a latent
+    width between two dense projections (`moe/w_latent_in` [d, latent],
+    `moe/w_latent_out` [latent, d], scope `moe_latent`); the router and the
+    shared expert keep the full width. shared_d_ff: the shared expert's
+    width (0: n_shared_experts x d_ff)."""
+    matrices: int = 3
+    activation: str = "silu"
+    latent_dim: int = 0
+    shared_d_ff: int = 0
+
+
+@dataclass(frozen=True)
+class PredictionModule:
+    """A multi-token prediction module of one depth (GPTConfig.mtp;
+    arXiv:2412.19437, section 2.2): with h the stream before the final
+    norm and e the embedding, g_i = [norm_e(e(t_{i+1})) ; norm_h(h_i)]
+    W [2d, d], then the layers `layer_kinds` names (layer_fn's own block),
+    a norm of its own and the SAME head; the loss gains loss_coef times
+    the cross-entropy of t_{i+2} given g_i."""
+    layer_kinds: Tuple[str, ...]
+    loss_coef: float = 0.3
 
 
 @dataclass(frozen=True)
@@ -80,8 +135,13 @@ class GPTConfig:
     # RMSNorm over each head's columns of q and of k, before the rotation:
     # one learned scale of head_dim for q and one for k, shared by the heads.
     qk_head_norm: bool = False
-    # The token mixer of each layer: "attention" | "conv" | "window" | "kda",
-    # one a layer. None = attention everywhere. A "conv" layer is a gated short
+    # The kind of each layer, one a layer; None = "attention" everywhere.
+    # "attention" | "conv" | "window" | "kda": that token mixer, then a
+    # feed-forward (ln1 -> mixer -> ln2 -> MLP | experts). A layer may also
+    # be ONE norm and ONE half: "ssm" (a state-space mixer, sized by `ssm`),
+    # "attention_alone" (that mixer and no feed-forward), "ff" (a
+    # feed-forward and no mixer: the MLP, or experts where the stack is
+    # sparse). A "conv" layer is a gated short
     # convolution: [B | C | X] = three projections of the normed input,
     # C * filter(B * X) with a causal depthwise filter of conv_filter taps
     # a channel, then an output projection. No bias, no activation.
@@ -202,17 +262,35 @@ class GPTConfig:
     remat_policy: str = "full"
     attention: str = "flash"          # flash | reference | ring
     tie_embeddings: bool = False
+    # Sub-records, each None where the stack has nothing of the kind:
+    # "ssm" layers' sizes, the feed-forwards' form, a prediction module.
+    ssm: Optional[StateSpace] = None
+    expert_form: Optional[ExpertForm] = None
+    mtp: Optional[PredictionModule] = None
 
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         kinds = self.layer_kinds
-        if kinds is not None and (
-                len(kinds) != self.n_layers
-                or set(kinds) - {"attention", "conv", "window", "kda"}):
+        every = (kinds or ()) + (self.mtp.layer_kinds if self.mtp else ())
+        if (kinds is not None and len(kinds) != self.n_layers) \
+                or set(every) - set(_HALVES):
             raise ValueError(
-                f"layer_kinds {kinds!r}: expected n_layers={self.n_layers} "
-                "of 'attention' | 'conv' | 'window' | 'kda'")
+                f"layer_kinds {kinds!r}"
+                + (f" and mtp.layer_kinds {self.mtp.layer_kinds!r}"
+                   if self.mtp else "")
+                + f": expected n_layers={self.n_layers} of "
+                + " | ".join(map(repr, _HALVES)))
+        if "ssm" in every and self.ssm is None:
+            raise ValueError("'ssm' layers need their sizes: GPTConfig.ssm")
+        if "ssm" in every and self.attention == "ring":
+            raise ValueError(
+                "an 'ssm' layer's state runs along the whole sequence: it is "
+                "not sharded over 'sequence' (attention='ring')")
+        if self.route_from == "input" and "ff" in every:
+            raise ValueError("route_from='input' reads a layer's normed "
+                             "input ahead of its mixer: an 'ff' layer has "
+                             "none")
         if self.attention_gate not in (False, True, "element"):
             raise ValueError(f"attention_gate={self.attention_gate!r}: "
                              "expected False | True | 'element'")
@@ -222,6 +300,11 @@ class GPTConfig:
         if self.gate_activation not in _ACTIVATIONS:
             raise ValueError(f"gate_activation={self.gate_activation!r}: "
                              f"expected {' | '.join(map(repr, _ACTIVATIONS))}")
+        form = self.feed_forward
+        if form.matrices not in (2, 3) or form.activation not in _ACTIVATIONS:
+            raise ValueError(
+                f"expert_form {form!r}: expected matrices 2 | 3 and an "
+                f"activation of {' | '.join(map(repr, _ACTIVATIONS))}")
         if "kda" in (kinds or ()) and self.attention == "ring":
             raise ValueError(
                 "a 'kda' layer's state runs along the whole sequence: it is "
@@ -282,6 +365,11 @@ class GPTConfig:
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
+    @property
+    def feed_forward(self) -> ExpertForm:
+        """expert_form, or the gated MLP under gate_activation."""
+        return self.expert_form or ExpertForm(activation=self.gate_activation)
+
     def heads_of(self, kind: str) -> int:
         """Query heads of an "attention" or a "window" layer."""
         return (self.window_heads if kind == "window" else 0) or self.n_heads
@@ -329,7 +417,10 @@ def _init_dense(key, shape, scale=None, dtype=jnp.float32):
 
 
 def gpt_init(key, cfg: GPTConfig) -> Dict:
-    """Build the parameter pytree (fp32 master weights)."""
+    """Build the parameter pytree (fp32 master weights). A layer holds
+    `ln1` and its mixer's group, then `ln2` and `mlp` | `moe`, or, where its
+    kind is one half alone (`_HALVES`), `ln1` and that half's group: the
+    block runs what a layer's parameters hold."""
     keys = jax.random.split(key, cfg.n_layers + 3)
     params: Dict[str, Any] = {
         "embed": {"table": _init_dense(keys[0], (cfg.vocab_size, cfg.d_model),
@@ -338,36 +429,50 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = _init_dense(keys[1], (cfg.d_model, cfg.vocab_size))
-    layers = []
     d, ff, e = cfg.d_model, cfg.d_ff, cfg.n_experts
     held = e if cfg.experts_held is None else cfg.experts_held[1]
+    form = cfg.feed_forward
+    kinds = cfg.layer_kinds or ("attention",) * cfg.n_layers
+    # how many times the stack adds to the residual stream (two a layer
+    # where every layer has both halves): an output projection's scale
+    adds = sum((mixer is not None) + has_ff
+               for mixer, has_ff in map(_HALVES.get, kinds))
 
-    def mlp(k, width):
-        return {
-            "w_gate": _init_dense(k[0], (d, width)),
-            "w_up": _init_dense(k[1], (d, width)),
-            "w_down": _init_dense(k[2], (width, d),
-                                  scale=1.0 / math.sqrt(2 * cfg.n_layers * width)),
-        }
+    def mlp(k, width, fan_in=d, stack=()):
+        """A feed-forward's matrices in cfg's form: [*stack, fan-in,
+        fan-out], each at its fan-in's scale."""
+        m = {"w_gate": _init_dense(k[0], stack + (fan_in, width),
+                                   scale=1.0 / math.sqrt(fan_in)),
+             "w_up": _init_dense(k[1], stack + (fan_in, width),
+                                 scale=1.0 / math.sqrt(fan_in)),
+             "w_down": _init_dense(k[2], stack + (width, fan_in),
+                                   scale=1.0 / math.sqrt(adds * width))}
+        if form.matrices == 2:
+            del m["w_gate"]
+        return m
 
-    for i in range(cfg.n_layers):
-        k = jax.random.split(keys[i + 2], 8)
-        layer = {
-            "ln1": {"scale": jnp.ones((d,), jnp.float32)},
-            "ln2": {"scale": jnp.ones((d,), jnp.float32)},
-        }
-        if cfg.layer_kinds and cfg.layer_kinds[i] == "conv":
+    def build(layer_key, kind, sparse):
+        k = jax.random.split(layer_key, 8)
+        mixer, has_ff = _HALVES[kind]
+        layer = {"ln1": {"scale": jnp.ones((d,), jnp.float32)}}
+        if mixer is not None and has_ff:
+            layer["ln2"] = {"scale": jnp.ones((d,), jnp.float32)}
+        if mixer == "conv":
             # w_in: the published [d, 3d] as its three chunks B, C, X
             layer["conv"] = {
                 "w_in": _init_dense(k[0], (3, d, d), scale=1.0 / math.sqrt(d)),
                 "filter": _init_dense(k[1], (d, cfg.conv_filter),
                                       scale=1.0 / math.sqrt(cfg.conv_filter)),
                 "w_out": _init_dense(k[3], (d, d),
-                                     scale=1.0 / math.sqrt(2 * cfg.n_layers * d)),
+                                     scale=1.0 / math.sqrt(adds * d)),
             }
-        elif cfg.layer_kinds and cfg.layer_kinds[i] == "kda":
-            layer["kda"] = _init_kda(jax.random.fold_in(keys[i + 2], 12), cfg)
-        elif cfg.kv_latent_dim:
+        elif mixer == "kda":
+            layer["kda"] = _init_kda(jax.random.fold_in(layer_key, 12), cfg,
+                                     adds)
+        elif mixer == "ssm":
+            layer["ssm"] = _init_ssm(jax.random.fold_in(layer_key, 13), cfg,
+                                     adds)
+        elif mixer is not None and cfg.kv_latent_dim:
             r, h = cfg.kv_latent_dim, cfg.n_heads
             layer["attn"] = {
                 "wq": _init_dense(k[0], (d, h * cfg.qk_head_dim)),
@@ -377,29 +482,28 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
                     k[2], (r, h * (cfg.qk_nope_dim + cfg.v_head_dim))),
                 "wo": _init_dense(
                     k[3], (h * cfg.v_head_dim, d),
-                    scale=1.0 / math.sqrt(2 * cfg.n_layers * h * cfg.v_head_dim)),
+                    scale=1.0 / math.sqrt(adds * h * cfg.v_head_dim)),
             }
-        else:
+        elif mixer is not None:
             # a window layer's matrices lie under a name of their own: the
             # block reads a layer's kind off its parameters
-            kind = cfg.layer_kinds[i] if cfg.layer_kinds else "attention"
             kv = cfg.kv_heads * cfg.head_dim
-            wide = cfg.heads_of(kind) * cfg.head_dim    # d, but for head_dim
-            layer[_GROUP[kind]] = {
+            wide = cfg.heads_of(mixer) * cfg.head_dim   # d, but for head_dim
+            layer[_GROUP[mixer]] = {
                 "wq": _init_dense(k[0], (d, wide)),
                 "wk": _init_dense(k[1], (d, kv)),
                 "wv": _init_dense(k[2], (d, kv)),
                 "wo": _init_dense(k[3], (wide, d),
-                                  scale=1.0 / math.sqrt(2 * cfg.n_layers * wide)),
+                                  scale=1.0 / math.sqrt(adds * wide)),
             }
             if cfg.attention_gate:
-                layer[_GROUP[kind]]["wg"] = _init_dense(
-                    jax.random.fold_in(keys[i + 2], 10),
+                layer[_GROUP[mixer]]["wg"] = _init_dense(
+                    jax.random.fold_in(layer_key, 10),
                     (d, wide if cfg.attention_gate == "element"
-                     else cfg.heads_of(kind)))
+                     else cfg.heads_of(mixer)))
         if "attn" in layer and cfg.index_topk:
             hi, di = cfg.index_heads, cfg.index_head_dim
-            ik = jax.random.split(jax.random.fold_in(keys[i + 2], 11), 3)
+            ik = jax.random.split(jax.random.fold_in(layer_key, 11), 3)
             layer["attn"]["index"] = {
                 "wq": _init_dense(ik[0], (d, hi * di)),
                 "wk": _init_dense(ik[1], (d, di)),
@@ -414,36 +518,50 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
             for name in ("q_head_norm", "k_head_norm"):
                 layer["attn"][name] = {
                     "scale": jnp.ones((cfg.head_dim,), jnp.float32)}
-        if e > 0 and i >= cfg.dense_layers:
-            # stacked [held, fan-in, fan-out]: the scale is the fan-in's
+        if has_ff and sparse:
+            # stacked [held, fan-in, fan-out]: the scale is the fan-in's,
+            # which is the latent width where the experts work in one
             layer["moe"] = {
                 "router": _init_dense(k[4], (d, e), scale=0.02),
-                "w_gate": _init_dense(k[5], (held, d, ff),
-                                      scale=1.0 / math.sqrt(d)),
-                "w_up": _init_dense(k[6], (held, d, ff),
-                                    scale=1.0 / math.sqrt(d)),
-                "w_down": _init_dense(k[7], (held, ff, d),
-                                      scale=1.0 / math.sqrt(2 * cfg.n_layers * ff)),
-            }
+                **mlp(k[5:], ff, form.latent_dim or d, (held,))}
             # (keys of what only some configurations have are folded in, so
             # that the others' weights stay what they were)
             if cfg.router_bias_scale:
                 layer["moe"]["router_bias"] = _init_dense(
-                    jax.random.fold_in(keys[i + 2], 8), (e,),
+                    jax.random.fold_in(layer_key, 8), (e,),
                     scale=cfg.router_bias_scale)
             if cfg.n_shared_experts:
                 layer["moe"]["shared"] = mlp(
-                    jax.random.split(jax.random.fold_in(keys[i + 2], 9), 3),
-                    cfg.n_shared_experts * ff)
-        else:
+                    jax.random.split(jax.random.fold_in(layer_key, 9), 3),
+                    form.shared_d_ff or cfg.n_shared_experts * ff)
+            if form.latent_dim:
+                lk = jax.random.split(jax.random.fold_in(layer_key, 14), 2)
+                layer["moe"]["w_latent_in"] = _init_dense(
+                    lk[0], (d, form.latent_dim))
+                layer["moe"]["w_latent_out"] = _init_dense(
+                    lk[1], (form.latent_dim, d))
+        elif has_ff:
             layer["mlp"] = mlp(k[5:], cfg.dense_d_ff if e > 0 else ff)
-        layers.append(layer)
-    params["layers"] = layers
+        return layer
+
+    params["layers"] = [
+        build(keys[i + 2], kind, e > 0 and i >= cfg.dense_layers)
+        for i, kind in enumerate(kinds)]
+    if cfg.mtp is not None:
+        mk = jax.random.split(jax.random.fold_in(key, 15),
+                              len(cfg.mtp.layer_kinds) + 1)
+        params["mtp"] = {
+            "proj": _init_dense(mk[0], (2 * d, d)),
+            **{name: {"scale": jnp.ones((d,), jnp.float32)}
+               for name in ("norm_e", "norm_h", "norm")},
+            "layers": [build(mk[j + 1], kind, e > 0)
+                       for j, kind in enumerate(cfg.mtp.layer_kinds)]}
     return params
 
 
-def _init_kda(key, cfg: GPTConfig) -> Dict:
-    """A "kda" layer's parameters. The projections at their fan-in's scale;
+def _init_kda(key, cfg: GPTConfig, adds: int) -> Dict:
+    """A "kda" layer's parameters (adds: gpt_init's). The projections at
+    their fan-in's scale;
     a filter's taps at 1 / sqrt(taps); the decay's two seeded ranges as the
     delta-rule papers publish them: exp(a_log), a head's decay rate,
     log-uniform over 1..16, and dt_bias the inverse softplus of a step
@@ -466,11 +584,42 @@ def _init_kda(key, cfg: GPTConfig) -> Dict:
         "wg_up": _init_dense(k[12], (hd, wide)),
         "o_norm": {"scale": jnp.ones((hd,), jnp.float32)},
         "wo": _init_dense(k[3], (wide, d),
-                          scale=1.0 / math.sqrt(2 * cfg.n_layers * wide)),
+                          scale=1.0 / math.sqrt(adds * wide)),
     }
     filters = _init_dense(k[4], (3, wide, taps), scale=1.0 / math.sqrt(taps))
     layer.update(q_conv=filters[0], k_conv=filters[1], v_conv=filters[2])
     return layer
+
+
+def _init_ssm(key, cfg: GPTConfig, adds: int) -> Dict:
+    """An "ssm" layer's parameters (adds: gpt_init's). The published
+    in_proj [d, 2 HP + 2 GN + H] as its three parts: w_z (the gate), w_xbc
+    (what the filter reads: x, then the G input and the G output
+    directions) and w_dt (a step a head), each at its fan-in's scale; the
+    taps at 1 / sqrt(taps), their bias 0; Mamba-2's published seeds:
+    exp(a_log), a head's decay rate, uniform over 1..16; dt_bias the
+    inverse softplus of a step log-uniform over 1e-3..0.1 (`_init_kda`'s
+    range), floored at 1e-4; the skip d = 1; the gated norm's scale 1."""
+    m, d = cfg.ssm, cfg.d_model
+    inner, directions = m.heads * m.head_dim, 2 * m.groups * m.state
+    k = jax.random.split(key, 7)
+    dt = jnp.maximum(1e-4, jnp.exp(jax.random.uniform(
+        k[5], (m.heads,), minval=math.log(1e-3), maxval=math.log(0.1))))
+    return {
+        "w_z": _init_dense(k[0], (d, inner)),
+        "w_xbc": _init_dense(k[1], (d, inner + directions)),
+        "w_dt": _init_dense(k[2], (d, m.heads)),
+        "conv": _init_dense(k[3], (inner + directions, cfg.conv_filter),
+                            scale=1.0 / math.sqrt(cfg.conv_filter)),
+        "conv_bias": jnp.zeros((inner + directions,), jnp.float32),
+        "a_log": jnp.log(jax.random.uniform(k[4], (m.heads,), minval=1.0,
+                                            maxval=16.0)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "d": jnp.ones((m.heads,), jnp.float32),
+        "norm": {"scale": jnp.ones((inner,), jnp.float32)},
+        "w_out": _init_dense(k[6], (inner, d),
+                             scale=1.0 / math.sqrt(adds * inner)),
+    }
 
 
 def _whole(y):
@@ -515,9 +664,11 @@ def _rmsnorm(x, scale, eps, psum=_whole):
         return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def _head_rmsnorm(y, scale, eps):
+def _head_rmsnorm(y, scale, eps, dim: int = 0):
     """RMSNorm over each head's columns of y [B, S, heads * D], scale [D]
-    shared by the heads, with the columns left where they are
+    shared by the heads (or, with dim = D given, a scale a column, [heads *
+    D]: a state-space layer's norm a group), with the columns left where
+    they are
     (`head_columns`): the heads' mean squares are a product with the 0/1
     matrix that says which column is in which head, and so is their way
     back to the columns: two thin matmuls, lane dense.
@@ -525,14 +676,15 @@ def _head_rmsnorm(y, scale, eps):
     out far below the result's own rounding); the way back takes three, so
     that a head's factor reaches its columns to 2^-16."""
     with jax.named_scope("norm"):
-        width, dim = y.shape[-1], scale.shape[0]
+        width, dim = y.shape[-1], dim or scale.shape[0]
         member = head_columns(width, dim)
         y32 = y.astype(jnp.float32)
         mean_sq = jnp.einsum("bsw,wh->bsh", y32 * y32, member,
                              precision=jax.lax.Precision.DEFAULT) / dim
         factor = jnp.einsum("bsh,wh->bsw", jax.lax.rsqrt(mean_sq + eps),
                             member, precision=jax.lax.Precision.HIGH)
-        return (y32 * factor * jnp.tile(scale, width // dim)).astype(y.dtype)
+        return (y32 * factor
+                * jnp.tile(scale, width // scale.shape[0])).astype(y.dtype)
 
 
 def _rope(x, rope, positions):
@@ -1080,15 +1232,74 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
                                      m["wo"].astype(dt))), stats
 
 
+def _ssm_block(m, x, cfg: GPTConfig, where: Setting):
+    """A state-space mixer in attention's place (Mamba-2; the recurrence
+    and its chunked form: ops/state_space.py). From the normed input x, with
+    H heads of P channels and G groups of N (cfg.ssm):
+
+      z, xBC, dt = x w_z | x w_xbc | x w_dt
+      [xs | B | C] = silu(filter(xBC) + bias), a causal depthwise filter a
+            channel (ops/short_conv.py:silu_conv); xs [H, P], B and C [G, N]
+      dt = softplus(dt + dt_bias) a head; the decay a_t = exp(-exp(a_log) dt)
+      y = ssd(xs, dt, a_log, B, C, d)
+      out = [RMSNorm_group(y * silu(z)) * scale] w_out, the mean square over
+            each group's H P / G columns (the gate BEFORE the norm)
+
+    -> (out, the layer's statistics: `ssm_dt_mean`, the mean step, and
+    `ssm_log_decay_min`, the smallest cumulative log-decay inside a chunk).
+    The filter, the scan and the norm work on a head's or a group's own
+    columns, so column-parallel projections and a row-parallel w_out leave
+    them local to a shard of 'tensor' that holds whole groups. Scope `ssm`
+    holds the layer; `ssm_core`, nested, the scan alone."""
+    dt_, f32, size = cfg.dtype, jnp.float32, cfg.ssm
+    b, s, _ = x.shape
+    columns = ("batch", None, "heads")
+    conv = _per_shard(silu_conv, where.mesh,
+                      (columns, ("heads", None), ("heads",)), columns)
+    with jax.named_scope("ssm"):
+        z = jnp.einsum("bsd,de->bse", x, m["w_z"].astype(dt_))
+        xbc = conv(jnp.einsum("bsd,de->bse", x, m["w_xbc"].astype(dt_)),
+                   m["conv"], m["conv_bias"])
+        step = jax.nn.softplus(jnp.einsum(
+            "bsd,dh->bsh", x, m["w_dt"].astype(dt_),
+            preferred_element_type=f32) + m["dt_bias"])
+        inner = z.shape[-1]
+        directions = (xbc.shape[-1] - inner) // 2
+
+        def groups(t):                    # [B, S, G * N] -> [B, S, G, N]
+            return t.reshape(b, s, -1, size.state)
+        with jax.named_scope("ssm_core"):
+            y = ssd(xbc[..., :inner].reshape(b, s, -1, size.head_dim), step,
+                    m["a_log"], groups(xbc[..., inner:inner + directions]),
+                    groups(xbc[..., inner + directions:]), m["d"],
+                    chunk=size.chunk)
+        stats = {"ssm_dt_mean": jnp.mean(step),
+                 "ssm_log_decay_min": jnp.min(ssm_log_decay(
+                     step, m["a_log"], size.chunk))}
+        gated = y.reshape(b, s, inner).astype(f32) * jax.nn.silu(
+            z.astype(f32))
+        # a group is whole wherever its columns are: no psum
+        normed = _head_rmsnorm(
+            gated, m["norm"]["scale"], cfg.rmsnorm_eps,
+            dim=inner // (directions // size.state)).astype(dt_)
+        return where.psum(jnp.einsum("bsd,de->bse", normed,
+                                     m["w_out"].astype(dt_))), stats
+
+
 def _mlp_block(m, x, cfg: GPTConfig, where: Setting):
     """The gated MLP through m's three matrices, down(act(gate x) * up x)
-    with cfg.gate_activation (SwiGLU by default): a dense layer's MLP, or
-    the shared expert of a sparse one."""
+    with cfg.gate_activation (SwiGLU by default), or, where m holds no gate
+    matrix (cfg.expert_form: two matrices), down(act(up x)): a dense
+    layer's MLP, or the shared expert of a sparse one."""
     dt = cfg.dtype
-    act = _ACTIVATIONS[cfg.gate_activation]
-    gate = jnp.einsum("bsd,df->bsf", x, m["w_gate"].astype(dt))
-    up = jnp.einsum("bsd,df->bsf", x, m["w_up"].astype(dt))
-    return where.psum(jnp.einsum("bsf,fd->bsd", act(gate) * up,
+    act = _ACTIVATIONS[cfg.feed_forward.activation]
+    if "w_gate" in m:
+        gate = jnp.einsum("bsd,df->bsf", x, m["w_gate"].astype(dt))
+        up = jnp.einsum("bsd,df->bsf", x, m["w_up"].astype(dt))
+        hidden = act(gate) * up
+    else:
+        hidden = act(jnp.einsum("bsd,df->bsf", x, m["w_up"].astype(dt)))
+    return where.psum(jnp.einsum("bsf,fd->bsd", hidden,
                                  m["w_down"].astype(dt)))
 
 
@@ -1154,27 +1365,34 @@ def _route(m, x, cfg: GPTConfig):
     return weights, idx, stats
 
 
-def _expert_rows(tiles, rows, order, x, weights, w_gate, w_up, w_down,
+def _expert_rows(tiles, rows, order, x, weights, *matrices,
                  activation="silu"):
     """_experts in a row space of `tiles` tiles of `rows` rows (which has to
-    hold the order's: moe.in_row_space): dispatch, three grouped matmuls
-    with the gated activation between, weighted return. Under "relu" the
-    result is (y, [zeros, units] float32): how many of the held experts'
-    hidden units relu(gate) are exactly 0 over the rows that hold a slot,
-    and how many such units there are (the padding rows, zeros all, and
-    the tiles nobody computes are not counted)."""
+    hold the order's: moe.in_row_space): dispatch, the grouped matmuls with
+    the activation between (three matrices, gate, up and down: down(act(gate
+    x) * up x); two, up and down: down(act(up x))), weighted return. Under
+    "relu" and "relu2" the result is (y, [zeros, units] float32): how many
+    of the held experts' hidden units act(.) are exactly 0 over the rows
+    that hold a slot, and how many such units there are (the padding rows,
+    zeros all, and the tiles nobody computes are not counted)."""
     b, s, d = x.shape
     with jax.named_scope("moe_route"):
         plan = moe.lay_out(order, rows, tiles)
         rows_in = moe.dispatch(x.reshape(b * s, d), plan)
-    gate = moe.grouped_matmul(rows_in, w_gate, plan)
-    up = moe.grouped_matmul(rows_in, w_up, plan)
-    hidden = _ACTIVATIONS[activation](gate)
-    out = moe.grouped_matmul(hidden * up, w_down, plan)
+    *w_gate, w_up, w_down = matrices
+    if w_gate:
+        gate = moe.grouped_matmul(rows_in, w_gate[0], plan)
+        up = moe.grouped_matmul(rows_in, w_up, plan)
+        hidden = _ACTIVATIONS[activation](gate)
+        out = moe.grouped_matmul(hidden * up, w_down, plan)
+    else:
+        hidden = _ACTIVATIONS[activation](
+            moe.grouped_matmul(rows_in, w_up, plan))
+        out = moe.grouped_matmul(hidden, w_down, plan)
     with jax.named_scope("moe_route"):
         y = moe.combine(out, weights.reshape(b * s, -1), plan)
     y = y.reshape(b, s, d)
-    if activation != "relu":
+    if activation not in _COUNTS_ZEROS:
         return y
     real = plan.row_slot < order.order.shape[0]
     zeros = jnp.sum(jnp.where(real[:, None], hidden == 0, False),
@@ -1183,9 +1401,14 @@ def _expert_rows(tiles, rows, order, x, weights, w_gate, w_up, w_down,
     return y, jax.lax.stop_gradient(jnp.stack([zeros, units]))
 
 
+# The activations whose zeros a sparse layer counts
+# (`expert_hidden_zero_share`).
+_COUNTS_ZEROS = ("relu", "relu2")
+
+
 @lru_cache(maxsize=None)
 def _expert_rows_with(activation: str):
-    """_expert_rows at a gate activation, ONE function an activation:
+    """_expert_rows at an activation, ONE function an activation:
     moe.in_row_space jits what it is handed, and layers of one shape share
     one trace of it only if they hand it the same function (under
     _expert_rows' own name, which the lowered step's text carries)."""
@@ -1241,38 +1464,41 @@ def _routing(m, x, cfg: GPTConfig, where: Setting):
     weights, idx, stats = _route(m, x, cfg)
     held = _held(cfg)
     order = _per_shard(
-        partial(_slot_order, groups=m["w_gate"].shape[0], held=held,
+        partial(_slot_order, groups=m["w_down"].shape[0], held=held,
                 dtype=x.dtype),
         where.mesh, (("batch", None, None),), _order_dims(held))(idx)
     return weights, order, stats
 
 
-def _experts(x, weights, order, w_gate, w_up, w_down, held=None,
-             activation="silu"):
+def _experts(x, weights, order, *matrices, held=None, activation="silu"):
     """x [b, s, d] through each token's chosen experts (ops/moe.py): rows
-    in the order `_slot_order` gave the slots, three grouped matmuls with
-    the gated activation between, weighted return. The gathers either side
+    in the order `_slot_order` gave the slots, the grouped matmuls (gate,
+    up and down, or up and down) with the activation between, weighted
+    return. The gathers either side
     are the layer's sparsity, not its arithmetic: scope `moe_route`. held:
     None where the matrices are all the experts', else (first, of how
     many): the matrices are experts first .. first + len - 1, and a slot
     chosen for another is left out. -> (y, and then, each where it
     exists: [1] whether the row space sized for the slots expected here
     held them (a share: moe.in_row_space); [1, 2] `_expert_rows`' zero
-    and all hidden units ("relu"))."""
-    groups = w_gate.shape[0]
+    and all hidden units ("relu", "relu2"))."""
+    groups = matrices[0].shape[0]
     order = moe.Order(*order)
     expected, rows = _row_space(order.order.shape[0], groups, held, x.dtype)
     out, fitted = moe.in_row_space(_expert_rows_with(activation), order,
-                                   rows, expected, x, weights, w_gate, w_up,
-                                   w_down)
-    y, *hidden = out if activation == "relu" else (out,)
+                                   rows, expected, x, weights, *matrices)
+    y, *hidden = out if activation in _COUNTS_ZEROS else (out,)
     flag = [jnp.reshape(fitted, (1,))] if held is not None else []
     return (y, *flag, *(h[None] for h in hidden))
 
 
 def _moe_block(layer, x, cfg: GPTConfig, where: Setting, routing=None):
     """Sparse experts in the MLP's place: y = sum over a token's top-k of
-    p_e x down_e(act(gate_e x) * up_e x), act = cfg.gate_activation. No
+    p_e x down_e(act(gate_e x) * up_e x), act = cfg.gate_activation (or, as
+    cfg.expert_form says, down_e(act(up_e x)) with no gate matrix, and the
+    routed experts in a latent width: x w_latent_in before them, w_latent_out
+    after their weighted sum, scope `moe_latent`; the router and the shared
+    expert read the full width). No
     capacity and no dropped token: every token-slot is computed, by its
     own expert only. The grouped matmuls run per shard (`_per_shard`): each
     device dispatches its own tokens to all the experts, whose matrices it
@@ -1291,8 +1517,8 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting, routing=None):
     joins _route's statistics: the share of the devices on which that row
     space held the routing at hand (the others ran every slot's, the same
     arithmetic: moe.in_row_space), the constant 1.0 where all the experts
-    are held. Under gate_activation "relu", `expert_hidden_zero_share`
-    joins them: the share of the experts' hidden units relu(gate) that are
+    are held. Under the activations "relu" and "relu2",
+    `expert_hidden_zero_share` joins them: the share of the experts' hidden units relu(gate) that are
     exactly 0, over the rows computed. With cfg.n_shared_experts a dense
     gated MLP of every token is added (scope `moe_shared`). Scope `moe`
     (layer_fn's, around this) keeps the experts' own arithmetic: grouped
@@ -1303,28 +1529,38 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting, routing=None):
     losses."""
     dt = cfg.dtype
     m = layer["moe"]
+    form = cfg.feed_forward
     if routing is None:
         with jax.named_scope("moe_route"):
             routing = _routing(m, x, cfg, where)
     weights, order, stats = routing
-    matrices = [m[name] for name in ("w_gate", "w_up", "w_down")]
+    matrices = [m[name] for name in ("w_gate", "w_up", "w_down")
+                if name in m]
     if where.mesh is not None and where.mesh.size > 1:
         # handed whole to every device: gather them in the rows' type. On
         # one device the kernels read the masters and round a block in VMEM
         # (moe.grouped_matmul), and no cast pass runs here
         matrices = [w.astype(dt) for w in matrices]
-    held, relu = _held(cfg), cfg.gate_activation == "relu"
+    held, counts = _held(cfg), form.activation in _COUNTS_ZEROS
     tokens = ("batch", None, None)
     # what `_experts` hands back beside y: a share's flag, relu's counts
     extra_dims = ([("batch",)] if held is not None else []) \
-        + ([("batch", None)] if relu else [])
+        + ([("batch", None)] if counts else [])
+    rows = x
+    if "w_latent_in" in m:
+        with jax.named_scope("moe_latent"):
+            rows = jnp.einsum("bsd,dl->bsl", x, m["w_latent_in"].astype(dt))
     y, *extras = _per_shard(
-        partial(_experts, held=held, activation=cfg.gate_activation),
-        where.mesh, (tokens, tokens, _order_dims(held)) + ((),) * 3,
-        (tokens, *extra_dims))(x, weights, order, *matrices)
+        partial(_experts, held=held, activation=form.activation),
+        where.mesh,
+        (tokens, tokens, _order_dims(held)) + ((),) * len(matrices),
+        (tokens, *extra_dims))(rows, weights, order, *matrices)
+    if "w_latent_out" in m:
+        with jax.named_scope("moe_latent"):
+            y = jnp.einsum("bsl,ld->bsd", y, m["w_latent_out"].astype(dt))
     stats = dict(stats, expert_rows_bounded=(
         1.0 if held is None else jnp.mean(extras[0])))
-    if relu:
+    if counts:
         zeros, units = jnp.sum(extras[-1], axis=0)
         stats["expert_hidden_zero_share"] = zeros / jnp.maximum(units, 1.0)
     if "shared" in m:
@@ -1336,10 +1572,12 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting, routing=None):
 def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     """(x [B, seq, D], one layer's parameters) -> (x, the layer's
     statistics: _route's dict for a sparse layer, {} for a dense one, and
-    an indexer's two or a delta-rule layer's two where the layer has
-    them; which it is, and whether its mixer is attention, the short
-    convolution or the delta rule, the layer's own parameters say, as
-    gpt_init built them). The one
+    an indexer's two, a delta-rule layer's two or a state-space layer's
+    two where the layer has them; which it is, whether its mixer is
+    attention, the short convolution, the delta rule or the state-space
+    scan, and whether it is both halves (ln1 -> mixer -> ln2 -> MLP |
+    experts) or ONE norm and one half alone, the layer's own parameters
+    say, as gpt_init built them). The one
     transformer block, rematted as cfg.remat_policy says, for whoever
     walks the layers: gpt_backbone loops over their list, a stage of
     parallel/pipeline.py scans over stacked ones. Under cfg.route_from
@@ -1350,7 +1588,9 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     `moe`."""
     # once a step, not once a layer and recompute: outside the remat; one
     # table for each kind of attention layer the stack has
-    kinds = set(cfg.layer_kinds or ("attention",)) - {"conv", "kda"}
+    every = (cfg.layer_kinds or ("attention",)) + (
+        cfg.mtp.layer_kinds if cfg.mtp else ())
+    kinds = {_HALVES[kind][0] for kind in every} & set(_GROUP)
     with jax.named_scope("attn_proj"):
         # (no table for a kind that does not rotate)
         tables = {
@@ -1374,12 +1614,19 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
             mixed = _conv_block(layer["conv"], normed, cfg, where)
         elif "kda" in layer:
             mixed, mixer_stats = _kda_block(layer["kda"], normed, cfg, where)
-        else:
+        elif "ssm" in layer:
+            mixed, mixer_stats = _ssm_block(layer["ssm"], normed, cfg, where)
+        elif _GROUP["window"] in layer or _GROUP["attention"] in layer:
             kind = "window" if _GROUP["window"] in layer else "attention"
             mixed, mixer_stats = _attention_block(
                 layer, normed, cfg, tables[kind], where, kind, index_table)
-        h = where.pin(x + mixed)
-        normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
+        else:
+            mixed = None                 # a feed-forward alone
+        h = x if mixed is None else where.pin(x + mixed)
+        if "moe" not in layer and "mlp" not in layer:
+            return h, mixer_stats        # a mixer alone
+        if mixed is not None:
+            normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
         if "moe" in layer:
             with jax.named_scope("moe"):
                 delta, stats = _moe_block(layer, normed, cfg, where, routing)
@@ -1393,11 +1640,12 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         # (of an indexer, its selection and its loss's gradients: the walk
         # over the score tiles then runs once a layer and step; of a
         # delta-rule layer, its output and its chunks' states: `kda_bwd`
-        # computes a chunk again from them)
+        # computes a chunk again from them; of a state-space layer, the
+        # same two: the scan over the chunks runs once)
         return jax.checkpoint(
             block, policy=jax.checkpoint_policies.save_only_these_names(
                 FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK,
-                indexer.INDEX_GRADS, KDA_OUT))
+                indexer.INDEX_GRADS, KDA_OUT, SSD_OUT))
     if cfg.remat_policy != "none":
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(expected 'full' | 'none')")
@@ -1424,12 +1672,32 @@ def gpt_forward(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     return logits, router
 
 
+def gpt_forward_both(params, tokens, cfg: GPTConfig):
+    """tokens [B, S + 1] -> (logits [B, S, vocab] of the token after each of
+    the first S, logits [B, S, vocab] through the prediction module of the
+    token TWO after each (its last position has none to predict); cfg.dtype
+    both, the second None without cfg.mtp): what gpt_loss_and_aux takes its
+    two cross-entropies from, as logits."""
+    where = Setting()
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    h, _, block = _stack(params, inputs, cfg, where)
+    w_head = _head_operands(params, h, targets, cfg)[1]
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bsd,dv->bsv", final_norm(params, h, cfg), w_head)
+    if cfg.mtp is None:
+        return logits, None
+    g, _ = _prediction_module(params, h, targets, block, cfg, where)
+    with jax.named_scope("head"):
+        return logits, jnp.einsum("bsd,dv->bsv", g, w_head)
+
+
 def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     """tokens: [B, S] -> (final hidden states [B, S, D] (pre-LM-head), the
     layers' statistics, each averaged over the layers that have it:
     _route's dict for a sparse model, an indexer's two (`index_kl`,
-    `index_selected_share`) and a delta-rule layer's two
-    (`kda_log_decay_min`, `kda_beta_mean`) where the layers have them, {}
+    `index_selected_share`), a delta-rule layer's two
+    (`kda_log_decay_min`, `kda_beta_mean`) and a state-space layer's two
+    (`ssm_dt_mean`, `ssm_log_decay_min`) where the layers have them, {}
     for a dense model).
 
     act_sharding (a NamedSharding for [B, S, D] activations, usually
@@ -1438,22 +1706,67 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     activation gradients (the "involuntary full rematerialization" failure
     mode on 2D tp_fsdp meshes).
     """
-    where = Setting(mesh, act_sharding)
+    x, per_layer, _ = _stack(params, tokens, cfg, Setting(mesh, act_sharding))
+    router = _layer_means(per_layer)
+    return final_norm(params, x, cfg), router
+
+
+def _stack(params, tokens, cfg: GPTConfig, where: Setting):
+    """tokens [B, S] through the embedding and the layers -> (the residual
+    stream BEFORE the final norm, the statistics of each layer that has
+    any, the block: layer_fn's, for a caller that runs further layers at
+    this sequence length)."""
     with jax.named_scope("embed"):
         x = where.pin(embed_lookup(params["embed"]["table"], tokens,
-                                   cfg.dtype, mesh))
+                                   cfg.dtype, where.mesh))
     layer = layer_fn(cfg, tokens.shape[1], where)
+    x, per_layer = _walk(layer, x, params["layers"])
+    return x, per_layer, layer
+
+
+def _walk(layer, x, layers):
+    """x through `layers` (their parameters, in order) by the block `layer`
+    -> (x, the statistics of each layer that has any)."""
     per_layer = []
-    for layer_params in params["layers"]:
+    for layer_params in layers:
         x, stats = layer(x, layer_params)
         if stats:
             per_layer.append(stats)
-    # each statistic over the layers that have it
+    return x, per_layer
+
+
+def _layer_means(per_layer):
+    """Each statistic over the layers that have it."""
     router = {}
     for name in sorted({name for stats in per_layer for name in stats}):
         layers = [stats[name] for stats in per_layer if name in stats]
         router[name] = sum(layers) / len(layers)
-    return final_norm(params, x, cfg), router
+    return router
+
+
+def _prediction_module(params, h, targets, layer, cfg: GPTConfig,
+                       where: Setting):
+    """The multi-token prediction module (cfg.mtp; `PredictionModule`) on
+    h [B, S, D], the stream before the final norm, and targets [B, S], the
+    token after each position: g = [norm_e(e(targets)) ; norm_h(h)] proj,
+    through the module's layers (`layer`: layer_fn's block) and its norm ->
+    (what the head reads to predict the token TWO after each position, the
+    statistics of its layers). Scope `mtp` holds the module's own ops (its
+    three norms, the concatenation, proj); its lookup, its layers and the
+    head pass fall under the regions they always do."""
+    m, eps = params["mtp"], cfg.rmsnorm_eps
+    with jax.named_scope("embed"):
+        e = embed_lookup(params["embed"]["table"], jnp.maximum(targets, 0),
+                         cfg.dtype, where.mesh)
+    with jax.named_scope("mtp"):
+        g = where.pin(jnp.einsum(
+            "bse,ed->bsd",
+            jnp.concatenate([_rmsnorm(e, m["norm_e"]["scale"], eps),
+                             _rmsnorm(h, m["norm_h"]["scale"], eps)], -1),
+            m["proj"].astype(cfg.dtype)))
+    g, per_layer = _walk(layer, g, m["layers"])
+    with jax.named_scope("mtp"):
+        return _rmsnorm(g, m["norm"]["scale"], eps), per_layer
 
 
 def _xent_chunks(x, targets, mask, chunk_rows):
@@ -1609,7 +1922,10 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
     """batch: {"tokens": [B, S+1]} -> (loss, aux): the mean next-token
     cross-entropy, plus under the softmax routing rule the router's two
     losses at the configuration's weights, plus the indexers' KL summed
-    over the layers at index_loss_coef; aux holds the cross-entropy alone
+    over the layers at index_loss_coef, plus, with a prediction module
+    (cfg.mtp), loss_coef times the cross-entropy of the token two ahead
+    through it and the same head (the mean over the positions that have
+    one; "mtp_xent"); aux holds the cross-entropy alone
     ("xent")
     and the router's statistics (the two losses unweighted, the largest
     expert's load over the mean, the share of the slots that fall to the
@@ -1618,11 +1934,26 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
     jax.value_and_grad(..., has_aux=True)."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, router = gpt_backbone(params, inputs, cfg, mesh, act_sharding)
-    total, denom = head_xent(params, x, targets, cfg)
+    where = Setting(mesh, act_sharding)
+    h, per_layer, block = _stack(params, inputs, cfg, where)
+    if cfg.mtp is not None:
+        g, module_stats = _prediction_module(params, h, targets, block, cfg,
+                                             where)
+        per_layer = per_layer + module_stats
+    router = _layer_means(per_layer)
+    total, denom = head_xent(params, final_norm(params, h, cfg), targets, cfg)
     loss = xent = total / jnp.maximum(denom, 1.0)
+    aux = {"xent": xent}
+    if cfg.mtp is not None:
+        # the token two ahead: the targets shifted once more, the last
+        # position (which has none) left out
+        ahead = jnp.concatenate(
+            [targets[:, 1:], jnp.full_like(targets[:, :1], -1)], axis=1)
+        total, denom = head_xent(params, g, ahead, cfg)
+        aux["mtp_xent"] = total / jnp.maximum(denom, 1.0)
+        loss = loss + cfg.mtp.loss_coef * aux["mtp_xent"]
     if "router_balance_loss" in router:
-        loss = (xent
+        loss = (loss
                 + cfg.router_aux_loss_coef * router["router_balance_loss"]
                 + cfg.router_z_loss_coef * router["router_z_loss"])
     if "index_kl" in router:
@@ -1631,7 +1962,7 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
         indexed = sum("index" in layer.get("attn", ())
                       for layer in params["layers"])
         loss = loss + cfg.index_loss_coef * indexed * router["index_kl"]
-    return loss, {"xent": xent, **router}
+    return loss, {**aux, **router}
 
 
 def gpt_loss(params, batch, cfg: GPTConfig, mesh=None, act_sharding=None):

@@ -31,6 +31,8 @@ names of their own, `conv_silu_fwd` (reads x, writes the result) and
 `conv_silu_bwd` (reads x and the cotangent, computes the filtered x again,
 in the block and in the rows after it whose cotangents reach back into it,
 and writes dx and the filter's gradient); `silu_conv_reference` is its jnp.
+The plain form takes a bias a channel (added before the SiLU): one more row
+of the taps' operand, whose gradient the backward kernel sums beside theirs.
 """
 
 from __future__ import annotations
@@ -243,30 +245,36 @@ def short_conv(gate_in, gate_out, value, taps, *,
 # The plain form: silu(filter(x))
 # ---------------------------------------------------------------------------
 
-def silu_conv_reference(x, taps):
-    """x: [batch, seq, channels]; taps: [channels, L]. silu of the filter
-    as L shifted products, zeros before the sequence's start."""
+def silu_conv_reference(x, taps, bias=None):
+    """x: [batch, seq, channels]; taps: [channels, L]; bias: [channels] or
+    None. silu of the filter as L shifted products (plus the bias), zeros
+    before the sequence's start."""
     f32 = jnp.float32
     seq, n = x.shape[1], taps.shape[1]
     padded = jnp.pad(x.astype(f32), ((0, 0), (n - 1, 0), (0, 0)))
     mixed = sum(taps[:, j].astype(f32) * padded[:, j:j + seq]
                 for j in range(n))
+    if bias is not None:
+        mixed = mixed + bias.astype(f32)
     return jax.nn.silu(mixed).astype(x.dtype)
 
 
-def _filtered(u, before, w_ref):
+def _filtered(u, before, w_ref, n: int):
     """(the filter's output on u [rows, cols] with `before` the halo ahead
-    of it, u moved down by each tap's rows)."""
-    n = w_ref.shape[0]
+    of it, u moved down by each tap's rows). w_ref: the n taps' rows and,
+    where the filter has a bias, one more row that holds it."""
     moved = [_shifted(u, before, k, True) for k in range(n)]
-    return sum(w_ref[n - 1 - k:n - k, :] * moved[k] for k in range(n)), moved
+    mixed = sum(w_ref[n - 1 - k:n - k, :] * moved[k] for k in range(n))
+    if w_ref.shape[0] > n:
+        mixed = mixed + w_ref[n:n + 1, :]
+    return mixed, moved
 
 
-def _silu_fwd_kernel(x_ref, x_prev_ref, w_ref, o_ref):
+def _silu_fwd_kernel(x_ref, x_prev_ref, w_ref, o_ref, *, n: int):
     """Grid (batch, seq block, channel block)."""
     f32 = jnp.float32
     before = jnp.where(pl.program_id(1) > 0, x_prev_ref[0].astype(f32), 0.0)
-    mixed, _ = _filtered(x_ref[0].astype(f32), before, w_ref)
+    mixed, _ = _filtered(x_ref[0].astype(f32), before, w_ref, n)
     o_ref[0] = (mixed * jax.nn.sigmoid(mixed)).astype(o_ref.dtype)
 
 
@@ -277,20 +285,20 @@ def _silu_slope(mixed):
 
 
 def _silu_bwd_kernel(x_ref, g_ref, x_prev_ref, x_next_ref, g_next_ref, w_ref,
-                     dx_ref, dw_ref):
+                     dx_ref, dw_ref, *, n: int):
     """Grid (batch, seq block, channel block). dm = g * silu'(m) is shifted
     the other way, so it needs m in the rows after the block: the filter
-    over the halo after it, whose own rows before are the block's last."""
+    over the halo after it, whose own rows before are the block's last. A
+    bias's gradient is dm summed, one more row of dw."""
     f32 = jnp.float32
-    n = w_ref.shape[0]
     i = pl.program_id(1)
     x = x_ref[0].astype(f32)
     halo = x_prev_ref.shape[1]
     before = jnp.where(i > 0, x_prev_ref[0].astype(f32), 0.0)
-    mixed, moved = _filtered(x, before, w_ref)
+    mixed, moved = _filtered(x, before, w_ref, n)
     dm = g_ref[0].astype(f32) * _silu_slope(mixed)
     mixed_after, _ = _filtered(x_next_ref[0].astype(f32),
-                               x[x.shape[0] - halo:], w_ref)
+                               x[x.shape[0] - halo:], w_ref, n)
     after = jnp.where(i < pl.num_programs(1) - 1, g_next_ref[0].astype(f32)
                       * _silu_slope(mixed_after), 0.0)
     dx = jnp.zeros_like(x)
@@ -298,67 +306,75 @@ def _silu_bwd_kernel(x_ref, g_ref, x_prev_ref, x_next_ref, g_next_ref, w_ref,
         dx = dx + w_ref[n - 1 - k:n - k, :] * _shifted(dm, after, k, False)
         dw_ref[0, 0, n - 1 - k:n - k, :] = jnp.sum(dm * moved[k], axis=0,
                                                    keepdims=True)
+    if w_ref.shape[0] > n:
+        dw_ref[0, 0, n:n + 1, :] = jnp.sum(dm, axis=0, keepdims=True)
     dx_ref[0] = dx.astype(dx_ref.dtype)
 
 
 @functools.lru_cache(maxsize=None)
 def _make_silu_conv_fn(blocks: _ConvBlocks, interpret: bool):
     """conv_silu_fwd with conv_silu_bwd as its backward; the residuals are
-    the two inputs."""
+    the inputs. f(x, w): w [channels, L] the taps, or [channels, L + 1] the
+    taps and a bias a channel in the last column (`silu_conv` joins them and
+    parts the gradient: the kernels see one more row of the same operand)."""
     rows, cols, _halo = blocks
 
-    def forward(x, taps):
+    def forward(x, w, n):
         batch, seq, d = x.shape
         main, prev, _nxt = _specs(blocks, seq)
         return pl.pallas_call(
-            _silu_fwd_kernel,
+            functools.partial(_silu_fwd_kernel, n=n),
             grid=(batch, seq // rows, d // cols),
-            in_specs=[main, prev, _taps_spec(taps.shape[1], cols)],
+            in_specs=[main, prev, _taps_spec(w.shape[1], cols)],
             out_specs=main,
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
             compiler_params=_PARAMS,
             interpret=interpret,
             name="conv_silu_fwd",
-        )(x, x, taps.astype(jnp.float32).T)
+        )(x, x, w.astype(jnp.float32).T)
 
-    @jax.custom_vjp
-    def f(x, taps):
-        return forward(x, taps)
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+    def f(x, w, n):
+        return forward(x, w, n)
 
-    def fwd(x, taps):
-        return forward(x, taps), (x, taps)
+    def fwd(x, w, n):
+        return forward(x, w, n), (x, w)
 
-    def bwd(residuals, g):
-        x, taps = residuals
+    def bwd(n, residuals, g):
+        x, w = residuals
         batch, seq, d = x.shape
-        n = taps.shape[1]
+        held = w.shape[1]
         main, prev, nxt = _specs(blocks, seq)
-        dx, d_taps = pl.pallas_call(
-            _silu_bwd_kernel,
+        dx, d_w = pl.pallas_call(
+            functools.partial(_silu_bwd_kernel, n=n),
             grid=(batch, seq // rows, d // cols),
-            in_specs=[main, main, prev, nxt, nxt, _taps_spec(n, cols)],
-            out_specs=[main, pl.BlockSpec((1, 1, n, cols),
+            in_specs=[main, main, prev, nxt, nxt, _taps_spec(held, cols)],
+            out_specs=[main, pl.BlockSpec((1, 1, held, cols),
                                           lambda b, i, j: (b, i, 0, j))],
             out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                       jax.ShapeDtypeStruct((batch, seq // rows, n, d),
+                       jax.ShapeDtypeStruct((batch, seq // rows, held, d),
                                             jnp.float32)],
             compiler_params=_PARAMS,
             interpret=interpret,
             name="conv_silu_bwd",
-        )(x, g, x, x, g, taps.astype(jnp.float32).T)
-        return dx, jnp.sum(d_taps, axis=(0, 1)).T.astype(taps.dtype)
+        )(x, g, x, x, g, w.astype(jnp.float32).T)
+        return dx, jnp.sum(d_w, axis=(0, 1)).T.astype(w.dtype)
 
     f.defvjp(fwd, bwd)
     return f
 
 
-def silu_conv(x, taps, *, interpret: Optional[bool] = None):
-    """silu(filter(x)): the plain form of the module's docstring. x:
-    [batch, seq, channels]; taps: [channels, L]."""
+def silu_conv(x, taps, bias=None, *, interpret: Optional[bool] = None):
+    """silu(filter(x) + bias): the plain form of the module's docstring. x:
+    [batch, seq, channels]; taps: [channels, L]; bias: [channels] or None
+    (the forward adds it before the SiLU; its gradient is summed in the
+    kernel that sums the filter's)."""
     _, seq, channels = x.shape
     blocks = _conv_blocks(seq, channels, taps.shape[1], x.dtype.itemsize)
     if blocks is None:
-        return silu_conv_reference(x, taps)
+        return silu_conv_reference(x, taps, bias)
     if interpret is None:
         interpret = attention._default_interpret()
-    return _make_silu_conv_fn(blocks, interpret)(x, taps)
+    w = taps if bias is None else jnp.concatenate(
+        [taps, bias.astype(taps.dtype)[:, None]], axis=1)
+    return _make_silu_conv_fn(blocks, interpret)(x, w, taps.shape[1])

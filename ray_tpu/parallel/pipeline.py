@@ -133,6 +133,12 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
     else:
         strategy = ShardingStrategy.pp()
         where = Setting()
+    if cfg.ssm is not None or cfg.mtp is not None:
+        raise ValueError(
+            "the pipeline preset carries the residual stream alone from "
+            "stage to stage: no state-space layer's state along a sequence "
+            "cut into microbatches has been shown right, and no prediction "
+            "module's second stream and loss reach the last rank")
     _refuse_what_a_stage_cannot_run(cfg, mesh, strategy)
     # (what those observations let through and nothing has shown right: a
     # stack of window layers alone, a gate a head on one shard of 'tensor')

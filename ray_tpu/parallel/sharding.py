@@ -132,6 +132,19 @@ _TRANSFORMER = _STAGE_ROWS + (
     (r"kda/(a_log|dt_bias)", ("heads",)),
     (r"kda/o_norm", ()),
     (r"kda/wo", _ROW),
+    # a state-space layer: whole heads of the gate's and the step's columns
+    # and of w_out's rows, with their rates, step biases, skips and columns
+    # of the gated norm's scale; what the filter reads holds heads' channels
+    # and then groups' directions side by side, so it, its taps and their
+    # bias are not cut over 'tensor'
+    (r"ssm/(w_z|w_dt)", _COLUMN),
+    (r"ssm/w_xbc", ("model", None)),
+    (r"ssm/(conv|conv_bias)", ()),
+    (r"ssm/(a_log|dt_bias|d)$", ("heads",)),
+    (r"ssm/norm", ("heads",)),
+    (r"ssm/w_out", _ROW),
+    # a prediction module's projection [2 d, d]: rows like any d_model side
+    (r"mtp/proj", ("model", None)),
     # Vocab over both axes under tp_fsdp, d_model replicated: a d-sharded
     # gather output cannot transition to batch-sharded activations without
     # an involuntary full rematerialization (permuted tile order), while a
@@ -145,6 +158,9 @@ _TRANSFORMER = _STAGE_ROWS + (
     (r"moe/shared/(w_up|w_gate)", _COLUMN),
     (r"moe/shared/w_down", _ROW),
     (r"moe/router_bias", ()),
+    # the latent projections either side of the routed experts: dense
+    (r"moe/w_latent_in", ("model", None)),
+    (r"moe/w_latent_out", (None, "model")),
     (r"moe/.*w_(gate|up)", ("expert",) + _COLUMN),
     (r"moe/.*w_down", ("expert",) + _ROW),
     (r"moe/router", ()),

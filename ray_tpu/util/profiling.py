@@ -124,8 +124,9 @@ def stack_dump() -> Dict[str, str]:
 # where it is opened.
 REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_window",
            "attn_index", "attn_out", "attn_gate", "conv", "conv_mix", "kda",
-           "kda_core", "mlp", "route_ahead",
-           "moe", "moe_route", "moe_shared", "norm", "head", "loss_and_grad",
+           "kda_core", "ssm", "ssm_core", "mlp", "route_ahead",
+           "moe", "moe_route", "moe_shared", "moe_latent", "mtp", "norm",
+           "head", "loss_and_grad",
            "grad_accum", "optimizer")
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
            "flash_win_bwd_dq", "flash_win_bwd_dkv", "flash_sel_fwd",

@@ -1,0 +1,680 @@
+"""The state-space scan (ops/state_space.py), the plain filter's bias
+(ops/short_conv.py), and a stack whose layers are ONE half each (a Mamba-2
+mixer, latent relu^2 experts, grouped-query attention without rotation) with
+a multi-token prediction module (models/gpt.py) against the plain float32
+reference of benchmark/families/nemotron_h.py, at a small size on the CPU:
+seeded random weights, the kernels in interpret mode."""
+
+import copy
+import json
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+CELL = "nemotron-3-super-120b-a12b"
+
+
+def _read(*parts):
+    with open(os.path.join(ROOT, *parts)) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """benchmark/rehearsal/configs/tiny-nemotron-h.json: the pattern MEM*E
+    and a prediction module *E; Mamba heads 4..7 of 8 at 32 with group 1 of
+    2, state 64; query heads 2 on 1 of 4 on 2 at 32; experts 4..7 of 16
+    held, 4 a token, width 96 in a latent 64, beside a shared one of 192."""
+    return _read("benchmark", "rehearsal", "configs", "tiny-nemotron-h.json")
+
+
+def _worst(jax, got, want):
+    import jax.numpy as jnp
+    return max(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda a, b: float(jnp.max(jnp.abs(a - b))), got, want)))
+
+
+# ---------------------------------------------------------------------------
+# (a) the scan: chunked against a token a step
+# ---------------------------------------------------------------------------
+
+def _scan_inputs(jax, seq, groups, step, seed=0):
+    """x [2, seq, 4, 8], steps of about `step`, rates over 1..16."""
+    import jax.numpy as jnp
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(k[0], (2, seq, 4, 8))
+    dt = step * jax.nn.softplus(jax.random.normal(k[1], (2, seq, 4)))
+    a_log = jnp.log(jax.random.uniform(k[2], (4,), minval=1.0, maxval=16.0))
+    b = jax.random.normal(k[3], (2, seq, groups, 16))
+    c = jax.random.normal(k[4], (2, seq, groups, 16))
+    return x, dt, a_log, b, c, 1.0 + 0.1 * jax.random.normal(k[5], (4,))
+
+
+@pytest.mark.parametrize("seq,chunk,groups,step", [
+    (128, 32, 2, 0.05),       # whole chunks
+    (100, 32, 1, 0.05),       # a ragged tail, one group for every head
+    (70, 16, 2, 1e-3),        # another chunk size, decays near 1
+    (96, 32, 4, 3.0),         # a chunk's decay underflows float32
+], ids=["whole", "ragged", "chunk16_slow", "underflow"])
+def test_chunked_scan_matches_the_recurrence(jax_cpu, seq, chunk, groups,
+                                             step):
+    """Values and all six gradients. No chunk divides by a decay: where a
+    chunk's cumulative log-decay passes float32's range the chunked form
+    still has the recurrence's numbers."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.state_space import chunk_log_decay, ssd, ssd_reference
+    args = _scan_inputs(jax, seq, groups, step)
+    assert chunk_log_decay(args[1], args[2], chunk).shape == (
+        2, -(-seq // chunk), 4, chunk)
+    if step == 3.0:
+        assert float(chunk_log_decay(args[1], args[2], chunk).min()) < -200.0
+    weight = jnp.cos(0.37 * jnp.arange(seq * 32).reshape(seq, 4, 8))
+
+    def run(fn):
+        def scalar(*a):
+            out = fn(*a)
+            return jnp.sum(out * weight), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=tuple(range(6)), has_aux=True))(*args)
+        return out, grads
+    out, grads = run(lambda *a: ssd(*a, chunk=chunk))
+    want, want_grads = run(ssd_reference)
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(out - want))) < 2e-5 * max(scale, 1.0)
+    for g, w in zip(grads, want_grads):
+        assert np.isfinite(np.asarray(g)).all()
+        top = max(float(jnp.max(jnp.abs(w))), 1.0)
+        assert float(jnp.max(jnp.abs(g - w))) < 2e-4 * top
+
+
+def test_scan_keeps_the_inputs_type_and_names_what_remat_keeps(jax_cpu):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.state_space import SSD_OUT, ssd
+    x, *rest = _scan_inputs(jax, 64, 2, 0.05)
+    assert jax.eval_shape(lambda *a: ssd(*a, chunk=32),
+                          x.astype(jnp.bfloat16), *rest).dtype == jnp.bfloat16
+    text = str(jax.make_jaxpr(lambda *a: ssd(*a, chunk=32))(x, *rest))
+    assert text.count(f"name[name={SSD_OUT}]") == 2     # y, the chunk states
+
+
+# ---------------------------------------------------------------------------
+# (b) the plain filter with a bias a channel
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype,shape,kernels", [
+    ("float32", (2, 64, 256), True),
+    ("bfloat16", (1, 128, 128), True),
+    ("float32", (2, 40, 96), False),     # does not tile: the jnp form
+], ids=["f32_kernels", "bf16_kernels", "jnp"])
+def test_plain_filter_with_a_bias_matches_jnp(jax_cpu, dtype, shape, kernels):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import short_conv
+    k = jax.random.split(jax.random.PRNGKey(1), 4)
+    x = jax.random.normal(k[0], shape).astype(dtype)
+    taps = 0.5 * jax.random.normal(k[1], (shape[2], 4))
+    bias = 0.3 * jax.random.normal(k[2], (shape[2],))
+    weight = jax.random.normal(k[3], shape)
+    blocks = short_conv._conv_blocks(shape[1], shape[2], 4,
+                                     jnp.dtype(dtype).itemsize)
+    assert (blocks is not None) == kernels
+
+    def run(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weight),
+            argnums=(0, 1, 2)))(x, taps, bias)
+    got, grads = run(lambda *a: short_conv.silu_conv(*a, interpret=True))
+    want, want_grads = run(short_conv.silu_conv_reference)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    assert abs(float(got - want)) < tol * max(abs(float(want)), 1.0) * 10
+    for g, w in zip(grads, want_grads):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32),
+            atol=tol * float(jnp.max(jnp.abs(w.astype(jnp.float32)))),
+            rtol=tol)
+    # a bias of zeros is the filter without one
+    np.testing.assert_array_equal(
+        np.asarray(short_conv.silu_conv(x, taps, jnp.zeros_like(bias),
+                                        interpret=True), np.float32),
+        np.asarray(short_conv.silu_conv(x, taps, interpret=True), np.float32))
+
+
+# ---------------------------------------------------------------------------
+# (c) the program against the family's reference
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def small(tiny):
+    """The tiny configuration at the pattern ME under its module *E: all
+    three kinds of layer and the module, in four layers."""
+    return dict(tiny, num_hidden_layers=2, hybrid_override_pattern="ME")
+
+
+@pytest.fixture(scope="module")
+def seeded(jax_cpu, small):
+    """(params, tokens [2, 129]) of the small configuration."""
+    jax = jax_cpu
+    from benchmark.families import nemotron_h
+    tiny = small
+    params = nemotron_h.program(tiny).init(jax.random.PRNGKey(3))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 129), 0,
+                                tiny["vocab_size"])
+    return params, tokens
+
+
+@pytest.mark.parametrize("attention", ["reference", "flash"])
+def test_logits_loss_and_gradients_match_the_reference(jax_cpu, small,
+                                                       seeded, attention):
+    """float32 on both sides: every logit of both heads' passes to 5e-5,
+    the loss (both cross-entropies) and the whole tree of gradients."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import nemotron_h as family
+    from ray_tpu.models.gpt import (GPTConfig, gpt_forward, gpt_forward_both,
+                                    gpt_loss, gpt_loss_and_aux)
+    params, tokens = seeded
+    tiny = small
+    cfg = GPTConfig(**family.gpt_config_kwargs(tiny), attention=attention,
+                    remat_policy="full", dtype=jnp.float32)
+    batch = {"tokens": tokens}
+
+    @jax.jit
+    def program(params):
+        (loss, aux), grads = jax.value_and_grad(
+            lambda p: gpt_loss_and_aux(p, batch, cfg), has_aux=True)(params)
+        return (gpt_forward_both(params, tokens, cfg),
+                gpt_forward(params, tokens[:, :-1], cfg)[0],
+                gpt_loss(params, batch, cfg), loss, aux, grads)
+
+    @jax.jit
+    def reference(params):
+        loss, grads = jax.value_and_grad(
+            lambda p: family.reference_loss(p, tokens, tiny))(params)
+        return (family.reference_logits(params, tokens[:, :-1], tiny),
+                family.reference_both_logprobs(params, tokens, tiny),
+                family.reference_logprobs(params, tokens[:, :-1], tiny),
+                loss, grads)
+
+    def logprob(z, t):
+        return jnp.take_along_axis(jax.nn.log_softmax(z), t[..., None],
+                                   -1)[..., 0]
+    with jax.default_matmul_precision("highest"):
+        (logits, ahead), again, alone, loss, aux, grads = program(params)
+        want_logits, (first, second), plain, want_loss, want = reference(
+            params)
+    assert float(jnp.max(jnp.abs(logits - want_logits))) < 5e-5
+    assert float(jnp.max(jnp.abs(again - want_logits))) < 5e-5
+    assert float(jnp.max(jnp.abs(
+        logprob(logits, tokens[:, 1:]) - first))) < 5e-5
+    assert float(jnp.max(jnp.abs(
+        logprob(ahead[:, :-1], tokens[:, 2:]) - second))) < 5e-5
+    np.testing.assert_allclose(plain, first[:, :-1], atol=1e-6)
+    assert float(loss) == float(alone) == pytest.approx(
+        float(aux["xent"]) + 0.3 * float(aux["mtp_xent"]), abs=1e-6)
+    assert float(loss) == pytest.approx(float(want_loss), abs=2e-5)
+    assert 0.3 < float(aux["expert_hidden_zero_share"]) < 0.7
+    assert float(aux["expert_rows_bounded"]) == 1.0
+    assert float(aux["ssm_log_decay_min"]) < 0 < float(aux["ssm_dt_mean"])
+    assert _worst(jax, grads, want) < 2e-6
+    moved = {jax.tree_util.keystr(path) for path, g
+             in jax.tree_util.tree_flatten_with_path(grads)[0]
+             if float(jnp.max(jnp.abs(g))) > 0}
+    still = {jax.tree_util.keystr(path) for path, _
+             in jax.tree_util.tree_flatten_with_path(grads)[0]} - moved
+    assert all("router_bias" in path for path in still), still
+
+
+def _gap(jax, params, tokens, sound, faulty):
+    """(median, root mean square) of |the faulty reference's per-token
+    log-probabilities less the sound one's| over both heads' passes."""
+    import jax.numpy as jnp
+    from benchmark.families import nemotron_h as family
+    with jax.default_matmul_precision("highest"):
+        a = jax.jit(lambda p, t: family.reference_both_logprobs(p, t, sound)
+                    )(params, tokens)
+        b = jax.jit(lambda p, t: family.reference_both_logprobs(
+            p, t, faulty[2]))(*faulty[:2])
+    gap = jnp.concatenate([(x - y).reshape(-1) for x, y in zip(a, b)])
+    return float(jnp.median(jnp.abs(gap))), float(jnp.sqrt(jnp.mean(gap ** 2)))
+
+
+@pytest.mark.parametrize("fault", ["no_decay", "dt_raw", "no_skip",
+                                   "gate_after_norm", "relu", "unscaled",
+                                   "fp8"])
+def test_the_reference_tells_each_mechanism_apart(jax_cpu, small, seeded,
+                                                  fault):
+    """Each of the chip controls' faults, in the reference alone, moves the
+    per-token log-probabilities far past what float32 leaves between
+    program and reference (5e-5 a logit)."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    params, tokens = seeded
+    tiny = small
+    if fault == "fp8":
+        low = jax.tree_util.tree_map(
+            lambda w: w.astype(jnp.float8_e4m3fn).astype(jnp.float32)
+            if w.ndim >= 2 else w, params)
+        median, rms = _gap(jax, params, tokens, tiny, (low, tokens, tiny))
+    else:
+        median, rms = _gap(jax, params, tokens, tiny,
+                           (params, tokens, dict(tiny, fault=fault)))
+    # (a step that is not through its softplus is negative for most tokens:
+    # the state grows without bound and the reading is nan, outside)
+    assert not (median <= 2e-3 or rms <= 5e-3), (median, rms)
+
+
+def test_the_modules_term_is_part_of_the_loss(jax_cpu, small, seeded):
+    jax = jax_cpu
+    from benchmark.families import nemotron_h as family
+    params, tokens = seeded
+    tiny = small
+    with jax.default_matmul_precision("highest"):
+        whole, without = (float(jax.jit(
+            lambda p, t: family.reference_loss(p, t, c))(params, tokens))
+            for c in (tiny, dict(tiny, fault="no_mtp")))
+    assert whole - without > 0.2 * whole / 1.3
+    with pytest.raises(ValueError, match="fault"):
+        family.reference_loss(params, tokens, dict(tiny, fault="typo"))
+
+
+def test_bfloat16_step_passes_the_per_token_check(jax_cpu, small, seeded):
+    """reference_loss with a `program_check`: the bf16 program's own forward
+    (both heads' passes) within the bounds gives the number, outside them
+    nan."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import nemotron_h as family
+    params, tokens = seeded
+    tiny = small
+
+    @jax.jit
+    def checked(params, tokens, slack):
+        first, second = family.reference_both_logprobs(params, tokens, tiny)
+        gap = family.program_logprob_gap(params, tokens, tiny, first, second)
+        check = dict(zip(("logprob_median_tol", "logprob_rms_tol",
+                          "logprob_p99_tol"), (g * w for g, w
+                                               in zip(gap, slack))))
+        return gap, family.reference_loss(
+            params, tokens, dict(tiny, program_check=check))
+    with jax.default_matmul_precision("highest"):
+        (median, rms, tail), loose = checked(params, tokens,
+                                             jnp.array([2.0, 2.0, 2.0]))
+        _, tight = checked(params, tokens, jnp.array([2.0, 0.5, 2.0]))
+    # (a near-tied expert that swaps under bf16 moves a few tokens much:
+    # the root mean square may pass the 99th percentile)
+    assert 0 < float(median) < 0.02 and 0 < float(tail) < 0.2
+    assert float(median) < float(rms) < 0.2
+    assert np.isfinite(float(loose)) and np.isnan(float(tight))
+
+
+# ---------------------------------------------------------------------------
+# (d) the share ties to the model
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind", ["ssm", "attention", "experts"])
+def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(jax_cpu, tiny,
+                                                             kind):
+    """model-configs guide, section 4: a whole layer of 8 Mamba heads in 2
+    groups, 4 query heads on 2 key/value heads, 16 experts, over tensor
+    parallel 2 (4 Mamba heads with their group, 2 query heads on their
+    key/value head) or expert parallel 4 (4 experts each). A head share's
+    mixer output is its heads' rows of the output projection's sum and the
+    gated norm is a group's own, so the two head shares add up to the uncut
+    mixer; the four expert shares' routed sums, through the latent
+    projection that every chip holds whole, add up with the shared expert
+    counted once to the uncut reference's layer."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import nemotron_h as family
+    from ray_tpu.models import gpt
+    from ray_tpu.models.gpt import GPTConfig, Setting, gpt_init
+    letter = {"ssm": "M", "attention": "*", "experts": "E"}[kind]
+    whole = copy.deepcopy(tiny)
+    del whole["share"]
+    whole.update(n_routed_experts=16, num_attention_heads=4,
+                 num_key_value_heads=2, mamba_num_heads=8, n_groups=2,
+                 num_hidden_layers=1, hybrid_override_pattern=letter,
+                 num_nextn_predict_layers=0)
+
+    def config(c):
+        return GPTConfig(**family.gpt_config_kwargs(c), dtype=jnp.float32,
+                         attention="reference", remat_policy="none")
+    full = config(whole)
+    assert full.experts_held is None and full.mtp is None
+    layer = gpt_init(jax.random.PRNGKey(7), full)["layers"][0]
+    group = {"ssm": "ssm", "attention": "attn", "experts": "moe"}[kind]
+    assert sorted(layer) == sorted([group, "ln1"])       # ONE norm, one half
+    if kind == "ssm":
+        # seeds that tell the heads and the columns apart
+        layer["ssm"]["conv_bias"] = 0.2 * jax.random.normal(
+            jax.random.PRNGKey(5), (512,))
+        layer["ssm"]["norm"]["scale"] = 1.0 + 0.2 * jax.random.normal(
+            jax.random.PRNGKey(6), (256,))
+    if kind == "experts":
+        layer["moe"]["router"] = 0.3 * jax.random.normal(
+            jax.random.PRNGKey(8), (128, 16))
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 64, 128), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.vmap(
+            lambda h: family.reference_layer(layer, h, whole)))(x)
+
+    def columns(leaf, rank, parts=2, axis=-1):
+        return jnp.split(leaf, parts, axis=axis)[rank]
+
+    def ssm_share(rank):
+        m = layer["ssm"]
+        # [x of 8 heads | B of 2 groups | C of 2 groups]: a rank's parts
+        def xbc(leaf, axis):
+            x_, b_, c_ = jnp.split(leaf, [256, 384], axis=axis)
+            return jnp.concatenate([columns(t, rank, axis=axis)
+                                    for t in (x_, b_, c_)], axis=axis)
+        return {"w_z": columns(m["w_z"], rank), "w_xbc": xbc(m["w_xbc"], 1),
+                "w_dt": columns(m["w_dt"], rank), "conv": xbc(m["conv"], 0),
+                "conv_bias": xbc(m["conv_bias"], 0),
+                "a_log": columns(m["a_log"], rank),
+                "dt_bias": columns(m["dt_bias"], rank),
+                "d": columns(m["d"], rank),
+                "norm": {"scale": columns(m["norm"]["scale"], rank)},
+                "w_out": columns(m["w_out"], rank, axis=0)}
+
+    def attention_share(rank):
+        a = layer["attn"]
+        return {"wq": columns(a["wq"], rank), "wk": columns(a["wk"], rank),
+                "wv": columns(a["wv"], rank),
+                "wo": columns(a["wo"], rank, axis=0)}
+
+    def experts_share(rank):
+        m = layer["moe"]
+        return dict(m, w_up=columns(m["w_up"], rank, 4, 0),
+                    w_down=columns(m["w_down"], rank, 4, 0))
+
+    with jax.default_matmul_precision("highest"):
+        if kind == "experts":
+            shared = jax.vmap(lambda h: family._relu2_mlp(
+                layer["moe"]["shared"],
+                family._norm(h, layer["ln1"]["scale"], 1e-5), whole))(x)
+            total = x + shared
+            for rank in range(4):
+                held = dict(whole, n_routed_experts=4, share={
+                    "rank": rank, "n_routed_experts": 16})
+                cfg = config(held)
+                assert cfg.experts_held == (4 * rank, 4)
+                part = {"ln1": layer["ln1"], "moe": experts_share(rank)}
+                y, stats = jax.jit(gpt.layer_fn(cfg, 64, Setting()))(x, part)
+                np.testing.assert_allclose(
+                    y, jax.jit(jax.vmap(lambda h: family.reference_layer(
+                        part, h, held)))(x), atol=2e-5)
+                total = total + (y - x - shared)
+        else:
+            held = dict(whole, num_attention_heads=2, num_key_value_heads=1,
+                        mamba_num_heads=4, n_groups=1,
+                        share=dict(whole, rank=0))
+            cfg = config(held)
+            share_of = ssm_share if kind == "ssm" else attention_share
+            total = x
+            for rank in range(2):
+                part = {"ln1": layer["ln1"], group: share_of(rank)}
+                y, _ = jax.jit(gpt.layer_fn(cfg, 64, Setting()))(x, part)
+                np.testing.assert_allclose(
+                    y, jax.jit(jax.vmap(lambda h: family.reference_layer(
+                        part, h, held)))(x), atol=2e-5)
+                total = total + (y - x)
+    np.testing.assert_allclose(total, want, atol=5e-5)
+
+
+def test_param_count_is_the_cut_and_the_programs_tree(jax_cpu, tiny):
+    jax = jax_cpu
+    from benchmark.families import nemotron_h as family
+    from ray_tpu.models.gpt import count_params
+    for config in (tiny, _read("benchmark", "configs", CELL + ".json")):
+        tree = jax.eval_shape(
+            lambda: family.program(config).init(jax.random.PRNGKey(0)))
+        assert count_params(tree) == family.param_count(config)
+    assert family.param_count(config) == 838_249_968      # 838M +- 1 %
+    assert abs(family.param_count(config) - 838.2e6) < 0.01 * 838.2e6
+    # the "A12B" of the name: at the published sizes, every layer and the
+    # module, 22 experts a token
+    whole = {**config, **config["published"], "share": None}
+    assert 120e9 < family.param_count(whole) < 125e9
+    assert 12e9 < family.active_param_count(whole) < 13.5e9
+
+
+def test_flops_and_kernel_arithmetic_count_what_is_computed_here():
+    from benchmark.families import nemotron_h as family
+    from benchmark.kernels import gqa_attention, kda, ssd
+    cell = _read("benchmark", "configs", CELL + ".json")
+    mix = _read("benchmark", "traffic", "train_b1_s8192_dp.json")
+    forward = family.train_flops_per_token(cell, 8192) / 3.0
+    assert forward == pytest.approx(1.19e9, rel=0.01)
+    assert family.forward_flops_per_token(cell, 8192) == forward
+    assert family.attention_call(cell, mix) == {
+        "batch": 1, "heads": 4, "kv_heads": 1, "seq": 8192, "head_dim": 128}
+    assert family.ssd_call(cell, mix) == {
+        "batch": 1, "seq": 8192, "heads": 16, "head_dim": 64, "groups": 1,
+        "state": 128, "chunk": 128}
+    # the ONE filter call of a layer: [1, 8192, 1280], 4 taps
+    flops, moved = kda.conv_silu_fwd(cell, mix)
+    assert moved == 2 * 8192 * 1280 * 2 and flops == 11 * 8192 * 1280
+    assert kda.conv_silu_bwd(cell, mix)[1] == 3 * 8192 * 1280 * 2
+    assert gqa_attention.flash_fwd(cell, mix)[0] > 0
+    # the scan, a token and head: a brute-force count of the chunked form
+    chunk, width, state, per_group = 128, 64, 128, 16
+    intra = sum(2 * state / per_group + 2 * width for t in range(chunk)
+                for s in range(t + 1)) / chunk
+    carried = 2 * width * state + 2 * width * state
+    assert ssd.ssd_flops_per_token(chunk, width, state, per_group) \
+        == pytest.approx(intra + carried, rel=0.01)
+    flops, moved = ssd.ssd(cell, mix)
+    assert flops == 8192 * 16 * ssd.ssd_flops_per_token(128, 64, 128, 16)
+    assert flops / 197e12 < moved / 819e9            # bound by bytes
+
+
+# ---------------------------------------------------------------------------
+# (e) sharding, refusals, scopes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("strategy,column,row", [
+    ("tp", (None, "tensor"), ("tensor", None)),
+    ("tp_fsdp", ("fsdp", "tensor"), ("tensor", "fsdp"))])
+def test_every_new_leaf_gets_its_rule(jax_cpu, tiny, strategy, column, row):
+    jax = jax_cpu
+    from jax.sharding import PartitionSpec as P
+    from benchmark.families import nemotron_h as family
+    from ray_tpu.models.gpt import GPTConfig, gpt_init
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+    cfg = GPTConfig(**family.gpt_config_kwargs(tiny))
+    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+    mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2),
+                      devices=jax.devices()[:4])
+    specs = jax.tree_util.tree_map(
+        lambda s: s.spec,
+        strategy_from_name(strategy).param_shardings(mesh, params))
+    ssm, moe = specs["layers"][0]["ssm"], specs["layers"][1]["moe"]
+    assert ssm["w_z"] == ssm["w_dt"] == P(*column)
+    assert ssm["w_out"] == specs["layers"][3]["attn"]["wo"] == P(*row)
+    assert ssm["w_xbc"] == P(column[0], None)
+    assert ssm["conv"] == P(None, None) and ssm["conv_bias"] == P(None)
+    assert ssm["a_log"] == ssm["dt_bias"] == ssm["d"] == P("tensor")
+    assert ssm["norm"]["scale"] == P("tensor")
+    assert moe["w_latent_in"] == P(column[0], None)
+    assert moe["w_latent_out"] == P(None, column[0])
+    assert moe["shared"]["w_up"] == P(*column)
+    assert "w_gate" not in moe and "w_gate" not in moe["shared"]
+    module = specs["mtp"]
+    assert module["proj"] == P(column[0], None)
+    assert module["layers"][0]["attn"]["wq"] == P(*column)
+    assert module["layers"][1]["moe"]["w_latent_in"] == P(column[0], None)
+
+
+def test_sharded_step_equals_one_device(jax_cpu, tiny):
+    """One step of a state-space layer and the module (attention, latent
+    experts, its projection and second loss) on tensor=2 equals
+    the one-device step: the `ssm/*`, `moe/w_latent_*` and `mtp/*` rows of
+    parallel/sharding.py's table (a CPU mesh: no chip claim)."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    import optax
+    from benchmark.families import nemotron_h as family
+    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+    from ray_tpu.train.train_step import init_train_state, make_train_step
+    cfg = GPTConfig(**family.gpt_config_kwargs(dict(
+        tiny, num_hidden_layers=1, hybrid_override_pattern="M",
+        num_attention_heads=4, num_key_value_heads=2)), dtype=jnp.float32,
+        attention="flash")
+    tokens = jnp.asarray(np.random.default_rng(5).integers(
+        0, 512, (2, 129), dtype=np.int32))
+
+    def one_step(name, axes, n):
+        mesh = build_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
+        strategy = strategy_from_name(name)
+        optimizer = optax.sgd(0.1)
+        state = init_train_state(
+            lambda: gpt_init(jax.random.PRNGKey(3), cfg), optimizer, mesh,
+            strategy)
+        step = make_train_step(
+            lambda p, b: gpt_loss(
+                p, b, cfg, mesh=mesh,
+                act_sharding=strategy.activation_sharding(mesh)),
+            optimizer, mesh, strategy, sample_params=state.params)
+        with jax.default_matmul_precision("highest"):
+            state, metrics = step(state, {"tokens": tokens})
+        return float(metrics["loss"]), jax.device_get(state.params)
+
+    ref_loss, ref_params = one_step("dp", {"data": 1}, 1)
+    loss, params = one_step("tp", {"data": 1, "tensor": 2}, 2)
+    assert abs(loss - ref_loss) < 1e-5
+    for (path, p), r in zip(jax.tree_util.tree_flatten_with_path(params)[0],
+                            jax.tree_util.tree_leaves(ref_params)):
+        np.testing.assert_allclose(p, r, rtol=1e-4, atol=2e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("change,says", [
+    ({"attention": "ring"}, "'ssm' layer's state.*attention='ring'"),
+    ({"ssm": None}, "'ssm' layers need their sizes"),
+    ({"route_from": "input"}, "an 'ff' layer has none"),
+    ({"layer_kinds": ("ssm", "mamba", "ff", "ff", "ff")}, "'attention_alone'"),
+    ({"expert_form": "two"}, None),
+], ids=["ring", "no_sizes", "route_ahead", "kinds_names", "form"])
+def test_the_configuration_refuses_by_name(tiny, change, says):
+    from benchmark.families import nemotron_h as family
+    from ray_tpu.models.gpt import ExpertForm, GPTConfig
+    if says is None:
+        change, says = {"expert_form": ExpertForm(matrices=4)}, "matrices 2"
+    with pytest.raises(ValueError, match=says):
+        GPTConfig(**dict(family.gpt_config_kwargs(tiny), **change))
+
+
+def test_pipeline_refuses_a_state_and_a_second_stream(jax_cpu, tiny):
+    jax = jax_cpu
+    from benchmark.families import nemotron_h as family
+    from ray_tpu.models.gpt import GPTConfig
+    from ray_tpu.parallel import pipeline
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    mesh = build_mesh(MeshConfig(data=1, pipeline=2),
+                      devices=jax.devices()[:2])
+    kwargs = family.gpt_config_kwargs(
+        dict(tiny, num_hidden_layers=2, hybrid_override_pattern="MM",
+             num_nextn_predict_layers=0))
+    with pytest.raises(ValueError, match="state-space layer's state"):
+        pipeline.make_gpt_pp_loss(GPTConfig(**kwargs), mesh, 2)
+
+
+def test_the_existing_configurations_have_none_of_it():
+    """The sub-records are None and the seeded weights what they were for a
+    configuration that asks for nothing new."""
+    from ray_tpu.models.gpt import ExpertForm, GPTConfig
+    cfg = GPTConfig.tiny()
+    assert cfg.ssm is None and cfg.expert_form is None and cfg.mtp is None
+    assert cfg.feed_forward == ExpertForm(matrices=3, activation="silu")
+    relu = GPTConfig(gate_activation="relu")
+    assert relu.feed_forward.activation == "relu"
+
+
+def test_the_new_scopes_are_regions_and_reach_the_compiled_step(jax_cpu,
+                                                                tiny):
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from benchmark.families import nemotron_h as family
+    from ray_tpu.models.gpt import gpt_init, gpt_loss
+    from ray_tpu.util import profiling
+    assert {"ssm", "ssm_core", "moe_latent", "mtp"} <= set(profiling.REGIONS)
+    cfg = family._train_config(dict(tiny, num_hidden_layers=2,
+                                    hybrid_override_pattern="ME"))
+    params = gpt_init(jax.random.PRNGKey(0), cfg)
+    text = jax.jit(jax.grad(lambda p, t: gpt_loss(p, {"tokens": t}, cfg))
+                   ).lower(params, jnp.zeros((2, 129), jnp.int32)
+                           ).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    regions = {profiling._last_of(n, profiling.REGIONS) for n in names}
+    assert {"ssm", "ssm_core", "moe_latent", "mtp", "moe", "moe_route",
+            "moe_shared", "attn_proj", "attn_core", "attn_out", "head",
+            "embed"} <= regions
+    for n in names:
+        if "conv_silu" in n:
+            assert profiling._last_of(n, profiling.REGIONS) == "ssm"
+    # the scan over the chunk states is ssm_core's, forward and transposed,
+    # and it is not run a second time under the remat
+    scans = [n for n in names if "/ssm_core/" in n and "while" in n]
+    assert any("transpose(" not in n for n in scans)
+    assert any("transpose(" in n for n in scans)
+    assert not any("rematted_computation" in n and "transpose(" not in n
+                   for n in scans)
+
+
+def test_configuration_file_keeps_the_catalog_and_states_the_cut():
+    cell = _read("benchmark", "configs", CELL + ".json")
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(catalog):
+        pytest.skip("no catalog here")
+    with open(catalog) as f:
+        row = next(r for r in map(json.loads, f)
+                   if r["source_url"] == cell["source"])
+    changed = {k for k, v in row["config"].items() if cell.get(k, "?") != v}
+    assert changed == set(cell["reduced"]) == {
+        "num_hidden_layers", "hybrid_override_pattern", "n_routed_experts",
+        "vocab_size", "num_attention_heads", "num_key_value_heads",
+        "mamba_num_heads", "n_groups"}
+    assert cell["published"] == {k: row["config"][k] for k in cell["reduced"]}
+    # published layers 26..36: one whole period in the published 5 : 5 : 1
+    pattern = row["config"]["hybrid_override_pattern"]
+    assert cell["hybrid_override_pattern"] == pattern[26:37] == "EMEMEMEMEM*"
+    assert [pattern.count(c) for c in "ME*"] == [40, 40, 8]
+    share = cell["share"]
+    assert share["expert_parallel"] == share["chips_per_layer"] == 64
+    assert share["expert_parallel"] * cell["n_routed_experts"] \
+        == share["n_routed_experts"] == 512
+    for key in ("vocab_size", "num_attention_heads", "mamba_num_heads",
+                "n_groups"):
+        assert share["tensor_parallel"] * cell[key] == share[key] \
+            == row["config"][key], key
+    # a key/value head is repeated over four chips of the group
+    assert cell["num_key_value_heads"] == 1 \
+        and share["num_key_value_heads"] == row["config"][
+            "num_key_value_heads"] == 2
+    assert cell["expand"] * cell["hidden_size"] \
+        == share["mamba_num_heads"] * cell["mamba_head_dim"]
+    assert {"no_rotation", "latent_experts", "mtp", "ssm_init",
+            "sequence_length"} <= set(cell["assumed"])
+    for key in ("source", "share", "reduced", "published", "reduced_why",
+                "distorts", "assumed", "departures", "deployment", "train",
+                "program_check"):
+        assert key in cell or key in cell["reduced_why"], key
+    bench = _read("BENCHMARK.json")
+    entry = next(c for c in bench["configs"] if c["name"] == cell["name"])
+    assert entry["reduced"] == cell["reduced"]
+    assert entry["source"] == cell["source"]
+    peak = cell["reduced_why"]["memory_peak_bytes"]
+    assert 0.25 * 16.91e9 < peak["chip"] < 16.91e9
