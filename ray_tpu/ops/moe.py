@@ -48,14 +48,19 @@ are fewer rows than that (R + T < T x k: a share's bounded row space),
 `lay_out` adds a token-ordered view of the row space (`ByToken`: one sort
 of its R keys) and the sum goes by the rows: gather the R rows in token
 order, add each token's run of at most k rows onto its head
-(`moe_run_sum`), gather the T heads. Which of the two runs is read off the
-plan's static shapes, nothing else: every slot's row space, the fallback
-above included, and a plan of all the experts (every slot is a real row:
-T x k is the least there is to move) go by the slots, op for op as they
-did. The sum is the same float32 sum of the same rows, rounded once; by
-the rows it adds them in choice order from the first held one. Every data
-movement is still a gather, forward and backward: the view only changes
-which rows are gathered, and how many.
+(`moe_run_sum`), gather the T heads. A run may cross into the next block
+of rows by k - 1 of them, which the kernel reads as a halo of whole sublane
+tiles, as many as k - 1 rows need (`_run_halo`: 16 rows of bfloat16 up to
+17 experts a token, 32 at 22), so any k goes by the rows as long as that
+halo divides a tile of the row space. Which of the two runs is read off
+the plan's static shapes and the rows' type, nothing else: every slot's
+row space, the fallback above included, a plan of all the experts (every
+slot is a real row: T x k is the least there is to move) and a tile the
+halo does not divide (16 rows under k = 22) go by the slots, op for op as
+they did. The sum is the same float32 sum of the same rows, rounded once;
+by the rows it adds them in choice order from the first held one. Every
+data movement is still a gather, forward and backward: the view only
+changes which rows are gathered, and how many.
 
 Kernels (names in util/profiling.KERNELS): `moe_gmm` (rows x an expert's
 matrix, forward and the gradient of the rows), `moe_tgmm` (rows^T x rows a
@@ -374,14 +379,25 @@ def _run_sum_kernel(x_ref, after_ref, c_ref, o_ref):
         o_ref[...] = total.astype(o_ref.dtype)
 
 
+def _run_halo(k: int, dtype) -> int:
+    """The rows `_run_sum` fetches past a block: whole sublane tiles, as
+    many as the k - 1 rows after a block's last one need (16 rows of
+    bfloat16 up to k = 17, 32 up to 33). k is at least 2 where a
+    token-ordered view exists (R + T < T x k)."""
+    sublanes = _sublanes(dtype)
+    return sublanes * -(-(k - 1) // sublanes)
+
+
 def _run_sum(x, c, block: int, interpret: bool):
     """x [R, d], c [R, k] float32 -> [R + block, d]: row j is the float32
     sum of c[j, a] x[j + a] over a < k, rounded once (c is zero wherever
     j + a is past the end), and the last `block` rows are zeros. One pass
     over x in blocks of `block` rows, the k - 1 rows a block needs of its
-    neighbour through a second BlockSpec of one sublane tile."""
+    neighbour through a second BlockSpec of `_run_halo` rows: the halo
+    follows k, and it has to divide the block (the second BlockSpec counts
+    x in halos), which `rows_to_tokens` sees to."""
     r, d = x.shape
-    halo = _sublanes(x.dtype)
+    halo = _run_halo(c.shape[1], x.dtype)
     cols = lane_divisor(d, 2048)
     per, blocks = block // halo, r // block
     return pl.pallas_call(
@@ -412,17 +428,17 @@ def rows_to_tokens(rows, plan: Plan, weights=None):
     rounded once. `combine` forward and `dispatch` backward.
 
     By the slots (plan.by_token is None: every slot is a row, or the row
-    space is as large as the slots): gather [T, k, d] through token_rows,
-    mask what is not held, sum over k. By the rows (a share's bounded row
-    space): gather the R rows in token order, add each run onto its head
+    space is as large as the slots; or the run's halo, `_run_halo`, does
+    not divide a tile): gather [T, k, d] through token_rows, mask what is
+    not held, sum over k. By the rows (a share's bounded row space, at any
+    k): gather the R rows in token order, add each run onto its head
     (`moe_run_sum`: a row's coefficients are its followers' weights as far
     as they are of its token), gather the T heads. R + T rows moved where
     T * k were, and nothing shaped [T, k, d]."""
     view = plan.by_token
     k = plan.token_rows.shape[1]
     tile = plan.row_slot.shape[0] // plan.tile_group.shape[0]
-    if (view is None or k - 1 > _sublanes(rows.dtype)
-            or tile % _sublanes(rows.dtype)):
+    if view is None or tile % _run_halo(k, rows.dtype):
         per_slot = rows[plan.token_rows]
         if weights is None:
             y = jnp.sum(_held_only(per_slot, plan), axis=1,

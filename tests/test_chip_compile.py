@@ -1018,6 +1018,21 @@ def test_embedding_lookup_compiles_without_a_scatter(v5e, monkeypatch, cell):
     assert f" = f32[{vocab},{d}]" in text
 
 
+def _configuration_and_traffic(name):
+    """benchmark/configs/<name>.json and the traffic of the cell that
+    BENCHMARK.json runs it under."""
+    import json
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def read(*parts):
+        with open(os.path.join(root, *parts)) as f:
+            return json.load(f)
+    traffic = next(w["traffic"] for w in read("BENCHMARK.json")["workloads"]
+                   if w["config"] == name)
+    return (read("benchmark", "configs", name + ".json"),
+            read("benchmark", "traffic", traffic + ".json"))
+
+
 # (configuration, rows a tile, tiles of the share's bounded row space, tiles
 # for every slot)
 SHARE_ROW_SPACES = [
@@ -1028,6 +1043,11 @@ SHARE_ROW_SPACES = [
     # x 4 a token, 8 of 64 held: 8192 expected in 256-row tiles, 2 x 32 + 8
     # = 72 tiles (18 432 rows) against 264 (67 584)
     ("lfm2-24b-a2b", 256, 72, 264),
+    # 1 x 8192 tokens x 22 a token, 8 of 512 held, in a latent width of
+    # 1024: 2816 expected in 128-row tiles, 2 x 22 + 8 = 52 tiles (6656 rows)
+    # against 1416 (181 248). k - 1 = 21 rows past a block are two sublane
+    # tiles of bfloat16: the run sum's halo follows k (ops/moe.py:_run_halo)
+    ("nemotron-3-super-120b-a12b", 128, 52, 1416),
 ]
 
 
@@ -1039,10 +1059,11 @@ def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, name,
     gradients under the layer's remat, for one described chip: the text
     holds the block over the bounded row space and over every slot's, one
     conditional forward and one backward (the forward one's recomputation
-    under the remat is dead code), the kernels once a branch; and the token
-    side sized by the slots in every slot's branch alone."""
-    import json
-
+    under the remat is dead code, unless a latent projection reads the
+    block's result: then it runs again, a third pass), the kernels once a
+    branch; and the token side sized by the slots in every slot's branch
+    alone, at any number of experts a token. Tokens a step are the cell's
+    own (BENCHMARK.json's traffic), the rows' width the experts' own."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -1050,10 +1071,8 @@ def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, name,
     from ray_tpu.models import gpt
     from ray_tpu.ops import attention
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           name + ".json")) as f:
-        config = json.load(f)
+    config, mix = _configuration_and_traffic(name)
+    batch, seq = mix["global_batch"], mix["seq"]
     monkeypatch.setattr(attention, "_default_interpret", lambda: False)
     cfg = model.family(config)._train_config(config)
     one_chip = SingleDeviceSharding(v5e[0])
@@ -1065,7 +1084,12 @@ def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, name,
     layers = jax.eval_shape(
         lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"]
     sparse = next(layer for layer in layers if "moe" in layer)["moe"]
-    d = cfg.d_model
+    # the rows' width: the model's, or the latent one the experts work in
+    d = sparse["w_down"].shape[-1]
+    matrices = sum(key in sparse for key in ("w_gate", "w_up", "w_down"))
+    # forward, backward and, where a latent projection's gradient needs the
+    # block's result, the forward again under the remat
+    passes = 3 if "w_latent_out" in sparse else 2
 
     def loss(m, x):
         block = jax.checkpoint(
@@ -1074,13 +1098,14 @@ def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, name,
                 attention.FLASH_OUT, attention.FLASH_LSE))
         return (block(x, m).astype(jnp.float32) ** 2).sum()
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        placed(sparse), jax.ShapeDtypeStruct((2, 8192, d), cfg.dtype,
-                                             sharding=one_chip)
+        placed(sparse), jax.ShapeDtypeStruct((batch, seq, cfg.d_model),
+                                             cfg.dtype, sharding=one_chip)
     ).compile().as_text()
-    assert len(re.findall(r" conditional\(", text)) == 2
-    # forward 3 + recomputed 3 + the rows' gradients 3, and 3: a branch
-    assert len(_kernel_ops(text, "moe_gmm")) == 18
-    assert len(_kernel_ops(text, "moe_tgmm")) == 6
+    assert len(re.findall(r" conditional\(", text)) == passes
+    # a branch and matrix: one product a forward pass, two in the backward's
+    # branch (the forward again, then the rows' gradient), and one tgmm
+    assert len(_kernel_ops(text, "moe_gmm")) == 2 * matrices * (passes + 1)
+    assert len(_kernel_ops(text, "moe_tgmm")) == 2 * matrices
     for tiles in (bounded, every):
         # the table of rows by tiles; the dispatched rows and the experts'
         # outputs, forward and backward
@@ -1092,10 +1117,11 @@ def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, name,
     # branch gathers its own rows in token order and the tokens' run heads
     # out of moe_run_sum's result, which has a tile of zeros appended.
     per_slot = [line for line in text.splitlines() if re.search(
-        rf" = bf16\[16384,{cfg.expert_top_k},{d}\]\S* gather\(", line)]
-    assert len(per_slot) == 2
+        rf" = bf16\[{batch * seq},{cfg.expert_top_k},{d}\]\S* gather\(",
+        line)]
+    assert len(per_slot) == passes
     assert all("/branch_0_fun/" in line for line in per_slot)
-    assert len(_kernel_ops(text, "moe_run_sum")) == 2
+    assert len(_kernel_ops(text, "moe_run_sum")) == passes
     runs = f"bf16[{(bounded + 1) * tile},{d}]"
     assert all(runs in line and "/branch_1_fun/" in line
                for line in _kernel_ops(text, "moe_run_sum"))
@@ -1220,8 +1246,6 @@ def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
     name), and arguments + temporaries stay under the chip's 16.91 GB. Under grouped queries k and
     v exist at the key/value heads' count alone: no tensor of the step has
     them at the query heads'."""
-    import json
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1232,16 +1256,7 @@ def test_cell_step_compiles_under_the_chips_memory(v5e, monkeypatch, name,
     from ray_tpu.parallel.sharding import strategy_from_name
     from ray_tpu.train.train_step import TrainState, make_train_step
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           name + ".json")) as f:
-        config = json.load(f)
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        traffic = next(w["traffic"] for w in json.load(f)["workloads"]
-                       if w["config"] == name)
-    with open(os.path.join(root, "benchmark", "traffic",
-                           traffic + ".json")) as f:
-        mix = json.load(f)
+    config, mix = _configuration_and_traffic(name)
     monkeypatch.setattr(attention, "_default_interpret", lambda: False)
     program = model.family(config).program(config)
     mesh = Mesh(np.array(v5e[:1]), ("data",))

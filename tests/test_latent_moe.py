@@ -590,6 +590,117 @@ def test_by_the_rows_equals_by_the_slots(jax_cpu, what, dtype):
         assert not got[0][here.sum(1) == 0].any()
 
 
+def _long_run_plan(jax, moe, k, tile):
+    """A share's plan at k experts a token over tiles of `tile` rows, read
+    by the rows wherever `moe._run_halo` divides the tile: 128 tokens, k of
+    512 experts held, and `tile` - 1 tokens with one slot here ahead of a
+    token with all k, so that its run starts on a block's last row and
+    takes k - 1 rows of the next block (the deepest a run can reach past
+    one: 16 rows at k = 17, 32 at 33); then tokens with none, with
+    several (2 .. k - 1) and with one or none."""
+    t, groups = 128, k
+    rng = np.random.default_rng(k)
+    idx = np.full((t, k), 400, np.int32)                   # not here
+    for token in range(tile - 1):                          # one slot here
+        idx[token, token % k] = token % groups
+    idx[tile - 1] = rng.permutation(groups)                # all k
+    for token in range(tile + 8, tile + 24):               # several
+        some = 2 + (token - tile - 8) % (k - 2)
+        idx[token, rng.permutation(k)[:some]] = rng.permutation(groups)[:some]
+    for token in range(tile + 24, t):                      # one or none
+        if rng.random() < 0.5:
+            idx[token, rng.integers(k)] = rng.integers(groups)
+    plan = jax.jit(lambda idx: moe.lay_out(                # one compile
+        moe.order_slots(idx, groups, tile, partial=True), tile,
+        groups + 8))(idx)
+    held = np.asarray(plan.token_held).sum(1)
+    assert {0, 1, 2, k} <= set(held) and held[tile - 1] == k
+    assert int(plan.tiles_used[0]) < groups + 7            # padding tiles
+    assert plan.by_token is not None
+    assert int(plan.by_token.heads[tile - 1]) == tile - 1
+    return plan, idx
+
+
+@pytest.mark.parametrize("k,dtype,tile", [
+    (17, "bfloat16", 32), (17, "float32", 32),     # halo 16 / 16
+    (18, "bfloat16", 64), (18, "float32", 48),     # 32 / 24
+    (22, "bfloat16", 64), (22, "float32", 48),     # 32 / 24
+    (33, "bfloat16", 64), (33, "float32", 64),     # 32 / 32
+    (22, "bfloat16", 16),                          # 32 divides no 16: slots
+], ids=str)
+def test_runs_past_one_sublane_tile_go_by_the_rows(jax_cpu, k, dtype, tile):
+    """`moe_run_sum`'s halo follows k: at 17, 18, 22 and 33 experts a token
+    the token side goes by the rows where the halo divides the tile, and is
+    the same plan's sum by the slots; `dispatch` and `combine` around a
+    grouped matmul give the masked dense computation's values and
+    gradients. The rows past tiles_used hold NaN and must reach no token. A
+    16-row tile under k = 22 keeps the slots' form."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import moe
+    d, f, dt = 128, 128, jnp.dtype(dtype)
+    plan, idx = _long_run_plan(jax, moe, k, tile)
+    by_slots = plan._replace(by_token=None)
+    t, groups, r = idx.shape[0], k, plan.row_slot.shape[0]
+    by_the_rows = tile % moe._run_halo(k, dt) == 0
+    assert by_the_rows == (tile != 16)
+    keys = jax.random.split(jax.random.PRNGKey(k), 4)
+    z = jax.random.normal(keys[0], (r, d), jnp.float32).astype(dt)
+    never = int(plan.tiles_used[0]) * tile
+    z = jnp.where(jnp.arange(r)[:, None] < never, z, jnp.nan)
+    weights = jax.random.uniform(keys[1], (t, k), jnp.float32)
+    assert ("moe_run_sum" in str(jax.make_jaxpr(
+        lambda z: moe.rows_to_tokens(z, plan, weights))(z))) == by_the_rows
+    # up to 33 terms in another order, rounded once
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == "float32" \
+        else dict(rtol=1e-2, atol=2e-2)
+    here = np.asarray(plan.token_held)
+    # combine forward (weighted) and dispatch backward (not), both ways; one
+    # jit: eagerly the followers' small ops take longer than the sums
+    sums = jax.jit(lambda z: [[moe.rows_to_tokens(z, p, w)
+                               for p in (plan, by_slots)]
+                              for w in (weights, None)])(z)
+    for got, want in sums:
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, **tol)
+        assert not got[here.sum(1) == 0].any()
+    # unweighted, a token with one row here gets that very row
+    one = np.flatnonzero(here.sum(1) == 1)[0]
+    np.testing.assert_array_equal(
+        got[one], np.asarray(z, np.float32)[
+            np.asarray(plan.token_rows)[one][here[one]][0]])
+
+    x = jax.random.normal(keys[2], (t, d), jnp.float32).astype(dt)
+    w = (jax.random.normal(keys[3], (groups, d, f), jnp.float32)
+         / np.sqrt(d)).astype(dt)
+
+    def sparse(x, w, weights):
+        y = moe.combine(moe.grouped_matmul(moe.dispatch(x, plan), w, plan),
+                        weights, plan).astype(jnp.float32)
+        return (y ** 2).sum(), y
+
+    def dense(x, w, weights):
+        every = jnp.einsum("td,gdf->tgf", x, w)
+        mask = (jnp.asarray(idx)[..., None] == jnp.arange(groups)) \
+            * weights[..., None]                            # [t, k, g]
+        y = jnp.einsum("tkg,tgf->tf", mask, every)
+        return (y ** 2).sum(), y
+    with jax.default_matmul_precision("highest"):
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            sparse, (0, 1, 2), has_aux=True))(x, w, weights)
+        (_, y_want), grads_want = jax.jit(jax.value_and_grad(
+            dense, (0, 1, 2), has_aux=True))(
+                x.astype(jnp.float32), w.astype(jnp.float32), weights)
+    # the rows are rounded to their type after the experts and again as
+    # tokens; the gradients carry both roundings
+    rel = 1e-5 if dtype == "float32" else 3e-2
+    for a, b in zip((y, *grads), (y_want, *grads_want)):
+        a, b = np.asarray(a, np.float32), np.asarray(b)
+        np.testing.assert_allclose(a, b, rtol=rel, atol=rel * np.abs(b).max())
+    assert not np.any(np.asarray(grads[2])[~here])
+
+
 # ---------------------------------------------------------------------------
 # (c2) the share's row space: sized for the rows expected, exact past it
 # ---------------------------------------------------------------------------
