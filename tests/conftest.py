@@ -92,6 +92,21 @@ def _gc_freeze_accumulated_heap():
     yield
 
 
+def pytest_generate_tests(metafunc):
+    """A family's file (tests/helpers/families.py) gives the shared checks
+    it imports their cases: FAMILY.cases[argument] -> values or
+    pytest.params. A test that parametrizes the argument itself keeps its
+    own."""
+    cases = getattr(getattr(metafunc.module, "FAMILY", None), "cases", {})
+    own = {name.strip() for mark in metafunc.definition.iter_markers(
+        "parametrize") for name in (
+            mark.args[0].split(",") if isinstance(mark.args[0], str)
+            else mark.args[0])}
+    for name, values in cases.items():
+        if name in metafunc.fixturenames and name not in own:
+            metafunc.parametrize(name, values)
+
+
 class _TestTimeout(Exception):
     pass
 

@@ -1,0 +1,389 @@
+"""Compiles for a TPU v5e that is described, not attached (the chip's own
+compiler is installed with libtpu): what tests/test_chip_compile.py (the
+kernels every cell shares) and each family's file (its kernels, its layers,
+its cell's whole step) use to hand the chip's compiler a program. Nothing
+runs; what the compiler would refuse on the chip — a tile it cannot lay out,
+a Mosaic call it cannot partition — it refuses here, at no chip time.
+
+A family's file imports the `v5e` fixture and the two checks at the end by
+name; its FAMILY (tests/helpers/families.py) holds their numbers. pytest
+does not collect this module.
+"""
+
+import os
+import re
+
+import pytest
+
+from helpers.families import read
+
+
+@pytest.fixture(scope="module")
+def v5e(jax_cpu):
+    """The four devices of a described v5e 2x2 host. The persistent compile
+    cache is off around these compiles: a TPU entry written without a chip
+    cannot be read back and only warns on the next run. Every file that
+    compiles for the described chip asks for the topology in its own pytest
+    process, and one process at a time may load libtpu unless
+    ALLOW_MULTIPLE_LIBTPU_LOAD=1 is set from outside, as the driver's test
+    command sets it (/root/TESTS_LAST_RUN.json): run several workers with
+    it, or one process without."""
+    jax = jax_cpu
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this box
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def configuration_and_traffic(name):
+    """benchmark/configs/<name>.json and the traffic of the cell that
+    BENCHMARK.json runs it under."""
+    traffic = next(w["traffic"] for w in read("BENCHMARK.json")["workloads"]
+                   if w["config"] == name)
+    return (read("benchmark", "configs", name + ".json"),
+            read("benchmark", "traffic", traffic + ".json"))
+
+
+def windows(text):
+    """The window sizes ("1x1x255") of the compiled text's reduce-windows."""
+    return re.findall(r"reduce-window\([^\n]*window=\{size=([0-9x]+)", text)
+
+
+def kernel_ops(text, kernel):
+    """The compiled text's lines that define a call of a Mosaic kernel (the
+    instruction takes the kernel's name, inside the transforms it was
+    traced under: `transpose_jvp_moe_gmm__.24`)."""
+    return [line for line in text.splitlines()
+            if re.match(rf"\s*%?(?:\w+_)?{kernel}_*[.\d]* = ", line)]
+
+
+def placed(tree, sharding):
+    """tree's leaves as shapes on `sharding`."""
+    import jax
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def compiled_on_chip(monkeypatch, fn, *shapes):
+    """jit(fn) compiled for where `shapes` lie, the kernels on their TPU
+    branch (jax.default_backend() is the CPU here)."""
+    import jax
+    from ray_tpu.ops import attention
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def cell_configuration(name, **fields):
+    """The GPTConfig of benchmark/configs/<name>.json as its family maps
+    it."""
+    from benchmark import model
+    from ray_tpu.models import gpt
+    config = read("benchmark", "configs", name + ".json")
+    return gpt.GPTConfig(**model.family(config).gpt_config_kwargs(config),
+                         **fields)
+
+
+def mixer_layer(cfg, kind, sharding):
+    """({group: the first layer's parameters of `kind`'s group} as shapes
+    on `sharding`, the group's name)."""
+    import jax
+    from ray_tpu.models import gpt
+    layers = jax.eval_shape(
+        lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"]
+    group = gpt._GROUP[kind]
+    return placed({group: next(layer[group] for layer in layers
+                               if group in layer)}, sharding), group
+
+
+def layer_on_four_chips(v5e, monkeypatch, widths, batch, seq):
+    """One layer's parameters and input as shapes under tp_fsdp on the
+    described fsdp=2 x tensor=2 mesh -> (cfg, mesh, strategy, layer, x)."""
+    import jax
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+
+    # jax.default_backend() is the CPU here; take the kernel's TPU branch.
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    cfg = gpt.GPTConfig(**widths)
+    mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2), devices=v5e)
+    strategy = strategy_from_name("tp_fsdp")
+
+    layer = jax.eval_shape(
+        lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"][0]
+    layer_sh = strategy.param_shardings(mesh, {"layers": [layer]})["layers"][0]
+    layer = jax.tree_util.tree_map(
+        lambda leaf, sh: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=sh), layer, layer_sh)
+    x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype,
+                             sharding=strategy.activation_sharding(mesh))
+    return cfg, mesh, strategy, layer, x
+
+
+def written_in_entry(text, ops="copy|transpose|reshape"):
+    """(the dimensions, the line) of every instruction of the entry
+    computation that writes a tensor by one of `ops`: what is written to
+    memory (an instruction inside a fused computation lives in registers)."""
+    written = re.compile(
+        rf"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* ({ops})\(")
+    for line in text[text.index("\nENTRY "):].splitlines():
+        made = written.match(line)
+        if made:
+            yield [int(d) for d in made.group(1).split(",")], line
+
+
+def attention_layer_gradients(monkeypatch, cfg, kind, batch, seq, sharding,
+                              mesh=None, layer=None, x=None, remat=True):
+    """The compiled text of one attention layer of `kind`, value and
+    gradient (under the layer's remat policy, which keeps the flash
+    forward's output and lse, unless remat is False), at [batch, seq]."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.rope import rope_table
+    if layer is None:
+        layer, _ = mixer_layer(cfg, kind, sharding)
+        x = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype,
+                                 sharding=sharding)
+    rope = cfg.rope_of(kind)
+
+    def loss(layer, x):
+        # layer_fn's own rule: no table for a kind that does not rotate
+        table = rope_table(seq, cfg.head_dim, rope) if rope else ()
+
+        def block(layer, x):
+            return gpt._attention_block(layer, x, cfg, table,
+                                        gpt.Setting(mesh), kind)[0]
+        if remat:
+            block = jax.checkpoint(
+                block, policy=jax.checkpoint_policies.save_only_these_names(
+                    attention.FLASH_OUT, attention.FLASH_LSE))
+        return block(layer, x).astype(jnp.float32).sum()
+
+    return compiled_on_chip(monkeypatch, jax.grad(loss, argnums=(0, 1)),
+                            layer, x).as_text()
+
+
+def heads_of_64_stay_by_token(text, rows, seq, heads, kv_heads):
+    """Of a compiled text that holds ONE attention layer whose heads are 64
+    wide, value and gradient under the layer's remat policy (`rows`, `heads`
+    and `kv_heads` a shard's, under a mesh). The heads fill lane tiles in
+    pairs (`ops/attention.py:tokens_first`), so q, k, v, o and their
+    cotangents stay [B, S, heads * 64] from the projections' matmuls to
+    `wo` and back: the three flash kernels once each, `rope_split` twice
+    forward (q's and k's; v takes none) and twice recomputed, `rope_merge`
+    twice, every one of them on [B, S, heads * 64] operands, and in the
+    entry computation no `copy`, `transpose` or `reshape` under `attn_core`
+    or `attn_out` writes a tensor the size of the key/value heads or larger
+    (the parent's steps turned [B, H, S, 64] under `attn_out` forward,
+    recomputed and backward). The twin of
+    test_heads_of_128_reach_wo_without_a_layout_pass
+    (tests/test_window_attention.py)."""
+    import math
+    by_token = {n: f"bf16[{rows},{seq},{n * 64}]" for n in (heads, kv_heads)}
+    for kernel, calls in (("flash_fwd", 1), ("flash_bwd_dq", 1),
+                          ("flash_bwd_dkv", 1), ("rope_split", 4),
+                          ("rope_merge", 2)):
+        ops = kernel_ops(text, kernel)
+        assert len(ops) == calls, (kernel, len(ops))
+        for op in ops:
+            assert by_token[heads] in op or by_token[kv_heads] in op, op
+            assert not re.search(rf"\[{rows},\d+,{seq},64\]", op), op
+    for dims, line in written_in_entry(text):
+        if re.search(r'op_name="[^"]*attn_(core|out)', line):
+            assert math.prod(dims) < rows * seq * kv_heads * 64, line
+
+
+# ---------------------------------------------------------------------------
+# A cell's whole step, compiled once a file
+# ---------------------------------------------------------------------------
+
+class CellStep:
+    """A one-chip cell's compiled train step: `text`, `memory`, and the
+    configuration and traffic it was built from."""
+
+    def __init__(self, v5e, name):
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        import optax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from benchmark import model
+        from ray_tpu.ops import attention
+        from ray_tpu.parallel.sharding import strategy_from_name
+        from ray_tpu.train.train_step import TrainState, make_train_step
+
+        self.config, self.mix = config, mix = configuration_and_traffic(name)
+        program = model.family(config).program(config)
+        mesh = Mesh(np.array(v5e[:1]), ("data",))
+        strategy = strategy_from_name(mix["strategy"])
+        optimizer = optax.adamw(config["train"]["learning_rate"])
+        whole = NamedSharding(mesh, P())
+        params = jax.eval_shape(lambda: program.init(jax.random.PRNGKey(0)))
+        state = TrainState(
+            placed(params, whole),
+            placed(jax.eval_shape(optimizer.init, params), whole),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=whole))
+        batch = {"tokens": jax.ShapeDtypeStruct(
+            (mix["global_batch"], mix["seq"] + 1), jnp.int32,
+            sharding=NamedSharding(mesh, strategy.batch_spec))}
+        step = make_train_step(
+            lambda p, b: program.loss(p, b, mesh,
+                                      strategy.activation_sharding(mesh)),
+            optimizer, mesh, strategy, sample_params=params)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(attention, "_default_interpret", lambda: False)
+            compiled = step.lower(state, batch).compile()
+        self.memory = compiled.memory_analysis()
+        self.text = compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def cell_step(v5e, family):
+    """The whole step of the file's cell (FAMILY.cell; the cell's own
+    traffic, adamw over fp32 masters) for one described chip: compiled
+    once, read by every case of the file that asserts on its text."""
+    return CellStep(v5e, family.cell)
+
+
+def test_cell_step_compiles_under_the_chips_memory(cell_step, family, cell):
+    """A one-chip cell's whole step (the cell's own traffic: 2 x 8192
+    tokens, 2 x 4096 at olmoe; adamw over fp32 masters) for one described
+    chip: every Mosaic call lays out, the
+    kernels are called as often as the layers say (`embed_grad` once a
+    step, the embedding lookup's backward, and never under `moe_tgmm`'s
+    name), and arguments + temporaries stay under the chip's 16.91 GB. Under grouped queries k and
+    v exist at the key/value heads' count alone: no tensor of the step has
+    them at the query heads'."""
+    assert cell == family.cell
+    config, mix = cell_step.config, cell_step.mix
+    kernel_calls, share = family.cell_kernel_calls, family.cell_memory_share
+    memory, text = cell_step.memory, cell_step.text
+    peak = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert share[0] * 16.91e9 < peak < share[1] * 16.91e9, peak
+    for kernel, calls in kernel_calls.items():
+        found = kernel_ops(text, kernel)
+        assert len(found) == calls, (kernel, len(found))
+        if kernel.startswith("index_"):
+            # once a layer: the walk's results are kept through the remat
+            assert not any("rematted_computation" in op for op in found)
+    if "index_kl" in kernel_calls:
+        # the indexer's walk left no row of 8192 keys to XLA: no window
+        # reduction over them (the router's own is 255 wide), and the
+        # step's temporaries are at or under those of PR 40's jnp walk
+        assert all(int(w.split("x")[-1]) < 512 for w in windows(text))
+        assert memory.temp_size_in_bytes <= 6.39e9
+    kv_heads = config.get("num_key_value_heads")
+    if kv_heads != config["num_attention_heads"]:
+        # dK and dV leave their kernel at the key/value heads' count, and
+        # the one tensor at the query heads' that enters it is q (with dO)
+        heads = config["num_attention_heads"]
+        dim = config.get("head_dim", config["hidden_size"] // heads)
+        b, s = mix["global_batch"], mix["seq"]
+        from ray_tpu.ops.attention import LANES, tokens_first
+        in_pairs = dim < LANES and tokens_first(dim, heads, kv_heads)
+        # by head, or (lfm2's heads of 64, in pairs) as the projections
+        # wrote them
+        at_kv_heads = (f"bf16[{b},{s},{kv_heads * dim}]" if in_pairs
+                       else f"bf16[{b * kv_heads},{s},{dim}]")
+        for kernel in ("flash_bwd_dkv", "flash_win_bwd_dkv",
+                       "flash_sel_bwd_dkv"):
+            for dkv in kernel_ops(text, kernel):
+                assert dkv.count(at_kv_heads) >= 4, dkv
+        by_head = (f"bf16[{b},{s},{heads * dim}]" if in_pairs
+                   else f"bf16[{b},{heads},{s},{dim}]")
+        made = [line for line in kernel_ops(text, "rope_split")
+                if f" = {by_head}" in line]
+        # q alone is split at the query heads' count: forward, and
+        # recomputed, in every full-attention layer (and in every window
+        # layer where both kinds have the one head count)
+        layers = kernel_calls["flash_fwd"] or kernel_calls["flash_sel_fwd"]
+        if "num_attention_heads_per_layer" not in config:
+            layers += kernel_calls.get("flash_win_fwd", 0)
+        assert len(made) == 2 * layers, made
+
+
+def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, family,
+                                                    sparse_cell):
+    """One sparse block of a cell that holds a share of the experts, its
+    gradients under the layer's remat, for one described chip: the text
+    holds the block over the bounded row space and over every slot's, one
+    conditional forward and one backward (the forward one's recomputation
+    under the remat is dead code, unless a latent projection reads the
+    block's result: then it runs again, a third pass), the kernels once a
+    branch; and the token side sized by the slots in every slot's branch
+    alone, at any number of experts a token. Tokens a step are the cell's
+    own (BENCHMARK.json's traffic), the rows' width the experts' own."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from benchmark import model
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+
+    tile, bounded, every = family.row_spaces
+    config, mix = configuration_and_traffic(sparse_cell)
+    batch, seq = mix["global_batch"], mix["seq"]
+    cfg = model.family(config)._train_config(config)
+    one_chip = SingleDeviceSharding(v5e[0])
+    layers = jax.eval_shape(
+        lambda: gpt.gpt_init(jax.random.PRNGKey(0), cfg))["layers"]
+    sparse = next(layer for layer in layers if "moe" in layer)["moe"]
+    # the rows' width: the model's, or the latent one the experts work in
+    d = sparse["w_down"].shape[-1]
+    matrices = sum(key in sparse for key in ("w_gate", "w_up", "w_down"))
+    # forward, backward and, where a latent projection's gradient needs the
+    # block's result, the forward again under the remat
+    passes = 3 if "w_latent_out" in sparse else 2
+
+    def loss(m, x):
+        block = jax.checkpoint(
+            lambda x, m: gpt._moe_block({"moe": m}, x, cfg, gpt.Setting())[0],
+            policy=jax.checkpoint_policies.save_only_these_names(
+                attention.FLASH_OUT, attention.FLASH_LSE))
+        return (block(x, m).astype(jnp.float32) ** 2).sum()
+    text = compiled_on_chip(
+        monkeypatch, jax.grad(loss, argnums=(0, 1)), placed(sparse, one_chip),
+        jax.ShapeDtypeStruct((batch, seq, cfg.d_model), cfg.dtype,
+                             sharding=one_chip)).as_text()
+    assert len(re.findall(r" conditional\(", text)) == passes
+    # a branch and matrix: one product a forward pass, two in the backward's
+    # branch (the forward again, then the rows' gradient), and one tgmm
+    assert len(kernel_ops(text, "moe_gmm")) == 2 * matrices * (passes + 1)
+    assert len(kernel_ops(text, "moe_tgmm")) == 2 * matrices
+    for tiles in (bounded, every):
+        # the table of rows by tiles; the dispatched rows and the experts'
+        # outputs, forward and backward
+        assert f"s32[{tiles},{tile}]" in text, tiles
+        assert text.count(f" = bf16[{tiles * tile},{d}]") >= 4, tiles
+    # the token side (combine forward, dispatch backward) moves every slot's
+    # row, bf16[T, k, d], over every slot's row space only: once a
+    # conditional, in the branch the predicate's false picks. The bounded
+    # branch gathers its own rows in token order and the tokens' run heads
+    # out of moe_run_sum's result, which has a tile of zeros appended.
+    per_slot = [line for line in text.splitlines() if re.search(
+        rf" = bf16\[{batch * seq},{cfg.expert_top_k},{d}\]\S* gather\(",
+        line)]
+    assert len(per_slot) == passes
+    assert all("/branch_0_fun/" in line for line in per_slot)
+    assert len(kernel_ops(text, "moe_run_sum")) == passes
+    runs = f"bf16[{(bounded + 1) * tile},{d}]"
+    assert all(runs in line and "/branch_1_fun/" in line
+               for line in kernel_ops(text, "moe_run_sum"))
+    # and no element gather or scatter-add of the kept weights
+    assert not re.search(r" scatter\(", text)

@@ -41,7 +41,11 @@ def test_collective_ops(ray_shared):
     members = [Member.remote(r, world) for r in range(world)]
     collective.create_collective_group(
         members, world, list(range(world)), group_name="g1")
-    res = ray_tpu.get([m.run.remote() for m in members], timeout=60)
+    # create_collective_group came back: both members have joined. The
+    # results are waited for under the test's own time limit (conftest's,
+    # 180 s), not a shorter one of its own: the two actors' workers start
+    # while xdist's other workers compile, and 60 s has passed before.
+    res = ray_tpu.get([m.run.remote() for m in members])
 
     # allreduce: sum of (1,1,1,1) and (2,2,2,2)
     for r in range(world):

@@ -1,40 +1,26 @@
 """The gated short convolution (ops/short_conv.py), grouped-query attention
-in the flash kernels (ops/attention.py), the norm a head, and a stack of two
-kinds of layer (models/gpt.py) against the plain float32 reference of
-benchmark/families/lfm2.py, at a small size on the CPU: seeded random
-weights, the kernels in interpret mode."""
+in the flash kernels (ops/attention.py) and the norm a head (models/gpt.py)
+against their plain references on the CPU (the kernels in interpret mode),
+and what lfm2_train_1chip hands the chip's compiler, for a described v5e:
+its kernels at the cell's shapes, its attention layer, its sparse block and
+its whole step. The family's program against the reference of
+benchmark/families/lfm2.py: tests/test_conv_gqa_model.py."""
 
-import copy
 import hashlib
-import json
-import os
-import re
-import sys
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-
-def _read(*parts):
-    with open(os.path.join(ROOT, *parts)) as f:
-        return json.load(f)
-
-
-@pytest.fixture(scope="module")
-def tiny():
-    """benchmark/rehearsal/configs/tiny-lfm2.json: conv + dense, then
-    attention / conv / conv with experts 4..7 of 16 held, 2 a token; 8
-    query heads of 16 on 2 key/value heads."""
-    return _read("benchmark", "rehearsal", "configs", "tiny-lfm2.json")
+from helpers.described_chip import (  # noqa: F401 — fixtures
+    cell_configuration, cell_step, heads_of_64_stay_by_token, v5e)
+from helpers.families import family  # noqa: F401
+from test_conv_gqa_model import FAMILY  # noqa: F401 — the cell's numbers
 
 
 # ---------------------------------------------------------------------------
 # (a) the operator between a convolution layer's projections
 # ---------------------------------------------------------------------------
+
 
 def _conv_loop(b, c, x, w):
     """out[n, t, ch] = c * sum_j w[ch, j] * (b * x)[t - (L - 1) + j],
@@ -159,6 +145,7 @@ def test_blocks_of_the_cell_and_the_kernels_names(jax_cpu):
 # (b) grouped queries in the three flash kernels
 # ---------------------------------------------------------------------------
 
+
 @pytest.mark.parametrize("heads,kv_heads,seq,blocks", [
     (8, 2, 256, {"block_q": 128, "block_k": 128}),   # several blocks a row
     (8, 2, 128, {}),                                  # one square block
@@ -268,7 +255,7 @@ def test_flash_refuses_head_counts_that_do_not_group(jax_cpu):
 # each ([1, 4, 256, 64] float32, blocks of 128, interpret mode), as the
 # parent's ops/attention.py (1eafc89) traces it: a key/value head for every
 # query head runs the kernels, grids and index maps it ran before grouped
-# queries. (The dense and sparse cells' whole steps: tests/test_latent_moe.py,
+# queries. (The dense and sparse cells' whole steps: tests/test_latent_moe_model.py,
 # OLMOE_STEP_SHA256.) Recorded anew by PR 55 with NARROW_HEADS_JAXPR_SHA256
 # above and for its reason (da176eee..f606 before).
 FLASH_MHA_JAXPR_SHA256 = (
@@ -305,6 +292,7 @@ def test_vmem_limit_follows_the_shape(seq, width, wide):
 # (c) the norm a head
 # ---------------------------------------------------------------------------
 
+
 def test_head_norm_is_an_rmsnorm_over_each_heads_columns(jax_cpu):
     jax = jax_cpu
     import jax.numpy as jnp
@@ -326,434 +314,82 @@ def test_head_norm_is_an_rmsnorm_over_each_heads_columns(jax_cpu):
 
 
 # ---------------------------------------------------------------------------
-# (d) the program against the reference: logits, loss and gradients
+# (d) for a described v5e: the cell's kernels, its attention layer, its
+# sparse block and (imported) its whole step
 # ---------------------------------------------------------------------------
 
-def _program(jax, config, attention, dtype=None):
+
+@pytest.mark.parametrize("heads,kv_heads", [(32, 8), (32, 32)],
+                         ids=["32_on_8", "32_on_32"])
+def test_flash_kernels_compile_at_8192_positions_of_64(v5e, heads, kv_heads):
+    """lfm2_train_1chip's call, [2, 32 on 8, 8192, 64], forward and both
+    backward kernels: several blocks of 2048 a row at a head of 64 need
+    more than the default 16 MB of VMEM (`_compiler_params`), grouped or
+    not; dK and dV leave at the key/value heads' count."""
+    import jax
     import jax.numpy as jnp
-    from benchmark.families import lfm2
-    from ray_tpu.models.gpt import GPTConfig, gpt_init
-    cfg = GPTConfig(**lfm2.gpt_config_kwargs(config), attention=attention,
-                    dtype=dtype or jnp.float32, remat_policy="none")
-    params = gpt_init(jax.random.PRNGKey(3), cfg)
-    for i, layer in enumerate(params["layers"]):
-        if "moe" in layer:
-            # a router with an opinion: at the init's 0.02 every score is 1/2
-            layer["moe"]["router"] = 0.3 * jax.random.normal(
-                jax.random.PRNGKey(100 + i), layer["moe"]["router"].shape)
-        if "attn" in layer:
-            # head norms that are not the identity on a unit vector, so that
-            # a norm after the rotation would show
-            for j, name in enumerate(("q_head_norm", "k_head_norm")):
-                layer["attn"][name]["scale"] = 1.0 + 0.3 * jax.random.normal(
-                    jax.random.PRNGKey(200 + j), (cfg.head_dim,))
-    tokens = np.random.default_rng(5).integers(
-        0, config["vocab_size"], (2, 129), dtype=np.int32)
-    return cfg, params, jnp.asarray(tokens)
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.attention import flash_attention
+
+    def shape(h):
+        return jax.ShapeDtypeStruct((2, h, 8192, 64), jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+    grads = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))
+    compiled = grads.lower(shape(heads), shape(kv_heads),
+                           shape(kv_heads)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    dq, dk, dv = compiled.out_info
+    assert dq.shape == (2, heads, 8192, 64)
+    assert dk.shape == dv.shape == (2, kv_heads, 8192, 64)
 
 
-@pytest.fixture(scope="module")
-def reference(jax_cpu, tiny):
-    jax = jax_cpu
-    from benchmark.families import lfm2
-    _cfg, params, tokens = _program(jax, tiny, "reference")
-    with jax.default_matmul_precision("highest"):
-        logits = jax.jit(lambda p, t: lfm2.reference_logits(
-            p, t[:, :-1], tiny))(params, tokens)
-        loss, grads = jax.jit(jax.value_and_grad(
-            lambda p, t: lfm2.reference_loss(p, t, tiny)))(params, tokens)
-    return logits, loss, grads
-
-
-@pytest.mark.parametrize("attention", ["reference", "flash"])
-def test_logits_loss_and_gradients_match_the_reference(jax_cpu, tiny,
-                                                       reference, attention):
-    """Two kinds of layer in one stack after a leading dense one, grouped
-    queries with the norm a head before the rotation, the sigmoid rule at
-    the published 1e-6 and the held experts, in float32: the whole tree of
-    gradients, the selection bias's (exactly zero) included."""
-    jax = jax_cpu
-    from ray_tpu.models.gpt import gpt_forward, gpt_loss_and_aux
-    cfg, params, tokens = _program(jax, tiny, attention)
-    assert [sorted(layer) for layer in params["layers"]] == [
-        ["conv", "ln1", "ln2", "mlp"], ["attn", "ln1", "ln2", "moe"],
-        ["conv", "ln1", "ln2", "moe"], ["conv", "ln1", "ln2", "moe"]]
-    attn, conv = params["layers"][1]["attn"], params["layers"][0]["conv"]
-    assert attn["wq"].shape == (128, 128) and attn["wk"].shape == (128, 32)
-    assert attn["q_head_norm"]["scale"].shape == (16,)
-    assert conv["w_in"].shape == (3, 128, 128)
-    assert conv["filter"].shape == (128, 3)
-    assert params["layers"][1]["moe"]["w_up"].shape == (4, 128, 64)
-    assert "lm_head" not in params                              # tied
-    with jax.default_matmul_precision("highest"):
-        logits, _ = jax.jit(lambda p, t: gpt_forward(p, t, cfg))(
-            params, tokens[:, :-1])
-        (loss, aux), grads = jax.jit(jax.value_and_grad(
-            lambda p, t: gpt_loss_and_aux(p, {"tokens": t}, cfg),
-            has_aux=True))(params, tokens)
-    ref_logits, ref_loss, ref_grads = reference
-    np.testing.assert_allclose(logits, ref_logits, atol=2e-5)
-    np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
-    assert float(loss) == float(aux["xent"])        # no router loss
-    assert 0.0 < float(aux["expert_slots_held_share"]) < 1.0
-    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
-                            jax.tree_util.tree_leaves(ref_grads)):
-        np.testing.assert_allclose(
-            g, r, atol=1e-5 * max(1.0, float(np.abs(r).max())),
-            err_msg=jax.tree_util.keystr(path))
-    for layer in grads["layers"][1:]:
-        assert not np.any(np.asarray(layer["moe"]["router_bias"]))
-
-
-def test_the_reference_tells_each_mechanism_apart(jax_cpu, tiny, reference):
-    """What `program_check` rests on: the reference with one mechanism
-    changed gives other logits (the norm after the rotation among them,
-    because this test's norm scales are not all one)."""
-    jax = jax_cpu
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_short_conv_kernels_compile_for_v5e(v5e, backward):
+    """ops/short_conv.py's pair at lfm2_train_1chip's [2, 8192, 2048]: the
+    sublane rolls, the halo blocks and the single-row loads and stores of
+    the taps lay out."""
+    import jax
     import jax.numpy as jnp
-    from benchmark.families import lfm2
-    _cfg, params, tokens = _program(jax, tiny, "reference")
-    sound = reference[0]
-    norm, rotated = lfm2._norm_heads, lfm2._rotated
-    faults = {
-        "_filtered": lambda u, w: u,
-        "_gated": lambda b, c, x, w: c * lfm2._filtered(x, w),
-        "_norm_heads": lambda t, scale, eps: t,
-        "_rotated": lambda t, cos, sin: norm(rotated(
-            t / norm(jnp.ones_like(t), params["layers"][1]["attn"][
-                "q_head_norm"]["scale"], 0.0), cos, sin),
-            params["layers"][1]["attn"]["q_head_norm"]["scale"], 0.0),
-        "_kv_head_of": lambda h, kv: jnp.arange(h) % kv,
-    }
-    for name, fault in faults.items():
-        kept = getattr(lfm2, name)
-        setattr(lfm2, name, fault)
-        try:
-            with jax.default_matmul_precision("highest"):
-                logits = jax.jit(lambda p, t: lfm2.reference_logits(
-                    p, t[:, :-1], tiny))(params, tokens)
-        finally:
-            setattr(lfm2, name, kept)
-        assert float(jnp.abs(logits - sound).max()) > 1e-3, name
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.short_conv import short_conv
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+
+    def fn(b, c, x, w, g):
+        out, vjp = jax.vjp(lambda *a: short_conv(*a, interpret=False),
+                           b, c, x, w)
+        return vjp(g) if backward else out
+    x = shape((2, 8192, 2048))
+    text = jax.jit(fn).lower(x, x, x, shape((2048, 3), jnp.float32),
+                             x).compile().as_text()
+    assert ("short_conv_bwd" if backward else "short_conv_fwd") in text
 
 
-def test_bfloat16_step_passes_the_per_token_check(jax_cpu, tiny):
-    """reference_loss with a `program_check` answers the loss where the
-    program's own forward (bf16, flash under grouped queries, the
-    convolution's kernels, the grouped-matmul kernels) agrees with the
-    reference token by token, and nan where a bound is broken."""
-    jax = jax_cpu
-    from benchmark.families import lfm2
-    _cfg, params, tokens = _program(jax, tiny, "flash")
-    checked = dict(tiny, program_check={"logprob_median_tol": 0.05,
-                                        "logprob_rms_tol": 0.2})
-    with jax.default_matmul_precision("highest"):
-        plain = float(jax.jit(lambda p, t: lfm2.reference_loss(
-            p, t, tiny))(params, tokens))
-        held = float(jax.jit(lambda p, t: lfm2.reference_loss(
-            p, t, checked))(params, tokens))
-        checked["program_check"]["logprob_median_tol"] = 1e-6
-        broken = float(jax.jit(lambda p, t: lfm2.reference_loss(
-            p, t, checked))(params, tokens))
-    assert held == plain and np.isnan(broken)
+@pytest.mark.parametrize("cell", ["lfm2_train_1chip"])
+def test_heads_of_64_reach_wo_without_a_layout_pass(cell_step, cell):
+    """The third cell whose heads are 64 wide (32 on 8 at [2, 8192]), held
+    to helpers/described_chip.py:heads_of_64_stay_by_token as
+    tests/test_chip_compile.py holds gpt2s' and smollm's. The cell has ONE
+    attention layer, in the entry computation of its whole step: the text is
+    the module's one compile of that step, not a second compile of the
+    layer alone."""
+    from ray_tpu.ops import attention
+    cfg = cell_configuration(FAMILY.cell, attention="flash")
+    assert cfg.head_dim == 64 and attention.tokens_first(
+        64, cfg.n_heads, cfg.kv_heads)
+    assert (cell_step.mix["global_batch"], cell_step.mix["seq"]) == (2, 8192)
+    heads_of_64_stay_by_token(cell_step.text, 2, 8192, cfg.n_heads,
+                              cfg.kv_heads)
 
 
-def test_renormalisation_epsilon_is_the_configurations(jax_cpu, tiny):
-    """1e-6 for this family, 1e-20 (the default) for kanana's."""
-    from benchmark.families import kanana, lfm2
-    from ray_tpu.models.gpt import GPTConfig
-    assert GPTConfig().router_renormalise_eps == 1e-20
-    assert GPTConfig(**lfm2.gpt_config_kwargs(
-        tiny)).router_renormalise_eps == 1e-6
-    other = _read("benchmark", "rehearsal", "configs", "tiny-kanana.json")
-    assert GPTConfig(**kanana.gpt_config_kwargs(
-        other)).router_renormalise_eps == 1e-20
-
-
-# ---------------------------------------------------------------------------
-# (e) the share: the parts add up to the whole
-# ---------------------------------------------------------------------------
-
-def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(jax_cpu, tiny):
-    """model-configs guide, section 4: a whole sparse convolution layer,
-    mixer and residual included. Every chip computes the mixer and the
-    residual alike, so they count once; what the four shares' experts add
-    (each the routed part of its own four experts) adds up with them to the
-    uncut reference's layer."""
-    jax = jax_cpu
-    import jax.numpy as jnp
-    from benchmark.families import lfm2
-    from ray_tpu.models.gpt import GPTConfig, Setting, gpt_init, layer_fn
-    whole = copy.deepcopy(tiny)
-    del whole["share"]
-    whole["num_experts"] = 16
-    full_cfg = GPTConfig(**lfm2.gpt_config_kwargs(whole), dtype=jnp.float32,
-                         attention="reference", remat_policy="none")
-    assert full_cfg.experts_held is None
-    layer = gpt_init(jax.random.PRNGKey(7), full_cfg)["layers"][2]
-    assert sorted(layer) == ["conv", "ln1", "ln2", "moe"]
-    layer["moe"]["router"] = 0.3 * jax.random.normal(
-        jax.random.PRNGKey(8), (128, 16))
-    x = jax.random.normal(jax.random.PRNGKey(9), (2, 64, 128), jnp.float32)
-
-    def reference_layer(h):
-        h = h + lfm2.reference_conv(
-            layer["conv"], lfm2._norm(h, layer["ln1"]["scale"], 1e-5), whole)
-        return h, h + lfm2.reference_experts(
-            layer["moe"], lfm2._norm(h, layer["ln2"]["scale"], 1e-5), whole)
-
-    with jax.default_matmul_precision("highest"):
-        mixed, want = jax.vmap(reference_layer)(x)
-        parts, held_share = [], 0.0
-        for rank in range(4):
-            cut = dict(tiny, share=dict(tiny["share"], rank=rank))
-            cfg = GPTConfig(**lfm2.gpt_config_kwargs(cut), dtype=jnp.float32,
-                            attention="reference", remat_policy="none")
-            assert cfg.experts_held == (4 * rank, 4)
-            mine = dict(layer, moe=dict(layer["moe"], **{
-                name: layer["moe"][name][4 * rank:4 * rank + 4]
-                for name in ("w_gate", "w_up", "w_down")}))
-            out, stats = layer_fn(cfg, 64, Setting())(x, mine)
-            # mixer and residual, the same on every chip, taken off
-            parts.append(out - mixed)
-            held_share += float(stats["expert_slots_held_share"])
-    np.testing.assert_allclose(mixed + sum(parts), want, atol=5e-5)
-    assert abs(held_share - 1.0) < 1e-6
-    # and a part is not the whole: the absent experts' sum is left out
-    assert float(jnp.abs(mixed + parts[0] - want).max()) > 1e-2
-
-
-# ---------------------------------------------------------------------------
-# (f) arithmetic, rules, refusals, names
-# ---------------------------------------------------------------------------
-
-def test_param_count_at_the_cell_is_the_programs_tree(jax_cpu, tiny):
-    jax = jax_cpu
-    from benchmark.families import lfm2
-    from ray_tpu.models.gpt import GPTConfig, count_params, gpt_init
-    cell = _read("benchmark", "configs", "lfm2-24b-a2b.json")
-    assert lfm2.param_count(cell) == 469_285_248
-    assert lfm2.share(cell) == (0, 8, 64)
-    for config in (cell, tiny):
-        cfg = GPTConfig(**lfm2.gpt_config_kwargs(config))
-        assert lfm2.param_count(config) == count_params(
-            jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg)))
-    # the published model, tied: 23.84B, its name
-    published = {k: v for k, v in cell.items() if k != "share"}
-    published.update(cell["published"])
-    assert round(lfm2.param_count(published) / 1e9, 2) == 23.84
-
-
-def test_flops_and_kernel_arithmetic_count_what_is_computed_here():
-    from benchmark.families import lfm2
-    from benchmark.kernels import gqa_attention
-    cell = _read("benchmark", "configs", "lfm2-24b-a2b.json")
-    mix = _read("benchmark", "traffic", "train_b2_s8192_dp.json")
-    d = 2048
-    active = (2 * d * d + 2 * d * 512 + 4 * (4 * d * d + 3 * d)
-              + 3 * d * 11776
-              + 4 * (d * 64 + 4 * 8 / 64 * 3 * d * 1536) + d * 8192)
-    assert lfm2.train_flops_per_token(cell, 8192) == pytest.approx(
-        6.0 * active + 3.0 * 32 * 128 * 8192)
-    assert lfm2.forward_flops_per_token(cell, 8192) == pytest.approx(
-        0.406e9, rel=0.01)        # a third of ISSUE 33's 1.22 GFLOP a token
-    assert lfm2.attention_call(cell, mix) == {
-        "batch": 2, "heads": 32, "kv_heads": 8, "seq": 8192, "head_dim": 64}
-    product = 2 * 32 * 8192 * 8192 * 64
-    wide, narrow = 2 * 32 * 8192 * 64 * 2, 2 * 8 * 8192 * 64 * 2
-    fwd, dq, dkv = (f(cell, mix) for f in (
-        gqa_attention.flash_fwd, gqa_attention.flash_bwd_dq,
-        gqa_attention.flash_bwd_dkv))
-    assert fwd == (2 * product, 2 * wide + 2 * narrow)
-    assert dq[0] + dkv[0] == 5 * product          # the backward's five
-    assert dq[1] == 3 * wide + 2 * narrow
-    assert dkv[1] == 2 * wide + 4 * narrow        # dK, dV at 8 heads
-
-
-@pytest.mark.parametrize("strategy,column,row", [
-    ("tp", (None, "tensor"), ("tensor", None)),
-    ("tp_fsdp", ("fsdp", "tensor"), ("tensor", "fsdp"))])
-def test_every_new_leaf_gets_its_rule(jax_cpu, tiny, strategy, column, row):
-    jax = jax_cpu
-    from jax.sharding import PartitionSpec as P
-    from benchmark.families import lfm2
-    from ray_tpu.models.gpt import GPTConfig, gpt_init
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-    from ray_tpu.parallel.sharding import strategy_from_name
-    cfg = GPTConfig(**lfm2.gpt_config_kwargs(tiny))
-    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
-    mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2),
-                      devices=jax.devices()[:4])
-    specs = jax.tree_util.tree_map(
-        lambda s: s.spec,
-        strategy_from_name(strategy).param_shardings(mesh, params))
-    conv, attn = specs["layers"][0]["conv"], specs["layers"][1]["attn"]
-    # the three chunks split by channel, each with its channels' filter
-    assert conv["w_in"] == P(None, *column)
-    assert conv["filter"] == P("tensor", None)
-    assert conv["w_out"] == P(*row)
-    assert attn["wq"] == attn["wk"] == attn["wv"] == P(*column)
-    assert attn["q_head_norm"]["scale"] == attn["k_head_norm"]["scale"] \
-        == P(None)
-
-
-def test_sharded_step_equals_one_device(jax_cpu, tiny):
-    """One step of the whole tiny model on fsdp=2 x tensor=2 (a key/value
-    head with its four query heads and the channels of B, C, X with their
-    filters on a shard of `tensor`, the kernels per shard) equals the
-    one-device step."""
-    jax = jax_cpu
-    import jax.numpy as jnp
-    import optax
-    from benchmark.families import lfm2
-    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-    from ray_tpu.parallel.sharding import strategy_from_name
-    from ray_tpu.train.train_step import init_train_state, make_train_step
-    cfg = GPTConfig(**lfm2.gpt_config_kwargs(tiny), dtype=jnp.float32,
-                    attention="flash")
-    tokens = jnp.asarray(np.random.default_rng(5).integers(
-        0, 512, (4, 129), dtype=np.int32))
-
-    def one_step(name, axes, n):
-        mesh = build_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
-        strategy = strategy_from_name(name)
-        optimizer = optax.sgd(0.1)
-        state = init_train_state(
-            lambda: gpt_init(jax.random.PRNGKey(3), cfg), optimizer, mesh,
-            strategy)
-        step = make_train_step(
-            lambda p, b: gpt_loss(
-                p, b, cfg, mesh=mesh,
-                act_sharding=strategy.activation_sharding(mesh)),
-            optimizer, mesh, strategy, sample_params=state.params)
-        with jax.default_matmul_precision("highest"):
-            state, metrics = step(state, {"tokens": tokens})
-        return float(metrics["loss"]), jax.device_get(state.params)
-
-    ref_loss, ref_params = one_step("dp", {"data": 1}, 1)
-    loss, params = one_step("tp_fsdp", {"data": 1, "fsdp": 2, "tensor": 2}, 4)
-    assert abs(loss - ref_loss) < 1e-5
-    for (path, p), r in zip(jax.tree_util.tree_flatten_with_path(params)[0],
-                            jax.tree_util.tree_leaves(ref_params)):
-        np.testing.assert_allclose(p, r, rtol=1e-4, atol=1e-6,
-                                   err_msg=jax.tree_util.keystr(path))
-
-
-@pytest.mark.parametrize("change,says", [
-    ({"attention": "ring"}, "n_kv_heads=2 != n_heads=8.*attention='ring'"),
-    ({"kv_latent_dim": 64, "qk_nope_dim": 16, "qk_rope_dim": 16,
-      "v_head_dim": 16}, "n_kv_heads=2 != n_heads=8.*a latent block"),
-    ({"n_kv_heads": 3}, "n_kv_heads=3 does not divide n_heads=8"),
-    ({"layer_kinds": ("conv", "attention")}, "layer_kinds.*n_layers=4"),
-    ({"layer_kinds": ("conv", "mamba", "conv", "conv")},
-     "'attention' | 'conv'"),
-], ids=["ring", "latent", "kv_heads", "kinds_length", "kinds_names"])
-def test_the_configuration_refuses_by_name(tiny, change, says):
-    from benchmark.families import lfm2
-    from ray_tpu.models.gpt import GPTConfig
-    with pytest.raises(ValueError, match=says):
-        GPTConfig(**dict(lfm2.gpt_config_kwargs(tiny), **change))
-
-
-@pytest.mark.parametrize("change,mesh_axes,says", [
-    ({"n_experts": 0, "dense_layers": 0, "experts_held": None},
-     {"pipeline": 2}, "parameters are not layer 0's.*conv/w_in"),
-    ({"layer_kinds": ("conv",) * 4, "n_experts": 0, "dense_layers": 0,
-      "experts_held": None}, {"pipeline": 2, "tensor": 2},
-     "no rule for conv/filter, conv/w_in, conv/w_out"),
-    ({"layer_kinds": None, "n_experts": 0, "dense_layers": 0,
-      "experts_held": None}, {"pipeline": 1, "tensor": 4},
-     "n_kv_heads=2 is not whole key/value heads over tp=4"),
-], ids=["two_kinds", "conv_under_pp_tp", "kv_heads_over_tensor"])
-def test_pipeline_refuses_by_name(jax_cpu, tiny, change, mesh_axes, says):
-    jax = jax_cpu
-    from benchmark.families import lfm2
-    from ray_tpu.models.gpt import GPTConfig
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-    from ray_tpu.parallel.pipeline import make_gpt_pp_loss
-    cfg = GPTConfig(**dict(lfm2.gpt_config_kwargs(tiny), **change))
-    n = int(np.prod(list(mesh_axes.values())))
-    mesh = build_mesh(MeshConfig(data=1, **mesh_axes),
-                      devices=jax.devices()[:n])
-    with pytest.raises(ValueError, match=says):
-        make_gpt_pp_loss(cfg, mesh, num_microbatches=2)
-
-
-def test_key_value_heads_stay_whole_over_tensor(jax_cpu, tiny):
-    jax = jax_cpu
-    import jax.numpy as jnp
-    from benchmark.families import lfm2
-    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-    cfg = GPTConfig(**lfm2.gpt_config_kwargs(tiny))
-    params = gpt_init(jax.random.PRNGKey(0), cfg)
-    mesh = build_mesh(MeshConfig(data=1, tensor=4), devices=jax.devices()[:4])
-    with pytest.raises(ValueError, match="n_kv_heads=2 is not whole "
-                                         "key/value heads over tensor=4"):
-        gpt_loss(params, {"tokens": jnp.zeros((2, 129), jnp.int32)}, cfg,
-                 mesh=mesh)
-
-
-def test_the_new_scopes_are_regions_and_reach_the_compiled_step(jax_cpu,
-                                                                tiny):
-    jax = jax_cpu
-    import jax.numpy as jnp
-    from benchmark.families import lfm2
-    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
-    from ray_tpu.util import profiling
-    assert {"conv", "conv_mix"} <= set(profiling.REGIONS)
-    cfg = GPTConfig(**lfm2.gpt_config_kwargs(tiny), attention="flash")
-    params = gpt_init(jax.random.PRNGKey(0), cfg)
-    text = jax.jit(jax.grad(lambda p, t: gpt_loss(p, {"tokens": t}, cfg))
-                   ).lower(params, jnp.zeros((2, 129), jnp.int32)
-                           ).compile().as_text()
-    names = set(re.findall(r'op_name="([^"]*)"', text))
-    regions = {profiling._last_of(n, profiling.REGIONS) for n in names}
-    assert {"conv", "conv_mix", "attn_proj", "attn_core", "moe",
-            "moe_route", "mlp"} <= regions
-    # the two projections are `conv`'s, gates and filter `conv_mix`'s
-    assert any("conv/bsd,de->bse" in n for n in names)
-    assert not any("conv_mix" in n and "dot_general" in n for n in names)
-
-
-def test_configuration_file_keeps_the_catalog_and_states_the_cut():
-    cell = _read("benchmark", "configs", "lfm2-24b-a2b.json")
-    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
-    if not os.path.exists(catalog):
-        pytest.skip("no catalog here")
-    with open(catalog) as f:
-        row = next(r for r in map(json.loads, f)
-                   if r["source_url"] == cell["source"])
-    changed = {k for k, v in row["config"].items() if cell.get(k, "?") != v}
-    assert changed == set(cell["reduced"]) == {
-        "num_hidden_layers", "num_experts", "vocab_size", "layer_types",
-        "num_dense_layers"}
-    assert cell["published"] == {k: row["config"][k] for k in cell["reduced"]}
-    # published layers 1..5: the second leading dense layer, then a period
-    assert cell["layer_types"] == row["config"]["layer_types"][1:6] == [
-        "conv", "full_attention", "conv", "conv", "conv"]
-    assert cell["share"]["chips_per_layer"] * cell["num_experts"] \
-        == cell["share"]["num_experts"] == 64
-    assert cell["share"]["chips_per_layer"] * cell["vocab_size"] == 65536
-    bench = _read("BENCHMARK.json")
-    entry = next(c for c in bench["configs"] if c["name"] == cell["name"])
-    assert entry["reduced"] == cell["reduced"]
-    assert entry["source"] == cell["source"]
-
-
-# ---------------------------------------------------------------------------
-# (g) the benchmark's own check of the cell that needs no chip
-# ---------------------------------------------------------------------------
-
-@pytest.mark.timeout(600)
-def test_the_cell_rehearses():
-    import subprocess
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)      # rehearse.py asks for its own devices
-    proc = subprocess.run(
-        [sys.executable, "benchmark/rehearse.py", "lfm2_train_1chip",
-         "--seconds", "2"], cwd=ROOT, env=env, capture_output=True,
-        text=True, timeout=540)
-    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    assert "rehearsal passed" in proc.stdout
+# Imported last: a module's names are collected in the order they are bound,
+# so the chip's compiler gets this file's programs after its own tests have
+# run, at another minute of a run than the other families' files.
+from helpers.described_chip import (  # noqa: E402,F401
+    test_cell_step_compiles_under_the_chips_memory,
+    test_sparse_layer_compiles_with_both_row_spaces)

@@ -108,12 +108,13 @@ def test_the_vocabulary_is_the_names_the_code_opens(jax_cpu):
     assert not set(KERNELS) & set(REGIONS)
 
 
-# The regions a dense step has. (attn_latent and moe_shared: tests/
-# test_latent_moe.py, on a step that has a latent block and a shared
-# expert; conv and conv_mix: tests/test_conv_gqa.py; attn_window and
-# attn_gate: tests/test_window_attention.py; attn_index: tests/
-# test_selected_attention.py; kda and kda_core: tests/
-# test_linear_attention.py; moe, moe_route and grad_accum: below)
+# The regions a dense step has. (A family's own scopes are held by its
+# `scopes` hook, tests/helpers/families.py: attn_latent and moe_shared in
+# tests/test_latent_moe_model.py, on a step that has a latent block and a
+# shared expert; conv and conv_mix: tests/test_conv_gqa_model.py; attn_window
+# and attn_gate: tests/test_window_attention_model.py; attn_index: tests/
+# test_selected_attention_model.py; kda and kda_core: tests/
+# test_linear_attention_model.py; moe, moe_route and grad_accum: below)
 @pytest.mark.parametrize("region", [
     "embed", "attn_proj", "attn_core", "attn_out", "mlp", "norm", "head",
     "loss_and_grad", "optimizer"])

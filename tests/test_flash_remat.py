@@ -10,6 +10,8 @@ import re
 import numpy as np
 import pytest
 
+from helpers.families import kernel_calls as _kernel_calls
+
 # two layers of multi-head attention (two heads of 64 a tensor shard, the
 # least the rope kernel tiles), and of latent attention at the published
 # head: q.k 128 + 64 rotated = 192 wide (padded to 256), v 128
@@ -72,18 +74,6 @@ def test_the_kept_tokens_first_output_gives_the_gradients(jax_cpu, kind,
     from helpers.flash_layout import check_tokens_first
     check_tokens_first(jax_cpu, jnp.dtype(dtype).type, keep=True,
                        **({"window": 100} if kind == "window" else {}))
-
-
-def _kernel_calls(jax, jaxpr, rematted=False):
-    """(kernel name, whether it runs in a layer's recompute pass: under a
-    checkpoint equation of the backward) for every pallas_call of jaxpr."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn.params["name"], rematted
-        # jax.checkpoint's equation, as the backward pass holds it
-        inner = rematted or eqn.params.get("differentiated", False)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _kernel_calls(jax, sub, inner)
 
 
 def _gradient_jaxpr(jax, entered):

@@ -1,0 +1,102 @@
+"""Every cell's train step, lowered for the TPU at the real sizes, is the
+text that was recorded: a PR that means to change a cell's program records
+its new hash here and says so; one that does not finds out here."""
+
+import hashlib
+import re
+
+import pytest
+
+from helpers.families import read
+
+
+# sha256 (16 digits) of each cell's train step lowered for the TPU at the
+# real sizes, Mosaic calls in it (their backend_config masked, locations
+# stripped), nothing compiled: the parent's (708aa58), read before this
+# PR's first edit. A PR that means to change a cell's step replaces its
+# line; one that does not finds out here. PR 39 (parent 8427b12) meant to
+# change kanana's (f5d072a05f3156d1 there: its latent block reaches the
+# flash kernels through ops/rope.py's latent kernels) and no other; laguna's
+# is its parent's.
+LOWERED = {
+    # PR 51 recorded every one-chip cell anew and the four-chip cell not:
+    # on one device the embedding's lookup is ops/embedding.py's (a gather
+    # of the master rows, `embed_grad` backward), under the four-chip mesh
+    # it is the parent's expression and the step the parent's text. Before
+    # it: gpt2s 0a5e354a41f2d309, lfm2 6d8075c1983c7f5a, olmoe
+    # de1ac5dddca614d0, kanana ccd30b735ee79374, laguna 2c9cca064dffa677,
+    # keye c7b4fffd346aa0f2, solar 830c2fd63a15f131.
+    # heads of 64, recorded anew by PR 55, which means to change exactly
+    # these three: the heads fill lane tiles in pairs, q and k are rotated
+    # where they lie (two `rope_split` calls a layer forward, none for v),
+    # the flash kernels read and write [B, S, heads * 64] and nothing is
+    # turned under `attn_out` (e83fa754d169a879, 3d347ff7870a2d4a and
+    # ee4dcfeb681f3aa3 before it, the parent's per-head steps since PR 51;
+    # PR 48 left them alone)
+    "gpt2s_train_1chip": "301b04a77398ad85",
+    "smollm17_train_4chip": "dfab71739954d841",
+    "lfm2_train_1chip": "fe8bdb7954ee2c8e",
+    # heads (v's) of 128, recorded anew by PR 48: the flash kernels write o
+    # and read dO as [B, S, H * 128], `wo` reads that as it is, delta and a
+    # gate a head go through `head_columns` (a875c8421b01b065,
+    # 64dedc5a37df64cf, a13b1de35328fc71, 2420d0b4f00749c5 and
+    # aa90d217a4905e58 before it: PR 42's masters in `moe_gmm`, and at
+    # solar PR 47's `kda_fwd` / `kda_bwd`)
+    "olmoe_train_1chip": "37f86a82ad7f1fa7",
+    "kanana2_train_1chip": "8e6cd298cd0a8c16",
+    "laguna_train_1chip": "e425c199e1b22258",
+    "keye2_train_1chip": "b478ecfb41cd7a16",
+    "solar2_train_1chip": "42d57e72e853172f",
+}
+
+
+@pytest.mark.timeout(600)
+@pytest.mark.parametrize("cell", list(LOWERED))
+def test_the_cells_that_were_there_lower_to_the_same_step(jax_cpu,
+                                                          monkeypatch, cell):
+    """The cells' programs are the text that was recorded: a PR that means
+    to change a cell's program records its new hash above and says so."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from benchmark import model
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.sharding import strategy_from_name
+    from ray_tpu.train import train_step as ts
+    monkeypatch.setattr(attention, "_default_interpret", lambda: False)
+    bench = read("BENCHMARK.json")
+    entry = next(w for w in bench["workloads"] if w["name"] == cell)
+    config = read(next(c for c in bench["configs"]
+                        if c["name"] == entry["config"])["file"])
+    mix = read("benchmark", "traffic", entry["traffic"] + ".json")
+    program = model.family(config).program(config)
+    mesh = build_mesh(MeshConfig(**mix["mesh"]),
+                      devices=jax.devices()[:entry["chips"]])
+    strategy = strategy_from_name(mix["strategy"])
+    optimizer = optax.adamw(config["train"]["learning_rate"])
+    params = jax.eval_shape(lambda: program.init(jax.random.PRNGKey(0)))
+    shardings = strategy.param_shardings(mesh, params)
+
+    def place(tree, sh):
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, sh)
+    state = ts.TrainState(
+        place(params, shardings),
+        place(jax.eval_shape(optimizer.init, params),
+              ts._opt_state_shardings(optimizer, params, shardings, mesh)),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P())))
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (mix["global_batch"], mix["seq"] + 1), jnp.int32,
+        sharding=NamedSharding(mesh, strategy.batch_spec))}
+    step = ts.make_train_step(
+        lambda p, b: program.loss(p, b, mesh,
+                                  strategy.activation_sharding(mesh)),
+        optimizer, mesh, strategy, sample_params=params)
+    text = step.trace(state, batch).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=False)
+    text = re.sub(r"loc\([^)]*\)", "", text)
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = "..."', text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == LOWERED[cell]

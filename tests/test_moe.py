@@ -1,16 +1,39 @@
 """Sparse experts (models/gpt.py's _moe_block over ops/moe.py) against the
 plain float32 reference of benchmark/families/olmoe.py, at a small OLMoE on
-the CPU: seeded random weights, the kernels in interpret mode."""
+the CPU: seeded random weights, the kernels in interpret mode; and
+olmoe_train_1chip's whole step compiled for a described chip (imported)."""
 
 import os
-import sys
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+from helpers.described_chip import cell_step, v5e  # noqa: F401 — fixtures
+from helpers.families import ROOT, Family, family  # noqa: F401
+from helpers.sparse_block import experts as _experts
+
+class Olmoe(Family):
+    """The first sparse family: its checks below predate the shared ones
+    (tests/helpers/families.py) and keep their own small configuration; the
+    class holds what the cell's compile for a described chip is held to."""
+
+    name, tiny, cell = "olmoe", "tiny-olmoe", "olmoe-1b-7b"
+    workload = "olmoe_train_1chip"
+
+    # olmoe_train_1chip (2 x 4096 tokens): one layer, all 64 experts held,
+    # so no conditional and every kernel once: 3 grouped matmuls forward, 3
+    # recomputed, 3 for the rows' gradients, 3 tgmm; the float32 masters
+    # reach `moe_gmm` as they are kept (PR 42). 11.2 GB when this was
+    # written: 7.51 of state, 3.7 of temporaries.
+    cell_kernel_calls = {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+                         "rope_split": 6, "rope_merge": 3, "moe_gmm": 9,
+                         "moe_tgmm": 3, "embed_grad": 1}
+    cell_memory_share = (0.55, 0.75)
+
+
+FAMILY = Olmoe()
+
+
 
 # a small OLMoE under the keys of benchmark/configs/olmoe-1b-7b.json
 SMALL = {
@@ -126,17 +149,6 @@ def test_loss_and_aux_returns_the_routing_statistics(loss_aux_grads):
 
 
 # (c) dispatch alone against the masked dense form: no token dropped
-def _experts(x, weights, idx, *matrices, held=None):
-    """models/gpt.py's two halves of the sparse block as `_moe_block` joins
-    them on one device: the slots' order from the routing decision
-    (`_slot_order`), then the experts over it. -> y, or with a share (y,
-    [1] whether the bounded row space held the routing)."""
-    from ray_tpu.models import gpt
-    order = gpt._slot_order(idx, matrices[0].shape[0], held, x.dtype)
-    out = gpt._experts(x, weights, order, *matrices, held=held)
-    return out[0] if held is None else out
-
-
 def _dense_experts(x, weights, idx, w_gate, w_up, w_down):
     import jax
     import jax.numpy as jnp
@@ -636,3 +648,9 @@ def test_reference_loss_holds_the_program_to_its_logprobs(
         assert median_low > tiny["program_check"]["logprob_median_tol"]
     else:
         assert np.isnan(loss)
+
+# Imported last: a module's names are collected in the order they are bound,
+# so the chip's compiler gets this file's programs after its own tests have
+# run, at another minute of a run than the other families' files.
+from helpers.described_chip import (  # noqa: E402,F401
+    test_cell_step_compiles_under_the_chips_memory)

@@ -190,32 +190,32 @@ def test_shed_surfaces_as_http_503(serve_app):
     serve.run(Busy.bind(), name="ft4", route_prefix="/shed")
     time.sleep(1.0)
 
-    codes, bodies = [], []
+    # one (code, body) pair a request: four threads appending to two lists
+    # interleave, and a request that fails outright has no body at all
+    answers = []
 
     def hit():
         try:
             with urllib.request.urlopen(
                     "http://127.0.0.1:8000/shed", timeout=30) as r:
-                codes.append(r.status)
-                bodies.append(r.read())
+                answers.append((r.status, r.read()))
         except urllib.error.HTTPError as e:
-            codes.append(e.code)
-            bodies.append(e.read())
+            answers.append((e.code, e.read()))
         except Exception as e:  # noqa: BLE001
-            codes.append(repr(e))
+            answers.append((repr(e), None))
 
     deadline = time.time() + 30
-    while time.time() < deadline and 503 not in codes:
-        codes.clear()
-        bodies.clear()
+    while time.time() < deadline and 503 not in dict(answers):
+        answers.clear()
         threads = [threading.Thread(target=hit) for _ in range(4)]
         for t in threads:
             t.start()
         for t in threads:
             t.join(45)
+    codes = [code for code, _body in answers]
     assert 503 in codes, codes
     assert 200 in codes, codes   # the admitted request succeeded
-    shed_body = json.loads(bodies[codes.index(503)])
+    shed_body = json.loads(dict(answers)[503])
     assert shed_body["error"] == "BackPressureError"
     assert shed_body["code"] == "RESOURCE_EXHAUSTED"
 

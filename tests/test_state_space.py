@@ -3,36 +3,157 @@
 mixer, latent relu^2 experts, grouped-query attention without rotation) with
 a multi-token prediction module (models/gpt.py) against the plain float32
 reference of benchmark/families/nemotron_h.py, at a small size on the CPU:
-seeded random weights, the kernels in interpret mode."""
+seeded random weights, the kernels in interpret mode. The checks every
+family has are tests/helpers/families.py's, given this file's FAMILY; the
+cell's sparse block compiles for a described chip at the end."""
 
 import copy
-import json
-import os
-import re
-import sys
 
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+from helpers.described_chip import v5e  # noqa: F401 — a fixture
+from helpers.families import (  # noqa: F401 — fixtures and shared checks
+    Family, case, family, read, steps_agree,
+    test_configuration_file_keeps_the_catalog_and_states_the_cut,
+    test_every_new_leaf_gets_its_rule,
+    test_param_count_is_the_published_model_and_the_programs_tree
+    as test_param_count_is_the_cut_and_the_programs_tree,
+    test_sharded_step_equals_one_device,
+    test_the_configuration_refuses_by_name,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step, tiny)
 
-CELL = "nemotron-3-super-120b-a12b"
+
+def _expert_form_of_four():
+    from ray_tpu.models.gpt import ExpertForm
+    return {"expert_form": ExpertForm(matrices=4)}
 
 
-def _read(*parts):
-    with open(os.path.join(ROOT, *parts)) as f:
-        return json.load(f)
-
-
-@pytest.fixture(scope="module")
-def tiny():
+class NemotronH(Family):
     """benchmark/rehearsal/configs/tiny-nemotron-h.json: the pattern MEM*E
     and a prediction module *E; Mamba heads 4..7 of 8 at 32 with group 1 of
     2, state 64; query heads 2 on 1 of 4 on 2 at 32; experts 4..7 of 16
     held, 4 a token, width 96 in a latent 64, beside a shared one of 192."""
-    return _read("benchmark", "rehearsal", "configs", "tiny-nemotron-h.json")
+
+    name, tiny, cell = ("nemotron_h", "tiny-nemotron-h",
+                        "nemotron-3-super-120b-a12b")
+
+    def tree(self, jax, config):
+        return jax.eval_shape(
+            lambda: self.module.program(config).init(jax.random.PRNGKey(0)))
+
+    cell_params = 838_249_968       # 838M +- 1 %
+
+    def published(self, cell, tiny_tree):
+        family = self.module
+        assert abs(family.param_count(cell) - 838.2e6) < 0.01 * 838.2e6
+        # the "A12B" of the name: at the published sizes, every layer and the
+        # module, 22 experts a token
+        whole = {**cell, **cell["published"], "share": None}
+        assert 120e9 < family.param_count(whole) < 125e9
+        assert 12e9 < family.active_param_count(whole) < 13.5e9
+
+    def rules(self, specs, column, row):
+        from jax.sharding import PartitionSpec as P
+        ssm, moe = specs["layers"][0]["ssm"], specs["layers"][1]["moe"]
+        assert ssm["w_z"] == ssm["w_dt"] == P(*column)
+        assert ssm["w_out"] == specs["layers"][3]["attn"]["wo"] == P(*row)
+        assert ssm["w_xbc"] == P(column[0], None)
+        assert ssm["conv"] == P(None, None) and ssm["conv_bias"] == P(None)
+        assert ssm["a_log"] == ssm["dt_bias"] == ssm["d"] == P("tensor")
+        assert ssm["norm"]["scale"] == P("tensor")
+        assert moe["w_latent_in"] == P(column[0], None)
+        assert moe["w_latent_out"] == P(None, column[0])
+        assert moe["shared"]["w_up"] == P(*column)
+        assert "w_gate" not in moe and "w_gate" not in moe["shared"]
+        module = specs["mtp"]
+        assert module["proj"] == P(column[0], None)
+        assert module["layers"][0]["attn"]["wq"] == P(*column)
+        assert module["layers"][1]["moe"]["w_latent_in"] == P(column[0], None)
+
+    def sharded_step(self, jax, tiny):
+        """A state-space layer and the module (attention, latent experts,
+        its projection and second loss) on tensor=2: the `ssm/*`,
+        `moe/w_latent_*` and `mtp/*` rows of parallel/sharding.py's table (a
+        CPU mesh: no chip claim)."""
+        steps_agree(jax, self, dict(
+            tiny, num_hidden_layers=1, hybrid_override_pattern="M",
+            num_attention_heads=4, num_key_value_heads=2), rows=2,
+            strategy="tp", axes={"data": 1, "tensor": 2}, atol=2e-6)
+
+    refusals = [
+        case(({"attention": "ring"}, "'ssm' layer's state.*attention='ring'"),
+             "ring"),
+        case(({"ssm": None}, "'ssm' layers need their sizes"), "no_sizes"),
+        case(({"route_from": "input"}, "an 'ff' layer has none"),
+             "route_ahead"),
+        case(({"layer_kinds": ("ssm", "mamba", "ff", "ff", "ff")},
+              "'attention_alone'"), "kinds_names"),
+        case((_expert_form_of_four, "matrices 2"), "form"),
+    ]
+
+    def scopes_config(self, tiny):
+        return self.module._train_config(dict(
+            tiny, num_hidden_layers=2, hybrid_override_pattern="ME"))
+
+    def scopes(self, names, regions):
+        from ray_tpu.util import profiling
+        assert {"ssm", "ssm_core", "moe_latent", "mtp"} <= set(
+            profiling.REGIONS)
+        assert {"ssm", "ssm_core", "moe_latent", "mtp", "moe", "moe_route",
+                "moe_shared", "attn_proj", "attn_core", "attn_out", "head",
+                "embed"} <= regions
+        for n in names:
+            if "conv_silu" in n:
+                assert profiling._last_of(n, profiling.REGIONS) == "ssm"
+        # the scan over the chunk states is ssm_core's, forward and
+        # transposed, and it is not run a second time under the remat
+        scans = [n for n in names if "/ssm_core/" in n and "while" in n]
+        assert any("transpose(" not in n for n in scans)
+        assert any("transpose(" in n for n in scans)
+        assert not any("rematted_computation" in n and "transpose(" not in n
+                       for n in scans)
+
+    reduced = {"num_hidden_layers", "hybrid_override_pattern",
+               "n_routed_experts", "vocab_size", "num_attention_heads",
+               "num_key_value_heads", "mamba_num_heads", "n_groups"}
+
+    def cut(self, cell, row, bench):
+        # published layers 26..36: one whole period in the published 5 : 5 : 1
+        pattern = row["config"]["hybrid_override_pattern"]
+        assert cell["hybrid_override_pattern"] == pattern[26:37] \
+            == "EMEMEMEMEM*"
+        assert [pattern.count(c) for c in "ME*"] == [40, 40, 8]
+        share = cell["share"]
+        assert share["expert_parallel"] == share["chips_per_layer"] == 64
+        assert share["expert_parallel"] * cell["n_routed_experts"] \
+            == share["n_routed_experts"] == 512
+        for key in ("vocab_size", "num_attention_heads", "mamba_num_heads",
+                    "n_groups"):
+            assert share["tensor_parallel"] * cell[key] == share[key] \
+                == row["config"][key], key
+        # a key/value head is repeated over four chips of the group
+        assert cell["num_key_value_heads"] == 1 \
+            and share["num_key_value_heads"] == row["config"][
+                "num_key_value_heads"] == 2
+        assert cell["expand"] * cell["hidden_size"] \
+            == share["mamba_num_heads"] * cell["mamba_head_dim"]
+        assert {"no_rotation", "latent_experts", "mtp", "ssm_init",
+                "sequence_length"} <= set(cell["assumed"])
+        for key in ("source", "share", "reduced", "published", "reduced_why",
+                    "distorts", "assumed", "departures", "deployment",
+                    "train", "program_check"):
+            assert key in cell or key in cell["reduced_why"], key
+
+    # 1 x 8192 tokens x 22 a token, 8 of 512 held, in a latent width of
+    # 1024: 2816 expected in 128-row tiles, 2 x 22 + 8 = 52 tiles (6656 rows)
+    # against 1416 (181 248). k - 1 = 21 rows past a block are two sublane
+    # tiles of bfloat16: the run sum's halo follows k (ops/moe.py:_run_halo)
+    row_spaces = (128, 52, 1416)
+
+
+FAMILY = NemotronH()
+CELL = FAMILY.cell
 
 
 def _worst(jax, got, want):
@@ -44,6 +165,7 @@ def _worst(jax, got, want):
 # ---------------------------------------------------------------------------
 # (a) the scan: chunked against a token a step
 # ---------------------------------------------------------------------------
+
 
 def _scan_inputs(jax, seq, groups, step, seed=0):
     """x [2, seq, 4, 8], steps of about `step`, rates over 1..16."""
@@ -110,6 +232,7 @@ def test_scan_keeps_the_inputs_type_and_names_what_remat_keeps(jax_cpu):
 # (b) the plain filter with a bias a channel
 # ---------------------------------------------------------------------------
 
+
 @pytest.mark.parametrize("dtype,shape,kernels", [
     ("float32", (2, 64, 256), True),
     ("bfloat16", (1, 128, 128), True),
@@ -151,6 +274,7 @@ def test_plain_filter_with_a_bias_matches_jnp(jax_cpu, dtype, shape, kernels):
 # ---------------------------------------------------------------------------
 # (c) the program against the family's reference
 # ---------------------------------------------------------------------------
+
 
 @pytest.fixture(scope="module")
 def small(tiny):
@@ -320,6 +444,7 @@ def test_bfloat16_step_passes_the_per_token_check(jax_cpu, small, seeded):
 # (d) the share ties to the model
 # ---------------------------------------------------------------------------
 
+
 @pytest.mark.parametrize("kind", ["ssm", "attention", "experts"])
 def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(jax_cpu, tiny,
                                                              kind):
@@ -431,28 +556,11 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(jax_cpu, tiny,
     np.testing.assert_allclose(total, want, atol=5e-5)
 
 
-def test_param_count_is_the_cut_and_the_programs_tree(jax_cpu, tiny):
-    jax = jax_cpu
-    from benchmark.families import nemotron_h as family
-    from ray_tpu.models.gpt import count_params
-    for config in (tiny, _read("benchmark", "configs", CELL + ".json")):
-        tree = jax.eval_shape(
-            lambda: family.program(config).init(jax.random.PRNGKey(0)))
-        assert count_params(tree) == family.param_count(config)
-    assert family.param_count(config) == 838_249_968      # 838M +- 1 %
-    assert abs(family.param_count(config) - 838.2e6) < 0.01 * 838.2e6
-    # the "A12B" of the name: at the published sizes, every layer and the
-    # module, 22 experts a token
-    whole = {**config, **config["published"], "share": None}
-    assert 120e9 < family.param_count(whole) < 125e9
-    assert 12e9 < family.active_param_count(whole) < 13.5e9
-
-
 def test_flops_and_kernel_arithmetic_count_what_is_computed_here():
     from benchmark.families import nemotron_h as family
     from benchmark.kernels import gqa_attention, kda, ssd
-    cell = _read("benchmark", "configs", CELL + ".json")
-    mix = _read("benchmark", "traffic", "train_b1_s8192_dp.json")
+    cell = read("benchmark", "configs", CELL + ".json")
+    mix = read("benchmark", "traffic", "train_b1_s8192_dp.json")
     forward = family.train_flops_per_token(cell, 8192) / 3.0
     assert forward == pytest.approx(1.19e9, rel=0.01)
     assert family.forward_flops_per_token(cell, 8192) == forward
@@ -479,102 +587,8 @@ def test_flops_and_kernel_arithmetic_count_what_is_computed_here():
 
 
 # ---------------------------------------------------------------------------
-# (e) sharding, refusals, scopes
+# (e) refusals
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("strategy,column,row", [
-    ("tp", (None, "tensor"), ("tensor", None)),
-    ("tp_fsdp", ("fsdp", "tensor"), ("tensor", "fsdp"))])
-def test_every_new_leaf_gets_its_rule(jax_cpu, tiny, strategy, column, row):
-    jax = jax_cpu
-    from jax.sharding import PartitionSpec as P
-    from benchmark.families import nemotron_h as family
-    from ray_tpu.models.gpt import GPTConfig, gpt_init
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-    from ray_tpu.parallel.sharding import strategy_from_name
-    cfg = GPTConfig(**family.gpt_config_kwargs(tiny))
-    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
-    mesh = build_mesh(MeshConfig(data=1, fsdp=2, tensor=2),
-                      devices=jax.devices()[:4])
-    specs = jax.tree_util.tree_map(
-        lambda s: s.spec,
-        strategy_from_name(strategy).param_shardings(mesh, params))
-    ssm, moe = specs["layers"][0]["ssm"], specs["layers"][1]["moe"]
-    assert ssm["w_z"] == ssm["w_dt"] == P(*column)
-    assert ssm["w_out"] == specs["layers"][3]["attn"]["wo"] == P(*row)
-    assert ssm["w_xbc"] == P(column[0], None)
-    assert ssm["conv"] == P(None, None) and ssm["conv_bias"] == P(None)
-    assert ssm["a_log"] == ssm["dt_bias"] == ssm["d"] == P("tensor")
-    assert ssm["norm"]["scale"] == P("tensor")
-    assert moe["w_latent_in"] == P(column[0], None)
-    assert moe["w_latent_out"] == P(None, column[0])
-    assert moe["shared"]["w_up"] == P(*column)
-    assert "w_gate" not in moe and "w_gate" not in moe["shared"]
-    module = specs["mtp"]
-    assert module["proj"] == P(column[0], None)
-    assert module["layers"][0]["attn"]["wq"] == P(*column)
-    assert module["layers"][1]["moe"]["w_latent_in"] == P(column[0], None)
-
-
-def test_sharded_step_equals_one_device(jax_cpu, tiny):
-    """One step of a state-space layer and the module (attention, latent
-    experts, its projection and second loss) on tensor=2 equals
-    the one-device step: the `ssm/*`, `moe/w_latent_*` and `mtp/*` rows of
-    parallel/sharding.py's table (a CPU mesh: no chip claim)."""
-    jax = jax_cpu
-    import jax.numpy as jnp
-    import optax
-    from benchmark.families import nemotron_h as family
-    from ray_tpu.models.gpt import GPTConfig, gpt_init, gpt_loss
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-    from ray_tpu.parallel.sharding import strategy_from_name
-    from ray_tpu.train.train_step import init_train_state, make_train_step
-    cfg = GPTConfig(**family.gpt_config_kwargs(dict(
-        tiny, num_hidden_layers=1, hybrid_override_pattern="M",
-        num_attention_heads=4, num_key_value_heads=2)), dtype=jnp.float32,
-        attention="flash")
-    tokens = jnp.asarray(np.random.default_rng(5).integers(
-        0, 512, (2, 129), dtype=np.int32))
-
-    def one_step(name, axes, n):
-        mesh = build_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
-        strategy = strategy_from_name(name)
-        optimizer = optax.sgd(0.1)
-        state = init_train_state(
-            lambda: gpt_init(jax.random.PRNGKey(3), cfg), optimizer, mesh,
-            strategy)
-        step = make_train_step(
-            lambda p, b: gpt_loss(
-                p, b, cfg, mesh=mesh,
-                act_sharding=strategy.activation_sharding(mesh)),
-            optimizer, mesh, strategy, sample_params=state.params)
-        with jax.default_matmul_precision("highest"):
-            state, metrics = step(state, {"tokens": tokens})
-        return float(metrics["loss"]), jax.device_get(state.params)
-
-    ref_loss, ref_params = one_step("dp", {"data": 1}, 1)
-    loss, params = one_step("tp", {"data": 1, "tensor": 2}, 2)
-    assert abs(loss - ref_loss) < 1e-5
-    for (path, p), r in zip(jax.tree_util.tree_flatten_with_path(params)[0],
-                            jax.tree_util.tree_leaves(ref_params)):
-        np.testing.assert_allclose(p, r, rtol=1e-4, atol=2e-6,
-                                   err_msg=jax.tree_util.keystr(path))
-
-
-@pytest.mark.parametrize("change,says", [
-    ({"attention": "ring"}, "'ssm' layer's state.*attention='ring'"),
-    ({"ssm": None}, "'ssm' layers need their sizes"),
-    ({"route_from": "input"}, "an 'ff' layer has none"),
-    ({"layer_kinds": ("ssm", "mamba", "ff", "ff", "ff")}, "'attention_alone'"),
-    ({"expert_form": "two"}, None),
-], ids=["ring", "no_sizes", "route_ahead", "kinds_names", "form"])
-def test_the_configuration_refuses_by_name(tiny, change, says):
-    from benchmark.families import nemotron_h as family
-    from ray_tpu.models.gpt import ExpertForm, GPTConfig
-    if says is None:
-        change, says = {"expert_form": ExpertForm(matrices=4)}, "matrices 2"
-    with pytest.raises(ValueError, match=says):
-        GPTConfig(**dict(family.gpt_config_kwargs(tiny), **change))
 
 
 def test_pipeline_refuses_a_state_and_a_second_stream(jax_cpu, tiny):
@@ -602,79 +616,8 @@ def test_the_existing_configurations_have_none_of_it():
     relu = GPTConfig(gate_activation="relu")
     assert relu.feed_forward.activation == "relu"
 
-
-def test_the_new_scopes_are_regions_and_reach_the_compiled_step(jax_cpu,
-                                                                tiny):
-    jax = jax_cpu
-    import jax.numpy as jnp
-    from benchmark.families import nemotron_h as family
-    from ray_tpu.models.gpt import gpt_init, gpt_loss
-    from ray_tpu.util import profiling
-    assert {"ssm", "ssm_core", "moe_latent", "mtp"} <= set(profiling.REGIONS)
-    cfg = family._train_config(dict(tiny, num_hidden_layers=2,
-                                    hybrid_override_pattern="ME"))
-    params = gpt_init(jax.random.PRNGKey(0), cfg)
-    text = jax.jit(jax.grad(lambda p, t: gpt_loss(p, {"tokens": t}, cfg))
-                   ).lower(params, jnp.zeros((2, 129), jnp.int32)
-                           ).compile().as_text()
-    names = set(re.findall(r'op_name="([^"]*)"', text))
-    regions = {profiling._last_of(n, profiling.REGIONS) for n in names}
-    assert {"ssm", "ssm_core", "moe_latent", "mtp", "moe", "moe_route",
-            "moe_shared", "attn_proj", "attn_core", "attn_out", "head",
-            "embed"} <= regions
-    for n in names:
-        if "conv_silu" in n:
-            assert profiling._last_of(n, profiling.REGIONS) == "ssm"
-    # the scan over the chunk states is ssm_core's, forward and transposed,
-    # and it is not run a second time under the remat
-    scans = [n for n in names if "/ssm_core/" in n and "while" in n]
-    assert any("transpose(" not in n for n in scans)
-    assert any("transpose(" in n for n in scans)
-    assert not any("rematted_computation" in n and "transpose(" not in n
-                   for n in scans)
-
-
-def test_configuration_file_keeps_the_catalog_and_states_the_cut():
-    cell = _read("benchmark", "configs", CELL + ".json")
-    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
-    if not os.path.exists(catalog):
-        pytest.skip("no catalog here")
-    with open(catalog) as f:
-        row = next(r for r in map(json.loads, f)
-                   if r["source_url"] == cell["source"])
-    changed = {k for k, v in row["config"].items() if cell.get(k, "?") != v}
-    assert changed == set(cell["reduced"]) == {
-        "num_hidden_layers", "hybrid_override_pattern", "n_routed_experts",
-        "vocab_size", "num_attention_heads", "num_key_value_heads",
-        "mamba_num_heads", "n_groups"}
-    assert cell["published"] == {k: row["config"][k] for k in cell["reduced"]}
-    # published layers 26..36: one whole period in the published 5 : 5 : 1
-    pattern = row["config"]["hybrid_override_pattern"]
-    assert cell["hybrid_override_pattern"] == pattern[26:37] == "EMEMEMEMEM*"
-    assert [pattern.count(c) for c in "ME*"] == [40, 40, 8]
-    share = cell["share"]
-    assert share["expert_parallel"] == share["chips_per_layer"] == 64
-    assert share["expert_parallel"] * cell["n_routed_experts"] \
-        == share["n_routed_experts"] == 512
-    for key in ("vocab_size", "num_attention_heads", "mamba_num_heads",
-                "n_groups"):
-        assert share["tensor_parallel"] * cell[key] == share[key] \
-            == row["config"][key], key
-    # a key/value head is repeated over four chips of the group
-    assert cell["num_key_value_heads"] == 1 \
-        and share["num_key_value_heads"] == row["config"][
-            "num_key_value_heads"] == 2
-    assert cell["expand"] * cell["hidden_size"] \
-        == share["mamba_num_heads"] * cell["mamba_head_dim"]
-    assert {"no_rotation", "latent_experts", "mtp", "ssm_init",
-            "sequence_length"} <= set(cell["assumed"])
-    for key in ("source", "share", "reduced", "published", "reduced_why",
-                "distorts", "assumed", "departures", "deployment", "train",
-                "program_check"):
-        assert key in cell or key in cell["reduced_why"], key
-    bench = _read("BENCHMARK.json")
-    entry = next(c for c in bench["configs"] if c["name"] == cell["name"])
-    assert entry["reduced"] == cell["reduced"]
-    assert entry["source"] == cell["source"]
-    peak = cell["reduced_why"]["memory_peak_bytes"]
-    assert 0.25 * 16.91e9 < peak["chip"] < 16.91e9
+# Imported last: a module's names are collected in the order they are bound,
+# so the chip's compiler gets this file's programs after its own tests have
+# run, at another minute of a run than the other families' files.
+from helpers.described_chip import (  # noqa: E402,F401
+    test_sparse_layer_compiles_with_both_row_spaces)

@@ -802,3 +802,59 @@ def test_pubsub_order_live_tree_clean():
     write they announce (the kill-actor and remove-pg publishes were
     hoisted above their slow RPC awaits when this pass landed)."""
     assert rule_clean("PUBSUB-ORDER") == []
+
+
+# ---------------------------------------------------------------------------
+# The families' tests: one body a check, one row a family
+# ---------------------------------------------------------------------------
+
+def test_every_family_has_its_row_and_no_test_body_is_copied():
+    """tests/helpers/families.py holds the checks every family has, once.
+    (a) Every module under benchmark/families/ that a configuration names
+    (the cells' and the rehearsal's) has its row in FILES, and the file the
+    row names defines FAMILY for that module. (b) No two files under tests/
+    define a test of one name with one body: a new family imports the shared
+    check and gives it data, it does not copy the last family's file."""
+    import ast
+    import collections
+    import glob
+
+    tests = os.path.join(REPO, "tests")
+    sys.path.insert(0, tests)
+    try:
+        families = importlib.import_module("helpers.families")
+        named = set()
+        for path in glob.glob(os.path.join(REPO, "benchmark", "configs",
+                                           "*.json")) + glob.glob(
+                os.path.join(REPO, "benchmark", "rehearsal", "configs",
+                             "*.json")):
+            with open(path) as f:
+                named.add(json.load(f).get("family", "gpt_dense"))
+        assert named and named <= set(families.FILES), \
+            named - set(families.FILES)
+        for name in named:
+            assert os.path.exists(os.path.join(
+                REPO, "benchmark", "families", name + ".py")), name
+            if families.FILES[name] is None:
+                continue
+            assert os.path.exists(os.path.join(tests, families.FILES[name]))
+            row = importlib.import_module(families.FILES[name][:-3]).FAMILY
+            assert isinstance(row, families.Family) and row.name == name
+    finally:
+        sys.path.remove(tests)
+
+    defined = collections.defaultdict(list)
+    for path in sorted(glob.glob(os.path.join(tests, "test_*.py"))):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in tree.body:
+            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)) \
+                    and node.name.startswith("test_"):
+                body = node.body
+                if ast.get_docstring(node) is not None:
+                    body = body[1:]
+                defined[node.name, "".join(map(ast.dump, body))].append(
+                    os.path.basename(path))
+    copied = {name: files for (name, _body), files in defined.items()
+              if len(files) > 1}
+    assert not copied, copied

@@ -1,0 +1,321 @@
+"""A stack of window and full attention layers with a head count of their
+own a kind, a rotation a kind and a gate a head (models/gpt.py) against the
+plain float32 reference of benchmark/families/laguna.py, at a small size on
+the CPU: seeded random weights, the kernels in interpret mode. The checks
+every family has are tests/helpers/families.py's, given this file's FAMILY;
+the kernels alone and the cell's compile for a described chip:
+tests/test_window_attention.py."""
+
+import copy
+
+import pytest
+
+from helpers.families import (  # noqa: F401 — fixtures and shared checks
+    Family, case, family, read, reference, seeded, step_kernel_calls,
+    test_bfloat16_step_passes_the_per_token_check,
+    test_configuration_file_keeps_the_catalog_and_states_the_cut,
+    test_every_new_leaf_gets_its_rule,
+    test_logits_loss_and_gradients_match_the_reference,
+    test_param_count_is_the_published_model_and_the_programs_tree,
+    test_pipeline_refuses_by_name, test_sharded_step_equals_one_device,
+    test_the_configuration_refuses_by_name,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step,
+    test_the_reference_tells_each_mechanism_apart,
+    test_the_shares_of_a_layer_add_up_to_the_uncut_reference, tiny)
+
+
+class Laguna(Family):
+    """benchmark/rehearsal/configs/tiny-laguna.json: full attention + dense,
+    then sliding x 3 and full with experts 4..7 of 16 held, 2 a token; 6
+    (full) and 8 (sliding) query heads of 32 on 2 key/value heads, window
+    24, half a head rotated with YaRN frequencies on the full layers."""
+
+    name, tiny, cell = "laguna", "tiny-laguna", "laguna-xs.2"
+    workload = "laguna_train_1chip"
+
+    # Both kinds of attention layer in one stack, two head counts on one
+    # key/value head count, the partial YaRN rotation with its attention
+    # factor beside the plain one, the window, the gate a head, a dense layer
+    # and then experts beside a shared one, in float32: the whole tree of
+    # gradients. On the flash path the full layers' q and k columns are
+    # permuted in the weights and three head counts go through rope_split.
+    logits_atol, grads_atol = 5e-5, 2e-5
+
+    def built(self, cfg, params):
+        assert [sorted(layer) for layer in params["layers"]] == [
+            ["attn", "ln1", "ln2", "mlp"]] + [
+            ["ln1", "ln2", "moe", "window_attn"]] * 3 + [
+            ["attn", "ln1", "ln2", "moe"]]
+        full, sliding = (params["layers"][0]["attn"],
+                         params["layers"][1]["window_attn"])
+        assert cfg.head_dim == 32 and cfg.d_model == 128
+        assert full["wq"].shape == (128, 6 * 32) \
+            and full["wg"].shape == (128, 6)
+        assert sliding["wq"].shape == (128, 8 * 32)
+        assert sliding["wo"].shape == (8 * 32, 128)
+        assert sliding["wg"].shape == (128, 8)
+        assert full["wk"].shape == sliding["wv"].shape == (128, 2 * 32)
+        assert params["layers"][1]["moe"]["w_up"].shape == (4, 128, 64)
+        assert params["layers"][1]["moe"]["router"].shape == (128, 16)
+        assert params["lm_head"].shape == (128, 512)                # untied
+
+    def statistics(self, aux, loss, reference):
+        assert float(loss) == float(aux["xent"])        # no router loss
+        assert 0.0 < float(aux["expert_slots_held_share"]) < 1.0
+
+    def moves(self, name):
+        return True
+
+    def other_configurations(self, tiny):
+        """(A window of 23 and of 25 for 24 stand in for the cell's 511 and
+        513.)"""
+        rope = tiny["rope_parameters"]
+
+        def with_rope(kind, **change):
+            changed = copy.deepcopy(tiny)
+            changed["rope_parameters"][kind] = dict(rope[kind], **change)
+            return changed
+        return {
+            "causal_whole": dict(tiny, sliding_window=10 ** 9),
+            "window_23": dict(tiny, sliding_window=23),
+            "window_25": dict(tiny, sliding_window=25),
+            "sliding_table_on_full": with_rope(
+                "full_attention", **rope["sliding_attention"]),
+            "full_table_on_sliding": with_rope(
+                "sliding_attention", **rope["full_attention"]),
+            "whole_head_rotated": with_rope("full_attention",
+                                            partial_rotary_factor=1),
+            "no_attention_factor": with_rope("full_attention",
+                                             attention_factor=1.0),
+            "yarn_not_blended": with_rope("full_attention",
+                                          rope_type="default"),
+            "no_gate": dict(tiny, gating=False),
+            "unscaled": dict(tiny, moe_routed_scaling_factor=1.0),
+        }
+
+    def faults(self, jax, tiny, params):
+        import jax.numpy as jnp
+        return [("kv_head_h_mod",
+                 {"_kv_head_of": lambda h, kv: jnp.arange(h) % kv}, False)]
+
+    # the program's own forward: bf16, the window and the full flash
+    # kernels, the grouped-matmul kernels
+    bf16_bounds = {"logprob_median_tol": 0.08, "logprob_rms_tol": 0.5}
+
+    # a whole sparse sliding-window layer, attention, gate and residual
+    # included: every chip computes attention, the residual and the shared
+    # expert alike
+    experts_key, shared_layer = "num_experts", 2
+
+    def shared_layer_is(self, layer):
+        assert sorted(layer) == ["ln1", "ln2", "moe", "window_attn"]
+
+    def uncut_layer(self, jax, layer, x, whole):
+        import jax.numpy as jnp
+        laguna = self.module
+
+        def reference_layer(h):
+            h = h + laguna.reference_attention(
+                layer["window_attn"],
+                laguna._norm(h, layer["ln1"]["scale"], 1e-6), whole,
+                "sliding_attention", 8)
+            m = laguna._norm(h, layer["ln2"]["scale"], 1e-6)
+            shared = laguna._swiglu(layer["moe"]["shared"], m, jnp.float32)
+            return h + shared, h + laguna.reference_experts(layer["moe"], m,
+                                                            whole)
+        return jax.vmap(reference_layer)(x)
+
+    cell_params, cell_share = 691_623_936, (0, 32, 256)
+
+    def published(self, cell, tiny_tree):
+        # the published model: 33.4B, 3.0B a token, its name (33.4B-A3B). The
+        # gate a head (assumed) is 4.7M of it; an element-wise gate
+        # [d, H x D] would add 0.63B and make it 34.1B
+        laguna = self.module
+        published = {k: v for k, v in cell.items() if k != "share"}
+        published.update(cell["published"])
+        assert round(laguna.param_count(published) / 1e9, 1) == 33.4
+        assert round(laguna.active_param_count(published) / 1e9, 1) == 3.0
+        gates = 2048 * (10 * 48 + 30 * 64)
+        assert gates == 4_915_200
+        assert round((laguna.param_count(published) - gates
+                      + 2048 * 128 * (10 * 48 + 30 * 64)) / 1e9, 1) == 34.1
+
+    def rules(self, specs, column, row):
+        from jax.sharding import PartitionSpec as P
+        # a window layer's matrices find attention's rules; the gate's
+        # columns are heads
+        for attn in (specs["layers"][0]["attn"],
+                     specs["layers"][1]["window_attn"]):
+            assert attn["wq"] == attn["wk"] == attn["wv"] == attn["wg"] \
+                == P(*column)
+            assert attn["wo"] == P(*row)
+
+    def sharded_step(self, jax, tiny):
+        """fsdp=2 x tensor=2: a key/value head with its three or four query
+        heads and their gates on a shard of `tensor`, the kernels per
+        shard."""
+        Family.sharded_step(self, jax, tiny)
+
+    refusals = [
+        case(({"attention": "ring"}, "'window' layer.*attention='ring'"),
+             "ring"),
+        case(({"attention_window": 0}, "attention_window=0"), "no_window"),
+        case(({"window_heads": 7}, "n_kv_heads=2 does not divide n_heads=7"),
+             "window_heads"),
+        case(({"qk_head_norm": True},
+              "'window' layer.*qk_norm or qk_head_norm"), "head_norm"),
+        case(({"layer_kinds": ("attention", "swa", "swa", "swa",
+                               "attention")},
+              "'attention' | 'conv' | 'window'"), "kinds_names"),
+    ]
+    pipeline_refusals = [
+        case(({"layer_kinds": ("window",) * 5, "n_experts": 0,
+               "dense_layers": 0, "experts_held": None,
+               "attention_gate": False}, {"pipeline": 1},
+              "no sliding-window layers"), "window_layers"),
+        case(({"layer_kinds": None, "n_experts": 0, "dense_layers": 0,
+               "experts_held": None}, {"pipeline": 1},
+              "no rule for a gate a head"), "gate"),
+    ]
+
+    def scopes(self, names, regions):
+        from ray_tpu.util import profiling
+        assert {"attn_window", "attn_gate"} <= set(profiling.REGIONS)
+        assert {"attn_window", "attn_gate", "attn_proj", "attn_core",
+                "attn_out", "moe", "moe_route", "moe_shared",
+                "mlp"} <= regions
+        # the window layers' kernels are attn_window's, the full layers'
+        # attn_core's; the gate's matmul is attn_gate's, not attn_out's
+        for n in names:
+            if "flash_win_" in n:
+                assert profiling._last_of(n, profiling.REGIONS) \
+                    == "attn_window"
+            elif "flash_" in n:
+                assert profiling._last_of(n, profiling.REGIONS) == "attn_core"
+        assert any("attn_gate/bsd,dh->bsh" in n for n in names)
+
+    reduced = {"num_hidden_layers", "num_experts", "vocab_size",
+               "layer_types", "mlp_layer_types",
+               "num_attention_heads_per_layer"}
+
+    def cut(self, cell, row, bench):
+        # published layers 0..4: the leading dense layer, then a whole period
+        for key in ("layer_types", "mlp_layer_types",
+                    "num_attention_heads_per_layer"):
+            assert cell[key] == row["config"][key][:5]
+        assert cell["layer_types"] == ["full_attention"] \
+            + ["sliding_attention"] * 3 + ["full_attention"]
+        assert cell["num_attention_heads_per_layer"] == [48, 64, 64, 64, 48]
+        assert cell["share"]["chips_per_layer"] * cell["num_experts"] \
+            == cell["share"]["num_experts"] == 256
+        assert cell["share"]["chips_per_layer"] * cell["vocab_size"] == 100352
+        assert {"gating", "router_score", "sequence_length"} <= set(
+            cell["assumed"])
+
+    # laguna_train_1chip: full attention (48 query heads on 8) with the
+    # dense MLP, three sliding-window layers (64 on 8, window 512) and a
+    # full one with 32 of 256 experts held. The window layers' kernels
+    # carry names of their own and run, like the full layers', once a layer
+    # (kept through the remat); q, k, v through rope_split at three head
+    # counts. 14.22 GB when this was written: 8.30 of state, 5.92 of
+    # temporaries.
+    cell_kernel_calls = {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2,
+                         "flash_win_fwd": 3, "flash_win_bwd_dq": 3,
+                         "flash_win_bwd_dkv": 3, "rope_split": 30,
+                         "rope_merge": 15, "moe_gmm": 72, "moe_tgmm": 24,
+                         "embed_grad": 1}
+    cell_memory_share = (0.78, 0.92)
+
+
+FAMILY = Laguna()
+
+
+def test_flops_and_kernel_arithmetic_count_what_is_computed_here():
+    from benchmark.families import laguna
+    from benchmark.kernels import gqa_attention, window_attention
+    cell = read("benchmark", "configs", "laguna-xs.2.json")
+    mix = read("benchmark", "traffic", "train_b2_s8192_dp.json")
+    d, s, w = 2048, 8192, 512
+    full = 2 * d * 48 * 128 + 2 * d * 8 * 128 + d * 48
+    sliding = 2 * d * 64 * 128 + 2 * d * 8 * 128 + d * 64
+    active = (2 * full + 3 * sliding + 3 * d * 8192
+              + 4 * (d * 256 + 3 * d * 512 + 8 * 32 / 256 * 3 * d * 512)
+              + d * 12544)
+    pairs = s * w - w * (w - 1) // 2
+    assert window_attention.band_pairs(s, w) == pairs == 4_063_488
+    assert laguna.train_flops_per_token(cell, s) == pytest.approx(
+        6.0 * active + 12.0 * 128 * (2 * 48 * s / 2 + 3 * 64 * pairs / s))
+    assert laguna.forward_flops_per_token(cell, s) == pytest.approx(
+        0.80e9, rel=0.01)                  # ISSUE 37's ~0.80 GFLOP a token
+    assert laguna.attention_call(cell, mix) == {
+        "batch": 2, "heads": 48, "kv_heads": 8, "seq": s, "head_dim": 128}
+    assert laguna.window_call(cell, mix) == {
+        "batch": 2, "heads": 64, "kv_heads": 8, "seq": s, "head_dim": 128,
+        "window": w}
+    # the full layers' calls are counted at 48 on 8
+    assert gqa_attention.flash_fwd(cell, mix)[0] == 2 * 2 * 48 * s * s * 128
+    product = 2.0 * pairs * 128 * 2 * 64
+    wide, narrow = 2 * 64 * s * 128 * 2, 2 * 8 * s * 128 * 2
+    fwd, dq, dkv = (f(cell, mix) for f in (
+        window_attention.flash_win_fwd, window_attention.flash_win_bwd_dq,
+        window_attention.flash_win_bwd_dkv))
+    assert fwd == (2 * product, 2 * wide + 2 * narrow)
+    assert dq[0] + dkv[0] == 5 * product          # the backward's five
+    assert dq[1] == 3 * wide + 2 * narrow
+    assert dkv[1] == 2 * wide + 4 * narrow        # dK, dV at 8 heads
+
+
+@pytest.mark.parametrize("seq,window", [(64, 8), (64, 1), (32, 32), (16, 40)])
+def test_band_pairs_is_a_brute_force_count(seq, window):
+    """The rooflines' S W - W (W - 1) / 2 against a count of the pairs
+    mha_reference's mask keeps, and the window kernels' FLOPs with it."""
+    from benchmark.kernels import window_attention
+    kept = sum(1 for i in range(seq) for j in range(seq)
+               if 0 <= i - j < window)
+    assert window_attention.band_pairs(seq, window) == kept
+    config = {"family": "laguna", "num_hidden_layers": 1,
+              "layer_types": ["sliding_attention"],
+              "mlp_layer_types": ["sparse"],
+              "num_attention_heads_per_layer": [4], "num_attention_heads": 2,
+              "num_key_value_heads": 2, "head_dim": 16,
+              "sliding_window": window}
+    mix = {"global_batch": 3, "seq": seq, "mesh": {"data": 1}}
+    flops, moved = window_attention.flash_win_fwd(config, mix)
+    assert flops == 2 * (2 * kept * 16) * 3 * 4       # S and PV, every head
+    assert moved == 2 * (3 * seq * 16 * 2) * (4 + 2)  # Q, O at 4; K, V at 2
+
+
+def test_head_dim_is_a_field_and_defaults_to_the_hidden_size_over_heads():
+    import dataclasses
+    from ray_tpu.models.gpt import GPTConfig
+    assert GPTConfig().head_dim == 64 and GPTConfig.tiny().head_dim == 32
+    wide = GPTConfig(d_model=128, n_heads=6, n_kv_heads=2, head_dim=32)
+    assert wide.head_dim == 32 and wide.qk_head_dim == 32
+    assert dataclasses.replace(wide, n_layers=3).head_dim == 32
+    assert wide.heads_of("window") == wide.heads_of("attention") == 6
+    assert wide.rope_of("window") == wide.rope_of("attention")
+    assert wide.rope_of("attention").plain
+
+
+def test_the_window_engages_and_its_forward_runs_once_a_layer(jax_cpu, tiny):
+    """The step's kernel calls are the counter: 3 of each flash_win_* and 2
+    of each flash_*, and under remat_policy="full" neither forward kernel
+    in a recompute pass (FLASH_OUT and FLASH_LSE are named in the window
+    kernel's forward rule too)."""
+    cfg, calls, _jaxpr = step_kernel_calls(jax_cpu, FAMILY, tiny)
+    assert cfg.remat_policy == "full"
+    for kind, layers in (("flash_win", 3), ("flash", 2)):
+        assert calls[(kind + "_fwd", False)] == layers
+        assert calls[(kind + "_fwd", True)] == 0
+        for kernel in (kind + "_bwd_dq", kind + "_bwd_dkv"):
+            # (the backward pass holds them inside the checkpoint equation)
+            assert calls[(kernel, False)] + calls[(kernel, True)] == layers
+    # what XLA runs is still recomputed: the window layers' q (8 heads of 32
+    # fill lane tiles; 6 and 2 take rope_split's jnp form), split forward
+    # and again in the backward's checkpoint
+    assert calls[("rope_split", False)] == calls[("rope_split", True)] == 3
+
+
+# (bound, and so run, last: see tests/test_conv_gqa_model.py)
+from helpers.families import test_the_cell_rehearses  # noqa: E402,F401
