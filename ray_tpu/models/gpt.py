@@ -1769,14 +1769,18 @@ def _prediction_module(params, h, targets, layer, cfg: GPTConfig,
         return _rmsnorm(g, m["norm"]["scale"], eps), per_layer
 
 
+def _rows_a_chunk(n, chunk_rows):
+    """Never chunk coarser than the batch itself: padding a small batch up
+    to a full 16k-row chunk would both waste LM-head FLOPs and raise the
+    HBM peak the chunking exists to cut."""
+    return min(chunk_rows, max(128, n))
+
+
 def _xent_chunks(x, targets, mask, chunk_rows):
     """Rows [N, ...] -> chunks [n_chunks, chunk_rows, ...], padded with
     masked-out rows."""
     n, d = x.shape
-    # Never chunk coarser than the batch itself: padding a small batch up
-    # to a full 16k-row chunk would both waste LM-head FLOPs and raise the
-    # HBM peak the chunking exists to cut.
-    chunk_rows = min(chunk_rows, max(128, n))
+    chunk_rows = _rows_a_chunk(n, chunk_rows)
     pad = (-n) % chunk_rows
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
@@ -1842,7 +1846,9 @@ def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
     is three vocabulary matmuls a chunk and one softmax; no jax.checkpoint,
     no second logits matmul. The residuals are dx [N, D] (x's dtype),
     dW [D, V] (fp32) and the rows' nll [N], and the backward rule scales
-    them by the cotangents, so nothing of a chunk outlives its iteration.
+    them by the cotangents (and hands the two gradients on together where
+    the compiler would else hold a lone chunk's logits: _chunked_xent_bwd),
+    so nothing of a chunk outlives its iteration.
     Evaluated only, no gradient is computed. Inside a differentiated scan
     use chunked_xent_recompute (its docstring says why)."""
     return chunked_xent_recompute(x, w_head, targets, mask, chunk_rows)
@@ -1877,8 +1883,26 @@ def _chunked_xent_fwd(x, w_head, targets, mask, chunk_rows):
 def _chunked_xent_bwd(chunk_rows, residuals, cotangents):
     dx, dw, nll, w_like = residuals
     g_total, g_denom = cotangents
-    return ((g_total * dx).astype(dx.dtype),
-            (g_total * dw).astype(w_like.dtype),
+    gx = (g_total * dx).astype(dx.dtype)
+    gw = (g_total * dw).astype(w_like.dtype)
+    n, d = dx.shape
+    rows = _rows_a_chunk(n, chunk_rows)
+    if n <= rows and rows * dx.dtype.itemsize < 3 * 4 * d:
+        # A scan of one chunk is inlined into the step, and dW's one reader
+        # is then the optimizer, so the chip's compiler fuses the chunk's
+        # dW product into w_head's update. Its scheduler counts that
+        # update's three float32 results [D, V] as new memory against the
+        # chunk's logits [rows, V] that it frees. Where the logits are the
+        # larger, the fusion stands right after dx and the update's traffic
+        # rides under the product: nothing to add. Where they are the
+        # smaller (rows < 6 D in bf16), it sinks to the end of the step and
+        # the logits are held through the whole backward, or computed
+        # again where memory is short (PERF.md, PR 59). There the two
+        # gradients leave together: tied to dx, which the backward reads
+        # at once, dW is made in the head, scaled and rounded in its
+        # product's epilogue, and the logits die there.
+        gx, gw = jax.lax.optimization_barrier((gx, gw))
+    return (gx, gw,
             np.zeros(nll.shape, jax.dtypes.float0),     # targets: integers
             g_total * nll + g_denom)
 

@@ -213,8 +213,9 @@ def heads_of_64_stay_by_token(text, rows, seq, heads, kv_heads):
 # ---------------------------------------------------------------------------
 
 class CellStep:
-    """A one-chip cell's compiled train step: `text`, `memory`, and the
-    configuration and traffic it was built from."""
+    """A one-chip cell's compiled train step: `text`, `memory`, the
+    parameters' shapes, and the configuration and traffic it was built
+    from."""
 
     def __init__(self, v5e, name):
         import jax
@@ -250,6 +251,7 @@ class CellStep:
             compiled = step.lower(state, batch).compile()
         self.memory = compiled.memory_analysis()
         self.text = compiled.as_text()
+        self.params = params
 
 
 @pytest.fixture(scope="module")
@@ -316,6 +318,66 @@ def test_cell_step_compiles_under_the_chips_memory(cell_step, family, cell):
         if "num_attention_heads_per_layer" not in config:
             layers += kernel_calls.get("flash_win_fwd", 0)
         assert len(made) == 2 * layers, made
+
+
+def fused_computation(text, instruction):
+    """The body of the computation that the fusion `instruction` (a line of
+    the compiled text) calls."""
+    called = re.search(r"calls=(%[\w.\-]+)", instruction).group(1)
+    body = text[text.index(f"\n{called} ("):]
+    return body[:body.index("\n}")]
+
+
+def test_cell_step_makes_a_heads_dw_where_its_logits_are(cell_step, family,
+                                                         cell):
+    """The same compiled step, its `head`: a head's three vocabulary matmuls
+    stand together. The text holds one logits matmul a head (the main one
+    and, where the model has a prediction module, its own), bf16[rows a
+    chunk, vocabulary]; no instruction under `head` is one the compiler
+    made again under memory pressure (`.remat`: its own rematerialisation,
+    which no jaxpr shows); and each head's dW product stands within a
+    hundred instructions of its logits, of the step's thousands, so no
+    logits live through the backward to reach it. Where the rule ties dW
+    to dx (a lone chunk whose logits are smaller than the update's three
+    float32 results: ray_tpu/models/gpt.py:_chunked_xent_bwd), the update
+    of `lm_head`, the fusion that reads its first moment, holds no matmul;
+    elsewhere the compiler puts the product into that fusion right after
+    dx by itself, and a cell that fails here says its scheduler no longer
+    does. (Tied to the embedding table the product waits for the lookup's
+    gradient at the end of the backward whatever is done here: lfm2, whose
+    place for it is not held.)"""
+    assert cell == family.cell
+    mix, text, params = cell_step.mix, cell_step.text, cell_step.params
+    tied = "lm_head" not in params
+    d, vocab = (params["embed"]["table"].shape[::-1] if tied
+                else params["lm_head"].shape)
+    rows = min(mix["global_batch"] * mix["seq"], 16384)
+    # one chunk: the scan is inlined, so the head's instructions that run
+    # as ops of their own are the entry computation's
+    entry = text[text.index("\nENTRY "):].splitlines()
+
+    def under_head(tail):
+        return [at for at, line in enumerate(entry) if re.search(
+            rf'op_name="[^"]*[(/]head[)/][^"]*{tail}"', line)]
+    logits = [at for at in under_head("closed_call/dot_general")
+              if f"bf16[{rows},{vocab}]" in entry[at].split(" fusion(")[0]]
+    assert len(logits) == 1 + ("mtp" in params), [entry[at] for at in logits]
+    again = [line for line in entry if re.match(
+        r"\s*(ROOT )?%[\w.\-]*\.remat[^=]* = .*op_name=\"[^\"]*[(/]head[)/]",
+        line)]
+    assert not again, again
+    if tied:
+        return
+    products = under_head("nd,nv->dv/dot_general")
+    assert len(products) == len(logits)
+    assert all(0 < dw - at < 100 for at, dw in zip(logits, products)), (
+        logits, products, len(entry))
+    if rows * 2 < 3 * 4 * d:
+        updates = [line for line in entry if re.search(
+            r" fusion\([^)]*%state_1__0__mu__lm_head__", line)]
+        assert len(updates) == 1, updates
+        assert not re.search(r" (convolution|dot)\(", fused_computation(
+            text, updates[0])), updates[0][:300]
 
 
 def test_sparse_layer_compiles_with_both_row_spaces(v5e, monkeypatch, family,

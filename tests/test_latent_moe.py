@@ -227,4 +227,5 @@ def test_latent_block_reaches_the_flash_kernels_without_a_layout_pass(
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
     test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_makes_a_heads_dw_where_its_logits_are,
     test_sparse_layer_compiles_with_both_row_spaces)

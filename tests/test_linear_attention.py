@@ -297,4 +297,5 @@ def test_delta_rule_layer_compiles_on_four_chip_mesh(v5e, monkeypatch):
 # so the chip's compiler gets this file's programs after its own tests have
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
-    test_cell_step_compiles_under_the_chips_memory)
+    test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_makes_a_heads_dw_where_its_logits_are)

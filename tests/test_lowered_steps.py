@@ -42,11 +42,25 @@ LOWERED = {
     # 64dedc5a37df64cf, a13b1de35328fc71, 2420d0b4f00749c5 and
     # aa90d217a4905e58 before it: PR 42's masters in `moe_gmm`, and at
     # solar PR 47's `kda_fwd` / `kda_bwd`)
-    "olmoe_train_1chip": "37f86a82ad7f1fa7",
     "kanana2_train_1chip": "8e6cd298cd0a8c16",
     "laguna_train_1chip": "e425c199e1b22258",
     "keye2_train_1chip": "b478ecfb41cd7a16",
-    "solar2_train_1chip": "42d57e72e853172f",
+    # not in the table until PR 59, which leaves it alone (the parent's,
+    # .proof/lower_text.py)
+    "smallthinker_train_1chip": "4fa221fa21dffe03",
+    # recorded anew by PR 59, which means to change exactly these three,
+    # the cells whose one chunk of 8192 rows holds logits smaller than the
+    # head matrix's update writes (models/gpt.py:_chunked_xent_bwd): the
+    # head's backward rule hands dx and dW on through one
+    # `optimization_barrier` a head and nothing else differs (the products,
+    # their operands and their rounding are the parent's), so that the
+    # chip's compiler makes dW beside dx and not inside `lm_head`'s update
+    # at the end of the step. Before it olmoe 37f86a82ad7f1fa7 and solar
+    # 42d57e72e853172f (since PR 48), nemotron 8e65faacc0b38e23 (since PR
+    # 57, not in the table).
+    "olmoe_train_1chip": "857d8b4221a8d29b",
+    "solar2_train_1chip": "3d034416b94d0f0e",
+    "nemotron3s_train_1chip": "9b951c1470dfbaa6",
 }
 
 

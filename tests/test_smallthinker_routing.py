@@ -300,4 +300,5 @@ def test_a_kind_that_rotates_nothing_compiles_without_a_rotation(
 # so the chip's compiler gets this file's programs after its own tests have
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
-    test_cell_step_compiles_under_the_chips_memory)
+    test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_makes_a_heads_dw_where_its_logits_are)

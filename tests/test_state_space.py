@@ -5,14 +5,15 @@ a multi-token prediction module (models/gpt.py) against the plain float32
 reference of benchmark/families/nemotron_h.py, at a small size on the CPU:
 seeded random weights, the kernels in interpret mode. The checks every
 family has are tests/helpers/families.py's, given this file's FAMILY; the
-cell's sparse block compiles for a described chip at the end."""
+cell's sparse block and its whole step compile for a described chip at the
+end."""
 
 import copy
 
 import numpy as np
 import pytest
 
-from helpers.described_chip import v5e  # noqa: F401 — a fixture
+from helpers.described_chip import cell_step, v5e  # noqa: F401 — fixtures
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
     Family, case, family, read, steps_agree,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
@@ -150,6 +151,19 @@ class NemotronH(Family):
     # against 1416 (181 248). k - 1 = 21 rows past a block are two sublane
     # tiles of bfloat16: the run sum's halo follows k (ops/moe.py:_run_halo)
     row_spaces = (128, 52, 1416)
+
+    # the whole step at 1 x 8192 tokens: one attention layer (`*`) of the
+    # cut and one of the module, five Mamba layers, six sparse blocks, two
+    # lookups. 14.75 GB when this was written: 10.06 of state, 4.69 of
+    # temporaries, the tightest cell (75-130 s alone here): the compiler made
+    # the main head's logits three times to fit until dW was made beside dx
+    cell_kernel_calls = {"flash_fwd": 2, "flash_bwd_dq": 2,
+                         "flash_bwd_dkv": 2, "rope_split": 12,
+                         "rope_merge": 6, "conv_silu_fwd": 10,
+                         "conv_silu_bwd": 5, "moe_gmm": 96, "moe_tgmm": 24,
+                         "moe_run_sum": 18, "embed_grad": 2}
+    cell_memory_share = (0.80, 0.92)
+    cell_step_marks = (pytest.mark.timeout(900),)
 
 
 FAMILY = NemotronH()
@@ -620,4 +634,6 @@ def test_the_existing_configurations_have_none_of_it():
 # so the chip's compiler gets this file's programs after its own tests have
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
-    test_sparse_layer_compiles_with_both_row_spaces)
+    test_sparse_layer_compiles_with_both_row_spaces,
+    test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_makes_a_heads_dw_where_its_logits_are)

@@ -12,6 +12,11 @@ import pytest
 # rows, chunk_rows, mask
 SHAPES = {
     "one_chunk": (256, 256, "some"),
+    # what eight of the ten cells run: the rows are fewer than the default
+    # chunk and are the chunk, so the scan is one iteration, which the
+    # compiler inlines into the step; at 144 rows of bf16 under D = 32 the
+    # rule ties dW to dx, as at the cells of 8192 rows
+    "the_rows_are_the_one_chunk": (144, 16384, "some"),
     "several_chunks": (512, 128, "some"),
     "rows_not_a_multiple_of_the_chunk": (300, 128, "some"),
     "fewer_rows_than_the_smallest_chunk": (100, 16384, "ones"),
@@ -165,7 +170,8 @@ def test_forward_bits_are_the_function_before(jax_cpu, shape, dtype):
 
 
 @pytest.mark.parametrize("shape", ["several_chunks",
-                                   "rows_not_a_multiple_of_the_chunk"])
+                                   "rows_not_a_multiple_of_the_chunk",
+                                   "the_rows_are_the_one_chunk"])
 def test_recompute_formulation_has_the_same_gradients(jax_cpu, shape):
     """What parallel/pipeline.py's last rank calls: autodiff through the
     rematted body, equal to the rule's gradients in float32."""
@@ -178,6 +184,36 @@ def test_recompute_formulation_has_the_same_gradients(jax_cpu, shape):
     assert float(loss) == float(want_loss)
     for got, ref in zip(grads, want):
         np.testing.assert_allclose(got, ref, atol=1e-6, rtol=0)
+
+
+@pytest.mark.parametrize("rows,d,dtype,chunk_rows,together", [
+    (128, 32, "bfloat16", 16384, True),     # 256 B of logits a column < 384
+    (256, 32, "bfloat16", 16384, False),    # 512 B: the compiler's own place
+    (128, 32, "float32", 16384, False),     # 512 B in float32 too
+    (128, 64, "float32", 16384, True),      # 512 B < 768
+    (256, 64, "bfloat16", 128, False),      # two chunks: a real loop
+], ids=["8192_rows_of_bf16_at_olmoe", "16384_rows", "float32",
+        "float32_and_wider", "a_real_loop"])
+def test_the_gradients_leave_together_where_a_lone_chunks_logits_are_smaller(
+        jax_cpu, rows, d, dtype, chunk_rows, together):
+    """The rule's backward ties dW to dx (one optimization_barrier) exactly
+    where the scan is one chunk whose logits, a vocabulary column, are
+    fewer bytes than the three float32 results of w_head's update: the
+    shapes at which the chip's compiler would hold the logits to the end of
+    the step (at a cell's scale: 8192 rows of bf16 under a model 2048 or
+    4096 wide, and not 16 384 rows under 2048 or 2560). Everywhere else the
+    step is the program it was."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.models.gpt import chunked_xent
+    x = jnp.ones((rows, d), jnp.dtype(dtype))
+    w = jnp.ones((d, V), jnp.dtype(dtype))
+    targets = jnp.zeros((rows,), jnp.int32)
+    mask = jnp.ones((rows,), jnp.float32)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda x, w: chunked_xent(x, w, targets, mask, chunk_rows)[0],
+        argnums=(0, 1)))(x, w))
+    assert text.count("optimization_barrier") == int(together)
 
 
 # ------------------------------------------------------- through the model
