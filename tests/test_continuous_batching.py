@@ -434,8 +434,11 @@ def test_decorator_submits_and_streams():
         assert a == [0, 1, 2] and b == [0, 1]
         sched = getattr(m, "__serve_cb_scheduler_step")
         assert sched.stats()["retired_total"] == 2
-        # Shared state proves BOTH requests rode one scheduler/batch.
+        # Shared state proves BOTH requests rode one scheduler/batch,
+        # and both phases' step times were recorded.
         assert sched.stats()["occupancy_mean"] > 1.0
+        assert sched.stats()["occupancy_p50"] > 1.0
+        assert set(sched.stats()["step_ms"]) >= {PREFILL, DECODE}
 
     asyncio.run(run())
 

@@ -11,10 +11,14 @@ interop, and correctness under injected RPC delays.
 
 import asyncio
 import os
+import subprocess
+import sys
 
 import pytest
 
 from ray_tpu._private import rpc
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run(coro):
@@ -425,6 +429,48 @@ class TestClusterBatching:
         # ready() on an ALREADY-created pg resolves via the state fetch.
         assert ray_tpu.get(pg.ready(), timeout=30) is True
         remove_placement_group(pg)
+
+    @pytest.mark.timeout(170)
+    def test_three_drivers_fan_in_on_one_cluster(self, ray_batching):
+        """Two more drivers join by `init(address=...)` and every one of
+        the three finishes its own burst of 200 tasks on the one cluster
+        while the others run theirs."""
+        from ray_tpu._private import worker_api
+        ray_tpu = ray_batching
+        script = (
+            "import os, sys\n"
+            "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
+            f"sys.path.insert(0, {REPO!r})\n"
+            "import ray_tpu\n"
+            f"ray_tpu.init(address={worker_api._state.gcs_address!r})\n"
+            "@ray_tpu.remote\n"
+            "def nop():\n"
+            "    return None\n"
+            "done = ray_tpu.get([nop.remote() for _ in range(200)],"
+            " timeout=120)\n"
+            "print('DONE', len(done))\n"
+            "ray_tpu.shutdown()\n")
+
+        @ray_tpu.remote
+        def nop():
+            return None
+
+        procs = [subprocess.Popen([sys.executable, "-c", script],
+                                  stdout=subprocess.PIPE,
+                                  stderr=subprocess.STDOUT, text=True)
+                 for _ in range(2)]
+        try:
+            counts = [len(ray_tpu.get([nop.remote() for _ in range(200)],
+                                      timeout=120))]
+            for p in procs:
+                stdout, _ = p.communicate(timeout=150)
+                assert p.returncode == 0, stdout[-3000:]
+                counts += [int(ln.split()[1]) for ln in stdout.splitlines()
+                           if ln.startswith("DONE ")]
+        finally:
+            for p in procs:
+                p.kill()
+        assert counts == [200, 200, 200]
 
 
 class TestDelayInjectionOverBatchedPaths:

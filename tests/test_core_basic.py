@@ -124,6 +124,21 @@ class TestObjects:
         out = ray.get(ref)
         assert np.array_equal(arr, out)
 
+    def test_same_node_large_get_is_a_view_into_an_attached_segment(
+            self, ray_shared):
+        """A same-node `get` of a plane-sized array hands back a view INTO
+        a shm segment the driver attached. A copy here would silently
+        double every large-payload hop."""
+        from helpers.store_segments import in_attached_segment
+        from ray_tpu._private import object_plane
+        ray = ray_shared
+        arr = np.ones(2 * object_plane.threshold(), dtype=np.uint8)
+        out = ray.get(ray.put(arr), timeout=60)
+        assert isinstance(out, np.ndarray) and out.nbytes == arr.nbytes
+        assert out[0] == 1 and out[-1] == 1
+        assert in_attached_segment(out)
+        assert not in_attached_segment(arr)
+
     def test_large_task_arg_and_return(self, ray_shared):
         ray = ray_shared
 

@@ -357,6 +357,17 @@ def test_prestart_hint_fills_pool(jax_cpu):
         assert raylet.prestart_hints_received >= 6
         # The hint pins the reap floor for its TTL.
         assert raylet._pools.floor("", fresh_floor=2) >= 6
+        # The warm path engages: a create after the hint takes a pooled
+        # worker, it does not spawn one.
+        hits = raylet._pools.hits
+
+        @ray_tpu.remote(num_cpus=0.01)
+        class Tiny:
+            def ready(self):
+                return 1
+
+        assert ray_tpu.get(Tiny.remote().ready.remote(), timeout=60) == 1
+        assert raylet._pools.hits > hits
     finally:
         cluster.shutdown()
 

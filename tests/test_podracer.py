@@ -170,6 +170,23 @@ class TestPodracerRuntime:
         finally:
             run.teardown()
 
+    @pytest.mark.timeout(240)
+    def test_ticks_pay_no_per_tick_task_rpc(self, ray_start):
+        """Act -> learn ticks ride the compiled DAG's rings, the weights
+        in the control tuple of the input ring: the transport's frame
+        counter does not grow with the ticks (a `.remote()` fan-out would
+        pay three task round trips a tick)."""
+        from helpers.transport_frames import \
+            assert_frames_do_not_grow_with_ticks
+        run = PodracerRun(_tiny_config(channel_depth=4))
+        try:
+            run.run(5, window=1, timeout=120)   # warm every hop + jits
+            assert_frames_do_not_grow_with_ticks(
+                lambda: run.run(40, window=4, timeout=120), 40)
+            _assert_invariants(run, num_actors=2)
+        finally:
+            run.teardown()
+
 
 # ---------------------------------------------------------------------------
 # Chaos proof: slice preemption mid-rollout

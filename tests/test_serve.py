@@ -327,6 +327,29 @@ def test_user_config_reconfigure(ray_mod):
     assert h.remote().result(timeout=30) == 5
 
 
+def test_large_body_is_read_in_place(ray_mod):
+    """A 2 MB body, wrapped as the proxy wraps one (`wrap_body`: over the
+    plane's serve-body threshold it pickles out of band), comes back
+    whole through a handle, and what the caller holds is a view INTO a
+    store segment it attached: the body crossed as a plane reference,
+    not as bytes copied into and out of the reply frame."""
+    from helpers.store_segments import in_attached_segment
+    from ray_tpu._private import object_plane
+
+    @serve.deployment(max_ongoing_requests=8)
+    def echo(b):
+        return b
+
+    h = serve.run(echo.bind(), name="lb", route_prefix="/lb")
+    body = b"x" * (2 << 20)
+    r = h.remote(object_plane.wrap_body(body)).result(timeout=60)
+    assert len(r) == len(body)
+    assert object_plane.body_view(r)[0] == 120
+    assert isinstance(r, object_plane.SharedPayload)
+    assert in_attached_segment(object_plane.body_view(r))
+    assert r == body
+
+
 def test_streaming_handle(ray_mod):
     """handle.options(stream=True) yields items as the replica produces
     them (reference: handle.py DeploymentResponseGenerator)."""
