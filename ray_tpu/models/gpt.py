@@ -1269,10 +1269,18 @@ def _ssm_block(m, x, cfg: GPTConfig, where: Setting):
         def groups(t):                    # [B, S, G * N] -> [B, S, G, N]
             return t.reshape(b, s, -1, size.state)
         with jax.named_scope("ssm_core"):
-            y = ssd(xbc[..., :inner].reshape(b, s, -1, size.head_dim), step,
-                    m["a_log"], groups(xbc[..., inner:inner + directions]),
-                    groups(xbc[..., inner + directions:]), m["d"],
-                    chunk=size.chunk)
+            whole_heads = ("batch", None, "heads", None)
+            # a shard's heads with their groups; one group serves every
+            # shard whole
+            shared = (whole_heads if size.groups > 1
+                      else ("batch", None, None, None))
+            y = _per_shard(
+                partial(ssd, chunk=size.chunk), where.mesh,
+                (whole_heads, ("batch", None, "heads"), ("heads",),
+                 shared, shared, ("heads",)), whole_heads)(
+                xbc[..., :inner].reshape(b, s, -1, size.head_dim), step,
+                m["a_log"], groups(xbc[..., inner:inner + directions]),
+                groups(xbc[..., inner + directions:]), m["d"])
         stats = {"ssm_dt_mean": jnp.mean(step),
                  "ssm_log_decay_min": jnp.min(ssm_log_decay(
                      step, m["a_log"], size.chunk))}

@@ -136,7 +136,7 @@ KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
            "latent_q_merge", "latent_kv_merge", "index_scores",
            "index_search", "index_kl", "index_grad_q", "index_grad_k",
            "conv_silu_fwd", "conv_silu_bwd", "kda_fwd", "kda_bwd",
-           "embed_grad")
+           "ssd_fwd", "ssd_bwd", "embed_grad")
 UNATTRIBUTED = "unattributed"
 STRETCH_SPAN = "device_trace"
 HOST_SPAN_PREFIXES = ("train:", "host:", "compile:")

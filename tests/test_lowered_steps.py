@@ -60,7 +60,14 @@ LOWERED = {
     # 57, not in the table).
     "olmoe_train_1chip": "857d8b4221a8d29b",
     "solar2_train_1chip": "3d034416b94d0f0e",
-    "nemotron3s_train_1chip": "9b951c1470dfbaa6",
+    # recorded anew by PR 61, which means to change exactly this one, the
+    # only cell with `ssm` layers: the scan is two Mosaic calls a layer,
+    # `ssd_fwd` / `ssd_bwd` (ops/state_space.py), where it was XLA einsums
+    # around a `lax.scan` (9b951c1470dfbaa6 before it, since PR 59). The
+    # hash does not see a kernel's body:
+    # tests/test_state_space.py::test_kernels_equal_the_xla_form_at_float32_rounding
+    # holds the kernels to the form they replaced.
+    "nemotron3s_train_1chip": "69b2dfb18851e2f7",
 }
 
 

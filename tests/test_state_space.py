@@ -107,13 +107,22 @@ class NemotronH(Family):
         for n in names:
             if "conv_silu" in n:
                 assert profiling._last_of(n, profiling.REGIONS) == "ssm"
-        # the scan over the chunk states is ssm_core's, forward and
-        # transposed, and it is not run a second time under the remat
-        scans = [n for n in names if "/ssm_core/" in n and "while" in n]
-        assert any("transpose(" not in n for n in scans)
-        assert any("transpose(" in n for n in scans)
-        assert not any("rematted_computation" in n and "transpose(" not in n
-                       for n in scans)
+        assert {"ssd_fwd", "ssd_bwd"} <= set(profiling.KERNELS)
+        # the scan's two kernels are ssm_core's, the forward's in phase
+        # forward and the backward's in the transpose; the forward is not
+        # run a second time under the remat, and no loop of XLA's is left
+        core = [n for n in names
+                if profiling._last_of(n, profiling.REGIONS) == "ssm_core"]
+        assert any("/ssd_fwd/" in n and "transpose(" not in n for n in core)
+        assert any("/ssd_bwd/" in n and "transpose(" in n for n in core)
+        for n in names:
+            if "ssd_fwd" in n or "ssd_bwd" in n:
+                assert profiling._last_of(n, profiling.REGIONS) == "ssm_core"
+            assert not ("ssd_fwd" in n and "rematted_computation" in n), n
+            # (here the interpreter walks a kernel's grid in a loop of its
+            # own, inside the kernel's name)
+            assert not ("/ssm_core/" in n and "while" in n
+                        and "/ssd_fwd/" not in n and "/ssd_bwd/" not in n), n
 
     reduced = {"num_hidden_layers", "hybrid_override_pattern",
                "n_routed_experts", "vocab_size", "num_attention_heads",
@@ -160,8 +169,9 @@ class NemotronH(Family):
     cell_kernel_calls = {"flash_fwd": 2, "flash_bwd_dq": 2,
                          "flash_bwd_dkv": 2, "rope_split": 12,
                          "rope_merge": 6, "conv_silu_fwd": 10,
-                         "conv_silu_bwd": 5, "moe_gmm": 96, "moe_tgmm": 24,
-                         "moe_run_sum": 18, "embed_grad": 2}
+                         "conv_silu_bwd": 5, "ssd_fwd": 5, "ssd_bwd": 5,
+                         "moe_gmm": 96, "moe_tgmm": 24, "moe_run_sum": 18,
+                         "embed_grad": 2}
     cell_memory_share = (0.80, 0.92)
     cell_step_marks = (pytest.mark.timeout(900),)
 
@@ -193,42 +203,141 @@ def _scan_inputs(jax, seq, groups, step, seed=0):
     return x, dt, a_log, b, c, 1.0 + 0.1 * jax.random.normal(k[5], (4,))
 
 
-@pytest.mark.parametrize("seq,chunk,groups,step", [
-    (128, 32, 2, 0.05),       # whole chunks
-    (100, 32, 1, 0.05),       # a ragged tail, one group for every head
-    (70, 16, 2, 1e-3),        # another chunk size, decays near 1
-    (96, 32, 4, 3.0),         # a chunk's decay underflows float32
-], ids=["whole", "ragged", "chunk16_slow", "underflow"])
+def _value_and_gradients(jax, fn, args):
+    """(fn's output in float32, the six gradients of its sum under fixed
+    weights), jitted."""
+    import jax.numpy as jnp
+    weight = jnp.cos(0.37 * jnp.arange(args[0].size // 2).reshape(
+        args[0].shape[1:]))
+
+    def scalar(*a):
+        out = fn(*a).astype(jnp.float32)
+        return jnp.sum(out * weight), out
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        scalar, argnums=tuple(range(6)), has_aux=True))(*args)
+    return out, grads
+
+
+@pytest.mark.parametrize("seq,chunk,groups,step,dtype", [
+    (128, 32, 2, 0.05, "float32"),      # whole chunks
+    (100, 32, 1, 0.05, "float32"),      # a ragged tail, one group
+    (70, 16, 2, 1e-3, "float32"),       # another chunk size, decays near 1
+    (96, 32, 4, 3.0, "float32"),        # a chunk's decay underflows float32
+    (64, 32, 1, 0.05, "float32"),       # one group serves every head
+    (64, 32, 1, 0.05, "bfloat16"),      # the cell's types: x, B, C bfloat16
+], ids=["whole", "ragged", "chunk16_slow", "underflow", "one_group",
+        "cell_types"])
 def test_chunked_scan_matches_the_recurrence(jax_cpu, seq, chunk, groups,
-                                             step):
-    """Values and all six gradients. No chunk divides by a decay: where a
-    chunk's cumulative log-decay passes float32's range the chunked form
-    still has the recurrence's numbers."""
+                                             step, dtype):
+    """The two kernels, interpreted: values and all six gradients. No chunk
+    divides by a decay: where a chunk's cumulative log-decay passes
+    float32's range the chunked form still has the recurrence's numbers.
+    At the cell's types (bfloat16 x, B and C are exact operands, one term
+    of three; dt float32) what comes out as bfloat16 is held to a rounding
+    of its type and the float32 gradients to float32's tolerance."""
     jax = jax_cpu
     import jax.numpy as jnp
     from ray_tpu.ops.state_space import chunk_log_decay, ssd, ssd_reference
-    args = _scan_inputs(jax, seq, groups, step)
+    args = list(_scan_inputs(jax, seq, groups, step))
+    for at in (0, 3, 4):
+        args[at] = args[at].astype(dtype)
     assert chunk_log_decay(args[1], args[2], chunk).shape == (
         2, -(-seq // chunk), 4, chunk)
     if step == 3.0:
         assert float(chunk_log_decay(args[1], args[2], chunk).min()) < -200.0
-    weight = jnp.cos(0.37 * jnp.arange(seq * 32).reshape(seq, 4, 8))
-
-    def run(fn):
-        def scalar(*a):
-            out = fn(*a)
-            return jnp.sum(out * weight), out
-        (_, out), grads = jax.jit(jax.value_and_grad(
-            scalar, argnums=tuple(range(6)), has_aux=True))(*args)
-        return out, grads
-    out, grads = run(lambda *a: ssd(*a, chunk=chunk))
-    want, want_grads = run(ssd_reference)
+    out, grads = _value_and_gradients(jax, lambda *a: ssd(*a, chunk=chunk),
+                                      args)
+    want, want_grads = _value_and_gradients(jax, ssd_reference, args)
     scale = float(jnp.max(jnp.abs(want)))
-    assert float(jnp.max(jnp.abs(out - want))) < 2e-5 * max(scale, 1.0)
-    for g, w in zip(grads, want_grads):
-        assert np.isfinite(np.asarray(g)).all()
+    rounding = {"float32": 2e-5, "bfloat16": 2 ** -8}
+    assert float(jnp.max(jnp.abs(out - want))) < rounding[dtype] * max(
+        scale, 1.0)
+    for g, w, like in zip(grads, want_grads, args):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        assert g.dtype == like.dtype
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
         top = max(float(jnp.max(jnp.abs(w))), 1.0)
-        assert float(jnp.max(jnp.abs(g - w))) < 2e-4 * top
+        assert float(jnp.max(jnp.abs(g - w))) < 10 * rounding[
+            str(like.dtype)] * top
+
+
+def _xla_chunked(x, dt, a_log, b, c, d, *, chunk):
+    """The chunked form as XLA einsums and one `lax.scan`, every product
+    at Precision.HIGHEST: what `ssd` was before its kernels (PR 56), kept
+    here as their oracle. Whole chunks only."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.state_space import chunk_log_decay
+    hi = jax.lax.Precision.HIGHEST
+    batch, seq, heads, width = x.shape
+    groups, n = b.shape[-2:]
+    per, chunks = heads // groups, seq // chunk
+    xc, dtc, bc, cc = (t.astype(jnp.float32).reshape(
+        batch, chunks, chunk, *t.shape[2:]) for t in (x, dt, b, c))
+    g = chunk_log_decay(dt, a_log, chunk)                     # [B,c,H,C]
+    u = dtc[..., None] * xc                                   # [B,c,C,H,P]
+    at = jnp.arange(chunk)
+    between = jnp.exp(jnp.where(at[:, None] >= at[None, :],
+                                g[..., :, None] - g[..., None, :], -jnp.inf))
+    cb = jnp.einsum("bktgn,bksgn->bkgts", cc, bc, precision=hi)
+    y = jnp.einsum("bkhts,bkshp->bkthp",
+                   between * jnp.repeat(cb, per, axis=2), u, precision=hi)
+    to_end = jnp.exp(g[..., -1:] - g).transpose(0, 1, 3, 2)   # [B,c,C,H]
+    added = jnp.einsum(
+        "bksgrp,bksgn->bkgrpn",
+        (to_end[..., None] * u).reshape(batch, chunks, chunk, groups, per,
+                                        width),
+        bc, precision=hi).reshape(batch, chunks, heads, width, n)
+
+    def step(state, inputs):
+        decay, add = inputs
+        return decay[..., None, None] * state + add, state
+    _, starts = jax.lax.scan(
+        step, jnp.zeros((batch, heads, width, n), jnp.float32),
+        (jnp.moveaxis(jnp.exp(g[..., -1]), 1, 0), jnp.moveaxis(added, 1, 0)))
+    carried = jnp.einsum(
+        "bktgn,bkgrpn->bktgrp", cc,
+        jnp.moveaxis(starts, 0, 1).reshape(batch, chunks, groups, per, width,
+                                           n),
+        precision=hi).reshape(batch, chunks, chunk, heads, width)
+    y = (y + jnp.exp(g).transpose(0, 1, 3, 2)[..., None] * carried
+         + d.astype(jnp.float32)[:, None] * xc)
+    return y.reshape(batch, seq, heads, width)
+
+
+@pytest.mark.parametrize("exact", [True, False], ids=["bfloat16_values",
+                                                      "float32_values"])
+def test_kernels_equal_the_xla_form_at_float32_rounding(jax_cpu, exact):
+    """`LOWERED` (tests/test_lowered_steps.py) does not see a kernel's body,
+    so this does: y and the six gradients of the kernels against the XLA
+    form they replaced, all in float32, so that nothing hides under a
+    bfloat16 rounding. With x, B and C holding bfloat16 values and declared
+    exact (the cell's types: ONE term of an operand's three enters its
+    products, `state_space._dot`), the sum is the all-HIGHEST form's; with
+    float32 values every product takes its six passes."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import state_space
+    args = list(_scan_inputs(jax, 64, 1, 0.05, seed=3))
+    if exact:
+        for at in (0, 3, 4):
+            args[at] = args[at].astype(jnp.bfloat16).astype(jnp.float32)
+    out, grads = _value_and_gradients(jax, lambda *a: state_space._scan(
+        *a, chunk=32, exact=(exact,) * 3, interpret=True), args)
+    want, want_grads = _value_and_gradients(
+        jax, lambda *a: _xla_chunked(*a, chunk=32), args)
+    assert out.dtype == jnp.float32
+    # of each one's largest value. The kernels read 0.9-3.1e-7 on y, dx,
+    # ddt, db, dc when this was written, and with the pairs of order two
+    # left out (Precision.HIGH's three passes) 2.6-8.5e-6; the two sums
+    # over a head's whole sequence, a_log's and d's, 3.1e-6 and 5.3e-7
+    # either way (the XLA form is as far from the recurrence)
+    for name, g, w in zip(("y", "x", "dt", "a_log", "b", "c", "d"),
+                          (out,) + grads, (want,) + want_grads):
+        top = max(float(jnp.max(jnp.abs(w))), 1.0)
+        tol = 1e-5 if name in ("a_log", "d") else 1e-6
+        assert float(jnp.max(jnp.abs(g - w))) < tol * top, (
+            name, float(jnp.max(jnp.abs(g - w))), top)
 
 
 def test_scan_keeps_the_inputs_type_and_names_what_remat_keeps(jax_cpu):
@@ -238,8 +347,29 @@ def test_scan_keeps_the_inputs_type_and_names_what_remat_keeps(jax_cpu):
     x, *rest = _scan_inputs(jax, 64, 2, 0.05)
     assert jax.eval_shape(lambda *a: ssd(*a, chunk=32),
                           x.astype(jnp.bfloat16), *rest).dtype == jnp.bfloat16
-    text = str(jax.make_jaxpr(lambda *a: ssd(*a, chunk=32))(x, *rest))
+    # the names are the custom_vjp's forward rule's: what a gradient traces
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(ssd(*a, chunk=32))))(x, *rest))
     assert text.count(f"name[name={SSD_OUT}]") == 2     # y, the chunk states
+    assert text.count("name=ssd_fwd") == text.count("name=ssd_bwd") == 1
+
+
+def test_scan_refuses_what_the_chips_tiles_cannot_hold(jax_cpu):
+    """No second path: a compiled chunk is whole lane tiles of tokens and a
+    head whole sublane tiles of channels, and the groups divide the heads
+    (the small shapes of this file run interpreted, where any shape
+    does)."""
+    jax = jax_cpu
+    from ray_tpu.ops.state_space import ssd
+    args = _scan_inputs(jax, 64, 2, 0.05)
+    with pytest.raises(ValueError, match="whole lane tiles"):
+        ssd(*args, chunk=32, interpret=False)
+    with pytest.raises(ValueError, match="whole sublane tiles"):
+        ssd(*args, chunk=128, interpret=False)
+    x, dt, a_log, b, c, d = args
+    with pytest.raises(ValueError, match="do not divide"):
+        ssd(x, dt, a_log, b[:, :, :1].repeat(3, 2), c[:, :, :1].repeat(3, 2),
+            d, chunk=32)
 
 
 # ---------------------------------------------------------------------------
@@ -629,6 +759,86 @@ def test_the_existing_configurations_have_none_of_it():
     assert cfg.feed_forward == ExpertForm(matrices=3, activation="silu")
     relu = GPTConfig(gate_activation="relu")
     assert relu.feed_forward.activation == "relu"
+
+
+# ---------------------------------------------------------------------------
+# (f) for a described v5e: the scan's kernels, a state-space layer on four
+# chips and (imported) the sparse block and the whole step
+# ---------------------------------------------------------------------------
+
+
+def test_scan_compiles_at_8192_positions_of_16_heads_of_64(v5e):
+    """ops/state_space.py's two kernels at a state-space layer of
+    nemotron3s_train_1chip, [1, 8192, 16, 64] on one group of 128 in chunks
+    of 128, the cell's types: `ssd_fwd` and `ssd_bwd` (the chunk function's
+    jax.vjp) compile inside their VMEM limit, one Mosaic call each and no
+    XLA loop beside them, neither over the 64 chunks nor the 8192 tokens.
+    All sixteen heads of the group a grid step: x, y, dy and dx blocks of
+    [16, 64, 128] bfloat16 (256 KB each), B and C of [128, 128] (32 KB), dB
+    and dC of [128, 128] float32 (64 KB), a chunk's states [16, 64, 128]
+    float32 (512 KB) and as much scratch, each block twice for the
+    pipeline: under 4 MB; the [16, 128, 128] float32 decay-and-score tiles
+    (1 MB each) are values inside the body, under the 64 MB the call may
+    use. What is kept between the two calls is the chunks' states (34 MB,
+    which the compiler may hold in VMEM: no lower bound here), where the
+    XLA form's decay-and-score tensors were 67 MB each."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.state_space import ssd
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+    args = (shape((1, 8192, 16, 64)), shape((1, 8192, 16), jnp.float32),
+            shape((16,), jnp.float32), shape((1, 8192, 1, 128)),
+            shape((1, 8192, 1, 128)), shape((16,), jnp.float32))
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(ssd(*a, interpret=False).astype(jnp.float32)),
+        argnums=tuple(range(6)))).lower(*args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\S*ssd_(?:fwd|bwd)\S*) = .*custom-call\(", text)
+    assert len(calls) == 2 and "fwd" in calls[0] and "bwd" in calls[1], calls
+    assert text.count("tpu_custom_call") == 2
+    assert " while(" not in text
+    states = 16 * 64 * 64 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * states
+
+
+def test_state_space_layer_compiles_on_four_chip_mesh(v5e, monkeypatch):
+    """A state-space layer at two chips' worth of nemotron3s_train_1chip's
+    share (32 heads of 64 in 2 groups of 128, on 4096) under tp_fsdp on
+    fsdp=2 x tensor=2, forward and backward: the two kernels run per shard
+    (`gpt.py:_per_shard`: a batch row, sixteen heads and their group a
+    device), as the filter beside them does; GSPMD would refuse the Mosaic
+    calls as they stand."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from helpers.described_chip import layer_on_four_chips
+    from ray_tpu.models import gpt
+    widths = dict(FAMILY.module.gpt_config_kwargs(FAMILY.cell_config()),
+                  n_layers=1, layer_kinds=("ssm",), n_experts=0,
+                  experts_held=None, n_shared_experts=0, expert_form=None,
+                  mtp=None, max_seq=2048,
+                  ssm=gpt.StateSpace(heads=32, head_dim=64, groups=2,
+                                     state=128, chunk=128))
+    cfg, mesh, _, layer, x = layer_on_four_chips(v5e, monkeypatch, widths,
+                                                  2, 2048)
+
+    def loss(layer, x):
+        out, _stats = gpt._ssm_block(layer["ssm"], x, cfg, gpt.Setting(mesh))
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        layer, x).compile().as_text()
+    calls = re.findall(r"%(\S*ssd_(?:fwd|bwd)\S*) = .*custom-call\(", text)
+    assert len(calls) == 2, calls
+    # a shard's own slice: a batch row of sixteen heads, tokens last
+    assert re.search(r"ssd_fwd\S* = .*bf16\[16,64,2048\]", text)
+    assert "conv_silu_fwd" in text and "all-reduce" in text
+
 
 # Imported last: a module's names are collected in the order they are bound,
 # so the chip's compiler gets this file's programs after its own tests have
