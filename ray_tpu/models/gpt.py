@@ -23,7 +23,10 @@ layers (ops/indexer.py: it chooses the keys a query sees, and is trained by
 a loss of its own),
 per-layer jax.checkpoint (remat) for memory: a layer keeps its input and
 the flash kernel's output and row statistics (a linear-attention layer's
-output), and recomputes the rest.
+output), and recomputes the rest. A layer is a mixer and then a feed-forward,
+or ONE of the two alone (`_HALVES`); optional scalar multipliers on the
+embedding, on what each half adds to the stream, on attention's scores and
+on the logits (`Multipliers`).
 
 Capability parity target: the models RLlib/Train wrap in the reference are
 torch modules; here the model is a (init, apply) pair compatible with pjit.
@@ -66,12 +69,13 @@ _ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
 _HALVES = {"attention": ("attention", True), "conv": ("conv", True),
            "window": ("window", True), "kda": ("kda", True),
            "attention_alone": ("attention", False),
-           "ssm": ("ssm", False), "ff": (None, True)}
+           "ssm": ("ssm", False), "ff": (None, True),
+           "ssm_ff": ("ssm", True)}
 
 
 @dataclass(frozen=True)
 class StateSpace:
-    """An "ssm" layer's sizes (GPTConfig.ssm): Mamba-2's mixer
+    """An "ssm" or "ssm_ff" layer's sizes (GPTConfig.ssm): Mamba-2's mixer
     (`_ssm_block`; ops/state_space.py). heads x head_dim inner channels,
     `groups` pairs of input and output directions of `state` numbers that
     the heads of a group share, a causal depthwise filter of
@@ -114,6 +118,22 @@ class PredictionModule:
 
 
 @dataclass(frozen=True)
+class Multipliers:
+    """Scalars on four places of the stack (GPTConfig.multipliers): the
+    embedding's rows times `embedding` (scope `embed`; the lookup's backward
+    reads the scaled cotangent); what each half of a layer adds to the
+    residual stream times `residual`, x + residual f(norm(x)); attention's
+    scores times `attention` in place of head_dim^-1/2 (None: that), the
+    kernels' `sm_scale`; the logits divided by `logits` (the head's rows are
+    scaled under `head`, so a tied matrix takes both its gradients on one
+    leaf). A multiplier of 1 emits no op."""
+    embedding: float = 1.0
+    residual: float = 1.0
+    attention: Optional[float] = None
+    logits: float = 1.0
+
+
+@dataclass(frozen=True)
 class GPTConfig:
     vocab_size: int = 50304           # GPT-2 vocab padded to a multiple of 128
     d_model: int = 768
@@ -141,7 +161,8 @@ class GPTConfig:
     # be ONE norm and ONE half: "ssm" (a state-space mixer, sized by `ssm`),
     # "attention_alone" (that mixer and no feed-forward), "ff" (a
     # feed-forward and no mixer: the MLP, or experts where the stack is
-    # sparse). A "conv" layer is a gated short
+    # sparse). "ssm_ff": the state-space mixer, then a feed-forward (both
+    # halves). A "conv" layer is a gated short
     # convolution: [B | C | X] = three projections of the normed input,
     # C * filter(B * X) with a causal depthwise filter of conv_filter taps
     # a channel, then an output projection. No bias, no activation.
@@ -263,10 +284,12 @@ class GPTConfig:
     attention: str = "flash"          # flash | reference | ring
     tie_embeddings: bool = False
     # Sub-records, each None where the stack has nothing of the kind:
-    # "ssm" layers' sizes, the feed-forwards' form, a prediction module.
+    # state-space layers' sizes, the feed-forwards' form, a prediction
+    # module, scalar multipliers.
     ssm: Optional[StateSpace] = None
     expert_form: Optional[ExpertForm] = None
     mtp: Optional[PredictionModule] = None
+    multipliers: Optional[Multipliers] = None
 
     def __post_init__(self):
         if not self.head_dim:
@@ -281,12 +304,25 @@ class GPTConfig:
                    if self.mtp else "")
                 + f": expected n_layers={self.n_layers} of "
                 + " | ".join(map(repr, _HALVES)))
-        if "ssm" in every and self.ssm is None:
-            raise ValueError("'ssm' layers need their sizes: GPTConfig.ssm")
-        if "ssm" in every and self.attention == "ring":
+        state_space = sorted(k for k in set(every) if _HALVES[k][0] == "ssm")
+        if state_space and self.ssm is None:
+            raise ValueError(f"{' and '.join(map(repr, state_space))} layers "
+                             "need their sizes: GPTConfig.ssm")
+        if state_space and self.attention == "ring":
             raise ValueError(
-                "an 'ssm' layer's state runs along the whole sequence: it is "
-                "not sharded over 'sequence' (attention='ring')")
+                f"an {state_space[0]!r} layer's state runs along the whole "
+                "sequence: it is not sharded over 'sequence' "
+                "(attention='ring')")
+        if self.sm_scale is not None and (self.kv_latent_dim
+                                          or self.index_topk):
+            raise ValueError(
+                f"multipliers.attention={self.sm_scale} scales the scores of "
+                "multi-head attention layers (full and window; flash, "
+                "reference and ring); it is not threaded to "
+                + ("a latent block, whose scale is its q.k width's"
+                   if self.kv_latent_dim
+                   else "an indexer, whose KL target is scaled by "
+                        "head_dim^-1/2"))
         if self.route_from == "input" and "ff" in every:
             raise ValueError("route_from='input' reads a layer's normed "
                              "input ahead of its mixer: an 'ff' layer has "
@@ -364,6 +400,18 @@ class GPTConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def scales(self) -> Multipliers:
+        """multipliers, or the record in which every one is 1."""
+        return self.multipliers or Multipliers()
+
+    @property
+    def sm_scale(self) -> Optional[float]:
+        """The softmax scale of the attention layers where the configuration
+        sets one (multipliers.attention); None: head_dim^-1/2, the kernels'
+        own default."""
+        return self.scales.attention
 
     @property
     def feed_forward(self) -> ExpertForm:
@@ -757,7 +805,9 @@ def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh, window=None):
     through the rotation and the flash kernels, per shard: the columns are
     whole heads, each device attends its own (batch, head) slice, a
     key/value head with the query heads that read it. A window layer's
-    kernels run under scope `attn_window` (in `attn_core`). -> the heads'
+    kernels run under scope `attn_window` (in `attn_core`). The scores'
+    scale is the kernels' own, head_dim^-1/2, or cfg.sm_scale where the
+    configuration sets one. -> the heads'
     outputs [B, S, H * D], the layout that leaves the kernels and the
     shard_map wherever `tokens_first` reads it off the shape a device
     holds:
@@ -785,9 +835,11 @@ def _flash_on_mesh(q, k, v, table, cfg: GPTConfig, mesh, window=None):
         with jax.named_scope("attn_core"):
             if window is None:
                 return flash_attention_native(q, k, v, causal=True,
+                                              sm_scale=cfg.sm_scale,
                                               in_pairs=in_pairs)
             with jax.named_scope("attn_window"):
                 return flash_attention_native(q, k, v, causal=True,
+                                              sm_scale=cfg.sm_scale,
                                               window=window,
                                               in_pairs=in_pairs)
 
@@ -1135,9 +1187,11 @@ def _multi_head_attention(a, x, cfg: GPTConfig, table, where: Setting,
         return _tokens_first(o), kl.reshape(1), share.reshape(1)
     with jax.named_scope("attn_core"):
         if cfg.attention == "ring":
-            o = ring_attention(q, k, v, mesh=where.mesh, causal=True)
+            o = ring_attention(q, k, v, mesh=where.mesh, causal=True,
+                               sm_scale=cfg.sm_scale)
         else:
-            o = mha_reference(q, k, v, causal=True, window=window)
+            o = mha_reference(q, k, v, causal=True, window=window,
+                              sm_scale=cfg.sm_scale)
     return _tokens_first(o)
 
 
@@ -1612,6 +1666,12 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
             index_table = rope_table(seq, cfg.index_head_dim,
                                      cfg.rope_of("attention"))
 
+    residual = cfg.scales.residual
+
+    def add(x, delta):
+        """x + residual f: what a half adds to the stream."""
+        return where.pin(x + (delta if residual == 1.0 else delta * residual))
+
     def block(x, layer):
         normed = _rmsnorm(x, layer["ln1"]["scale"], cfg.rmsnorm_eps)
         mixer_stats, routing = {}, None
@@ -1630,7 +1690,7 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
                 layer, normed, cfg, tables[kind], where, kind, index_table)
         else:
             mixed = None                 # a feed-forward alone
-        h = x if mixed is None else where.pin(x + mixed)
+        h = x if mixed is None else add(x, mixed)
         if "moe" not in layer and "mlp" not in layer:
             return h, mixer_stats        # a mixer alone
         if mixed is not None:
@@ -1642,7 +1702,7 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
             with jax.named_scope("mlp"):
                 delta, stats = _mlp_block(
                     layer["mlp"], normed, cfg, where), {}
-        return where.pin(h + delta), {**stats, **mixer_stats}
+        return add(h, delta), {**stats, **mixer_stats}
 
     if cfg.remat_policy == "full":
         # (of an indexer, its selection and its loss's gradients: the walk
@@ -1671,6 +1731,7 @@ def gpt_forward(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     dt = cfg.dtype
     x, router = gpt_backbone(params, tokens, cfg, mesh, act_sharding)
     with jax.named_scope("head"):
+        x = _head_rows(x, cfg)
         if cfg.tie_embeddings:
             logits = jnp.einsum("bsd,vd->bsv", x,
                                 params["embed"]["table"].astype(dt))
@@ -1691,12 +1752,14 @@ def gpt_forward_both(params, tokens, cfg: GPTConfig):
     h, _, block = _stack(params, inputs, cfg, where)
     w_head = _head_operands(params, h, targets, cfg)[1]
     with jax.named_scope("head"):
-        logits = jnp.einsum("bsd,dv->bsv", final_norm(params, h, cfg), w_head)
+        logits = jnp.einsum("bsd,dv->bsv",
+                            _head_rows(final_norm(params, h, cfg), cfg),
+                            w_head)
     if cfg.mtp is None:
         return logits, None
     g, _ = _prediction_module(params, h, targets, block, cfg, where)
     with jax.named_scope("head"):
-        return logits, jnp.einsum("bsd,dv->bsv", g, w_head)
+        return logits, jnp.einsum("bsd,dv->bsv", _head_rows(g, cfg), w_head)
 
 
 def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
@@ -1719,14 +1782,22 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     return final_norm(params, x, cfg), router
 
 
+def _embed(params, tokens, cfg: GPTConfig, where: Setting):
+    """tokens [B, S] -> their rows of the embedding [B, S, D] in cfg.dtype,
+    times multipliers.embedding where the configuration has one (the
+    caller's scope `embed` holds both)."""
+    x = embed_lookup(params["embed"]["table"], tokens, cfg.dtype, where.mesh)
+    scale = cfg.scales.embedding
+    return x if scale == 1.0 else x * scale
+
+
 def _stack(params, tokens, cfg: GPTConfig, where: Setting):
     """tokens [B, S] through the embedding and the layers -> (the residual
     stream BEFORE the final norm, the statistics of each layer that has
     any, the block: layer_fn's, for a caller that runs further layers at
     this sequence length)."""
     with jax.named_scope("embed"):
-        x = where.pin(embed_lookup(params["embed"]["table"], tokens,
-                                   cfg.dtype, where.mesh))
+        x = where.pin(_embed(params, tokens, cfg, where))
     layer = layer_fn(cfg, tokens.shape[1], where)
     x, per_layer = _walk(layer, x, params["layers"])
     return x, per_layer, layer
@@ -1764,8 +1835,7 @@ def _prediction_module(params, h, targets, layer, cfg: GPTConfig,
     head pass fall under the regions they always do."""
     m, eps = params["mtp"], cfg.rmsnorm_eps
     with jax.named_scope("embed"):
-        e = embed_lookup(params["embed"]["table"], jnp.maximum(targets, 0),
-                         cfg.dtype, where.mesh)
+        e = _embed(params, jnp.maximum(targets, 0), cfg, where)
     with jax.named_scope("mtp"):
         g = where.pin(jnp.einsum(
             "bse,ed->bsd",
@@ -1918,11 +1988,21 @@ def _chunked_xent_bwd(chunk_rows, residuals, cotangents):
 chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)
 
 
+def _head_rows(x, cfg: GPTConfig):
+    """What the head's matmul reads of the final hidden states: x, or, where
+    the logits are divided by multipliers.logits, x over it (x W / c = (x /
+    c) W: at a power of two, Granite's 8, no bit of x moves; the head's dW
+    and, tied, the embedding's gradient are then of the scaled logits)."""
+    scale = cfg.scales.logits
+    return x if scale == 1.0 else x * (1.0 / scale)
+
+
 def _head_operands(params, x, targets, cfg: GPTConfig):
-    """head_xent's arguments as chunked_xent's: rows, the head's matrix in
-    the model dtype (the embedding table's transpose if tied), targets and
-    the mask that leaves negative targets out."""
+    """head_xent's arguments as chunked_xent's: rows (`_head_rows`), the
+    head's matrix in the model dtype (the embedding table's transpose if
+    tied), targets and the mask that leaves negative targets out."""
     b, s, d = x.shape
+    x = _head_rows(x, cfg)
     if cfg.tie_embeddings:
         w_head = params["embed"]["table"].astype(cfg.dtype).T
     else:

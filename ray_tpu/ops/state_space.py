@@ -32,21 +32,26 @@ and masked above the diagonal before it. A sequence that is no whole number
 of chunks is padded with tokens of dt = 0, which leave the state as it is.
 
 `_chunk` states all of that once, for one chunk of a few heads of one
-group, as a jnp function of values that live in VMEM (at nemotron's [128
-tokens, 64 channels, 128 directions] a head's x is 32 KB in float32, a
-[C, C] tile 64 KB, a state 32 KB). The tokens lie along the lanes (x a head
+group, as a jnp function of values that live in VMEM (a head's x is 4 P C
+bytes in float32, a [C, C] tile 4 C^2, a state 4 P N: at nemotron's [128
+tokens, 64 channels, 128 directions] 32 KB, 64 KB and 32 KB; at granite's
+chunks of 256 a head's x is 64 KB and a [C, C] tile 256 KB, four times
+nemotron's, so a grid step takes fewer heads there: `_heads_a_step`). The
+tokens lie along the lanes (x a head
 is [P, C]), so a head of 64 channels fills its tiles, what a token and head
 scales by (dt, the decays) is a row that broadcasts down the sublanes, and
 a state is [P, N] as the recurrence keeps it. `ssd_fwd` runs it over a grid
-(heads / h, chunks), the chunks in order, the state in VMEM scratch: a
+(heads / h, chunks), h heads of one group a step (a group of more heads than
+h is several blocks), the chunks in order, the state in VMEM scratch: a
 chunk's x, B, C, dt and g cross HBM once and C B^T, the decays, the scores
 and both state products stay on the chip. `ssd_bwd` runs `jax.vjp` of the
 same function over the chunks from the last to the first, the state's
 cotangent in scratch: it computes the chunk again from its inputs and the
 state it started from, which the forward keeps ([heads, chunks, P, N]
-float32, 34 MB a layer at [1, 8192, 16, 64] on 128), and writes the
-gradients, B's and C's a block of heads (summed over a group's blocks
-outside). g is the caller's cumulative sum (`chunk_log_decay`), so its
+float32: 34 MB a layer at [1, 8192, 16, 64] on 128 in chunks of 128, 67 MB
+at [1, 8192, 64, 64] in chunks of 256), and writes the gradients, B's and
+C's a block of heads, float32 (XLA sums a group's blocks outside the
+kernel: one block at 16 heads a group, four or more at 64). g is the caller's cumulative sum (`chunk_log_decay`), so its
 transpose back onto dt and a_log is XLA's, over [B, S, H] numbers. The
 output and the kept states carry the name SSD_OUT, so that a remat policy
 that saves it runs the forward once a layer and step, as KDA_OUT does for
@@ -289,6 +294,26 @@ def _bwd_kernel(exact, x_ref, b_ref, c_ref, dt_ref, g_ref, d_ref, states_ref,
 # one step B, C and their gradients cross HBM once a group).
 _HEADS = 16
 
+# The bytes ONE [h, C, C] float32 tile of a grid step may take (the decays
+# between two tokens, the scores under them, their bfloat16 terms and, in
+# the backward, the cotangent of each: `jax.vjp` of `_chunk` holds about a
+# dozen at once, beside x, the state and their cotangents at h P (C + N)
+# floats each, inside the 64 MB the call may use). A tile is 4 C^2 a head:
+# 16 heads of chunks of 128 are 1 MB, of chunks of 256 4 MB, and at chunks
+# of 512 a step takes 4. At [1, 8192, 64, 64] on one group of 128 in chunks
+# of 256, a v5e read 0.81 / 3.65 ms forward / forward and gradient at 16
+# heads a step, 0.88 / 3.76 at 8, 1.02 / 4.09 at 4 (and 0.67 / 2.83 in
+# chunks of 128 at 16).
+_TILE_BYTES = 4 << 20
+
+
+def _heads_a_step(chunk: int, per_group: int) -> int:
+    """The block of heads of one grid step: the most that divide a group's
+    heads, stay within _HEADS and keep a chunk's [h, C, C] tiles within
+    _TILE_BYTES each."""
+    most = max(1, min(_HEADS, _TILE_BYTES // (4 * chunk * chunk)))
+    return max(n for n in range(1, most + 1) if per_group % n == 0)
+
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"),
     vmem_limit_bytes=64 << 20)
@@ -300,7 +325,7 @@ def _make_ssd_fn(chunk: int, per_group: int, exact, interpret: bool):
     whole chunks, b and c [groups, tokens, N], dt and g [heads, chunks, 1,
     chunk], d [heads, 1, 1] (heads and groups times the batch). The
     residuals are the six inputs and the chunks' states."""
-    h = max(n for n in range(1, _HEADS + 1) if per_group % n == 0)
+    h = _heads_a_step(chunk, per_group)
 
     def specs(width, n, order):
         x = pl.BlockSpec((h, width, chunk), lambda i, j: (i, 0, order(j)))

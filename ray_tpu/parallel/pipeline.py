@@ -139,6 +139,12 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
             "stage to stage: no state-space layer's state along a sequence "
             "cut into microbatches has been shown right, and no prediction "
             "module's second stream and loss reach the last rank")
+    if cfg.scales.embedding != 1.0:
+        raise ValueError(
+            f"multipliers.embedding={cfg.scales.embedding}: rank 0's "
+            "lookup is written out in the tick and scales nothing (the "
+            "residual's, the scores' and the logits' multipliers ride in "
+            "layer_fn's block and head_xent_recompute)")
     _refuse_what_a_stage_cannot_run(cfg, mesh, strategy)
     # (what those observations let through and nothing has shown right: a
     # stack of window layers alone, a gate a head on one shard of 'tensor')

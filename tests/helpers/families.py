@@ -46,6 +46,7 @@ FILES = {
     "solar": "test_linear_attention_model.py",
     "smallthinker": "test_smallthinker.py",
     "nemotron_h": "test_state_space.py",
+    "granite_hybrid": "test_hybrid_mixer_model.py",
 }
 
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"

@@ -1,0 +1,52 @@
+"""For a described v5e, at no chip time: the state-space scan's two kernels at
+granite4hm_train_1chip's call (64 heads on ONE B/C group, chunks of 256) and
+the cell's whole step. The family's checks against its reference are
+tests/test_hybrid_mixer_model.py's, the scan's own tests
+tests/test_state_space.py's."""
+
+import re
+
+from helpers.described_chip import cell_step, v5e  # noqa: F401 — fixtures
+from helpers.families import family  # noqa: F401
+from test_hybrid_mixer_model import FAMILY  # noqa: F401
+
+
+def test_scan_compiles_at_8192_positions_of_64_heads_on_one_group(v5e):
+    """ops/state_space.py's two kernels at a Mamba layer of
+    granite4hm_train_1chip, [1, 8192, 64, 64] on one group of 128 in chunks
+    of 256, the cell's types: the block of heads follows the chunk
+    (`_heads_a_step`: 16 heads a step, four blocks on the group), a
+    chunk's [16, 256, 256] float32 tiles are 4 MB each and `ssd_bwd`'s
+    `jax.vjp` fits the 64 MB the call may use; one
+    Mosaic call each and no XLA loop beside them. B's and C's gradients
+    leave the kernel a block of heads, float32, and XLA sums a group's
+    blocks: [64 / h, 8192, 128] each. What is kept between the two calls is
+    the chunks' states, [64, 32, 64, 128] float32 = 67 MB a layer."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.state_space import _heads_a_step, ssd
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+    args = (shape((1, 8192, 64, 64)), shape((1, 8192, 64), jnp.float32),
+            shape((64,), jnp.float32), shape((1, 8192, 1, 128)),
+            shape((1, 8192, 1, 128)), shape((64,), jnp.float32))
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(ssd(*a, chunk=256, interpret=False).astype(
+            jnp.float32)), argnums=tuple(range(6)))).lower(*args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(\S*ssd_(?:fwd|bwd)\S*) = .*custom-call\(", text)
+    assert len(calls) == 2 and "fwd" in calls[0] and "bwd" in calls[1], calls
+    assert text.count("tpu_custom_call") == 2 and " while(" not in text
+    blocks = 64 // _heads_a_step(256, 64)
+    assert f"f32[{blocks},8192,128]" in text          # dB, dC a block of heads
+    states = 64 * 32 * 64 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * states \
+        + 2 * blocks * 8192 * 128 * 4
+
+
+from helpers.described_chip import (  # noqa: E402,F401
+    test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_makes_a_heads_dw_where_its_logits_are)

@@ -68,6 +68,11 @@ LOWERED = {
     # tests/test_state_space.py::test_kernels_equal_the_xla_form_at_float32_rounding
     # holds the kernels to the form they replaced.
     "nemotron3s_train_1chip": "69b2dfb18851e2f7",
+    # new with PR 62, which leaves the ten above alone (their lines are the
+    # parent's): every layer a mixer and a gated MLP (`ssm_ff`), the four
+    # multipliers' products, `sm_scale` 1/64 on the paired flash kernels,
+    # `ssd_fwd` / `ssd_bwd` over four blocks of 16 heads at chunks of 256
+    "granite4hm_train_1chip": "4ea5a3064d7f262b",
 }
 
 

@@ -191,16 +191,17 @@ def _worst(jax, got, want):
 # ---------------------------------------------------------------------------
 
 
-def _scan_inputs(jax, seq, groups, step, seed=0):
-    """x [2, seq, 4, 8], steps of about `step`, rates over 1..16."""
+def _scan_inputs(jax, seq, groups, step, seed=0, heads=4):
+    """x [2, seq, heads, 8], steps of about `step`, rates over 1..16."""
     import jax.numpy as jnp
     k = jax.random.split(jax.random.PRNGKey(seed), 6)
-    x = jax.random.normal(k[0], (2, seq, 4, 8))
-    dt = step * jax.nn.softplus(jax.random.normal(k[1], (2, seq, 4)))
-    a_log = jnp.log(jax.random.uniform(k[2], (4,), minval=1.0, maxval=16.0))
+    x = jax.random.normal(k[0], (2, seq, heads, 8))
+    dt = step * jax.nn.softplus(jax.random.normal(k[1], (2, seq, heads)))
+    a_log = jnp.log(jax.random.uniform(k[2], (heads,), minval=1.0,
+                                       maxval=16.0))
     b = jax.random.normal(k[3], (2, seq, groups, 16))
     c = jax.random.normal(k[4], (2, seq, groups, 16))
-    return x, dt, a_log, b, c, 1.0 + 0.1 * jax.random.normal(k[5], (4,))
+    return x, dt, a_log, b, c, 1.0 + 0.1 * jax.random.normal(k[5], (heads,))
 
 
 def _value_and_gradients(jax, fn, args):
@@ -218,17 +219,24 @@ def _value_and_gradients(jax, fn, args):
     return out, grads
 
 
-@pytest.mark.parametrize("seq,chunk,groups,step,dtype", [
-    (128, 32, 2, 0.05, "float32"),      # whole chunks
-    (100, 32, 1, 0.05, "float32"),      # a ragged tail, one group
-    (70, 16, 2, 1e-3, "float32"),       # another chunk size, decays near 1
-    (96, 32, 4, 3.0, "float32"),        # a chunk's decay underflows float32
-    (64, 32, 1, 0.05, "float32"),       # one group serves every head
-    (64, 32, 1, 0.05, "bfloat16"),      # the cell's types: x, B, C bfloat16
+@pytest.mark.parametrize("seq,chunk,groups,step,dtype,heads", [
+    (128, 32, 2, 0.05, "float32", 4),   # whole chunks
+    (100, 32, 1, 0.05, "float32", 4),   # a ragged tail, one group
+    (70, 16, 2, 1e-3, "float32", 4),    # another chunk size, decays near 1
+    (96, 32, 4, 3.0, "float32", 4),     # a chunk's decay underflows float32
+    (64, 32, 1, 0.05, "float32", 4),    # one group serves every head
+    (64, 32, 1, 0.05, "bfloat16", 4),   # the cell's types: x, B, C bfloat16
+    # granite's call, small: chunks of 256 and more heads on ONE group than
+    # a grid step takes, so B's and C's gradients are summed over the
+    # group's blocks of heads outside the kernel
+    (300, 256, 1, 0.01, "float32", 32),
+    (256, 256, 1, 0.01, "bfloat16", 32),
+    (64, 32, 2, 0.05, "float32", 64),   # two blocks of 16 in each of 2 groups
 ], ids=["whole", "ragged", "chunk16_slow", "underflow", "one_group",
-        "cell_types"])
+        "cell_types", "chunk256_blocks_of_a_group", "chunk256_cell_types",
+        "two_groups_of_two_blocks"])
 def test_chunked_scan_matches_the_recurrence(jax_cpu, seq, chunk, groups,
-                                             step, dtype):
+                                             step, dtype, heads):
     """The two kernels, interpreted: values and all six gradients. No chunk
     divides by a decay: where a chunk's cumulative log-decay passes
     float32's range the chunked form still has the recurrence's numbers.
@@ -237,12 +245,15 @@ def test_chunked_scan_matches_the_recurrence(jax_cpu, seq, chunk, groups,
     of its type and the float32 gradients to float32's tolerance."""
     jax = jax_cpu
     import jax.numpy as jnp
-    from ray_tpu.ops.state_space import chunk_log_decay, ssd, ssd_reference
-    args = list(_scan_inputs(jax, seq, groups, step))
+    from ray_tpu.ops.state_space import (_heads_a_step, chunk_log_decay, ssd,
+                                         ssd_reference)
+    args = list(_scan_inputs(jax, seq, groups, step, heads=heads))
     for at in (0, 3, 4):
         args[at] = args[at].astype(dtype)
+    if heads > 4:
+        assert _heads_a_step(chunk, heads // groups) < heads // groups
     assert chunk_log_decay(args[1], args[2], chunk).shape == (
-        2, -(-seq // chunk), 4, chunk)
+        2, -(-seq // chunk), heads, chunk)
     if step == 3.0:
         assert float(chunk_log_decay(args[1], args[2], chunk).min()) < -200.0
     out, grads = _value_and_gradients(jax, lambda *a: ssd(*a, chunk=chunk),
@@ -305,9 +316,11 @@ def _xla_chunked(x, dt, a_log, b, c, d, *, chunk):
     return y.reshape(batch, seq, heads, width)
 
 
-@pytest.mark.parametrize("exact", [True, False], ids=["bfloat16_values",
-                                                      "float32_values"])
-def test_kernels_equal_the_xla_form_at_float32_rounding(jax_cpu, exact):
+@pytest.mark.parametrize("exact,heads", [(True, 4), (False, 4), (True, 32)],
+                         ids=["bfloat16_values", "float32_values",
+                              "bfloat16_values_two_blocks_of_a_group"])
+def test_kernels_equal_the_xla_form_at_float32_rounding(jax_cpu, exact,
+                                                        heads):
     """`LOWERED` (tests/test_lowered_steps.py) does not see a kernel's body,
     so this does: y and the six gradients of the kernels against the XLA
     form they replaced, all in float32, so that nothing hides under a
@@ -318,7 +331,7 @@ def test_kernels_equal_the_xla_form_at_float32_rounding(jax_cpu, exact):
     jax = jax_cpu
     import jax.numpy as jnp
     from ray_tpu.ops import state_space
-    args = list(_scan_inputs(jax, 64, 1, 0.05, seed=3))
+    args = list(_scan_inputs(jax, 64, 1, 0.05, seed=3, heads=heads))
     if exact:
         for at in (0, 3, 4):
             args[at] = args[at].astype(jnp.bfloat16).astype(jnp.float32)
@@ -352,6 +365,21 @@ def test_scan_keeps_the_inputs_type_and_names_what_remat_keeps(jax_cpu):
         lambda *a: jnp.sum(ssd(*a, chunk=32))))(x, *rest))
     assert text.count(f"name[name={SSD_OUT}]") == 2     # y, the chunk states
     assert text.count("name=ssd_fwd") == text.count("name=ssd_bwd") == 1
+
+
+def test_the_block_of_heads_follows_the_chunk_and_the_group():
+    """A grid step takes the most heads that divide a group's, stay within
+    16 and keep a chunk's [h, C, C] float32 tiles within the bytes
+    `_TILE_BYTES` gives one: nemotron's call (16 heads on a group, chunks of
+    128) is what it was, one block of 16; granite's 64 heads on one group at
+    chunks of 256 are four blocks of 16, and chunks of 512 would be blocks of 4."""
+    from ray_tpu.ops.state_space import _TILE_BYTES, _heads_a_step
+    assert _heads_a_step(128, 16) == 16 and _heads_a_step(128, 64) == 16
+    assert _heads_a_step(128, 12) == 12 and _heads_a_step(64, 24) == 12
+    assert _heads_a_step(256, 64) == 16 \
+        == _TILE_BYTES // (4 * 256 * 256)
+    assert _heads_a_step(512, 64) == 4 and _heads_a_step(512, 6) == 3
+    assert _heads_a_step(2048, 64) == 1             # never none
 
 
 def test_scan_refuses_what_the_chips_tiles_cannot_hold(jax_cpu):
