@@ -22,8 +22,13 @@ optional learned sparse-attention indexer on the attention
 layers (ops/indexer.py: it chooses the keys a query sees, and is trained by
 a loss of its own),
 per-layer jax.checkpoint (remat) for memory: a layer keeps its input and
-the flash kernel's output and row statistics (a linear-attention layer's
-output), and recomputes the rest. A layer is a mixer and then a feed-forward,
+what its kernels name (the flash kernel's output and row statistics, an
+indexer's selection, a linear-attention or state-space layer's output and
+chunk states) and recomputes the rest; where the devices' memory is
+reckoned to hold them beside the state, every layer's MLP also keeps its
+matmul results (MLP_OUT): `up x`, or `up x` and `gate x`
+(`mlp_products_kept`: from what the train step's builder reports,
+parallel/memory.py, and the shapes; nobody sets it). A layer is a mixer and then a feed-forward,
 or ONE of the two alone (`_HALVES`); optional scalar multipliers on the
 embedding, on what each half adds to the stream, on attention's scores and
 on the logits (`Multipliers`).
@@ -43,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import indexer, moe
@@ -57,7 +63,15 @@ from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
 from ray_tpu.ops.short_conv import short_conv, silu_conv
 from ray_tpu.ops.state_space import SSD_OUT, ssd
 from ray_tpu.ops.state_space import chunk_log_decay as ssm_log_decay
+from ray_tpu.parallel import memory
 from ray_tpu.parallel.sharding import MESH_AXES
+
+# The name an MLP's matmul results carry (`_mlp_block`: up x and gate x,
+# the pre-activations; jax.ad_checkpoint.checkpoint_name), as FLASH_OUT,
+# KDA_OUT and SSD_OUT name the kernels' results: a block that keeps it
+# (layer_fn's `keep_mlp(n)`) computes the named products once a layer and
+# step.
+MLP_OUT = "mlp_out"
 
 # GPTConfig.gate_activation and ExpertForm.activation: relu's derivative at
 # 0 is 0 (jax.nn.relu's), and so is relu2's, relu(.)^2.
@@ -279,6 +293,10 @@ class GPTConfig:
     #   XLA runs in the layer is recomputed in backward, the Pallas
     #   attention forward is not run a second time. The reference and ring
     #   paths name nothing to keep: the whole layer is recomputed there.
+    #   Where a train step's builder reports the devices' memory
+    #   (parallel/memory.py) every layer's MLP also keeps `up x`, or both
+    #   its matmul results, if they are reckoned to fit
+    #   (`mlp_products_kept`).
     # "none": save everything (max HBM, min FLOPs)
     remat_policy: str = "full"
     attention: str = "flash"          # flash | reference | ring
@@ -1348,19 +1366,26 @@ def _ssm_block(m, x, cfg: GPTConfig, where: Setting):
                                      m["w_out"].astype(dt_))), stats
 
 
-def _mlp_block(m, x, cfg: GPTConfig, where: Setting):
+def _mlp_block(m, x, cfg: GPTConfig, where: Setting, named: int = 0):
     """The gated MLP through m's three matrices, down(act(gate x) * up x)
     with cfg.gate_activation (SwiGLU by default), or, where m holds no gate
     matrix (cfg.expert_form: two matrices), down(act(up x)): a dense
-    layer's MLP, or the shared expert of a sparse one."""
+    layer's MLP, or the shared expert of a sparse one. named: how many of
+    its products carry the name MLP_OUT, up x first and gate x second
+    (layer_fn's keeping blocks, whose remat policy saves the name; the
+    value is the product either way)."""
     dt = cfg.dtype
     act = _ACTIVATIONS[cfg.feed_forward.activation]
+
+    def product(w, name):
+        y = jnp.einsum("bsd,df->bsf", x, m[w].astype(dt))
+        return checkpoint_name(y, MLP_OUT) if name else y
+
     if "w_gate" in m:
-        gate = jnp.einsum("bsd,df->bsf", x, m["w_gate"].astype(dt))
-        up = jnp.einsum("bsd,df->bsf", x, m["w_up"].astype(dt))
+        gate, up = product("w_gate", named > 1), product("w_up", named > 0)
         hidden = act(gate) * up
     else:
-        hidden = act(jnp.einsum("bsd,df->bsf", x, m["w_up"].astype(dt)))
+        hidden = act(product("w_up", named > 0))
     return where.psum(jnp.einsum("bsf,fd->bsd", hidden,
                                  m["w_down"].astype(dt)))
 
@@ -1554,7 +1579,8 @@ def _experts(x, weights, order, *matrices, held=None, activation="silu"):
     return (y, *flag, *(h[None] for h in hidden))
 
 
-def _moe_block(layer, x, cfg: GPTConfig, where: Setting, routing=None):
+def _moe_block(layer, x, cfg: GPTConfig, where: Setting, routing=None,
+               named_mlp: int = 0):
     """Sparse experts in the MLP's place: y = sum over a token's top-k of
     p_e x down_e(act(gate_e x) * up_e x), act = cfg.gate_activation (or, as
     cfg.expert_form says, down_e(act(up_e x)) with no gate matrix, and the
@@ -1627,7 +1653,7 @@ def _moe_block(layer, x, cfg: GPTConfig, where: Setting, routing=None):
         stats["expert_hidden_zero_share"] = zeros / jnp.maximum(units, 1.0)
     if "shared" in m:
         with jax.named_scope("moe_shared"):
-            y = y + _mlp_block(m["shared"], x, cfg, where)
+            y = y + _mlp_block(m["shared"], x, cfg, where, named_mlp)
     return y, stats
 
 
@@ -1642,7 +1668,17 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     say, as gpt_init built them). The one
     transformer block, rematted as cfg.remat_policy says, for whoever
     walks the layers: gpt_backbone loops over their list, a stage of
-    parallel/pipeline.py scans over stacked ones. Under cfg.route_from
+    parallel/pipeline.py scans over stacked ones. Under "full" it is ONE
+    body under a policy: the block returned keeps a layer's input and its
+    kernels' named results and computes every XLA matmul of the layer
+    again in the backward pass; its attribute `keep_mlp(n)` gives the
+    block that also keeps n of the MLP's matmul results (MLP_OUT,
+    [B, S, d_ff] each: up x, then gate x), so that the backward pass reads
+    them where it would compute them again (0: the block itself). Who
+    walks the layers runs ONE block for all of them, so that a step traces
+    as many kinds of layer as it did, with n reckoned from the devices'
+    memory (`mlp_products_kept`). Under "none" every block is the bare
+    body. Under cfg.route_from
     "input" a sparse layer's routing (`_routing`) is worked out from the
     normed INPUT under scope `route_ahead`, before the mixer, and handed
     across it to `_moe_block`, whose rows come from the normed stream
@@ -1672,7 +1708,7 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         """x + residual f: what a half adds to the stream."""
         return where.pin(x + (delta if residual == 1.0 else delta * residual))
 
-    def block(x, layer):
+    def block(x, layer, named_mlp=0):
         normed = _rmsnorm(x, layer["ln1"]["scale"], cfg.rmsnorm_eps)
         mixer_stats, routing = {}, None
         if cfg.route_from == "input" and "moe" in layer:
@@ -1697,11 +1733,12 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
             normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
         if "moe" in layer:
             with jax.named_scope("moe"):
-                delta, stats = _moe_block(layer, normed, cfg, where, routing)
+                delta, stats = _moe_block(layer, normed, cfg, where, routing,
+                                          named_mlp)
         else:
             with jax.named_scope("mlp"):
                 delta, stats = _mlp_block(
-                    layer["mlp"], normed, cfg, where), {}
+                    layer["mlp"], normed, cfg, where, named_mlp), {}
         return add(h, delta), {**stats, **mixer_stats}
 
     if cfg.remat_policy == "full":
@@ -1710,14 +1747,141 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         # delta-rule layer, its output and its chunks' states: `kda_bwd`
         # computes a chunk again from them; of a state-space layer, the
         # same two: the scan over the chunks runs once)
-        return jax.checkpoint(
-            block, policy=jax.checkpoint_policies.save_only_these_names(
-                FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK,
-                indexer.INDEX_GRADS, KDA_OUT, SSD_OUT))
+        names = (FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK,
+                 indexer.INDEX_GRADS, KDA_OUT, SSD_OUT)
+        save = jax.checkpoint_policies.save_only_these_names
+        recompute = jax.checkpoint(block, policy=save(*names))
+
+        @lru_cache(maxsize=None)
+        def keep_mlp(n):
+            # (the MLP's products carry their name in a block that keeps
+            # them and in no other: a step that keeps none is the text it
+            # was)
+            return recompute if n == 0 else jax.checkpoint(
+                partial(block, named_mlp=n), policy=save(*names, MLP_OUT))
+        recompute.keep_mlp = keep_mlp
+        return recompute
     if cfg.remat_policy != "none":
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(expected 'full' | 'none')")
+    block.keep_mlp = lambda n: block
     return block
+
+
+def _mlp_of(layer):
+    """The matrices of the layer's `_mlp_block`: its dense MLP's, or its
+    sparse block's shared expert's; None where it has neither."""
+    return layer.get("mlp", layer.get("moe", {}).get("shared"))
+
+
+def _a_devices_part(batch: int, seq: int, cfg: GPTConfig, where: Setting):
+    """-> (the rows of a [batch, seq] batch that one device holds, over how
+    many devices whole heads and an MLP's columns are cut: 'tensor'; the
+    residual stream is cut over the batch alone)."""
+    if where.act_sharding is not None:
+        batch = where.act_sharding.shard_shape(
+            (batch, seq, cfg.d_model))[0]
+    return batch, (where.mesh.shape.get(MESH_AXES["heads"], 1)
+                   if where.mesh is not None else 1)
+
+
+def _layer_bytes(layer, batch: int, seq: int, cfg: GPTConfig,
+                 where: Setting):
+    """-> (kept, products), bytes a device: what layer_fn's block keeps of
+    one layer with these parameters at [batch, seq] tokens (its input and
+    what its mixer's kernels name: the flash kernels' output and row
+    statistics, an indexer's selection and gradients, a delta-rule or
+    state-space layer's output and chunk states), and what `keep_mlp(n)`
+    keeps more, n = 0, 1, 2 (n of the MLP's matmul results, as many as it
+    has; 0 where the layer has no `_mlp_block`: `_mlp_of`). From the shapes alone;
+    tests/test_mlp_kept.py holds both to what jax.checkpoint saves of each
+    family's layers."""
+    batch, heads_over = _a_devices_part(batch, seq, cfg, where)
+    tokens, item = batch * seq, jnp.dtype(cfg.dtype).itemsize
+    named = 0
+    for kind, group in _GROUP.items():
+        if group not in layer or cfg.attention != "flash":
+            continue
+        heads = cfg.heads_of(kind)
+        named += tokens * heads * (
+            (cfg.v_head_dim or cfg.head_dim) * item + 4)   # out, lse
+        if "index" in layer[group]:
+            hi, di = cfg.index_heads, cfg.index_head_dim
+            named += batch * seq * seq + tokens * (
+                (hi * di + di) * item + hi * 4)
+    if "kda" in layer:
+        # (the kernels hold a head's columns in whole lane tiles)
+        heads, hd = cfg.n_heads, cfg.head_dim + -cfg.head_dim % LANES
+        named += tokens * heads * hd * item \
+            + batch * heads * (seq // 64) * hd * hd * 4
+    if "ssm" in layer:
+        size = cfg.ssm
+        named += tokens * size.heads * size.head_dim * item \
+            + batch * size.heads * -(-seq // size.chunk) \
+            * size.head_dim * size.state * 4
+    one, has = 0, 0
+    m = _mlp_of(layer)
+    if m is not None:
+        one = tokens * m["w_up"].shape[-1] * item // heads_over
+        has = len(m) - 1
+    return (tokens * cfg.d_model * item + named // heads_over,
+            tuple(min(n, has) * one for n in range(3)))
+
+
+def _working_set(layers, batch: int, seq: int, cfg: GPTConfig,
+                 where: Setting):
+    """-> (bytes a device needs beside what is kept while ONE of `layers`
+    is differentiated, bytes the head needs): the part of the peak that no
+    list of kept values shows. From the shapes, so that a cell of 65 536
+    tokens a device is given its 3 GB and one of 8192 its 1.2: a token's
+    rows of the widest layer with their cotangents (the MLP's and the
+    chosen experts' hidden rows; the mixer's q, k, v and output, an
+    indexer's scores over the sequence), beside the residual stream's
+    float32 copies; or the head's chunk of logits. The factors are whole
+    numbers under which the reckoned peak of every cell's step is at or
+    over what the chip read (PERF.md section 6, PR 63)."""
+    batch, heads_over = _a_devices_part(batch, seq, cfg, where)
+    tokens, item = batch * seq, jnp.dtype(cfg.dtype).itemsize
+
+    def rows(layer):
+        """The widths a token's activations take in `layer`: the
+        feed-forward's hidden rows, the mixer's."""
+        m = _mlp_of(layer)
+        hidden = 0 if m is None else m["w_up"].shape[-1]
+        if "moe" in layer:
+            # (of a token's chosen experts, at most those held here)
+            held, _, width = layer["moe"]["w_up"].shape
+            hidden += min(cfg.expert_top_k, held) * width
+        mixer = 0
+        for kind, group in _GROUP.items():
+            if group in layer:
+                wide = cfg.qk_head_dim + qk_padding(cfg.qk_head_dim)
+                mixer = cfg.heads_of(kind) * wide
+                if "wg" in layer[group]:        # the gate on the output
+                    mixer += layer[group]["wg"].shape[-1]
+                if "index" in layer[group]:
+                    mixer += 2 * seq            # float32 scores and their KL
+        if "kda" in layer:
+            mixer = 2 * cfg.n_heads * cfg.head_dim
+        if "ssm" in layer:
+            mixer = layer["ssm"]["w_xbc"].shape[-1]
+        if "conv" in layer:
+            mixer = 3 * cfg.d_model
+        return hidden, mixer
+
+    widest = max(_MLP_ROWS * hidden + _MIXER_ROWS * mixer
+                 for hidden, mixer in map(rows, layers))
+    layer = tokens * (widest * item // heads_over
+                      + _STREAM_COPIES * cfg.d_model * 4)
+    # (chunked_xent's default rows a chunk)
+    head = _rows_a_chunk(tokens, 16384) * cfg.vocab_size * item \
+        * _HEAD_COPIES // (2 * heads_over)
+    return layer, head
+
+
+# `_working_set`'s factors: tensors a hidden row, a mixer's row and the
+# residual stream take while one layer is differentiated.
+_MLP_ROWS, _MIXER_ROWS, _STREAM_COPIES, _HEAD_COPIES = 5, 4, 3, 3
 
 
 def final_norm(params, x, cfg: GPTConfig):
@@ -1794,11 +1958,13 @@ def _embed(params, tokens, cfg: GPTConfig, where: Setting):
 def _stack(params, tokens, cfg: GPTConfig, where: Setting):
     """tokens [B, S] through the embedding and the layers -> (the residual
     stream BEFORE the final norm, the statistics of each layer that has
-    any, the block: layer_fn's, for a caller that runs further layers at
-    this sequence length)."""
+    any, the block the layers ran: layer_fn's, keeping as many of the
+    MLPs' matmul results through the remat as `mlp_products_kept` reckons,
+    for a caller that runs further layers at this sequence length)."""
     with jax.named_scope("embed"):
         x = where.pin(_embed(params, tokens, cfg, where))
-    layer = layer_fn(cfg, tokens.shape[1], where)
+    layer = layer_fn(cfg, tokens.shape[1], where).keep_mlp(
+        mlp_products_kept(params, *tokens.shape, cfg, where))
     x, per_layer = _walk(layer, x, params["layers"])
     return x, per_layer, layer
 
@@ -1812,6 +1978,58 @@ def _walk(layer, x, layers):
         if stats:
             per_layer.append(stats)
     return x, per_layer
+
+
+def memory_plan(params, batch: int, seq: int, cfg: GPTConfig,
+                where: Setting, share: float):
+    """What memory.reckoned_peak reckons with, bytes a device of a step
+    over [batch, seq] tokens, in its order: the gradient born before the
+    layers' (of every parameter outside them but the embedding's table,
+    whose gradient comes last unless the head reads the table too), each
+    layer's gradient, the embedding's, what each layer keeps through the
+    remat keeping 0, 1 and 2 of its MLP's products (three lists), the
+    working set of one layer and of the head; the prediction module's
+    layers after the stack's. share: the part of the parameters' bytes
+    that a device holds."""
+    layers = params["layers"] + params.get("mtp", {}).get("layers", [])
+    grads = [int(share * memory.tree_bytes(layer)) for layer in layers]
+    after = (0 if cfg.tie_embeddings
+             else int(share * memory.tree_bytes(params["embed"])))
+    kept, products = zip(*(_layer_bytes(layer, batch, seq, cfg, where)
+                           for layer in layers))
+    held = [[k + p[n] for k, p in zip(kept, products)] for n in range(3)]
+    return (int(share * memory.tree_bytes(params)) - sum(grads) - after,
+            grads, after, held,
+            *_working_set(layers, batch, seq, cfg, where))
+
+
+def mlp_products_kept(params, batch: int, seq: int, cfg: GPTConfig,
+                      where: Setting) -> int:
+    """How many of its matmul results every layer's MLP keeps through the
+    remat (layer_fn's `keep_mlp(n)`): 0, 1 (up x) or 2 (gate x too):
+    observed, not set. The most that memory.reckoned_peak puts under
+    memory.CEILING of the devices' limit beside the state that the step's
+    builder reports (memory.budget: train/train_step.py gives it around
+    the trace); 0 where nobody reports (a loss differentiated by hand, the
+    serving forward), where the platform gives no limit (the CPU), and
+    under remat_policy "none", which keeps everything as it is. All layers
+    keep alike, so that the step traces and lowers as many kinds of layer
+    as it did (a second kind cost gpt2s 3.4-5 s of set-up: PERF.md section
+    6, PR 63). The traced step says what it chose (memory.report)."""
+    told = memory.budget()
+    if told is None or cfg.remat_policy != "full":
+        return 0
+    before, grads, after, held, working, head = memory_plan(
+        params, batch, seq, cfg, where, told.share)
+    peaks = [memory.reckoned_peak(told.state, before, grads, after, h,
+                                  working, head) for h in held]
+    n = memory.most_kept(told.limit, peaks)
+    while n and sum(held[n]) == sum(held[n - 1]):
+        n -= 1                       # no gate, or no MLP at all: no more kept
+    having = sum(h2 > h0 for h0, h2 in zip(held[0], held[2]))
+    memory.report(n, having, sum(held[n]) - sum(held[0]), peaks[n],
+                  told.limit)
+    return n
 
 
 def _layer_means(per_layer):

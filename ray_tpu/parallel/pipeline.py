@@ -21,11 +21,14 @@ body is told to psum over 'tensor').
 
 Memory: stage activations are carried through the scan (GPipe-style full
 activation footprint / num_microbatches granularity); per-layer remat
-(cfg.remat_policy) bounds the within-stage footprint.
+(cfg.remat_policy) bounds the within-stage footprint, and where the devices
+are reckoned to hold them (models/gpt.py:mlp_products_kept) every layer
+also keeps its MLP's matmul results through it.
 """
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict
 
 import jax
@@ -34,7 +37,8 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.models.gpt import (GPTConfig, Setting, final_norm, gpt_init,
-                                head_xent_recompute, layer_fn)
+                                head_xent_recompute, layer_fn,
+                                mlp_products_kept)
 from ray_tpu.parallel.sharding import (MESH_AXES, ShardingStrategy,
                                        _path_str)
 
@@ -154,7 +158,7 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
             "carries no period of kinds, head counts or rope tables) and "
             "no rule for a gate a head (attn/wg)")
 
-    def body(params, inputs, targets):
+    def body(keep_mlp, params, inputs, targets):
         # Per-device blocks: params["stacked"] [L/S, ...] (+tensor-sharded
         # matrices), inputs/targets [B/data, S].
         embed_tbl = params["embed"]["table"]
@@ -166,7 +170,7 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
                              f"microbatches {M}")
         inputs_mb = inputs.reshape(M, mb, s)
         targets_mb = targets.reshape(M, mb, s)
-        layer = layer_fn(cfg, s, where)
+        layer = layer_fn(cfg, s, where).keep_mlp(keep_mlp)
 
         n_ticks = M + n_stages - 1
 
@@ -214,10 +218,22 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
             lambda sharding: sharding.spec,
             strategy.param_shardings(mesh, pp_params))
         tokens = batch["tokens"]
+        # What a device keeps through the remat it keeps once a layer of
+        # its stage AND tick (the schedule differentiates a scan over
+        # ticks): reckoned as that many layers at a microbatch's tokens.
+        one = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+            pp_params["stacked"])
+        like = {**{k: v for k, v in pp_params.items() if k != "stacked"},
+                "layers": [one] * (cfg.n_layers // n_stages
+                                   * (M + n_stages - 1))}
+        keep_mlp = mlp_products_kept(
+            like, tokens.shape[0] // (mesh.shape["data"] * M),
+            tokens.shape[1] - 1, cfg, Setting())
         # check_vma off: the body mixes collectives manually, with
         # per-rank lax.cond branches the replication check rejects.
         fn = shard_map(
-            body, mesh=mesh,
+            partial(body, keep_mlp), mesh=mesh,
             in_specs=(param_specs, P("data"), P("data")),
             out_specs=P(), check_vma=False)
         return fn(pp_params, tokens[:, :-1], tokens[:, 1:])

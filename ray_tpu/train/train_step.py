@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.parallel import memory
 from ray_tpu.parallel.sharding import ShardingStrategy, strategy_from_name
 
 
@@ -113,6 +114,25 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
             return jax.value_and_grad(loss_fn)(params, batch)
 
     def _step(state: TrainState, batch):
+        with memory.told(_budget(state)):
+            return _traced_step(state, batch)
+
+    def _budget(state: TrainState) -> memory.Budget:
+        """What a device holds across the step, for a model that spends
+        spare memory on less recomputation (parallel/memory.py): the state
+        as its shardings cut it (whole where the caller gave no
+        sample_params), the accumulated gradient beside it."""
+        whole = memory.tree_bytes(state.params)
+        held = (whole if state_sh is None
+                else memory.tree_bytes(state.params, state_sh.params))
+        opt = memory.tree_bytes(
+            state.opt_state, None if state_sh is None else state_sh.opt_state)
+        return memory.Budget(
+            limit=memory.device_limit(mesh.devices.flat),
+            state=held + opt + (held if accum_steps else 0),
+            share=held / max(whole, 1))
+
+    def _traced_step(state: TrainState, batch):
         if accum_steps:
             def micro(carry, mb):
                 loss_sum, gacc = carry
@@ -145,6 +165,7 @@ def make_train_step(loss_fn: Callable, optimizer, mesh: Mesh,
         bspec = P(*((None,) + tuple(bspec)))
     batch_sh = NamedSharding(mesh, bspec)
     kwargs = {}
+    state_sh = None
     if donate:
         kwargs["donate_argnums"] = (0,)
     if sample_params is not None:

@@ -212,12 +212,20 @@ def heads_of_64_stay_by_token(text, rows, seq, heads, kv_heads):
 # A cell's whole step, compiled once a file
 # ---------------------------------------------------------------------------
 
+# A v5e's `bytes_limit`, which a described device does not report.
+V5E_BYTES = 16909336064
+
+
 class CellStep:
     """A one-chip cell's compiled train step: `text`, `memory`, the
     parameters' shapes, and the configuration and traffic it was built
-    from."""
+    from. limit: what the step's builder reads as the devices' memory
+    limit (parallel/memory.py:device_limit), which a described device has
+    none of: None compiles the step that keeps nothing more through the
+    remat, V5E_BYTES the one the chip runs; `kept` is what the traced step
+    then reported (memory.report's arguments)."""
 
-    def __init__(self, v5e, name):
+    def __init__(self, v5e, name, limit=None):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -225,6 +233,7 @@ class CellStep:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from benchmark import model
         from ray_tpu.ops import attention
+        from ray_tpu.parallel import memory
         from ray_tpu.parallel.sharding import strategy_from_name
         from ray_tpu.train.train_step import TrainState, make_train_step
 
@@ -246,9 +255,13 @@ class CellStep:
             lambda p, b: program.loss(p, b, mesh,
                                       strategy.activation_sharding(mesh)),
             optimizer, mesh, strategy, sample_params=params)
+        said = []
         with pytest.MonkeyPatch.context() as patch:
             patch.setattr(attention, "_default_interpret", lambda: False)
+            patch.setattr(memory, "device_limit", lambda devices: limit)
+            patch.setattr(memory, "report", lambda *a: said.append(a))
             compiled = step.lower(state, batch).compile()
+        self.kept, = said
         self.memory = compiled.memory_analysis()
         self.text = compiled.as_text()
         self.params = params
@@ -258,7 +271,10 @@ class CellStep:
 def cell_step(v5e, family):
     """The whole step of the file's cell (FAMILY.cell; the cell's own
     traffic, adamw over fp32 masters) for one described chip: compiled
-    once, read by every case of the file that asserts on its text."""
+    once, read by every case of the file that asserts on its text. (A
+    described device reports no memory limit, so this is the step that
+    keeps nothing more through the remat; tests/test_mlp_kept.py compiles
+    the two cells with the most to gain as the chip runs them.)"""
     return CellStep(v5e, family.cell)
 
 
