@@ -44,18 +44,22 @@ a state is [P, N] as the recurrence keeps it. `ssd_fwd` runs it over a grid
 (heads / h, chunks), h heads of one group a step (a group of more heads than
 h is several blocks), the chunks in order, the state in VMEM scratch: a
 chunk's x, B, C, dt and g cross HBM once and C B^T, the decays, the scores
-and both state products stay on the chip. `ssd_bwd` runs `jax.vjp` of the
-same function over the chunks from the last to the first, the state's
-cotangent in scratch: it computes the chunk again from its inputs and the
-state it started from, which the forward keeps ([heads, chunks, P, N]
-float32: 34 MB a layer at [1, 8192, 16, 64] on 128 in chunks of 128, 67 MB
-at [1, 8192, 64, 64] in chunks of 256), and writes the gradients, B's and
-C's a block of heads, float32 (XLA sums a group's blocks outside the
-kernel: one block at 16 heads a group, four or more at 64). g is the caller's cumulative sum (`chunk_log_decay`), so its
-transpose back onto dt and a_log is XLA's, over [B, S, H] numbers. The
-output and the kept states carry the name SSD_OUT, so that a remat policy
-that saves it runs the forward once a layer and step, as KDA_OUT does for
-the delta rule.
+and both state products stay on the chip. `ssd_bwd` runs the same
+function's transpose, written out (`_bwd_kernel`), over the chunks from the
+last to the first and, under each, a group's blocks of heads one after
+another, the state's cotangent of every head in scratch: it computes again,
+from a chunk's inputs and the state it started from, which the forward
+keeps ([heads, chunks, P, N] float32: 34 MB a layer at [1, 8192, 16, 64] on
+128 in chunks of 128, 67 MB at [1, 8192, 64, 64] in chunks of 256), only
+what the transposes read, and writes every gradient as its owner takes it:
+dx in x's type, B's and C's once a group in theirs (summed over the
+group's blocks of heads in VMEM: one block at 16 heads a group, four at
+64), dt's with g's transpose applied. g is the caller's cumulative sum
+(`chunk_log_decay`), forward; backward the kernel sums g's cotangent back
+over the later tokens of the chunk (one product against a triangle of
+ones) onto dt, and onto a_log a chunk. The output and the kept states carry
+the name SSD_OUT, so that a remat policy that saves it runs the forward
+once a layer and step, as KDA_OUT does for the delta rule.
 
 Every product is the float32 one at full precision (`_product`): the sum,
 in a float32 accumulator, of the bfloat16 terms' products that
@@ -67,8 +71,10 @@ and the sum is the same sum. That is why dt rides on the scores' columns
 and on the decays to the chunk's end, never on x: in y = scores x and in
 the state's x^T B the x and B operands are exact (three passes instead of
 six), C B^T is exact on both sides (one), and C S_0 is exact in C (three).
-A cotangent is float32 and always three terms. Float32 inputs take all six
-passes everywhere.
+Backward the same holds of dy: the cotangent of a bfloat16 y arrives as
+bfloat16 and is one term (dy^T x one pass, dy W three), while a cotangent
+that is float32 by nature (the state's, e^g dy, the scores') is three.
+Float32 inputs, and a float32 dy, take all six passes everywhere.
 """
 
 from __future__ import annotations
@@ -139,7 +145,7 @@ def _terms(a, count: int):
     holds a bfloat16 value (the caller's word), else the three that add up
     to every bit of it."""
     bf16, f32 = jnp.bfloat16, jnp.float32
-    terms = [a.astype(bf16)]
+    terms = [a.astype(bf16)]          # a bfloat16 a is its own one term
     for _ in range(count - 1):
         a = a - terms[-1].astype(f32)
         terms.append(a.astype(bf16))
@@ -164,7 +170,9 @@ def _dot(a, b, contract, terms):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _product(a, b, contract, terms):
     """`_dot`, with the transposes that are the same kind of product: a
-    cotangent is three terms, and an operand keeps the count it had."""
+    cotangent is three terms, and an operand keeps the count it had (what
+    JAX's own transpose of `_chunk` can know: the backward kernel's oracle
+    in the tests)."""
     return _dot(a, b, contract, terms)
 
 
@@ -220,7 +228,8 @@ def _chunk(x, b, c, dt, g, d, state, exact):
     [h, 1, 1] and the state the chunk starts from [h, P, N] -> (y [h, P,
     C], the state after the chunk). `exact` = (x, b, c): whether each holds
     bfloat16 values. The one statement of the chunked form: the forward
-    kernel runs it and the backward kernel runs its jax.vjp."""
+    kernel runs it, the backward kernel its transpose written out, which
+    tests/test_state_space.py holds to this function's jax.vjp."""
     heads, width, chunk = x.shape
     of_x, of_b, of_c = (1 if e else 3 for e in exact)
     t = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 1)
@@ -261,30 +270,138 @@ def _fwd_kernel(exact, x_ref, b_ref, c_ref, dt_ref, g_ref, d_ref, y_ref,
     y_ref[...] = y.astype(y_ref.dtype)
 
 
-def _bwd_kernel(exact, x_ref, b_ref, c_ref, dt_ref, g_ref, d_ref, states_ref,
-                dy_ref, dx_ref, db_ref, dc_ref, ddt_ref, dg_ref, dd_ref,
-                dstate):
-    """Grid (heads / h, chunks), the chunks from the last to the first:
-    `dstate` [h, P, N] carries the cotangent of the state a chunk hands on.
-    A chunk is computed again from its inputs and the state it started
-    from, and transposed."""
-    f32 = jnp.float32
+def _tiles(x, dy, dt, g, scores, of_x: int, of_dy: int):
+    """What a chunk's transpose does on [C, C] tiles, for k of the step's
+    heads: x and dy [k, P, C] (in the type they arrived in), dt and g [k,
+    1, C], scores = C B^T [C, C] -> (the weights' part of dx [k, P, C]; the
+    scores' cotangent summed over the k heads [C, C]; as rows [k, 1, C]:
+    dt's cotangent from the weights, and g's as the later token of a
+    pair). The decays and the weights W = between scores dt are computed
+    again as `_chunk` states them; y is not."""
+    chunk = g.shape[-1]
+    t = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 1)
+    s = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 2)
+    diagonal = t == s
+    down = jnp.sum(jnp.where(diagonal, g, 0.0), axis=2, keepdims=True)
+    between = jnp.exp(jnp.where(s <= t, down - g, -jnp.inf))   # [k, C, C]
+    unstepped = between * scores
+    # W's cotangent, dy^T x: both operands as they arrived
+    dw = _dot(dy, x, (1, 1), (of_dy, of_x))                    # [t, s]
+    dx = _dot(dy, unstepped * dt, (2, 1), (of_dy, 3))
+    stepped = dw * dt
+    # the exponent's cotangent is dW W: its row sums are g's as t (a
+    # column, turned to a row on the diagonal as g was turned down), its
+    # column sums dt times what dt's own cotangent sums
+    as_first = jnp.sum(stepped * unstepped, axis=2, keepdims=True)
+    return (dx, jnp.sum(stepped * between, axis=0),
+            jnp.sum(dw * unstepped, axis=1, keepdims=True),
+            jnp.sum(jnp.where(diagonal, as_first, 0.0), axis=1,
+                    keepdims=True))
 
-    @pl.when(pl.program_id(1) == 0)
+
+def _bwd_kernel(exact, blocks, x_ref, b_ref, c_ref, dt_ref, g_ref, d_ref,
+                a_ref, states_ref, dy_ref, dx_ref, db_ref, dc_ref, ddt_ref,
+                da_ref, dd_ref, dstate, dx_tiles, rows, dscores, *of_group):
+    """Grid (chunks, heads / h), the chunks from the last to the first and
+    under each the blocks of heads, a group's `blocks` one after another:
+    `dstate` [heads, P, N] carries, for every head of the call, the
+    cotangent of the state a chunk hands on; B's and C's blocks stay where
+    they are over a group's blocks of heads, and their cotangents are
+    summed in `of_group` [2, C, N] (float32) and written, in their type, at
+    the group's last block (one block a group writes them as they come).
+    `_chunk`'s transpose, written out: of the chunk only what the
+    transposes read is computed again (C B^T, the decays, the weights and
+    what the starting state gives each token, which g's cotangent reads),
+    and every product is `_dot`'s sum with the terms its operands HAVE: dy
+    that arrives as bfloat16 is one term (dy^T x one pass at exact x, dy W
+    three), a cotangent that is float32 by nature (e^g dy, the state's, the
+    scores') three. The [C, C] tiles are walked `_tile_heads` heads at a
+    time (`_tiles`), their results through scratch: `dx_tiles` [h, P, C],
+    `rows` [2, h, 1, C], `dscores` [C, C]."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    heads, width, chunk = x_ref.shape
+    of_x, of_b, of_c = (1 if e else 3 for e in exact)
+    of_dy = 1 if dy_ref.dtype == bf16 else 3
+    rows_of = lambda v: v.reshape(heads * width, -1)
+
+    block = pl.program_id(1)
+    mine = pl.ds(block * heads, heads)
+
+    @pl.when(pl.program_id(0) == 0)
     def _():
-        dstate[...] = jnp.zeros_like(dstate)
-    _, pull = jax.vjp(
-        functools.partial(_chunk, exact=exact),
-        x_ref[...].astype(f32), b_ref[0].astype(f32), c_ref[0].astype(f32),
-        dt_ref[:, 0], g_ref[:, 0], d_ref[...], states_ref[:, 0])
-    dx, db, dc, ddt, dg, dd, dstate[...] = pull(
-        (dy_ref[...].astype(f32), dstate[...]))
-    dx_ref[...] = dx.astype(dx_ref.dtype)
-    db_ref[0] = db
-    dc_ref[0] = dc
-    ddt_ref[:, 0] = ddt
-    dg_ref[:, 0] = dg
-    dd_ref[:, 0] = dd
+        dstate[mine] = jnp.zeros((heads,) + dstate.shape[1:], f32)
+    b, c = b_ref[0], c_ref[0]
+    scores = _dot(c, b, (1, 1), (of_c, of_b))                  # [t, s]
+    some = _tile_heads(chunk, heads)
+    dscores[...] = jnp.zeros_like(dscores)
+
+    def walk(i, carry):
+        at = pl.ds(i * some, some)
+        dx_tiles[at], ds, rows[0, at], rows[1, at] = _tiles(
+            x_ref[at], dy_ref[at], dt_ref[at, 0], g_ref[at, 0], scores,
+            of_x, of_dy)
+        dscores[...] += ds
+        return carry
+    jax.lax.fori_loop(0, heads // some, walk, None)
+
+    x, dy = x_ref[...].astype(f32), dy_ref[...].astype(f32)
+    dt, g, state, handed = dt_ref[:, 0], g_ref[:, 0], states_ref[:, 0], \
+        dstate[mine]
+    last = jax.lax.broadcasted_iota(jnp.int32, g.shape, 2) == chunk - 1
+    whole = jnp.sum(jnp.where(last, g, 0.0), axis=2, keepdims=True)  # g_C
+    decay, to_end = jnp.exp(g), jnp.exp(whole - g)                # [h, 1, C]
+    reach = to_end * dt                  # `_chunk`'s to_end: x's way to S
+    kept = jnp.exp(whole)                                         # [h, 1, 1]
+    over = lambda v: jnp.sum(jnp.sum(v, axis=1, keepdims=True), axis=2,
+                             keepdims=True)
+    carried = _dot(rows_of(state), c, (1, 1), (3, of_c)).reshape(x.shape)
+    dcarried = decay * dy
+    # the cotangent of x reach, the operand of the state's x^T B, and reach's
+    dadded = _dot(rows_of(handed), b, (1, 1), (3, of_b)).reshape(x.shape)
+    dreach = jnp.sum(x * dadded, axis=1, keepdims=True)           # [h, 1, C]
+    dx_ref[...] = (dx_tiles[...] + d_ref[...] * dy
+                   + reach * dadded).astype(dx_ref.dtype)
+    dd_ref[:, 0] = over(dy * x)
+    dwhole = (jnp.sum(reach * dreach, axis=2, keepdims=True)
+              + kept * over(handed * state))
+    dg = (rows[1] - dt * rows[0]
+          + decay * jnp.sum(dy * carried, axis=1, keepdims=True)
+          - reach * dreach + jnp.where(last, dwhole, 0.0))
+    # g_u = -e^{a_log} sum_{s <= u} dt_s, `chunk_log_decay`'s: a_log's
+    # cotangent is sum_u dg_u g_u and dt_s's -e^{a_log} sum_{u >= s} dg_u,
+    # every head's sums in ONE product against a triangle of ones (exact
+    # in one term)
+    da_ref[:, 0] = jnp.sum(dg * g, axis=2, keepdims=True)
+    later = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+             >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
+    after = _dot(dg.reshape(heads, chunk), later.astype(bf16), (1, 0),
+                 (3, 1)).reshape(g.shape)
+    ddt_ref[:, 0] = rows[0] + to_end * dreach - jnp.exp(a_ref[...]) * after
+    dstate[mine] = kept * handed + _dot(
+        rows_of(dcarried), c, (1, 0), (3, of_c)).reshape(handed.shape)
+    db = (_dot(dscores[...], c, (0, 0), (3, of_c))
+          + _dot(rows_of(x * reach), rows_of(handed), (0, 0), (3, 3)))
+    dc = (_dot(dscores[...], b, (1, 0), (3, of_b))
+          + _dot(rows_of(dcarried), rows_of(state), (0, 0), (3, 3)))
+    if blocks == 1:
+        db_ref[0], dc_ref[0] = db.astype(db_ref.dtype), dc.astype(dc_ref.dtype)
+        return
+    summed, = of_group
+    at = jax.lax.rem(block, blocks)
+
+    @pl.when(at == 0)
+    def _():
+        summed[0], summed[1] = db, dc
+
+    @pl.when(at > 0)
+    def _():
+        summed[0] += db
+        summed[1] += dc
+
+    @pl.when(at == blocks - 1)
+    def _():
+        db_ref[0] = summed[0].astype(db_ref.dtype)
+        dc_ref[0] = summed[1].astype(dc_ref.dtype)
 
 
 # Heads a grid step, at most (and all of one group): their products are
@@ -295,28 +412,63 @@ def _bwd_kernel(exact, x_ref, b_ref, c_ref, dt_ref, g_ref, d_ref, states_ref,
 _HEADS = 16
 
 # The bytes ONE [h, C, C] float32 tile of a grid step may take (the decays
-# between two tokens, the scores under them, their bfloat16 terms and, in
-# the backward, the cotangent of each: `jax.vjp` of `_chunk` holds about a
-# dozen at once, beside x, the state and their cotangents at h P (C + N)
-# floats each, inside the 64 MB the call may use). A tile is 4 C^2 a head:
-# 16 heads of chunks of 128 are 1 MB, of chunks of 256 4 MB, and at chunks
-# of 512 a step takes 4. At [1, 8192, 64, 64] on one group of 128 in chunks
-# of 256, a v5e read 0.81 / 3.65 ms forward / forward and gradient at 16
-# heads a step, 0.88 / 3.76 at 8, 1.02 / 4.09 at 4 (and 0.67 / 2.83 in
-# chunks of 128 at 16).
+# between two tokens, the scores under them and their bfloat16 terms; the
+# forward holds a few at once, beside x and the state at h P (C + N) floats
+# each, and the backward walks its own a megabyte at a time: _WALK_BYTES).
+# A tile is 4 C^2 a head: 16 heads of chunks of 128 are 1 MB, of chunks of
+# 256 4 MB, and at chunks of 512 a step takes 4. At [1, 8192, 64, 64] on
+# one group of 128 in chunks of 256, a v5e read 0.81 / 3.65 ms forward /
+# forward and gradient at 16 heads a step, 0.88 / 3.76 at 8, 1.02 / 4.09
+# at 4 (and 0.67 / 2.83 in chunks of 128 at 16), with `jax.vjp(_chunk)` as
+# the backward's body.
 _TILE_BYTES = 4 << 20
+
+
+def _most_heads(of: int, most: int, chunk: int, tile_bytes: int) -> int:
+    """The most heads that divide `of`, stay within `most` and keep a
+    chunk's [n, C, C] float32 tile within tile_bytes (never none)."""
+    most = max(1, min(most, tile_bytes // (4 * chunk * chunk)))
+    return max(n for n in range(1, most + 1) if of % n == 0)
 
 
 def _heads_a_step(chunk: int, per_group: int) -> int:
     """The block of heads of one grid step: the most that divide a group's
     heads, stay within _HEADS and keep a chunk's [h, C, C] tiles within
     _TILE_BYTES each."""
-    most = max(1, min(_HEADS, _TILE_BYTES // (4 * chunk * chunk)))
-    return max(n for n in range(1, most + 1) if per_group % n == 0)
+    return _most_heads(per_group, _HEADS, chunk, _TILE_BYTES)
 
+
+# The bytes of ONE [k, C, C] float32 tile of the heads the backward walks at
+# a time (`_tiles`): the tiles' elementwise work is a pass over VMEM an
+# operation, so a walk of few heads stores and loads less between two
+# operations, and a walk of many batches more matrix passes. At [1, 8192,
+# 64, 64] on one group of 128 in chunks of 256 (16 heads a grid step), a
+# v5e read `ssd_bwd` 2.42 / 2.21 / 2.08 / 2.11 ms a call at 1 / 2 / 4 / 16
+# heads a walk, and at [1, 8192, 16, 64] in chunks of 128 0.77 / 0.55 /
+# 0.53 / 0.47: 1 MB either way.
+_WALK_BYTES = 1 << 20
+
+
+def _tile_heads(chunk: int, heads: int) -> int:
+    """The heads of a grid step's `heads` that `_tiles` takes at a time:
+    the most that divide them and keep a [k, C, C] tile in _WALK_BYTES."""
+    return _most_heads(heads, heads, chunk, _WALK_BYTES)
+
+
+# What a call may take of VMEM. XLA keeps that much free ACROSS the call, so
+# what its neighbours hold there (an MLP's operands, prefetched) is evicted
+# by a limit the body does not need: with 64 MB granite's step read 398.5
+# ms, with 32 MB 395.2 (`mlp` 189.6 -> 186.3 ms a step). At 16 heads of
+# chunks of 256 the forward's body takes 15 MB and the backward's 19, and
+# _TILE_BYTES / _WALK_BYTES bound both at any chunk.
+_VMEM_LIMIT = 32 << 20
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"),
-    vmem_limit_bytes=64 << 20)
+    vmem_limit_bytes=_VMEM_LIMIT)
+# the backward sums over a group's blocks of heads, its inner axis
+_BWD_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary"),
+    vmem_limit_bytes=_VMEM_LIMIT)
 
 
 @functools.lru_cache(maxsize=None)
@@ -327,21 +479,25 @@ def _make_ssd_fn(chunk: int, per_group: int, exact, interpret: bool):
     residuals are the six inputs and the chunks' states."""
     h = _heads_a_step(chunk, per_group)
 
-    def specs(width, n, order):
-        x = pl.BlockSpec((h, width, chunk), lambda i, j: (i, 0, order(j)))
-        shared = pl.BlockSpec(
-            (1, chunk, n),
-            lambda i, j: (jax.lax.div(i * h, per_group), order(j), 0))
-        row = pl.BlockSpec((h, 1, 1, chunk), lambda i, j: (i, order(j), 0, 0))
-        skip = pl.BlockSpec((h, 1, 1), lambda i, j: (i, 0, 0))
-        states = pl.BlockSpec((h, 1, width, n),
-                              lambda i, j: (i, order(j), 0, 0))
-        return x, shared, row, skip, states
+    def specs(width, n, at):
+        """The blocks of a grid whose two indices `at` turns into (block of
+        heads, chunk)."""
+        def spec(shape, where):
+            return pl.BlockSpec(shape, lambda *ids: where(*at(*ids)))
+        x = spec((h, width, chunk), lambda i, j: (i, 0, j))
+        shared = spec((1, chunk, n),
+                      lambda i, j: (jax.lax.div(i * h, per_group), j, 0))
+        row = spec((h, 1, 1, chunk), lambda i, j: (i, j, 0, 0))
+        skip = spec((h, 1, 1), lambda i, j: (i, 0, 0))
+        states = spec((h, 1, width, n), lambda i, j: (i, j, 0, 0))
+        per_chunk = spec((h, 1, 1, 1), lambda i, j: (i, j, 0, 0))
+        return x, shared, row, skip, states, per_chunk
 
     def forward(x, b, c, dt, g, d):
         heads, width, tokens = x.shape
         n, chunks = b.shape[-1], tokens // chunk
-        wide, shared, row, skip, states = specs(width, n, lambda j: j)
+        wide, shared, row, skip, states, _ = specs(width, n,
+                                                   lambda i, j: (i, j))
         return pl.pallas_call(
             functools.partial(_fwd_kernel, exact),
             grid=(heads // h, chunks),
@@ -357,50 +513,49 @@ def _make_ssd_fn(chunk: int, per_group: int, exact, interpret: bool):
         )(x, b, c, dt, g, d)
 
     @jax.custom_vjp
-    def f(x, b, c, dt, g, d):
+    def f(x, b, c, dt, g, d, a_log):
         return forward(x, b, c, dt, g, d)[0]
 
-    def fwd(x, b, c, dt, g, d):
+    def fwd(x, b, c, dt, g, d, a_log):
         y, states = forward(x, b, c, dt, g, d)
         # both kept by a remat policy that saves the name: the forward runs
         # once a layer and step
         return checkpoint_name(y, SSD_OUT), (
-            x, b, c, dt, g, d, checkpoint_name(states, SSD_OUT))
+            x, b, c, dt, g, d, a_log, checkpoint_name(states, SSD_OUT))
 
     def bwd(residuals, dy):
-        x, b, c, dt, g, d, kept = residuals
+        x, b, c, dt, g, d, a_log, kept = residuals
         heads, width, tokens = x.shape
         n, chunks = b.shape[-1], tokens // chunk
-        wide, shared, row, skip, states = specs(width, n,
-                                                lambda j: chunks - 1 - j)
+        # the chunks from the last, outside; a group's blocks of heads inside
+        wide, shared, row, skip, states, per_chunk = specs(
+            width, n, lambda j, i: (i, chunks - 1 - j))
         f32 = jnp.float32
-        # B's and C's gradients a block of heads, d's a chunk
-        block = pl.BlockSpec((1, chunk, n),
-                             lambda i, j: (i, chunks - 1 - j, 0))
-        dx, db, dc, ddt, dg, dd = pl.pallas_call(
-            functools.partial(_bwd_kernel, exact),
-            grid=(heads // h, chunks),
-            in_specs=[wide, shared, shared, row, row, skip, states, wide],
-            out_specs=[wide, block, block, row, row,
-                       pl.BlockSpec((h, 1, 1, 1),
-                                    lambda i, j: (i, chunks - 1 - j, 0, 0))],
+        blocks = per_group // h
+        dx, db, dc, ddt, da, dd = pl.pallas_call(
+            functools.partial(_bwd_kernel, exact, blocks),
+            grid=(chunks, heads // h),
+            in_specs=[wide, shared, shared, row, row, skip, skip, states,
+                      wide],
+            out_specs=[wide, shared, shared, row, per_chunk, per_chunk],
             out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                       jax.ShapeDtypeStruct((heads // h, tokens, n), f32),
-                       jax.ShapeDtypeStruct((heads // h, tokens, n), f32),
+                       jax.ShapeDtypeStruct(b.shape, b.dtype),
+                       jax.ShapeDtypeStruct(c.shape, c.dtype),
                        jax.ShapeDtypeStruct(dt.shape, f32),
-                       jax.ShapeDtypeStruct(g.shape, f32),
+                       jax.ShapeDtypeStruct((heads, chunks, 1, 1), f32),
                        jax.ShapeDtypeStruct((heads, chunks, 1, 1), f32)],
-            scratch_shapes=[pltpu.VMEM((h, width, n), f32)],
-            compiler_params=_PARAMS,
+            scratch_shapes=[pltpu.VMEM((heads, width, n), f32),
+                            pltpu.VMEM((h, width, chunk), f32),
+                            pltpu.VMEM((2, h, 1, chunk), f32),
+                            pltpu.VMEM((chunk, chunk), f32)]
+            + [pltpu.VMEM((2, chunk, n), f32)] * (blocks > 1),
+            compiler_params=_BWD_PARAMS,
             interpret=interpret,
             name="ssd_bwd",
-        )(x, b, c, dt, g, d, kept, dy)
-
-        def of_group(t, like):
-            return t.reshape(like.shape[0], per_group // h, tokens, n).sum(
-                1).astype(like.dtype)
-        return (dx, of_group(db, b), of_group(dc, c), ddt, dg,
-                dd.sum(1, keepdims=True)[..., 0])
+        )(x, b, c, dt, g, d, a_log, kept, dy)
+        of_head = lambda t: t.sum(1, keepdims=True)[..., 0]
+        # g's cotangent went onto dt and a_log inside the kernel
+        return (dx, db, dc, ddt, jnp.zeros_like(g), of_head(dd), of_head(da))
 
     f.defvjp(fwd, bwd)
     return f
@@ -447,10 +602,17 @@ def _scan(x, dt, a_log, b, c, d, chunk: int, exact, interpret: bool):
         return whole_chunks(t).transpose(0, 2, 1, 3).reshape(
             batch * groups, chunks * chunk, n)
     steps = whole_chunks(dt.astype(f32)).reshape(batch, chunks, chunk, heads)
+
+    def a_head(t):                                  # [H] -> [B H, 1, 1]
+        return jnp.broadcast_to(t.astype(f32), (batch, heads)).reshape(
+            -1, 1, 1)
+    # g is the caller's cumulative sum, forward; its transpose onto dt and
+    # a_log is the backward kernel's, so nothing flows back through it here
+    # and a_log rides along for its cotangent
+    g = jax.lax.stop_gradient(chunk_log_decay(dt, a_log, chunk))
     y = _make_ssd_fn(chunk, heads // groups, exact, interpret)(
         whole_chunks(x).transpose(0, 2, 3, 1).reshape(
             batch * heads, width, chunks * chunk),
-        shared(b), shared(c), rows(steps.transpose(0, 1, 3, 2)),
-        rows(chunk_log_decay(dt, a_log, chunk)),
-        jnp.broadcast_to(d.astype(f32), (batch, heads)).reshape(-1, 1, 1))
+        shared(b), shared(c), rows(steps.transpose(0, 1, 3, 2)), rows(g),
+        a_head(d), a_head(a_log))
     return y.reshape(batch, heads, width, -1).transpose(0, 3, 1, 2)[:, :seq]

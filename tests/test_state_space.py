@@ -353,6 +353,164 @@ def test_kernels_equal_the_xla_form_at_float32_rounding(jax_cpu, exact,
             name, float(jnp.max(jnp.abs(g - w))), top)
 
 
+def _chunks_differentiated(x, dt, a_log, b, c, d, *, chunk, exact):
+    """`state_space._chunk` a chunk and group under a `lax.scan`, as plain
+    jnp, on operands laid out as the kernels' (tokens last), with g
+    `chunk_log_decay`'s: differentiated by JAX this is `jax.vjp(_chunk)` a
+    chunk (`_product`'s and `_spread`'s rules: every cotangent three
+    terms) and XLA's transpose of the cumulative sum, which is what
+    `ssd_bwd` was until PR 64 wrote the transpose out. Its oracle."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.state_space import _chunk, chunk_log_decay
+    f32 = jnp.float32
+    batch, seq, heads, width = x.shape
+    groups, n = b.shape[-2:]
+    per, pad = heads // groups, -seq % chunk
+    chunks = (seq + pad) // chunk
+
+    def cut(t):      # [B, S, ...] -> [chunks, B, chunk, ...], whole chunks
+        t = jnp.pad(t.astype(f32), ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        return jnp.moveaxis(t.reshape(batch, chunks, chunk, *t.shape[2:]), 1, 0)
+    g = jnp.moveaxis(chunk_log_decay(dt, a_log, chunk), 1, 0)   # [c, B, H, C]
+    skip = d.astype(f32).reshape(groups, per, 1, 1)
+
+    def a_group(state, xs):
+        x_c, b_c, c_c, dt_c, g_c, skip_g = xs
+        return _chunk(x_c.transpose(1, 2, 0), b_c, c_c, dt_c.T[:, None],
+                      g_c[:, None], skip_g, state, exact)
+
+    def a_chunk(states, xs):
+        x_c, b_c, c_c, dt_c, g_c = xs             # [B, C, H, P], .., [B, H, C]
+        y, states = jax.vmap(jax.vmap(a_group, in_axes=(0, (1, 1, 1, 1, 0, 0)),
+                                      out_axes=(0, 0)),
+                             in_axes=(0, (0, 0, 0, 0, 0, None)))(
+            states, (x_c.reshape(batch, chunk, groups, per, width), b_c, c_c,
+                     dt_c.reshape(batch, chunk, groups, per),
+                     g_c.reshape(batch, groups, per, chunk), skip))
+        return states, y                           # y [B, G, per, P, C]
+    _, y = jax.lax.scan(
+        a_chunk, jnp.zeros((batch, groups, per, width, n), f32),
+        (cut(x), cut(b), cut(c), cut(dt), g))
+    y = y.transpose(1, 0, 5, 2, 3, 4).reshape(batch, chunks * chunk, heads,
+                                              width)
+    return y[:, :seq].astype(x.dtype)
+
+
+@pytest.mark.parametrize("types,declared,chunk,seq,heads,groups,a_step", [
+    ("bfloat16", (True,) * 3, 128, 200, 4, 1, None),
+    ("bfloat16", (True,) * 3, 256, 300, 8, 1, 2),
+    ("float32", (True,) * 3, 128, 256, 8, 1, 2),
+    ("float32", (False,) * 3, 256, 512, 4, 1, None),
+    ("float32", (False,) * 3, 128, 200, 16, 2, 2),
+    ("x_bfloat16", (True, False, False), 128, 256, 4, 2, None),
+], ids=["cell_types_chunk128_ragged", "cell_types_chunk256_four_blocks_ragged",
+        "exact_values_float32_dy_four_blocks", "float32_chunk256",
+        "float32_two_groups_of_four_blocks_ragged", "bfloat16_x_alone"])
+def test_the_written_transpose_equals_the_chunks_vjp(
+        jax_cpu, monkeypatch, types, declared, chunk, seq, heads, groups,
+        a_step):
+    """`ssd_bwd`'s body is `_chunk`'s transpose written by hand (PR 64): it
+    leaves out the pairs of terms that are exactly zero (dy that arrives as
+    bfloat16 is ONE term), sums g's cotangent back onto dt and a_log itself
+    and a group's dB and dC over its blocks of heads. Held here, all six
+    gradients, to JAX's own transpose of the same `_chunk` on the same
+    values (`_chunks_differentiated`): the float32 ones at float32
+    rounding, those that leave in bfloat16 at one rounding of theirs. The
+    cases: x / B / C exact or not, dy bfloat16 or float32, chunks of 128
+    and 256, one block of heads a group and four (`_HEADS` lowered: the
+    block follows from the shape), a ragged tail."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import state_space
+    if a_step:
+        monkeypatch.setattr(state_space, "_HEADS", a_step)
+        assert heads // groups == 4 * state_space._heads_a_step(
+            chunk, heads // groups)
+    state_space._make_ssd_fn.cache_clear()
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = list(_scan_inputs(jax, seq, groups, 0.05, seed=5, heads=heads))
+    args[0], args[1] = args[0][:1], args[1][:1]              # one sequence
+    args[3], args[4] = args[3][:1], args[4][:1]
+    for at, exact in zip((0, 3, 4), declared):
+        if exact:                                  # bfloat16 VALUES
+            args[at] = args[at].astype(bf16).astype(f32)
+        if types == "bfloat16" or (types == "x_bfloat16" and at == 0):
+            args[at] = args[at].astype(bf16)
+    weight = jnp.cos(0.37 * jnp.arange(args[0].size).reshape(args[0].shape))
+
+    def gradients(fn):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a).astype(f32)
+                                                   * weight),
+                                argnums=tuple(range(6))))(*args)
+    try:
+        got = gradients(lambda *a: state_space._scan(
+            *a, chunk=chunk, exact=declared, interpret=True))
+    finally:
+        state_space._make_ssd_fn.cache_clear()
+    want = gradients(lambda *a: _chunks_differentiated(
+        *a, chunk=chunk, exact=declared))
+    # read when this was written (of each one's largest value): x, b, c, d
+    # and dt at most 4.7e-7, a_log (a head's sum over the sequence of
+    # terms that cancel) 1.9e-6 to 3.4e-5
+    for name, g, w, like in zip(("x", "dt", "a_log", "b", "c", "d"), got,
+                                want, args):
+        assert g.dtype == w.dtype == like.dtype, name
+        top = max(float(jnp.max(jnp.abs(w.astype(f32)))), 1.0)
+        tol = (2.0 ** -8 if like.dtype == bf16
+               else 1e-4 if name == "a_log" else 2e-6)
+        worst = float(jnp.max(jnp.abs(g.astype(f32) - w.astype(f32))))
+        assert worst < tol * top, (name, worst, top)
+
+
+def _dots_of(jaxpr):
+    """How many dot_generals a jaxpr holds, its sub-jaxprs' counted once
+    each (a loop's body is one head's)."""
+    from jax._src import core
+    return sum(
+        (eqn.primitive.name == "dot_general")
+        + sum(_dots_of(sub) for sub in core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("dtype,dots", [("bfloat16", 35), ("float32", 63)])
+def test_the_backward_multiplies_the_terms_its_operands_have(jax_cpu, dtype,
+                                                             dots):
+    """`LOWERED` does not see a kernel's body (D24), so the count stands
+    here: `ssd_bwd` at granite's block (16 heads of 64 on one group of 128,
+    chunks of 256) traces to one bfloat16 matmul a pair of terms that
+    `_dot` keeps. x / B / C and dy bfloat16: C B^T 1, dy^T x 1 and dy W 3
+    (dy is ONE term: three-term cotangents would make them 3 and 6, 40 in
+    all), C S_0 3, the cotangent of x^T B's operand 3, the state's
+    cotangent 3, g's reverse cumulative sum against the triangle of ones 3,
+    dB and dC 3 + 6 each. Float32 operands take every product's six
+    passes (the triangle's three)."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from jax._src import core
+    from ray_tpu.ops.state_space import ssd
+    shape = lambda *dims, dtype=dtype: jax.ShapeDtypeStruct(dims, dtype)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(ssd(*a, chunk=256, interpret=False)),
+        argnums=tuple(range(6))))(
+        shape(1, 512, 64, 64), shape(1, 512, 64, dtype="float32"),
+        shape(64, dtype="float32"), shape(1, 512, 1, 128),
+        shape(1, 512, 1, 128), shape(64, dtype="float32")).jaxpr
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+    by_name = {eqn.params["name"]: eqn for eqn in calls(jaxpr)}
+    assert sorted(by_name) == ["ssd_bwd", "ssd_fwd"]
+    assert _dots_of(by_name["ssd_bwd"].params["jaxpr"]) == dots
+    # the forward's: C B^T, x W, C S_0, x^T B
+    assert _dots_of(by_name["ssd_fwd"].params["jaxpr"]) == {
+        "bfloat16": 1 + 3 + 3 + 3, "float32": 24}[dtype]
+
+
 def test_scan_keeps_the_inputs_type_and_names_what_remat_keeps(jax_cpu):
     jax = jax_cpu
     import jax.numpy as jnp
@@ -380,6 +538,12 @@ def test_the_block_of_heads_follows_the_chunk_and_the_group():
         == _TILE_BYTES // (4 * 256 * 256)
     assert _heads_a_step(512, 64) == 4 and _heads_a_step(512, 6) == 3
     assert _heads_a_step(2048, 64) == 1             # never none
+    # the backward walks a step's [C, C] tiles a megabyte at a time: all
+    # sixteen heads at chunks of 128, four at 256, what divides the block
+    from ray_tpu.ops.state_space import _tile_heads
+    assert _tile_heads(128, 16) == 16 and _tile_heads(256, 16) == 4
+    assert _tile_heads(128, 12) == 12 and _tile_heads(256, 6) == 3
+    assert _tile_heads(1024, 4) == 1
 
 
 def test_scan_refuses_what_the_chips_tiles_cannot_hold(jax_cpu):
@@ -799,14 +963,15 @@ def test_scan_compiles_at_8192_positions_of_16_heads_of_64(v5e):
     """ops/state_space.py's two kernels at a state-space layer of
     nemotron3s_train_1chip, [1, 8192, 16, 64] on one group of 128 in chunks
     of 128, the cell's types: `ssd_fwd` and `ssd_bwd` (the chunk function's
-    jax.vjp) compile inside their VMEM limit, one Mosaic call each and no
-    XLA loop beside them, neither over the 64 chunks nor the 8192 tokens.
+    transpose, written out) compile inside their VMEM limit, one Mosaic
+    call each and no XLA loop beside them, neither over the 64 chunks nor
+    the 8192 tokens.
     All sixteen heads of the group a grid step: x, y, dy and dx blocks of
-    [16, 64, 128] bfloat16 (256 KB each), B and C of [128, 128] (32 KB), dB
-    and dC of [128, 128] float32 (64 KB), a chunk's states [16, 64, 128]
+    [16, 64, 128] bfloat16 (256 KB each), B, C, dB and dC of [128, 128] (32
+    KB each), a chunk's states [16, 64, 128]
     float32 (512 KB) and as much scratch, each block twice for the
     pipeline: under 4 MB; the [16, 128, 128] float32 decay-and-score tiles
-    (1 MB each) are values inside the body, under the 64 MB the call may
+    (1 MB each) are values inside the body, under the 32 MB the call may
     use. What is kept between the two calls is the chunks' states (34 MB,
     which the compiler may hold in VMEM: no lower bound here), where the
     XLA form's decay-and-score tensors were 67 MB each."""
