@@ -282,7 +282,9 @@ class GPTConfig:
     # (projected beside the latent); v and the output are v_head_dim wide.
     # rope_interleaved: the rotated columns are pairs [x0, y0, x1, y1, ..]
     # in the weights (de-interleaved there, once a step, then rotated as
-    # halves). 0 = multi-head attention at head_dim, q, k and v alike.
+    # halves). Under use_rope False the block rotates nothing: the
+    # qk_rope_dim columns of q and the shared key part are plain columns.
+    # 0 = multi-head attention at head_dim, q, k and v alike.
     kv_latent_dim: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
@@ -363,14 +365,11 @@ class GPTConfig:
             raise ValueError(
                 "a 'kda' layer's state runs along the whole sequence: it is "
                 "not sharded over 'sequence' (attention='ring')")
-        if (self.rope_of("attention") is None
-                and (self.kv_latent_dim or self.index_topk)):
+        if self.rope_of("attention") is None and self.index_topk:
             raise ValueError(
                 "attention layers that rotate nothing (use_rope=False, or "
                 "a rope that rotates no column) are built for multi-head "
-                "attention layers, not for "
-                + ("a latent block" if self.kv_latent_dim
-                   else "an indexer"))
+                "attention layers and latent blocks, not for an indexer")
         for heads in {self.n_heads, self.heads_of("window")}:
             if heads % self.kv_heads:
                 raise ValueError(f"n_kv_heads={self.n_kv_heads} does not "
@@ -968,9 +967,12 @@ def _deinterleaved(w, heads: int, keep: int, pairs: int):
 
 def _rope_tail(t, table, n: int):
     """t [B, S, heads, width]: the last n columns of every head rotated as
-    halves by `table` (rope_table of S and n), the rest as they are. The
+    halves by `table` (rope_table of S and n), the rest as they are; t
+    itself without a table (a block that rotates nothing). The
     jnp formulation of what ops/rope.py's latent kernels do in registers
     (`_latent_heads` says when it runs)."""
+    if not table:
+        return t
     cos, sin = (c[:, None, :n] for c in table)
     x = t[..., -n:].astype(jnp.float32)
     other = jnp.concatenate([x[..., n // 2:], x[..., :n // 2]], axis=-1)
@@ -985,10 +987,11 @@ def _latent_heads(q, kv, k_rope, table, nope: int, rope: int, dv: int,
     its oracle (`attention="reference"`): q [B, S, H * (nope + rope)], kv
     [B, S, H * (nope + dv)] and the shared k_rope [B, S, rope] -> q and k
     [B, H, S, nope + rope + fill] and v [B, H, S, dv], the rope columns
-    rotated (`_rope_tail`), the one key part repeated to every head, `fill`
-    zero columns after them. On the chip it is slices and concatenates at
-    64-column granularity, float32 in the backward, and four transposes of
-    [B, S, H, 256 | 128] tensors (PERF.md, PR 39)."""
+    rotated (`_rope_tail`; as projected without a table), the one key part
+    repeated to every head, `fill` zero columns after them. On the chip it
+    is slices and concatenates at 64-column granularity, float32 in the
+    backward, and four transposes of [B, S, H, 256 | 128] tensors (PERF.md,
+    PR 39)."""
     b, s, _ = q.shape
     q = q.reshape(b, s, -1, nope + rope)
     zeros = [jnp.zeros(q.shape[:3] + (fill,), q.dtype)] if fill else []
@@ -1008,8 +1011,11 @@ def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
     [B, S, H * v_head_dim] (as `_flash_on_mesh` has them, by v's width; the
     reference's are turned): q straight from x, k and v up from one
     normalised latent, RoPE on qk_rope_dim columns of q's heads and on the
-    one key part all heads share, then the flash kernels at q.k width
-    qk_nope_dim + qk_rope_dim and v width v_head_dim. Scope `attn_latent`
+    one key part all heads share (table (): a block that rotates nothing,
+    cfg.rope_of gives None; the same columns, as they were projected: no
+    table, no rotation and no de-interleaving on any path), then the flash
+    kernels at q.k width qk_nope_dim + qk_rope_dim and v width v_head_dim.
+    Scope `attn_latent`
     (inside `attn_proj`) holds what exists only because attention is
     latent: both kv projections, their norm, the assembly of k and v.
     Between the projections and the flash kernels: ops/rope.py's latent
@@ -1022,12 +1028,14 @@ def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
     nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     latent = cfg.kv_latent_dim
     wq, w_kva = a["wq"].astype(dt), a["w_kva"].astype(dt)
+    # (pairs are put apart for the rotation's sake alone)
+    interleaved = cfg.rope_interleaved and bool(table)
     with jax.named_scope("attn_proj"):
-        if cfg.rope_interleaved:
+        if interleaved:
             wq = _deinterleaved(wq, wq.shape[1] // (nope + rope), nope, rope)
         q = jnp.einsum("bsd,de->bse", x, wq)
         with jax.named_scope("attn_latent"):
-            if cfg.rope_interleaved:
+            if interleaved:
                 w_kva = _deinterleaved(w_kva, 1, latent, rope)
             c = jnp.einsum("bsd,de->bse", x, w_kva)
             kv = jnp.einsum(
@@ -1066,7 +1074,8 @@ def _latent_attention(layer, x, cfg: GPTConfig, table, where: Setting):
                          "rotated key part is not sharded over 'sequence'")
     columns = ("batch", None, "heads")
     o = _per_shard(split_and_attend, where.mesh,
-                   (columns, columns, ("batch", None, None), (), ()),
+                   (columns, columns, ("batch", None, None))
+                   + ((),) * len(table),
                    _heads_dims(by_kernels))(q, kv, k_rope, *table)
     return _tokens_first(o, by_kernels)
 

@@ -37,7 +37,9 @@ four kernels of the same kind (`latent_q_split`, `latent_kv_split` and their
 transposes `latent_q_merge`, `latent_kv_merge`: the second half of this
 file) wherever the parts fill whole or half lane tiles; anything else (heads
 of 32 + 16, `attention="reference"`) keeps models/gpt.py's jnp assembly
-(`_rope_tail`, `_latent_heads`).
+(`_rope_tail`, `_latent_heads`). A latent block that rotates nothing hands
+them no table: the same four passes assemble the heads, the 64 columns
+reach the flash kernels as projected.
 """
 
 from __future__ import annotations
@@ -429,49 +431,60 @@ def _rotated_tail(y, i: int, rope: int, fill: int):
         [tail, jnp.zeros((y.shape[0], fill), y.dtype)], axis=1)
 
 
-def _latent_q_split_kernel(x_ref, cos_ref, sin_ref, o_ref, *, nope, rope):
+def _rotated_tile(y, table, rope: int, back: bool = False):
+    """y [rows, W], the rotated parts of a group of heads side by side,
+    rotated by the table's refs (`back`: rotated back, sin negated) and
+    rounded to its type; as it is where the block rotates nothing (no
+    table)."""
+    if not table:
+        return y
+    cos, sin = table[0][...], table[1][...]
+    return _rotate(y.astype(jnp.float32), cos, -sin if back else sin,
+                   rope).astype(y.dtype)
+
+
+def _latent_q_split_kernel(x_ref, *refs, nope, rope):
     """Grid (batch, seq block, head block): x [1, rows, heads * (nope +
-    rope)] -> out [1, heads, rows, nope + rope + fill]."""
+    rope)] -> out [1, heads, rows, nope + rope + fill]. refs: the table's
+    (cos, sin) where the block rotates, then out."""
+    *table, o_ref = refs
     head = nope + rope
     fill = o_ref.shape[3] - head
     group = _lane_tile(rope) // rope
     for g in range(o_ref.shape[1] // group):
         x = x_ref[0, :, g * group * head:(g + 1) * group * head]
-        y = _side_by_side([x[:, i * head + nope:(i + 1) * head]
-                           for i in range(group)])
-        y = _rotate(y.astype(jnp.float32), cos_ref[...], sin_ref[...],
-                    rope).astype(o_ref.dtype)
+        y = _rotated_tile(_side_by_side([x[:, i * head + nope:(i + 1) * head]
+                                         for i in range(group)]), table, rope)
         for i in range(group):
             o_ref[0, g * group + i, :, :nope] = x[:, i * head:i * head + nope]
             o_ref[0, g * group + i, :, nope:] = _rotated_tail(y, i, rope, fill)
 
 
-def _latent_q_merge_kernel(g_ref, cos_ref, sin_ref, o_ref, *, nope, rope):
+def _latent_q_merge_kernel(g_ref, *refs, nope, rope):
     """_latent_q_split_kernel's transpose: g [1, heads, rows, nope + rope +
     fill] -> out [1, rows, heads * (nope + rope)], rotated back."""
+    *table, o_ref = refs
     head = nope + rope
     group = _lane_tile(rope) // rope
     for g in range(g_ref.shape[1] // group):
         grads = [g_ref[0, g * group + i] for i in range(group)]
-        y = _side_by_side([t[:, nope:head] for t in grads])
-        y = _rotate(y.astype(jnp.float32), cos_ref[...], -sin_ref[...],
-                    rope).astype(o_ref.dtype)
+        y = _rotated_tile(_side_by_side([t[:, nope:head] for t in grads]),
+                          table, rope, back=True)
         o_ref[0, :, g * group * head:(g + 1) * group * head] = _side_by_side(
             [part for i, t in enumerate(grads)
              for part in (t[:, :nope], y[:, i * rope:(i + 1) * rope])])
 
 
-def _latent_kv_split_kernel(kv_ref, kr_ref, cos_ref, sin_ref, k_ref, v_ref,
-                            *, nope, rope):
+def _latent_kv_split_kernel(kv_ref, kr_ref, *refs, nope, rope):
     """Grid (batch, seq block, head block): kv [1, rows, heads * (nope +
     dv)], k_rope [1, rows, rope] -> k [1, heads, rows, nope + rope + fill],
-    v [1, heads, rows, dv]."""
+    v [1, heads, rows, dv]. refs: the table's (cos, sin) where the block
+    rotates, then k and v."""
+    *table, k_ref, v_ref = refs
     dv = v_ref.shape[3]
     fill = k_ref.shape[3] - nope - rope
     group = _lane_tile(rope) // rope
-    y = _side_by_side([kr_ref[0]] * group)
-    y = _rotate(y.astype(jnp.float32), cos_ref[...], sin_ref[...],
-                rope).astype(k_ref.dtype)
+    y = _rotated_tile(_side_by_side([kr_ref[0]] * group), table, rope)
     tail = _rotated_tail(y, 0, rope, fill)
     for h in range(k_ref.shape[1]):
         at = h * (nope + dv)
@@ -480,12 +493,12 @@ def _latent_kv_split_kernel(kv_ref, kr_ref, cos_ref, sin_ref, k_ref, v_ref,
         v_ref[0, h] = kv_ref[0, :, at + nope:at + nope + dv]
 
 
-def _latent_kv_merge_kernel(dk_ref, dv_ref, cos_ref, sin_ref, dkv_ref,
-                            dkr_ref, sum_ref, *, nope, rope):
+def _latent_kv_merge_kernel(dk_ref, dv_ref, *refs, nope, rope):
     """_latent_kv_split_kernel's transpose. The head block is the grid's
     last, sequential axis: sum_ref [rows, rope] float32 carries the heads'
     rotated columns of dk from block to block, and the last one rotates
     the sum back into d k_rope [1, rows, rope]."""
+    *table, dkv_ref, dkr_ref, sum_ref = refs
     dv = dv_ref.shape[3]
     group = _lane_tile(rope) // rope
     j = pl.program_id(2)
@@ -500,8 +513,8 @@ def _latent_kv_merge_kernel(dk_ref, dv_ref, cos_ref, sin_ref, dkv_ref,
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
-        y = _rotate(_side_by_side([total] * group), cos_ref[...],
-                    -sin_ref[...], rope)
+        y = _rotated_tile(_side_by_side([total] * group), table, rope,
+                          back=True)
         dkr_ref[0] = y[:, :rope].astype(dkr_ref.dtype)
 
 
@@ -629,7 +642,9 @@ def latent_split(seq: int, heads: int, nope: int, rope: int, dv: int, dtype,
       zero columns of attention.qk_padding.
     kv_split(kv [B, S, heads * (nope + dv)], k_rope [B, S, rope], cos, sin)
       -> (k [B, heads, S, nope + rope + fill], v [B, heads, S, dv]): k_rope
-      rotated once and repeated to every head."""
+      rotated once and repeated to every head.
+    Called without (cos, sin), a block that rotates nothing: the same
+    passes, the rope columns as they were projected."""
     blocks = _latent_blocks(seq, heads, nope, rope, dv,
                             jnp.dtype(dtype).itemsize)
     if blocks is None:

@@ -47,6 +47,7 @@ FILES = {
     "smallthinker": "test_smallthinker.py",
     "nemotron_h": "test_state_space.py",
     "granite_hybrid": "test_hybrid_mixer_model.py",
+    "kimi_linear": "test_kimi_linear_model.py",
 }
 
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"

@@ -95,6 +95,12 @@ LOWERED = {
     # multipliers' products, `sm_scale` 1/64 on the paired flash kernels,
     # `ssd_fwd` / `ssd_bwd` over four blocks of 16 heads at chunks of 256
     "granite4hm_train_1chip": "5936a9f19a88e1fd",
+    # new with PR 65, which leaves the eleven above alone (their lines are
+    # the parent's): four `kda` layers of 32 heads beside a latent layer
+    # whose four layout kernels are handed no table (nothing is rotated and
+    # no table is built), the first layer a `kda` mixer over the dense MLP,
+    # both products of every MLP kept through the remat
+    "kimilinear_train_1chip": "54748505aae61025",
 }
 
 

@@ -32,6 +32,9 @@ CELLS = {
     "smallthinker_train_1chip": (0, 0),
     "nemotron3s_train_1chip": (0, 6),
     "granite4hm_train_1chip": (1, 10),
+    # the dense MLP of 9216 under the first delta-rule layer and four
+    # shared experts of 1024: 0.37 GB for both products of all five
+    "kimilinear_train_1chip": (2, 5),
 }
 
 
@@ -206,7 +209,8 @@ def test_what_a_layer_keeps_is_what_the_checkpoint_saves(jax_cpu):
     from ray_tpu.models import gpt
     kinds = set()
     for name in ("tiny", "tiny-granite-hybrid", "tiny-kanana", "tiny-keye",
-                 "tiny-laguna", "tiny-lfm2", "tiny-nemotron-h", "tiny-solar"):
+                 "tiny-kimi-linear", "tiny-laguna", "tiny-lfm2",
+                 "tiny-nemotron-h", "tiny-solar"):
         config = read("benchmark", "rehearsal", "configs", name + ".json")
         cfg = model.family(config)._train_config(config) if hasattr(
             model.family(config), "_train_config") else None
@@ -234,7 +238,7 @@ def test_what_a_layer_keeps_is_what_the_checkpoint_saves(jax_cpu):
                     if "the argument layer" not in why
                     and "constant" not in why)
                 assert saved == want, (kind, n, saved, want)
-    assert len(kinds) >= 12, kinds
+    assert len(kinds) >= 15, kinds
 
 
 def test_a_pipeline_stage_keeps_what_it_is_told(jax_cpu, monkeypatch):

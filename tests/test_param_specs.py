@@ -1,5 +1,5 @@
 """How a transformer's parameters are divided over the mesh, leaf for leaf:
-every parameter of the benchmark's architectures (the eight rehearsal
+every parameter of the benchmark's architectures (the nine rehearsal
 configurations hold every parameter name of the nine cells) under `tp` and
 `tp_fsdp` on fsdp=2 x tensor=2, and the dense one, stacked, under `pp` and
 `pp_tp`. The expectations were recorded at PR 42, before
@@ -104,7 +104,7 @@ EXPECTED = {"tp": (GSPMD, 0, NO_ROW), "tp_fsdp": (GSPMD, 1, NO_ROW),
 CASES = [(name, strategy)
          for name in ("tiny", "tiny-olmoe", "tiny-kanana", "tiny-lfm2",
                       "tiny-laguna", "tiny-keye", "tiny-solar",
-                      "tiny-smallthinker")
+                      "tiny-smallthinker", "tiny-kimi-linear")
          for strategy in ("tp", "tp_fsdp")] + [("tiny", "pp"),
                                                ("tiny", "pp_tp")]
 

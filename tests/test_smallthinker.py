@@ -145,9 +145,6 @@ class SmallThinker(Family):
         case(({"index_topk": 8, "index_heads": 2, "index_head_dim": 16,
                "layer_kinds": None}, "rotate nothing.*not for an indexer"),
              "indexer"),
-        case(({"kv_latent_dim": 32, "qk_nope_dim": 16, "qk_rope_dim": 16,
-               "v_head_dim": 16, "layer_kinds": None, "n_kv_heads": 0},
-              "rotate nothing.*not for a latent block"), "latent"),
     ]
 
     reduced = {"num_hidden_layers", "moe_num_primary_experts", "vocab_size",
