@@ -1753,9 +1753,9 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     if cfg.remat_policy == "full":
         # (of an indexer, its selection and its loss's gradients: the walk
         # over the score tiles then runs once a layer and step; of a
-        # delta-rule layer, its output and its chunks' states: `kda_bwd`
-        # computes a chunk again from them; of a state-space layer, the
-        # same two: the scan over the chunks runs once)
+        # delta-rule layer, its output, its chunks' states and their A,
+        # Aqk and inverse: `kda_bwd` reads them; of a state-space layer,
+        # its output and states: the scan over the chunks runs once)
         names = (FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK,
                  indexer.INDEX_GRADS, KDA_OUT, SSD_OUT)
         save = jax.checkpoint_policies.save_only_these_names
@@ -1800,7 +1800,8 @@ def _layer_bytes(layer, batch: int, seq: int, cfg: GPTConfig,
     one layer with these parameters at [batch, seq] tokens (its input and
     what its mixer's kernels name: the flash kernels' output and row
     statistics, an indexer's selection and gradients, a delta-rule or
-    state-space layer's output and chunk states), and what `keep_mlp(n)`
+    state-space layer's output and chunk states, a delta-rule layer's
+    kept matrices), and what `keep_mlp(n)`
     keeps more, n = 0, 1, 2 (n of the MLP's matmul results, as many as it
     has; 0 where the layer has no `_mlp_block`: `_mlp_of`). From the shapes alone;
     tests/test_mlp_kept.py holds both to what jax.checkpoint saves of each
@@ -1821,8 +1822,10 @@ def _layer_bytes(layer, batch: int, seq: int, cfg: GPTConfig,
     if "kda" in layer:
         # (the kernels hold a head's columns in whole lane tiles)
         heads, hd = cfg.n_heads, cfg.head_dim + -cfg.head_dim % LANES
+        # the output, and a chunk of 64's state and its A, Aqk and inverse
+        # packed into [64, 2 x 64]
         named += tokens * heads * hd * item \
-            + batch * heads * (seq // 64) * hd * hd * 4
+            + batch * heads * (seq // 64) * (hd * hd + 64 * 2 * 64) * 4
     if "ssm" in layer:
         size = cfg.ssm
         named += tokens * size.heads * size.head_dim * item \

@@ -47,14 +47,37 @@ function of values that live in VMEM (a chunk's q, k, v, g are 32 KB each
 at [64, 128], its A and inverse 16 KB, a state 64 KB). `kda_fwd` runs it
 over a grid (heads / h, chunks), the chunks in order, the state in VMEM
 scratch: a chunk's operands cross HBM once and every intermediate stays on
-the chip. `kda_bwd` runs `jax.vjp` of the same function over the chunks
-from the last to the first, the state's cotangent in scratch: it computes
-the chunk again from its inputs and the state it started from, which the
-forward keeps ([heads, chunks, dv, dk] float32, 67 MB a layer at [1, 8,
-8192, 128]), and writes the five gradients, the log-decay's among them
-(the doubling of `_chunk` is linear in it). The output and the kept states
-carry the name KDA_OUT, so that a remat policy that saves it runs the
-forward once a layer and step, as FLASH_OUT does for the flash kernels.
+the chip. `kda_bwd` runs the same function's transpose, written out
+(`_bwd_kernel`), over the chunks from the last to the first, the state's
+cotangent in scratch. What the transposes read of the forward it READS:
+the state a chunk started from ([heads, chunks, dv, dk] float32, 67 MB a
+layer at [1, 8, 8192, 128]) and the chunk's A, Aqk and inverse T, which the
+forward hands out packed into one [C, 2 C] float32 block (`_packed`: A
+below the diagonal of the left half and T's transpose above it, Aqk the
+right half; 32 KB a chunk and head, 34 MB a layer there and 134 MB at
+kimi's 32 heads). What it makes AGAIN is the decays of every level (adds,
+rotations and exponentials, no product) and Wv, Wk and U, three products
+from T. T's cotangent goes back through the inverse in closed form, dM =
+-T^T dT T^T below the diagonal (two products where the doubling's
+mechanical transpose took twenty), and the tree is walked once, from the
+chunk's whole down to the pairs of neighbours, a level's two products on A's
+and Aqk's cotangents stacked (128 rows against one operand). It writes the
+five gradients, the log-decay's among them (the doubling of `_chunk` is
+linear in it: `_undoubled`). The output, the kept states and the kept
+matrices carry the name KDA_OUT, so that a remat policy that saves it runs
+the forward once a layer and step, as FLASH_OUT does for the flash kernels.
+
+Every product is the float32 one at full precision: `Precision.HIGHEST` on
+float32 operands, six bfloat16 passes of the matrix unit. The backward
+leaves out the passes that multiply zeros (`_product`, over ops/terms.py):
+dO arrives in o's type and v in its own, and as bfloat16 they are their own
+one term of the three HIGHEST would split them into (three passes in place
+of six), so beta rides on T's columns and never on v, and dO is multiplied
+before anything scales it. Whatever is float32 by nature (a decayed q or k,
+the states, A, T, every other cotangent) is three terms, and float32 inputs
+take all six passes everywhere. That is the same sum, not a lower
+precision: the triangular system amplifies a rounding of A, and the state
+is summed over the chunks.
 """
 
 from __future__ import annotations
@@ -68,14 +91,15 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, terms
 from ray_tpu.ops.attention import LANES
 
 # The name the output carries (jax.ad_checkpoint.checkpoint_name).
 KDA_OUT = "kda_out"
 
-# Every product of the chunked form, float32 operands: the triangular
-# system amplifies a rounding of A, and the state is summed over the chunks.
+# Every product of the recurrence and of the chunked form, float32 operands:
+# the triangular system amplifies a rounding of A, and the state is summed
+# over the chunks.
 _PRECISION = jax.lax.Precision.HIGHEST
 
 
@@ -120,6 +144,15 @@ def _dot(x, y, contract):
         precision=_PRECISION, preferred_element_type=jnp.float32)
 
 
+def _product(x, y, contract, of):
+    """`_dot` where `of`, the bfloat16 terms each operand HAS (ops/terms.py),
+    is three a side; else the same sum without the pairs of the terms that
+    are zero: three passes of the matrix unit in place of six."""
+    if of == (3, 3):
+        return _dot(x, y, contract)
+    return terms.dot(x, y, contract, of)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _rows_down(x, shift: int):
     """x [h, C, d] with row t holding x[t - shift], around the end (a
@@ -138,13 +171,53 @@ def _rows_down_bwd(shift, _, g):
 _rows_down.defvjp(_rows_down_fwd, _rows_down_bwd)
 
 
+def _pairs(chunk: int):
+    """(t, s): the row's and the column's token of a [1, C, C] tile."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 1),
+            jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 2))
+
+
+def _in_right_half(level: int, chunk: int):
+    """[1, C, 1]: whether a token lies in the right half of its block of
+    2 ** (level + 1)."""
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, 1), 1)
+    return ((at >> level) & 1) == 1
+
+
+def _doubled(level: int, since, until, whole):
+    """The decays of blocks of 2b tokens from those of blocks of b = 2 **
+    level, [h, C, dk] each (`_chunk`'s docstring): a block's whole is added
+    to its sibling's tokens, the left block's to the right's `since` and
+    the right's to the left's `until`."""
+    chunk, half = whole.shape[1], 1 << level
+    right = _in_right_half(level, chunk)
+    before = _rows_down(whole, half)
+    after = _rows_down(whole, chunk - half)
+    return (since + jnp.where(right, before, 0.0),
+            until + jnp.where(right, 0.0, after),
+            whole + jnp.where(right, before, after))
+
+
+def _undoubled(level: int, dsince, duntil, dwhole):
+    """`_doubled`'s transpose: the cotangents of the decays of blocks of 2b
+    tokens -> what they hand the decays of blocks of b."""
+    chunk, half = dwhole.shape[1], 1 << level
+    right = _in_right_half(level, chunk)
+    dbefore = jnp.where(right, dsince + dwhole, 0.0)
+    dafter = jnp.where(right, 0.0, duntil + dwhole)
+    return dsince, duntil, (dwhole + pltpu.roll(dbefore, chunk - half, 1)
+                            + pltpu.roll(dafter, half, 1))
+
+
 def _chunk(q, k, v, a, beta, state):
     """One chunk of h heads, on values that live in VMEM: q, k, the
     log-decay a [h, C, dk], v [h, C, dv], beta [h, 1, C] (a row: tokens
     along the lanes) and the state the chunk starts from, transposed [h,
-    dv, dk], all float32 -> (o [h, C, dv], the state after the chunk). The
-    one statement of the chunked form: the forward kernel runs it and the
-    backward kernel runs its jax.vjp.
+    dv, dk], all float32 -> (o [h, C, dv], the state after the chunk, and
+    what the backward reads of it: A, Aqk and the inverse, [h, C, C] each).
+    The one statement of the chunked form: the forward kernel runs it, the
+    backward kernel its transpose written out, which
+    tests/test_linear_attention.py holds to this function's jax.vjp.
 
     The decays between the pairs of a level need no cumulative sum and no
     reference row: with F_b[t] the log-decay summed from the start of t's
@@ -155,30 +228,25 @@ def _chunk(q, k, v, a, beta, state):
     rows (F_1 = T_1 = a, B_1 = 0; at the top F = g, B = g_C - g, T =
     g_C). Every exponent is <= 0."""
     chunk = q.shape[1]
-    at = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, 1), 1)
-    t = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 1)
-    s = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, chunk), 2)
+    t, s = _pairs(chunk)
     eye = t == s
     # beta a row in, a column here: the diagonal's row sums
     beta = jnp.sum(jnp.where(eye, beta, 0.0), axis=2, keepdims=True)
     since, until, whole = a, jnp.zeros_like(a), a
     aqk = jnp.where(eye, jnp.sum(q * k, axis=2, keepdims=True), 0.0)
+    akk = jnp.zeros_like(aqk)
     inverse = jnp.broadcast_to(eye.astype(jnp.float32), aqk.shape)
     for level in range(chunk.bit_length() - 1):
-        half = 1 << level
         # (t in a block's right half, s in its left), zero elsewhere
         pairs = (((t ^ s) >> level) == 1) & (t > s)
         rows, cols = jnp.exp(since), k * jnp.exp(until)
-        m = jnp.where(pairs, beta * _dot(k * rows, cols, (2, 2)), 0.0)
+        between = _dot(k * rows, cols, (2, 2))
+        akk = akk + jnp.where(pairs, between, 0.0)
+        m = jnp.where(pairs, beta * between, 0.0)
         aqk = aqk + jnp.where(pairs, _dot(q * rows, cols, (2, 2)), 0.0)
         inverse = inverse - (m if level == 0 else _dot(
             _dot(inverse, m, (2, 1)), inverse, (2, 1)))
-        right = ((at >> level) & 1) == 1
-        before = _rows_down(whole, half)
-        after = _rows_down(whole, chunk - half)
-        since = since + jnp.where(right, before, 0.0)
-        until = until + jnp.where(right, 0.0, after)
-        whole = whole + jnp.where(right, before, after)
+        since, until, whole = _doubled(level, since, until, whole)
     decayed = jnp.exp(since)                        # e^g, the chunk's own
     wv = _dot(inverse, beta * v, (2, 1))
     wk = _dot(inverse, beta * (k * decayed), (2, 1))
@@ -186,88 +254,212 @@ def _chunk(q, k, v, a, beta, state):
     o = _dot(q * decayed, state, (2, 2)) + _dot(aqk, u, (2, 1))
     after = (state * jnp.exp(whole[:, :1])
              + _dot(u, k * jnp.exp(until), (1, 1)))
-    return o, after
+    return o, after, (akk, aqk, inverse)
+
+
+def _packed(akk, aqk, inverse):
+    """A, Aqk and the inverse [h, C, C] as ONE [h, C, 2 C] block (at chunks
+    of 64 whole lane tiles of 128: a [C, C] array of its own is laid out
+    in HBM at twice its bytes). A is zero on and above its diagonal and the
+    inverse is 1 on its own and zero above it, so the left half holds A
+    below the diagonal and the inverse's TRANSPOSE above it; the right half
+    is Aqk."""
+    t, s = _pairs(akk.shape[1])
+    return jnp.concatenate(
+        [akk + jnp.where(t < s, jnp.swapaxes(inverse, 1, 2), 0.0), aqk],
+        axis=2)
+
+
+def _unpacked(kept):
+    """`_packed`'s [h, C, 2 C] -> (A, Aqk, the inverse's transpose)."""
+    chunk = kept.shape[1]
+    t, s = _pairs(chunk)
+    left = kept[:, :, :chunk]
+    return (jnp.where(t > s, left, 0.0), kept[:, :, chunk:],
+            jnp.where(t < s, left, 0.0) + (t == s).astype(jnp.float32))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, states_ref,
-                state):
+                kept_ref, state):
     """Grid (heads / h, chunks), the chunks in order: `state` [h, dv, dk]
     carries each head's state; states_ref keeps what a chunk started from
-    for the backward."""
+    and kept_ref its A, Aqk and inverse (`_packed`) for the backward."""
     f32 = jnp.float32
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
     states_ref[:, 0] = state[...]
-    o, state[...] = _chunk(q_ref[...].astype(f32), k_ref[...].astype(f32),
-                           v_ref[...].astype(f32), a_ref[...],
-                           beta_ref[:, 0], state[...])
+    o, state[...], kept = _chunk(
+        q_ref[...].astype(f32), k_ref[...].astype(f32),
+        v_ref[...].astype(f32), a_ref[...], beta_ref[:, 0], state[...])
     o_ref[...] = o.astype(o_ref.dtype)
+    kept_ref[:, 0] = _packed(*kept)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, da_ref, dbeta_ref, dstate):
+def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, kept_ref,
+                do_ref, dq_ref, dk_ref, dv_ref, da_ref, dbeta_ref, dstate):
     """Grid (heads / h, chunks), the chunks from the last to the first:
     `dstate` [h, dv, dk] carries the cotangent of the state a chunk hands
-    on. A chunk is computed again from its inputs and the state it started
-    from, and transposed."""
-    f32 = jnp.float32
+    on. `_chunk`'s transpose, written out. Read, not made again: the state
+    the chunk started from, A, Aqk and the inverse T (`_unpacked`). Made
+    again: the decays of every level (adds, rotations and exponentials, no
+    product) and Wv, Wk, U from T. T's cotangent goes back through the
+    inverse in closed form, dM = -T^T dT T^T below the diagonal (M =
+    Diag(beta) A), and the tree is walked once, from the chunk's whole down
+    to the pairs of neighbours, a level's two products on its stacked
+    cotangents [dA ; dAqk]. Every product is `_product`'s, with the terms
+    its operands HAVE: dO and v that arrive as bfloat16 are one term (beta
+    rides on T's columns, not on v), whatever is float32 by nature (a
+    decayed q or k, the states, T, every other cotangent) three."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    of_v, of_do = (1 if ref.dtype == bf16 else 3 for ref in (v_ref, do_ref))
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
-    _, pull = jax.vjp(_chunk, q_ref[...].astype(f32), k_ref[...].astype(f32),
-                      v_ref[...].astype(f32), a_ref[...], beta_ref[:, 0],
-                      states_ref[:, 0])
-    dq, dk, dv, da, dbeta, dstate[...] = pull(
-        (do_ref[...].astype(f32), dstate[...]))
+    q, k, v, do = (ref[...].astype(f32)
+                   for ref in (q_ref, k_ref, v_ref, do_ref))
+    state, handed = states_ref[:, 0], dstate[...]
+    akk, aqk, inverse_t = _unpacked(kept_ref[:, 0])
+    chunk = q.shape[1]
+    levels = chunk.bit_length() - 1
+    t, s = _pairs(chunk)
+    eye = t == s
+    row = beta_ref[:, 0]                            # beta [h, 1, C]
+    beta = jnp.sum(jnp.where(eye, row, 0.0), axis=2, keepdims=True)
+
+    # e^F and e^B of every level, as `_chunk` makes them
+    since, until, whole = a_ref[...], jnp.zeros(q.shape, f32), a_ref[...]
+    from_start, to_end = [], []
+    for level in range(levels):
+        from_start.append(jnp.exp(since))
+        to_end.append(jnp.exp(until))
+        since, until, whole = _doubled(level, since, until, whole)
+    decayed, ending = jnp.exp(since), jnp.exp(until)
+    fade = jnp.exp(whole[:, :1])                    # e^{g_C} [h, 1, dk]
+    qbar, kbar, ktilde = q * decayed, k * decayed, k * ending
+
+    # the tail of the chunk again: [Wv | Wk] = T Diag(beta) [V | Kbar]
+    wv = _product(inverse_t * beta, v, (1, 1), (3, of_v))
+    wk = _product(inverse_t, beta * kbar, (1, 1), (3, 3))
+    u = wv - _product(wk, state, (2, 2), (3, 3))
+    # its transposes: O = Qbar S^T + Aqk U, S' = e^{g_C} S + U^T Ktilde
+    du = (_product(aqk, do, (1, 1), (3, of_do))
+          + _product(ktilde, handed, (2, 2), (3, 3)))
+    daqk = jnp.where(t >= s, _product(do, u, (2, 2), (of_do, 3)), 0.0)
+    dqbar = _product(do, state, (2, 1), (of_do, 3))
+    dktilde = _product(u, handed, (2, 1), (3, 3))
+    dwk = -_product(du, state, (2, 1), (3, 3))      # U = Wv - Wk S^T
+    dstate[...] = (handed * fade + _product(do, qbar, (1, 1), (of_do, 3))
+                   - _product(du, wk, (1, 1), (3, 3)))
+    # through T: its operands' cotangents T^T dW, its own dW (operands)^T
+    drv = _product(inverse_t, du, (2, 1), (3, 3))
+    drk = _product(inverse_t, dwk, (2, 1), (3, 3))
+    dinverse = row * (_product(du, v, (2, 2), (3, of_v))
+                      + _product(dwk, kbar, (2, 2), (3, 3)))
+    # T = (I + M)^-1: dM = -T^T dT T^T, and M has the pairs below the
+    # diagonal alone
+    dm = -_product(_product(inverse_t, dinverse, (2, 1), (3, 3)), inverse_t,
+                   (2, 1), (3, 3))
+    dm = jnp.where(t > s, dm, 0.0)
+    dbeta = (jnp.sum(dm * akk, axis=2, keepdims=True)
+             + jnp.sum(drv * v, axis=2, keepdims=True)
+             + jnp.sum(drk * kbar, axis=2, keepdims=True))
+    dbeta_ref[:, 0] = jnp.sum(jnp.where(eye, dbeta, 0.0), axis=1,
+                              keepdims=True)
+    dv_ref[...] = (beta * drv).astype(dv_ref.dtype)
+    dakk, dkbar = beta * dm, beta * drk
+    on_diagonal = jnp.sum(jnp.where(eye, daqk, 0.0), axis=2, keepdims=True)
+    dq = dqbar * decayed + on_diagonal * k
+    dk = dkbar * decayed + dktilde * ending + on_diagonal * q
+    # the log-decay's: of e^F, e^B and (its first row) e^{g_C} at the top
+    dsince = dqbar * qbar + dkbar * kbar
+    duntil = dktilde * ktilde
+    first = jax.lax.broadcasted_iota(jnp.int32, (1, chunk, 1), 1) == 0
+    dwhole = jnp.where(
+        first, fade * jnp.sum(handed * state, axis=1, keepdims=True), 0.0)
+    for level in reversed(range(levels)):
+        dsince, duntil, dwhole = _undoubled(level, dsince, duntil, dwhole)
+        pairs = (((t ^ s) >> level) == 1) & (t > s)
+        rows = from_start[level]
+        cols = k * to_end[level]
+        # A's and Aqk's cotangents on the level's pairs, stacked: their
+        # products share cols, and its own cotangent is one product
+        both = jnp.concatenate([jnp.where(pairs, dakk, 0.0),
+                                jnp.where(pairs, daqk, 0.0)], axis=1)
+        scaled = jnp.concatenate([k * rows, q * rows], axis=1)
+        dscaled = _product(both, cols, (2, 1), (3, 3))       # [h, 2 C, dk]
+        dcols = _product(both, scaled, (1, 1), (3, 3))       # [h, C, dk]
+        dk = dk + dscaled[:, :chunk] * rows + dcols * to_end[level]
+        dq = dq + dscaled[:, chunk:] * rows
+        product = dscaled * scaled
+        dsince = dsince + product[:, :chunk] + product[:, chunk:]
+        duntil = duntil + dcols * cols
     dq_ref[...] = dq.astype(dq_ref.dtype)
     dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
-    da_ref[...] = da
-    dbeta_ref[:, 0] = dbeta
+    # F_1 = T_1 = a; B_1 = 0 is no function of it
+    da_ref[...] = dsince + dwhole
 
 
 # Heads a grid step, at most: their products are batched, and the chains of
-# dependent ones interleave (at [1, 8, 8192, 128] on a v5e 2.03 / 6.29 ms
-# forward / backward at 4, 3.05 / 9.19 at 1, 2.01 / 7.49 at 8).
-_HEADS = 4
+# dependent ones interleave (the forward's longest is the inverse's
+# doubling), while every head more is more [h, C, dk] values held at once
+# (backward: the decays of every level, the running gradients). A v5e, ms a
+# call at [1, 8, 8192, 128] / [1, 32, 8192, 128]: `kda_fwd` 2.11 / 8.42 at
+# 2 heads, 2.04 / 8.15 at 4, 2.01 / 8.04 at 8; `kda_bwd` 3.09 / 12.36 at 1,
+# 2.63 / 10.51 at 2, 2.52 / 10.08 at 4, 2.54 / 10.16 at 8.
+_FWD_HEADS = 8
+_BWD_HEADS = 4
 
-_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "arbitrary"),
-    vmem_limit_bytes=64 << 20)
+
+def _params(vmem_limit_bytes: int):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_limit_bytes)
+
+
+# What a call may take of VMEM. XLA keeps that much free ACROSS the call, so
+# a limit the body does not need evicts what its neighbours hold there
+# (ops/state_space.py's finding). Compiled for the described v5e the
+# forward's body takes 9.3 MB at 8 heads a step and the backward's 8.7 at 4.
+_FWD_PARAMS = _params(16 << 20)
+_BWD_PARAMS = _params(12 << 20)
 
 
 @functools.lru_cache(maxsize=None)
 def _make_kda_fn(chunk: int, interpret: bool):
     """kda_fwd with kda_bwd as its backward, on [heads, tokens, width]
     operands of whole chunks and whole lane tiles; beta [heads, chunks, 1,
-    chunk]. The residuals are the five inputs and the chunks' states."""
+    chunk]. The residuals are the five inputs, the chunks' states and
+    their kept matrices (`_packed`)."""
 
-    def specs(heads, dk, dv, order):
-        h = max(d for d in range(1, _HEADS + 1) if heads % d == 0)
+    def specs(most, heads, dk, dv, order):
+        h = max(d for d in range(1, most + 1) if heads % d == 0)
         wide = lambda d: pl.BlockSpec((h, chunk, d),
                                       lambda i, j: (i, order(j), 0))
-        beta = pl.BlockSpec((h, 1, 1, chunk),
-                            lambda i, j: (i, order(j), 0, 0))
-        states = pl.BlockSpec((h, 1, dv, dk),
-                              lambda i, j: (i, order(j), 0, 0))
-        return h, wide(dk), wide(dv), beta, states
+        a_chunk = lambda *dims: pl.BlockSpec(
+            (h, 1) + dims, lambda i, j: (i, order(j), 0, 0))
+        return (h, wide(dk), wide(dv), a_chunk(1, chunk), a_chunk(dv, dk),
+                a_chunk(chunk, 2 * chunk))
 
     def forward(q, k, v, a, beta):
         heads, tokens, dk = q.shape
         dv, n = v.shape[-1], tokens // chunk
-        h, key, value, row, states = specs(heads, dk, dv, lambda j: j)
+        h, key, value, row, states, kept = specs(_FWD_HEADS, heads, dk, dv,
+                                                 lambda j: j)
+        f32 = jnp.float32
         return pl.pallas_call(
             _fwd_kernel,
             grid=(heads // h, n),
             in_specs=[key, key, value, key, row],
-            out_specs=[value, states],
+            out_specs=[value, states, kept],
             out_shape=[jax.ShapeDtypeStruct(v.shape, q.dtype),
-                       jax.ShapeDtypeStruct((heads, n, dv, dk), jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((h, dv, dk), jnp.float32)],
-            compiler_params=_PARAMS,
+                       jax.ShapeDtypeStruct((heads, n, dv, dk), f32),
+                       jax.ShapeDtypeStruct((heads, n, chunk, 2 * chunk),
+                                            f32)],
+            scratch_shapes=[pltpu.VMEM((h, dv, dk), f32)],
+            compiler_params=_FWD_PARAMS,
             interpret=interpret,
             name="kda_fwd",
         )(q, k, v, a, beta)
@@ -277,30 +469,31 @@ def _make_kda_fn(chunk: int, interpret: bool):
         return forward(q, k, v, a, beta)[0]
 
     def fwd(q, k, v, a, beta):
-        o, states = forward(q, k, v, a, beta)
-        # both kept by a remat policy that saves the name: the forward runs
-        # once a layer and step
+        o, states, kept = forward(q, k, v, a, beta)
+        # all three kept by a remat policy that saves the name: the forward
+        # runs once a layer and step
         return checkpoint_name(o, KDA_OUT), (
-            q, k, v, a, beta, checkpoint_name(states, KDA_OUT))
+            q, k, v, a, beta, checkpoint_name(states, KDA_OUT),
+            checkpoint_name(kept, KDA_OUT))
 
     def bwd(residuals, g):
-        q, k, v, a, beta, kept = residuals
+        q, k, v, a, beta, states_kept, matrices = residuals
         heads, tokens, dk = q.shape
         dv, n = v.shape[-1], tokens // chunk
-        h, key, value, row, states = specs(heads, dk, dv,
-                                           lambda j: n - 1 - j)
+        h, key, value, row, states, kept = specs(_BWD_HEADS, heads, dk, dv,
+                                                 lambda j: n - 1 - j)
         return tuple(pl.pallas_call(
             _bwd_kernel,
             grid=(heads // h, n),
-            in_specs=[key, key, value, key, row, states, value],
+            in_specs=[key, key, value, key, row, states, kept, value],
             out_specs=[key, key, value, key, row],
             out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                        for x in (q, k, v, a, beta)],
             scratch_shapes=[pltpu.VMEM((h, dv, dk), jnp.float32)],
-            compiler_params=_PARAMS,
+            compiler_params=_BWD_PARAMS,
             interpret=interpret,
             name="kda_bwd",
-        )(q, k, v, a, beta, kept, g))
+        )(q, k, v, a, beta, states_kept, matrices, g))
 
     f.defvjp(fwd, bwd)
     return f

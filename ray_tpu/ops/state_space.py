@@ -61,7 +61,8 @@ ones) onto dt, and onto a_log a chunk. The output and the kept states carry
 the name SSD_OUT, so that a remat policy that saves it runs the forward
 once a layer and step, as KDA_OUT does for the delta rule.
 
-Every product is the float32 one at full precision (`_product`): the sum,
+Every product is the float32 one at full precision (`_product`, over
+ops/terms.py's `dot`, which ops/linear_attention.py shares): the sum,
 in a float32 accumulator, of the bfloat16 terms' products that
 Precision.HIGHEST keeps (an operand is three terms, hi + mid + lo, and the
 six pairs whose orders add to at most two are multiplied). What is open is
@@ -90,6 +91,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import LANES
+from ray_tpu.ops.terms import dot as _dot
 
 # The name the output and the chunks' states carry
 # (jax.ad_checkpoint.checkpoint_name).
@@ -138,33 +140,6 @@ def chunk_log_decay(dt, a_log, chunk: int = 128):
     steps = steps.reshape(batch, -1, chunk, heads).transpose(0, 1, 3, 2)
     return jnp.cumsum(-jnp.exp(a_log.astype(jnp.float32))[:, None] * steps,
                       axis=-1)
-
-
-def _terms(a, count: int):
-    """float32 a as `count` bfloat16 terms, the largest first: one where a
-    holds a bfloat16 value (the caller's word), else the three that add up
-    to every bit of it."""
-    bf16, f32 = jnp.bfloat16, jnp.float32
-    terms = [a.astype(bf16)]          # a bfloat16 a is its own one term
-    for _ in range(count - 1):
-        a = a - terms[-1].astype(f32)
-        terms.append(a.astype(bf16))
-    return terms
-
-
-def _dot(a, b, contract, terms):
-    """a . b over `contract` (one dimension of each; operands of three
-    dimensions are batched over their first), float32 at full precision:
-    the bfloat16 terms' products whose orders add to at most two (with
-    three terms a side Precision.HIGHEST's six), summed in float32."""
-    batch = ((0,), (0,)) if a.ndim == 3 else ((), ())
-    dims = ((contract[:1], contract[1:]), batch)
-    pairs = [(i + j, s, t) for i, s in enumerate(_terms(a, terms[0]))
-             for j, t in enumerate(_terms(b, terms[1])) if i + j <= 2]
-    pairs.sort(key=lambda pair: -pair[0])                 # the smallest first
-    return functools.reduce(jnp.add, (
-        jax.lax.dot_general(s, t, dims, preferred_element_type=jnp.float32)
-        for _, s, t in pairs))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))

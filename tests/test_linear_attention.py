@@ -14,6 +14,7 @@ import pytest
 from helpers.described_chip import (  # noqa: F401 — fixtures
     cell_step, layer_on_four_chips, v5e)
 from helpers.families import family  # noqa: F401
+from helpers.jaxprs import dots_of, pallas_calls, passes_of
 from test_linear_attention_model import FAMILY  # noqa: F401
 
 
@@ -100,8 +101,8 @@ def test_delta_rule_keeps_the_inputs_type_and_refuses_an_odd_chunk(jax_cpu):
 def test_delta_rule_gradients_are_the_recurrences(jax_cpu, seq, decay,
                                                   beta_top, dtype):
     """All five gradients of the kernels (`kda_bwd`: the chunk function's
-    jax.vjp walked from the last chunk to the first) against jax.grad of the
-    recurrence on the same inputs, one head of two, under a cotangent of its
+    transpose, written out, walked from the last chunk to the first) against
+    jax.grad of the recurrence on the same inputs, one head of two, under a cotangent of its
     own: the decay mild and underflowing inside a chunk, beta up to 2, a
     sequence that is not whole chunks, inputs of four bytes and of two."""
     jax = jax_cpu
@@ -132,6 +133,128 @@ def test_delta_rule_gradients_are_the_recurrences(jax_cpu, seq, decay,
         assert g.dtype == r.dtype and np.isfinite(g).all() and np.any(r), name
         np.testing.assert_allclose(g, r, atol=tol * max(1.0, np.abs(r).max()),
                                    err_msg=name)
+
+
+def _chunks_differentiated(q, k, v, log_decay, beta, *, chunk):
+    """`linear_attention._chunk` a chunk under a `lax.scan`, as plain jnp,
+    on operands padded to whole chunks as `kda` pads them: differentiated
+    by JAX this is `jax.vjp(_chunk)` a chunk from the last to the first,
+    which is what `kda_bwd` was until PR 66 wrote the transpose out. Its
+    oracle."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.ops.linear_attention import _chunk
+    f32 = jnp.float32
+    b, h, s, dk = q.shape
+    pad = -s % chunk
+    n = (s + pad) // chunk
+
+    def cut(x):                  # [B, H, S, d] -> [chunks, B H, chunk, d]
+        x = jnp.pad(x.astype(f32), ((0, 0), (0, 0), (0, pad), (0, 0)))
+        return jnp.moveaxis(x.reshape(b * h, n, chunk, -1), 1, 0)
+    rows = jnp.pad(beta.astype(f32), ((0, 0), (0, 0), (0, pad)))
+    rows = jnp.moveaxis(rows.reshape(b * h, n, 1, chunk), 1, 0)
+
+    def a_chunk(state, xs):
+        o, state, _kept = _chunk(*xs, state)
+        return state, o
+    _, o = jax.lax.scan(
+        a_chunk, jnp.zeros((b * h, v.shape[-1], dk), f32),
+        (cut(q), cut(k), cut(v), cut(log_decay), rows))
+    o = jnp.moveaxis(o, 0, 1).reshape(b, h, n * chunk, -1)
+    return o[:, :, :s].astype(q.dtype)
+
+
+@pytest.mark.parametrize("dtype,seq,chunk,decay,beta_top,a_step", [
+    ("float32", 64, 32, 0.05, 2.0, 4),
+    ("bfloat16", 64, 64, 0.05, 1.0, 4),
+    ("float32", 40, 16, 8.0, 2.0, 1),
+    ("bfloat16", 80, 32, 8.0, 2.0, 1),
+], ids=["float32_beta_near_2", "bfloat16_four_heads_a_step",
+        "float32_underflow_ragged_a_head_a_step",
+        "bfloat16_underflow_ragged_a_head_a_step"])
+def test_the_written_transpose_equals_the_chunks_vjp(
+        jax_cpu, monkeypatch, dtype, seq, chunk, decay, beta_top, a_step):
+    """`kda_bwd`'s body is `_chunk`'s transpose written by hand (PR 66): it
+    reads A, Aqk and the inverse the forward made, sends the inverse's
+    cotangent back in closed form (dM = -T^T dT T^T), walks the tree once
+    on stacked cotangents and leaves out the pairs of terms that are
+    exactly zero (dO and v that arrive as bfloat16 are ONE term). Held
+    here, all five gradients, to JAX's own transpose of the same `_chunk`
+    on the same values (`_chunks_differentiated`): the float32 ones at
+    float32 rounding, those that leave in bfloat16 at one rounding of
+    theirs. The cases: q / k / v (and with them the cotangent) float32 or
+    bfloat16, beta near 2, a decay past -87 inside a chunk, a ragged tail,
+    four heads a grid step and one."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops import linear_attention
+    from ray_tpu.ops.linear_attention import chunk_log_decay, kda
+    monkeypatch.setattr(linear_attention, "_FWD_HEADS", a_step)
+    monkeypatch.setattr(linear_attention, "_BWD_HEADS", a_step)
+    linear_attention._make_kda_fn.cache_clear()
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    q, k, v, log_decay, beta = (jnp.concatenate([x[0], x[1, :1]])[None]
+                                for x in _delta_inputs(
+                                    jax, seq, 32, decay, beta_top, seed=3))
+    q, k, v = (x.astype(dtype) for x in (q, k, v))            # four heads
+    if decay == 8.0:
+        assert float(chunk_log_decay(log_decay, chunk).min()) < -87.0
+    ct = jax.random.normal(jax.random.PRNGKey(11), (1, 4, seq, 32))
+
+    def gradients(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(fn(*a, chunk=chunk).astype(f32) * ct),
+            argnums=(0, 1, 2, 3, 4)))(q, k, v, log_decay, beta)
+    try:
+        got = gradients(kda)
+    finally:
+        linear_attention._make_kda_fn.cache_clear()
+    want = gradients(_chunks_differentiated)
+    for name, g, w, like in zip(("q", "k", "v", "log_decay", "beta"), got,
+                                want, (q, k, v, log_decay, beta)):
+        assert g.dtype == w.dtype == like.dtype, name
+        assert np.any(np.asarray(w.astype(f32))), name
+        top = max(float(jnp.max(jnp.abs(w.astype(f32)))), 1.0)
+        # read when this was written (of each one's largest value): the
+        # float32 ones at most 2.8e-7, those in bfloat16 1.2e-4
+        tol = 2.0 ** -8 if like.dtype == bf16 else 4e-6
+        worst = float(jnp.max(jnp.abs(g.astype(f32) - w.astype(f32))))
+        assert worst < tol * top, (name, worst, top)
+
+
+@pytest.mark.parametrize("dtype,passes", [("bfloat16", 156),
+                                          ("float32", 174)])
+def test_the_backward_multiplies_the_terms_its_operands_have(jax_cpu, dtype,
+                                                             passes):
+    """`LOWERED` does not see a kernel's body, so the count stands here:
+    the bfloat16 passes of the matrix unit that `kda_bwd` traces to at a
+    chunk of 64 (`passes_of`: a float32 product at HIGHEST is six, a
+    bfloat16 one one). Its 29 products: Wv, Wk, Wk S; the tail's transposes
+    (Aqk^T dO, Ktilde dS, dO U^T, dO S, U dS, dU S, dO^T Qbar, dU^T Wk);
+    T^T dWv, T^T dWk, dT's two, dM's two; two a level of the tree's six.
+    float32 q / k / v and cotangent: six passes each, 174. bfloat16: the
+    four products against dO and the two against the bare v (Wv, and dT's
+    dWv v^T) are three bfloat16 matmuls each, 156; a three-term dO or v
+    would make them six again, a one-term cotangent by nature fewer."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.linear_attention import kda
+    shape = lambda *dims, dtype=dtype: jax.ShapeDtypeStruct(dims, dtype)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kda(*a, interpret=False).astype(jnp.float32)),
+        argnums=tuple(range(5))))(
+        shape(1, 4, 128, 128), shape(1, 4, 128, 128), shape(1, 4, 128, 128),
+        shape(1, 4, 128, 128, dtype="float32"),
+        shape(1, 4, 128, dtype="float32")).jaxpr
+    bodies = pallas_calls(jaxpr)
+    assert sorted(bodies) == ["kda_bwd", "kda_fwd"]
+    assert passes_of(bodies["kda_bwd"]) == passes
+    assert dots_of(bodies["kda_bwd"]) == {"bfloat16": 23 + 6 * 3,
+                                          "float32": 29}[dtype]
+    # the forward's, each ONE product at Precision.HIGHEST whatever the
+    # types: two a level, the inverse's two at five levels, the tail's six
+    assert dots_of(bodies["kda_fwd"]) == 12 + 10 + 6
 
 
 def test_a_negative_eigenvalue_flips_what_the_state_holds(jax_cpu):
@@ -233,25 +356,31 @@ def test_plain_filter_kernels_compile_for_v5e(v5e, backward):
     assert ("conv_silu_bwd" if backward else "conv_silu_fwd") in text
 
 
-def test_delta_rule_compiles_at_8192_positions_of_128(v5e):
+@pytest.mark.parametrize("heads", [8, 32], ids=["solar_8_heads",
+                                                "kimi_32_heads"])
+def test_delta_rule_compiles_at_8192_positions_of_128(v5e, heads):
     """ops/linear_attention.py's two kernels at a delta-rule layer of
-    solar2_train_1chip, [1, 8, 8192, 128]: `kda_fwd` and `kda_bwd` (the
-    chunk function's jax.vjp: the transposed products, the rotations back)
-    compile inside their VMEM limit, one Mosaic call each and no XLA loop
-    beside them, neither over the 128 chunks nor the 8192 tokens; the
-    temporaries are the chunks' kept states (67 MB), far under the gigabyte
+    solar2_train_1chip, [1, 8, 8192, 128], and of kimilinear_train_1chip,
+    [1, 32, 8192, 128]: `kda_fwd` and `kda_bwd` (the chunk's transpose
+    written out: the stacked products of the tree, the rotations back)
+    compile inside the VMEM limit each asks for, one Mosaic call each and no
+    XLA loop beside them, neither over the 128 chunks nor the 8192 tokens;
+    the temporaries are the chunks' kept states (67 MB at 8 heads) and their
+    kept matrices (A, Aqk and the inverse packed into [64, 128]: 34 MB,
+    which the compiler may hold in VMEM at 8 heads), far under the gigabyte
     and a half the XLA form was held to."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops import linear_attention
     from ray_tpu.ops.linear_attention import kda
 
     def shape(dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype,
                                     sharding=SingleDeviceSharding(v5e[0]))
-    x = shape((1, 8, 8192, 128))
-    args = (x, x, x, shape((1, 8, 8192, 128), jnp.float32),
-            shape((1, 8, 8192), jnp.float32))
+    x = shape((1, heads, 8192, 128))
+    args = (x, x, x, shape((1, heads, 8192, 128), jnp.float32),
+            shape((1, heads, 8192), jnp.float32))
     compiled = jax.jit(jax.grad(
         lambda *a: jnp.sum(kda(*a, interpret=False).astype(jnp.float32)),
         argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
@@ -260,10 +389,21 @@ def test_delta_rule_compiles_at_8192_positions_of_128(v5e):
     assert len(calls) == 2 and "fwd" in calls[0] and "bwd" in calls[1], calls
     assert text.count("tpu_custom_call") == 2
     assert " while(" not in text
-    # the chunks' states, [8, 128, 128, 128] float32, and little else
-    states = 8 * 128 * 128 * 128 * 4
-    assert states <= compiled.memory_analysis().temp_size_in_bytes < 1.5e9
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * states
+    # what XLA keeps free of VMEM across each call is what its body asked
+    # for (the first size of a call's line; the second is what the body
+    # takes), at most half of what `jax.vjp` of the chunk was given
+    limits = [int(re.search(r'"size":"(\d+)"', line).group(1))
+              for line in text.splitlines()
+              if re.search(r"%\S*kda_(?:fwd|bwd)\S* = .*custom-call\(", line)]
+    assert limits == [linear_attention._FWD_PARAMS.vmem_limit_bytes,
+                      linear_attention._BWD_PARAMS.vmem_limit_bytes]
+    assert max(limits) <= 32 << 20
+    # the chunks' states, [heads, 128, 128, 128] float32, their matrices,
+    # [heads, 128, 64, 128], and little else (at 8 heads the compiler may
+    # hold either in VMEM between the two calls)
+    states = heads * 128 * 128 * 128 * 4
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert states // 2 <= temporaries < 1.1 * (states + states // 2)
 
 
 def test_delta_rule_layer_compiles_on_four_chip_mesh(v5e, monkeypatch):

@@ -23,6 +23,7 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_sharded_step_equals_one_device,
     test_the_configuration_refuses_by_name,
     test_the_new_scopes_are_regions_and_reach_the_compiled_step, tiny)
+from helpers.jaxprs import dots_of, pallas_calls
 
 
 def _expert_form_of_four():
@@ -463,16 +464,6 @@ def test_the_written_transpose_equals_the_chunks_vjp(
         assert worst < tol * top, (name, worst, top)
 
 
-def _dots_of(jaxpr):
-    """How many dot_generals a jaxpr holds, its sub-jaxprs' counted once
-    each (a loop's body is one head's)."""
-    from jax._src import core
-    return sum(
-        (eqn.primitive.name == "dot_general")
-        + sum(_dots_of(sub) for sub in core.jaxprs_in_params(eqn.params))
-        for eqn in jaxpr.eqns)
-
-
 @pytest.mark.parametrize("dtype,dots", [("bfloat16", 35), ("float32", 63)])
 def test_the_backward_multiplies_the_terms_its_operands_have(jax_cpu, dtype,
                                                              dots):
@@ -487,7 +478,6 @@ def test_the_backward_multiplies_the_terms_its_operands_have(jax_cpu, dtype,
     passes (the triangle's three)."""
     jax = jax_cpu
     import jax.numpy as jnp
-    from jax._src import core
     from ray_tpu.ops.state_space import ssd
     shape = lambda *dims, dtype=dtype: jax.ShapeDtypeStruct(dims, dtype)
     jaxpr = jax.make_jaxpr(jax.grad(
@@ -497,17 +487,11 @@ def test_the_backward_multiplies_the_terms_its_operands_have(jax_cpu, dtype,
         shape(64, dtype="float32"), shape(1, 512, 1, 128),
         shape(1, 512, 1, 128), shape(64, dtype="float32")).jaxpr
 
-    def calls(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                yield eqn
-            for sub in core.jaxprs_in_params(eqn.params):
-                yield from calls(sub)
-    by_name = {eqn.params["name"]: eqn for eqn in calls(jaxpr)}
-    assert sorted(by_name) == ["ssd_bwd", "ssd_fwd"]
-    assert _dots_of(by_name["ssd_bwd"].params["jaxpr"]) == dots
+    bodies = pallas_calls(jaxpr)
+    assert sorted(bodies) == ["ssd_bwd", "ssd_fwd"]
+    assert dots_of(bodies["ssd_bwd"]) == dots
     # the forward's: C B^T, x W, C S_0, x^T B
-    assert _dots_of(by_name["ssd_fwd"].params["jaxpr"]) == {
+    assert dots_of(bodies["ssd_fwd"]) == {
         "bfloat16": 1 + 3 + 3 + 3, "float32": 24}[dtype]
 
 
