@@ -17,7 +17,9 @@ optional sliding-window layers among the full-attention layers (a head
 count and a rotation of their own a kind, or no rotation at all, a gate a
 head or an element on attention's output), optional gated delta-rule
 linear-attention layers among them (ops/linear_attention.py: a state a head
-that the data decays a channel at a time and overwrites along the key), an
+that the data decays a channel at a time, or by one number a head, and
+overwrites along the key; widths of their own for key and value: `delta`),
+the norm of a half before it or after it (`norm_after`), an
 optional learned sparse-attention indexer on the attention
 layers (ops/indexer.py: it chooses the keys a query sees, and is trained by
 a loss of its own),
@@ -100,6 +102,24 @@ class StateSpace:
     groups: int
     state: int
     chunk: int = 128
+
+
+@dataclass(frozen=True)
+class DeltaRule:
+    """A "kda" layer's widths and forms where they are not KDA's
+    (GPTConfig.delta; `_kda_block`): n_heads heads of key_dim for q and k and
+    value_dim for v, a state [key_dim, value_dim] a head, the norm on the
+    heads' outputs over value_dim. decay "channel": a log-decay a channel of
+    the key through a low-rank pair of rank key_dim with a step bias a
+    channel; "head": ONE log-decay a head and token, -exp(a_log)
+    softplus(x w_decay [d, heads] + dt_bias [heads]) (Gated DeltaNet,
+    arXiv:2412.06464). gate "sigmoid": a sigmoid gate an element through a
+    low-rank pair of rank key_dim; "silu": a SiLU gate an element from a
+    full matrix wg [d, heads x value_dim]."""
+    key_dim: int
+    value_dim: int
+    decay: str = "channel"            # channel | head
+    gate: str = "sigmoid"             # sigmoid | silu
 
 
 @dataclass(frozen=True)
@@ -198,6 +218,11 @@ class GPTConfig:
     # False: no attention layer rotates q or k (no position enters the
     # scores but through the causal mask), and no rope table is built.
     use_rope: bool = True
+    # Where the norm of a half sits: False, before it (h = x + f(norm(x)));
+    # True, after it, on what the half ADDS (h = x + norm(f(x)): the mixer
+    # and the feed-forward read the stream itself; ln1 and ln2 are those
+    # norms' scales).
+    norm_after: bool = False
     # A gate on attention's output, from the normed input, before the
     # output projection: True, a gate a head, sigmoid(x wg [d, heads])
     # times the head's output; "element", a gate an element, wg [d, heads x
@@ -211,7 +236,9 @@ class GPTConfig:
     # from the normed input (beta in (0, 1), or (0, 2) with
     # kda_neg_eigval), the heads' outputs under an RMSNorm a head times a
     # sigmoid gate an element, then the output projection. The decay and
-    # the gate come through low-rank pairs of rank head_dim.
+    # the gate come through low-rank pairs of rank head_dim. `delta` gives
+    # the layer widths of its own for key and value, a decay a head and a
+    # SiLU gate from a full matrix.
     kda_neg_eigval: bool = False
     # index_topk > 0: every attention layer carries an indexer (`attn/index`:
     # index_heads thin heads of index_head_dim on ONE key head, which has a
@@ -304,9 +331,11 @@ class GPTConfig:
     attention: str = "flash"          # flash | reference | ring
     tie_embeddings: bool = False
     # Sub-records, each None where the stack has nothing of the kind:
-    # state-space layers' sizes, the feed-forwards' form, a prediction
-    # module, scalar multipliers.
+    # state-space layers' sizes, delta-rule layers' widths and forms (None:
+    # KDA's at head_dim), the feed-forwards' form, a prediction module,
+    # scalar multipliers.
     ssm: Optional[StateSpace] = None
+    delta: Optional[DeltaRule] = None
     expert_form: Optional[ExpertForm] = None
     mtp: Optional[PredictionModule] = None
     multipliers: Optional[Multipliers] = None
@@ -361,6 +390,11 @@ class GPTConfig:
             raise ValueError(
                 f"expert_form {form!r}: expected matrices 2 | 3 and an "
                 f"activation of {' | '.join(map(repr, _ACTIVATIONS))}")
+        if self.delta is not None and (
+                self.delta.decay not in ("channel", "head")
+                or self.delta.gate not in ("sigmoid", "silu")):
+            raise ValueError(f"delta {self.delta!r}: expected decay 'channel' "
+                             "| 'head' and gate 'sigmoid' | 'silu'")
         if "kda" in (kinds or ()) and self.attention == "ring":
             raise ValueError(
                 "a 'kda' layer's state runs along the whole sequence: it is "
@@ -429,6 +463,11 @@ class GPTConfig:
         sets one (multipliers.attention); None: head_dim^-1/2, the kernels'
         own default."""
         return self.scales.attention
+
+    @property
+    def delta_rule(self) -> DeltaRule:
+        """delta, or KDA's layer: q, k and v alike at head_dim."""
+        return self.delta or DeltaRule(self.head_dim, self.head_dim)
 
     @property
     def feed_forward(self) -> ExpertForm:
@@ -577,8 +616,12 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
                 "ww": _init_dense(ik[2], (d, hi)),
             }
         if "attn" in layer and cfg.qk_norm:
-            layer["attn"]["q_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
-            layer["attn"]["k_norm"] = {"scale": jnp.ones((d,), jnp.float32)}
+            # over the whole projection: its columns, which are d_model's
+            # count only where heads x head_dim is
+            for name, w in (("q_norm", "wq"), ("k_norm", "wk")):
+                width = layer["attn"][w].shape[1] if w in layer["attn"] else d
+                layer["attn"][name] = {"scale": jnp.ones((width,),
+                                                         jnp.float32)}
         if "attn" in layer and cfg.qk_head_norm:
             for name in ("q_head_norm", "k_head_norm"):
                 layer["attn"][name] = {
@@ -625,34 +668,57 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
 
 
 def _init_kda(key, cfg: GPTConfig, adds: int) -> Dict:
-    """A "kda" layer's parameters (adds: gpt_init's). The projections at
-    their fan-in's scale;
+    """A "kda" layer's parameters (adds: gpt_init's), at cfg.delta_rule's
+    widths and forms. The projections at their fan-in's scale;
     a filter's taps at 1 / sqrt(taps); the decay's two seeded ranges as the
     delta-rule papers publish them: exp(a_log), a head's decay rate,
     log-uniform over 1..16, and dt_bias the inverse softplus of a step
-    log-uniform over 1e-3..0.1, a channel."""
-    d, hd, h = cfg.d_model, cfg.head_dim, cfg.n_heads
-    wide, taps = h * hd, cfg.conv_filter
+    log-uniform over 1e-3..0.1, a channel (a head, where the decay is a
+    head's: `w_decay` [d, heads] then stands where the low-rank pair
+    `wf_down`, `wf_up` does). A SiLU gate is one full matrix `wg` where the
+    sigmoid gate is the pair `wg_down`, `wg_up`. Under cfg.delta the three
+    projections are ONE matrix `w_qkv` and their filters one, `qkv_conv`,
+    a head's dk + dk + dv columns side by side (heads x (2 dk + dv) fills
+    lane tiles where heads x dk alone need not)."""
+    d, h, size = cfg.d_model, cfg.n_heads, cfg.delta_rule
+    keys, wide, rank = h * size.key_dim, h * size.value_dim, size.key_dim
+    taps = cfg.conv_filter
     k = jax.random.split(key, 13)
-    dt = jnp.exp(jax.random.uniform(k[11], (wide,), minval=math.log(1e-3),
-                                    maxval=math.log(0.1)))
+    dt = jnp.exp(jax.random.uniform(
+        k[11], (keys if size.decay == "channel" else h,),
+        minval=math.log(1e-3), maxval=math.log(0.1)))
     layer = {
-        "wq": _init_dense(k[0], (d, wide)),
-        "wk": _init_dense(k[1], (d, wide)),
-        "wv": _init_dense(k[2], (d, wide)),
-        "wf_down": _init_dense(k[6], (d, hd)),
-        "wf_up": _init_dense(k[7], (hd, wide)),
         "a_log": jax.random.uniform(k[10], (h,), maxval=math.log(16.0)),
         "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
         "w_beta": _init_dense(k[8], (d, h)),
-        "wg_down": _init_dense(k[9], (d, hd)),
-        "wg_up": _init_dense(k[12], (hd, wide)),
-        "o_norm": {"scale": jnp.ones((hd,), jnp.float32)},
+        "o_norm": {"scale": jnp.ones((size.value_dim,), jnp.float32)},
         "wo": _init_dense(k[3], (wide, d),
                           scale=1.0 / math.sqrt(adds * wide)),
     }
-    filters = _init_dense(k[4], (3, wide, taps), scale=1.0 / math.sqrt(taps))
-    layer.update(q_conv=filters[0], k_conv=filters[1], v_conv=filters[2])
+    if cfg.delta is None:
+        filters = _init_dense(k[4], (3, wide, taps),
+                              scale=1.0 / math.sqrt(taps))
+        layer.update(wq=_init_dense(k[0], (d, keys)),
+                     wk=_init_dense(k[1], (d, keys)),
+                     wv=_init_dense(k[2], (d, wide)),
+                     q_conv=filters[0], k_conv=filters[1], v_conv=filters[2])
+    else:
+        # ONE projection and ONE filter, a head's columns [q | k | v] side
+        # by side: whole heads are whole columns of it
+        layer.update(
+            w_qkv=_init_dense(k[0], (d, 2 * keys + wide)),
+            qkv_conv=_init_dense(k[4], (2 * keys + wide, taps),
+                                 scale=1.0 / math.sqrt(taps)))
+    if size.decay == "channel":
+        layer.update(wf_down=_init_dense(k[6], (d, rank)),
+                     wf_up=_init_dense(k[7], (rank, keys)))
+    else:
+        layer["w_decay"] = _init_dense(k[6], (d, h))
+    if size.gate == "sigmoid":
+        layer.update(wg_down=_init_dense(k[9], (d, rank)),
+                     wg_up=_init_dense(k[12], (rank, wide)))
+    else:
+        layer["wg"] = _init_dense(k[9], (d, wide))
     return layer
 
 
@@ -1245,17 +1311,23 @@ def _conv_block(m, x, cfg: GPTConfig, where: Setting):
 
 
 def _kda_block(m, x, cfg: GPTConfig, where: Setting):
-    """Gated delta-rule linear attention in attention's place (KDA; the
-    recurrence and its chunked form: ops/linear_attention.py). From the
-    normed input x, a head h of head_dim columns:
+    """Gated delta-rule linear attention in attention's place (KDA, or the
+    layer cfg.delta describes; the recurrence and its chunked form:
+    ops/linear_attention.py). From the block's input x (normed, or under
+    cfg.norm_after the stream itself), a head h of dk columns for q and k
+    and dv for v (cfg.delta_rule: head_dim both, or cfg.delta's own):
 
       q, k, v = silu(filter(x wq | wk | wv)), a causal depthwise filter a
-            channel (ops/short_conv.py:silu_conv); q and k divided by their
-            head's norm, q times head_dim^-1/2
-      log-decay a channel: -exp(a_log_h) softplus((x wf_down) wf_up + dt_bias)
+            channel (ops/short_conv.py:silu_conv; where m holds `w_qkv`,
+            one projection and one filter, a head's columns [q | k | v]);
+            q and k divided by their head's norm, q times dk^-1/2
+      log-decay a channel: -exp(a_log_h) softplus((x wf_down) wf_up + dt_bias),
+            or, where m holds `w_decay`, ONE a head:
+            -exp(a_log_h) softplus(x w_decay + dt_bias_h)
       beta a head: sigmoid(x w_beta), doubled under cfg.kda_neg_eigval
       o = kda(q, k, v, log-decay, beta)
-      y = [RMSNorm_head(o) * sigmoid((x wg_down) wg_up)] wo
+      y = [RMSNorm_head(o) * sigmoid((x wg_down) wg_up)] wo, or, where m
+            holds `wg`, [RMSNorm_head(o) * silu(x wg)] wo
 
     -> (y, the layer's statistics: `kda_log_decay_min`, the smallest
     cumulative log-decay inside a chunk, and `kda_beta_mean`). The filters,
@@ -1265,13 +1337,13 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
     delta rule alone."""
     dt, f32 = cfg.dtype, jnp.float32
     b, s, _ = x.shape
-    hd = cfg.head_dim
+    size = cfg.delta_rule
     columns = ("batch", None, "heads")
     conv = _per_shard(silu_conv, where.mesh, (columns, ("heads", None)),
                       columns)
 
-    def heads(y):                        # [B, S, H * hd] -> [B, H, S, hd]
-        return y.reshape(b, s, -1, hd).transpose(0, 2, 1, 3)
+    def heads(y, width):                 # [B, S, H * w] -> [B, H, S, w]
+        return y.reshape(b, s, -1, width).transpose(0, 2, 1, 3)
 
     def unit(y):
         y = y.astype(f32)
@@ -1282,19 +1354,37 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
             "bsr,re->bse", jnp.einsum("bsd,dr->bsr", x, m[down].astype(dt)),
             m[up].astype(dt), preferred_element_type=f32)
 
+    def a_head(w):                       # x w [d, H], float32 -> [B, S, H]
+        return jnp.einsum("bsd,dh->bsh", x, m[w].astype(dt),
+                          preferred_element_type=f32)
+
     with jax.named_scope("kda"):
-        q, k, v = (heads(conv(jnp.einsum("bsd,de->bse", x, m[w].astype(dt)),
-                              m[taps]))
-                   for w, taps in (("wq", "q_conv"), ("wk", "k_conv"),
-                                   ("wv", "v_conv")))
-        q = (unit(q) * hd ** -0.5).astype(dt)
+        if "w_qkv" in m:
+            # one projection, one filter; a head's columns are [q | k | v]
+            qkv = heads(conv(jnp.einsum("bsd,de->bse", x,
+                                        m["w_qkv"].astype(dt)),
+                             m["qkv_conv"]), 2 * size.key_dim + size.value_dim)
+            q, k, v = (qkv[..., :size.key_dim],
+                       qkv[..., size.key_dim:2 * size.key_dim],
+                       qkv[..., 2 * size.key_dim:])
+        else:
+            q, k, v = (
+                heads(conv(jnp.einsum("bsd,de->bse", x, m[w].astype(dt)),
+                           m[taps]), width)
+                for w, taps, width in (("wq", "q_conv", size.key_dim),
+                                       ("wk", "k_conv", size.key_dim),
+                                       ("wv", "v_conv", size.value_dim)))
+        q = (unit(q) * size.key_dim ** -0.5).astype(dt)
         k = unit(k).astype(dt)
-        rate = jnp.repeat(jnp.exp(m["a_log"].astype(f32)), hd)
-        log_decay = heads(-rate * jax.nn.softplus(
-            low_rank("wf_down", "wf_up") + m["dt_bias"]))
-        beta = jax.nn.sigmoid(jnp.einsum(
-            "bsd,dh->bsh", x, m["w_beta"].astype(dt),
-            preferred_element_type=f32)).transpose(0, 2, 1)
+        if "w_decay" in m:
+            # one number a head and token: [B, H, S, 1]
+            log_decay = (-jnp.exp(m["a_log"].astype(f32)) * jax.nn.softplus(
+                a_head("w_decay") + m["dt_bias"])).transpose(0, 2, 1)[..., None]
+        else:
+            rate = jnp.repeat(jnp.exp(m["a_log"].astype(f32)), size.key_dim)
+            log_decay = heads(-rate * jax.nn.softplus(
+                low_rank("wf_down", "wf_up") + m["dt_bias"]), size.key_dim)
+        beta = jax.nn.sigmoid(a_head("w_beta")).transpose(0, 2, 1)
         if cfg.kda_neg_eigval:
             beta = 2.0 * beta
         with jax.named_scope("kda_core"):
@@ -1305,9 +1395,13 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
         stats = {"kda_log_decay_min": jnp.min(chunk_log_decay(log_decay)),
                  "kda_beta_mean": jnp.mean(beta)}
         # a head is whole wherever its columns are: no psum
-        o = _rmsnorm(o.transpose(0, 2, 1, 3).astype(f32),      # [B, S, H, hd]
+        o = _rmsnorm(o.transpose(0, 2, 1, 3).astype(f32),      # [B, S, H, dv]
                      m["o_norm"]["scale"], cfg.rmsnorm_eps)
-        gate = jax.nn.sigmoid(low_rank("wg_down", "wg_up"))
+        if "wg" in m:
+            gate = jax.nn.silu(jnp.einsum("bsd,de->bse", x, m["wg"].astype(dt),
+                                          preferred_element_type=f32))
+        else:
+            gate = jax.nn.sigmoid(low_rank("wg_down", "wg_up"))
         o = (o.reshape(b, s, -1) * gate).astype(dt)
         return where.psum(jnp.einsum("bsd,de->bse", o,
                                      m["wo"].astype(dt))), stats
@@ -1717,8 +1811,14 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         """x + residual f: what a half adds to the stream."""
         return where.pin(x + (delta if residual == 1.0 else delta * residual))
 
+    def norm(name, y, layer):
+        return _rmsnorm(y, layer[name]["scale"], cfg.rmsnorm_eps)
+
     def block(x, layer, named_mlp=0):
-        normed = _rmsnorm(x, layer["ln1"]["scale"], cfg.rmsnorm_eps)
+        # (under norm_after a half reads the stream itself and its norm
+        # sits on what it adds, where every shard of 'tensor' has the sum)
+        after = cfg.norm_after
+        normed = x if after else norm("ln1", x, layer)
         mixer_stats, routing = {}, None
         if cfg.route_from == "input" and "moe" in layer:
             with jax.named_scope("route_ahead"):
@@ -1735,11 +1835,16 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
                 layer, normed, cfg, tables[kind], where, kind, index_table)
         else:
             mixed = None                 # a feed-forward alone
+        if after and mixed is not None:
+            mixed = norm("ln1", mixed, layer)
         h = x if mixed is None else add(x, mixed)
         if "moe" not in layer and "mlp" not in layer:
             return h, mixer_stats        # a mixer alone
-        if mixed is not None:
-            normed = _rmsnorm(h, layer["ln2"]["scale"], cfg.rmsnorm_eps)
+        second = "ln1" if mixed is None else "ln2"
+        if after:
+            normed = h
+        elif mixed is not None:
+            normed = norm("ln2", h, layer)
         if "moe" in layer:
             with jax.named_scope("moe"):
                 delta, stats = _moe_block(layer, normed, cfg, where, routing,
@@ -1748,6 +1853,8 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
             with jax.named_scope("mlp"):
                 delta, stats = _mlp_block(
                     layer["mlp"], normed, cfg, where, named_mlp), {}
+        if after:
+            delta = norm(second, delta, layer)
         return add(h, delta), {**stats, **mixer_stats}
 
     if cfg.remat_policy == "full":
@@ -1821,11 +1928,12 @@ def _layer_bytes(layer, batch: int, seq: int, cfg: GPTConfig,
                 (hi * di + di) * item + hi * 4)
     if "kda" in layer:
         # (the kernels hold a head's columns in whole lane tiles)
-        heads, hd = cfg.n_heads, cfg.head_dim + -cfg.head_dim % LANES
+        heads, size = cfg.n_heads, cfg.delta_rule
+        dk, dv = (w + -w % LANES for w in (size.key_dim, size.value_dim))
         # the output, and a chunk of 64's state and its A, Aqk and inverse
         # packed into [64, 2 x 64]
-        named += tokens * heads * hd * item \
-            + batch * heads * (seq // 64) * (hd * hd + 64 * 2 * 64) * 4
+        named += tokens * heads * dv * item \
+            + batch * heads * (seq // 64) * (dv * dk + 64 * 2 * 64) * 4
     if "ssm" in layer:
         size = cfg.ssm
         named += tokens * size.heads * size.head_dim * item \
@@ -1874,7 +1982,8 @@ def _working_set(layers, batch: int, seq: int, cfg: GPTConfig,
                 if "index" in layer[group]:
                     mixer += 2 * seq            # float32 scores and their KL
         if "kda" in layer:
-            mixer = 2 * cfg.n_heads * cfg.head_dim
+            mixer = cfg.n_heads * (cfg.delta_rule.key_dim
+                                   + cfg.delta_rule.value_dim)
         if "ssm" in layer:
             mixer = layer["ssm"]["w_xbc"].shape[-1]
         if "conv" in layer:

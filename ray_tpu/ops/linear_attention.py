@@ -1,6 +1,8 @@
 """Linear attention that carries a state: the gated delta rule with a decay
 a channel (KDA, the form arXiv:2510.26692 publishes for Kimi Delta
-Attention). A head keeps S [dk, dv] and, a token,
+Attention) or ONE decay a head (Gated DeltaNet, arXiv:2412.06464: the same
+recurrence with every channel of alpha_t the same number). A head keeps S
+[dk, dv] and, a token,
 
     S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t,                                           S_0 = 0
@@ -9,9 +11,11 @@ alpha_t = exp(log_decay_t) in (0, 1]^dk, beta_t a number (up to 2: the
 transition then has a negative eigenvalue along k_t). The data decays the
 state a channel at a time and overwrites what it holds along k_t.
 
-q, k [B, H, S, dk], v [B, H, S, dv], log_decay [B, H, S, dk] (<= 0, float32)
-and beta [B, H, S] in, o [B, H, S, dv] out, in q's type; every sum and the
-state float32. The caller normalises and scales q and k.
+q, k [B, H, S, dk], v [B, H, S, dv], log_decay [B, H, S, dk] (<= 0, float32;
+[B, H, S, 1] for a decay a head) and beta [B, H, S] in, o [B, H, S, dv] out,
+in q's type; every sum and the state float32. dk and dv need not be alike
+nor whole lane tiles (padded: 96 / 192 run as 128 / 256). The caller
+normalises and scales q and k.
 
 Two formulations. `kda_reference` is the recurrence as it stands, a token a
 step of a `lax.scan` (the oracle, never the timed path). `kda` is its
@@ -41,6 +45,16 @@ levels cover every pair below the diagonal once. The same tree inverts I +
 Diag(beta) A, which is unit lower triangular: with X the inverse of its
 diagonal blocks of b tokens and M the level's pairs, X - X M X is the
 inverse of the blocks of 2b (block forward substitution, as matmuls).
+
+ONE decay a head needs no tree for A: g_t is a number, e^{g_t - g_s} a [C, C]
+matrix of differences (all <= 0 on and below the diagonal: nothing divides),
+A and Aqk are one masked product each, K K^T and Q K^T, times that matrix, and
+Kbar, Ktilde and Qbar are rows times a number. `kda` hands the kernels each
+chunk's cumulative log-decay as a row of C numbers (as beta's), `_chunk` and
+`_bwd_kernel` read off the decay's shape which form they run, and the inverse's
+doubling and everything after it are the same lines for both. A v5e, ms a call
+at [1, 15, 8192, 96 / 192]: `kda_fwd` 3.07 and `kda_bwd` 4.05, against 4.58 and
+6.16 for the same decay broadcast to the key's channels through the tree.
 
 `_chunk` states all of that once, for one chunk of a few heads, as a jnp
 function of values that live in VMEM (a chunk's q, k, v, g are 32 KB each
@@ -209,7 +223,34 @@ def _undoubled(level: int, dsince, duntil, dwhole):
                             + pltpu.roll(dafter, half, 1))
 
 
-def _chunk(q, k, v, a, beta, state):
+def _column(row):
+    """[h, 1, C], tokens along the lanes -> [h, C, 1]: the diagonal's row
+    sums."""
+    t, s = _pairs(row.shape[2])
+    return jnp.sum(jnp.where(t == s, row, 0.0), axis=2, keepdims=True)
+
+
+def _last(row):
+    """[h, 1, C] -> [h, 1, 1]: the row's last token's."""
+    chunk = row.shape[2]
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, 1, chunk), 2)
+    return jnp.sum(jnp.where(at == chunk - 1, row, 0.0), axis=2,
+                   keepdims=True)
+
+
+def _a_heads_decays(g):
+    """ONE decay a head: g [h, 1, C], the cumulative log-decay inside the
+    chunk, a row -> (e^{g_t - g_s} [h, C, C] on and below the diagonal and
+    0 above it, e^{g_t}, e^{g_C - g_t} [h, C, 1] and e^{g_C} [h, 1, 1]).
+    The pairs' decays are a matrix of differences, every exponent <= 0:
+    nothing divides and no tree is walked."""
+    t, s = _pairs(g.shape[2])
+    column, last = _column(g), _last(g)
+    between = jnp.where(t >= s, jnp.exp(jnp.minimum(column - g, 0.0)), 0.0)
+    return between, jnp.exp(column), jnp.exp(last - column), jnp.exp(last)
+
+
+def _chunk(q, k, v, a, beta, state, exact: int = 3):
     """One chunk of h heads, on values that live in VMEM: q, k, the
     log-decay a [h, C, dk], v [h, C, dv], beta [h, 1, C] (a row: tokens
     along the lanes) and the state the chunk starts from, transposed [h,
@@ -226,34 +267,59 @@ def _chunk(q, k, v, a, beta, state):
     right half and e^{g_r - g_s} = e^{B_b[s]} for s in a left half, and a
     level doubles them by adding the sibling block's whole, T_b moved b
     rows (F_1 = T_1 = a, B_1 = 0; at the top F = g, B = g_C - g, T =
-    g_C). Every exponent is <= 0."""
+    g_C). Every exponent is <= 0.
+
+    The function adapts to the decay it is handed. ONE decay a head arrives
+    as a [h, 1, C], a row like beta, and is g itself, the cumulative
+    log-decay inside the chunk: e^{g_t - g_s} is then a [C, C] matrix of
+    differences (`_a_heads_decays`), A and Aqk are ONE masked product each
+    times it where the decay a channel walks the tree's log2 C levels, and
+    Kbar, Ktilde, Qbar are rows times a number; the inverse's doubling and
+    everything after it are the same lines. exact: the bfloat16 terms q and
+    k have (1 where they arrived as bfloat16: their plain products are one
+    pass)."""
     chunk = q.shape[1]
     t, s = _pairs(chunk)
     eye = t == s
     # beta a row in, a column here: the diagonal's row sums
     beta = jnp.sum(jnp.where(eye, beta, 0.0), axis=2, keepdims=True)
-    since, until, whole = a, jnp.zeros_like(a), a
-    aqk = jnp.where(eye, jnp.sum(q * k, axis=2, keepdims=True), 0.0)
-    akk = jnp.zeros_like(aqk)
-    inverse = jnp.broadcast_to(eye.astype(jnp.float32), aqk.shape)
-    for level in range(chunk.bit_length() - 1):
-        # (t in a block's right half, s in its left), zero elsewhere
-        pairs = (((t ^ s) >> level) == 1) & (t > s)
-        rows, cols = jnp.exp(since), k * jnp.exp(until)
-        between = _dot(k * rows, cols, (2, 2))
-        akk = akk + jnp.where(pairs, between, 0.0)
-        m = jnp.where(pairs, beta * between, 0.0)
-        aqk = aqk + jnp.where(pairs, _dot(q * rows, cols, (2, 2)), 0.0)
-        inverse = inverse - (m if level == 0 else _dot(
-            _dot(inverse, m, (2, 1)), inverse, (2, 1)))
-        since, until, whole = _doubled(level, since, until, whole)
-    decayed = jnp.exp(since)                        # e^g, the chunk's own
+    inverse = jnp.broadcast_to(eye.astype(jnp.float32),
+                               (q.shape[0], chunk, chunk))
+    if a.shape[1] == 1 and chunk > 1:
+        # ONE decay a head: a is g [h, 1, C], and A and Aqk are one masked
+        # product each times the matrix of the pairs' decays
+        between, decayed, ending, fade = _a_heads_decays(a)
+        akk = jnp.where(eye, 0.0,
+                        _product(k, k, (2, 2), (exact, exact)) * between)
+        aqk = _product(q, k, (2, 2), (exact, exact)) * between
+        for level in range(chunk.bit_length() - 1):
+            pairs = (((t ^ s) >> level) == 1) & (t > s)
+            m = jnp.where(pairs, beta * akk, 0.0)
+            inverse = inverse - (m if level == 0 else _dot(
+                _dot(inverse, m, (2, 1)), inverse, (2, 1)))
+    else:
+        since, until, whole = a, jnp.zeros_like(a), a
+        aqk = jnp.where(eye, jnp.sum(q * k, axis=2, keepdims=True), 0.0)
+        akk = jnp.zeros_like(aqk)
+        for level in range(chunk.bit_length() - 1):
+            # (t in a block's right half, s in its left), zero elsewhere
+            pairs = (((t ^ s) >> level) == 1) & (t > s)
+            rows, cols = jnp.exp(since), k * jnp.exp(until)
+            between = _dot(k * rows, cols, (2, 2))
+            akk = akk + jnp.where(pairs, between, 0.0)
+            m = jnp.where(pairs, beta * between, 0.0)
+            aqk = aqk + jnp.where(pairs, _dot(q * rows, cols, (2, 2)), 0.0)
+            inverse = inverse - (m if level == 0 else _dot(
+                _dot(inverse, m, (2, 1)), inverse, (2, 1)))
+            since, until, whole = _doubled(level, since, until, whole)
+        # e^g, the chunk's own; e^{g_C - g}; e^{g_C} [h, 1, dk]
+        decayed, ending, fade = (jnp.exp(since), jnp.exp(until),
+                                 jnp.exp(whole[:, :1]))
     wv = _dot(inverse, beta * v, (2, 1))
     wk = _dot(inverse, beta * (k * decayed), (2, 1))
     u = wv - _dot(wk, state, (2, 2))
     o = _dot(q * decayed, state, (2, 2)) + _dot(aqk, u, (2, 1))
-    after = (state * jnp.exp(whole[:, :1])
-             + _dot(u, k * jnp.exp(until), (1, 1)))
+    after = state * fade + _dot(u, k * ending, (1, 1))
     return o, after, (akk, aqk, inverse)
 
 
@@ -290,9 +356,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, states_ref,
     def _():
         state[...] = jnp.zeros_like(state)
     states_ref[:, 0] = state[...]
+    # a decay a head is a row a chunk, as beta is
+    a = a_ref[:, 0] if len(a_ref.shape) == 4 else a_ref[...]
     o, state[...], kept = _chunk(
         q_ref[...].astype(f32), k_ref[...].astype(f32),
-        v_ref[...].astype(f32), a_ref[...], beta_ref[:, 0], state[...])
+        v_ref[...].astype(f32), a, beta_ref[:, 0], state[...],
+        exact=1 if q_ref.dtype == jnp.bfloat16 else 3)
     o_ref[...] = o.astype(o_ref.dtype)
     kept_ref[:, 0] = _packed(*kept)
 
@@ -329,15 +398,20 @@ def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, kept_ref,
     row = beta_ref[:, 0]                            # beta [h, 1, C]
     beta = jnp.sum(jnp.where(eye, row, 0.0), axis=2, keepdims=True)
 
-    # e^F and e^B of every level, as `_chunk` makes them
-    since, until, whole = a_ref[...], jnp.zeros(q.shape, f32), a_ref[...]
-    from_start, to_end = [], []
-    for level in range(levels):
-        from_start.append(jnp.exp(since))
-        to_end.append(jnp.exp(until))
-        since, until, whole = _doubled(level, since, until, whole)
-    decayed, ending = jnp.exp(since), jnp.exp(until)
-    fade = jnp.exp(whole[:, :1])                    # e^{g_C} [h, 1, dk]
+    a_head = len(a_ref.shape) == 4                  # ONE decay a head
+    if a_head:
+        between, decayed, ending, fade = _a_heads_decays(a_ref[:, 0])
+    else:
+        # e^F and e^B of every level, as `_chunk` makes them
+        since, until, whole = (a_ref[...], jnp.zeros(q.shape, f32),
+                               a_ref[...])
+        from_start, to_end = [], []
+        for level in range(levels):
+            from_start.append(jnp.exp(since))
+            to_end.append(jnp.exp(until))
+            since, until, whole = _doubled(level, since, until, whole)
+        decayed, ending = jnp.exp(since), jnp.exp(until)
+        fade = jnp.exp(whole[:, :1])                # e^{g_C} [h, 1, dk]
     qbar, kbar, ktilde = q * decayed, k * decayed, k * ending
 
     # the tail of the chunk again: [Wv | Wk] = T Diag(beta) [V | Kbar]
@@ -370,6 +444,38 @@ def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, kept_ref,
                               keepdims=True)
     dv_ref[...] = (beta * drv).astype(dv_ref.dtype)
     dakk, dkbar = beta * dm, beta * drk
+    if a_head:
+        # A = (K K^T) D and Aqk = (Q K^T) D under the pairs' decays D, the
+        # diagonal of Aqk like any pair: their cotangents times D go back
+        # through the two plain products, stacked (q and k with the terms
+        # they have), and D's own is dA A + dAqk Aqk, e^x's slope being e^x
+        of_k = 1 if k_ref.dtype == bf16 else 3
+        both = jnp.concatenate([dakk * between, daqk * between], axis=1)
+        dplain = _product(both, k, (2, 1), (3, of_k))         # [h, 2 C, dk]
+        dcols = _product(both, jnp.concatenate([k, q], axis=1), (1, 1),
+                         (3, of_k))                           # [h, C, dk]
+        dq_ref[...] = (dqbar * decayed
+                       + dplain[:, chunk:]).astype(dq_ref.dtype)
+        dk_ref[...] = (dkbar * decayed + dktilde * ending + dplain[:, :chunk]
+                       + dcols).astype(dk_ref.dtype)
+        # g's: a column from e^{g_t} (Qbar, Kbar), e^{g_C - g_t} (Ktilde)
+        # and the rows of D's cotangent, less a row from its columns; the
+        # last token's from e^{g_C} (Ktilde, the state's fade)
+        dbetween = dakk * akk + daqk * aqk
+        leaving = jnp.sum(dktilde * ktilde, axis=2, keepdims=True)
+        dg = (jnp.sum(dqbar * qbar + dkbar * kbar, axis=2, keepdims=True)
+              - leaving + jnp.sum(dbetween, axis=2, keepdims=True))
+        dlast = (jnp.sum(leaving, axis=1, keepdims=True)
+                 + fade * jnp.sum(jnp.sum(handed * state, axis=2,
+                                          keepdims=True), axis=1,
+                                  keepdims=True))
+        at_end = jax.lax.broadcasted_iota(jnp.int32, (1, 1, chunk),
+                                          2) == chunk - 1
+        da_ref[:, 0] = (jnp.sum(jnp.where(eye, dg, 0.0), axis=1,
+                                keepdims=True)
+                        - jnp.sum(dbetween, axis=1, keepdims=True)
+                        + jnp.where(at_end, dlast, 0.0))
+        return
     on_diagonal = jnp.sum(jnp.where(eye, daqk, 0.0), axis=2, keepdims=True)
     dq = dqbar * decayed + on_diagonal * k
     dk = dkbar * decayed + dktilde * ending + on_diagonal * q
@@ -408,7 +514,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, kept_ref,
 # (backward: the decays of every level, the running gradients). A v5e, ms a
 # call at [1, 8, 8192, 128] / [1, 32, 8192, 128]: `kda_fwd` 2.11 / 8.42 at
 # 2 heads, 2.04 / 8.15 at 4, 2.01 / 8.04 at 8; `kda_bwd` 3.09 / 12.36 at 1,
-# 2.63 / 10.51 at 2, 2.52 / 10.08 at 4, 2.54 / 10.16 at 8.
+# 2.63 / 10.51 at 2, 2.52 / 10.08 at 4, 2.54 / 10.16 at 8. A count they do
+# not divide takes its largest divisor under them: 15 heads of 96 / 192
+# under ONE decay a head run 5 forward and 3 backward, `kda_fwd` 3.20 at 3
+# heads, 3.07 at 5, 3.02 at 15; `kda_bwd` 5.26 at 1, 4.05 at 3, 3.92 at 5
+# (which asks for more VMEM than the limit below: 0.13 ms a call is not
+# worth moving solar's and kimi's settings for).
 _FWD_HEADS = 8
 _BWD_HEADS = 4
 
@@ -422,7 +533,8 @@ def _params(vmem_limit_bytes: int):
 # What a call may take of VMEM. XLA keeps that much free ACROSS the call, so
 # a limit the body does not need evicts what its neighbours hold there
 # (ops/state_space.py's finding). Compiled for the described v5e the
-# forward's body takes 9.3 MB at 8 heads a step and the backward's 8.7 at 4.
+# forward's body takes 9.3 MB at 8 heads a step and the backward's 8.7 at 4
+# (8.4 at 5 heads of 96 / 192 padded and 8.7 at 3 under a decay a head).
 _FWD_PARAMS = _params(16 << 20)
 _BWD_PARAMS = _params(12 << 20)
 
@@ -431,28 +543,32 @@ _BWD_PARAMS = _params(12 << 20)
 def _make_kda_fn(chunk: int, interpret: bool):
     """kda_fwd with kda_bwd as its backward, on [heads, tokens, width]
     operands of whole chunks and whole lane tiles; beta [heads, chunks, 1,
-    chunk]. The residuals are the five inputs, the chunks' states and
-    their kept matrices (`_packed`)."""
+    chunk]; the log-decay a channel [heads, tokens, dk] or, ONE a head, the
+    cumulative log-decay inside each chunk as rows like beta's [heads,
+    chunks, 1, chunk] (the kernels read which off its rank). The residuals
+    are the five inputs, the chunks' states and their kept matrices
+    (`_packed`)."""
 
-    def specs(most, heads, dk, dv, order):
+    def specs(most, heads, dk, dv, order, a):
         h = max(d for d in range(1, most + 1) if heads % d == 0)
         wide = lambda d: pl.BlockSpec((h, chunk, d),
                                       lambda i, j: (i, order(j), 0))
         a_chunk = lambda *dims: pl.BlockSpec(
             (h, 1) + dims, lambda i, j: (i, order(j), 0, 0))
-        return (h, wide(dk), wide(dv), a_chunk(1, chunk), a_chunk(dv, dk),
-                a_chunk(chunk, 2 * chunk))
+        row = a_chunk(1, chunk)
+        return (h, wide(dk), wide(dv), wide(dk) if a.ndim == 3 else row, row,
+                a_chunk(dv, dk), a_chunk(chunk, 2 * chunk))
 
     def forward(q, k, v, a, beta):
         heads, tokens, dk = q.shape
         dv, n = v.shape[-1], tokens // chunk
-        h, key, value, row, states, kept = specs(_FWD_HEADS, heads, dk, dv,
-                                                 lambda j: j)
+        h, key, value, decay, row, states, kept = specs(
+            _FWD_HEADS, heads, dk, dv, lambda j: j, a)
         f32 = jnp.float32
         return pl.pallas_call(
             _fwd_kernel,
             grid=(heads // h, n),
-            in_specs=[key, key, value, key, row],
+            in_specs=[key, key, value, decay, row],
             out_specs=[value, states, kept],
             out_shape=[jax.ShapeDtypeStruct(v.shape, q.dtype),
                        jax.ShapeDtypeStruct((heads, n, dv, dk), f32),
@@ -480,13 +596,13 @@ def _make_kda_fn(chunk: int, interpret: bool):
         q, k, v, a, beta, states_kept, matrices = residuals
         heads, tokens, dk = q.shape
         dv, n = v.shape[-1], tokens // chunk
-        h, key, value, row, states, kept = specs(_BWD_HEADS, heads, dk, dv,
-                                                 lambda j: n - 1 - j)
+        h, key, value, decay, row, states, kept = specs(
+            _BWD_HEADS, heads, dk, dv, lambda j: n - 1 - j, a)
         return tuple(pl.pallas_call(
             _bwd_kernel,
             grid=(heads // h, n),
-            in_specs=[key, key, value, key, row, states, kept, value],
-            out_specs=[key, key, value, key, row],
+            in_specs=[key, key, value, decay, row, states, kept, value],
+            out_specs=[key, key, value, decay, row],
             out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                        for x in (q, k, v, a, beta)],
             scratch_shapes=[pltpu.VMEM((h, dv, dk), jnp.float32)],
@@ -501,11 +617,14 @@ def _make_kda_fn(chunk: int, interpret: bool):
 
 def kda(q, k, v, log_decay, beta, *, chunk: int = 64,
         interpret: Optional[bool] = None):
-    """The gated delta rule with a decay a channel, chunked: see the
-    module's docstring. q, k [B, H, S, dk], v [B, H, S, dv], log_decay
-    [B, H, S, dk], beta [B, H, S] -> o [B, H, S, dv]. S need not be whole
-    chunks (the tail is padded with tokens that leave the state alone), nor
-    the widths whole lane tiles (padded with channels that hold nothing)."""
+    """The gated delta rule, chunked: see the module's docstring. q, k [B,
+    H, S, dk], v [B, H, S, dv], beta [B, H, S] -> o [B, H, S, dv];
+    log_decay [B, H, S, dk], a decay a channel, or [B, H, S, 1], ONE a head
+    and token (Gated DeltaNet's: the kernels then take the cumulative
+    log-decay of each chunk, a number a token, and build the pairs' decays
+    as one matrix of differences). S need not be whole chunks (the tail is
+    padded with tokens that leave the state alone), nor the widths whole
+    lane tiles (padded with channels that hold nothing)."""
     if chunk & (chunk - 1):
         raise ValueError(f"chunk={chunk} is not a power of two")
     if interpret is None:
@@ -522,8 +641,17 @@ def kda(q, k, v, log_decay, beta, *, chunk: int = 64,
             x = jnp.pad(x, pad)
         return x.reshape((b * h,) + x.shape[2:])
     f32 = jnp.float32
-    rows = jnp.pad(beta.astype(f32), ((0, 0), (0, 0), (0, n * chunk - s)))
+
+    def whole_chunks(x):         # [B, H, S], float32, the tail padded
+        return jnp.pad(x.astype(f32), ((0, 0), (0, 0), (0, n * chunk - s)))
+    rows = whole_chunks(beta)
+    q, k, v = laid_out(q), laid_out(k), laid_out(v)
+    if log_decay.shape[-1] == 1 and dk > 1:
+        # ONE decay a head: each chunk's cumulative log-decay, rows as beta's
+        decay = jnp.cumsum(whole_chunks(log_decay[..., 0]).reshape(
+            b * h, n, 1, chunk), axis=3)
+    else:
+        decay = laid_out(log_decay.astype(f32))
     o = _make_kda_fn(chunk, interpret)(
-        laid_out(q), laid_out(k), laid_out(v), laid_out(log_decay.astype(f32)),
-        rows.reshape(b * h, n, 1, chunk))
+        q, k, v, decay, rows.reshape(b * h, n, 1, chunk))
     return o.reshape(b, h, n * chunk, -1)[:, :, :s, :dv]

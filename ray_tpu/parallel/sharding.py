@@ -125,10 +125,14 @@ _TRANSFORMER = _STAGE_ROWS + (
     # pairs (their down-halves are of rank head_dim: not cut over 'tensor');
     # a shard holds its heads' filters, decay rates and step biases and
     # wo's rows; the norm's one scale of head_dim is every head's
-    (r"kda/(wq|wk|wv|w_beta)", _COLUMN),
+    (r"kda/(wq|wk|wv|w_qkv|w_beta)", _COLUMN),
     (r"kda/(wf|wg)_up", (None, "heads")),
     (r"kda/(wf|wg)_down", ("model", None)),
-    (r"kda/(q|k|v)_conv", ("heads", None)),
+    # (a decay a head and a gate from a full matrix: columns of whole heads,
+    # as are those of the three projections where they are one, `w_qkv`,
+    # a head's q, k and v side by side, under one filter)
+    (r"kda/(w_decay|wg)$", _COLUMN),
+    (r"kda/(q|k|v|qkv)_conv", ("heads", None)),
     (r"kda/(a_log|dt_bias)", ("heads",)),
     (r"kda/o_norm", ()),
     (r"kda/wo", _ROW),

@@ -48,6 +48,7 @@ FILES = {
     "nemotron_h": "test_state_space.py",
     "granite_hybrid": "test_hybrid_mixer_model.py",
     "kimi_linear": "test_kimi_linear_model.py",
+    "olmo_hybrid": "test_olmo_hybrid_model.py",
 }
 
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"

@@ -152,29 +152,38 @@ def _chunks_differentiated(q, k, v, log_decay, beta, *, chunk):
     def cut(x):                  # [B, H, S, d] -> [chunks, B H, chunk, d]
         x = jnp.pad(x.astype(f32), ((0, 0), (0, 0), (0, pad), (0, 0)))
         return jnp.moveaxis(x.reshape(b * h, n, chunk, -1), 1, 0)
-    rows = jnp.pad(beta.astype(f32), ((0, 0), (0, 0), (0, pad)))
-    rows = jnp.moveaxis(rows.reshape(b * h, n, 1, chunk), 1, 0)
+    def rows(x):                 # [B, H, S] -> [chunks, B H, 1, chunk]
+        x = jnp.pad(x.astype(f32), ((0, 0), (0, 0), (0, pad)))
+        return jnp.moveaxis(x.reshape(b * h, n, 1, chunk), 1, 0)
+    # ONE decay a head: the chunk's cumulative log-decay, a row like beta's
+    decay = (jnp.cumsum(rows(log_decay[..., 0]), axis=3)
+             if log_decay.shape[-1] == 1 else cut(log_decay))
 
     def a_chunk(state, xs):
         o, state, _kept = _chunk(*xs, state)
         return state, o
     _, o = jax.lax.scan(
         a_chunk, jnp.zeros((b * h, v.shape[-1], dk), f32),
-        (cut(q), cut(k), cut(v), cut(log_decay), rows))
+        (cut(q), cut(k), cut(v), decay, rows(beta)))
     o = jnp.moveaxis(o, 0, 1).reshape(b, h, n * chunk, -1)
     return o[:, :, :s].astype(q.dtype)
 
 
-@pytest.mark.parametrize("dtype,seq,chunk,decay,beta_top,a_step", [
-    ("float32", 64, 32, 0.05, 2.0, 4),
-    ("bfloat16", 64, 64, 0.05, 1.0, 4),
-    ("float32", 40, 16, 8.0, 2.0, 1),
-    ("bfloat16", 80, 32, 8.0, 2.0, 1),
+@pytest.mark.parametrize("dtype,seq,chunk,decay,beta_top,a_step,a_head", [
+    ("float32", 64, 32, 0.05, 2.0, 4, False),
+    ("bfloat16", 64, 64, 0.05, 1.0, 4, False),
+    ("float32", 40, 16, 8.0, 2.0, 1, False),
+    ("bfloat16", 80, 32, 8.0, 2.0, 1, False),
+    ("float32", 40, 16, 8.0, 2.0, 2, True),
+    ("bfloat16", 80, 32, 0.05, 2.0, 4, True),
 ], ids=["float32_beta_near_2", "bfloat16_four_heads_a_step",
         "float32_underflow_ragged_a_head_a_step",
-        "bfloat16_underflow_ragged_a_head_a_step"])
+        "bfloat16_underflow_ragged_a_head_a_step",
+        "float32_underflow_ragged_a_decay_a_head",
+        "bfloat16_ragged_a_decay_a_head"])
 def test_the_written_transpose_equals_the_chunks_vjp(
-        jax_cpu, monkeypatch, dtype, seq, chunk, decay, beta_top, a_step):
+        jax_cpu, monkeypatch, dtype, seq, chunk, decay, beta_top, a_step,
+        a_head):
     """`kda_bwd`'s body is `_chunk`'s transpose written by hand (PR 66): it
     reads A, Aqk and the inverse the forward made, sends the inverse's
     cotangent back in closed form (dM = -T^T dT T^T), walks the tree once
@@ -185,7 +194,10 @@ def test_the_written_transpose_equals_the_chunks_vjp(
     float32 rounding, those that leave in bfloat16 at one rounding of
     theirs. The cases: q / k / v (and with them the cotangent) float32 or
     bfloat16, beta near 2, a decay past -87 inside a chunk, a ragged tail,
-    four heads a grid step and one."""
+    four heads a grid step and one; and ONE decay a head (the log-decay's
+    last dimension 1: A and Aqk one masked product each under the matrix of
+    the pairs' decays, their transposes two stacked products, and g's
+    gradient a row)."""
     jax = jax_cpu
     import jax.numpy as jnp
     from ray_tpu.ops import linear_attention
@@ -198,6 +210,8 @@ def test_the_written_transpose_equals_the_chunks_vjp(
                                 for x in _delta_inputs(
                                     jax, seq, 32, decay, beta_top, seed=3))
     q, k, v = (x.astype(dtype) for x in (q, k, v))            # four heads
+    if a_head:
+        log_decay = log_decay[..., :1]
     if decay == 8.0:
         assert float(chunk_log_decay(log_decay, chunk).min()) < -87.0
     ct = jax.random.normal(jax.random.PRNGKey(11), (1, 4, seq, 32))
@@ -255,6 +269,115 @@ def test_the_backward_multiplies_the_terms_its_operands_have(jax_cpu, dtype,
     # the forward's, each ONE product at Precision.HIGHEST whatever the
     # types: two a level, the inverse's two at five levels, the tail's six
     assert dots_of(bodies["kda_fwd"]) == 12 + 10 + 6
+
+
+@pytest.mark.parametrize("dtype,heads,dk,dv,seq,decay", [
+    ("float32", 15, 96, 192, 128, 0.05),
+    ("float32", 3, 24, 48, 100, 3.0),
+    ("bfloat16", 6, 96, 192, 80, 0.5),
+], ids=["float32_15_heads_of_96_and_192", "float32_underflow_ragged",
+        "bfloat16_ragged_96_and_192"])
+def test_a_decay_a_head_is_the_decay_broadcast_and_the_recurrence(
+        jax_cpu, dtype, heads, dk, dv, seq, decay):
+    """Gated DeltaNet's layer: a key of 96 and a value of 192 (one lane tile
+    and two, a state of [256, 128] in the kernels), 15 heads (5 a grid step
+    forward and 3 backward: the largest divisors under `_FWD_HEADS` /
+    `_BWD_HEADS`), ONE log-decay a head and token. `kda` handed the decay
+    with a last dimension of 1 runs the chunk for a decay a head; that equals
+    the same decay broadcast to the key's channels through the tree, and the
+    recurrence a token at a time: values and all five gradients (the
+    log-decay's summed over the channels where it was broadcast), float32
+    at float32's rounding, bfloat16 at one rounding of theirs; a decay past
+    -87 inside a chunk, beta to 2 and a ragged tail among the cases."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.linear_attention import (chunk_log_decay, kda,
+                                              kda_reference)
+    f32 = jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+    q, k = (jax.random.normal(key, (1, heads, seq, dk)) for key in keys[:2])
+    q = (q / jnp.linalg.norm(q, axis=-1, keepdims=True) * dk ** -0.5
+         ).astype(dtype)
+    k = (k / jnp.linalg.norm(k, axis=-1, keepdims=True)).astype(dtype)
+    v = jax.random.normal(keys[2], (1, heads, seq, dv)).astype(dtype)
+    a_head = -decay * jax.nn.softplus(
+        jax.random.normal(keys[3], (1, heads, seq, 1)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(keys[4], (1, heads, seq)))
+    ct = jax.random.normal(keys[5], (1, heads, seq, dv))
+    if decay == 3.0:
+        assert float(chunk_log_decay(a_head).min()) < -87.0
+
+    def run(fn, log_decay):
+        def scalar(*a):
+            out = fn(*a)
+            return jnp.sum(out.astype(f32) * ct), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+            q, k, v, log_decay, beta)
+        # a broadcast decay's gradient, summed back to the head's number
+        return out, grads[:3] + (
+            jnp.sum(grads[3], axis=-1, keepdims=True), grads[4])
+    broadcast = jnp.broadcast_to(a_head, q.shape)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q: jnp.sum(kda(
+        q, k, v, a_head, beta).astype(f32))))(q).jaxpr
+    bodies = pallas_calls(jaxpr)
+    assert sorted(bodies) == ["kda_bwd", "kda_fwd"]
+    # the head's form walks no tree: two plain products forward beside the
+    # inverse's ten and the tail's six where the tree makes twelve
+    assert dots_of(bodies["kda_fwd"]) == 2 + 10 + 6
+    out, grads = run(kda, a_head)
+    same, same_grads = run(kda, broadcast)
+    ref, ref_grads = run(kda_reference, broadcast)
+    assert out.shape == (1, heads, seq, dv) and out.dtype == q.dtype
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    for got, want in ((out, same), (out, ref)):
+        np.testing.assert_allclose(got.astype(f32), want.astype(f32),
+                                   atol=tol)
+    for name, g, b, r in zip(("q", "k", "v", "log_decay", "beta"), grads,
+                             same_grads, ref_grads):
+        g, b, r = (np.asarray(x.astype(f32)) for x in (g, b, r))
+        assert np.isfinite(g).all() and np.any(r), name
+        top = tol * max(1.0, np.abs(r).max())
+        np.testing.assert_allclose(g, r, atol=top, err_msg=name)
+        np.testing.assert_allclose(g, b, atol=top, err_msg=name)
+
+
+def test_fifteen_heads_run_five_and_three_a_grid_step(jax_cpu):
+    """`specs` takes the largest divisor of the heads under `_FWD_HEADS` 8 /
+    `_BWD_HEADS` 4: at 15 heads 5 forward and 3 backward, a grid of (3,
+    chunks) and (5, chunks); the decay a head rides in as rows [heads,
+    chunks, 1, chunk] like beta's, its gradient leaves the same way."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.linear_attention import kda
+    shape = lambda *dims, dtype="bfloat16": jax.ShapeDtypeStruct(dims, dtype)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kda(*a, interpret=False).astype(jnp.float32)),
+        argnums=tuple(range(5))))(
+        shape(1, 15, 256, 96), shape(1, 15, 256, 96), shape(1, 15, 256, 192),
+        shape(1, 15, 256, 1, dtype="float32"),
+        shape(1, 15, 256, dtype="float32")).jaxpr
+    grids = {}
+
+    def walk(jaxpr):
+        from jax._src import core
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids[eqn.params["name"]] = (
+                    eqn.params["grid_mapping"].grid,
+                    [tuple(v.aval.shape) for v in eqn.invars],
+                    [tuple(v.aval.shape) for v in eqn.outvars])
+            for sub in core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jaxpr)
+    grid, ins, outs = grids["kda_fwd"]
+    assert grid == (3, 4)
+    assert ins == [(15, 256, 128), (15, 256, 128), (15, 256, 256),
+                   (15, 4, 1, 64), (15, 4, 1, 64)]
+    # o, the chunks' states [256, 128] and their kept matrices
+    assert outs == [(15, 256, 256), (15, 4, 256, 128), (15, 4, 64, 128)]
+    grid, ins, outs = grids["kda_bwd"]
+    assert grid == (5, 4) and outs[3] == outs[4] == (15, 4, 1, 64)
 
 
 def test_a_negative_eigenvalue_flips_what_the_state_holds(jax_cpu):
@@ -404,6 +527,44 @@ def test_delta_rule_compiles_at_8192_positions_of_128(v5e, heads):
     states = heads * 128 * 128 * 128 * 4
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert states // 2 <= temporaries < 1.1 * (states + states // 2)
+
+
+def test_a_heads_decay_compiles_at_8192_positions_of_96_and_192(v5e):
+    """The two kernels at a delta-rule layer of olmohybrid_train_1chip, [1,
+    15, 8192, 96 / 192] under ONE decay a head: 5 heads a grid step forward
+    and 3 backward compile inside the VMEM limits solar's and kimi's shapes
+    ask for (their settings are not this shape's to move), one Mosaic call
+    each and no XLA loop beside them; the temporaries are the chunks' kept
+    states at [256, 128] (the widths padded to lane tiles: 252 MB), their
+    matrices (63 MB) and the padded operands."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops import linear_attention
+    from ray_tpu.ops.linear_attention import kda
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(v5e[0]))
+    args = (shape((1, 15, 8192, 96)), shape((1, 15, 8192, 96)),
+            shape((1, 15, 8192, 192)), shape((1, 15, 8192, 1), jnp.float32),
+            shape((1, 15, 8192), jnp.float32))
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(kda(*a, interpret=False).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    lines = [line for line in text.splitlines()
+             if re.search(r"%\S*kda_(?:fwd|bwd)\S* = .*custom-call\(", line)]
+    assert len(lines) == 2 and text.count("tpu_custom_call") == 2
+    assert " while(" not in text
+    asked, taken = zip(*(map(int, re.findall(r'"size":"(\d+)"', line)[:2])
+                         for line in lines))
+    assert list(asked) == [linear_attention._FWD_PARAMS.vmem_limit_bytes,
+                           linear_attention._BWD_PARAMS.vmem_limit_bytes]
+    assert all(t < a for t, a in zip(taken, asked)), (taken, asked)
+    states = 15 * 128 * 256 * 128 * 4
+    assert states <= compiled.memory_analysis().temp_size_in_bytes \
+        < 2.5 * states
 
 
 def test_delta_rule_layer_compiles_on_four_chip_mesh(v5e, monkeypatch):

@@ -108,6 +108,16 @@ LOWERED = {
     # no table is built), the first layer a `kda` mixer over the dense MLP,
     # both products of every MLP kept through the remat
     "kimilinear_train_1chip": "fd6f12d5a5c9dedc",
+    # new with PR 67, which leaves the twelve above alone (their lines are
+    # the parent's: a configuration without `GPTConfig.delta` and
+    # `norm_after` traces the block and the delta rule as it did): three
+    # `kda` layers of 15 heads at key 96 / value 192 under ONE decay a head
+    # (the kernels take the chunks' cumulative log-decay as rows, like
+    # beta's), one projection and one filter over [q | k | v], a full layer
+    # of 15 heads of 128 through `rope_split` without a table under a q/k
+    # norm of 1920 columns, the norm after each half, `up x` of every MLP
+    # kept through the remat
+    "olmohybrid_train_1chip": "485e120577e83a31",
 }
 
 

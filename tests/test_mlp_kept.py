@@ -35,6 +35,8 @@ CELLS = {
     # the dense MLP of 9216 under the first delta-rule layer and four
     # shared experts of 1024: 0.37 GB for both products of all five
     "kimilinear_train_1chip": (2, 5),
+    # four MLPs of 11008 beside 12.26 GB of state: `up x` alone is 0.72 GB
+    "olmohybrid_train_1chip": (1, 4),
 }
 
 
@@ -210,6 +212,7 @@ def test_what_a_layer_keeps_is_what_the_checkpoint_saves(jax_cpu):
     kinds = set()
     for name in ("tiny", "tiny-granite-hybrid", "tiny-kanana", "tiny-keye",
                  "tiny-kimi-linear", "tiny-laguna", "tiny-lfm2",
+                 "tiny-olmo-hybrid",
                  "tiny-nemotron-h", "tiny-solar"):
         config = read("benchmark", "rehearsal", "configs", name + ".json")
         cfg = model.family(config)._train_config(config) if hasattr(

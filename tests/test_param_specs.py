@@ -1,6 +1,6 @@
 """How a transformer's parameters are divided over the mesh, leaf for leaf:
-every parameter of the benchmark's architectures (the nine rehearsal
-configurations hold every parameter name of the nine cells) under `tp` and
+every parameter of the benchmark's architectures (the ten rehearsal
+configurations hold every parameter name of the ten cells) under `tp` and
 `tp_fsdp` on fsdp=2 x tensor=2, and the dense one, stacked, under `pp` and
 `pp_tp`. The expectations were recorded at PR 42, before
 parallel/sharding.py's rule lists became one table (a delta-rule layer's
@@ -53,6 +53,11 @@ GSPMD = {
     "layers/*/kda/wq": COLUMN, "layers/*/kda/wk": COLUMN,
     "layers/*/kda/wv": COLUMN, "layers/*/kda/w_beta": COLUMN,
     "layers/*/kda/wo": ROW,
+    # (a layer under GPTConfig.delta: one projection and one filter for a
+    # head's [q | k | v], a decay a head, a gate from a full matrix)
+    "layers/*/kda/w_qkv": COLUMN, "layers/*/kda/wg": COLUMN,
+    "layers/*/kda/w_decay": COLUMN,
+    "layers/*/kda/qkv_conv": ((T, None), (T, None)),
     "layers/*/kda/wf_up": ((None, T), (None, T)),
     "layers/*/kda/wg_up": ((None, T), (None, T)),
     "layers/*/kda/wf_down": ((None, None), (F, None)),
@@ -104,7 +109,8 @@ EXPECTED = {"tp": (GSPMD, 0, NO_ROW), "tp_fsdp": (GSPMD, 1, NO_ROW),
 CASES = [(name, strategy)
          for name in ("tiny", "tiny-olmoe", "tiny-kanana", "tiny-lfm2",
                       "tiny-laguna", "tiny-keye", "tiny-solar",
-                      "tiny-smallthinker", "tiny-kimi-linear")
+                      "tiny-smallthinker", "tiny-kimi-linear",
+                      "tiny-olmo-hybrid")
          for strategy in ("tp", "tp_fsdp")] + [("tiny", "pp"),
                                                ("tiny", "pp_tp")]
 
