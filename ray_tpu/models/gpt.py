@@ -59,7 +59,8 @@ from ray_tpu.ops.attention import (FLASH_LSE, FLASH_OUT, LANES,
                                    mha_reference, qk_padding, ring_attention,
                                    tokens_first)
 from ray_tpu.ops.embedding import embed_lookup
-from ray_tpu.ops.linear_attention import KDA_OUT, chunk_log_decay, kda
+from ray_tpu.ops.linear_attention import (KDA_OUT, by_token, chunk_log_decay,
+                                            kda)
 from ray_tpu.ops.rope import (RopeSpec, as_spec, halves_apart, latent_split,
                               rope_frequencies, rope_split, rope_table)
 from ray_tpu.ops.short_conv import short_conv, silu_conv
@@ -795,7 +796,7 @@ def _rmsnorm(x, scale, eps, psum=_whole):
         return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
-def _head_rmsnorm(y, scale, eps, dim: int = 0):
+def _head_rmsnorm(y, scale, eps, dim: int = 0, exact: bool = False):
     """RMSNorm over each head's columns of y [B, S, heads * D], scale [D]
     shared by the heads (or, with dim = D given, a scale a column, [heads *
     D]: a state-space layer's norm a group), with the columns left where
@@ -805,15 +806,20 @@ def _head_rmsnorm(y, scale, eps, dim: int = 0):
     back to the columns: two thin matmuls, lane dense.
     One bf16 pass is enough for the squares (64 roundings of 2^-9 average
     out far below the result's own rounding); the way back takes three, so
-    that a head's factor reaches its columns to 2^-16."""
+    that a head's factor reaches its columns to 2^-16. exact: both at full
+    precision, float32 sums of float32 squares and every bit of the factor
+    (a delta-rule layer's output norm, whose sums were a float32 reduction
+    over a [.., heads, D] view)."""
+    squares, back = ((jax.lax.Precision.HIGHEST,) * 2 if exact else
+                     (jax.lax.Precision.DEFAULT, jax.lax.Precision.HIGH))
     with jax.named_scope("norm"):
         width, dim = y.shape[-1], dim or scale.shape[0]
         member = head_columns(width, dim)
         y32 = y.astype(jnp.float32)
         mean_sq = jnp.einsum("bsw,wh->bsh", y32 * y32, member,
-                             precision=jax.lax.Precision.DEFAULT) / dim
+                             precision=squares) / dim
         factor = jnp.einsum("bsh,wh->bsw", jax.lax.rsqrt(mean_sq + eps),
-                            member, precision=jax.lax.Precision.HIGH)
+                            member, precision=back)
         return (y32 * factor
                 * jnp.tile(scale, width // scale.shape[0])).astype(y.dtype)
 
@@ -1334,20 +1340,51 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
     the norms and the state work on a head's own columns, so
     column-parallel projections and a row-parallel wo leave them local to a
     shard of 'tensor'. Scope `kda` holds the layer; `kda_core`, nested, the
-    delta rule alone."""
+    delta rule alone.
+
+    Which layout the layer's tensors have between the filter and `wo` is
+    read off the widths (ops/linear_attention.py:by_token). At heads of
+    whole lane tiles (128 / 128) q, k, v, the log-decay, o and their
+    gradients stay [B, S, H w], as the projections wrote them and `wo` reads
+    them: `kda`'s block maps place a head, a head's sum of squares (the unit
+    norm of q and k, `o_norm`) is a product with the 0/1 membership matrix
+    and so is the factor's way back (`head_columns`; both at full precision:
+    float32 sums of float32 squares, as the reduction over a [.., H, w] view
+    was), and beta and ONE decay a head are [B, S, H]. No [.., H, w] view
+    exists, which on the chip is a relayout pass a tensor and direction.
+    At any other width (96 / 192 inside one [q | k | v] filter) the tensors
+    are turned by head after the filter, [B, H, S, w], padded inside `kda`,
+    and o is turned back under the gated norm."""
     dt, f32 = cfg.dtype, jnp.float32
     b, s, _ = x.shape
     size = cfg.delta_rule
+    dk, dv = size.key_dim, size.value_dim
+    stay = by_token(dk, dv)     # heads of whole lane tiles: by token all along
     columns = ("batch", None, "heads")
     conv = _per_shard(silu_conv, where.mesh, (columns, ("heads", None)),
                       columns)
 
-    def heads(y, width):                 # [B, S, H * w] -> [B, H, S, w]
-        return y.reshape(b, s, -1, width).transpose(0, 2, 1, 3)
+    def placed(y, width):
+        """A tensor of the heads' columns where `kda` takes it: [B, S, H w]
+        as it is, or by head, [B, H, S, w]."""
+        return y if stay else y.reshape(b, s, -1, width).transpose(0, 2, 1, 3)
+
+    def a_row(y):
+        """A number a head and token, [B, S, H]: as it is, or [B, H, S]."""
+        return y if stay else y.transpose(0, 2, 1)
 
     def unit(y):
         y = y.astype(f32)
-        return y * jax.lax.rsqrt(jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
+        if not stay:
+            return y * jax.lax.rsqrt(
+                jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
+        # float32 sums of float32 squares, and every bit of a head's factor
+        # back on its columns: two thin matmuls at full precision
+        member = head_columns(y.shape[-1], dk)
+        exact = jax.lax.Precision.HIGHEST
+        norm = jax.lax.rsqrt(jnp.einsum("bsw,wh->bsh", y * y, member,
+                                        precision=exact) + 1e-6)
+        return y * jnp.einsum("bsh,wh->bsw", norm, member, precision=exact)
 
     def low_rank(down, up):
         return jnp.einsum(
@@ -1361,42 +1398,56 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
     with jax.named_scope("kda"):
         if "w_qkv" in m:
             # one projection, one filter; a head's columns are [q | k | v]
-            qkv = heads(conv(jnp.einsum("bsd,de->bse", x,
-                                        m["w_qkv"].astype(dt)),
-                             m["qkv_conv"]), 2 * size.key_dim + size.value_dim)
-            q, k, v = (qkv[..., :size.key_dim],
-                       qkv[..., size.key_dim:2 * size.key_dim],
-                       qkv[..., 2 * size.key_dim:])
+            qkv = conv(jnp.einsum("bsd,de->bse", x, m["w_qkv"].astype(dt)),
+                       m["qkv_conv"])
+            if stay:
+                # each part's lane tiles of every head, side by side
+                q, k, v = (jnp.concatenate(
+                    [qkv[..., at + first:at + first + width]
+                     for at in range(0, qkv.shape[-1], 2 * dk + dv)], axis=-1)
+                    for first, width in ((0, dk), (dk, dk), (2 * dk, dv)))
+            else:
+                qkv = placed(qkv, 2 * dk + dv)
+                q, k, v = qkv[..., :dk], qkv[..., dk:2 * dk], qkv[..., 2 * dk:]
         else:
             q, k, v = (
-                heads(conv(jnp.einsum("bsd,de->bse", x, m[w].astype(dt)),
-                           m[taps]), width)
-                for w, taps, width in (("wq", "q_conv", size.key_dim),
-                                       ("wk", "k_conv", size.key_dim),
-                                       ("wv", "v_conv", size.value_dim)))
-        q = (unit(q) * size.key_dim ** -0.5).astype(dt)
+                placed(conv(jnp.einsum("bsd,de->bse", x, m[w].astype(dt)),
+                            m[taps]), width)
+                for w, taps, width in (("wq", "q_conv", dk),
+                                       ("wk", "k_conv", dk),
+                                       ("wv", "v_conv", dv)))
+        q = (unit(q) * dk ** -0.5).astype(dt)
         k = unit(k).astype(dt)
         if "w_decay" in m:
-            # one number a head and token: [B, H, S, 1]
-            log_decay = (-jnp.exp(m["a_log"].astype(f32)) * jax.nn.softplus(
-                a_head("w_decay") + m["dt_bias"])).transpose(0, 2, 1)[..., None]
+            # one number a head and token: [B, S, H], by head [B, H, S, 1]
+            log_decay = a_row(-jnp.exp(m["a_log"].astype(f32))
+                              * jax.nn.softplus(a_head("w_decay")
+                                                + m["dt_bias"]))
+            if not stay:
+                log_decay = log_decay[..., None]
         else:
-            rate = jnp.repeat(jnp.exp(m["a_log"].astype(f32)), size.key_dim)
-            log_decay = heads(-rate * jax.nn.softplus(
-                low_rank("wf_down", "wf_up") + m["dt_bias"]), size.key_dim)
-        beta = jax.nn.sigmoid(a_head("w_beta")).transpose(0, 2, 1)
+            rate = jnp.repeat(jnp.exp(m["a_log"].astype(f32)), dk)
+            log_decay = placed(-rate * jax.nn.softplus(
+                low_rank("wf_down", "wf_up") + m["dt_bias"]), dk)
+        beta = a_row(jax.nn.sigmoid(a_head("w_beta")))
         if cfg.kda_neg_eigval:
             beta = 2.0 * beta
         with jax.named_scope("kda_core"):
             whole_heads = ("batch", "heads", None, None)
-            o = _per_shard(kda, where.mesh,
-                           (whole_heads,) * 4 + (("batch", "heads", None),),
-                           whole_heads)(q, k, v, log_decay, beta)
+            dims = ((columns,) * 5 if stay else
+                    (whole_heads,) * 4 + (("batch", "heads", None),))
+            o = _per_shard(kda, where.mesh, dims,
+                           columns if stay else whole_heads)(
+                q, k, v, log_decay, beta)
         stats = {"kda_log_decay_min": jnp.min(chunk_log_decay(log_decay)),
                  "kda_beta_mean": jnp.mean(beta)}
         # a head is whole wherever its columns are: no psum
-        o = _rmsnorm(o.transpose(0, 2, 1, 3).astype(f32),      # [B, S, H, dv]
-                     m["o_norm"]["scale"], cfg.rmsnorm_eps)
+        if stay:
+            o = _head_rmsnorm(o.astype(f32), m["o_norm"]["scale"],
+                              cfg.rmsnorm_eps, exact=True)
+        else:
+            o = _rmsnorm(o.transpose(0, 2, 1, 3).astype(f32),  # [B, S, H, dv]
+                         m["o_norm"]["scale"], cfg.rmsnorm_eps)
         if "wg" in m:
             gate = jax.nn.silu(jnp.einsum("bsd,de->bse", x, m["wg"].astype(dt),
                                           preferred_element_type=f32))

@@ -11,11 +11,22 @@ alpha_t = exp(log_decay_t) in (0, 1]^dk, beta_t a number (up to 2: the
 transition then has a negative eigenvalue along k_t). The data decays the
 state a channel at a time and overwrites what it holds along k_t.
 
-q, k [B, H, S, dk], v [B, H, S, dv], log_decay [B, H, S, dk] (<= 0, float32;
-[B, H, S, 1] for a decay a head) and beta [B, H, S] in, o [B, H, S, dv] out,
-in q's type; every sum and the state float32. dk and dv need not be alike
-nor whole lane tiles (padded: 96 / 192 run as 128 / 256). The caller
-normalises and scales q and k.
+The operands lie by token, as a layer's projections write them and its
+output projection reads them: q, k [B, S, H dk], v [B, S, H dv], log_decay
+[B, S, H dk] (<= 0, float32; [B, S, H] for a decay a head) and beta [B, S, H]
+in, o [B, S, H dv] out, in q's type; every sum and the state float32. Where a
+head's dk and dv are whole lane tiles (128 / 128) nothing ever turns them by
+head: the kernels' block maps place a grid step's heads among a token's
+columns (`_make_kda_fn`), and a head is a static slice of the block (`_heads`,
+`_place`). The chip tiles [.., H, 128] as 8 heads x 128 lanes of one token and
+[.., H 128] as 8 tokens x 128 lanes, so every [B, H, S, w] view of such a
+tensor was a relayout pass of its own, in float32 where its consumer was
+(PERF.md, PRs 48 and 68). Or they lie by head, q, k [B, H, S, dk], v [B, H, S,
+dv], log_decay [B, H, S, dk] or [B, H, S, 1], beta [B, H, S] in, o [B, H, S,
+dv] out: then dk and dv need not be whole lane tiles (padded: 96 / 192 run as
+128 / 256), and operands by token at such widths are turned by head on the way
+in (`kda`). The same two kernel bodies run both. dk and dv need not be alike.
+The caller normalises and scales q and k.
 
 Two formulations. `kda_reference` is the recurrence as it stands, a token a
 step of a `lax.scan` (the oracle, never the timed path). `kda` is its
@@ -60,9 +71,9 @@ at [1, 15, 8192, 96 / 192]: `kda_fwd` 3.07 and `kda_bwd` 4.05, against 4.58 and
 function of values that live in VMEM (a chunk's q, k, v, g are 32 KB each
 at [64, 128], its A and inverse 16 KB, a state 64 KB). `kda_fwd` runs it
 over a grid (heads / h, chunks), the chunks in order, the state in VMEM
-scratch: a chunk's operands cross HBM once and every intermediate stays on
-the chip. `kda_bwd` runs the same function's transpose, written out
-(`_bwd_kernel`), over the chunks from the last to the first, the state's
+scratch (h neighbouring heads of a batch row a step): a chunk's operands
+cross HBM once and every intermediate stays on the chip. `kda_bwd` runs
+the same function's transpose, written out (`_bwd_kernel`), over the chunks from the last to the first, the state's
 cotangent in scratch. What the transposes read of the forward it READS:
 the state a chunk started from ([heads, chunks, dv, dk] float32, 67 MB a
 layer at [1, 8, 8192, 128]) and the chunk's A, Aqk and inverse T, which the
@@ -139,15 +150,18 @@ def kda_reference(q, k, v, log_decay, beta):
 
 
 def chunk_log_decay(log_decay, chunk: int = 64):
-    """log_decay [B, H, S, dk] -> the cumulative log-decay inside each chunk
-    [B, H, S / chunk (rounded up), chunk, dk], float32: g of the module's
-    docstring. Its smallest value is what the chunked form's range depends
-    on (where it passes float32's -87 a decay underflows to an exact 0)."""
-    b, h, s, dk = log_decay.shape
+    """log_decay [.., S, w] (by token [B, S, H dk] or [B, S, H]; by head [B,
+    H, S, dk] or [B, H, S, 1]: the tokens are the last dimension but one
+    either way) -> the cumulative log-decay inside each chunk [.., S / chunk
+    (rounded up), chunk, w], float32: g of the module's docstring. Its
+    smallest value is what the chunked form's range depends on (where it
+    passes float32's -87 a decay underflows to an exact 0)."""
+    *lead, s, w = log_decay.shape
     pad = -s % chunk
     a = jnp.pad(log_decay.astype(jnp.float32),
-                ((0, 0), (0, 0), (0, pad), (0, 0)))
-    return jnp.cumsum(a.reshape(b, h, (s + pad) // chunk, chunk, dk), axis=3)
+                ((0, 0),) * len(lead) + ((0, pad), (0, 0)))
+    return jnp.cumsum(a.reshape(*lead, (s + pad) // chunk, chunk, w),
+                      axis=len(lead) + 1)
 
 
 def _dot(x, y, contract):
@@ -345,24 +359,47 @@ def _unpacked(kept):
             jnp.where(t < s, left, 0.0) + (t == s).astype(jnp.float32))
 
 
+def _heads(ref, h: int):
+    """A grid step's block of q, k, v, the decay a channel or dO as `_chunk`
+    takes it, [h, C, w]: the block itself where the operands lie by head,
+    [h, C, w]; each head's columns, a static slice of whole lane tiles, of a
+    block [1, C, h w] of the operands as the projections wrote them."""
+    if ref.shape[0] == h:
+        return ref[...]
+    w = ref.shape[2] // h
+    return jnp.stack([ref[0, :, i * w:(i + 1) * w] for i in range(h)])
+
+
+def _place(ref, x):
+    """x [h, C, w] into the step's block of o or of a gradient: `_heads`'
+    way back, in the block's type."""
+    h, _, w = x.shape
+    if ref.shape[0] == h:
+        ref[...] = x.astype(ref.dtype)
+        return
+    for i in range(h):
+        ref[0, :, i * w:(i + 1) * w] = x[i].astype(ref.dtype)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, o_ref, states_ref,
                 kept_ref, state):
     """Grid (heads / h, chunks), the chunks in order: `state` [h, dv, dk]
     carries each head's state; states_ref keeps what a chunk started from
-    and kept_ref its A, Aqk and inverse (`_packed`) for the backward."""
-    f32 = jnp.float32
+    and kept_ref its A, Aqk and inverse (`_packed`) for the backward. The
+    wide blocks are by head or a token's columns (`_heads`, `_place`)."""
+    f32, h = jnp.float32, state.shape[0]
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
     states_ref[:, 0] = state[...]
     # a decay a head is a row a chunk, as beta is
-    a = a_ref[:, 0] if len(a_ref.shape) == 4 else a_ref[...]
+    a = a_ref[:, 0] if len(a_ref.shape) == 4 else _heads(a_ref, h)
     o, state[...], kept = _chunk(
-        q_ref[...].astype(f32), k_ref[...].astype(f32),
-        v_ref[...].astype(f32), a, beta_ref[:, 0], state[...],
+        _heads(q_ref, h).astype(f32), _heads(k_ref, h).astype(f32),
+        _heads(v_ref, h).astype(f32), a, beta_ref[:, 0], state[...],
         exact=1 if q_ref.dtype == jnp.bfloat16 else 3)
-    o_ref[...] = o.astype(o_ref.dtype)
+    _place(o_ref, o)
     kept_ref[:, 0] = _packed(*kept)
 
 
@@ -380,14 +417,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, kept_ref,
     cotangents [dA ; dAqk]. Every product is `_product`'s, with the terms
     its operands HAVE: dO and v that arrive as bfloat16 are one term (beta
     rides on T's columns, not on v), whatever is float32 by nature (a
-    decayed q or k, the states, T, every other cotangent) three."""
-    f32, bf16 = jnp.float32, jnp.bfloat16
+    decayed q or k, the states, T, every other cotangent) three. The wide
+    blocks are by head or a token's columns, as the forward's."""
+    f32, bf16, h = jnp.float32, jnp.bfloat16, dstate.shape[0]
     of_v, of_do = (1 if ref.dtype == bf16 else 3 for ref in (v_ref, do_ref))
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
-    q, k, v, do = (ref[...].astype(f32)
+    q, k, v, do = (_heads(ref, h).astype(f32)
                    for ref in (q_ref, k_ref, v_ref, do_ref))
     state, handed = states_ref[:, 0], dstate[...]
     akk, aqk, inverse_t = _unpacked(kept_ref[:, 0])
@@ -403,8 +441,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, kept_ref,
         between, decayed, ending, fade = _a_heads_decays(a_ref[:, 0])
     else:
         # e^F and e^B of every level, as `_chunk` makes them
-        since, until, whole = (a_ref[...], jnp.zeros(q.shape, f32),
-                               a_ref[...])
+        a = _heads(a_ref, h)
+        since, until, whole = a, jnp.zeros(q.shape, f32), a
         from_start, to_end = [], []
         for level in range(levels):
             from_start.append(jnp.exp(since))
@@ -442,7 +480,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, kept_ref,
              + jnp.sum(drk * kbar, axis=2, keepdims=True))
     dbeta_ref[:, 0] = jnp.sum(jnp.where(eye, dbeta, 0.0), axis=1,
                               keepdims=True)
-    dv_ref[...] = (beta * drv).astype(dv_ref.dtype)
+    _place(dv_ref, beta * drv)
     dakk, dkbar = beta * dm, beta * drk
     if a_head:
         # A = (K K^T) D and Aqk = (Q K^T) D under the pairs' decays D, the
@@ -454,10 +492,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, kept_ref,
         dplain = _product(both, k, (2, 1), (3, of_k))         # [h, 2 C, dk]
         dcols = _product(both, jnp.concatenate([k, q], axis=1), (1, 1),
                          (3, of_k))                           # [h, C, dk]
-        dq_ref[...] = (dqbar * decayed
-                       + dplain[:, chunk:]).astype(dq_ref.dtype)
-        dk_ref[...] = (dkbar * decayed + dktilde * ending + dplain[:, :chunk]
-                       + dcols).astype(dk_ref.dtype)
+        _place(dq_ref, dqbar * decayed + dplain[:, chunk:])
+        _place(dk_ref, dkbar * decayed + dktilde * ending + dplain[:, :chunk]
+               + dcols)
         # g's: a column from e^{g_t} (Qbar, Kbar), e^{g_C - g_t} (Ktilde)
         # and the rows of D's cotangent, less a row from its columns; the
         # last token's from e^{g_C} (Ktilde, the state's fade)
@@ -502,10 +539,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, a_ref, beta_ref, states_ref, kept_ref,
         product = dscaled * scaled
         dsince = dsince + product[:, :chunk] + product[:, chunk:]
         duntil = duntil + dcols * cols
-    dq_ref[...] = dq.astype(dq_ref.dtype)
-    dk_ref[...] = dk.astype(dk_ref.dtype)
+    _place(dq_ref, dq)
+    _place(dk_ref, dk)
     # F_1 = T_1 = a; B_1 = 0 is no function of it
-    da_ref[...] = dsince + dwhole
+    _place(da_ref, dsince + dwhole)
 
 
 # Heads a grid step, at most: their products are batched, and the chains of
@@ -541,29 +578,44 @@ _BWD_PARAMS = _params(12 << 20)
 
 @functools.lru_cache(maxsize=None)
 def _make_kda_fn(chunk: int, interpret: bool):
-    """kda_fwd with kda_bwd as its backward, on [heads, tokens, width]
-    operands of whole chunks and whole lane tiles; beta [heads, chunks, 1,
-    chunk]; the log-decay a channel [heads, tokens, dk] or, ONE a head, the
-    cumulative log-decay inside each chunk as rows like beta's [heads,
-    chunks, 1, chunk] (the kernels read which off its rank). The residuals
-    are the five inputs, the chunks' states and their kept matrices
-    (`_packed`)."""
+    """kda_fwd with kda_bwd as its backward, on operands of whole chunks
+    and whole lane tiles. The wide ones (q, k, v, the log-decay a channel,
+    o, and dO and the gradients backward) lie by token, [rows, tokens,
+    heads a row x width], a row's heads side by side as the projections
+    wrote them, or by head, [heads, tokens, width]; beta is [heads, chunks,
+    1, chunk] either way, and how many times its heads are q's rows says
+    which (by head: once). ONE decay a head is the cumulative log-decay
+    inside each chunk as rows like beta's (the kernels read which off its
+    rank). A grid step's h heads are h neighbours of one row's columns, a
+    block [1, chunk, h x width] at (row, chunk, step of the row), or h
+    heads' [h, chunk, width]: the kernels' edges read which off the block
+    (`_heads`). The residuals are the five inputs, the chunks' states and
+    their kept matrices (`_packed`), both [heads, chunks, ..]."""
 
-    def specs(most, heads, dk, dv, order, a):
-        h = max(d for d in range(1, most + 1) if heads % d == 0)
-        wide = lambda d: pl.BlockSpec((h, chunk, d),
-                                      lambda i, j: (i, order(j), 0))
+    def specs(most, q, v, a, beta, order):
+        heads, a_row = beta.shape[0], beta.shape[0] // q.shape[0]
+        dk, dv = q.shape[2] // a_row, v.shape[2] // a_row
+        h = max(d for d in range(1, most + 1)
+                if (heads if a_row == 1 else a_row) % d == 0)
+        if a_row == 1:
+            wide = lambda d: pl.BlockSpec((h, chunk, d),
+                                          lambda i, j: (i, order(j), 0))
+        else:
+            steps = a_row // h
+            wide = lambda d: pl.BlockSpec(
+                (1, chunk, h * d),
+                lambda i, j: (i // steps, order(j), i % steps))
         a_chunk = lambda *dims: pl.BlockSpec(
             (h, 1) + dims, lambda i, j: (i, order(j), 0, 0))
         row = a_chunk(1, chunk)
-        return (h, wide(dk), wide(dv), wide(dk) if a.ndim == 3 else row, row,
-                a_chunk(dv, dk), a_chunk(chunk, 2 * chunk))
+        return (h, heads, dk, dv, wide(dk), wide(dv),
+                wide(dk) if a.ndim == 3 else row, row, a_chunk(dv, dk),
+                a_chunk(chunk, 2 * chunk))
 
     def forward(q, k, v, a, beta):
-        heads, tokens, dk = q.shape
-        dv, n = v.shape[-1], tokens // chunk
-        h, key, value, decay, row, states, kept = specs(
-            _FWD_HEADS, heads, dk, dv, lambda j: j, a)
+        n = q.shape[1] // chunk
+        h, heads, dk, dv, key, value, decay, row, states, kept = specs(
+            _FWD_HEADS, q, v, a, beta, lambda j: j)
         f32 = jnp.float32
         return pl.pallas_call(
             _fwd_kernel,
@@ -594,10 +646,9 @@ def _make_kda_fn(chunk: int, interpret: bool):
 
     def bwd(residuals, g):
         q, k, v, a, beta, states_kept, matrices = residuals
-        heads, tokens, dk = q.shape
-        dv, n = v.shape[-1], tokens // chunk
-        h, key, value, decay, row, states, kept = specs(
-            _BWD_HEADS, heads, dk, dv, lambda j: n - 1 - j, a)
+        n = q.shape[1] // chunk
+        h, heads, dk, dv, key, value, decay, row, states, kept = specs(
+            _BWD_HEADS, q, v, a, beta, lambda j: n - 1 - j)
         return tuple(pl.pallas_call(
             _bwd_kernel,
             grid=(heads // h, n),
@@ -615,43 +666,77 @@ def _make_kda_fn(chunk: int, interpret: bool):
     return f
 
 
+def by_token(dk: int, dv: int) -> bool:
+    """Whether `kda` runs operands that lie by token as they are: a head's
+    columns of q, k and v are whole lane tiles, so a grid step's heads are a
+    block of a token's columns that the block maps place. At any other
+    width (96 / 192) the operands go by head, padded to whole tiles."""
+    return dk % LANES == 0 and dv % LANES == 0
+
+
 def kda(q, k, v, log_decay, beta, *, chunk: int = 64,
         interpret: Optional[bool] = None):
-    """The gated delta rule, chunked: see the module's docstring. q, k [B,
-    H, S, dk], v [B, H, S, dv], beta [B, H, S] -> o [B, H, S, dv];
-    log_decay [B, H, S, dk], a decay a channel, or [B, H, S, 1], ONE a head
-    and token (Gated DeltaNet's: the kernels then take the cumulative
-    log-decay of each chunk, a number a token, and build the pairs' decays
-    as one matrix of differences). S need not be whole chunks (the tail is
-    padded with tokens that leave the state alone), nor the widths whole
-    lane tiles (padded with channels that hold nothing)."""
+    """The gated delta rule, chunked: see the module's docstring. The
+    operands lie by token, as a layer's projections write them and its
+    output projection reads them: q, k [B, S, H dk], v [B, S, H dv], beta
+    [B, S, H] -> o [B, S, H dv]; log_decay [B, S, H dk], a decay a channel,
+    or [B, S, H], ONE a head and token (Gated DeltaNet's: the kernels then
+    take the cumulative log-decay of each chunk, a number a token, and build
+    the pairs' decays as one matrix of differences). At heads of whole lane
+    tiles (`by_token`) nothing turns them: the kernels' block maps place a
+    grid step's heads among a token's columns. Or they lie by head: q, k [B,
+    H, S, dk], v [B, H, S, dv], beta [B, H, S] -> o [B, H, S, dv]; log_decay
+    [B, H, S, dk] or [B, H, S, 1]; the widths then need not be whole lane
+    tiles (padded with channels that hold nothing), and operands by token at
+    such widths are turned by head here, and o back. S need not be whole
+    chunks (the tail is padded with tokens that leave the state alone)."""
     if chunk & (chunk - 1):
         raise ValueError(f"chunk={chunk} is not a power of two")
     if interpret is None:
         interpret = attention._default_interpret()
-    b, h, s, dk = q.shape
-    dv = v.shape[-1]
+    columns = q.ndim == 3
+    if columns:
+        b, s, h = beta.shape
+        dk, dv = q.shape[-1] // h, v.shape[-1] // h
+        if not by_token(dk, dv):
+            turned = lambda x: x.reshape(b, s, h, -1).transpose(0, 2, 1, 3)
+            o = kda(turned(q), turned(k), turned(v), turned(log_decay),
+                    beta.transpose(0, 2, 1), chunk=chunk, interpret=interpret)
+            return o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
+        a_head = log_decay.shape[-1] == h
+    else:
+        b, h, s, dk = q.shape
+        dv = v.shape[-1]
+        a_head = log_decay.shape[-1] == 1 and dk > 1
     n = -(-s // chunk)
-
-    def laid_out(x):
-        # [B, H, S, d] -> [B * H, whole chunks, whole lane tiles]. A padded
-        # token has k = 0, beta = 0, no decay: the state passes it
-        pad = ((0, 0), (0, 0), (0, n * chunk - s), (0, -x.shape[3] % LANES))
-        if any(p for _, p in pad):
-            x = jnp.pad(x, pad)
-        return x.reshape((b * h,) + x.shape[2:])
     f32 = jnp.float32
 
-    def whole_chunks(x):         # [B, H, S], float32, the tail padded
+    def laid_out(x):
+        # whole chunks, and by head [B, H, S, d] -> [B * H, S, whole lane
+        # tiles]. A padded token has k = 0, beta = 0, no decay: the state
+        # passes it
+        lanes = 0 if columns else -x.shape[3] % LANES
+        pad = ((0, 0),) * (x.ndim - 2) + ((0, n * chunk - s), (0, lanes))
+        if any(p for _, p in pad):
+            x = jnp.pad(x, pad)
+        return x if columns else x.reshape((b * h,) + x.shape[2:])
+
+    def whole_chunks(x):
+        # a number a head and token -> [B, H, S], float32, the tail padded
+        if columns:
+            x = x.transpose(0, 2, 1)
         return jnp.pad(x.astype(f32), ((0, 0), (0, 0), (0, n * chunk - s)))
     rows = whole_chunks(beta)
     q, k, v = laid_out(q), laid_out(k), laid_out(v)
-    if log_decay.shape[-1] == 1 and dk > 1:
+    if a_head:
         # ONE decay a head: each chunk's cumulative log-decay, rows as beta's
-        decay = jnp.cumsum(whole_chunks(log_decay[..., 0]).reshape(
-            b * h, n, 1, chunk), axis=3)
+        decay = jnp.cumsum(whole_chunks(
+            log_decay if columns else log_decay[..., 0]).reshape(
+                b * h, n, 1, chunk), axis=3)
     else:
         decay = laid_out(log_decay.astype(f32))
     o = _make_kda_fn(chunk, interpret)(
         q, k, v, decay, rows.reshape(b * h, n, 1, chunk))
+    if columns:
+        return o[:, :s]
     return o.reshape(b, h, n * chunk, -1)[:, :, :s, :dv]

@@ -341,6 +341,46 @@ def test_cell_step_compiles_under_the_chips_memory(cell_step, family, cell):
         assert len(made) == 2 * layers, made
 
 
+def test_cell_step_keeps_the_delta_rule_by_token(cell_step, family, cell):
+    """A cell whose delta-rule heads are whole lane tiles (solar 8 x 128 /
+    128, kimi 32 x 128 / 128): under scope `kda` the compiled step holds no
+    tensor by head, forward, recomputed or backward: no instruction of the
+    entry computation makes a [B, S, H, w], [B, H, S, w] or [B H, S, w]
+    result there, nothing the size of q is written by a `copy` or a
+    `transpose` (the parent wrote 25 a layer, float32 at the norms and the
+    decay), and `kda_fwd` / `kda_bwd` take q, k, v, the decay a channel and
+    dO, and write o and the four wide gradients, as [B, S, H w]: what the
+    filters wrote and `wo` reads. (How often each kernel is called is
+    `test_cell_step_compiles_under_the_chips_memory`'s, from the family's
+    `cell_kernel_calls`.)"""
+    import math
+    from ray_tpu.ops.linear_attention import by_token
+    assert cell == family.cell
+    cfg = cell_configuration(family.cell)
+    size, heads = cfg.delta_rule, cfg.n_heads
+    assert by_token(size.key_dim, size.value_dim)
+    rows, seq = cell_step.mix["global_batch"], cell_step.mix["seq"]
+    text = cell_step.text
+    entry = [line for line in text[text.index("\nENTRY "):].splitlines()
+             if re.search(r'op_name="[^"]*[/(]kda[/)]', line)]
+    assert len(entry) > 100 * len(kernel_ops(text, "kda_fwd"))
+    by_head = "|".join(
+        rf"\[{rows},{seq},{heads},{w}\]|\[{rows},{heads},{seq},{w}\]"
+        rf"|\[{rows * heads},{seq},{w}\]"
+        for w in {size.key_dim, size.value_dim})
+    assert not [line for line in entry if re.search(by_head, line)]
+    wide = rows * seq * heads * min(size.key_dim, size.value_dim)
+    for line in entry:
+        made = re.match(r"\s*%(\S+) = \(?\w+\[([\d,]+)\]", line)
+        if made and re.search("copy|transpose", made.group(1)):
+            assert math.prod(map(int, made.group(2).split(","))) < wide, line
+    by_token_q = f"bf16[{rows},{seq},{heads * size.key_dim}]"
+    by_token_v = f"bf16[{rows},{seq},{heads * size.value_dim}]"
+    for call in kernel_ops(text, "kda_fwd") + kernel_ops(text, "kda_bwd"):
+        # q, k, v in and o out at the least
+        assert call.count(by_token_q) + call.count(by_token_v) >= 4, call
+
+
 def fused_computation(text, instruction):
     """The body of the computation that the fusion `instruction` (a line of
     the compiled text) calls."""

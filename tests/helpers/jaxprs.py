@@ -37,3 +37,20 @@ def pallas_calls(jaxpr) -> dict:
         for sub in core.jaxprs_in_params(eqn.params):
             found.update(pallas_calls(sub))
     return found
+
+
+def pallas_operands(jaxpr, kernel):
+    """(grid, the operands' shapes, the results' shapes) of the pallas_call
+    named `kernel` in a jaxpr, its sub-jaxprs searched; None if none is."""
+    from jax._src import core
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == kernel):
+            return (eqn.params["grid_mapping"].grid,
+                    [tuple(v.aval.shape) for v in eqn.invars],
+                    [tuple(v.aval.shape) for v in eqn.outvars])
+        for sub in core.jaxprs_in_params(eqn.params):
+            found = pallas_operands(sub, kernel)
+            if found:
+                return found
+    return None

@@ -7,6 +7,7 @@ latent kernels' tests/test_latent_moe.py's."""
 
 from helpers.described_chip import (  # noqa: F401 — fixtures and checks
     cell_step, test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_keeps_the_delta_rule_by_token,
     test_cell_step_makes_a_heads_dw_where_its_logits_are, v5e)
 from helpers.families import family  # noqa: F401
 from test_kimi_linear_model import FAMILY  # noqa: F401

@@ -14,7 +14,8 @@ import pytest
 from helpers.described_chip import (  # noqa: F401 — fixtures
     cell_step, layer_on_four_chips, v5e)
 from helpers.families import family  # noqa: F401
-from helpers.jaxprs import dots_of, pallas_calls, passes_of
+from helpers.jaxprs import (dots_of, pallas_calls, pallas_operands,
+                            passes_of)
 from test_linear_attention_model import FAMILY  # noqa: F401
 
 
@@ -357,27 +358,99 @@ def test_fifteen_heads_run_five_and_three_a_grid_step(jax_cpu):
         shape(1, 15, 256, 96), shape(1, 15, 256, 96), shape(1, 15, 256, 192),
         shape(1, 15, 256, 1, dtype="float32"),
         shape(1, 15, 256, dtype="float32")).jaxpr
-    grids = {}
-
-    def walk(jaxpr):
-        from jax._src import core
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                grids[eqn.params["name"]] = (
-                    eqn.params["grid_mapping"].grid,
-                    [tuple(v.aval.shape) for v in eqn.invars],
-                    [tuple(v.aval.shape) for v in eqn.outvars])
-            for sub in core.jaxprs_in_params(eqn.params):
-                walk(sub)
-    walk(jaxpr)
-    grid, ins, outs = grids["kda_fwd"]
+    grid, ins, outs = pallas_operands(jaxpr, "kda_fwd")
     assert grid == (3, 4)
     assert ins == [(15, 256, 128), (15, 256, 128), (15, 256, 256),
                    (15, 4, 1, 64), (15, 4, 1, 64)]
     # o, the chunks' states [256, 128] and their kept matrices
     assert outs == [(15, 256, 256), (15, 4, 256, 128), (15, 4, 64, 128)]
-    grid, ins, outs = grids["kda_bwd"]
+    grid, ins, outs = pallas_operands(jaxpr, "kda_bwd")
     assert grid == (5, 4) and outs[3] == outs[4] == (15, 4, 1, 64)
+
+
+@pytest.mark.parametrize("batch,heads,seq,dk,dv,a_head,dtype,steps", [
+    (1, 8, 64, 128, 128, False, "bfloat16", (1, 2)),
+    (1, 4, 64, 128, 128, False, "float32", (1, 1)),
+    (2, 4, 64, 128, 128, True, "bfloat16", (2, 2)),
+    (2, 2, 100, 128, 256, False, "bfloat16", (2, 2)),
+    (1, 3, 64, 96, 192, True, "bfloat16", None),
+], ids=["a_full_forward_group", "four_heads", "a_decay_a_head",
+        "a_ragged_tail_of_two_rows", "96_192_goes_by_head"])
+def test_operands_by_token_are_the_by_head_call_bit_for_bit(
+        jax_cpu, batch, heads, seq, dk, dv, a_head, dtype, steps):
+    """`kda` on q, k, v and the log-decay as a layer's projections write
+    them, [B, S, H w], beta and ONE decay a head [B, S, H], against the same
+    numbers turned by head, [B, H, S, w]: o and all five gradients have the
+    same bits. At heads of whole lane tiles nothing is turned: the kernels
+    take the operands as they are and a grid step's heads (8 forward, 4
+    backward, or every head of a row that has fewer) are a block of a
+    token's columns, `steps` = (forward, backward) grid rows; at 96 / 192
+    the operands are turned by head and padded, the call the by-head one.
+
+    Both programs are compiled with LLVM's optimisations off: XLA's CPU
+    backend contracts a product and a sum into one fused multiply-add
+    wherever its own fusion puts the two in one loop, which the shape of
+    the block that is written decides (the log-decay's gradient at a decay
+    a channel differed in its last bit on every other token), and at level
+    0 nothing is contracted: what is compared is the program's sums."""
+    jax = jax_cpu
+    import jax.numpy as jnp
+    from ray_tpu.ops.linear_attention import by_token, kda
+    keys = jax.random.split(jax.random.PRNGKey(heads * seq + dv), 6)
+
+    def unit(x):
+        x = x.reshape(batch, seq, heads, dk)
+        return (x / jnp.linalg.norm(x, axis=-1, keepdims=True)).reshape(
+            batch, seq, heads * dk)
+    q = (unit(jax.random.normal(keys[0], (batch, seq, heads * dk)))
+         * dk ** -0.5).astype(dtype)
+    k = unit(jax.random.normal(keys[1], (batch, seq, heads * dk))).astype(
+        dtype)
+    v = jax.random.normal(keys[2], (batch, seq, heads * dv)).astype(dtype)
+    log_decay = -0.1 * jax.nn.softplus(jax.random.normal(
+        keys[3], (batch, seq, heads if a_head else heads * dk)))
+    beta = 2.0 * jax.nn.sigmoid(
+        jax.random.normal(keys[4], (batch, seq, heads)) + 1.0)
+    ct = jax.random.normal(keys[5], (batch, seq, heads * dv)).astype(dtype)
+    args = (q, k, v, log_decay, beta)
+
+    def turned(x):
+        return x.reshape(batch, seq, heads, -1).transpose(0, 2, 1, 3)
+
+    def by_head(q, k, v, log_decay, beta):
+        o = kda(turned(q), turned(k), turned(v), turned(log_decay),
+                beta.transpose(0, 2, 1))
+        return o.transpose(0, 2, 1, 3).reshape(batch, seq, heads * dv)
+
+    def run(fn):
+        def all_of(*a):
+            o, vjp = jax.vjp(fn, *a)
+            return (o,) + vjp(ct)
+        traced = jax.jit(all_of).trace(*args)
+        return traced.jaxpr.jaxpr, traced.lower().compile(
+            compiler_options={"xla_backend_optimization_level": 0})(*args)
+    names = ("o", "q", "k", "v", "log_decay", "beta")
+    (jaxpr, got), (_, want) = run(kda), run(by_head)
+    for name, mine, theirs in zip(names, got, want):
+        assert mine.shape == theirs.shape and mine.dtype == theirs.dtype, name
+        assert np.any(np.asarray(theirs, np.float32)), name
+        np.testing.assert_array_equal(
+            np.asarray(mine, np.float32), np.asarray(theirs, np.float32),
+            err_msg=name)
+    # which operands the kernels were handed
+    chunks = -(-seq // 64)
+    for kernel, at in (("kda_fwd", 0), ("kda_bwd", 1)):
+        grid, ins, _ = pallas_operands(jaxpr, kernel)
+        if by_token(dk, dv):
+            assert grid == (steps[at], chunks), (kernel, grid)
+            assert ins[:3] == [(batch, chunks * 64, heads * dk)] * 2 + [
+                (batch, chunks * 64, heads * dv)], (kernel, ins)
+            assert ins[3] == ((batch * heads, chunks, 1, 64) if a_head
+                              else (batch, chunks * 64, heads * dk))
+        else:
+            assert ins[:3] == [(batch * heads, chunks * 64, 128)] * 2 + [
+                (batch * heads, chunks * 64, 256)], (kernel, ins)
+        assert ins[4] == (batch * heads, chunks, 1, 64)
 
 
 def test_a_negative_eigenvalue_flips_what_the_state_holds(jax_cpu):
@@ -590,8 +663,8 @@ def test_delta_rule_layer_compiles_on_four_chip_mesh(v5e, monkeypatch):
         layer, x).compile().as_text()
     calls = re.findall(r"%(\S*kda_(?:fwd|bwd)\S*) = .*custom-call\(", text)
     assert len(calls) == 2, calls
-    # a shard's own slice: a batch row of four heads
-    assert re.search(r"kda_fwd\S* = .*bf16\[4,2048,128\]", text)
+    # a shard's own slice: a batch row, four heads' columns of a token
+    assert re.search(r"kda_fwd\S* = .*bf16\[1,2048,512\]", text)
     assert "conv_silu_fwd" in text and "all-reduce" in text
 
 # Imported last: a module's names are collected in the order they are bound,
@@ -599,4 +672,5 @@ def test_delta_rule_layer_compiles_on_four_chip_mesh(v5e, monkeypatch):
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
     test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_keeps_the_delta_rule_by_token,
     test_cell_step_makes_a_heads_dw_where_its_logits_are)

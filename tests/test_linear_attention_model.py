@@ -446,6 +446,65 @@ def test_the_delta_rule_kernels_least_times_at_the_cell():
     assert delta_rule.kda_fwd(cell, mix)[1] < kda.delta_rule(cell, mix)[1]
 
 
+@pytest.mark.parametrize("delta", [
+    None, ("head", "silu")], ids=["three_projections_a_decay_a_channel",
+                                  "one_qkv_filter_a_decay_a_head"])
+def test_a_layer_by_token_is_the_layer_by_head(jax_cpu, tiny, monkeypatch,
+                                               delta):
+    """At heads of whole lane tiles (128 / 128) a delta-rule layer keeps q,
+    k, v, the log-decay and o [B, S, H w] from the filter to `wo` and its
+    norms sum a head's squares through `head_columns` at full precision
+    (`_kda_block`; the widths decide, ops/linear_attention.py:by_token): it
+    is the layer that turns them by head, as every width did before and 96 /
+    192 still do, to float32 rounding (the sums' order), value, statistics
+    and the gradients of every parameter and of x. Both forms of layer: the
+    three projections with a decay a channel and the sigmoid gate pair
+    (solar, kimi), and ONE [q | k | v] projection and filter, whose parts
+    are cut out as lane tiles, with a decay a head and the SiLU gate."""
+    jax = jax_cpu
+    import dataclasses
+    import jax.numpy as jnp
+    from benchmark.families import solar
+    from ray_tpu.models import gpt
+    cfg = dataclasses.replace(
+        gpt.GPTConfig(**solar.gpt_config_kwargs(tiny), dtype=jnp.float32),
+        d_model=64, n_heads=2, head_dim=128,
+        delta=delta and gpt.DeltaRule(128, 128, *delta))
+    kinds = list(cfg.layer_kinds)
+    layer = gpt.gpt_init(jax.random.PRNGKey(3), cfg)["layers"][
+        kinds.index("kda")]["kda"]
+    assert ("w_qkv" in layer) == (delta is not None)
+    layer["o_norm"]["scale"] = 1.0 + 0.3 * jax.random.normal(
+        jax.random.PRNGKey(5), (128,))
+    x = jax.random.normal(jax.random.PRNGKey(9), (1, 80, 64), jnp.float32)
+    weight = jnp.cos(0.37 * jnp.arange(80 * 64).reshape(80, 64))
+
+    def run():
+        def scalar(layer, x):
+            out, stats = gpt._kda_block(layer, x, cfg, gpt.Setting())
+            return jnp.sum(out * weight), (out, stats)
+        text = str(jax.make_jaxpr(scalar)(layer, x))
+        (_, (out, stats)), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1), has_aux=True))(layer, x)
+        return out, stats, grads, text
+    with jax.default_matmul_precision("highest"):
+        out, stats, grads, text = run()
+        monkeypatch.setattr(gpt, "by_token", lambda dk, dv: False)
+        want, want_stats, want_grads, by_head = run()
+    # the kernels' operands: a token's columns, or a head's rows
+    assert "bf16" not in text and "f32[1,128,256]" in text
+    assert "f32[2,128,128]" in by_head and "f32[1,128,256]" not in by_head
+    np.testing.assert_allclose(out, want, atol=2e-5 * float(
+        jnp.abs(want).max()))
+    for name in want_stats:
+        np.testing.assert_allclose(stats[name], want_stats[name], rtol=1e-5)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        assert np.any(np.asarray(w)), path
+        np.testing.assert_allclose(
+            g, w, atol=2e-5 * float(jnp.abs(w).max()), err_msg=str(path))
+
+
 def test_the_kind_is_read_off_the_parameters_and_no_table_is_built(jax_cpu,
                                                                    tiny):
     """A layer's mixer is what its parameters hold (`kda` | `attn`), and a

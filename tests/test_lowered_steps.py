@@ -79,7 +79,19 @@ LOWERED = {
     # float32 under the name KDA_OUT, and `kda_bwd` reads it. The kernel's
     # body, now the chunk's transpose written by hand, is held by
     # tests/test_linear_attention.py::test_the_written_transpose_equals_the_chunks_vjp.
-    "solar2_train_1chip": "01d97333f72f6be1",
+    # Both recorded anew by PR 68, which means to change exactly these two,
+    # the cells whose delta-rule heads are whole lane tiles (01d97333f72f6be1
+    # and fd6f12d5a5c9dedc since PR 66 before it): q, k, v, the log-decay, o
+    # and their gradients stay [B, S, H x 128] from the filters to `wo`,
+    # `kda_fwd` / `kda_bwd` take them so (a grid step's heads are a block of
+    # a token's columns), and the unit norm of q and k and `o_norm` sum a
+    # head's squares through `head_columns` at full precision: no transpose
+    # and no [.., H, 128] reshape is left under `kda`. olmohybrid's 96 / 192
+    # keep the by-head operands and the parent's line. The kernels' bodies
+    # (a head is a column slice of the block) are held to the by-head call
+    # bit for bit by
+    # tests/test_linear_attention.py::test_operands_by_token_are_the_by_head_call_bit_for_bit.
+    "solar2_train_1chip": "e24649581d1b74e7",
     # recorded anew by PR 61, which means to change exactly this one, the
     # only cell with `ssm` layers: the scan is two Mosaic calls a layer,
     # `ssd_fwd` / `ssd_bwd` (ops/state_space.py), where it was XLA einsums
@@ -107,7 +119,7 @@ LOWERED = {
     # whose four layout kernels are handed no table (nothing is rotated and
     # no table is built), the first layer a `kda` mixer over the dense MLP,
     # both products of every MLP kept through the remat
-    "kimilinear_train_1chip": "fd6f12d5a5c9dedc",
+    "kimilinear_train_1chip": "93d3b120f5a4eedf",
     # new with PR 67, which leaves the twelve above alone (their lines are
     # the parent's: a configuration without `GPTConfig.delta` and
     # `norm_after` traces the block and the delta rule as it did): three
