@@ -23,7 +23,9 @@ directly comparable; within-process durations are exact.
 from __future__ import annotations
 
 import os
+import sys
 import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 # Ordered stamp names. A phase duration is the gap between two consecutive
@@ -227,6 +229,14 @@ def request_phase_durations(rec: Sequence) -> List[Tuple[str, float]]:
 def new_trace_id() -> str:
     """An id for a trace or a span: 8 random bytes, as util/tracing draws."""
     return os.urandom(8).hex()
+
+
+def stamp() -> Tuple[float, bool]:
+    """An edge of a start-up span: time.time(), and whether jax is loaded in
+    this process then (one lookup). A span that carries both of its edges'
+    answers as `jax_loaded` says whether it paid the process's `import
+    jax`, whichever way that import is reached."""
+    return time.time(), "jax" in sys.modules
 
 
 def span_event(name: str, trace_id: str, start: float, end: float,

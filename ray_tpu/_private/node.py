@@ -16,6 +16,7 @@ import time
 import uuid
 from typing import Dict, Optional
 
+from ray_tpu._private import flightrec
 from ray_tpu._private.config import Config
 from ray_tpu._private.gcs import GcsServer
 from ray_tpu._private.raylet import Raylet
@@ -47,21 +48,21 @@ class HeadNode:
         self._resources = resources
         self._labels = labels
         self._object_store_memory = object_store_memory
-        # phase -> (start, end) on time.time(), stamped by start():
-        # worker_api.init's gauge and runtime:* spans read them
+        # phase -> its two edges, each a flightrec.stamp(), taken by
+        # start(): worker_api.init's gauge and runtime:* spans read them
         self.boot_phases: Dict[str, tuple] = {}
 
     async def start(self, port: int = 0) -> str:
-        t_gcs = time.time()
+        at_gcs = flightrec.stamp()
         gcs_address = await self.gcs.start(port=port)
-        t_raylet = time.time()
+        at_raylet = flightrec.stamp()
         self.raylet = Raylet(
             self.config, gcs_address, self.session_dir,
             resources=self._resources, labels=self._labels, is_head=True,
             object_store_memory=self._object_store_memory, node_name="head")
         await self.raylet.start()
-        self.boot_phases = {"gcs": (t_gcs, t_raylet),
-                            "raylet": (t_raylet, time.time())}
+        self.boot_phases = {"gcs": (at_gcs, at_raylet),
+                            "raylet": (at_raylet, flightrec.stamp())}
         return gcs_address
 
     async def stop(self):

@@ -58,6 +58,11 @@ class _SharedForkServer:
         # a dead zygote can fail over to Popen as a batch.
         self._pending_spawns: List[tuple] = []
         self._base_env: Optional[Dict[str, str]] = None
+        # time.time() when the zygote was asked for and at its `ready`
+        # event: ray_tpu_worker_zygote_ready_seconds, and how much of a
+        # spawn waited for it (waited_in)
+        self.asked_at: Optional[float] = None
+        self.ready_at: Optional[float] = None
 
     @classmethod
     def get(cls) -> "_SharedForkServer":
@@ -73,6 +78,7 @@ class _SharedForkServer:
             return
         self._base_env = dict(env)
         self._starting = True
+        self.asked_at = time.time()
         try:
             self.proc = await asyncio.create_subprocess_exec(
                 sys.executable, "-m", "ray_tpu._private.worker_forkserver",
@@ -148,6 +154,7 @@ class _SharedForkServer:
                 event = msg.get("event")
                 if event == "ready":
                     self.ready = True
+                    self.ready_at = time.time()
                     for cb in self._ready_callbacks:
                         try:
                             cb()
@@ -192,6 +199,26 @@ class _SharedForkServer:
             except Exception:
                 pass
         self.handlers.clear()
+
+    def waited_in(self, start: float, end: float) -> float:
+        """Seconds of [start, end] that lay before the zygote's `ready`
+        event: what a spawn asked for at `start` waited for it (0.0 for one
+        asked for after it, or where it never came)."""
+        if self.ready_at is None:
+            return 0.0
+        return max(0.0, min(self.ready_at, end) - start)
+
+    def record_ready(self) -> None:
+        """How long this process's zygote took, into the registry of the
+        raylet's process: every raylet that joins it says so again."""
+        from ray_tpu.util import metrics
+        metrics.Gauge(
+            "ray_tpu_worker_zygote_ready_seconds",
+            "the worker fork server (zygote) of this process asked for -> "
+            "its `ready` event: its interpreter up and its imports (jax "
+            "among them) done; a cold spawn asked for meanwhile waits for "
+            "it (actor:spawn's zygote_wait_s)").set(
+                self.ready_at - self.asked_at)
 
     def on_ready(self, cb):
         if self.ready:
@@ -940,7 +967,7 @@ class Raylet:
         return shapes
 
     def _record_span(self, trace_id: str, name: str, start: float,
-                     end: float):
+                     end: float, **extra):
         """Launch-path flight-recorder span (actor:spawn / actor:register
         / actor:ctor): buffered here, flushed to the GCS task-event ring
         by the heartbeat loop so `ray_tpu timeline` shows, on this node's
@@ -952,7 +979,8 @@ class Raylet:
             "kind": "span", "trace_id": trace_id,
             "span_id": os.urandom(8).hex(), "parent_id": "",
             "name": name, "task_id": trace_id,
-            "start": start, "end": end, "node_id": self.node_id.hex()})
+            "start": start, "end": end, "node_id": self.node_id.hex(),
+            **extra})
 
     async def _flush_spans(self):
         if not self._pending_spans:
@@ -1053,6 +1081,7 @@ class Raylet:
         await fs.ensure_started(self._worker_env_for(WorkerID.from_random()))
         if not fs.dead and not self._stopped:
             fs.on_ready(self._prestart_workers)
+            fs.on_ready(fs.record_ready)
 
     def _on_forkserver_event(self, event: str, msg: dict):
         if event == "spawned":
@@ -2356,7 +2385,10 @@ class Raylet:
             "how long an actor create waited for its worker "
             "(Mode=warm: pool hit; Mode=cold: process boot)",
             tag_keys=("Mode",)).observe(t_worker - t0, tags={"Mode": mode})
-        self._record_span(trace, "actor:spawn", t0, t_worker)
+        self._record_span(
+            trace, "actor:spawn", t0, t_worker,
+            zygote_wait_s=(_SharedForkServer.get().waited_in(t0, t_worker)
+                           if mode == "cold" else 0.0))
         if result_fut is None:
             # Warm pool hit / idle rescue: lease here and dispatch the
             # constructor over the worker's RPC server.

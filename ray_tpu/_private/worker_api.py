@@ -9,11 +9,13 @@ from __future__ import annotations
 import asyncio
 import atexit
 import logging
+import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
 from ray_tpu import exceptions as exc
+from ray_tpu._private import flightrec
 from ray_tpu._private.config import Config, set_config
 from ray_tpu._private.core_worker import CoreWorker
 from ray_tpu._private.node import HeadNode, detect_node_resources
@@ -38,6 +40,9 @@ class _GlobalState:
         # Ray-client mode (init(address="ray_tpu://...")): every API call
         # proxies through this context instead of a local CoreWorker.
         self.client = None
+        # flightrec.stamp() at the return of this process's last init():
+        # where BackendExecutor.start's `before` stretch begins
+        self.init_returned: Optional[tuple] = None
 
     def run(self, coro, timeout: Optional[float] = None):
         fut = asyncio.run_coroutine_threadsafe(coro, self.loop)
@@ -114,7 +119,7 @@ def init(address: Optional[str] = None, *,
         if ignore_reinit_error:
             return _state
         raise RuntimeError("ray_tpu already initialized")
-    started = time.time()
+    entered = flightrec.stamp()
     if isinstance(address, str) and (address.startswith("ray_tpu://")
                                      or address.startswith("ray://")):
         # Client mode (reference: ray.init("ray://...")): the process
@@ -134,15 +139,14 @@ def init(address: Optional[str] = None, *,
     if address in (None, "auto"):
         # Job entrypoints / CLI children inherit the cluster address
         # (reference: RAY_ADDRESS handling in ray.init).
-        import os as _os
-        address = _os.environ.get("RAY_TPU_ADDRESS") or None
+        address = os.environ.get("RAY_TPU_ADDRESS") or None
     logging.basicConfig(level=log_level)
     config = Config.load(system_config)
     set_config(config)
     _ensure_loop()
     _state.namespace = namespace
 
-    # phase -> (start, end) on time.time(), stamped at the boundaries below
+    # phase -> its two edges, each a flightrec.stamp() at a boundary below
     phases: Dict[str, tuple] = {}
 
     async def _boot():
@@ -166,7 +170,7 @@ def init(address: Optional[str] = None, *,
             heads = [n for n in alive if n.is_head]
             raylet_address = (heads[0] if heads else alive[0]).address
         from ray_tpu._private import rpc
-        t_connect = time.time()
+        at_connect = flightrec.stamp()
         conn = await rpc.connect(gcs_address)
         job_id = await conn.request("register_job",
                                     {"driver_address": "", "entrypoint": ""})
@@ -174,7 +178,7 @@ def init(address: Optional[str] = None, *,
         core = CoreWorker("driver", gcs_address, raylet_address, config,
                           job_id=job_id)
         await core.start_async()
-        phases["connect"] = (t_connect, time.time())
+        phases["connect"] = (at_connect, flightrec.stamp())
         _state.core = core
         _state.gcs_address = gcs_address
         return gcs_address
@@ -182,24 +186,51 @@ def init(address: Optional[str] = None, *,
     _state.run(_boot(), timeout=60)
     _state.initialized = True
     atexit.register(shutdown)
-    _record_init(started, time.time(), phases)
+    _state.init_returned = flightrec.stamp()
+    _record_init(entered, _state.init_returned, phases)
     return _state
 
 
-_INIT_PHASE_SPANS = {"gcs": "runtime:gcs_start",
+def init_returned() -> Optional[tuple]:
+    """flightrec.stamp() at the return of this process's last init(), None
+    where it called none (a worker, a client)."""
+    return _state.init_returned
+
+
+def _process_start_wall() -> Optional[float]:
+    """When this process started, on time.time()'s clock: its start time
+    from /proc (clock ticks after boot) against the uptime. None where
+    /proc does not say."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return time.time() - (uptime - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+_INIT_PHASE_SPANS = {"before": "runtime:before_init",
+                     "gcs": "runtime:gcs_start",
                      "raylet": "runtime:raylet_start",
                      "connect": "runtime:connect"}
 
 
-def _record_init(start: float, end: float, phases: Dict[str, tuple]) -> None:
-    """init() as the caller saw it: ray_tpu_init_seconds and, by phase,
-    ray_tpu_init_phase_seconds in this process's registry (gcs and raylet
-    read 0.0 where init joined a cluster that was there) and, where tracing
-    is enabled (a driver with tracing off records no span at all), the
-    `runtime:init` flight-recorder span with one child a phase that ran."""
+def _record_init(entered: tuple, returned: tuple,
+                 phases: Dict[str, tuple]) -> None:
+    """init() as the caller saw it, from flightrec.stamp()s at its edges:
+    ray_tpu_init_seconds and, by phase, ray_tpu_init_phase_seconds in this
+    process's registry (gcs and raylet read 0.0 where init joined a cluster
+    that was there; before, the process's start -> init() entered, 0.0
+    where /proc does not say when that was) and, where tracing is enabled (a
+    driver with tracing off records no span at all), the `runtime:init`
+    flight-recorder span with one child a phase that ran and, ahead of it
+    in the same trace, `runtime:before_init`, each with `jax_loaded` at its
+    two edges."""
     try:
-        from ray_tpu._private import flightrec
         from ray_tpu.util import metrics, tracing
+        (start, _), (end, _) = entered, returned
         metrics.Gauge(
             "ray_tpu_init_seconds",
             "wall time of this process's last ray_tpu.init(): head or "
@@ -210,19 +241,29 @@ def _record_init(start: float, end: float, phases: Dict[str, tuple]) -> None:
             "inside ray_tpu_init_seconds: Phase=gcs (GcsServer.start), "
             "Phase=raylet (the head Raylet.start), both 0.0 where init() "
             "joined a running cluster, and Phase=connect (register_job and "
-            "the driver's CoreWorker.start_async)",
+            "the driver's CoreWorker.start_async); ahead of it: "
+            "Phase=before (this process's start, from /proc -> init() "
+            "entered; 0.0 where /proc does not say)",
             tag_keys=("Phase",))
+        born = _process_start_wall()
+        if born is not None:    # a process starts with nothing loaded
+            phases = dict(phases, before=((born, False), entered))
         for phase in _INIT_PHASE_SPANS:
-            a, b = phases.get(phase, (0.0, 0.0))
+            (a, _), (b, _) = phases.get(phase, ((0.0, None), (0.0, None)))
             by_phase.set(b - a, {"Phase": phase})
         if tracing.is_enabled():
-            root = flightrec.span_event("runtime:init",
-                                        flightrec.new_trace_id(), start, end)
+            root = flightrec.span_event(
+                "runtime:init", flightrec.new_trace_id(), start, end,
+                jax_loaded=[entered[1], returned[1]])
             tracing.export_span(root)
-            for phase, (a, b) in phases.items():
+            for phase, ((a, a_loaded), (b, b_loaded)) in phases.items():
+                # `before` lies ahead of runtime:init, so it is a second
+                # root of the trace: the timeline clamps a child into its
+                # parent's slice
                 tracing.export_span(flightrec.span_event(
                     _INIT_PHASE_SPANS[phase], root["trace_id"], a, b,
-                    parent_id=root["span_id"]))
+                    parent_id="" if phase == "before" else root["span_id"],
+                    jax_loaded=[a_loaded, b_loaded]))
     except Exception:  # noqa: BLE001 — observability never blocks init
         logger.debug("init span/metric not recorded", exc_info=True)
 

@@ -18,7 +18,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import cloudpickle
 
-from ray_tpu._private import flightrec
+from ray_tpu._private import compile_cache, flightrec
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.config import ScalingConfig
 from ray_tpu.train.session import TrainContext
@@ -97,6 +97,8 @@ class JaxBackendConfig(BackendConfig):
 # stamped there, rides the message the worker sends anyway, and is folded
 # and exported here.
 _metrics: Optional[dict] = None
+_MAX_PROGRAMS = 16              # ray_tpu_train_program_seconds' names a run
+_program_rows: List[dict] = []  # its rows of the last run, to take away
 
 
 def _metric_handles() -> dict:
@@ -112,12 +114,32 @@ def _metric_handles() -> dict:
                 "Phase=actors (first train-worker actor asked for -> "
                 "every node_info back), Phase=hook (backend.on_start); "
                 "Phase=training (loop shipped, every worker's "
-                "start_run back). In the slowest worker, carried by its "
-                "first message: Phase=first_report (start_run -> the "
-                "loop's first report()) and, inside it, what it compiled: "
-                "Phase=trace, lower, cache_load (loads from the persistent "
-                "cache), compile (backend compiles less those loads)",
+                "start_run back); Phase=before (this process's last "
+                "init() returned -> BackendExecutor.start entered; not set "
+                "where it called none). In the slowest worker, carried by "
+                "its first message: Phase=first_report (start_run -> the "
+                "loop's first report() entered) and, inside it, what it "
+                "compiled: Phase=trace, lower, cache_load (loads from the "
+                "persistent cache), compile (backend compiles less those "
+                "loads), of them Phase=off_thread on other threads than the "
+                "loop's; and the loop's thread between its compiles: "
+                "Phase=head (start_run -> the first compile record), "
+                "between (the gaps from one record to the next), "
+                "first_step (the last record -> the first report()), so "
+                "that first_report = head + trace + lower + cache_load + "
+                "compile - off_thread + between + first_step. A worker "
+                "whose compiles were not watched from start_run sets "
+                "first_report alone",
                 tag_keys=("Phase",)),
+            "program": metrics.Gauge(
+                "ray_tpu_train_program_seconds",
+                "the slowest worker's start-up by program (Program= the "
+                "compile records' fun_name, the 16 largest and the rest "
+                "under `other`): Phase=trace, lower, cache_load, compile, "
+                "their sum Phase=total, and Phase=after (the loop's thread "
+                "from a record of the program to the next record, or to "
+                "the first report() after the last one)",
+                tag_keys=("Program", "Phase")),
             "recompiles": metrics.Counter(
                 "ray_tpu_train_recompiles_total",
                 "programs a train worker compiled after its first "
@@ -165,13 +187,15 @@ class BackendExecutor:
         self.world_size = scaling.num_workers
 
     def start(self):
-        self._started_at = started = time.time()
+        entered = flightrec.stamp()
+        self._started_at = started = entered[0]
         self._save_pushed = False
         # the run's trace: every span of the run carries run_id, and
         # train:run (exported by shutdown) is the root of its tree
         self.run_id = flightrec.new_trace_id()
         self._run_span = flightrec.new_trace_id()
         workers_span = flightrec.new_trace_id()
+        self._record_before(entered)
         self._last_taken: Dict[int, tuple] = {}
         self.worker_group = WorkerGroup(
             self.scaling.num_workers, self.scaling.worker_resources(),
@@ -193,6 +217,23 @@ class BackendExecutor:
         gauge.set(now - started, {"Phase": "workers"})
         self._span("train:start_workers", started, now, self._run_span,
                    span_id=workers_span)
+
+    def _record_before(self, entered: tuple) -> None:
+        """The driver's stretch between the two calls: this process's last
+        init() returned -> start() entered (`entered`, a flightrec.stamp()),
+        as a gauge and, with tracing enabled, a span of the run's trace
+        with `jax_loaded` at its two edges (a root beside train:run, which
+        starts where it ends: the timeline clamps a child into its parent's
+        slice). Nothing where this process called no init()."""
+        from ray_tpu._private import worker_api
+        returned = worker_api.init_returned()
+        if returned is None:
+            return
+        _metric_handles()["start"].set(entered[0] - returned[0],
+                                       {"Phase": "before"})
+        if tracing.is_enabled():
+            self._span("train:before_start", returned[0], entered[0], "",
+                       jax_loaded=[returned[1], entered[1]])
 
     def _span(self, name: str, start: float, end: float, parent_id: str,
               **extra) -> None:
@@ -454,12 +495,13 @@ class BackendExecutor:
         """What the workers compiled since their last message
         (_private/compile_cache.py's records, carried by this round's
         messages): every record a compile:<phase> span on its worker's
-        lane, under `parent`; the first round's are the run's start-up, in the gauge, and
-        any later one is a recompile, in the counter."""
+        lane, under `parent`; the first round's are the run's start-up, in
+        the gauges, and any later one is a recompile, in the counter."""
         handles = _metric_handles()
         for i, out in messages.items():
             pid = self.node_info_per_worker[i].get("pid")
-            for fun_name, phase, start, end, load_s in out.get("compiles", ()):
+            for fun_name, phase, start, end, load_s, _thread in out.get(
+                    "compiles", ()):
                 cache = ({"cache": "miss" if load_s is None else "hit"}
                          if phase == "compile" else {})
                 self._span("compile:" + phase, start, end, parent, pid=pid,
@@ -474,18 +516,69 @@ class BackendExecutor:
         self._first_round = False
         slowest = max(messages.values(),
                       key=lambda out: out.get("first_report_s", -1.0))
-        seconds = dict.fromkeys(("trace", "lower", "cache_load", "compile"),
-                                0.0)
-        for _name, phase, start, end, load_s in slowest.get("compiles", ()):
-            if phase == "compile":
-                seconds["cache_load"] += load_s or 0.0
-                seconds["compile"] += max(0.0, end - start - (load_s or 0.0))
-            else:
-                seconds[phase] += end - start
-        if "first_report_s" in slowest:     # a loop that never reports: none
-            seconds["first_report"] = slowest["first_report_s"]
-        for phase, value in seconds.items():
+        traced = tracing.is_enabled()
+        for i, out in messages.items():
+            # a loop that never reports carries no stamps, and one whose
+            # compiles were not watched from start_run has no partition
+            if not out.get("watched") or not (traced or out is slowest):
+                continue
+            laid = compile_cache.partition(
+                out.get("compiles", ()), out["started_at"],
+                out["started_at"] + out["first_report_s"],
+                out["loop_thread"])
+            if traced:
+                self._export_start_up_spans(
+                    laid.gaps, self.node_info_per_worker[i].get("pid"))
+            if out is slowest:
+                self._set_start_up(laid)
+        if not slowest.get("watched"):
+            self._set_start_up(None)
+        if "first_report_s" in slowest:
+            handles["start"].set(slowest["first_report_s"],
+                                 {"Phase": "first_report"})
+
+    def _export_start_up_spans(self, gaps, pid) -> None:
+        """A worker's lane from start_run to its first report without a
+        hole: what lies between the compile:* spans, under train:run."""
+        for name, start, end, after in gaps:
+            if name != "between":
+                self._span("train:" + name, start, end, self._run_span,
+                           pid=pid)
+            elif end - start >= 0.001:
+                self._span("train:between", start, end, self._run_span,
+                           pid=pid, after=after)
+
+    def _set_start_up(self, laid: Optional[compile_cache.Partition]) -> None:
+        """The slowest worker's start-up into the gauges: its phases and its
+        programs. None (its compiles were not watched from start_run) takes
+        the last run's rows away and sets nothing, so that a reader finds no
+        row where nothing was measured."""
+        from ray_tpu.util import metrics
+        global _program_rows
+        handles = _metric_handles()
+        for tags in _program_rows:
+            metrics.remove("ray_tpu_train_program_seconds", tags)
+        _program_rows = []
+        if laid is None:
+            for phase in compile_cache.STRETCHES:
+                metrics.remove("ray_tpu_train_start_seconds",
+                               {"Phase": phase})
+            return
+        for phase, value in laid.seconds.items():
             handles["start"].set(value, {"Phase": phase})
+        by_size = sorted(laid.programs.items(),
+                         key=lambda item: -sum(item[1].values()))
+        rows: Dict[str, Dict[str, float]] = dict(by_size[:_MAX_PROGRAMS])
+        for _name, own in by_size[_MAX_PROGRAMS:]:
+            other = rows.setdefault("other", dict.fromkeys(own, 0.0))
+            for phase, value in own.items():
+                other[phase] += value
+        for program, own in rows.items():
+            own = dict(own, total=sum(own[p] for p in compile_cache.PHASES))
+            for phase, value in own.items():
+                tags = {"Program": program, "Phase": phase}
+                handles["program"].set(value, tags)
+                _program_rows.append(tags)
 
     def _interrupt(self):
         for w in self.worker_group.workers:

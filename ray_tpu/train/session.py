@@ -95,7 +95,7 @@ class _Session:
     def __init__(self, context: TrainContext,
                  checkpoint: Optional[Checkpoint] = None,
                  datasets: Optional[Dict[str, Any]] = None,
-                 started: Optional[float] = None):
+                 started: Optional[float] = None, watched: bool = False):
         self.context = context
         self.starting_checkpoint = checkpoint
         self.datasets = datasets or {}
@@ -109,9 +109,13 @@ class _Session:
         # the next message too (blocked_s lies inside call_s).
         self._call_s: Optional[float] = None
         self._returned: Optional[float] = None
-        # When TrainWorker.start_run was entered, on this process's
-        # monotonic clock: the first report says how long ago that was.
+        # When TrainWorker.start_run was entered, on time.time() (the clock
+        # compile_cache's records are on), and whether the compile watch was
+        # on from then: the first report carries both, with how long ago
+        # that was and the loop's thread, and the driver lays the records
+        # out between the two stamps (compile_cache.partition).
         self._started = started
+        self._watched = watched
         self._stop = threading.Event()
         # Save-on-preempt: set by TrainWorker.request_save (driver push) or
         # implied by a drain notice for this worker's node; cleared when a
@@ -147,13 +151,21 @@ class _Session:
         # Where jax is loaded the report is a span of the loop's thread in
         # the profiler's own trace, beside the device's ops.
         jax = sys.modules.get("jax")
+        if jax is not None:
+            compile_cache.watch()   # a loop that imported jax itself
         with (jax.profiler.TraceAnnotation("train:report") if jax is not None
               else contextlib.nullcontext()):
             message = {"type": "report", "metrics": _host_value(metrics),
                        "checkpoint": checkpoint,
                        "rank": self.context.world_rank}
             if self._started is not None:
-                message["first_report_s"] = time.monotonic() - self._started
+                # this report's entry on the wall clock: now, less what
+                # perf_counter says has passed since
+                message.update(
+                    started_at=self._started, watched=self._watched,
+                    loop_thread=threading.get_ident(),
+                    first_report_s=time.time() - self._started
+                    - (time.perf_counter() - entered))
                 self._started = None
             self._put(message, entered)
         # Block until consumed: put the *next* item only after the driver

@@ -47,12 +47,12 @@ class TrainWorker:
     def start_run(self, fn_bytes: bytes, config: Optional[dict],
                   context: TrainContext,
                   checkpoint=None, datasets: Optional[dict] = None) -> None:
-        started = time.monotonic()
-        compile_cache.watch()
+        started = time.time()       # the clock the watch's records are on
+        watched = compile_cache.watch()
         compile_cache.drain()       # an earlier run's, or the backend hook's
         fn = cloudpickle.loads(fn_bytes)
         sess = _Session(context, checkpoint=checkpoint, datasets=datasets,
-                        started=started)
+                        started=started, watched=watched)
         self._session = sess
         _session_mod._set_session(sess)
 

@@ -1,11 +1,13 @@
 """_private/compile_cache.py's watch: every compile of a process as records
-(fun_name, phase, start, end, load_s), phase trace | lower | compile, one a
-thread's outermost span, and as compile:<phase> spans of the profiler's own
-trace. All on the CPU."""
+(fun_name, phase, start, end, load_s, thread), phase trace | lower | compile,
+one a thread's outermost span, and as compile:<phase> spans of the profiler's
+own trace; partition() lays a thread's records out between two stamps. All on
+the CPU."""
 
 import glob
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -68,9 +70,10 @@ def test_one_record_a_phase_under_the_outer_name(watched, how):
     records = compile_cache.drain()
     assert [r[1] for r in records] == list(PHASES)
     assert {r[0] for r in records} == {"outer"}
-    for (_n, _p, start, end, _l), (_n2, _p2, later, _e2, _l2) in zip(
+    for (_n, _p, start, end, _l, _t), (_n2, _p2, later, _e2, _l2, _t2) in zip(
             records, records[1:]):
         assert start <= end <= later
+    assert {r[5] for r in records} == {threading.get_ident()}
     assert compile_cache.drain() == []
 
 
@@ -171,6 +174,77 @@ def test_threads_keep_their_own_depth(watched):
     assert not errors and not any(t.is_alive() for t in threads)
     records = compile_cache.drain()
     assert sorted(r[1] for r in records) == sorted(PHASES * 2)
+    assert {r[5] for r in records} == {t.ident for t in threads}
+
+
+def test_a_compile_on_a_second_thread_is_counted_apart(watched):
+    """The loop's thread compiles one program while another thread compiles
+    one too: the four phases hold both, `off_thread` the other thread's
+    seconds, and the loop's thread is still a partition: head + its own
+    records + between + first_step is the whole stretch."""
+    started = time.time()
+    aside = threading.Thread(
+        target=lambda: _nested(watched)(
+            np.ones(17, np.float32)).block_until_ready())
+    aside.start()
+    _nested(watched)(np.ones(19, np.float32)).block_until_ready()
+    aside.join(timeout=60)
+    reported = time.time()
+    records = compile_cache.drain()
+    laid = compile_cache.partition(records, started, reported,
+                                   threading.get_ident())
+    seconds = laid.seconds
+    other = sum(r[3] - r[2] for r in records if r[5] == aside.ident)
+    assert len(records) == 6 and other > 0.0
+    assert seconds["off_thread"] == pytest.approx(other)
+    assert sum(seconds[p] for p in compile_cache.PHASES) == pytest.approx(
+        sum(r[3] - r[2] for r in records))
+    assert (seconds["head"] + seconds["between"] + seconds["first_step"]
+            + sum(seconds[p] for p in compile_cache.PHASES)
+            - seconds["off_thread"]) == pytest.approx(reported - started)
+    assert [name for name, _a, _b, _after in laid.gaps] == [
+        "head", "between", "between", "first_step"]
+
+
+def _record(name, phase, start, end, load_s=None, thread=1):
+    return (name, phase, start, end, load_s, thread)
+
+
+@pytest.mark.parametrize("records,seconds,programs,gaps", [
+    # nothing compiled: the whole stretch is the head
+    ([], {"head": 10.0}, {}, [("head", 100.0, 110.0, None)]),
+    # two programs, the second loaded from the cache: every gap is named,
+    # and is the `after` of the program whose record it follows
+    ([_record("init", "trace", 102.0, 103.0),
+      _record("init", "lower", 103.5, 104.0),
+      _record("init", "compile", 104.0, 105.0),
+      _record("_step", "trace", 106.0, 107.0),
+      _record("_step", "lower", 107.0, 107.5),
+      _record("_step", "compile", 107.5, 109.0, load_s=1.0)],
+     {"head": 2.0, "trace": 2.0, "lower": 1.0, "compile": 1.5,
+      "cache_load": 1.0, "between": 1.5, "first_step": 1.0},
+     {"init": {"trace": 1.0, "lower": 0.5, "compile": 1.0, "after": 1.5},
+      "_step": {"trace": 1.0, "lower": 0.5, "compile": 0.5,
+                "cache_load": 1.0, "after": 1.0}},
+     [("head", 100.0, 102.0, None), ("between", 103.0, 103.5, "init"),
+      ("between", 104.0, 104.0, "init"), ("between", 105.0, 106.0, "init"),
+      ("between", 107.0, 107.0, "_step"), ("between", 107.5, 107.5, "_step"),
+      ("first_step", 109.0, 110.0, "_step")]),
+    # a record of another thread is in the phases and in no gap
+    ([_record("aside", "compile", 101.0, 108.0, thread=2),
+      _record("_step", "compile", 103.0, 104.0)],
+     {"head": 3.0, "compile": 8.0, "off_thread": 7.0, "first_step": 6.0},
+     {"aside": {"compile": 7.0}, "_step": {"compile": 1.0, "after": 6.0}},
+     [("head", 100.0, 103.0, None), ("first_step", 104.0, 110.0, "_step")]),
+])
+def test_partition_names_every_stretch(records, seconds, programs, gaps):
+    laid = compile_cache.partition(records, 100.0, 110.0, thread=1)
+    assert {k: v for k, v in laid.seconds.items() if v} == seconds
+    assert {name: {k: v for k, v in own.items() if v}
+            for name, own in laid.programs.items()} == programs
+    assert laid.gaps == gaps
+    assert (sum(laid.seconds.values()) - 2 * laid.seconds["off_thread"]
+            ) == 10.0
 
 
 def test_a_compile_is_a_span_of_the_profilers_trace(watched, tmp_path):

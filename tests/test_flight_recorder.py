@@ -441,3 +441,32 @@ def test_build_trace_leaves_a_serve_requests_spans_to_its_request():
     trace = flightrec.build_trace([hop, span])
     assert [e["cat"] for e in trace if e.get("span_id") == "s1"] == [
         "serve_span"]
+
+
+def test_a_stamp_is_the_clock_and_whether_jax_is_loaded(monkeypatch):
+    """An edge of a start-up span (runtime:*, train:before_start): when,
+    and one lookup in sys.modules, which imports nothing."""
+    import sys
+    from ray_tpu._private import flightrec
+    monkeypatch.setitem(sys.modules, "jax", object())
+    before = time.time()
+    at, loaded = flightrec.stamp()
+    assert before <= at <= time.time() and loaded is True
+    monkeypatch.delitem(sys.modules, "jax")
+    assert flightrec.stamp()[1] is False and "jax" not in sys.modules
+
+
+@pytest.mark.parametrize("name,extra", [
+    ("runtime:before_init", {"jax_loaded": [False, True]}),
+    ("train:between", {"pid": 200, "after": "_step"}),
+    ("train:head", {"pid": 200})])
+def test_build_trace_keeps_what_a_start_up_span_says(name, extra):
+    """What the start-up spans carry beside their edges is the slice's
+    args: which stretch paid the import, whose gap it is."""
+    from ray_tpu._private import flightrec
+    event = flightrec.span_event(name, "run1", 1.0, 1.5, **extra)
+    drawn, = [e for e in flightrec.build_trace([event])
+              if e["cat"] == "span"]
+    assert drawn["name"] == name and drawn["dur"] == 0.5e6
+    assert drawn["pid"] == str(event["pid"])
+    assert drawn["args"] == {k: v for k, v in extra.items() if k != "pid"}
