@@ -37,6 +37,11 @@ _DEFAULT_TEST_TIMEOUT = float(os.environ.get("RAY_TPU_TEST_TIMEOUT", "180"))
 
 
 def pytest_configure(config):
+    # xdist's loadfile hands the files out in the order collected (see
+    # `heavy_first`) and not, its default since 3.7, those with the most
+    # tests first: a cell's whole step is a file of two or three. Set where
+    # the scheduler lives, in the process that collects nothing.
+    config.option.loadscopereorder = False
     config.addinivalue_line(
         "markers", "timeout(seconds): per-test wall-clock limit "
         f"(default {_DEFAULT_TEST_TIMEOUT:.0f}s)")
@@ -60,6 +65,47 @@ def pytest_configure(config):
                 os.unlink(os.path.join("/dev/shm", name))
             except OSError:
                 pass  # raced with a concurrent reaper / foreign owner
+
+
+# ---------------------------------------------------------------------------
+# The heavy files first. Under `--dist loadfile` a worker is handed the next
+# file when it has two tests left, so the files that start last decide how
+# long the other workers stand idle at the end: they should be the light
+# ones. What makes a file heavy is read off what was collected, so a new
+# family's two files are placed without a list to edit.
+# ---------------------------------------------------------------------------
+
+def heavy_first(files):
+    """files: {file: (whether its module has a FAMILY, the fixtures its tests
+    take)} -> the files in the order they run: the families' files, one that
+    compiles a cell's whole step for the described chip (`cell_step`) and
+    one of the others in turn (the whole steps take ~4 cores each and five
+    of them stand under the 180 s ceiling: taken in a row they would run
+    four at a time), then whatever else compiles for the described chip
+    (`v5e`), then what runs JAX on the CPU (`jax_cpu`), then the rest; by
+    name within each, so that every xdist worker collects one order."""
+    kind = {file: (0 if "cell_step" in fixtures else 1 if has_family
+                   else 2 if "v5e" in fixtures else 3 if "jax_cpu" in fixtures
+                   else 4) for file, (has_family, fixtures) in files.items()}
+    turn = {}       # a family's file -> its place among those of its kind
+    for heavy in (0, 1):
+        turn.update((file, at) for at, file in enumerate(sorted(
+            file for file in files if kind[file] == heavy)))
+    return sorted(files, key=lambda file: (
+        max(kind[file], 1), turn.get(file, 0), kind[file], file))
+
+
+def pytest_collection_modifyitems(items):
+    """Whole files change places; a file's tests keep their order."""
+    def file_of(item):
+        return item.nodeid.split("::", 1)[0]
+    files = {}
+    for item in items:
+        _has_family, fixtures = files.setdefault(file_of(item), (
+            hasattr(getattr(item, "module", None), "FAMILY"), set()))
+        fixtures.update(getattr(item, "fixturenames", ()))
+    place = {file: at for at, file in enumerate(heavy_first(files))}
+    items.sort(key=lambda item: place[file_of(item)])
 
 
 # ---------------------------------------------------------------------------

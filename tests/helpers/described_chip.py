@@ -273,9 +273,37 @@ def cell_step(v5e, family):
     traffic, adamw over fp32 masters) for one described chip: compiled
     once, read by every case of the file that asserts on its text. (A
     described device reports no memory limit, so this is the step that
-    keeps nothing more through the remat; tests/test_mlp_kept.py compiles
-    the two cells with the most to gain as the chip runs them.)"""
-    return CellStep(v5e, family.cell)
+    keeps nothing more through the remat, unless the family's `cell_limit`
+    says the chip's: granite's file compiles the step the chip runs, once,
+    for `a_step_keeps_up_x` too.)"""
+    return CellStep(v5e, family.cell, limit=family.cell_limit)
+
+
+def a_step_keeps_up_x(step, layers):
+    """Of a cell with much to gain, its whole step compiled for one
+    described chip as the chip runs it (CellStep at limit=V5E_BYTES: the
+    builder reads a v5e's limit): `mlp_products_kept` keeps `up x` in every
+    layer's MLP (both products are reckoned over the ceiling); of the gate
+    and up products ONE a layer stands a second time in the entry
+    computation's backward pass (`rematted_computation` in its op_name)
+    where the step that keeps none has two; arguments + temporaries stay
+    under the ceiling the reckoning holds itself to, beneath the reckoned
+    peak; and what is kept is in the temporaries (the step that keeps
+    nothing more holds that much less: 12.42 GB at granite for 13.63)."""
+    from ray_tpu.parallel import memory
+    products, of, kept_bytes, peak, limit = step.kept
+    assert (products, of, limit) == (1, layers, V5E_BYTES)
+    entry = step.text[step.text.index("\nENTRY "):].splitlines()
+    again = [at for at, line in enumerate(entry) if re.search(
+        r'op_name="[^"]*rematted_computation[^"]*/mlp/bsd,df->bsf/'
+        r'dot_general', line)]
+    assert len(again) == layers, again
+    compiled = (step.memory.argument_size_in_bytes
+                + step.memory.temp_size_in_bytes
+                + step.memory.output_size_in_bytes
+                - step.memory.alias_size_in_bytes)
+    assert compiled + memory.OVERHEAD <= peak <= memory.CEILING * limit
+    assert step.memory.temp_size_in_bytes > kept_bytes
 
 
 def test_cell_step_compiles_under_the_chips_memory(cell_step, family, cell):

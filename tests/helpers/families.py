@@ -11,6 +11,22 @@ FAMILY, and tests/conftest.py's `pytest_generate_tests` gives a check its
 cases from `FAMILY.cases`. A new architecture is a row in FILES, its class,
 and its own kernels' tests.
 
+Which programs a family's two files compile, and which check reads which
+(PR 70; a new family brings no other). The model file: the reference's
+logits, loss and gradients (the `reference` fixture: one program), the tiny
+program on one device in float32 once an attention path (`programmed`:
+logits, loss and gradients in one program, read by the float32 check AND, at
+`flash`, by the sharded step as its one-device twin: `flash_twin`, params -
+0.1 gradients, so no one-device step is compiled unless the family's mesh
+needs another configuration than its tiny one), the sharded step, the bf16
+check's one program, the scopes' compile, and ONE rehearsal of the cell in a
+subprocess, traced (`--trace 1`: the untraced run is a subset of it, and
+kanana's own case keeps both). The kernels file: the cell's whole step for
+the described chip, once (`cell_step`, helpers/described_chip.py), at the
+limit the family says (`cell_limit`) and read by every check of that file.
+tests/conftest.py runs the files that take `cell_step` first and the
+families' other files next, by what it collects: no list names them.
+
 pytest does not collect this module (no test_ prefix).
 """
 
@@ -89,7 +105,9 @@ class Family:
     # the program the checks build (`program` below)
     remat = "none"      # None: GPTConfig's own
     tokens_seed = 5
-    attentions = ("reference", "flash")
+    # (`reference`, models/gpt.py's jnp attention held to the same
+    # reference, is no path a cell runs: out of tier-1 since PR 70)
+    attentions = (case("reference", "reference", pytest.mark.slow), "flash")
 
     # test_logits_loss_and_gradients_match_the_reference
     logits_atol = None
@@ -120,11 +138,13 @@ class Family:
 
     # tests/helpers/described_chip.py: the cell's whole step for one
     # described chip ({kernel: calls}, (low, high) share of 16.91 GB, marks
-    # of the case) and a share's sparse block ((rows a tile, tiles of the
-    # bounded row space, tiles for every slot))
+    # of the case, the memory limit its builder is told: None, a described
+    # device's own, or V5E_BYTES, the chip's) and a share's sparse block
+    # ((rows a tile, tiles of the bounded row space, tiles for every slot))
     cell_kernel_calls = None
     cell_memory_share = None
     cell_step_marks = ()
+    cell_limit = None
     row_spaces = None
 
     @property
@@ -227,8 +247,10 @@ class Family:
     def shares_statistics(self, stats):
         pass
 
-    def sharded_step(self, jax, tiny):
-        steps_agree(jax, self, tiny)
+    def sharded_step(self, jax, tiny, twin):
+        """twin: () -> `flash_twin`, called by a family that steps its tiny
+        configuration as it stands."""
+        steps_agree(jax, self, tiny, twin())
 
     def tree(self, jax, config):
         """The shapes of the program's parameters."""
@@ -290,12 +312,46 @@ def reference(jax_cpu, family, tiny, seeded):
     module = family.module
     _cfg, params, tokens = seeded("reference")
     with jax.default_matmul_precision("highest"):
-        logits = jax.jit(lambda p, t: module.reference_logits(
-            p, t[:, :-1], tiny))(params, tokens)
-        loss, grads = jax.jit(jax.value_and_grad(
-            lambda p, t: module.reference_loss(p, t, tiny)))(params, tokens)
+        # one program, as `programmed`'s: the layers' forward compiled once
+        logits, (loss, grads) = jax.jit(lambda p, t: (
+            module.reference_logits(p, t[:, :-1], tiny), jax.value_and_grad(
+                lambda p: module.reference_loss(p, t, tiny))(p)))(
+                    params, tokens)
         more = family.reference_more(jax, params, tokens, tiny)
     return logits, loss, grads, more
+
+
+@pytest.fixture(scope="module")
+def programmed(jax_cpu, seeded):
+    """attention -> (logits, (loss, aux), gradients) of the tiny program on
+    one device, float32 at full matmul precision: compiled once a path, held
+    to the reference below and, at `flash`, the one-device twin that the
+    sharded step is compared with (`flash_twin`)."""
+    jax = jax_cpu
+    from ray_tpu.models.gpt import gpt_forward, gpt_loss_and_aux
+    made = {}
+
+    def of(attention):
+        if attention not in made:
+            cfg, params, tokens = seeded(attention)
+            with jax.default_matmul_precision("highest"):
+                # one program: the layers' forward is compiled once for both
+                made[attention] = jax.jit(lambda p, t: (
+                    gpt_forward(p, t[:, :-1], cfg)[0], jax.value_and_grad(
+                        lambda p: gpt_loss_and_aux(p, {"tokens": t}, cfg),
+                        has_aux=True)(p)))(params, tokens)
+        return made[attention]
+    return of
+
+
+def flash_twin(seeded, programmed):
+    """(params, tokens, loss, gradients) of the flash path's program: what a
+    sharded step starts from and what one float32 SGD step on one device
+    makes of it, params - 0.1 gradients, with no compile of its own
+    (`steps_agree`)."""
+    _cfg, params, tokens = seeded("flash")
+    _logits, ((loss, _aux), grads) = programmed("flash")
+    return params, tokens, loss, grads
 
 
 # ---------------------------------------------------------------------------
@@ -303,21 +359,15 @@ def reference(jax_cpu, family, tiny, seeded):
 # ---------------------------------------------------------------------------
 
 def test_logits_loss_and_gradients_match_the_reference(jax_cpu, family,
-                                                       seeded, reference,
-                                                       attention):
+                                                       seeded, programmed,
+                                                       reference, attention):
     """In float32: every logit, the loss and the whole tree of gradients;
     the family's class says what the tree is (`built`), what the step
     hands back (`statistics`) and which gradients move."""
     jax = jax_cpu
-    from ray_tpu.models.gpt import gpt_forward, gpt_loss_and_aux
-    cfg, params, tokens = seeded(attention)
+    cfg, params, _tokens = seeded(attention)
     family.built(cfg, params)
-    with jax.default_matmul_precision("highest"):
-        # one program: the layers' forward is compiled once for both
-        logits, ((loss, aux), grads) = jax.jit(lambda p, t: (
-            gpt_forward(p, t[:, :-1], cfg)[0], jax.value_and_grad(
-                lambda p: gpt_loss_and_aux(p, {"tokens": t}, cfg),
-                has_aux=True)(p)))(params, tokens)
+    logits, ((loss, aux), grads) = programmed(attention)
     ref_logits, ref_loss, ref_grads = reference[:3]
     np.testing.assert_allclose(logits, ref_logits, atol=family.logits_atol)
     np.testing.assert_allclose(loss, ref_loss, rtol=1e-6)
@@ -332,6 +382,24 @@ def test_logits_loss_and_gradients_match_the_reference(jax_cpu, family,
             g, r, atol=family.grads_atol * max(1.0, float(np.abs(r).max())),
             err_msg=name)
     family.gradients(grads)
+
+
+def test_the_programs_gradient_moves_where_the_references_does(jax_cpu,
+                                                               programmed,
+                                                               reference):
+    """Of the flash path's own gradients, leaf by leaf: a non-zero element
+    exactly where the reference's has one. The float32 check above holds a
+    leaf to the reference's within `grads_atol` x its largest element, so a
+    leaf that the program leaves out of its loss (all zeros: a stop_gradient,
+    a branch not taken) passes there wherever the reference's gradient is
+    smaller than that bound, and `moves` is asked of the reference's tree
+    alone."""
+    jax = jax_cpu
+    _logits, (_loss, grads) = programmed("flash")
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree_util.tree_leaves(reference[2])):
+        assert bool(np.any(np.asarray(g))) == bool(np.any(np.asarray(r))), \
+            jax.tree_util.keystr(path)
 
 
 def test_the_reference_tells_each_mechanism_apart(jax_cpu, family, tiny,
@@ -481,10 +549,17 @@ def test_every_new_leaf_gets_its_rule(jax_cpu, family, tiny, strategy, column,
     family.rules(specs, column, row)
 
 
-def steps_agree(jax, family, config, rows=4, strategy="tp_fsdp", axes=None,
-                atol=1e-6, **fields):
-    """One step (float32, flash, sgd) of `config` on a mesh of `axes` under
-    `strategy` equals the one-device step: the loss and every parameter.
+def steps_agree(jax, family, config, twin=None, rows=4, strategy="tp_fsdp",
+                axes=None, atol=1e-6, **fields):
+    """One step (float32, flash, sgd at 0.1) of `config` on a mesh of `axes`
+    under `strategy` equals the one-device step: the loss and every
+    parameter. twin: `flash_twin`'s (params, tokens, loss, gradients),
+    for a `config` that is the family's tiny one as it stands: the step
+    starts from those parameters and tokens and is held to that loss and to
+    params - 0.1 gradients, which the float32 check holds to the reference.
+    Without it (a family whose mesh takes fewer layers or other heads than
+    its tiny configuration has) the one-device step is compiled here, on
+    `rows` rows of tokens.
     -> the function that takes one step (name, axes, devices), for a caller
     that has more to ask."""
     import jax.numpy as jnp
@@ -496,16 +571,22 @@ def steps_agree(jax, family, config, rows=4, strategy="tp_fsdp", axes=None,
     axes = axes or {"data": 1, "fsdp": 2, "tensor": 2}
     cfg = family.config(config, dtype=jnp.float32, attention="flash",
                         **fields)
-    tokens = jnp.asarray(np.random.default_rng(5).integers(
-        0, 512, (rows, 129), dtype=np.int32))
+    if twin is None:
+        def init():
+            return gpt_init(jax.random.PRNGKey(3), cfg)
+        tokens = jnp.asarray(np.random.default_rng(5).integers(
+            0, 512, (rows, 129), dtype=np.int32))
+    else:
+        start, tokens, ref_loss, grads = twin
+
+        def init():
+            return start
 
     def one_step(name, axes, n):
         mesh = build_mesh(MeshConfig(**axes), devices=jax.devices()[:n])
         strategy = strategy_from_name(name)
         optimizer = optax.sgd(0.1)
-        state = init_train_state(
-            lambda: gpt_init(jax.random.PRNGKey(3), cfg), optimizer, mesh,
-            strategy)
+        state = init_train_state(init, optimizer, mesh, strategy)
         step = make_train_step(
             lambda p, b: gpt_loss(
                 p, b, cfg, mesh=mesh,
@@ -515,9 +596,14 @@ def steps_agree(jax, family, config, rows=4, strategy="tp_fsdp", axes=None,
             state, metrics = step(state, {"tokens": tokens})
         return float(metrics["loss"]), jax.device_get(state.params)
 
-    ref_loss, ref_params = one_step("dp", {"data": 1}, 1)
+    if twin is None:
+        ref_loss, ref_params = one_step("dp", {"data": 1}, 1)
+    else:
+        ref_params = jax.tree_util.tree_map(
+            lambda p, g: np.asarray(p) - np.float32(0.1) * np.asarray(g),
+            start, grads)
     loss, params = one_step(strategy, axes, int(np.prod(list(axes.values()))))
-    assert abs(loss - ref_loss) < 1e-5
+    assert abs(loss - float(ref_loss)) < 1e-5
     for (path, p), r in zip(jax.tree_util.tree_flatten_with_path(params)[0],
                             jax.tree_util.tree_leaves(ref_params)):
         np.testing.assert_allclose(p, r, rtol=1e-4, atol=atol,
@@ -525,11 +611,14 @@ def steps_agree(jax, family, config, rows=4, strategy="tp_fsdp", axes=None,
     return cfg, one_step
 
 
-def test_sharded_step_equals_one_device(jax_cpu, family, tiny):
+def test_sharded_step_equals_one_device(request, jax_cpu, family, tiny):
     """One step of the tiny model on a mesh (`sharded_step` of the family's
     class says which, and what lies on a shard) equals the one-device
-    step."""
-    family.sharded_step(jax_cpu, tiny)
+    step: `flash_twin` where the family asks for it (the file then has
+    `seeded` and `programmed`)."""
+    family.sharded_step(jax_cpu, tiny, lambda: flash_twin(
+        request.getfixturevalue("seeded"),
+        request.getfixturevalue("programmed")))
 
 
 def test_the_configuration_refuses_by_name(family, tiny, refusal):
@@ -636,5 +725,9 @@ def benchmark_command_says(command, says):
 
 @pytest.mark.timeout(600)
 def test_the_cell_rehearses(family):
+    """The cell once, traced: run_cell's traced run goes through all the
+    untraced one does and the trace's readers besides (kanana's own case,
+    tests/test_latent_moe_model.py, keeps both runs)."""
     benchmark_command_says(["benchmark/rehearse.py", family.workload,
-                            "--seconds", "2"], "rehearsal passed")
+                            "--seconds", "2", "--trace", "1"],
+                           "rehearsal passed")

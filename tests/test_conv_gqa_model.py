@@ -14,7 +14,7 @@ import pytest
 # then start in the same minute of a run)
 from helpers.families import test_the_cell_rehearses  # noqa: F401
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
-    Family, case, family, read, reference, seeded,
+    Family, case, family, programmed, read, reference, seeded,
     test_bfloat16_step_passes_the_per_token_check,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
     test_every_new_leaf_gets_its_rule,
@@ -24,6 +24,7 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_pipeline_refuses_by_name, test_sharded_step_equals_one_device,
     test_the_configuration_refuses_by_name,
     test_the_new_scopes_are_regions_and_reach_the_compiled_step,
+    test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart,
     test_the_shares_of_a_layer_add_up_to_the_uncut_reference, tiny)
 
@@ -131,11 +132,11 @@ class Lfm2(Family):
         assert attn["q_head_norm"]["scale"] == attn["k_head_norm"]["scale"] \
             == P(None)
 
-    def sharded_step(self, jax, tiny):
+    def sharded_step(self, jax, tiny, twin):
         """fsdp=2 x tensor=2: a key/value head with its four query heads
         and the channels of B, C, X with their filters on a shard of
         `tensor`, the kernels per shard."""
-        Family.sharded_step(self, jax, tiny)
+        Family.sharded_step(self, jax, tiny, twin)
 
     refusals = [
         case(({"attention": "ring"},

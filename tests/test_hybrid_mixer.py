@@ -6,7 +6,10 @@ tests/test_state_space.py's."""
 
 import re
 
-from helpers.described_chip import cell_step, v5e  # noqa: F401 — fixtures
+import pytest
+
+from helpers.described_chip import (  # noqa: F401 — fixtures
+    a_step_keeps_up_x, cell_step, v5e)
 from helpers.families import family  # noqa: F401
 from test_hybrid_mixer_model import FAMILY  # noqa: F401
 
@@ -66,3 +69,13 @@ def test_scan_compiles_at_8192_positions_of_64_heads_on_one_group(
 from helpers.described_chip import (  # noqa: E402,F401
     test_cell_step_compiles_under_the_chips_memory,
     test_cell_step_makes_a_heads_dw_where_its_logits_are)
+
+
+@pytest.mark.parametrize("name,layers", [("granite-4.0-h-micro", 10)])
+def test_a_step_keeps_up_x_under_the_chips_memory(cell_step,  # noqa: F811
+                                                  name, layers):
+    """The file's one compiled step is the one the chip runs (FAMILY.
+    cell_limit), so it is read here too (until PR 70 a case of
+    tests/test_mlp_kept.py, which compiled the cell a second time)."""
+    assert name == FAMILY.cell
+    a_step_keeps_up_x(cell_step, layers)

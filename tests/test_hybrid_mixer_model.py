@@ -14,9 +14,11 @@ import re
 import numpy as np
 import pytest
 
+from helpers.described_chip import V5E_BYTES
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
-    Family, case, family, read, reference, seeded, step_kernel_calls,
-    steps_agree, test_bfloat16_step_passes_the_per_token_check,
+    Family, case, family, programmed, read, reference, seeded,
+    step_kernel_calls, steps_agree,
+    test_bfloat16_step_passes_the_per_token_check,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
     test_every_new_leaf_gets_its_rule,
     test_logits_loss_and_gradients_match_the_reference,
@@ -25,6 +27,7 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_sharded_step_equals_one_device, test_the_cell_rehearses,
     test_the_configuration_refuses_by_name,
     test_the_new_scopes_are_regions_and_reach_the_compiled_step,
+    test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart, tiny)
 
 
@@ -144,7 +147,7 @@ class GraniteHybrid(Family):
             assert layer["mlp"]["w_down"] == P(*row)
         assert "lm_head" not in specs
 
-    def sharded_step(self, jax, tiny):
+    def sharded_step(self, jax, tiny, twin):
         """A state-space layer with its MLP and an attention layer on
         tensor=2 (16 heads of the one group a shard, a key/value head with
         its two query heads; the tied matrix's two gradients on one leaf)."""
@@ -233,15 +236,20 @@ class GraniteHybrid(Family):
     # filter call over 4352 channels (forward + recomputed, backward); one
     # grouped-query layer of 32 on 8 heads of 64 IN PAIRS without a
     # rotation: q, k and v reach the kernels as projected, no `rope_split`
-    # at all; one lookup. 12.42 GB when this was written: 9.27 of state (12 B
-    # a parameter; the gradient is a temporary) and 3.15 of temporaries,
-    # 73 % of the chip (~50 s alone here).
+    # at all; one lookup. Compiled ONCE, as the chip runs it (`cell_limit`:
+    # the builder reads a v5e's limit and keeps `up x` in all ten MLPs, which
+    # tests/test_hybrid_mixer.py reads off the same step): 13.63 GB, 9.27 of
+    # state (12 B a parameter; the gradient is a temporary) and 4.36 of
+    # temporaries, 81 % of the chip; the step that keeps nothing more, which
+    # this file compiled until PR 70, was 12.42 GB under (0.68, 0.80), and
+    # the bounds moved by the kept product's 0.07 (~50 s alone here).
     cell_kernel_calls = {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
                          "rope_split": 0, "rope_merge": 0, "ssd_fwd": 9,
                          "ssd_bwd": 9, "conv_silu_fwd": 18, "conv_silu_bwd": 9,
                          "embed_grad": 1}
-    cell_memory_share = (0.68, 0.80)
-    cell_step_marks = (pytest.mark.timeout(900),)
+    cell_memory_share = (0.75, 0.87)
+    cell_step_marks = (pytest.mark.timeout(600),)
+    cell_limit = V5E_BYTES
 
 
 FAMILY = GraniteHybrid()

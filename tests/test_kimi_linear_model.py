@@ -11,8 +11,9 @@ import numpy as np
 import pytest
 
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
-    Family, case, family, read, reference, seeded, step_kernel_calls,
-    steps_agree, test_bfloat16_step_passes_the_per_token_check,
+    Family, case, family, programmed, read, reference, seeded,
+    step_kernel_calls, steps_agree,
+    test_bfloat16_step_passes_the_per_token_check,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
     test_every_new_leaf_gets_its_rule,
     test_logits_loss_and_gradients_match_the_reference,
@@ -20,6 +21,7 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_sharded_step_equals_one_device, test_the_cell_rehearses,
     test_the_configuration_refuses_by_name,
     test_the_new_scopes_are_regions_and_reach_the_compiled_step,
+    test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart,
     test_the_shares_of_a_layer_add_up_to_the_uncut_reference, tiny)
 
@@ -193,7 +195,7 @@ class KimiLinear(Family):
         assert moe["w_up"] == P("expert", *column)
         assert moe["router_bias"] == P(None)
 
-    def sharded_step(self, jax, tiny):
+    def sharded_step(self, jax, tiny, twin):
         """A delta-rule layer over the dense MLP and the latent layer over
         experts on tensor=2 (two delta-rule heads with their filters, decay
         rates and step biases, and two latent heads, on a shard of `tensor`;
@@ -267,7 +269,7 @@ class KimiLinear(Family):
                          "conv_silu_fwd": 24, "conv_silu_bwd": 12,
                          "kda_fwd": 4, "kda_bwd": 4}
     cell_memory_share = (0.57, 0.92)
-    cell_step_marks = (pytest.mark.timeout(900),)
+    cell_step_marks = (pytest.mark.timeout(600),)
 
 
 FAMILY = KimiLinear()

@@ -15,15 +15,16 @@ import numpy as np
 import pytest
 
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
-    Family, benchmark_command_says, case, family, read, reference, seeded,
-    test_bfloat16_step_passes_the_per_token_check,
+    Family, benchmark_command_says, case, family, programmed, read, reference,
+    seeded, test_bfloat16_step_passes_the_per_token_check,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
     test_every_new_leaf_gets_its_rule,
     test_logits_loss_and_gradients_match_the_reference,
     test_pipeline_refuses_by_name
     as test_pipeline_refuses_a_layer_pattern_by_name,
     test_sharded_step_equals_one_device,
-    test_the_new_scopes_are_regions_and_reach_the_compiled_step, tiny)
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step,
+    test_the_programs_gradient_moves_where_the_references_does, tiny)
 
 
 class Kanana(Family):
@@ -71,10 +72,10 @@ class Kanana(Family):
         assert moe["shared"]["w_down"] == P(*row)
         assert moe["w_up"] == P("expert", *column)
 
-    def sharded_step(self, jax, tiny):
+    def sharded_step(self, jax, tiny, twin):
         """fsdp=2 x tensor=2: whole heads of wq, w_kvb and wo over
         `tensor`, the kernels per shard."""
-        Family.sharded_step(self, jax, tiny)
+        Family.sharded_step(self, jax, tiny, twin)
 
     pipeline_refusals = [
         case(({"n_layers": 4}, {"pipeline": 2},

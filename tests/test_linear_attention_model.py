@@ -12,8 +12,9 @@ import numpy as np
 import pytest
 
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
-    Family, case, family, read, reference, seeded, step_kernel_calls,
-    steps_agree, test_bfloat16_step_passes_the_per_token_check,
+    Family, case, family, programmed, read, reference, seeded,
+    step_kernel_calls, steps_agree,
+    test_bfloat16_step_passes_the_per_token_check,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
     test_every_new_leaf_gets_its_rule,
     test_logits_loss_and_gradients_match_the_reference,
@@ -169,7 +170,7 @@ class Solar(Family):
         assert kda["a_log"] == kda["dt_bias"] == P("tensor")
         assert kda["o_norm"]["scale"] == P(None)
 
-    def sharded_step(self, jax, tiny):
+    def sharded_step(self, jax, tiny, twin):
         """A grouped-query and a delta-rule layer on tensor=2 (two
         delta-rule heads with their filters, decay rates and step biases,
         and a key/value head with its two query heads and their gates, on a
@@ -251,7 +252,7 @@ class Solar(Family):
                          "moe_tgmm": 24, "embed_grad": 1,
                          "conv_silu_fwd": 18, "conv_silu_bwd": 9}
     cell_memory_share = (0.80, 0.93)
-    cell_step_marks = (pytest.mark.timeout(900),)
+    cell_step_marks = (pytest.mark.timeout(600),)
 
 
 FAMILY = Solar()

@@ -14,8 +14,9 @@ import numpy as np
 import pytest
 
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
-    Family, case, family, read, reference, seeded, step_kernel_calls,
-    steps_agree, test_bfloat16_step_passes_the_per_token_check,
+    Family, case, family, programmed, read, reference, seeded,
+    step_kernel_calls, steps_agree,
+    test_bfloat16_step_passes_the_per_token_check,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
     test_every_new_leaf_gets_its_rule,
     test_logits_loss_and_gradients_match_the_reference,
@@ -23,6 +24,7 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_sharded_step_equals_one_device, test_the_cell_rehearses,
     test_the_configuration_refuses_by_name,
     test_the_new_scopes_are_regions_and_reach_the_compiled_step,
+    test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart, tiny)
 
 
@@ -134,7 +136,7 @@ class OlmoHybrid(Family):
         assert kda["a_log"] == kda["dt_bias"] == P("tensor")
         assert kda["o_norm"]["scale"] == P(None)
 
-    def sharded_step(self, jax, tiny):
+    def sharded_step(self, jax, tiny, twin):
         """A delta-rule layer and the full layer on tensor=2 (a head of each
         with its filters, decay rate and step bias on a shard of `tensor`;
         the q/k norm's mean square and the norm after a mixer over both
@@ -202,7 +204,7 @@ class OlmoHybrid(Family):
                          "conv_silu_fwd": 6, "conv_silu_bwd": 3,
                          "kda_fwd": 3, "kda_bwd": 3}
     cell_memory_share = (0.72, 0.92)
-    cell_step_marks = (pytest.mark.timeout(900),)
+    cell_step_marks = (pytest.mark.timeout(600),)
 
 
 def _delta(**change):

@@ -70,7 +70,7 @@ def _three_ways(jax, heads, kv_heads, seq, dtype, options, blocks=128,
     results = []
     for fn, args in ((reference, (q32, k32, v32)), (per_head, (q, k, v)),
                      (in_pairs, (q, k, v)))[:2 + pairs]:
-        value, grads = jax.value_and_grad(fn, (0, 1, 2))(*args)
+        value, grads = jax.jit(jax.value_and_grad(fn, (0, 1, 2)))(*args)
         results.append((value, *(_columns(x).astype(jnp.float32)
                                  for x in grads)))
     return results

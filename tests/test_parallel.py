@@ -134,8 +134,9 @@ class TestAttention:
                 lambda *a: attn(*a).astype(jnp.float32), *args)
             return (out,) + vjp(w)
 
-        got = run(flash, q, k, v)
-        want = run(reference, *(x.astype(jnp.float32) for x in (q, k, v)))
+        got = jax.jit(lambda *a: run(flash, *a))(q, k, v)
+        want = jax.jit(lambda *a: run(reference, *a))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
         tol_out, tol_grad = FLASH_TOL[dtype]
         for name, tol, a, e in zip(("out", "dq", "dk", "dv"),
                                    (tol_out,) + (tol_grad,) * 3, got, want):

@@ -83,8 +83,10 @@ def test_pp_grads_match_dense(env, axes, qk_norm):
     cfg = case["cfg"]
     pp_params = env["gpt_params_to_pp"](case["params"])
     loss_fn = env["make_gpt_pp_loss"](cfg, mesh, num_microbatches=4)
-    g_pp = jax.grad(loss_fn)(pp_params, case["batch"])
-    g_dense = jax.grad(lambda p, b: gpt_loss(p, b, cfg))(
+    # (each side ONE program: taken op by op, the schedule's shard_map and
+    # the dense backward were some hundred small compiles, 33 s a case)
+    g_pp = jax.jit(jax.grad(loss_fn))(pp_params, case["batch"])
+    g_dense = jax.jit(jax.grad(lambda p, b: gpt_loss(p, b, cfg)))(
         case["params"], case["batch"])
     g_pp_as_dense = env["pp_params_to_gpt"](g_pp, cfg.n_layers)
 

@@ -11,8 +11,9 @@ import numpy as np
 import pytest
 
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
-    Family, case, family, read, reference, seeded, step_kernel_calls,
-    steps_agree, test_bfloat16_step_passes_the_per_token_check,
+    Family, case, family, flash_twin, programmed, read, reference, seeded,
+    step_kernel_calls, steps_agree,
+    test_bfloat16_step_passes_the_per_token_check,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
     test_every_new_leaf_gets_its_rule,
     test_logits_loss_and_gradients_match_the_reference,
@@ -21,6 +22,7 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_the_configuration_refuses_by_name,
     test_the_new_scopes_are_regions_and_reach_the_compiled_step
     as test_the_new_scope_is_a_region_and_reaches_the_compiled_step,
+    test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart,
     test_the_shares_of_a_layer_add_up_to_the_uncut_reference, tiny)
 
@@ -458,13 +460,15 @@ def test_the_indexer_kernels_least_times_at_the_cell():
     assert 0.60 < least["index_grad_q"] == least["index_grad_k"] < 0.62
 
 
-def test_data_parallel_step_equals_one_device(jax_cpu, tiny):
+def test_data_parallel_step_equals_one_device(jax_cpu, tiny, seeded,
+                                              programmed):
     """One step of the whole tiny model on data=2 (the walk and the
     flash_sel kernels per shard, the KL a mean of the shards') equals the
     one-device step; `tensor` > 1 refuses by name."""
     # (the balance loss's f and P are the whole batch's on both)
-    _cfg, one_step = steps_agree(jax_cpu, FAMILY, tiny, strategy="dp",
-                                 axes={"data": 2})
+    _cfg, one_step = steps_agree(
+        jax_cpu, FAMILY, tiny, flash_twin(seeded, programmed), strategy="dp",
+        axes={"data": 2})
     with pytest.raises(ValueError, match="'tensor' > 1"):
         one_step("tp_fsdp", {"data": 1, "fsdp": 2, "tensor": 2}, 4)
 

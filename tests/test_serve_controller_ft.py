@@ -21,6 +21,12 @@ import pytest
 import ray_tpu
 from ray_tpu import serve
 
+# This file's proxy has a port of its own (as tests/test_serve_trace.py's
+# has): test_serve.py keeps the default 8000, and two files' proxies on one
+# port, in two xdist workers at once, lost the bind (D2's "load-sensitive"
+# failures of PRs 40 and 55).
+PORT = 8152
+
 
 @pytest.fixture
 def serve_cluster():
@@ -86,7 +92,7 @@ def test_controller_sigkill_mid_rolling_update(serve_cluster):
 
         return Roll
 
-    serve.start(proxy=True)
+    serve.start(http_options=serve.HTTPOptions(port=PORT))
     serve.run(make("1").bind(), name="roll", route_prefix="/roll")
     assert _wait_ready("roll", "Roll", 3)
     h = serve.get_app_handle("roll")
@@ -114,8 +120,8 @@ def test_controller_sigkill_mid_rolling_update(serve_cluster):
         # Proxy autonomy: healthz AND real routed requests must keep
         # answering 200 from stale routing through the whole outage.
         while not stop.is_set():
-            for url in ("http://127.0.0.1:8000/-/healthz",
-                        "http://127.0.0.1:8000/roll"):
+            for url in (f"http://127.0.0.1:{PORT}/-/healthz",
+                        f"http://127.0.0.1:{PORT}/roll"):
                 try:
                     with urllib.request.urlopen(url, timeout=15) as r:
                         if r.status != 200:
@@ -327,7 +333,7 @@ def test_proxy_and_controller_die_together_ingress_recovers(serve_cluster):
     restartable detached actor, the recovered controller reattaches its
     persisted binding and the proxy watch re-arms the listener — HTTP
     ingress comes back on the same port without serve.start()."""
-    serve.start(proxy=True)
+    serve.start(http_options=serve.HTTPOptions(port=PORT))
 
     @serve.deployment(num_replicas=1, request_replay=True)
     def echo(request):
@@ -342,7 +348,7 @@ def test_proxy_and_controller_die_together_ingress_recovers(serve_cluster):
     deadline = time.time() + 30
     while time.time() < deadline:
         try:
-            assert http_get("http://127.0.0.1:8000/px")[0] == 200
+            assert http_get(f"http://127.0.0.1:{PORT}/px")[0] == 200
             break
         except (urllib.error.URLError, ConnectionError, OSError):
             time.sleep(0.3)
@@ -365,7 +371,7 @@ def test_proxy_and_controller_die_together_ingress_recovers(serve_cluster):
     ok = False
     while time.time() < deadline:
         try:
-            status, body = http_get("http://127.0.0.1:8000/px", timeout=5)
+            status, body = http_get(f"http://127.0.0.1:{PORT}/px", timeout=5)
             if status == 200 and body == b"ok":
                 ok = True
                 break

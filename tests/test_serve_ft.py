@@ -22,6 +22,12 @@ from ray_tpu.serve.exceptions import (BackPressureError, ReplicaDiedError,
                                       ReplicaDrainingError,
                                       RequestTimeoutError)
 
+# This file's proxy has a port of its own (as tests/test_serve_trace.py's
+# has): test_serve.py keeps the default 8000, and two files' proxies on one
+# port, in two xdist workers at once, lost the bind (D2's "load-sensitive"
+# failures of PRs 40 and 55).
+PORT = 8153
+
 
 @pytest.fixture(scope="module")
 def ray_mod():
@@ -186,7 +192,7 @@ def test_shed_surfaces_as_http_503(serve_app):
             await asyncio.sleep(1.2)
             return "ok"
 
-    serve.start(proxy=True)
+    serve.start(http_options=serve.HTTPOptions(port=PORT))
     serve.run(Busy.bind(), name="ft4", route_prefix="/shed")
     time.sleep(1.0)
 
@@ -197,7 +203,7 @@ def test_shed_surfaces_as_http_503(serve_app):
     def hit():
         try:
             with urllib.request.urlopen(
-                    "http://127.0.0.1:8000/shed", timeout=30) as r:
+                    f"http://127.0.0.1:{PORT}/shed", timeout=30) as r:
                 answers.append((r.status, r.read()))
         except urllib.error.HTTPError as e:
             answers.append((e.code, e.read()))
@@ -479,14 +485,14 @@ def test_healthz_stays_ready_during_rolling_update(serve_app):
 
         return handler
 
-    serve.start(proxy=True)
+    serve.start(http_options=serve.HTTPOptions(port=PORT))
     serve.run(make("1").bind(), name="ft8", route_prefix="/ft8")
     time.sleep(1.0)
 
     def healthz():
         try:
             with urllib.request.urlopen(
-                    "http://127.0.0.1:8000/-/healthz", timeout=5) as r:
+                    f"http://127.0.0.1:{PORT}/-/healthz", timeout=5) as r:
                 return r.status
         except urllib.error.HTTPError as e:
             return e.code
@@ -531,7 +537,7 @@ def test_websocket_closes_on_replica_death(serve_app):
                     return
                 yield f"echo:{msg}"
 
-    serve.start(proxy=True)
+    serve.start(http_options=serve.HTTPOptions(port=PORT))
     serve.run(Chat.bind(), name="ft9", route_prefix="/ftchat")
     time.sleep(1.0)
 
@@ -540,7 +546,7 @@ def test_websocket_closes_on_replica_death(serve_app):
         while True:
             try:
                 reader, writer = await asyncio.open_connection(
-                    "127.0.0.1", 8000)
+                    "127.0.0.1", PORT)
                 key = base64.b64encode(_os.urandom(16)).decode()
                 writer.write(
                     f"GET /ftchat HTTP/1.1\r\nHost: x\r\n"

@@ -16,13 +16,14 @@ import pytest
 # then start in the same minute of a run)
 from helpers.families import test_the_cell_rehearses  # noqa: F401
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
-    Family, case, family, patched as _patched, read, reference, seeded,
-    steps_agree, test_bfloat16_step_passes_the_per_token_check,
+    Family, case, family, patched as _patched, programmed, read, reference,
+    seeded, steps_agree, test_bfloat16_step_passes_the_per_token_check,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
     test_logits_loss_and_gradients_match_the_reference,
     test_param_count_is_the_published_model_and_the_programs_tree,
     test_pipeline_refuses_by_name, test_sharded_step_equals_one_device,
     test_the_configuration_refuses_by_name,
+    test_the_programs_gradient_moves_where_the_references_does,
     test_the_shares_of_a_layer_add_up_to_the_uncut_reference, tiny)
 
 
@@ -114,12 +115,12 @@ class SmallThinker(Family):
         assert round(active / 1e9, 1) == 3.7
         assert round((active - 2 * 151936 * 2560) / 1e9, 1) == 2.9
 
-    def sharded_step(self, jax, tiny):
+    def sharded_step(self, jax, tiny, twin):
         """The two-period model on fsdp=2 x tensor=2: a key/value head with
         its seven query heads on a shard of `tensor`, the slots' order
         worked out per shard ahead of the mixer and handed to the experts'
         shard_map after it."""
-        cfg, _ = steps_agree(jax, self, tiny)
+        cfg, _ = steps_agree(jax, self, tiny, twin())
         assert cfg.remat_policy == "full"
 
     # The refusals parallel/pipeline.py gives today, kept: a period of
@@ -192,7 +193,7 @@ class SmallThinker(Family):
                          "rope_merge": 12, "moe_gmm": 72, "moe_tgmm": 24,
                          "embed_grad": 1, "moe_run_sum": 8}
     cell_memory_share = (0.60, 0.80)
-    cell_step_marks = (pytest.mark.timeout(900),)
+    cell_step_marks = (pytest.mark.timeout(600),)
 
 
 FAMILY = SmallThinker()

@@ -73,7 +73,7 @@ class NemotronH(Family):
         assert module["layers"][0]["attn"]["wq"] == P(*column)
         assert module["layers"][1]["moe"]["w_latent_in"] == P(column[0], None)
 
-    def sharded_step(self, jax, tiny):
+    def sharded_step(self, jax, tiny, twin):
         """A state-space layer and the module (attention, latent experts,
         its projection and second loss) on tensor=2: the `ssm/*`,
         `moe/w_latent_*` and `mtp/*` rows of parallel/sharding.py's table (a
@@ -174,7 +174,7 @@ class NemotronH(Family):
                          "moe_gmm": 96, "moe_tgmm": 24, "moe_run_sum": 18,
                          "embed_grad": 2}
     cell_memory_share = (0.80, 0.92)
-    cell_step_marks = (pytest.mark.timeout(900),)
+    cell_step_marks = (pytest.mark.timeout(600),)
 
 
 FAMILY = NemotronH()
@@ -615,9 +615,32 @@ def seeded(jax_cpu, small):
     return params, tokens
 
 
+@pytest.fixture(scope="module")
+def referred(jax_cpu, small, seeded):
+    """The reference's (logits, both heads' log-probabilities, the main
+    head's, loss, gradients) of `seeded`, float32 at full matmul precision:
+    one program, compiled once for both attention paths' cases."""
+    jax = jax_cpu
+    from benchmark.families import nemotron_h as family
+    params, tokens = seeded
+    tiny = small
+
+    @jax.jit
+    def reference(params):
+        loss, grads = jax.value_and_grad(
+            lambda p: family.reference_loss(p, tokens, tiny))(params)
+        return (family.reference_logits(params, tokens[:, :-1], tiny),
+                family.reference_both_logprobs(params, tokens, tiny),
+                family.reference_logprobs(params, tokens[:, :-1], tiny),
+                loss, grads)
+    with jax.default_matmul_precision("highest"):
+        return reference(params)
+
+
 @pytest.mark.parametrize("attention", ["reference", "flash"])
 def test_logits_loss_and_gradients_match_the_reference(jax_cpu, small,
-                                                       seeded, attention):
+                                                       seeded, referred,
+                                                       attention):
     """float32 on both sides: every logit of both heads' passes to 5e-5,
     the loss (both cross-entropies) and the whole tree of gradients."""
     jax = jax_cpu
@@ -639,22 +662,12 @@ def test_logits_loss_and_gradients_match_the_reference(jax_cpu, small,
                 gpt_forward(params, tokens[:, :-1], cfg)[0],
                 gpt_loss(params, batch, cfg), loss, aux, grads)
 
-    @jax.jit
-    def reference(params):
-        loss, grads = jax.value_and_grad(
-            lambda p: family.reference_loss(p, tokens, tiny))(params)
-        return (family.reference_logits(params, tokens[:, :-1], tiny),
-                family.reference_both_logprobs(params, tokens, tiny),
-                family.reference_logprobs(params, tokens[:, :-1], tiny),
-                loss, grads)
-
     def logprob(z, t):
         return jnp.take_along_axis(jax.nn.log_softmax(z), t[..., None],
                                    -1)[..., 0]
     with jax.default_matmul_precision("highest"):
         (logits, ahead), again, alone, loss, aux, grads = program(params)
-        want_logits, (first, second), plain, want_loss, want = reference(
-            params)
+    want_logits, (first, second), plain, want_loss, want = referred
     assert float(jnp.max(jnp.abs(logits - want_logits))) < 5e-5
     assert float(jnp.max(jnp.abs(again - want_logits))) < 5e-5
     assert float(jnp.max(jnp.abs(

@@ -858,3 +858,74 @@ def test_every_family_has_its_row_and_no_test_body_is_copied():
     copied = {name: files for (name, _body), files in defined.items()
               if len(files) > 1}
     assert not copied, copied
+
+
+def test_the_heavy_files_are_placed_first_by_what_they_take():
+    """tests/conftest.py's order of the files, a plain function of what the
+    collector sees of each (here: read off the source, nothing collected or
+    compiled): every family's file (helpers/families.py:FILES) and every
+    file that takes `cell_step` stands before every file that is neither,
+    a `cell_step` one and another in turn; the order is the same from any order
+    of the input, which xdist's workers need; and the hook moves whole
+    files and keeps a file's own order, and xdist's reordering of the files
+    by their count of tests is switched off where its scheduler reads it."""
+    import ast
+    import glob
+    import random
+    import types
+
+    tests = os.path.join(REPO, "tests")
+    sys.path.insert(0, tests)
+    try:
+        conftest = importlib.import_module("conftest")
+        families = importlib.import_module("helpers.families")
+    finally:
+        sys.path.remove(tests)
+    files = {}
+    for path in glob.glob(os.path.join(tests, "test_*.py")):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        names = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom):
+                names.update(a.asname or a.name for a in node.names)
+            elif isinstance(node, ast.Assign):
+                names.update(t.id for t in node.targets
+                             if isinstance(t, ast.Name))
+            elif isinstance(node, ast.FunctionDef) \
+                    and node.name.startswith("test_"):
+                names.update(a.arg for a in node.args.args)
+        files[os.path.basename(path)] = ("FAMILY" in names, names)
+    order = conftest.heavy_first(files)
+    assert sorted(order) == sorted(files)
+    heavy = {f for f in files if "cell_step" in files[f][1]} | {
+        f for f in families.FILES.values() if f}
+    assert heavy <= set(files)
+    assert set(order[:len(heavy)]) == heavy
+    steps = [f for f in order if "cell_step" in files[f][1]]
+    assert 11 <= len(steps) < len(heavy)
+    pairs = 2 * min(len(steps), len(heavy) - len(steps))
+    assert order[:pairs:2] == steps[:pairs // 2]
+    assert not set(order[1:pairs:2]) & set(steps)
+    shuffled = list(files.items())
+    for seed in range(3):
+        random.Random(seed).shuffle(shuffled)
+        assert conftest.heavy_first(dict(shuffled)) == order
+
+    def item(file, name, *fixtures, **module):
+        return types.SimpleNamespace(
+            nodeid=f"{file}::{name}", fixturenames=fixtures,
+            module=types.SimpleNamespace(**module))
+    items = [item("test_a.py", "one"), item("test_m.py", "two", "jax_cpu"),
+             item("test_z.py", "b", FAMILY=1), item("test_m.py", "one"),
+             item("test_z.py", "a", FAMILY=1),
+             item("test_y.py", "step", "v5e", "cell_step")]
+    conftest.pytest_collection_modifyitems(items)
+    assert [i.nodeid for i in items] == [
+        "test_y.py::step", "test_z.py::b", "test_z.py::a", "test_m.py::two",
+        "test_m.py::one", "test_a.py::one"]
+    config = types.SimpleNamespace(
+        option=types.SimpleNamespace(loadscopereorder=True),
+        addinivalue_line=lambda *a: None)
+    conftest.pytest_configure(config)
+    assert config.option.loadscopereorder is False

@@ -18,6 +18,12 @@ import ray_tpu
 from ray_tpu import serve
 from ray_tpu.serve.config import SLOConfig
 
+# This file's proxy has a port of its own (as tests/test_serve_trace.py's
+# has): test_serve.py keeps the default 8000, and two files' proxies on one
+# port, in two xdist workers at once, lost the bind (D2's "load-sensitive"
+# failures of PRs 40 and 55).
+PORT = 8154
+
 
 @pytest.fixture(scope="module")
 def ray_mod():
@@ -150,7 +156,7 @@ def test_top_once_renders_live_rows(ray_mod):
 
 @pytest.mark.timeout(180)
 def test_trace_search_filters(ray_mod):
-    serve.start(proxy=True)
+    serve.start(http_options=serve.HTTPOptions(port=PORT))
 
     @serve.deployment
     class Mixed:
@@ -167,7 +173,7 @@ def test_trace_search_filters(ray_mod):
     assert _wait_ready("tr", "Mixed", 1)
 
     def post(body):
-        req = urllib.request.Request("http://127.0.0.1:8000/tr",
+        req = urllib.request.Request(f"http://127.0.0.1:{PORT}/tr",
                                      data=body, method="POST")
         try:
             with urllib.request.urlopen(req, timeout=10) as r:
@@ -224,7 +230,7 @@ def test_proxy_stall_drives_slo_upscale(ray_mod):
     Queue wait measured proxy-side must fold into the deployment's SLO
     bad fraction and drive a burn upscale — with zero replica-side
     slowness."""
-    serve.start(proxy=True)
+    serve.start(http_options=serve.HTTPOptions(port=PORT))
 
     @serve.deployment(
         num_replicas=1, max_ongoing_requests=8, max_queued_requests=64,
@@ -258,7 +264,7 @@ def test_proxy_stall_drives_slo_upscale(ray_mod):
         while not stop.is_set():
             try:
                 with urllib.request.urlopen(
-                        "http://127.0.0.1:8000/qslo", timeout=10) as r:
+                        f"http://127.0.0.1:{PORT}/qslo", timeout=10) as r:
                     r.read()
             except Exception:
                 pass

@@ -190,7 +190,13 @@ def _start_seconds(fitted, phase):
 def test_start_up_gauges(fitted, name, tags):
     row = _row(fitted, name, **tags)
     assert row["type"] == "gauge"
-    assert 0.0 < row["value"] < 120.0
+    longest = 120.0
+    if (name, tags.get("Phase")) == ("ray_tpu_init_phase_seconds", "before"):
+        # this process's start -> init(): whatever pytest ran in it before
+        # this file counts, so the bound is the process's age, not a number
+        from ray_tpu._private import worker_api
+        longest = time.time() - worker_api._process_start_wall()
+    assert 0.0 < row["value"] < longest
 
 
 @pytest.mark.parametrize("phase", ["cache_load", "off_thread"])
@@ -710,7 +716,10 @@ def test_a_worker_is_watched_from_start_run_or_sets_no_compile_phase(
     if loaded:
         assert set(WORKER_PHASES) <= set(start)
         assert sum(start[p] for p in COMPILE_PHASES + GAP_PHASES
-                   ) == pytest.approx(start["first_report"], rel=1e-6)
+                   # (stamps are time.time()s, 2.4e-7 s apart at today's
+                   # date: a stretch of 0.08 s is not known to 1e-6 of itself)
+                   ) == pytest.approx(start["first_report"], rel=1e-6,
+                                      abs=1e-5)
         assert programs == {"<lambda>"}
         assert program.read({}, args) == start["trace"] > 0.0
     else:

@@ -11,7 +11,8 @@ import copy
 import pytest
 
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
-    Family, case, family, read, reference, seeded, step_kernel_calls,
+    Family, case, family, programmed, read, reference, seeded,
+    step_kernel_calls,
     test_bfloat16_step_passes_the_per_token_check,
     test_configuration_file_keeps_the_catalog_and_states_the_cut,
     test_every_new_leaf_gets_its_rule,
@@ -20,6 +21,7 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_pipeline_refuses_by_name, test_sharded_step_equals_one_device,
     test_the_configuration_refuses_by_name,
     test_the_new_scopes_are_regions_and_reach_the_compiled_step,
+    test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart,
     test_the_shares_of_a_layer_add_up_to_the_uncut_reference, tiny)
 
@@ -151,11 +153,11 @@ class Laguna(Family):
                 == P(*column)
             assert attn["wo"] == P(*row)
 
-    def sharded_step(self, jax, tiny):
+    def sharded_step(self, jax, tiny, twin):
         """fsdp=2 x tensor=2: a key/value head with its three or four query
         heads and their gates on a shard of `tensor`, the kernels per
         shard."""
-        Family.sharded_step(self, jax, tiny)
+        Family.sharded_step(self, jax, tiny, twin)
 
     refusals = [
         case(({"attention": "ring"}, "'window' layer.*attention='ring'"),
