@@ -19,7 +19,10 @@ head or an element on attention's output), optional gated delta-rule
 linear-attention layers among them (ops/linear_attention.py: a state a head
 that the data decays a channel at a time, or by one number a head, and
 overwrites along the key; widths of their own for key and value: `delta`),
-the norm of a half before it or after it (`norm_after`), an
+the norm of a half before it, after it or both (`norm_after`), the
+stack run several times a step over the SAME weights with the final norm
+inside the loop, every pass read by the head and the passes' cross-entropies
+weighted a token by an exit gate (`loop`: a looped, weight-shared stack), an
 optional learned sparse-attention indexer on the attention
 layers (ops/indexer.py: it chooses the keys a query sees, and is trained by
 a loss of its own),
@@ -169,6 +172,26 @@ class Multipliers:
 
 
 @dataclass(frozen=True)
+class Loop:
+    """A looped stack (GPTConfig.loop; arXiv:2510.25741): the layers run
+    `passes` times a step over the SAME parameters, h_0 the embedding, h_t =
+    final_norm(layers(h_{t-1})): the final norm sits INSIDE the loop, the
+    normed output of a pass is what the next pass reads, and the positions
+    are the same in every pass. Every pass is read by the same head. An exit
+    gate (`exit_gate/w` [d, 1], `exit_gate/b` [1]; scope `exit_gate`) gives
+    lam_t = sigmoid(h_t w + b) a token, and the exit distribution p_t =
+    lam_t prod_{j<t} (1 - lam_j) for t < passes, p_T the rest. The loss is
+    the mean over the tokens of sum_t p_t xent_t - entropy_coef H(p), the
+    gradient through p as well as through the cross-entropies. A forward
+    (gpt_forward, gpt_backbone) gives the LAST pass's: no token leaves
+    early. The passes are one traced body (a lax.scan): a step holds each
+    layer's kernels once, and a shared weight's gradient is the sum over
+    the passes."""
+    passes: int
+    entropy_coef: float = 0.1
+
+
+@dataclass(frozen=True)
 class GPTConfig:
     vocab_size: int = 50304           # GPT-2 vocab padded to a multiple of 128
     d_model: int = 768
@@ -222,8 +245,10 @@ class GPTConfig:
     # Where the norm of a half sits: False, before it (h = x + f(norm(x)));
     # True, after it, on what the half ADDS (h = x + norm(f(x)): the mixer
     # and the feed-forward read the stream itself; ln1 and ln2 are those
-    # norms' scales).
-    norm_after: bool = False
+    # norms' scales); "both", a norm either side, h = x + norm(f(norm(x))):
+    # ln1 and ln2 before the halves, `ln1_after` and `ln2_after` on what
+    # they add (four scales a layer).
+    norm_after: Any = False
     # A gate on attention's output, from the normed input, before the
     # output projection: True, a gate a head, sigmoid(x wg [d, heads])
     # times the head's output; "element", a gate an element, wg [d, heads x
@@ -334,12 +359,13 @@ class GPTConfig:
     # Sub-records, each None where the stack has nothing of the kind:
     # state-space layers' sizes, delta-rule layers' widths and forms (None:
     # KDA's at head_dim), the feed-forwards' form, a prediction module,
-    # scalar multipliers.
+    # scalar multipliers, a looped stack (None: the layers run once).
     ssm: Optional[StateSpace] = None
     delta: Optional[DeltaRule] = None
     expert_form: Optional[ExpertForm] = None
     mtp: Optional[PredictionModule] = None
     multipliers: Optional[Multipliers] = None
+    loop: Optional[Loop] = None
 
     def __post_init__(self):
         if not self.head_dim:
@@ -380,6 +406,22 @@ class GPTConfig:
         if self.attention_gate not in (False, True, "element"):
             raise ValueError(f"attention_gate={self.attention_gate!r}: "
                              "expected False | True | 'element'")
+        if self.norm_after not in (False, True, "both"):
+            raise ValueError(f"norm_after={self.norm_after!r}: expected "
+                             "False | True | 'both'")
+        if self.loop is not None:
+            if self.loop.passes < 1:
+                raise ValueError(f"loop {self.loop!r}: expected passes >= 1")
+            if self.mtp is not None:
+                raise ValueError(
+                    "loop runs the final norm inside every pass: a "
+                    "prediction module (mtp) reads the stream BEFORE the "
+                    "final norm, which a looped stack does not hand on")
+            if self.attention == "ring":
+                raise ValueError(
+                    "a looped stack (loop) is built for the flash and "
+                    "reference paths, not for attention='ring': the exit "
+                    "gate's weights a token are not sharded over 'sequence'")
         if self.route_from not in ("mixed", "input"):
             raise ValueError(f"route_from={self.route_from!r}: expected "
                              "'mixed' | 'input'")
@@ -525,7 +567,10 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
     """Build the parameter pytree (fp32 master weights). A layer holds
     `ln1` and its mixer's group, then `ln2` and `mlp` | `moe`, or, where its
     kind is one half alone (`_HALVES`), `ln1` and that half's group: the
-    block runs what a layer's parameters hold."""
+    block runs what a layer's parameters hold. Under norm_after "both" a
+    half has a second scale, `ln1_after` | `ln2_after`; a looped stack
+    (cfg.loop) has its exit gate, `exit_gate`: w [d, 1] at its fan-in's
+    scale (a normed row then scores ~N(0, 1)) and a bias b [1] of 0."""
     keys = jax.random.split(key, cfg.n_layers + 3)
     params: Dict[str, Any] = {
         "embed": {"table": _init_dense(keys[0], (cfg.vocab_size, cfg.d_model),
@@ -562,6 +607,10 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
         layer = {"ln1": {"scale": jnp.ones((d,), jnp.float32)}}
         if mixer is not None and has_ff:
             layer["ln2"] = {"scale": jnp.ones((d,), jnp.float32)}
+        if cfg.norm_after == "both":
+            for name in list(layer):         # ln1, and ln2 where it has one
+                layer[name + "_after"] = {"scale": jnp.ones((d,),
+                                                            jnp.float32)}
         if mixer == "conv":
             # w_in: the published [d, 3d] as its three chunks B, C, X
             layer["conv"] = {
@@ -665,6 +714,10 @@ def gpt_init(key, cfg: GPTConfig) -> Dict:
                for name in ("norm_e", "norm_h", "norm")},
             "layers": [build(mk[j + 1], kind, e > 0)
                        for j, kind in enumerate(cfg.mtp.layer_kinds)]}
+    if cfg.loop is not None:
+        params["exit_gate"] = {
+            "w": _init_dense(jax.random.fold_in(key, 16), (d, 1)),
+            "b": jnp.zeros((1,), jnp.float32)}
     return params
 
 
@@ -1867,8 +1920,11 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
 
     def block(x, layer, named_mlp=0):
         # (under norm_after a half reads the stream itself and its norm
-        # sits on what it adds, where every shard of 'tensor' has the sum)
-        after = cfg.norm_after
+        # sits on what it adds, where every shard of 'tensor' has the sum;
+        # under "both" a half reads its own norm of the stream and a second
+        # scale, `<name>_after`, norms what it adds)
+        both = cfg.norm_after == "both"
+        after = bool(cfg.norm_after) and not both
         normed = x if after else norm("ln1", x, layer)
         mixer_stats, routing = {}, None
         if cfg.route_from == "input" and "moe" in layer:
@@ -1886,8 +1942,8 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
                 layer, normed, cfg, tables[kind], where, kind, index_table)
         else:
             mixed = None                 # a feed-forward alone
-        if after and mixed is not None:
-            mixed = norm("ln1", mixed, layer)
+        if (after or both) and mixed is not None:
+            mixed = norm("ln1_after" if both else "ln1", mixed, layer)
         h = x if mixed is None else add(x, mixed)
         if "moe" not in layer and "mlp" not in layer:
             return h, mixer_stats        # a mixer alone
@@ -1904,8 +1960,8 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
             with jax.named_scope("mlp"):
                 delta, stats = _mlp_block(
                     layer["mlp"], normed, cfg, where, named_mlp), {}
-        if after:
-            delta = norm(second, delta, layer)
+        if after or both:
+            delta = norm(second + "_after" if both else second, delta, layer)
         return add(h, delta), {**stats, **mixer_stats}
 
     if cfg.remat_policy == "full":
@@ -2061,9 +2117,17 @@ def final_norm(params, x, cfg: GPTConfig):
     return _rmsnorm(x, params["final_norm"]["scale"], cfg.rmsnorm_eps)
 
 
+def _last_normed(params, h, cfg: GPTConfig):
+    """`_stack`'s stream -> what the head reads of the LAST pass [B, S, D]:
+    the final norm of it, or, of a looped stack's passes [T, B, S, D]
+    (normed inside the loop), the last."""
+    return final_norm(params, h, cfg) if cfg.loop is None else h[-1]
+
+
 def gpt_forward(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     """tokens: [B, S] int32 -> (logits [B, S, vocab] (cfg.dtype), the
-    router's statistics as gpt_backbone gives them)."""
+    router's statistics as gpt_backbone gives them). Of a looped stack
+    (cfg.loop): the LAST pass's logits."""
     dt = cfg.dtype
     x, router = gpt_backbone(params, tokens, cfg, mesh, act_sharding)
     with jax.named_scope("head"):
@@ -2082,14 +2146,16 @@ def gpt_forward_both(params, tokens, cfg: GPTConfig):
     the first S, logits [B, S, vocab] through the prediction module of the
     token TWO after each (its last position has none to predict); cfg.dtype
     both, the second None without cfg.mtp): what gpt_loss_and_aux takes its
-    two cross-entropies from, as logits."""
+    two cross-entropies from, as logits (of a looped stack, the last
+    pass's: gpt_forward_passes gives every pass's)."""
     where = Setting()
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     h, _, block = _stack(params, inputs, cfg, where)
-    w_head = _head_operands(params, h, targets, cfg)[1]
+    w_head = _head_operands(params, h[-1] if cfg.loop else h, targets,
+                            cfg)[1]
     with jax.named_scope("head"):
         logits = jnp.einsum("bsd,dv->bsv",
-                            _head_rows(final_norm(params, h, cfg), cfg),
+                            _head_rows(_last_normed(params, h, cfg), cfg),
                             w_head)
     if cfg.mtp is None:
         return logits, None
@@ -2115,7 +2181,19 @@ def gpt_backbone(params, tokens, cfg: GPTConfig, mesh=None, act_sharding=None):
     """
     x, per_layer, _ = _stack(params, tokens, cfg, Setting(mesh, act_sharding))
     router = _layer_means(per_layer)
-    return final_norm(params, x, cfg), router
+    return _last_normed(params, x, cfg), router
+
+
+def gpt_forward_passes(params, tokens, cfg: GPTConfig):
+    """A looped stack's every pass: tokens [B, S] -> (logits [T, B, S,
+    vocab] in cfg.dtype, pass t's under the one head, the exit distribution
+    p [T, B, S] float32: what gpt_loss_and_aux weights the passes'
+    cross-entropies by)."""
+    h, _, _ = _stack(params, tokens, cfg, Setting())
+    w_head = _head_operands(params, h[-1], tokens, cfg)[1]
+    with jax.named_scope("head"):
+        logits = jnp.einsum("tbsd,dv->tbsv", _head_rows(h, cfg), w_head)
+    return logits, jnp.exp(_exit_log_p(params, h))
 
 
 def _embed(params, tokens, cfg: GPTConfig, where: Setting):
@@ -2132,13 +2210,35 @@ def _stack(params, tokens, cfg: GPTConfig, where: Setting):
     stream BEFORE the final norm, the statistics of each layer that has
     any, the block the layers ran: layer_fn's, keeping as many of the
     MLPs' matmul results through the remat as `mlp_products_kept` reckons,
-    for a caller that runs further layers at this sequence length)."""
+    for a caller that runs further layers at this sequence length).
+
+    A looped stack (cfg.loop) runs the layers `passes` times over the same
+    parameters, the final norm inside the loop, as ONE traced body (a
+    lax.scan over the passes whose carry is the stream: the program holds
+    each layer's ops once, and the scan's transpose sums a shared weight's
+    gradients over the passes) -> the passes' NORMED streams [T, B, S, D]
+    in the stream's place, each statistic averaged over the passes."""
     with jax.named_scope("embed"):
         x = where.pin(_embed(params, tokens, cfg, where))
     layer = layer_fn(cfg, tokens.shape[1], where).keep_mlp(
         mlp_products_kept(params, *tokens.shape, cfg, where))
-    x, per_layer = _walk(layer, x, params["layers"])
-    return x, per_layer, layer
+    if cfg.loop is None:
+        x, per_layer = _walk(layer, x, params["layers"])
+        return x, per_layer, layer
+
+    # (the norm keeps its input alone through the loop, as a layer does:
+    # its float32 copies of a pass's stream would be kept once a pass)
+    normed = jax.checkpoint(partial(final_norm, cfg=cfg))
+
+    def one_pass(x, _):
+        x, per_layer = _walk(layer, x, params["layers"])
+        x = where.pin(normed(params, x))
+        return x, (x, per_layer)
+
+    _, (streams, per_layer) = jax.lax.scan(one_pass, x, None,
+                                           length=cfg.loop.passes)
+    return streams, jax.tree_util.tree_map(
+        lambda stat: jnp.mean(stat, axis=0), per_layer), layer
 
 
 def _walk(layer, x, layers):
@@ -2162,7 +2262,9 @@ def memory_plan(params, batch: int, seq: int, cfg: GPTConfig,
     remat keeping 0, 1 and 2 of its MLP's products (three lists), the
     working set of one layer and of the head; the prediction module's
     layers after the stack's. share: the part of the parameters' bytes
-    that a device holds."""
+    that a device holds. What a layer keeps is of ONE application of it: a
+    looped stack keeps that once a pass (memory.reckoned_peak's
+    `passes`)."""
     layers = params["layers"] + params.get("mtp", {}).get("layers", [])
     grads = [int(share * memory.tree_bytes(layer)) for layer in layers]
     after = (0 if cfg.tie_embeddings
@@ -2170,9 +2272,20 @@ def memory_plan(params, batch: int, seq: int, cfg: GPTConfig,
     kept, products = zip(*(_layer_bytes(layer, batch, seq, cfg, where)
                            for layer in layers))
     held = [[k + p[n] for k, p in zip(kept, products)] for n in range(3)]
+    working, head = _working_set(layers, batch, seq, cfg, where)
+    if cfg.loop is not None:
+        # what the loop itself keeps once a pass: the normed stream it
+        # hands the head, and the final norm's input; and what the chip's
+        # compiler keeps across both loops: it casts every layer's matrices
+        # to the model dtype once, outside them, where a stack walked once
+        # casts a layer's as it comes to it (a compile for the described
+        # v5e: PERF.md section 6, PR 71)
+        rows, _ = _a_devices_part(batch, seq, cfg, where)
+        item = jnp.dtype(cfg.dtype).itemsize
+        working += (2 * cfg.loop.passes * rows * seq * cfg.d_model * item
+                    + sum(grads) * item // 4)
     return (int(share * memory.tree_bytes(params)) - sum(grads) - after,
-            grads, after, held,
-            *_working_set(layers, batch, seq, cfg, where))
+            grads, after, held, working, head)
 
 
 def mlp_products_kept(params, batch: int, seq: int, cfg: GPTConfig,
@@ -2187,20 +2300,32 @@ def mlp_products_kept(params, batch: int, seq: int, cfg: GPTConfig,
     under remat_policy "none", which keeps everything as it is. All layers
     keep alike, so that the step traces and lowers as many kinds of layer
     as it did (a second kind cost gpt2s 3.4-5 s of set-up: PERF.md section
-    6, PR 63). The traced step says what it chose (memory.report)."""
+    6, PR 63). A looped stack (cfg.loop) keeps a layer's bytes once a pass
+    and holds every layer's gradient, a sum over the passes, across the
+    whole loop. The traced step says what it chose (memory.report)."""
     told = memory.budget()
     if told is None or cfg.remat_policy != "full":
         return 0
     before, grads, after, held, working, head = memory_plan(
         params, batch, seq, cfg, where, told.share)
+    passes = cfg.loop.passes if cfg.loop else 1
     peaks = [memory.reckoned_peak(told.state, before, grads, after, h,
-                                  working, head) for h in held]
+                                  working, head, passes) for h in held]
     n = memory.most_kept(told.limit, peaks)
+    if passes > 1:
+        # A loop's kept values are stacked, one buffer of [passes, ...] a
+        # layer and product, written in the forward loop and read in the
+        # backward one: with the MLPs' products among them the chip's
+        # compiler laid the two loops' buffers out with as much lost
+        # between them as they hold (5.55 GB of 11.37 at 8 layers) and
+        # refused a step whose values fit (PERF.md section 6, PR 71). A
+        # looped stack keeps nothing more until a layout holds them.
+        n = 0
     while n and sum(held[n]) == sum(held[n - 1]):
         n -= 1                       # no gate, or no MLP at all: no more kept
     having = sum(h2 > h0 for h0, h2 in zip(held[0], held[2]))
-    memory.report(n, having, sum(held[n]) - sum(held[0]), peaks[n],
-                  told.limit)
+    memory.report(n, having, passes * (sum(held[n]) - sum(held[0])),
+                  peaks[n], told.limit, passes)
     return n
 
 
@@ -2277,35 +2402,45 @@ def _chunk_nll(xk, w_head, tk):
     return logits, lse, lse - picked.astype(jnp.float32)
 
 
-def chunked_xent_recompute(x, w_head, targets, mask, chunk_rows: int = 16384):
+def chunked_xent_recompute(x, w_head, targets, mask, chunk_rows: int = 16384,
+                           plain: bool = False):
     """chunked_xent differentiated by autodiff: each chunk's body is under
     jax.checkpoint, so the backward computes the chunk's logits and their
     logsumexp a second time (four vocabulary matmuls a chunk) and a
     differentiated call saves nothing but its inputs. For a caller that is
     differentiated INSIDE a scan (parallel/pipeline.py's last rank, once a
     tick): there chunked_xent's residuals would be saved once an iteration,
-    w_head's fp32 gradient among them."""
+    w_head's fp32 gradient among them. mask and plain: chunked_xent's."""
     xc, tc, mc = _xent_chunks(x, targets, mask, chunk_rows)
 
     @jax.checkpoint
     def body(carry, args):
         xk, tk, mk = args
         _, _, nll = _chunk_nll(xk, w_head, tk)
-        return (carry[0] + jnp.sum(nll * mk), carry[1] + jnp.sum(mk)), None
+        return ((carry[0] + jnp.sum(nll * mk), carry[1] + jnp.sum(mk)),
+                jax.lax.stop_gradient(nll) if plain else None)
 
-    (total, denom), _ = jax.lax.scan(body, (0.0, 0.0), (xc, tc, mc))
-    return total, denom
+    sums, nll = jax.lax.scan(body, (0.0, 0.0), (xc, tc, mc))
+    return sums + (nll.reshape(-1)[:x.shape[0]],) if plain else sums
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384,
+                 plain: bool = False):
     """Next-token cross-entropy WITHOUT materializing full [N, vocab] fp32
     logits (12.8 GB at bs=64/seq=1024/vocab=50k — an HBM-capacity bug for
     any capacity-size batch): rows go through the head in chunks of a scan.
     TPU-native analogue of fused linear+cross-entropy.
 
     x: [N, D] (model dtype), w_head: [D, V], targets: [N] int32,
-    mask: [N] fp32. Returns (sum_nll, sum_mask).
+    mask: [N] fp32, a WEIGHT a row: 0 / 1 where a caller leaves rows out,
+    any float where it weights them (a looped stack's exit distribution).
+    Returns (sum of mask x nll, sum_mask): the weighted sum and the
+    weights' sum; d / d mask is the row's nll (and 1 through the second
+    sum), in both formulations. plain: a third result from the same pass
+    over the logits, the rows' own nll [N] float32, unweighted (what a plain
+    mean cross-entropy over any of the rows needs beside the weighted sum);
+    it carries no gradient.
 
     Differentiated, the same scan takes the gradient while the chunk's
     logits are there (_chunked_xent_fwd): p = (softmax - onehot) * mask
@@ -2319,10 +2454,10 @@ def chunked_xent(x, w_head, targets, mask, chunk_rows: int = 16384):
     so nothing of a chunk outlives its iteration.
     Evaluated only, no gradient is computed. Inside a differentiated scan
     use chunked_xent_recompute (its docstring says why)."""
-    return chunked_xent_recompute(x, w_head, targets, mask, chunk_rows)
+    return chunked_xent_recompute(x, w_head, targets, mask, chunk_rows, plain)
 
 
-def _chunked_xent_fwd(x, w_head, targets, mask, chunk_rows):
+def _chunked_xent_fwd(x, w_head, targets, mask, chunk_rows, plain):
     xc, tc, mc = _xent_chunks(x, targets, mask, chunk_rows)
     vocab = w_head.shape[1]
 
@@ -2342,15 +2477,15 @@ def _chunked_xent_fwd(x, w_head, targets, mask, chunk_rows):
     (total, denom, dw), (dx, nll) = jax.lax.scan(
         body, (0.0, 0.0, jnp.zeros(w_head.shape, jnp.float32)), (xc, tc, mc))
     n = x.shape[0]
+    dx, nll = dx.reshape(-1, x.shape[1])[:n], nll.reshape(-1)[:n]
     # (a residual has to be an array: w_head's dtype rides on an empty one)
-    return (total, denom), (dx.reshape(-1, x.shape[1])[:n], dw,
-                            nll.reshape(-1)[:n],
-                            jnp.zeros((0,), w_head.dtype))
+    return (total, denom) + (nll,) * plain, (
+        dx, dw, nll, jnp.zeros((0,), w_head.dtype))
 
 
-def _chunked_xent_bwd(chunk_rows, residuals, cotangents):
+def _chunked_xent_bwd(chunk_rows, plain, residuals, cotangents):
     dx, dw, nll, w_like = residuals
-    g_total, g_denom = cotangents
+    g_total, g_denom = cotangents[:2]    # (the rows' plain nll's: none)
     gx = (g_total * dx).astype(dx.dtype)
     gw = (g_total * dw).astype(w_like.dtype)
     n, d = dx.shape
@@ -2419,6 +2554,72 @@ def head_xent_recompute(params, x, targets, cfg: GPTConfig):
             *_head_operands(params, x, targets, cfg))
 
 
+def _exit_log_p(params, h):
+    """A looped stack's exit distribution: the passes' normed streams h
+    [T, B, S, D] -> log p [T, B, S] float32, with lam_t = sigmoid(h_t . w +
+    b) (`exit_gate`; the product a float32 multiply and sum over the row, no
+    matmul of one column): p_t = lam_t prod_{j<t} (1 - lam_j) before the
+    last pass, p_T = prod_{j<T} (1 - lam_j), the rest; as logarithms, from
+    log_sigmoid of the score and of its negative, so that a token sure of
+    its exit gives a finite log and p sums to one over the passes to
+    float32's rounding."""
+    gate = params["exit_gate"]
+
+    @jax.checkpoint
+    def scores(h, w, b):
+        # (recomputed in the backward pass: kept, the float32 copy of the
+        # passes' streams would be twice their bytes)
+        return jnp.sum(h.astype(jnp.float32) * w[:, 0], axis=-1) + b
+
+    with jax.named_scope("exit_gate"):
+        score = scores(h, gate["w"], gate["b"])
+        stay = jax.nn.log_sigmoid(-score)            # log (1 - lam_t)
+        stayed = jnp.cumsum(stay, axis=0) - stay     # sum over j < t
+        return jnp.concatenate(
+            [jax.nn.log_sigmoid(score[:-1]) + stayed[:-1], stayed[-1:]])
+
+
+def _looped_xent(params, h, targets, cfg: GPTConfig):
+    """A looped stack's objective (`Loop`): the passes' normed streams h
+    [T, B, S, D], targets [B, S] with negatives left out -> (the mean over
+    the tokens of sum_t p_t xent_t - entropy_coef H(p), aux). Every pass
+    goes through the one head under its tokens' weights p_t (chunked_xent's
+    float `mask`, whose cotangent is the token's cross-entropy: the
+    gradient reaches the gate through p as well as the stack through the
+    cross-entropies), the passes' rows end to end in ONE call, so that the
+    head's matrix has one gradient and the program one head; the same pass
+    over the logits gives the rows' plain nll. aux: `xent` (the last pass's
+    plain mean cross-entropy), `xent_pass_<t>`, `exit_p_<t>` (the mean of
+    p_t over the tokens), `exit_entropy`, `exit_expected_passes` (the mean
+    of sum_t t p_t), t from 1."""
+    passes = h.shape[0]
+    log_p = _exit_log_p(params, h)
+    with jax.named_scope("exit_gate"):
+        valid = (targets >= 0).astype(jnp.float32)
+        p = jnp.exp(log_p)
+        weights = p * valid
+    with jax.named_scope("head"):
+        rows, w_head, flat_targets, _ = _head_operands(
+            params, h.reshape(-1, *h.shape[2:]),
+            jnp.tile(targets, (passes, 1)), cfg)
+        total, _, nll = chunked_xent(rows, w_head, flat_targets,
+                                     weights.reshape(-1), plain=True)
+    with jax.named_scope("exit_gate"):
+        n = jnp.maximum(jnp.sum(valid), 1.0)
+        entropy = -jnp.sum(jnp.sum(p * log_p, axis=0) * valid) / n
+        loss = total / n - cfg.loop.entropy_coef * entropy
+        xent = jnp.sum(nll.reshape(passes, -1) * valid.reshape(-1),
+                       axis=1) / n
+        exits = jnp.sum(weights, axis=(1, 2)) / n
+        aux = {"xent": xent[-1], "exit_entropy": entropy,
+               "exit_expected_passes": sum(
+                   (t + 1) * exits[t] for t in range(passes))}
+        for t in range(passes):
+            aux[f"xent_pass_{t + 1}"] = xent[t]
+            aux[f"exit_p_{t + 1}"] = exits[t]
+    return loss, aux
+
+
 def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
                      act_sharding=None):
     """batch: {"tokens": [B, S+1]} -> (loss, aux): the mean next-token
@@ -2427,8 +2628,11 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
     over the layers at index_loss_coef, plus, with a prediction module
     (cfg.mtp), loss_coef times the cross-entropy of the token two ahead
     through it and the same head (the mean over the positions that have
-    one; "mtp_xent"); aux holds the cross-entropy alone
-    ("xent")
+    one; "mtp_xent"); of a looped stack (cfg.loop) the cross-entropy's
+    place is taken by `_looped_xent`'s objective, every pass's
+    cross-entropy weighted a token by the exit gate, and aux gains its
+    statistics; aux holds the cross-entropy alone
+    ("xent": a looped stack's last pass's)
     and the router's statistics (the two losses unweighted, the largest
     expert's load over the mean, the share of the slots that fall to the
     held experts and of the layers whose bounded row space held them), for
@@ -2443,9 +2647,13 @@ def gpt_loss_and_aux(params, batch, cfg: GPTConfig, mesh=None,
                                              where)
         per_layer = per_layer + module_stats
     router = _layer_means(per_layer)
-    total, denom = head_xent(params, final_norm(params, h, cfg), targets, cfg)
-    loss = xent = total / jnp.maximum(denom, 1.0)
-    aux = {"xent": xent}
+    if cfg.loop is not None:
+        loss, aux = _looped_xent(params, h, targets, cfg)
+    else:
+        total, denom = head_xent(params, final_norm(params, h, cfg), targets,
+                                 cfg)
+        loss = xent = total / jnp.maximum(denom, 1.0)
+        aux = {"xent": xent}
     if cfg.mtp is not None:
         # the token two ahead: the targets shifted once more, the last
         # position (which has none) left out

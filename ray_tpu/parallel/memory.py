@@ -109,7 +109,7 @@ def tree_bytes(tree: Any, shardings: Any = None) -> int:
 
 def reckoned_peak(state: int, grads_before: int, grads: Sequence[int],
                   grads_after: int, held: Sequence[int], working: int,
-                  head: int) -> int:
+                  head: int, passes: int = 1) -> int:
     """The module docstring's walk: bytes a device holds at the fullest
     moment of a step whose layer i keeps held[i] bytes through its remat
     and has grads[i] bytes of gradient. grads_before: of the parameters
@@ -117,7 +117,16 @@ def reckoned_peak(state: int, grads_before: int, grads: Sequence[int],
     after them (the embedding's); working: what differentiating one layer
     takes beside what is kept; head: what the head takes after the forward
     pass. The moments: the head; each layer's turn; the end, all gradients
-    and nothing else."""
+    and nothing else. passes > 1: a looped stack, which runs the layers
+    that often over the same parameters. It keeps held[i] once a pass, and
+    a layer's gradient is a sum over the passes that the loop carries: all
+    of them are live from the backward of the last pass to the end of the
+    first, so the fullest moment is the start of that backward, under every
+    gradient and all that every pass keeps."""
+    if passes > 1:
+        kept, born = passes * sum(held), grads_before + sum(grads)
+        return state + OVERHEAD + max(born + grads_after, kept + head,
+                                      born + kept + working)
     most = max(grads_before + sum(grads) + grads_after, sum(held) + head)
     born, kept = grads_before + sum(grads), 0
     for g, h in zip(grads, held):
@@ -151,18 +160,23 @@ _KEPT = metrics.Gauge(
 
 
 def report(products: int, of: int, kept_bytes: int, peak: int,
-           limit: Optional[int]) -> None:
+           limit: Optional[int], passes: int = 1) -> None:
     """A traced step says what it keeps: the gauge
     ray_tpu_train_mlp_kept_layers (What=products | kept | of | bytes |
     peak_bytes) in this process's registry, and a line of the log; every
     one of the `of` layers that have an MLP keeps `products` of its
-    products. A step that keeps nothing says so too."""
+    products. A step that keeps nothing says so too. passes > 1: a looped
+    stack, whose line says how often a layer's bytes are kept and that its
+    gradients are held across the loop."""
     kept = of if products else 0
     for what, value in (("products", products), ("kept", kept), ("of", of),
                         ("bytes", kept_bytes), ("peak_bytes", peak)):
         _KEPT.set(value, tags={"What": what})
     logger.info(
         "mlp_kept_layers %d of %d, %d of an MLP's products each (%.2f GB a "
-        "device kept; reckoned peak %.2f GB of %s)", kept, of, products,
+        "device kept; reckoned peak %.2f GB of %s)%s", kept, of, products,
         kept_bytes / 1e9, peak / 1e9,
-        "no limit reported" if limit is None else f"{limit / 1e9:.2f}")
+        "no limit reported" if limit is None else f"{limit / 1e9:.2f}",
+        "" if passes == 1 else
+        f"; a looped stack: a layer's kept bytes counted {passes} times, "
+        "every layer's gradient live across the loop")

@@ -74,8 +74,15 @@ def _refuse_what_a_stage_cannot_run(cfg: GPTConfig, mesh: Mesh,
     def leaves(tree):
         return {_path_str(path): (leaf.shape, leaf.dtype) for path, leaf
                 in jax.tree_util.tree_flatten_with_path(tree)[0]}
-    layers = jax.eval_shape(
-        lambda: gpt_init(jax.random.PRNGKey(0), cfg))["layers"]
+    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
+    if "exit_gate" in params:
+        raise ValueError(
+            f"the parameters hold an exit gate (GPTConfig.loop={cfg.loop!r}):"
+            " a looped stack sends the stream through its layers "
+            f"{cfg.loop.passes} times a step and weights every pass's "
+            "cross-entropy; the schedule sends a microbatch down the stages "
+            "once (a circular schedule is not built)")
+    layers = params["layers"]
     first = leaves(layers[0])
     for i, layer in enumerate(layers[1:], 1):
         other = leaves(layer)

@@ -149,6 +149,11 @@ _TRANSFORMER = _STAGE_ROWS + (
     (r"ssm/w_out", _ROW),
     # a prediction module's projection [2 d, d]: rows like any d_model side
     (r"mtp/proj", ("model", None)),
+    # a looped stack's exit gate, one column [d, 1] and its bias, and the
+    # second scale of a half under a norm either side of it: not divided
+    # (every shard norms and scores a whole row)
+    (r"exit_gate/(w|b)", ()),
+    (r"ln(1|2)_after", ()),
     # Vocab over both axes under tp_fsdp, d_model replicated: a d-sharded
     # gather output cannot transition to batch-sharded activations without
     # an involuntary full rematerialization (permuted tile order), while a

@@ -126,7 +126,7 @@ REGIONS = ("embed", "attn_proj", "attn_latent", "attn_core", "attn_window",
            "attn_index", "attn_out", "attn_gate", "conv", "conv_mix", "kda",
            "kda_core", "ssm", "ssm_core", "mlp", "route_ahead",
            "moe", "moe_route", "moe_shared", "moe_latent", "mtp", "norm",
-           "head", "loss_and_grad",
+           "head", "exit_gate", "loss_and_grad",
            "grad_accum", "optimizer")
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "flash_win_fwd",
            "flash_win_bwd_dq", "flash_win_bwd_dkv", "flash_sel_fwd",

@@ -291,7 +291,7 @@ def a_step_keeps_up_x(step, layers):
     peak; and what is kept is in the temporaries (the step that keeps
     nothing more holds that much less: 12.42 GB at granite for 13.63)."""
     from ray_tpu.parallel import memory
-    products, of, kept_bytes, peak, limit = step.kept
+    products, of, kept_bytes, peak, limit, _passes = step.kept
     assert (products, of, limit) == (1, layers, V5E_BYTES)
     entry = step.text[step.text.index("\nENTRY "):].splitlines()
     again = [at for at, line in enumerate(entry) if re.search(

@@ -65,6 +65,7 @@ FILES = {
     "granite_hybrid": "test_hybrid_mixer_model.py",
     "kimi_linear": "test_kimi_linear_model.py",
     "olmo_hybrid": "test_olmo_hybrid_model.py",
+    "ouro": "test_ouro_model.py",
 }
 
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"

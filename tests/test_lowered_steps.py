@@ -130,6 +130,14 @@ LOWERED = {
     # norm of 1920 columns, the norm after each half, `up x` of every MLP
     # kept through the remat
     "olmohybrid_train_1chip": "485e120577e83a31",
+    # new with PR 71, which leaves the thirteen above alone (their lines are
+    # the parent's: a configuration without `GPTConfig.loop` walks its
+    # layers once and calls `chunked_xent` without its third result): eight
+    # layers under four norms each inside ONE scan of four passes, so the
+    # text holds 8 `flash_fwd` calls and not 32, the final norm under a
+    # checkpoint inside it, the passes' rows through the head in one call
+    # of two chunks under the exit gate's weights, nothing more kept
+    "ouro26_train_1chip": "75376408a21b0486",
 }
 
 

@@ -102,7 +102,7 @@ def _kept(cell, limit):
         patch.setattr(memory, "report", lambda *a: said.append(a))
         with memory.told(budget(limit=limit)):
             n = gpt.mlp_products_kept(*args)
-    (products, of, kept_bytes, peak, _), = said
+    (products, of, kept_bytes, peak, _limit, _passes), = said
     assert products == n and (kept_bytes > 0) == (n > 0)
     return n, of, peak
 

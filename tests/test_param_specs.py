@@ -1,6 +1,6 @@
 """How a transformer's parameters are divided over the mesh, leaf for leaf:
-every parameter of the benchmark's architectures (the ten rehearsal
-configurations hold every parameter name of the ten cells) under `tp` and
+every parameter of the benchmark's architectures (the eleven rehearsal
+configurations here hold every parameter name of their cells) under `tp` and
 `tp_fsdp` on fsdp=2 x tensor=2, and the dense one, stacked, under `pp` and
 `pp_tp`. The expectations were recorded at PR 42, before
 parallel/sharding.py's rule lists became one table (a delta-rule layer's
@@ -30,6 +30,11 @@ GSPMD = {
     "final_norm/scale": DEFAULTED,
     "layers/*/ln1/scale": DEFAULTED,
     "layers/*/ln2/scale": DEFAULTED,
+    # (a half under a norm either side: its second scale, and a looped
+    # stack's exit gate, one column and its bias: rows of their own, whole)
+    "layers/*/ln1_after/scale": WHOLE_VECTOR,
+    "layers/*/ln2_after/scale": WHOLE_VECTOR,
+    "exit_gate/w": WHOLE_MATRIX, "exit_gate/b": WHOLE_VECTOR,
     "layers/*/attn/wq": COLUMN, "layers/*/attn/wk": COLUMN,
     "layers/*/attn/wv": COLUMN, "layers/*/attn/wo": ROW,
     "layers/*/attn/wg": COLUMN,
@@ -110,7 +115,7 @@ CASES = [(name, strategy)
          for name in ("tiny", "tiny-olmoe", "tiny-kanana", "tiny-lfm2",
                       "tiny-laguna", "tiny-keye", "tiny-solar",
                       "tiny-smallthinker", "tiny-kimi-linear",
-                      "tiny-olmo-hybrid")
+                      "tiny-olmo-hybrid", "tiny-ouro")
          for strategy in ("tp", "tp_fsdp")] + [("tiny", "pp"),
                                                ("tiny", "pp_tp")]
 
