@@ -30,10 +30,12 @@ per-layer jax.checkpoint (remat) for memory: a layer keeps its input and
 what its kernels name (the flash kernel's output and row statistics, an
 indexer's selection, a linear-attention or state-space layer's output and
 chunk states) and recomputes the rest; where the devices' memory is
-reckoned to hold them beside the state, every layer's MLP also keeps its
-matmul results (MLP_OUT): `up x`, or `up x` and `gate x`
-(`mlp_products_kept`: from what the train step's builder reports,
-parallel/memory.py, and the shapes; nobody sets it). A layer is a mixer and then a feed-forward,
+reckoned to hold them beside the state, every layer also keeps the first
+rungs of ONE ladder of its products: its MLP's matmul results (MLP_OUT:
+`up x`, then `gate x`), then what a delta-rule or state-space mixer's
+filters read, then what they write (MIXER_OUT) (`products_kept`: from what
+the train step's builder reports, parallel/memory.py, and the shapes;
+nobody sets it). A layer is a mixer and then a feed-forward,
 or ONE of the two alone (`_HALVES`); optional scalar multipliers on the
 embedding, on what each half adds to the stream, on attention's scores and
 on the logits (`Multipliers`).
@@ -75,9 +77,19 @@ from ray_tpu.parallel.sharding import MESH_AXES
 # The name an MLP's matmul results carry (`_mlp_block`: up x and gate x,
 # the pre-activations; jax.ad_checkpoint.checkpoint_name), as FLASH_OUT,
 # KDA_OUT and SSD_OUT name the kernels' results: a block that keeps it
-# (layer_fn's `keep_mlp(n)`) computes the named products once a layer and
+# (layer_fn's `keeping(n)`) computes the named products once a layer and
 # step.
 MLP_OUT = "mlp_out"
+# The name of what a delta-rule or state-space mixer's `silu_conv` filters
+# read (the projections' products: the filter's backward reads its input)
+# and of what they write (the unit norms' backward and `kda_bwd` read the
+# filter's result): `_filtered`.
+MIXER_OUT = "mixer_out"
+# What `layer_fn(..).keeping(n)` keeps of a layer through the remat beside
+# its input and its kernels' named results: rungs 1..n, in this order, each
+# in every layer that has it (`products_kept` reckons n).
+LADDER = ("up x", "gate x", "what the mixer's filters read",
+          "what they write")
 
 # GPTConfig.gate_activation and ExpertForm.activation: relu's derivative at
 # 0 is 0 (jax.nn.relu's), and so is relu2's, relu(.)^2.
@@ -350,8 +362,9 @@ class GPTConfig:
     #   paths name nothing to keep: the whole layer is recomputed there.
     #   Where a train step's builder reports the devices' memory
     #   (parallel/memory.py) every layer's MLP also keeps `up x`, or both
-    #   its matmul results, if they are reckoned to fit
-    #   (`mlp_products_kept`).
+    #   its matmul results, and a delta-rule or state-space mixer what its
+    #   filters read and write, as far as they are reckoned to fit
+    #   (`products_kept`, the rungs of `LADDER`).
     # "none": save everything (max HBM, min FLOPs)
     remat_policy: str = "full"
     attention: str = "flash"          # flash | reference | ring
@@ -1369,7 +1382,21 @@ def _conv_block(m, x, cfg: GPTConfig, where: Setting):
             jnp.einsum("bsd,de->bse", y, m["w_out"].astype(dt)))
 
 
-def _kda_block(m, x, cfg: GPTConfig, where: Setting):
+def _filtered(conv, product, *taps, named: int = 0):
+    """silu(filter(product)) as `conv` runs it (ops/short_conv.py:silu_conv
+    on a shard's columns), for a projection's product [B, S, columns].
+    named: how many of the filter's two operands-to-be carry the name
+    MIXER_OUT, what it reads first (its backward's residual, so that the
+    projection's matmul is not run again) and what it writes second (so that
+    the filter is not: the mixer's norms and its kernel's backward read the
+    result); layer_fn's keeping blocks, whose remat policy saves the name.
+    The values are the same either way."""
+    y = conv(checkpoint_name(product, MIXER_OUT) if named > 0 else product,
+             *taps)
+    return checkpoint_name(y, MIXER_OUT) if named > 1 else y
+
+
+def _kda_block(m, x, cfg: GPTConfig, where: Setting, named: int = 0):
     """Gated delta-rule linear attention in attention's place (KDA, or the
     layer cfg.delta describes; the recurrence and its chunked form:
     ops/linear_attention.py). From the block's input x (normed, or under
@@ -1407,15 +1434,19 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
     exists, which on the chip is a relayout pass a tensor and direction.
     At any other width (96 / 192 inside one [q | k | v] filter) the tensors
     are turned by head after the filter, [B, H, S, w], padded inside `kda`,
-    and o is turned back under the gated norm."""
+    and o is turned back under the gated norm.
+
+    named: how many of each filter's operands carry MIXER_OUT (`_filtered`:
+    0, 1 what it reads, 2 what it writes too)."""
     dt, f32 = cfg.dtype, jnp.float32
     b, s, _ = x.shape
     size = cfg.delta_rule
     dk, dv = size.key_dim, size.value_dim
     stay = by_token(dk, dv)     # heads of whole lane tiles: by token all along
     columns = ("batch", None, "heads")
-    conv = _per_shard(silu_conv, where.mesh, (columns, ("heads", None)),
-                      columns)
+    conv = partial(_filtered, _per_shard(
+        silu_conv, where.mesh, (columns, ("heads", None)), columns),
+        named=named)
 
     def placed(y, width):
         """A tensor of the heads' columns where `kda` takes it: [B, S, H w]
@@ -1511,7 +1542,7 @@ def _kda_block(m, x, cfg: GPTConfig, where: Setting):
                                      m["wo"].astype(dt))), stats
 
 
-def _ssm_block(m, x, cfg: GPTConfig, where: Setting):
+def _ssm_block(m, x, cfg: GPTConfig, where: Setting, named: int = 0):
     """A state-space mixer in attention's place (Mamba-2; the recurrence
     and its chunked form: ops/state_space.py). From the normed input x, with
     H heads of P channels and G groups of N (cfg.ssm):
@@ -1529,12 +1560,14 @@ def _ssm_block(m, x, cfg: GPTConfig, where: Setting):
     The filter, the scan and the norm work on a head's or a group's own
     columns, so column-parallel projections and a row-parallel w_out leave
     them local to a shard of 'tensor' that holds whole groups. Scope `ssm`
-    holds the layer; `ssm_core`, nested, the scan alone."""
+    holds the layer; `ssm_core`, nested, the scan alone. named: how many of
+    the filter's operands carry MIXER_OUT (`_filtered`)."""
     dt_, f32, size = cfg.dtype, jnp.float32, cfg.ssm
     b, s, _ = x.shape
     columns = ("batch", None, "heads")
-    conv = _per_shard(silu_conv, where.mesh,
-                      (columns, ("heads", None), ("heads",)), columns)
+    conv = partial(_filtered, _per_shard(
+        silu_conv, where.mesh, (columns, ("heads", None), ("heads",)),
+        columns), named=named)
     with jax.named_scope("ssm"):
         z = jnp.einsum("bsd,de->bse", x, m["w_z"].astype(dt_))
         xbc = conv(jnp.einsum("bsd,de->bse", x, m["w_xbc"].astype(dt_)),
@@ -1878,13 +1911,20 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     parallel/pipeline.py scans over stacked ones. Under "full" it is ONE
     body under a policy: the block returned keeps a layer's input and its
     kernels' named results and computes every XLA matmul of the layer
-    again in the backward pass; its attribute `keep_mlp(n)` gives the
-    block that also keeps n of the MLP's matmul results (MLP_OUT,
-    [B, S, d_ff] each: up x, then gate x), so that the backward pass reads
-    them where it would compute them again (0: the block itself). Who
+    again in the backward pass; its attribute `keeping(n)` gives the
+    block that also keeps the first n rungs of `LADDER`, five choices in
+    all, so that the backward pass reads them where it would compute them
+    again (0: the block itself): 1 and 2, the MLP's matmul results
+    (MLP_OUT, [B, S, d_ff] each: up x, then gate x); 3, what a delta-rule
+    or state-space mixer's `silu_conv` filters read, the projections'
+    products (MIXER_OUT: x wq, x wk, x wv or the one x w_qkv; x w_xbc);
+    4, what those filters write too, so that the backward pass neither
+    multiplies nor filters again. A layer keeps the rungs it has: one
+    without such a mixer (a latent or attention layer) its MLP's products
+    and nothing more at 3 and 4. Who
     walks the layers runs ONE block for all of them, so that a step traces
     as many kinds of layer as it did, with n reckoned from the devices'
-    memory (`mlp_products_kept`). Under "none" every block is the bare
+    memory (`products_kept`). Under "none" every block is the bare
     body. Under cfg.route_from
     "input" a sparse layer's routing (`_routing`) is worked out from the
     normed INPUT under scope `route_ahead`, before the mixer, and handed
@@ -1918,7 +1958,9 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
     def norm(name, y, layer):
         return _rmsnorm(y, layer[name]["scale"], cfg.rmsnorm_eps)
 
-    def block(x, layer, named_mlp=0):
+    def block(x, layer, kept=0):
+        # (kept: the rungs of LADDER whose products carry their names)
+        named_mlp, named_mixer = min(kept, 2), max(kept - 2, 0)
         # (under norm_after a half reads the stream itself and its norm
         # sits on what it adds, where every shard of 'tensor' has the sum;
         # under "both" a half reads its own norm of the stream and a second
@@ -1933,9 +1975,11 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         if "conv" in layer:
             mixed = _conv_block(layer["conv"], normed, cfg, where)
         elif "kda" in layer:
-            mixed, mixer_stats = _kda_block(layer["kda"], normed, cfg, where)
+            mixed, mixer_stats = _kda_block(layer["kda"], normed, cfg, where,
+                                            named_mixer)
         elif "ssm" in layer:
-            mixed, mixer_stats = _ssm_block(layer["ssm"], normed, cfg, where)
+            mixed, mixer_stats = _ssm_block(layer["ssm"], normed, cfg, where,
+                                            named_mixer)
         elif _GROUP["window"] in layer or _GROUP["attention"] in layer:
             kind = "window" if _GROUP["window"] in layer else "attention"
             mixed, mixer_stats = _attention_block(
@@ -1969,25 +2013,24 @@ def layer_fn(cfg: GPTConfig, seq: int, where: Setting):
         # over the score tiles then runs once a layer and step; of a
         # delta-rule layer, its output, its chunks' states and their A,
         # Aqk and inverse: `kda_bwd` reads them; of a state-space layer,
-        # its output and states: the scan over the chunks runs once)
-        names = (FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK,
-                 indexer.INDEX_GRADS, KDA_OUT, SSD_OUT)
-        save = jax.checkpoint_policies.save_only_these_names
-        recompute = jax.checkpoint(block, policy=save(*names))
+        # its output and states: the scan over the chunks runs once; of
+        # LADDER's products, those a keeping block names: they carry their
+        # names in no other, so a step that keeps none is the text it was)
+        policy = jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT, FLASH_LSE, indexer.INDEX_MASK, indexer.INDEX_GRADS,
+            KDA_OUT, SSD_OUT, MLP_OUT, MIXER_OUT)
+        recompute = jax.checkpoint(block, policy=policy)
 
         @lru_cache(maxsize=None)
-        def keep_mlp(n):
-            # (the MLP's products carry their name in a block that keeps
-            # them and in no other: a step that keeps none is the text it
-            # was)
+        def keeping(n):
             return recompute if n == 0 else jax.checkpoint(
-                partial(block, named_mlp=n), policy=save(*names, MLP_OUT))
-        recompute.keep_mlp = keep_mlp
+                partial(block, kept=n), policy=policy)
+        recompute.keeping = keeping
         return recompute
     if cfg.remat_policy != "none":
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r} "
                          "(expected 'full' | 'none')")
-    block.keep_mlp = lambda n: block
+    block.keeping = lambda n: block
     return block
 
 
@@ -2015,11 +2058,14 @@ def _layer_bytes(layer, batch: int, seq: int, cfg: GPTConfig,
     what its mixer's kernels name: the flash kernels' output and row
     statistics, an indexer's selection and gradients, a delta-rule or
     state-space layer's output and chunk states, a delta-rule layer's
-    kept matrices), and what `keep_mlp(n)`
-    keeps more, n = 0, 1, 2 (n of the MLP's matmul results, as many as it
-    has; 0 where the layer has no `_mlp_block`: `_mlp_of`). From the shapes alone;
-    tests/test_mlp_kept.py holds both to what jax.checkpoint saves of each
-    family's layers."""
+    kept matrices), and what `keeping(n)` keeps more, n = 0 .. 4, the five
+    choices of `LADDER`: 1 and 2, of the MLP's matmul results as many as it
+    has (none where the layer has no `_mlp_block`: `_mlp_of`); 3, what the
+    filters of a delta-rule or state-space mixer read beside them, the
+    columns of wq, wk, wv (or w_qkv), or of w_xbc, a token; 4, as many
+    again, what they write (a layer with no such mixer keeps at 3 and 4
+    what it keeps at 2). From the shapes alone; tests/test_mlp_kept.py
+    holds both to what jax.checkpoint saves of each family's layers."""
     batch, heads_over = _a_devices_part(batch, seq, cfg, where)
     tokens, item = batch * seq, jnp.dtype(cfg.dtype).itemsize
     named = 0
@@ -2051,8 +2097,13 @@ def _layer_bytes(layer, batch: int, seq: int, cfg: GPTConfig,
     if m is not None:
         one = tokens * m["w_up"].shape[-1] * item // heads_over
         has = len(m) - 1
+    mixer = layer.get("kda", layer.get("ssm", {}))
+    filtered = tokens * item * sum(
+        mixer[w].shape[-1] for w in ("w_qkv", "wq", "wk", "wv", "w_xbc")
+        if w in mixer) // heads_over
+    mlp = min(2, has) * one
     return (tokens * cfg.d_model * item + named // heads_over,
-            tuple(min(n, has) * one for n in range(3)))
+            (0, min(1, has) * one, mlp, mlp + filtered, mlp + 2 * filtered))
 
 
 def _working_set(layers, batch: int, seq: int, cfg: GPTConfig,
@@ -2063,23 +2114,25 @@ def _working_set(layers, batch: int, seq: int, cfg: GPTConfig,
     tokens a device is given its 3 GB and one of 8192 its 1.2: a token's
     rows of the widest layer with their cotangents (the MLP's and the
     chosen experts' hidden rows; the mixer's q, k, v and output, an
-    indexer's scores over the sequence), beside the residual stream's
+    indexer's scores over the sequence; a delta-rule layer's float32
+    tensors a column, once each), beside the residual stream's
     float32 copies; or the head's chunk of logits. The factors are whole
     numbers under which the reckoned peak of every cell's step is at or
-    over what the chip read (PERF.md section 6, PR 63)."""
+    over what the chip read (PERF.md section 6, PRs 63 and 72)."""
     batch, heads_over = _a_devices_part(batch, seq, cfg, where)
     tokens, item = batch * seq, jnp.dtype(cfg.dtype).itemsize
 
     def rows(layer):
         """The widths a token's activations take in `layer`: the
-        feed-forward's hidden rows, the mixer's."""
+        feed-forward's hidden rows, the mixer's, and the float32 columns
+        the mixer holds beside them."""
         m = _mlp_of(layer)
         hidden = 0 if m is None else m["w_up"].shape[-1]
         if "moe" in layer:
             # (of a token's chosen experts, at most those held here)
             held, _, width = layer["moe"]["w_up"].shape
             hidden += min(cfg.expert_top_k, held) * width
-        mixer = 0
+        mixer = floats = 0
         for kind, group in _GROUP.items():
             if group in layer:
                 wide = cfg.qk_head_dim + qk_padding(cfg.qk_head_dim)
@@ -2089,17 +2142,28 @@ def _working_set(layers, batch: int, seq: int, cfg: GPTConfig,
                 if "index" in layer[group]:
                     mixer += 2 * seq            # float32 scores and their KL
         if "kda" in layer:
-            mixer = cfg.n_heads * (cfg.delta_rule.key_dim
-                                   + cfg.delta_rule.value_dim)
+            size = cfg.delta_rule
+            keys, values = (cfg.n_heads * w
+                            for w in (size.key_dim, size.value_dim))
+            mixer = keys + values
+            # what the recomputed layer holds in float32 while its
+            # feed-forward is differentiated (kimi's compiled step, PERF.md
+            # section 6, PR 72): the gate's pre-activation, the log-decay
+            # and its pre-activation (a channel's or a head's) and, by
+            # token, the three norms' factors spread over their heads'
+            # columns by the membership product
+            floats = values + 2 * layer["kda"]["dt_bias"].shape[-1]
+            if by_token(size.key_dim, size.value_dim):
+                floats += 2 * keys + values
         if "ssm" in layer:
             mixer = layer["ssm"]["w_xbc"].shape[-1]
         if "conv" in layer:
             mixer = 3 * cfg.d_model
-        return hidden, mixer
+        return hidden, mixer, floats
 
-    widest = max(_MLP_ROWS * hidden + _MIXER_ROWS * mixer
-                 for hidden, mixer in map(rows, layers))
-    layer = tokens * (widest * item // heads_over
+    widest = max((_MLP_ROWS * hidden + _MIXER_ROWS * mixer) * item
+                 + floats * 4 for hidden, mixer, floats in map(rows, layers))
+    layer = tokens * (widest // heads_over
                       + _STREAM_COPIES * cfg.d_model * 4)
     # (chunked_xent's default rows a chunk)
     head = _rows_a_chunk(tokens, 16384) * cfg.vocab_size * item \
@@ -2209,7 +2273,7 @@ def _stack(params, tokens, cfg: GPTConfig, where: Setting):
     """tokens [B, S] through the embedding and the layers -> (the residual
     stream BEFORE the final norm, the statistics of each layer that has
     any, the block the layers ran: layer_fn's, keeping as many of the
-    MLPs' matmul results through the remat as `mlp_products_kept` reckons,
+    layers' products through the remat as `products_kept` reckons,
     for a caller that runs further layers at this sequence length).
 
     A looped stack (cfg.loop) runs the layers `passes` times over the same
@@ -2220,8 +2284,8 @@ def _stack(params, tokens, cfg: GPTConfig, where: Setting):
     in the stream's place, each statistic averaged over the passes."""
     with jax.named_scope("embed"):
         x = where.pin(_embed(params, tokens, cfg, where))
-    layer = layer_fn(cfg, tokens.shape[1], where).keep_mlp(
-        mlp_products_kept(params, *tokens.shape, cfg, where))
+    layer = layer_fn(cfg, tokens.shape[1], where).keeping(
+        products_kept(params, *tokens.shape, cfg, where))
     if cfg.loop is None:
         x, per_layer = _walk(layer, x, params["layers"])
         return x, per_layer, layer
@@ -2259,7 +2323,7 @@ def memory_plan(params, batch: int, seq: int, cfg: GPTConfig,
     layers' (of every parameter outside them but the embedding's table,
     whose gradient comes last unless the head reads the table too), each
     layer's gradient, the embedding's, what each layer keeps through the
-    remat keeping 0, 1 and 2 of its MLP's products (three lists), the
+    remat keeping the first 0 .. 4 rungs of `LADDER` (five lists), the
     working set of one layer and of the head; the prediction module's
     layers after the stack's. share: the part of the parameters' bytes
     that a device holds. What a layer keeps is of ONE application of it: a
@@ -2271,7 +2335,8 @@ def memory_plan(params, batch: int, seq: int, cfg: GPTConfig,
              else int(share * memory.tree_bytes(params["embed"])))
     kept, products = zip(*(_layer_bytes(layer, batch, seq, cfg, where)
                            for layer in layers))
-    held = [[k + p[n] for k, p in zip(kept, products)] for n in range(3)]
+    held = [[k + p[n] for k, p in zip(kept, products)]
+            for n in range(len(LADDER) + 1)]
     working, head = _working_set(layers, batch, seq, cfg, where)
     if cfg.loop is not None:
         # what the loop itself keeps once a pass: the normed stream it
@@ -2288,10 +2353,14 @@ def memory_plan(params, batch: int, seq: int, cfg: GPTConfig,
             grads, after, held, working, head)
 
 
-def mlp_products_kept(params, batch: int, seq: int, cfg: GPTConfig,
-                      where: Setting) -> int:
-    """How many of its matmul results every layer's MLP keeps through the
-    remat (layer_fn's `keep_mlp(n)`): 0, 1 (up x) or 2 (gate x too):
+def products_kept(params, batch: int, seq: int, cfg: GPTConfig,
+                  where: Setting) -> int:
+    """How many rungs of `LADDER` every layer keeps through the remat
+    (layer_fn's `keeping(n)`), five choices: 0, 1 (its MLP's up x), 2 (gate
+    x too), 3 (what a delta-rule or state-space mixer's filters read), 4
+    (what they write too); a layer keeps the rungs it has, and the answer
+    is the lowest rung that keeps as much as the one reckoned to fit (2, not
+    4, in a stack without such a mixer):
     observed, not set. The most that memory.reckoned_peak puts under
     memory.CEILING of the devices' limit beside the state that the step's
     builder reports (memory.budget: train/train_step.py gives it around
@@ -2322,9 +2391,10 @@ def mlp_products_kept(params, batch: int, seq: int, cfg: GPTConfig,
         # looped stack keeps nothing more until a layout holds them.
         n = 0
     while n and sum(held[n]) == sum(held[n - 1]):
-        n -= 1                       # no gate, or no MLP at all: no more kept
+        n -= 1            # no gate, no such mixer, no MLP at all: no more kept
     having = sum(h2 > h0 for h0, h2 in zip(held[0], held[2]))
-    memory.report(n, having, passes * (sum(held[n]) - sum(held[0])),
+    mixers = sum(h > h2 for h2, h in zip(held[2], held[n]))
+    memory.report(n, having, mixers, passes * (sum(held[n]) - sum(held[0])),
                   peaks[n], told.limit, passes)
     return n
 

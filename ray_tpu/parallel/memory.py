@@ -5,9 +5,11 @@ model it differentiates cannot see: the devices, their memory limit, and the
 bytes a device holds across the step (parameters and optimizer state, as their
 shardings cut them). It says so around the trace (`told`), and a model that
 can spend spare memory on less recomputation asks (`budget`) and reckons
-(`reckoned_peak`, `most_kept`): models/gpt.py keeps its MLPs' matmul
-results through the per-layer remat where the reckoned peak allows. Nothing here
-is set by a caller: no budget (a loss differentiated by hand, a platform
+(`reckoned_peak`, `most_kept`): models/gpt.py keeps the first rungs of a
+layer's products through the per-layer remat where the reckoned peak allows,
+five choices (gpt.LADDER: nothing more, its MLPs' `up x`, `gate x` too, what
+a delta-rule or state-space mixer's filters read, what they write too).
+Nothing here is set by a caller: no budget (a loss differentiated by hand, a platform
 that reports no limit, as the CPU) means nothing more is kept.
 
 The reckoning is a walk over the backward pass, not one sum. A layer's kept
@@ -152,29 +154,39 @@ def most_kept(limit: Optional[int], peaks: Sequence[int],
 _KEPT = metrics.Gauge(
     "ray_tpu_train_mlp_kept_layers",
     "set when a train step is traced (parallel/memory.py): What=products, "
-    "how many of its matmul results an MLP keeps through the remat (0, 1: "
-    "up x, 2: gate x too), What=kept, the layers that keep them, What=of, "
-    "the layers that have such an MLP, What=bytes, what is kept so on a "
-    "device, What=peak_bytes, the peak reckoned for a device at that choice",
+    "the rung of its products a layer keeps through the remat (0, 1: its "
+    "MLP's up x, 2: gate x too, 3: what a delta-rule or state-space mixer's "
+    "filters read, 4: what they write too), What=kept, the layers whose MLP "
+    "keeps something, What=of, the layers that have such an MLP, "
+    "What=mixer_kept, the layers whose mixer keeps something, What=bytes, "
+    "what is kept so on a device, What=peak_bytes, the peak reckoned for a "
+    "device at that choice",
     tag_keys=("What",))
 
 
-def report(products: int, of: int, kept_bytes: int, peak: int,
+def report(products: int, of: int, mixers: int, kept_bytes: int, peak: int,
            limit: Optional[int], passes: int = 1) -> None:
     """A traced step says what it keeps: the gauge
-    ray_tpu_train_mlp_kept_layers (What=products | kept | of | bytes |
-    peak_bytes) in this process's registry, and a line of the log; every
-    one of the `of` layers that have an MLP keeps `products` of its
-    products. A step that keeps nothing says so too. passes > 1: a looped
-    stack, whose line says how often a layer's bytes are kept and that its
-    gradients are held across the loop."""
+    ray_tpu_train_mlp_kept_layers (What=products | kept | of | mixer_kept |
+    bytes | peak_bytes) in this process's registry, and a line of the log.
+    products: the rung (0 .. 4); every one of the `of` layers that have an
+    MLP keeps min(products, 2) of its products, and `mixers` layers' mixers
+    what their filters read (rung 3) and write (4). A step that keeps
+    nothing says so too. passes > 1: a looped stack, whose line says how
+    often a layer's bytes are kept and that its gradients are held across
+    the loop."""
     kept = of if products else 0
     for what, value in (("products", products), ("kept", kept), ("of", of),
-                        ("bytes", kept_bytes), ("peak_bytes", peak)):
+                        ("mixer_kept", mixers), ("bytes", kept_bytes),
+                        ("peak_bytes", peak)):
         _KEPT.set(value, tags={"What": what})
     logger.info(
-        "mlp_kept_layers %d of %d, %d of an MLP's products each (%.2f GB a "
-        "device kept; reckoned peak %.2f GB of %s)%s", kept, of, products,
+        "mlp_kept_layers %d of %d, %d of an MLP's products each%s (%.2f GB a "
+        "device kept; reckoned peak %.2f GB of %s)%s", kept, of,
+        min(products, 2),
+        "" if products < 3 else
+        f", rung {products}: {mixers} layers' mixers keep what their filters "
+        "read" + (" and write" if products > 3 else ""),
         kept_bytes / 1e9, peak / 1e9,
         "no limit reported" if limit is None else f"{limit / 1e9:.2f}",
         "" if passes == 1 else

@@ -22,8 +22,10 @@ body is told to psum over 'tensor').
 Memory: stage activations are carried through the scan (GPipe-style full
 activation footprint / num_microbatches granularity); per-layer remat
 (cfg.remat_policy) bounds the within-stage footprint, and where the devices
-are reckoned to hold them (models/gpt.py:mlp_products_kept) every layer
-also keeps its MLP's matmul results through it.
+are reckoned to hold them (models/gpt.py:products_kept) every layer
+also keeps the first rungs of its products through it (gpt.LADDER, five
+choices: nothing more, its MLP's up x, gate x too, what a delta-rule or
+state-space mixer's filters read, what they write too).
 """
 
 from __future__ import annotations
@@ -38,7 +40,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.models.gpt import (GPTConfig, Setting, final_norm, gpt_init,
                                 head_xent_recompute, layer_fn,
-                                mlp_products_kept)
+                                products_kept)
 from ray_tpu.parallel.sharding import (MESH_AXES, ShardingStrategy,
                                        _path_str)
 
@@ -165,7 +167,7 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
             "carries no period of kinds, head counts or rope tables) and "
             "no rule for a gate a head (attn/wg)")
 
-    def body(keep_mlp, params, inputs, targets):
+    def body(kept, params, inputs, targets):
         # Per-device blocks: params["stacked"] [L/S, ...] (+tensor-sharded
         # matrices), inputs/targets [B/data, S].
         embed_tbl = params["embed"]["table"]
@@ -177,7 +179,7 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
                              f"microbatches {M}")
         inputs_mb = inputs.reshape(M, mb, s)
         targets_mb = targets.reshape(M, mb, s)
-        layer = layer_fn(cfg, s, where).keep_mlp(keep_mlp)
+        layer = layer_fn(cfg, s, where).keeping(kept)
 
         n_ticks = M + n_stages - 1
 
@@ -234,13 +236,13 @@ def make_gpt_pp_loss(cfg: GPTConfig, mesh: Mesh, num_microbatches: int):
         like = {**{k: v for k, v in pp_params.items() if k != "stacked"},
                 "layers": [one] * (cfg.n_layers // n_stages
                                    * (M + n_stages - 1))}
-        keep_mlp = mlp_products_kept(
+        kept = products_kept(
             like, tokens.shape[0] // (mesh.shape["data"] * M),
             tokens.shape[1] - 1, cfg, Setting())
         # check_vma off: the body mixes collectives manually, with
         # per-rank lax.cond branches the replication check rejects.
         fn = shard_map(
-            partial(body, keep_mlp), mesh=mesh,
+            partial(body, kept), mesh=mesh,
             in_specs=(param_specs, P("data"), P("data")),
             out_specs=P(), check_vma=False)
         return fn(pp_params, tokens[:, :-1], tokens[:, 1:])

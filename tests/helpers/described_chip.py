@@ -282,7 +282,7 @@ def cell_step(v5e, family):
 def a_step_keeps_up_x(step, layers):
     """Of a cell with much to gain, its whole step compiled for one
     described chip as the chip runs it (CellStep at limit=V5E_BYTES: the
-    builder reads a v5e's limit): `mlp_products_kept` keeps `up x` in every
+    builder reads a v5e's limit): `products_kept` keeps `up x` in every
     layer's MLP (both products are reckoned over the ceiling); of the gate
     and up products ONE a layer stands a second time in the entry
     computation's backward pass (`rematted_computation` in its op_name)
@@ -291,7 +291,7 @@ def a_step_keeps_up_x(step, layers):
     peak; and what is kept is in the temporaries (the step that keeps
     nothing more holds that much less: 12.42 GB at granite for 13.63)."""
     from ray_tpu.parallel import memory
-    products, of, kept_bytes, peak, limit, _passes = step.kept
+    products, of, _mixers, kept_bytes, peak, limit, _passes = step.kept
     assert (products, of, limit) == (1, layers, V5E_BYTES)
     entry = step.text[step.text.index("\nENTRY "):].splitlines()
     again = [at for at, line in enumerate(entry) if re.search(

@@ -23,7 +23,7 @@ LOWERED = {
     # PR 63 lowers every cell as the chip runs it (the builder reads a
     # v5e's memory limit: see the test) and means to change exactly the
     # five cells whose MLPs then keep matmul results through the remat
-    # (`MLP_OUT`; models/gpt.py:mlp_products_kept): `up x` in every layer
+    # (`MLP_OUT`; models/gpt.py:products_kept): `up x` in every layer
     # at gpt2s (301b04a77398ad85 before it) and granite
     # (4ea5a3064d7f262b), both products at lfm2 (fe8bdb7954ee2c8e), kanana
     # (8e6cd298cd0a8c16) and laguna (e425c199e1b22258). The other six are
@@ -118,8 +118,18 @@ LOWERED = {
     # the parent's): four `kda` layers of 32 heads beside a latent layer
     # whose four layout kernels are handed no table (nothing is rotated and
     # no table is built), the first layer a `kda` mixer over the dense MLP,
-    # both products of every MLP kept through the remat
-    "kimilinear_train_1chip": "93d3b120f5a4eedf",
+    # both products of every MLP kept through the remat.
+    # Recorded anew by PR 72, which means to change exactly this one
+    # (93d3b120f5a4eedf since PR 68 before it): the ladder of what a layer
+    # keeps through the remat goes past the MLP's products
+    # (models/gpt.py:LADDER), and on a v5e kimi's step is reckoned to hold
+    # its top rung, so the four `kda` layers' x wq, x wk and x wv and the
+    # three filters' results carry MIXER_OUT and the backward pass neither
+    # multiplies nor filters them again. The other thirteen stop at the rung
+    # they stopped at (granite, gpt2s and olmohybrid at 1, where `gate x`
+    # does not fit; solar and nemotron over the ceiling at 0) or have no
+    # such mixer, and their lines are the parent's.
+    "kimilinear_train_1chip": "d134dd2cc6a86574",
     # new with PR 67, which leaves the twelve above alone (their lines are
     # the parent's: a configuration without `GPTConfig.delta` and
     # `norm_after` traces the block and the delta rule as it did): three
@@ -160,7 +170,7 @@ def test_the_cells_that_were_there_lower_to_the_same_step(jax_cpu,
     monkeypatch.setattr(attention, "_default_interpret", lambda: False)
     # the step as the chip runs it: the builder reads a v5e's memory limit
     # (the CPU reports none), so a cell that keeps its MLPs' products
-    # through the remat (models/gpt.py:mlp_layers_kept) lowers with them
+    # through the remat (models/gpt.py:products_kept) lowers with them
     monkeypatch.setattr(memory, "device_limit", lambda devices: V5E_BYTES)
     bench = read("BENCHMARK.json")
     entry = next(w for w in bench["workloads"] if w["name"] == cell)

@@ -27,7 +27,7 @@ def test_the_loop_is_one_body_and_the_head_one_call(cell_step):
         assert not kernel_ops(entry, kernel), kernel
     assert len(re.findall(r" while\(", entry)) == 3
     assert not re.findall(r"%[\w.\-]*\.remat[\w.\-]* = ", text)
-    products, of, kept_bytes, peak, limit, passes = cell_step.kept
+    products, of, _mixers, kept_bytes, peak, limit, passes = cell_step.kept
     assert (passes, products, of, kept_bytes) == (4, 0, 8, 0)
     state = cell_step.memory.argument_size_in_bytes
     assert state + 8 * 51_388_416 * 4 < peak < 0.88 * limit
