@@ -19,11 +19,15 @@ logits, loss and gradients in one program, read by the float32 check AND, at
 `flash`, by the sharded step as its one-device twin: `flash_twin`, params -
 0.1 gradients, so no one-device step is compiled unless the family's mesh
 needs another configuration than its tiny one), the sharded step, the bf16
-check's one program, the scopes' compile, and ONE rehearsal of the cell in a
-subprocess, traced (`--trace 1`: the untraced run is a subset of it, and
+check's one program, and ONE rehearsal of the cell in a subprocess, traced (`--trace 1`: the untraced run is a subset of it, and
 kanana's own case keeps both). The kernels file: the cell's whole step for
-the described chip, once (`cell_step`, helpers/described_chip.py), at the
-limit the family says (`cell_limit`) and read by every check of that file.
+the described chip, lowered and compiled once (`cell_step`,
+helpers/described_chip.py:CellStep, the one builder of a cell's step under
+tests/), as the chip runs it, and read by every check of that file: its
+memory, kernel calls and layout, the text tests/test_lowered_steps.py
+records, the family's scopes among its op names (until PR 73 a compile of
+the tiny step's gradient on the CPU, in the model file) and a share's sparse
+blocks (until PR 73 a compile of one block alone).
 tests/conftest.py runs the files that take `cell_step` first and the
 families' other files next, by what it collects: no list names them.
 
@@ -35,7 +39,6 @@ import copy
 import importlib
 import json
 import os
-import re
 import subprocess
 import sys
 
@@ -138,26 +141,35 @@ class Family:
     pipeline_refusals = ()
 
     # tests/helpers/described_chip.py: the cell's whole step for one
-    # described chip ({kernel: calls}, (low, high) share of 16.91 GB, marks
-    # of the case, the memory limit its builder is told: None, a described
-    # device's own, or V5E_BYTES, the chip's) and a share's sparse block
-    # ((rows a tile, tiles of the bounded row space, tiles for every slot))
+    # described chip, as the chip runs it ({kernel: calls}; (low, high)
+    # share of 16.91 GB; the rung of models/gpt.py:LADDER every layer keeps;
+    # the GB by which the builder's reckoned peak stands (under compiled +
+    # overhead, over its own ceiling) where `the_reckoning_holds` does not
+    # hold, each with its reason in the family's row) and a share's sparse
+    # block ((rows a tile, tiles of the bounded row space, tiles for every
+    # slot))
     cell_kernel_calls = None
     cell_memory_share = None
-    cell_step_marks = ()
-    cell_limit = None
+    cell_rung = 0
+    cell_reckoned = (0.0, 0.0)
     row_spaces = None
 
     @property
     def cases(self):
         """argument name -> the cases of the shared checks that take it
-        (the two described-chip checks take the cell's name as their one
-        case: the id they had in tests/test_chip_compile.py)."""
+        (the described-chip checks take the cell's configuration as their
+        one case, the id they had in tests/test_chip_compile.py, and the
+        recorded-text check the cell's name, the id it had in
+        tests/test_lowered_steps.py). Either may be the first to read the
+        file's one whole step, so each has the limit a whole-step compile
+        needs: 100-150 s in a whole run, of conftest.py's 180."""
+        whole_step = pytest.mark.timeout(600)
         return {
             "attention": self.attentions,
             "refusal": self.refusals,
             "pipeline_refusal": self.pipeline_refusals,
-            "cell": [pytest.param(self.cell, marks=self.cell_step_marks)],
+            "cell": [pytest.param(self.cell, marks=whole_step)],
+            "lowered": [pytest.param(self.workload, marks=whole_step)],
             "sparse_cell": [self.cell],
         }
 
@@ -267,11 +279,10 @@ class Family:
         "tensor"), row ("tensor", None)) and tp_fsdp."""
         raise NotImplementedError
 
-    def scopes_config(self, tiny):
-        return self.module._train_config(tiny)
-
     def scopes(self, names, regions):
-        """The new scopes among the compiled step's op names."""
+        """The new scopes among the op names of the cell's compiled step
+        (helpers/described_chip.py:
+        test_the_new_scopes_are_regions_and_reach_the_compiled_step)."""
         raise NotImplementedError
 
     def cut(self, cell, row, bench):
@@ -482,27 +493,35 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_reference(jax_cpu,
                              remat_policy="none")
     full_cfg = config(whole)
     assert full_cfg.experts_held is None
-    layer = gpt_init(jax.random.PRNGKey(7), full_cfg)["layers"][
-        family.shared_layer]
+    layer = jax.jit(lambda key: gpt_init(key, full_cfg)["layers"][
+        family.shared_layer])(jax.random.PRNGKey(7))
     family.shared_layer_is(layer)
     layer["moe"]["router"] = 0.3 * jax.random.normal(
         jax.random.PRNGKey(8), (128, 16))
     x = jax.random.normal(jax.random.PRNGKey(9), (2, 64, 128), jnp.float32)
 
-    with jax.default_matmul_precision("highest"):
-        alike, want = family.uncut_layer(jax, layer, x, whole)
-        parts, held_share, seen = [], 0.0, []
+    @jax.jit
+    def layers(layer, x):
+        """(the uncut layer's two results, every share's layer and its
+        statistics): one program, where op by op each product, norm and
+        sort was a program of its own (16-35 s a family in a whole run)."""
+        shares = []
         for rank in range(4):
             cfg = config(dict(tiny, share=dict(tiny["share"], rank=rank)))
             assert cfg.experts_held == (4 * rank, 4)
             mine = dict(layer, moe=dict(layer["moe"], **{
                 name: layer["moe"][name][4 * rank:4 * rank + 4]
                 for name in ("w_gate", "w_up", "w_down")}))
-            out, stats = layer_fn(cfg, 64, Setting())(x, mine)
-            # what is the same on every chip, taken off
-            parts.append(out - alike)
-            held_share += float(stats["expert_slots_held_share"])
-            seen.append(stats)
+            shares.append(layer_fn(cfg, 64, Setting())(x, mine))
+        return family.uncut_layer(jax, layer, x, whole), shares
+
+    with jax.default_matmul_precision("highest"):
+        (alike, want), shares = layers(layer, x)
+    # what is the same on every chip, taken off
+    parts = [out - alike for out, _stats in shares]
+    seen = [stats for _out, stats in shares]
+    held_share = sum(float(stats["expert_slots_held_share"])
+                     for stats in seen)
     np.testing.assert_allclose(alike + sum(parts), want, atol=5e-5)
     assert abs(held_share - 1.0) < 1e-6
     family.shares_statistics(seen)
@@ -665,26 +684,6 @@ def step_kernel_calls(jax, family, config):
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: gpt_loss(p, {"tokens": tokens}, cfg)))(params)
     return cfg, Counter(kernel_calls(jax, jaxpr.jaxpr)), jaxpr
-
-
-def compiled_scopes(jax, cfg):
-    """-> (the op names of the compiled gradient of cfg's loss at [2, 129]
-    tokens, the trace's regions they end in)."""
-    import jax.numpy as jnp
-    from ray_tpu.models.gpt import gpt_init, gpt_loss
-    from ray_tpu.util import profiling
-    params = jax.eval_shape(lambda: gpt_init(jax.random.PRNGKey(0), cfg))
-    text = jax.jit(jax.grad(lambda p, t: gpt_loss(p, {"tokens": t}, cfg))
-                   ).lower(params, jax.ShapeDtypeStruct((2, 129), jnp.int32)
-                           ).compile().as_text()
-    names = set(re.findall(r'op_name="([^"]*)"', text))
-    return names, {profiling._last_of(n, profiling.REGIONS) for n in names}
-
-
-def test_the_new_scopes_are_regions_and_reach_the_compiled_step(jax_cpu,
-                                                                family, tiny):
-    names, regions = compiled_scopes(jax_cpu, family.scopes_config(tiny))
-    family.scopes(names, regions)
 
 
 def test_configuration_file_keeps_the_catalog_and_states_the_cut(family):

@@ -370,6 +370,17 @@ def test_short_conv_kernels_compile_for_v5e(v5e, backward):
     assert ("short_conv_bwd" if backward else "short_conv_fwd") in text
 
 
+# Imported last: a module's names are collected in the order they are bound,
+# so the chip's compiler gets this file's programs after its own tests have
+# run, at another minute of a run than the other families' files.
+from helpers.described_chip import (  # noqa: E402,F401
+    test_the_cells_that_were_there_lower_to_the_same_step,
+    test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_makes_a_heads_dw_where_its_logits_are,
+    test_sparse_layer_compiles_with_both_row_spaces,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step)
+
+
 @pytest.mark.parametrize("cell", ["lfm2_train_1chip"])
 def test_heads_of_64_reach_wo_without_a_layout_pass(cell_step, cell):
     """The third cell whose heads are 64 wide (32 on 8 at [2, 8192]), held
@@ -385,12 +396,3 @@ def test_heads_of_64_reach_wo_without_a_layout_pass(cell_step, cell):
     assert (cell_step.mix["global_batch"], cell_step.mix["seq"]) == (2, 8192)
     heads_of_64_stay_by_token(cell_step.text, 2, 8192, cfg.n_heads,
                               cfg.kv_heads)
-
-
-# Imported last: a module's names are collected in the order they are bound,
-# so the chip's compiler gets this file's programs after its own tests have
-# run, at another minute of a run than the other families' files.
-from helpers.described_chip import (  # noqa: E402,F401
-    test_cell_step_compiles_under_the_chips_memory,
-    test_cell_step_makes_a_heads_dw_where_its_logits_are,
-    test_sparse_layer_compiles_with_both_row_spaces)

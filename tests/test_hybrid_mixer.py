@@ -67,15 +67,17 @@ def test_scan_compiles_at_8192_positions_of_64_heads_on_one_group(
 
 
 from helpers.described_chip import (  # noqa: E402,F401
+    test_the_cells_that_were_there_lower_to_the_same_step,
     test_cell_step_compiles_under_the_chips_memory,
-    test_cell_step_makes_a_heads_dw_where_its_logits_are)
+    test_cell_step_makes_a_heads_dw_where_its_logits_are,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step)
 
 
 @pytest.mark.parametrize("name,layers", [("granite-4.0-h-micro", 10)])
 def test_a_step_keeps_up_x_under_the_chips_memory(cell_step,  # noqa: F811
                                                   name, layers):
-    """The file's one compiled step is the one the chip runs (FAMILY.
-    cell_limit), so it is read here too (until PR 70 a case of
+    """The file's one compiled step is the one the chip runs, as every
+    family's is, so it is read here too (until PR 70 a case of
     tests/test_mlp_kept.py, which compiled the cell a second time)."""
     assert name == FAMILY.cell
     a_step_keeps_up_x(cell_step, layers)

@@ -14,7 +14,6 @@ import re
 import numpy as np
 import pytest
 
-from helpers.described_chip import V5E_BYTES
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
     Family, case, family, programmed, read, reference, seeded,
     step_kernel_calls, steps_agree,
@@ -26,7 +25,6 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_pipeline_refuses_by_name,
     test_sharded_step_equals_one_device, test_the_cell_rehearses,
     test_the_configuration_refuses_by_name,
-    test_the_new_scopes_are_regions_and_reach_the_compiled_step,
     test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart, tiny)
 
@@ -179,9 +177,6 @@ class GraniteHybrid(Family):
         case(({}, {"pipeline": 2}, "no state-space layer's state"), "state"),
     ]
 
-    def scopes_config(self, tiny):
-        return self.module._train_config(dict(
-            tiny, num_hidden_layers=2, layer_types=["mamba", "attention"]))
 
     def scopes(self, names, regions):
         from ray_tpu.util import profiling
@@ -236,8 +231,8 @@ class GraniteHybrid(Family):
     # filter call over 4352 channels (forward + recomputed, backward); one
     # grouped-query layer of 32 on 8 heads of 64 IN PAIRS without a
     # rotation: q, k and v reach the kernels as projected, no `rope_split`
-    # at all; one lookup. Compiled ONCE, as the chip runs it (`cell_limit`:
-    # the builder reads a v5e's limit and keeps `up x` in all ten MLPs, which
+    # at all; one lookup. Compiled ONCE, as the chip runs it (the builder
+    # reads a v5e's limit and keeps `up x` in all ten MLPs, which
     # tests/test_hybrid_mixer.py reads off the same step): 13.63 GB, 9.27 of
     # state (12 B a parameter; the gradient is a temporary) and 4.36 of
     # temporaries, 81 % of the chip; the step that keeps nothing more, which
@@ -248,8 +243,7 @@ class GraniteHybrid(Family):
                          "ssd_bwd": 9, "conv_silu_fwd": 18, "conv_silu_bwd": 9,
                          "embed_grad": 1}
     cell_memory_share = (0.75, 0.87)
-    cell_step_marks = (pytest.mark.timeout(600),)
-    cell_limit = V5E_BYTES
+    cell_rung = 1
 
 
 FAMILY = GraniteHybrid()

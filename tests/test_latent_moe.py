@@ -184,6 +184,17 @@ def test_latent_kernels_compile_for_v5e(v5e, backward):
     assert text.count("tpu_custom_call") >= (4 if backward else 2)
 
 
+# Imported last: a module's names are collected in the order they are bound,
+# so the chip's compiler gets this file's programs after its own tests have
+# run, at another minute of a run than the other families' files.
+from helpers.described_chip import (  # noqa: E402,F401
+    test_the_cells_that_were_there_lower_to_the_same_step,
+    test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_makes_a_heads_dw_where_its_logits_are,
+    test_sparse_layer_compiles_with_both_row_spaces,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step)
+
+
 def test_latent_block_reaches_the_flash_kernels_without_a_layout_pass(
         cell_step):
     """kanana2_train_1chip's five attention blocks, forward and backward,
@@ -221,11 +232,3 @@ def test_latent_block_reaches_the_flash_kernels_without_a_layout_pass(
             assert re.search(r"/latent_(q|kv)_(split|merge)/pallas_call",
                              line), line
         assert not re.search(rf"f32\[{batch},{seq},\d", made), line
-
-# Imported last: a module's names are collected in the order they are bound,
-# so the chip's compiler gets this file's programs after its own tests have
-# run, at another minute of a run than the other families' files.
-from helpers.described_chip import (  # noqa: E402,F401
-    test_cell_step_compiles_under_the_chips_memory,
-    test_cell_step_makes_a_heads_dw_where_its_logits_are,
-    test_sparse_layer_compiles_with_both_row_spaces)

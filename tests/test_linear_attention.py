@@ -671,6 +671,8 @@ def test_delta_rule_layer_compiles_on_four_chip_mesh(v5e, monkeypatch):
 # so the chip's compiler gets this file's programs after its own tests have
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
+    test_the_cells_that_were_there_lower_to_the_same_step,
     test_cell_step_compiles_under_the_chips_memory,
     test_cell_step_keeps_the_delta_rule_by_token,
-    test_cell_step_makes_a_heads_dw_where_its_logits_are)
+    test_cell_step_makes_a_heads_dw_where_its_logits_are,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step)

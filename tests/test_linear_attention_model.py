@@ -22,7 +22,6 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     as test_param_count_is_the_cut_and_the_programs_tree,
     test_sharded_step_equals_one_device,
     test_the_configuration_refuses_by_name,
-    test_the_new_scopes_are_regions_and_reach_the_compiled_step,
     test_the_reference_tells_each_mechanism_apart, tiny)
 
 
@@ -191,8 +190,6 @@ class Solar(Family):
               "'conv' | 'window' | 'kda'"), "kinds_names"),
     ]
 
-    def scopes_config(self, tiny):
-        return self.module._train_config(dict(tiny, num_hidden_layers=2))
 
     def scopes(self, names, regions):
         from ray_tpu.util import profiling
@@ -252,7 +249,11 @@ class Solar(Family):
                          "moe_tgmm": 24, "embed_grad": 1,
                          "conv_silu_fwd": 18, "conv_silu_bwd": 9}
     cell_memory_share = (0.80, 0.93)
-    cell_step_marks = (pytest.mark.timeout(600),)
+    # rung 0 is the floor: the reckoning reads 15.24 GB where its ceiling is
+    # 14.88 and has nothing left to drop; the step compiles to 13.83 (+
+    # OVERHEAD 14.25) and the chip reads 14.08 (83.261 %, ledger PR 72), so
+    # `_working_set` reads this cell ~1 GB high (ROADMAP D29)
+    cell_reckoned = (0.0, 0.36)
 
 
 FAMILY = Solar()

@@ -337,11 +337,12 @@ def test_a_pipeline_stage_keeps_what_it_is_told(jax_cpu, monkeypatch):
 
 
 @pytest.mark.timeout(600)
-@pytest.mark.parametrize("name,layers", [("gpt2s", 12)])
-def test_a_step_keeps_up_x_under_the_chips_memory(v5e, name, layers):  # noqa: F811
+@pytest.mark.parametrize("cell,layers", [
+    pytest.param("gpt2s_train_1chip", 12, id="gpt2s-12")])
+def test_a_step_keeps_up_x_under_the_chips_memory(v5e, cell, layers):  # noqa: F811
     """One of the two cells with the most to gain, its whole step compiled
     as the chip runs it: helpers/described_chip.py:a_step_keeps_up_x. (The
     other, granite, has a family's file, whose one compile of the cell is
     this step: tests/test_hybrid_mixer.py holds its case.)"""
     from helpers.described_chip import CellStep, a_step_keeps_up_x
-    a_step_keeps_up_x(CellStep(v5e, name, limit=V5E), layers)
+    a_step_keeps_up_x(CellStep(cell, v5e, axes=("data",)), layers)

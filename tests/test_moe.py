@@ -653,5 +653,6 @@ def test_reference_loss_holds_the_program_to_its_logprobs(
 # so the chip's compiler gets this file's programs after its own tests have
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
+    test_the_cells_that_were_there_lower_to_the_same_step,
     test_cell_step_compiles_under_the_chips_memory,
     test_cell_step_makes_a_heads_dw_where_its_logits_are)

@@ -7,6 +7,8 @@ kernels at these widths and head counts tests/test_linear_attention.py's."""
 
 from helpers.described_chip import (  # noqa: F401 — fixtures and checks
     cell_step, test_cell_step_compiles_under_the_chips_memory,
-    test_cell_step_makes_a_heads_dw_where_its_logits_are, v5e)
+    test_cell_step_makes_a_heads_dw_where_its_logits_are,
+    test_the_cells_that_were_there_lower_to_the_same_step,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step, v5e)
 from helpers.families import family  # noqa: F401
 from test_olmo_hybrid_model import FAMILY  # noqa: F401

@@ -23,7 +23,6 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_param_count_is_the_published_model_and_the_programs_tree,
     test_sharded_step_equals_one_device, test_the_cell_rehearses,
     test_the_configuration_refuses_by_name,
-    test_the_new_scopes_are_regions_and_reach_the_compiled_step,
     test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart, tiny)
 
@@ -198,13 +197,17 @@ class OlmoHybrid(Family):
     # once; ONE plain filter over [q | k | v] forward + recomputed, and
     # backward), one full layer of 15 heads of 128 that rotates nothing (one
     # call of each flash kernel; q, k, v through `rope_split` without a
-    # table, forward and recomputed, as solar's grouped-query layer)
+    # table, forward and recomputed, as solar's grouped-query layer). As
+    # the chip runs it all four MLPs keep `up x` through the remat (rung 1,
+    # 0.72 GB; `gate x` does not fit): 13.67 GB compiled (13.62 at rung 0,
+    # which this file compiled until PR 73, under (0.72, 0.92)); + OVERHEAD
+    # 14.09 for the 13.77 the chip read (81.426 %, ledger PR 72).
     cell_kernel_calls = {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
                          "rope_split": 6, "rope_merge": 3, "embed_grad": 1,
                          "conv_silu_fwd": 6, "conv_silu_bwd": 3,
                          "kda_fwd": 3, "kda_bwd": 3}
-    cell_memory_share = (0.72, 0.92)
-    cell_step_marks = (pytest.mark.timeout(600),)
+    cell_memory_share = (0.78, 0.84)
+    cell_rung = 1
 
 
 def _delta(**change):

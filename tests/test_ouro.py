@@ -7,7 +7,8 @@ tests/test_ouro_model.py's."""
 
 from helpers.described_chip import (  # noqa: F401 — fixtures and checks
     cell_step, kernel_ops, test_cell_step_compiles_under_the_chips_memory,
-    v5e)
+    test_the_cells_that_were_there_lower_to_the_same_step,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step, v5e)
 from helpers.families import family  # noqa: F401
 from test_ouro_model import FAMILY  # noqa: F401
 

@@ -13,7 +13,6 @@ import re
 import numpy as np
 import pytest
 
-from helpers.described_chip import V5E_BYTES
 from helpers.families import (  # noqa: F401 — fixtures and shared checks
     Family, case, family, patched, programmed, read, reference, seeded,
     step_kernel_calls, steps_agree,
@@ -24,7 +23,6 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_param_count_is_the_published_model_and_the_programs_tree,
     test_pipeline_refuses_by_name, test_sharded_step_equals_one_device,
     test_the_cell_rehearses, test_the_configuration_refuses_by_name,
-    test_the_new_scopes_are_regions_and_reach_the_compiled_step,
     test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart, tiny)
 
@@ -239,10 +237,12 @@ class Ouro(Family):
                          "flash_bwd_dkv": 8, "rope_split": 48,
                          "rope_merge": 24, "embed_grad": 1}
     cell_memory_share = (0.6, 0.95)
-    cell_step_marks = (pytest.mark.timeout(900),)
-    # compiled ONCE, as the chip runs it: the builder reads a v5e's limit
-    # and the reckoning (over the ceiling) keeps nothing more
-    cell_limit = V5E_BYTES
+    # the loop's row of `_working_set` reads 11.52 GB where the step compiles
+    # to 12.29 (+ OVERHEAD 12.71) and the chip reads 12.55 (74.239 %, ledger
+    # PR 72): the chip's compiler hoists the bf16 casts of every layer's
+    # matrices out of both loops, which the row does not count. It decides
+    # nothing (a looped stack's rung is 0 by rule): ROADMAP D29
+    cell_reckoned = (1.19, 0.0)
 
 
 def _loop(**change):

@@ -238,5 +238,8 @@ def test_selected_kernels_and_the_walk_compile_at_8192_positions(v5e):
 # so the chip's compiler gets this file's programs after its own tests have
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
+    test_the_cells_that_were_there_lower_to_the_same_step,
     test_cell_step_compiles_under_the_chips_memory,
-    test_cell_step_makes_a_heads_dw_where_its_logits_are)
+    test_cell_step_makes_a_heads_dw_where_its_logits_are,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step
+    as test_the_new_scope_is_a_region_and_reaches_the_compiled_step)

@@ -20,8 +20,6 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_param_count_is_the_published_model_and_the_programs_tree,
     test_pipeline_refuses_by_name,
     test_the_configuration_refuses_by_name,
-    test_the_new_scopes_are_regions_and_reach_the_compiled_step
-    as test_the_new_scope_is_a_region_and_reaches_the_compiled_step,
     test_the_programs_gradient_moves_where_the_references_does,
     test_the_reference_tells_each_mechanism_apart,
     test_the_shares_of_a_layer_add_up_to_the_uncut_reference, tiny)
@@ -206,15 +204,20 @@ class Keye(Family):
              "statistics")]
 
     def scopes(self, names, regions):
+        import re
         from ray_tpu.util import profiling
         assert {"attn_index", "attn_proj", "attn_core", "attn_out", "moe",
                 "moe_route"} <= regions
-        # the kernels under the selection are attn_core's; the walk's scan,
-        # the indexer's projections and its table are attn_index's
+        # the kernels under the selection are attn_core's; the walk's
+        # kernels (the CPU's compile of the tiny step, which this read until
+        # PR 73, had the interpreter's `while` in their place), the
+        # indexer's projections and its table are attn_index's
         for n in names:
             if "flash_sel_" in n:
                 assert profiling._last_of(n, profiling.REGIONS) == "attn_core"
-        assert any("attn_index" in n and "while" in n for n in names)
+            if re.search(r"/index_\w+/pallas_call", n):
+                assert profiling._last_of(n, profiling.REGIONS) == "attn_index"
+        assert any("attn_index" in n and "index_search" in n for n in names)
         assert any("attn_index/bsd,dh->bsh" in n for n in names)
 
     reduced = {"num_hidden_layers", "num_experts", "vocab_size"}

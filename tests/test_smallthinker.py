@@ -193,7 +193,6 @@ class SmallThinker(Family):
                          "rope_merge": 12, "moe_gmm": 72, "moe_tgmm": 24,
                          "embed_grad": 1, "moe_run_sum": 8}
     cell_memory_share = (0.60, 0.80)
-    cell_step_marks = (pytest.mark.timeout(600),)
 
 
 FAMILY = SmallThinker()

@@ -15,7 +15,7 @@ import pytest
 from helpers.described_chip import (  # noqa: F401 — fixtures
     attention_layer_gradients, cell_configuration, cell_step, kernel_ops,
     v5e)
-from helpers.families import compiled_scopes, family, tiny  # noqa: F401
+from helpers.families import family, tiny  # noqa: F401
 from test_smallthinker import FAMILY  # noqa: F401
 
 
@@ -47,28 +47,6 @@ def test_the_router_runs_once_a_layer_wherever_it_reads(jax_cpu, tiny,
     jaxpr = jax.make_jaxpr(lambda p, t: gpt_forward(p, t, cfg))(
         params, tokens[:, :-1]).jaxpr
     assert _primitives(jax, jaxpr, "top_k") == cfg.n_layers
-
-
-def test_the_route_ahead_scope_reaches_the_compiled_step(jax_cpu, tiny):
-    """`route_ahead` is a region of the trace's vocabulary, holds the
-    router's product, its top-k and the slots' order (their sorts), and
-    `moe_route` keeps what needs the rows; under remat_policy="full" the
-    step differentiates through the carried routing."""
-    from ray_tpu.util import profiling
-    assert "route_ahead" in profiling.REGIONS
-    cfg = FAMILY.module._train_config(tiny)
-    assert cfg.remat_policy == "full" and cfg.route_from == "input"
-    names, regions = compiled_scopes(jax_cpu, cfg)
-    assert {"route_ahead", "moe", "moe_route", "attn_window", "attn_core",
-            "attn_proj", "attn_out"} <= regions
-    ahead = {n for n in names
-             if profiling._last_of(n, profiling.REGIONS) == "route_ahead"}
-    assert any("bsd,de->bse" in n for n in ahead)       # the router
-    assert any("top_k" in n for n in ahead)
-    assert any("sort" in n for n in ahead)              # the slots' order
-    later = {n for n in names
-             if profiling._last_of(n, profiling.REGIONS) == "moe_route"}
-    assert later and not any("top_k" in n for n in later)
 
 
 def test_a_scanned_stack_carries_the_routing_as_the_loop_does(jax_cpu, tiny):
@@ -300,5 +278,31 @@ def test_a_kind_that_rotates_nothing_compiles_without_a_rotation(
 # so the chip's compiler gets this file's programs after its own tests have
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
+    test_the_cells_that_were_there_lower_to_the_same_step,
     test_cell_step_compiles_under_the_chips_memory,
     test_cell_step_makes_a_heads_dw_where_its_logits_are)
+
+
+def test_the_route_ahead_scope_reaches_the_compiled_step(cell_step):
+    """`route_ahead` is a region of the trace's vocabulary, holds the
+    router's product, its top-k and the slots' order (their sorts), and
+    `moe_route` keeps what needs the rows; under remat_policy="full" the
+    step differentiates through the carried routing. Read off the cell's
+    whole step as the chip's compiler leaves it (until PR 73 a compile of
+    the tiny step's gradient on the CPU)."""
+    from ray_tpu.util import profiling
+    assert "route_ahead" in profiling.REGIONS
+    cfg = FAMILY.module._train_config(FAMILY.cell_config())
+    assert cfg.remat_policy == "full" and cfg.route_from == "input"
+    names = set(re.findall(r'op_name="([^"]*)"', cell_step.text))
+    regions = {profiling._last_of(n, profiling.REGIONS) for n in names}
+    assert {"route_ahead", "moe", "moe_route", "attn_window", "attn_core",
+            "attn_proj", "attn_out"} <= regions
+    ahead = {n for n in names
+             if profiling._last_of(n, profiling.REGIONS) == "route_ahead"}
+    assert any("bsd,de->bse" in n for n in ahead)       # the router
+    assert any("top_k" in n for n in ahead)
+    assert any("sort" in n for n in ahead)              # the slots' order
+    later = {n for n in names
+             if profiling._last_of(n, profiling.REGIONS) == "moe_route"}
+    assert later and not any("top_k" in n for n in later)

@@ -21,8 +21,7 @@ from helpers.families import (  # noqa: F401 — fixtures and shared checks
     test_param_count_is_the_published_model_and_the_programs_tree
     as test_param_count_is_the_cut_and_the_programs_tree,
     test_sharded_step_equals_one_device,
-    test_the_configuration_refuses_by_name,
-    test_the_new_scopes_are_regions_and_reach_the_compiled_step, tiny)
+    test_the_configuration_refuses_by_name, tiny)
 from helpers.jaxprs import dots_of, pallas_calls
 
 
@@ -39,6 +38,7 @@ class NemotronH(Family):
 
     name, tiny, cell = ("nemotron_h", "tiny-nemotron-h",
                         "nemotron-3-super-120b-a12b")
+    workload = "nemotron3s_train_1chip"
 
     def tree(self, jax, config):
         return jax.eval_shape(
@@ -94,9 +94,6 @@ class NemotronH(Family):
         case((_expert_form_of_four, "matrices 2"), "form"),
     ]
 
-    def scopes_config(self, tiny):
-        return self.module._train_config(dict(
-            tiny, num_hidden_layers=2, hybrid_override_pattern="ME"))
 
     def scopes(self, names, regions):
         from ray_tpu.util import profiling
@@ -174,7 +171,11 @@ class NemotronH(Family):
                          "moe_gmm": 96, "moe_tgmm": 24, "moe_run_sum": 18,
                          "embed_grad": 2}
     cell_memory_share = (0.80, 0.92)
-    cell_step_marks = (pytest.mark.timeout(600),)
+    # rung 0 is the floor: the reckoning reads 16.24 GB where its ceiling is
+    # 14.88 and has nothing left to drop; the step compiles to 14.96 (+
+    # OVERHEAD 15.38) and the chip reads 15.06 (89.047 %, ledger PR 72), so
+    # `_working_set` reads this cell ~0.9 GB high (ROADMAP D29)
+    cell_reckoned = (0.0, 1.36)
 
 
 FAMILY = NemotronH()
@@ -1034,6 +1035,8 @@ def test_state_space_layer_compiles_on_four_chip_mesh(v5e, monkeypatch):
 # so the chip's compiler gets this file's programs after its own tests have
 # run, at another minute of a run than the other families' files.
 from helpers.described_chip import (  # noqa: E402,F401
-    test_sparse_layer_compiles_with_both_row_spaces,
+    test_the_cells_that_were_there_lower_to_the_same_step,
     test_cell_step_compiles_under_the_chips_memory,
-    test_cell_step_makes_a_heads_dw_where_its_logits_are)
+    test_cell_step_makes_a_heads_dw_where_its_logits_are,
+    test_sparse_layer_compiles_with_both_row_spaces,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step)

@@ -929,3 +929,67 @@ def test_the_heavy_files_are_placed_first_by_what_they_take():
         addinivalue_line=lambda *a: None)
     conftest.pytest_configure(config)
     assert config.option.loadscopereorder is False
+
+
+def test_a_cells_whole_step_has_one_builder():
+    """tests/helpers/described_chip.py:CellStep is the one place under tests/
+    that turns a cell of BENCHMARK.json into `make_train_step`'s step at the
+    cell's real sizes, and it builds the step the chip runs: (a) no other
+    function under tests/ both calls `make_train_step` and reads
+    BENCHMARK.json or a file of benchmark/configs/ (the rehearsal's tiny
+    configurations are another thing); (b) nobody tells the builder a
+    memory limit: `CellStep` takes none, no family's row has one, and
+    `memory.device_limit` is replaced in the builder alone; (c) every cell
+    in LOWERED is read off a family's one whole step or lowered in
+    tests/test_lowered_steps.py, and none twice."""
+    import ast
+    import glob
+    import inspect
+    import re
+
+    tests = os.path.join(REPO, "tests")
+    check = "test_the_cells_that_were_there_lower_to_the_same_step"
+    builders, limits, steps, checked = [], [], [], []
+    for path in sorted(glob.glob(os.path.join(tests, "**", "*.py"),
+                                 recursive=True)):
+        with open(path) as f:
+            source = f.read()
+        name = os.path.relpath(path, tests)
+        if path == os.path.abspath(__file__):
+            continue        # this case names what it looks for
+        if re.search(r"""setattr\(\s*memory,\s*["']device_limit""", source):
+            limits.append(name)
+        for node in ast.walk(ast.parse(source)):
+            if isinstance(node, ast.ImportFrom) \
+                    and node.module == "helpers.described_chip":
+                names = {alias.name for alias in node.names}
+                steps += [name] * ("cell_step" in names)
+                checked += [name] * (check in names)
+            if not isinstance(node, ast.FunctionDef):
+                continue
+            body = ast.get_source_segment(source, node)
+            if "make_train_step(" in body and re.search(
+                    r"""BENCHMARK\.json|["']benchmark["'],\s*["']configs["']"""
+                    r"""|benchmark/configs""", body):
+                builders.append(f"{name}:{node.name}")
+    assert builders == ["helpers/described_chip.py:__init__"], builders
+    assert limits == ["helpers/described_chip.py"], limits
+
+    sys.path.insert(0, tests)
+    try:
+        chip = importlib.import_module("helpers.described_chip")
+        families = importlib.import_module("helpers.families")
+        lowered = importlib.import_module("test_lowered_steps")
+        rows = [importlib.import_module(file[:-3]).FAMILY
+                for file in families.FILES.values() if file]
+    finally:
+        sys.path.remove(tests)
+    assert list(inspect.signature(chip.CellStep).parameters) == [
+        "cell", "devices", "axes"]
+    for row in rows + [families.Family]:
+        assert not [name for name in dir(row) if "limit" in name], row
+    read = [row.workload for row in rows] + list(lowered.ON_THE_CPU)
+    assert sorted(read) == sorted(lowered.LOWERED), read
+    assert steps == checked and len(steps) == len(rows), (steps, checked)
+    for row in rows:
+        assert row.cases["lowered"][0].values == (row.workload,)

@@ -296,6 +296,16 @@ def test_window_kernels_compile_at_8192_positions_of_128(v5e):
     assert dk.shape == dv.shape == (2, 8, 8192, 128)
 
 
+# Imported last: a module's names are collected in the order they are bound,
+# so the chip's compiler gets this file's programs after its own tests have
+# run, at another minute of a run than the other families' files.
+from helpers.described_chip import (  # noqa: E402,F401
+    test_the_cells_that_were_there_lower_to_the_same_step,
+    test_cell_step_compiles_under_the_chips_memory,
+    test_cell_step_makes_a_heads_dw_where_its_logits_are,
+    test_the_new_scopes_are_regions_and_reach_the_compiled_step)
+
+
 @pytest.mark.parametrize("kind,heads", [("attention", 48), ("window", 64)])
 def test_heads_of_128_reach_wo_without_a_layout_pass(cell_step, kind, heads):
     """laguna_train_1chip's two kinds of attention layer, [2, 48 | 64 on 8,
@@ -325,11 +335,3 @@ def test_heads_of_128_reach_wo_without_a_layout_pass(cell_step, kind, heads):
         assert len(kernel_ops(text, name + kernel)) == layers, kernel
     for dims, line in written_in_entry(text):
         assert math.prod(dims) != batch * seq * heads * 128, line
-
-
-# Imported last: a module's names are collected in the order they are bound,
-# so the chip's compiler gets this file's programs after its own tests have
-# run, at another minute of a run than the other families' files.
-from helpers.described_chip import (  # noqa: E402,F401
-    test_cell_step_compiles_under_the_chips_memory,
-    test_cell_step_makes_a_heads_dw_where_its_logits_are)
